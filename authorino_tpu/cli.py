@@ -503,6 +503,9 @@ async def run_server(args) -> None:
         import jax
 
         jax.config.update("jax_platforms", args.jax_platform)
+    from .utils.jax_env import setup_jax
+
+    compile_cache_dir = setup_jax()
 
     from .controllers.reconciler import AuthConfigReconciler, SecretReconciler
     from .controllers.sources import YamlDirSource
@@ -519,6 +522,7 @@ async def run_server(args) -> None:
         format="%(asctime)s %(levelname)s %(name)s %(message)s",
     )
     log = logging.getLogger("authorino_tpu")
+    log.info("jax compile cache: %s", compile_cache_dir)
 
     cache_mod.EVALUATOR_CACHE_MAX_ENTRIES = args.evaluator_cache_size
     metrics_mod.DEEP_METRICS_ENABLED = args.deep_metrics_enabled
@@ -833,7 +837,9 @@ async def run_server(args) -> None:
     # frontend starts below, after this app — the holder closure lets
     # /debug/vars see it once it exists
     native_holder: dict = {}
-    app = build_app(engine, readiness=reconciler.ready,
+    app = build_app(engine,
+                    readiness=lambda: (reconciler.ready()
+                                       and native_holder.get("warm", True)),
                     max_body=args.max_http_request_body_size,
                     frontend=lambda: native_holder.get("fe"),
                     enable_profile=bool(getattr(args, "debug_profile", False)))
@@ -887,8 +893,10 @@ async def run_server(args) -> None:
                              if kernel_lane_arg != "auto" else None),
             )
             native_fe.start()
-            native_holder["fe"] = native_fe  # /debug/vars picks it up
-            log.info("native grpc ext_authz listening on :%d", args.ext_auth_grpc_port)
+            # /debug/vars picks the frontend up; /readyz is 503 until warmed
+            native_holder.update(fe=native_fe, warm=False)
+            log.info("native grpc ext_authz listening on :%d; warming the "
+                     "jit grid", args.ext_auth_grpc_port)
         except Exception as e:
             if native_fe is not None:
                 # start() may fail after the C++ socket bound — release the
@@ -904,6 +912,20 @@ async def run_server(args) -> None:
     elif native_mode == "on" and tls_credentials is not None:
         raise RuntimeError("--native-frontend=on is incompatible with --tls-cert "
                            "(terminate TLS in front of the native listener)")
+    if native_fe is not None:
+        # not called ready until every warm-grid variant has compiled: a
+        # cold XLA compile must not land on live batches (the completer
+        # watchdog would time them into the degrade path), and a kernel
+        # that cannot compile is a start-up error, not a log line
+        warmed = await asyncio.get_running_loop().run_in_executor(
+            None, native_fe.wait_warm, 3600.0)
+        if not warmed:
+            err = native_fe.warm_error or "warm grid timed out"
+            await asyncio.get_running_loop().run_in_executor(
+                None, native_fe.stop, 0.0)
+            raise RuntimeError(f"native kernel warm grid failed: {err}")
+        native_holder["warm"] = True
+        log.info("native jit grid warm")
     if native_fe is None:
         grpc_server = build_server(
             engine, address=f"0.0.0.0:{args.ext_auth_grpc_port}",

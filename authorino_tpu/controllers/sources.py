@@ -110,6 +110,10 @@ class YamlDirSource:
         return tuple(sig)
 
     async def sync(self) -> None:
+        # remembered BEFORE the read (a write racing it is picked up by the
+        # next poll): the poller's first pass then finds nothing new, where
+        # it used to reconcile the whole start-up corpus a second time
+        self._snapshot_sig = self._signature()
         authconfigs, secrets = load_manifests(self.path)
         current = {s.key for s in secrets}
         for existing in await self.cluster.list_secrets(LabelSelector()):
@@ -121,9 +125,7 @@ class YamlDirSource:
 
     async def run(self) -> None:
         while True:
-            sig = self._signature()
-            if sig != self._snapshot_sig:
-                self._snapshot_sig = sig
+            if self._signature() != self._snapshot_sig:
                 try:
                     await self.sync()
                 except Exception as e:

@@ -13,7 +13,7 @@ dicts and never know the difference).
 Crash semantics are the acceptance criterion: ``crash()`` models a replica
 dying mid-flight — every subsequent (and in-flight) check resolves to a
 TYPED ``CheckAbort(UNAVAILABLE)``, never a raw exception, so the harness's
-failover retry and the caller's error taxonomy both stay honest.  Snapshot
+failover retry and the caller's error classes both stay honest.  Snapshot
 adoption goes through the ordinary distribution path
 (:class:`~..snapshots.distribution.SnapshotReplica` ``poll_once``), so a
 replica joining mid-canary converges on the manifest's ``current`` — the

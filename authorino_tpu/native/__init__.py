@@ -13,10 +13,16 @@ front-ends:
 compiler/encode.py's Python implementation is the semantic reference and the
 automatic fallback.  Builds on first use with the baked-in g++ (no pip
 deps); AUTHORINO_TPU_NATIVE=0 forces the Python path.
+
+Staleness is keyed on a digest of the C++ sources stored beside the ``.so``
+(not on mtimes: a copied tree can carry a stale ``.so`` that is newer than
+the sources it was not built from), and ``source_digest()`` names what the
+loaded library was built from.
 """
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import logging
 import os
@@ -24,7 +30,8 @@ import subprocess
 import sysconfig
 import threading
 
-__all__ = ["load_library", "native_enabled", "NativeEncoder", "get_native_encoder"]
+__all__ = ["load_library", "native_enabled", "source_digest", "loaded_digest",
+           "NativeEncoder", "get_native_encoder"]
 
 log = logging.getLogger("authorino_tpu.native")
 
@@ -32,9 +39,12 @@ _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
 _LIB_PATH = os.path.join(_BUILD_DIR, "_atpuenc.so")
+_DIGEST_PATH = _LIB_PATH + ".src-sha256"
+_SOURCES = ("encoder.cpp", "frontend.cpp", "pymod.cpp")
 
 _lock = threading.Lock()
 _mod = None
+_mod_digest = None
 _load_failed = False
 
 
@@ -42,7 +52,31 @@ def native_enabled() -> bool:
     return os.environ.get("AUTHORINO_TPU_NATIVE", "1") not in ("0", "false", "no")
 
 
-def _build() -> bool:
+def source_digest() -> str:
+    """sha256 over the extension's C++ sources as they stand on disk."""
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
+def loaded_digest():
+    """``source_digest()`` of what the LOADED extension was built from
+    (None before a successful ``load_library``)."""
+    return _mod_digest
+
+
+def _built_digest() -> str:
+    """Digest recorded beside the ``.so`` at build time ("" when absent)."""
+    try:
+        with open(_DIGEST_PATH) as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
+def _build(digest: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
     cmd = [
         "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
@@ -53,7 +87,14 @@ def _build() -> bool:
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=180)
+        # digest away first: a crash between the two renames leaves a .so
+        # with no digest (rebuilt next time), never one with a wrong digest
+        if os.path.exists(_DIGEST_PATH):
+            os.remove(_DIGEST_PATH)
         os.replace(_LIB_PATH + ".tmp", _LIB_PATH)
+        with open(_DIGEST_PATH + ".tmp", "w") as f:
+            f.write(digest + "\n")
+        os.replace(_DIGEST_PATH + ".tmp", _DIGEST_PATH)
         return True
     except (subprocess.SubprocessError, OSError) as e:
         detail = getattr(e, "stderr", b"")
@@ -64,20 +105,20 @@ def _build() -> bool:
 
 def load_library():
     """Build (if stale) and import the _atpuenc extension; None on failure."""
-    global _mod, _load_failed
+    global _mod, _mod_digest, _load_failed
     if _mod is not None or _load_failed or not native_enabled():
         return _mod
     with _lock:
         if _mod is not None or _load_failed:
             return _mod
         try:
-            srcs = [os.path.join(_NATIVE_DIR, f)
-                    for f in ("encoder.cpp", "frontend.cpp", "pymod.cpp")]
-            stale = (not os.path.exists(_LIB_PATH)
-                     or os.path.getmtime(_LIB_PATH) < max(os.path.getmtime(s) for s in srcs))
-        except OSError:
-            stale = True
-        if stale and not _build():
+            digest = source_digest()
+        except OSError as e:
+            log.warning("native sources unreadable: %s", e)
+            _load_failed = True
+            return None
+        stale = not os.path.exists(_LIB_PATH) or _built_digest() != digest
+        if stale and not _build(digest):
             _load_failed = True
             return None
         try:
@@ -88,7 +129,7 @@ def load_library():
             log.warning("native encoder load failed: %s", e)
             _load_failed = True
             return None
-        _mod = mod
+        _mod, _mod_digest = mod, digest
         return _mod
 
 

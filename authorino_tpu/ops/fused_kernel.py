@@ -17,11 +17,11 @@ kernel" taken seriously:
     the [B, 1+2E] attribution concat, and the little-endian bitpack are
     inlined (no separate ``_bitpack_rows`` launch) so the kernel's only
     output is the [B, W] uint8 readback.
-  - ``dispatch_megakernel`` wraps the whole thing in ONE launch: a Pallas
-    kernel on a real TPU backend, ``pl.pallas_call(..., interpret=True)``
-    on this CPU image (bit-exact, so tier-1 pins parity), and a single-jit
-    lax fallback when Pallas is unavailable.  Either way the PR 16 ledger
-    sees ``launches_per_batch == 1.0``.
+  - ``dispatch_megakernel`` wraps the whole thing in ONE ``pl.pallas_call``:
+    compiled by Mosaic on a TPU backend, ``interpret=True`` on a CPU
+    (bit-exact, so tier-1 pins parity).  A body Mosaic refuses raises at
+    the call — there is no quiet switch to another body under the same
+    lane label.  The PR 16 ledger sees ``launches_per_batch == 1.0``.
   - ``dispatch_staged`` is the honest UNFUSED baseline: the same math cut
     into per-stage jits (leaves / DFA / value lanes / circuit / bitpack),
     each its own launch, bit-exact with the fused result — what
@@ -32,8 +32,7 @@ kernel" taken seriously:
 
 Lane selection: ``to_device(..., lane="fused")`` or the
 ``AUTHORINO_TPU_KERNEL_LANE`` env mirror of ``--kernel-lane``; ``auto``
-arms fused only on a real TPU backend (interpret-mode Pallas is an
-emulation, correct but slow — docs/performance.md "Fused mega-kernel").
+never arms it (docs/performance.md "Fused mega-kernel").
 """
 
 from __future__ import annotations
@@ -52,8 +51,7 @@ from ..compiler.compile import DFA_VALUE_BYTES, CompiledPolicy
 
 __all__ = [
     "fused_operands", "eval_fused_kernel", "dispatch_megakernel",
-    "dispatch_staged", "staged_launches", "fused_kernel_supported",
-    "prewarm_fused", "occupancy_pad",
+    "dispatch_staged", "staged_launches", "prewarm_fused", "occupancy_pad",
 ]
 
 
@@ -198,7 +196,7 @@ def _fused_packed(params, ops: dict):
 
 
 # ---------------------------------------------------------------------------
-# the one launch: Pallas kernel (interpret on CPU) / single-jit lax fallback
+# the one launch: Pallas kernel (interpret mode off-TPU)
 # ---------------------------------------------------------------------------
 
 
@@ -241,48 +239,20 @@ def _pallas_wrap(params, ops: dict, extra_flat=None, defuse_layout=None):
     )(*cast, *tail)
 
 
-_PALLAS_OK: Optional[bool] = None
-
-
-def fused_kernel_supported() -> bool:
-    """One-time probe that a tiny Pallas kernel (interpret-mode off-TPU)
-    round-trips on this backend; the dispatcher degrades to the single-jit
-    lax fallback — never to more launches — when it does not."""
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        try:
-            from jax.experimental import pallas as pl
-
-            def k(x_ref, o_ref):
-                o_ref[...] = x_ref[...] + 1
-
-            got = pl.pallas_call(
-                k, out_shape=jax.ShapeDtypeStruct((4,), jnp.int32),
-                interpret=jax.default_backend() != "tpu",
-            )(jnp.arange(4, dtype=jnp.int32))
-            _PALLAS_OK = bool(
-                np.array_equal(np.asarray(got), np.arange(4) + 1))
-        except Exception:
-            _PALLAS_OK = False
-    return _PALLAS_OK
-
-
-@partial(jax.jit, static_argnames=("layout", "use_pallas"))
-def _fused_buf_jit(params, buf, layout, use_pallas):
+@partial(jax.jit, static_argnames=("layout",))
+def _fused_buf_jit(params, buf, layout):
     """ONE launch over the fused H2D staging buffer: operand decode, every
     lane, the circuit, and the bitpack in a single executable."""
-    if use_pallas:
-        return _pallas_wrap(params, {}, extra_flat=(buf,),
-                            defuse_layout=layout)
-    return _fused_packed(params, pe._defuse(buf, layout))
+    return _pallas_wrap(params, {}, extra_flat=(buf,), defuse_layout=layout)
 
 
-@partial(jax.jit, static_argnames=("use_pallas",))
+@jax.jit
 def _fused_ops_jit(params, attrs_val, members_c, cpu_dense, config_id,
                    attr_bytes, byte_ovf, attrs_num, num_valid, rel_rows,
-                   member_ovf, use_pallas):
+                   member_ovf):
     """Per-operand-transfer variant of the one launch (big-endian hosts
-    where the fused H2D bitcast probe fails, and the zero-operand warm)."""
+    where the fused H2D bitcast probe fails, the native lane whose operands
+    are already separate arrays, and the zero-operand warm)."""
     ops = {"attrs_val": attrs_val, "members_c": members_c,
            "cpu_dense": cpu_dense, "config_id": config_id}
     for name, a in (("attr_bytes", attr_bytes), ("byte_ovf", byte_ovf),
@@ -290,18 +260,15 @@ def _fused_ops_jit(params, attrs_val, members_c, cpu_dense, config_id,
                     ("rel_rows", rel_rows), ("member_ovf", member_ovf)):
         if a is not None:
             ops[name] = a
-    if use_pallas:
-        return _pallas_wrap(params, ops)
-    return _fused_packed(params, ops)
+    return _pallas_wrap(params, ops)
 
 
 def eval_fused_kernel(params, db) -> "jax.Array":
     """One compact batch through the mega-kernel; returns the on-device
     [B, W] uint8 bitpacked readback (decode with ``pe.unpack_verdicts``)."""
-    use_pallas = fused_kernel_supported()
     if pe.fused_h2d_supported():
         buf, layout = pe.fuse_batch(db)
-        return _fused_buf_jit(params, jnp.asarray(buf), layout, use_pallas)
+        return _fused_buf_jit(params, jnp.asarray(buf), layout)
     has_dfa = params["dfa_tables"] is not None
     return _fused_ops_jit(
         params,
@@ -312,7 +279,6 @@ def eval_fused_kernel(params, db) -> "jax.Array":
         jnp.asarray(db.attr_bytes) if has_dfa else None,
         jnp.asarray(db.byte_ovf) if has_dfa else None,
         *pe._extra_operands(db),
-        use_pallas=use_pallas,
     )
 
 

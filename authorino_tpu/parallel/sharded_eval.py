@@ -22,11 +22,6 @@ Layout:
 
 Mesh as the first-class lane (ISSUE 11):
 
-  - **shard-map port**: ``jax.shard_map`` only exists on newer jax; this
-    image's jax 0.4.37 ships it as ``jax.experimental.shard_map.shard_map``.
-    ``_shard_map`` resolves the fast path when present and falls back to the
-    experimental module — the seed AttributeError family converts to
-    passing tests.
   - **grid relief**: each mp shard compiles only its sub-corpus, so its
     member-attr grid M is ~1/mp of the monolithic corpus — the per-device
     membership payload budget (M × K) supports a proportionally LARGER
@@ -98,13 +93,6 @@ def flat_config_rows(shards, rows, configs_per_shard):
             + _np.asarray(rows, dtype=_np.int64))
 
 log = logging.getLogger("authorino_tpu.sharded_eval")
-
-# jax.shard_map is the stable spelling on newer jax; 0.4.37 (this image)
-# only has the experimental module.  Resolve once at import.
-try:
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 # grid relief ceiling: rule-sharding shrinks each shard's member-attr grid
 # ~1/mp, so the compact membership K can grow ~mp× inside the same
@@ -191,7 +179,7 @@ def _sharded_step(mesh: Mesh, has_dfa: bool, has_matmul: bool, n_levels: int,
                  if has_num else (None, None))
     rel_specs = ((P("dp", "mp", None),) if has_rel else (None,))
     ovf_specs = ((P("dp", "mp", None),) if has_ovf else (None,))
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local_eval,
         mesh=mesh,
         in_specs=(
@@ -309,9 +297,16 @@ class MeshState:
                 n = self.occupancy[d] = self.occupancy.get(d, 0) + 1
                 if n > self.occupancy_peak.get(d, 0):
                     self.occupancy_peak[d] = n
-                self.launches[d] = self.launches.get(d, 0) + 1
                 metrics_mod.mesh_shard_occupancy.labels(str(d)).set(n)
         return MeshRoute(self, devices)
+
+    def count_launch(self, devices: List[int]) -> None:
+        """One serving launch that ran on ``devices`` (counted where the
+        launch happens, so every lane that dispatches shows up — the
+        engine's routed launches and the native frontend's alike)."""
+        with self.lock:
+            for d in devices:
+                self.launches[d] = self.launches.get(d, 0) + 1
 
     def release(self, devices: List[int]) -> None:
         from ..utils import metrics as metrics_mod
@@ -749,11 +744,11 @@ class ShardedPolicyModel:
             member_ovf=take(encoded.member_ovf),
         )
 
-    def dispatch_full(self, encoded: _ShardedEncoded):
-        """Non-blocking launch: returns the ON-DEVICE packed own-rows
-        result [B, 1+2E] (readback copy started eagerly), so the caller can
-        keep further batches in flight while this one rides the link — the
-        sharded mirror of the engine's pipelined dispatch window."""
+    def launch(self, encoded: _ShardedEncoded):
+        """Enqueue the shard_map step for one encoded batch and return the
+        on-device bit-packed result — THE operand list of the step (absent
+        lanes pass None, matching its in_specs), with no accounting: warm-up
+        calls it with zero operands, ``dispatch_full`` wraps it for serving."""
         if self._step is None:
             raise RuntimeError(
                 "ShardedPolicyModel not staged: call upload() after the "
@@ -775,6 +770,15 @@ class ShardedPolicyModel:
                 jnp.asarray(encoded.shard_of),
                 jnp.asarray(encoded.row_of),
             )
+        return packed
+
+    def dispatch_full(self, encoded: _ShardedEncoded):
+        """Non-blocking serving launch: returns the ON-DEVICE packed
+        own-rows result [B, 1+2E] (readback copy started eagerly), so the
+        caller can keep further batches in flight while this one rides the
+        link — the sharded mirror of the engine's pipelined dispatch
+        window."""
+        packed = self.launch(encoded)
         try:
             packed.copy_to_host_async()
         except Exception:
@@ -784,6 +788,7 @@ class ShardedPolicyModel:
         LEDGER.observe_launch("mesh", 1,
                               h2d_bytes=self._encoded_h2d_bytes(encoded),
                               d2h_bytes=self._d2h_bytes(encoded))
+        self.state.count_launch(self.state.device_ids)
         return packed
 
     def _encoded_h2d_bytes(self, encoded: _ShardedEncoded) -> int:
@@ -856,6 +861,7 @@ class ShardedPolicyModel:
         LEDGER.observe_launch("mesh", 1,
                               h2d_bytes=self._encoded_h2d_bytes(encoded),
                               d2h_bytes=self._d2h_bytes(encoded))
+        self.state.count_launch([device_id])
         return packed
 
     def dispatch_routed(self, encoded: _ShardedEncoded, lane: str = "engine"

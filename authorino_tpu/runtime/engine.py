@@ -700,6 +700,9 @@ class PolicyEngine:
         # AUTHORINO_TPU_KERNEL_LANE; "fused" arms the one-launch
         # mega-kernel, ops/fused_kernel.py)
         self.kernel_lane = kernel_lane
+        # the serving snapshot's kernel compile failure at swap-time
+        # prewarm, if any (/debug/vars, /readyz)
+        self.warm_error: Optional[str] = None
         self.metadata_prefetcher = None
         if metadata_prefetch:
             from ..relations.prefetch import MetadataPrefetcher
@@ -1133,15 +1136,20 @@ class PolicyEngine:
             log.exception("kernel cost analysis failed (swap unaffected)")
         # fused mega-kernel pre-warm (ISSUE 17): compile the one-launch
         # entry at a small warm-grid pad at swap so the first
-        # post-reconcile batch pays no XLA/Pallas compile.  Advisory: a
-        # warm failure never affects the swap (dispatch compiles lazily).
+        # post-reconcile batch pays no XLA/Pallas compile.  The swap has
+        # already happened (the degrade path answers exactly if dispatch
+        # fails too), but a kernel that does not lower is surfaced:
+        # warm_error rides /debug/vars and turns /readyz 503.
         try:
             if snap.policy is not None and snap.params is not None:
                 from ..ops import fused_kernel as fused_mod
 
                 fused_mod.prewarm_fused(snap.policy, snap.params, pad=16)
-        except Exception:
-            log.exception("fused-kernel prewarm failed (swap unaffected)")
+            self.warm_error = None
+        except Exception as e:
+            log.exception("fused kernel failed to compile at swap "
+                          "(generation %d)", snap.generation)
+            self.warm_error = f"{type(e).__name__}: {e}"
 
     def _build_heat(self, snap: "_Snapshot") -> None:
         if snap.heat is not None:
@@ -1977,6 +1985,7 @@ class PolicyEngine:
             "translation_validation": (getattr(snap, "translation", None)
                                        if snap is not None else None),
             "breaker": self.breaker.to_json(),
+            "warm_error": self.warm_error,
             "draining": self._draining,
             "device_timeout_s": self.device_timeout_s,
             "device_rtt_ewma_s": self._device_ewma,

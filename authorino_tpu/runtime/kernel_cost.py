@@ -282,26 +282,12 @@ def entry_points(policy=None, sharded=None) -> List[Dict[str, Any]]:
         })
         out.append({
             "entry": "fused_kernel",
-            "kind": "one-launch mega-kernel (ISSUE 17): Pallas on TPU, "
-                    "interpret-mode Pallas on CPU, single-jit lax "
-                    "fallback; every lane + circuit + in-kernel bitpack "
-                    "in one executable, armed by --kernel-lane fused",
+            "kind": "one-launch mega-kernel (ISSUE 17): one Pallas call "
+                    "(interpret mode off-TPU); every lane + circuit + "
+                    "in-kernel bitpack in one executable, armed only by "
+                    "an explicit --kernel-lane fused",
             "operands": ops,
         })
-    # --kernel-lane auto provenance (ISSUE 18 satellite): the last auto
-    # resolution (lane armed + the device platforms consulted) rides the
-    # dispatchable entries as a FIELD — the entry list itself is a pinned
-    # audit surface and must not grow phantom entry points
-    try:
-        from ..ops.pattern_eval import last_auto_decision
-
-        dec = last_auto_decision()
-    except Exception:  # pragma: no cover - import cycle hygiene
-        dec = None
-    if dec is not None:
-        for e in out:
-            if e["entry"] in ("fused_kernel", "sharded_step"):
-                e["kernel_lane_auto"] = dec
     return out
 
 

@@ -593,6 +593,11 @@ class _SnapRec:
     # ref pkg/evaluators/authorization/opa.go:141)
     warm: set = field(default_factory=set)
     warm_done: threading.Event = field(default_factory=threading.Event)
+    # first kernel lowering/compile failure of this snapshot's warm grid
+    # (swap gate or background rest): surfaced on /debug/vars and /readyz,
+    # never only in the log — a kernel that cannot compile must not look
+    # healthy while the degrade path does the serving
+    warm_error: Optional[str] = None
     # configs with dyn sources: entry.id → (fc_idx, auth_attrs, policy,
     # {id(IdentityConfig): (source idx, ttl cap)}, hybrid) — the slow lane
     # registers verified-credential plan variants against this snapshot
@@ -983,8 +988,12 @@ class NativeFrontend:
         backlog gauges, the serving snapshot id, its warmed jit grid, and
         the frontend's batching knobs."""
         rec = self._cur_rec
+        from ..native import loaded_digest
+
         out: Dict[str, Any] = {
             "running": self._running,
+            # sha256 of the C++ sources the loaded extension was built from
+            "source_digest": loaded_digest(),
             "stats": {k: int(v) for k, v in self.stats().items()},
             "max_batch": self.max_batch,
             "window_us": self.window_us,
@@ -1049,12 +1058,37 @@ class NativeFrontend:
             out["snapshot"] = {
                 "snap_id": rec.snap_id,
                 "warm": sorted([list(pe) for pe in rec.warm]),
-                "warm_done": rec.warm_done.is_set(),
+                "warm_done": (rec.warm_done.is_set()
+                              and rec.warm_error is None),
+                "warm_error": rec.warm_error,
+                # what a device dispatch of this snapshot runs: the lane
+                # (operand layout + math) and the body that executes it
+                "kernel": self._kernel_of(rec),
                 "fast_configs": len(rec.row_labels),
                 "hybrid_configs": len(rec.hybrid_rows),
                 "dyn_registrations": len(rec.dyn_regs),
             }
         return out
+
+    @staticmethod
+    def _kernel_of(rec: _SnapRec) -> Optional[Dict[str, str]]:
+        from ..ops.pattern_eval import kernel_body_of, kernel_lane_of
+
+        if rec.sharded is not None:
+            return {"lane": kernel_lane_of(rec.sharded.host_view),
+                    "body": "lax", "entry": "sharded_step"}
+        if rec.params is None:
+            return None
+        lane = kernel_lane_of(rec.params)
+        return {"lane": lane, "body": kernel_body_of(rec.params),
+                "entry": ("fused_kernel" if lane == "fused"
+                          else "eval_bitpacked")}
+
+    @property
+    def warm_error(self) -> Optional[str]:
+        """The serving snapshot's kernel warm failure, if any (/readyz)."""
+        rec = self._cur_rec
+        return rec.warm_error if rec is not None else None
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1211,23 +1245,22 @@ class NativeFrontend:
         from ..ops.pattern_eval import eval_bitpacked_jit
 
         if rec.sharded is not None:
+            from ..parallel.sharded_eval import _ShardedEncoded
+
             sh = rec.sharded
             p0 = sh.shards[0]
             S, A, M, K = sh.n_shards, p0.n_attrs, p0.n_member_attrs, p0.members_k
             C, NB = p0.n_cpu_leaves, max(p0.n_byte_attrs, 1)
-            with sh.state.launch_lock:  # psum enqueue-order consistency
-                out = sh._step(
-                    sh.params,
-                    jnp.asarray(np.zeros((pad, S, A), dtype=np.int32)),
-                    jnp.asarray(np.full((pad, S, M, K), PAD, dtype=np.int32)),
-                    jnp.asarray(np.zeros((pad, S, C), dtype=bool)),
-                    jnp.asarray(np.zeros((pad, S, NB, eff), dtype=np.uint8))
-                    if eff else None,
-                    jnp.asarray(np.zeros((pad, S, NB), dtype=bool))
-                    if eff else None,
-                    jnp.asarray(np.zeros((pad,), dtype=np.int32)),
-                    jnp.asarray(np.zeros((pad,), dtype=np.int32)),
-                )
+            out = sh.launch(_ShardedEncoded(
+                attrs_val=np.zeros((pad, S, A), dtype=np.int32),
+                members_c=np.full((pad, S, M, K), PAD, dtype=np.int32),
+                cpu_dense=np.zeros((pad, S, C), dtype=bool),
+                attr_bytes=np.zeros((pad, S, NB, eff), dtype=np.uint8)
+                if eff else None,
+                byte_ovf=np.zeros((pad, S, NB), dtype=bool) if eff else None,
+                shard_of=np.zeros((pad,), dtype=np.int32),
+                row_of=np.zeros((pad,), dtype=np.int32),
+                host_fallback=np.zeros((pad,), dtype=bool)))
             jax.block_until_ready(out)
             rec.warm.add((pad, eff))
             return
@@ -1265,7 +1298,6 @@ class NativeFrontend:
                 jnp.asarray(np.zeros((pad, NB), dtype=bool))
                 if eff else None,
                 None, None, None, None,
-                use_pallas=fused_mod.fused_kernel_supported(),
             )
             jax.block_until_ready(out)
         rec.warm.add((pad, eff))
@@ -1278,10 +1310,7 @@ class NativeFrontend:
             # before the long tail of device variants compiles (the same
             # latency-spike class as the brownout worker-thread fix)
             if self.lanes.enabled:
-                try:
-                    self._warm_host(rec)
-                except Exception:
-                    log.exception("host-lane jit pre-warm failed")
+                self._warm_host(rec)
             for pad, eff in grid:
                 # bail once superseded: a draining snapshot never sees new
                 # shapes, and its compiles would contend with the successor's
@@ -1292,8 +1321,10 @@ class NativeFrontend:
                 if (pad, eff) in rec.warm:
                     continue
                 self._warm_one(rec, pad, eff)
-        except Exception:
-            log.exception("jit pre-warm failed")
+        except Exception as e:
+            log.exception("kernel warm grid failed for snapshot %d",
+                          rec.snap_id)
+            rec.warm_error = rec.warm_error or f"{type(e).__name__}: {e}"
         finally:
             rec.warm_done.set()
 
@@ -1318,6 +1349,21 @@ class NativeFrontend:
                 if (pad, eff) not in rec.host_warm:
                     self._warm_host_one(rec, pad, eff)
 
+    @staticmethod
+    def _host_twin(rec: _SnapRec):
+        """The CPU device the host twin runs on, with ``rec.host_params``
+        built (once per snapshot) FOR that device: in a TPU process the
+        default backend's bf16 matmul operands are what the CPU backend
+        cannot multiply."""
+        import jax
+
+        from ..ops.pattern_eval import to_device
+
+        cpu = jax.devices("cpu")[0]
+        if rec.host_params is None:
+            rec.host_params = to_device(rec.policy, device=cpu, host=True)
+        return cpu
+
     def _warm_host_one(self, rec: _SnapRec, pad: int, eff: int) -> None:
         """Compile (and cache) the CPU-backend jit variant for one bucket
         shape using throwaway zero operands — the host-lane mirror of
@@ -1327,15 +1373,13 @@ class NativeFrontend:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pattern_eval import eval_bitpacked_jit, to_device
+        from ..ops.pattern_eval import eval_bitpacked_jit
 
-        if rec.host_params is None:
-            rec.host_params = to_device(rec.policy, host=True)
+        cpu = self._host_twin(rec)
         policy = rec.policy
         dt = wire_dtype(policy)
         A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
         C, NB = policy.n_cpu_leaves, max(policy.n_byte_attrs, 1)
-        cpu = jax.devices("cpu")[0]
         with jax.default_device(cpu):
             out = eval_bitpacked_jit(
                 rec.host_params,
@@ -1379,14 +1423,16 @@ class NativeFrontend:
         ch.inc()
 
     def wait_warm(self, timeout_s: float = 600.0) -> bool:
-        """Block until every jit bucket variant of the newest snapshot is
-        compiled (bench/CLI call this after start() so no XLA compile lands
-        on live traffic)."""
+        """Block until the newest snapshot's warm grid has finished; True
+        when every jit bucket variant compiled, False on a timeout or a
+        kernel that failed to compile (``warm_error`` says which).  The CLI
+        and the benches call this after start(), so the server is not
+        called ready while XLA compiles can still land on live traffic."""
         with self._lock:
             rec = self._snaps.get(self._next_snap_id - 1)
         if rec is None:
             return True
-        return rec.warm_done.wait(timeout_s)
+        return rec.warm_done.wait(timeout_s) and rec.warm_error is None
 
     # ------------------------------------------------------------------
     def refresh(self) -> None:
@@ -1797,8 +1843,10 @@ class NativeFrontend:
                 # previous snapshot keeps serving meanwhile, and once this
                 # one is current every batch shape can round up to it
                 self._warm_one(rec, *grid[0])
-            except Exception:
-                log.exception("jit pre-warm (swap gate) failed")
+            except Exception as e:
+                log.exception("kernel failed to compile at the swap gate "
+                              "(snapshot %d, shape %s)", snap_id, grid[0])
+                rec.warm_error = f"{type(e).__name__}: {e}"
         mod.fe_swap(spec)
         metrics_mod.snapshot_generation.labels("native_frontend").set(snap_id)
         try:
@@ -1810,7 +1858,7 @@ class NativeFrontend:
                                      recorder=RECORDER)
         except Exception:
             log.exception("kernel cost analysis failed (swap unaffected)")
-        if grid:
+        if grid and rec.warm_error is None:
             # NON-daemon and tracked: a daemon thread mid-XLA-compile at
             # interpreter exit force-unwinds through native code and aborts
             # the process ("FATAL: exception not rethrown"); stop() joins
@@ -2071,19 +2119,18 @@ class NativeFrontend:
             unique_rows, inverse = miss_rows, np.arange(len(miss_rows))
         return ckeys, eligible, cached, miss_rows, unique_rows, inverse, elig_miss
 
-    def _row_h2d_bytes(self, a: Dict[str, np.ndarray], eff: int,
-                       has_dfa: bool, sharded: bool) -> int:
-        """Per-row operand bytes one launch stages from this slot's
-        arrays at byte-width ``eff`` (pure shape arithmetic — numpy basic
-        indexing views, no copies): multiply by the pad bucket for the
-        ledger's exact H2D count."""
+    @staticmethod
+    def _row_h2d_bytes(a: Dict[str, np.ndarray], eff: int,
+                       has_dfa: bool) -> int:
+        """Per-row operand bytes one single-corpus launch stages from this
+        slot's arrays at byte-width ``eff`` (pure shape arithmetic — numpy
+        basic indexing views, no copies): multiply by the pad bucket for
+        the ledger's exact H2D count."""
         per = (a["attrs_val"][0].nbytes + a["members"][0].nbytes
                + a["cpu_dense"][0].nbytes + a["config_id"].dtype.itemsize)
         if has_dfa:
             per += (a["attr_bytes"][0][..., :eff].nbytes
                     + a["byte_ovf"][0].nbytes)
-        if sharded:
-            per += a["shard_of"].dtype.itemsize  # mesh routing row
         return int(per)
 
     def _dispatch(self, snap_id: int, slot: int, count: int,
@@ -2229,20 +2276,21 @@ class NativeFrontend:
                 faults.FAULTS.check("h2d", "native")
                 faults.FAULTS.check("kernel", "native")
             if rec.sharded is not None:
-                with sh.state.launch_lock:  # psum enqueue-order consistency
-                    packed = sh._step(
-                        sh.params,
-                        jnp.asarray(sel("attrs_val")),
-                        jnp.asarray(sel("members")),
-                        jnp.asarray(sel("cpu_dense").view(bool)),
-                        jnp.asarray(np.ascontiguousarray(
-                            sel("attr_bytes")[..., :eff]))
-                        if has_dfa else None,
-                        jnp.asarray(sel("byte_ovf").view(bool))
-                        if has_dfa else None,
-                        jnp.asarray(sel("shard_of")),
-                        jnp.asarray(sel("config_id")),
-                    )
+                from ..parallel.sharded_eval import _ShardedEncoded
+
+                # dispatch_full owns the step's operand list, the mesh
+                # ledger launch (+ exact operand bytes) and the per-device
+                # launch counts
+                packed = sh.dispatch_full(_ShardedEncoded(
+                    attrs_val=sel("attrs_val"),
+                    members_c=sel("members"),
+                    cpu_dense=sel("cpu_dense").view(bool),
+                    attr_bytes=np.ascontiguousarray(
+                        sel("attr_bytes")[..., :eff]) if has_dfa else None,
+                    byte_ovf=sel("byte_ovf").view(bool) if has_dfa else None,
+                    shard_of=sel("shard_of"),
+                    row_of=sel("config_id"),
+                    host_fallback=np.zeros((pad,), dtype=bool)))
             elif rec.params.get("fused") is not None:
                 # fused lane (ISSUE 17): the ONE-launch mega-kernel entry
                 # (operands are already separate arrays here, so the
@@ -2262,7 +2310,6 @@ class NativeFrontend:
                     jnp.asarray(sel("byte_ovf").view(bool))
                     if has_dfa else None,
                     None, None, None, None,
-                    use_pallas=fused_mod.fused_kernel_supported(),
                 )
             else:
                 packed = eval_bitpacked_jit(
@@ -2294,16 +2341,10 @@ class NativeFrontend:
             # structural cost fold (ISSUE 16): ONE launch per slot, the
             # exact H2D operand bytes this (pad, eff) variant staged and
             # the bitpacked [pad, W] readback.  eff-column slack is the
-            # warm-shape round-up (eff - eff_need); sharded slots count
-            # their collective launch on the mesh lane instead (one per
-            # shard-step — LEDGER.observe_launch fires in sh._step's
-            # dispatch path only for dispatch_full, so count it here)
-            h2d = pad * self._row_h2d_bytes(a, eff, has_dfa,
-                                            rec.sharded is not None)
-            d2h = int(packed.shape[0]) * int(packed.shape[1])
+            # warm-shape round-up (eff - eff_need); sharded slots fold the
+            # batch here, their collective launch and its bytes were
+            # counted on the mesh lane by dispatch_full
             if rec.sharded is not None:
-                LEDGER.observe_launch("mesh", 1, h2d_bytes=h2d,
-                                      d2h_bytes=d2h)
                 LEDGER.observe(
                     "mesh", rows=count, device_rows=u, pad_rows=pad,
                     eff_slack_cols=eff - eff_need,
@@ -2314,7 +2355,9 @@ class NativeFrontend:
             else:
                 LEDGER.observe(
                     "native", rows=count, device_rows=u, launches=1,
-                    h2d_bytes=h2d, d2h_bytes=d2h, pad_rows=pad,
+                    h2d_bytes=pad * self._row_h2d_bytes(a, eff, has_dfa),
+                    d2h_bytes=int(packed.shape[0]) * int(packed.shape[1]),
+                    pad_rows=pad,
                     eff_slack_cols=eff - eff_need,
                     dedup_avoided_rows=(len(fan[3]) - u
                                         if fan is not None else 0),
@@ -2672,15 +2715,10 @@ class NativeFrontend:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pattern_eval import (
-            eval_bitpacked_jit,
-            to_device,
-            unpack_attribution,
-        )
+        from ..ops.pattern_eval import eval_bitpacked_jit, unpack_attribution
 
         a = rec.arrays[slot]
-        if rec.host_params is None:
-            rec.host_params = to_device(rec.policy, host=True)
+        cpu = self._host_twin(rec)
         has_dfa = rec.host_params["dfa_tables"] is not None
         pad = min(bucket_pow2(count), self.max_batch)
         eff = (_trim_bytes(a["attr_bytes"][:count]).shape[-1]

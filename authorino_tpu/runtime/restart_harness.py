@@ -334,6 +334,9 @@ def main(argv=None) -> int:
     r.add_argument("--table", required=True)
     r.add_argument("--report", required=True)
     args = ap.parse_args(argv)
+    from ..utils.jax_env import setup_jax
+
+    setup_jax()
     if args.cmd == "serve":
         return cmd_serve(args)
     return cmd_restart(args)

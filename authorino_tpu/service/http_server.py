@@ -181,8 +181,18 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
         if getattr(engine, "draining", False):
             return web.Response(status=503, text="draining")
         if readiness is None or readiness():
+            # a kernel that failed to lower/compile for the serving
+            # snapshot is NOT ready, whatever the degrade path can still
+            # answer: the device this server exists for is not serving
+            owners = (("engine", engine), ("native", _frontend()))
+            for lane, owner in owners:
+                err = getattr(owner, "warm_error", None) if owner else None
+                if isinstance(err, str) and err:
+                    return web.Response(
+                        status=503,
+                        text=f"not ready: {lane} kernel warm failed: {err}")
             degraded = []
-            for lane, owner in (("engine", engine), ("native", _frontend())):
+            for lane, owner in owners:
                 breaker = getattr(owner, "breaker", None) if owner else None
                 if breaker is not None and breaker.state != "closed":
                     degraded.append(
@@ -243,9 +253,14 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
         read; safe to scrape under load."""
         import time as _time
 
+        from ..utils.jax_env import jax_process_info
+
         data = {
             "engine": engine.debug_vars(),
-            "process": {"pid": os.getpid(), "time": _time.time()},
+            # what the kernels run on: platform, device kind + count,
+            # library versions, compile-cache placement and traffic
+            "process": {"pid": os.getpid(), "time": _time.time(),
+                        **jax_process_info()},
         }
         fe = _frontend()
         if fe is not None:

@@ -284,7 +284,7 @@ batch_queue_wait = _histogram(
 device_dispatch_duration = _histogram(
     "auth_server_device_dispatch_seconds",
     "Wall time of one kernel launch: operand upload + device execute + "
-    "verdict readback (on a tunneled device this is dominated by link RTT).",
+    "verdict readback.",
     _LANE_LABELS,
     buckets=STAGE_BUCKETS,
 )

@@ -1821,7 +1821,7 @@ static void complete_batch(Server* S, int64_t snap_id, int slot, const uint8_t* 
     for (Done& d : dones) S->done_q.push_back(std::move(d));
   }
   // per-request on-box stages + the duration series the pipeline observes
-  // (ref pkg/service/auth_pipeline.go:26-36): all clocked here, no tunnel.
+  // (ref pkg/service/auth_pipeline.go:26-36): all clocked here, on the box.
   // Hybrid handoffs skip the duration series — the Python pipeline they
   // continue into observes them itself (no double counting)
   for (size_t i = 0; i < entries.size(); ++i) {

@@ -389,40 +389,37 @@ def test_kernel_lane_env_and_auto_resolution(monkeypatch):
     monkeypatch.setenv("AUTHORINO_TPU_KERNEL_LANE", "fused")
     p = pe.to_device(policy)
     assert p["fused"] is not None and pe.kernel_lane_of(p) == "fused"
+    assert pe.kernel_body_of(p) == "pallas"
     monkeypatch.delenv("AUTHORINO_TPU_KERNEL_LANE")
-    if jax.default_backend() != "tpu":
-        # auto keeps the classic per-stage lane off-TPU
-        assert pe.to_device(policy)["fused"] is None
     # explicit argument wins regardless of env
     monkeypatch.setenv("AUTHORINO_TPU_KERNEL_LANE", "gather")
     assert pe.to_device(policy, lane="fused")["fused"] is not None
 
 
-def test_kernel_lane_auto_consults_every_device(monkeypatch):
-    """ISSUE 18 satellite: auto arms the fused lane iff EVERY device is a
-    real TPU.  jax.default_backend() names only the highest-priority
-    platform, so a single TPU in a mixed device set used to arm the
-    Pallas kernel for devices that can only interpret it."""
+def test_kernel_lane_auto_is_matmul_on_every_platform(monkeypatch):
+    """`--kernel-lane auto` resolves to the matmul lane whatever the target
+    device: on a TPU v5e it is the body that compiled and served (PR 21's
+    chip run), and the fused lane's Pallas body does not lower there — so
+    auto never arms it, and what serves is reported as lax, not Pallas."""
 
     class _Dev:
         def __init__(self, platform):
             self.platform = platform
 
-    assert pe.auto_lane(_Dev("tpu")) == "fused"
-    assert pe.auto_lane(_Dev("cpu")) != "fused"
-    # the regression: mixed visibility must NOT arm fused, whatever the
-    # default backend claims
-    monkeypatch.setattr(pe.jax, "devices",
-                        lambda *a, **k: [_Dev("tpu"), _Dev("cpu")])
-    assert pe.auto_lane() != "fused"
-    dec = pe.last_auto_decision()
-    assert dec == {"requested": "auto", "lane": dec["lane"],
-                   "devices": 2, "platforms": ["cpu", "tpu"]}
-    # all-TPU visibility is the one case that arms it
-    monkeypatch.setattr(pe.jax, "devices",
-                        lambda *a, **k: [_Dev("tpu"), _Dev("tpu")])
-    assert pe.auto_lane() == "fused"
-    assert pe.last_auto_decision()["platforms"] == ["tpu"]
+    policy = compile_corpus([ConfigRules(name="c", evaluators=[
+        (None, Pattern("a.b", Operator.EQ, "x"))])], members_k=4)
+    monkeypatch.delenv("AUTHORINO_TPU_KERNEL_LANE", raising=False)
+    monkeypatch.delenv("AUTHORINO_TPU_EVAL_LANE", raising=False)
+    for platform in ("tpu", "cpu"):
+        p = pe.to_device(policy, device=_Dev(platform), host=True)
+        assert pe.kernel_lane_of(p) == "matmul", platform
+        assert p["fused"] is None and pe.kernel_body_of(p) == "lax"
+    # the operands follow the device they are built for: bf16 for the MXU,
+    # f32 for the CPU backend (the native lane's host twin in a TPU process)
+    tpu = pe.to_device(policy, device=_Dev("tpu"), host=True)
+    cpu = pe.to_device(policy, device=_Dev("cpu"), host=True)
+    assert str(tpu["matmul"]["rule_m"].dtype) == "bfloat16"
+    assert str(cpu["matmul"]["rule_m"].dtype) == "float32"
 
 
 def test_occupancy_pad_shapes():

@@ -343,14 +343,26 @@ class TestNativeRowBytes:
 
         a = self._arrays()
         base = 12 + 32 + 5 + 4
-        assert NativeFrontend._row_h2d_bytes(None, a, 0, False, False) \
-            == base
+        assert NativeFrontend._row_h2d_bytes(a, 0, False) == base
         # DFA lane ships the eff-trimmed byte columns + overflow flags
-        assert NativeFrontend._row_h2d_bytes(None, a, 6, True, False) \
+        assert NativeFrontend._row_h2d_bytes(a, 6, True) \
             == base + 2 * 6 + 2
-        # mesh routing adds one shard_of element per row
-        assert NativeFrontend._row_h2d_bytes(None, a, 6, True, True) \
-            == base + 2 * 6 + 2 + 4
+        # mesh slots are counted by the sharded model's own dispatch_full
+        # (the native lane hands it the slot arrays): routing adds one
+        # shard_of element per row
+        from authorino_tpu.parallel.sharded_eval import (
+            ShardedPolicyModel,
+            _ShardedEncoded,
+        )
+
+        enc = _ShardedEncoded(
+            attrs_val=a["attrs_val"], members_c=a["members"],
+            cpu_dense=a["cpu_dense"],
+            attr_bytes=np.ascontiguousarray(a["attr_bytes"][..., :6]),
+            byte_ovf=a["byte_ovf"], shard_of=a["shard_of"],
+            row_of=a["config_id"], host_fallback=np.zeros((4,), bool))
+        assert ShardedPolicyModel._encoded_h2d_bytes(None, enc) \
+            == 4 * (base + 2 * 6 + 2 + 4)
 
 
 # ---------------------------------------------------------------------------
@@ -402,28 +414,6 @@ class TestEntryPointAudit:
 
     def test_no_snapshot_is_empty(self):
         assert entry_points() == []
-
-    def test_auto_lane_decision_rides_the_audit_surface(self):
-        """ISSUE 18 satellite: the `--kernel-lane auto` resolution is
-        recorded on the kernel-dispatch entries of /debug/vars
-        kernel_cost.entry_points — as a FIELD, never a phantom entry
-        (the entry list and operand lanes above are a pinned surface)."""
-        from authorino_tpu.ops import pattern_eval as pe
-
-        pol = compile_corpus([self._cfg(
-            Pattern("m", Operator.EQ, "GET"))],
-            members_k=4, ovf_assist=False)
-        pe.auto_lane()  # resolve against this process's visible devices
-        ep = entry_points(policy=pol)
-        assert [e["entry"] for e in ep] == ["eval_bitpacked", "eval_fused",
-                                            "fused_kernel"]
-        dec = [e for e in ep if e["entry"] == "fused_kernel"][0][
-            "kernel_lane_auto"]
-        assert dec["requested"] == "auto"
-        assert dec["lane"] == pe.last_auto_decision()["lane"]
-        assert dec["devices"] >= 1 and dec["platforms"]
-        # eval-stage entries never carry it: auto arms the DISPATCH lane
-        assert "kernel_lane_auto" not in ep[0]
 
 
 # ---------------------------------------------------------------------------

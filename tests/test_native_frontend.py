@@ -1589,6 +1589,72 @@ def test_prewarm_covers_bucket_grid(stack):
     assert set(fe._bucket_grid(rec)) <= rec.warm
 
 
+def test_warm_time_lowering_failure_is_not_ready(stack, monkeypatch):
+    """A kernel that fails to lower/compile for a snapshot's warm grid is a
+    swap error visible from outside — wait_warm() False, warm_done never
+    true, the compiler's words on /debug/vars and a 503 /readyz — not only
+    a logged exception while the degrade path serves exact verdicts."""
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from authorino_tpu.ops import pattern_eval
+    from authorino_tpu.service.http_server import build_app
+
+    engine, fe, _, _ = stack
+    assert fe.wait_warm(180) and fe.warm_error is None
+
+    def refuse(*a, **k):
+        raise NotImplementedError("planted: Mosaic cannot lower this body")
+
+    def readyz():
+        async def go():
+            async with TestClient(TestServer(
+                    build_app(engine, frontend=fe))) as c:
+                r = await c.get("/readyz")
+                return r.status, await r.text()
+        return asyncio.run(go())
+
+    assert readyz()[0] == 200
+    try:
+        # the swap gate (largest shape, compiled before the swap goes live)
+        with monkeypatch.context() as m:
+            m.setattr(pattern_eval, "eval_bitpacked_jit", refuse)
+            fe.refresh()
+        assert fe.wait_warm(30) is False
+        snap = fe.debug_vars()["snapshot"]
+        assert snap["warm_done"] is False and snap["warm"] == []
+        assert "planted: Mosaic cannot lower" in snap["warm_error"]
+        status, body = readyz()
+        assert status == 503
+        assert "native kernel warm failed" in body and "planted" in body
+
+        # the background rest of the grid: the gate compiles, a later
+        # shape does not
+        real = pattern_eval.eval_bitpacked_jit
+        calls = []
+
+        def second_refuses(*a, **k):
+            calls.append(1)
+            if len(calls) > 1:
+                refuse()
+            return real(*a, **k)
+
+        with monkeypatch.context() as m:
+            m.setattr(pattern_eval, "eval_bitpacked_jit", second_refuses)
+            fe.refresh()
+            assert fe.wait_warm(60) is False
+        snap = fe.debug_vars()["snapshot"]
+        assert snap["warm_done"] is False and len(snap["warm"]) == 1
+        assert "planted" in snap["warm_error"]
+        assert readyz()[0] == 503
+    finally:
+        fe.refresh()  # a clean snapshot for the tests that follow
+    assert fe.wait_warm(180) and fe.warm_error is None
+    assert fe.debug_vars()["snapshot"]["warm_done"] is True
+    assert readyz()[0] == 200
+
+
 def test_swap_under_load_never_compiles_on_live_requests(stack):
     """Reconcile swaps with NEW corpus shapes must keep serving from
     warmed jit variants only: the previous snapshot serves until the new
