@@ -851,6 +851,11 @@ def _print_flight_bundle(bundle: dict) -> None:
     for comp, dv in (bundle.get("vars") or {}).items():
         if not isinstance(dv, dict):
             continue
+        if "batches" in dv and "fields" in dv:
+            # the native lane's ring of batch timelines (batch_stages.py)
+            print(f"  {comp}: the last {len(dv['batches'])} batch timelines "
+                  f"of {dv.get('committed')}")
+            continue
         breaker = (dv.get("breaker") or {}).get("state")
         adm = (dv.get("admission") or {}).get("state")
         gen = dv.get("generation", dv.get("snapshot"))

@@ -499,13 +499,15 @@ async def run_webhooks(args) -> None:
 async def run_server(args) -> None:
     from aiohttp import web
 
-    if args.jax_platform:
-        import jax
+    import jax
 
+    if args.jax_platform:
         jax.config.update("jax_platforms", args.jax_platform)
-    from .utils.jax_env import setup_jax
+    from .utils.jax_env import boot_stamp, setup_jax
 
     compile_cache_dir = setup_jax()
+    jax.devices()  # the backend comes up here, not under the first reconcile
+    boot_stamp("backend_s")
 
     from .controllers.reconciler import AuthConfigReconciler, SecretReconciler
     from .controllers.sources import YamlDirSource
@@ -813,6 +815,7 @@ async def run_server(args) -> None:
         # block serving until the first list lands (cache-sync semantics);
         # retries internally while the apiserver is unreachable
         await source.sync()
+        boot_stamp("reconcile_s")
         source.start()
         from .k8s.leader import leader_election_id
 
@@ -828,6 +831,7 @@ async def run_server(args) -> None:
     elif args.watch_dir:
         source = YamlDirSource(args.watch_dir, reconciler, cluster, secret_reconciler)
         await source.sync()
+        boot_stamp("reconcile_s")
         source.start()
         log.info("watching manifests under %s", args.watch_dir)
     else:
@@ -925,6 +929,7 @@ async def run_server(args) -> None:
                 None, native_fe.stop, 0.0)
             raise RuntimeError(f"native kernel warm grid failed: {err}")
         native_holder["warm"] = True
+        boot_stamp("warm_s")
         log.info("native jit grid warm")
     if native_fe is None:
         grpc_server = build_server(

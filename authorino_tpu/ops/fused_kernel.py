@@ -243,7 +243,9 @@ def _pallas_wrap(params, ops: dict, extra_flat=None, defuse_layout=None):
 def _fused_buf_jit(params, buf, layout):
     """ONE launch over the fused H2D staging buffer: operand decode, every
     lane, the circuit, and the bitpack in a single executable."""
-    return _pallas_wrap(params, {}, extra_flat=(buf,), defuse_layout=layout)
+    with jax.named_scope("fused_kernel"):
+        return _pallas_wrap(params, {}, extra_flat=(buf,),
+                            defuse_layout=layout)
 
 
 @jax.jit
@@ -260,7 +262,8 @@ def _fused_ops_jit(params, attrs_val, members_c, cpu_dense, config_id,
                     ("rel_rows", rel_rows), ("member_ovf", member_ovf)):
         if a is not None:
             ops[name] = a
-    return _pallas_wrap(params, ops)
+    with jax.named_scope("fused_kernel"):
+        return _pallas_wrap(params, ops)
 
 
 def eval_fused_kernel(params, db) -> "jax.Array":

@@ -420,12 +420,14 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
     const = params["leaf_const"].astype(f32)                 # [L]
 
     # ---- leaf selection: one-hot matmuls, exact in f32 -------------------
-    val = jnp.matmul(attrs_val.astype(f32), attr_oh, precision=_HIGH)  # [B, L]
-    eq = val == const[None, :]
-    memb = jnp.einsum(
-        "bmk,ml->bkl", members_c.astype(f32), mm["memb_onehot"], precision=_HIGH
-    )                                                        # [B, K, L]
-    incl = jnp.any(memb == const[None, None, :], axis=1)     # [B, L]
+    with jax.named_scope("leaf_compares"):
+        val = jnp.matmul(attrs_val.astype(f32), attr_oh, precision=_HIGH)  # [B, L]
+        eq = val == const[None, :]
+    with jax.named_scope("membership"):
+        memb = jnp.einsum(
+            "bmk,ml->bkl", members_c.astype(f32), mm["memb_onehot"], precision=_HIGH
+        )                                                        # [B, K, L]
+        incl = jnp.any(memb == const[None, None, :], axis=1)     # [B, L]
 
     # ---- dense CPU lane spread onto the leaf axis ------------------------
     cpu_lane = jnp.matmul(
@@ -433,47 +435,48 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
     ) > 0.5                                                  # [B, L]
 
     # ---- device regex lane: DFA scan, transitions as batched matmuls -----
-    if params["dfa_tables"] is not None and attr_bytes is not None:
-        tables = mm["dfa_tables_f"]                          # [R, S, 256] bf16
-        R, S = tables.shape[0], tables.shape[1]
-        # spread each row's attr bytes from its slot: [B, NB, LB] → [B, R, LB]
-        row_bytes = jnp.einsum(
-            "bnl,nr->brl", attr_bytes.astype(cdt), mm["slot_row_oh"],
-            preferred_element_type=f32,
-        )
-        iota_s = jnp.arange(S, dtype=f32)
-        iota_c = jnp.arange(256, dtype=f32)
-
-        def dfa_step(state, byte_col):  # state [B,R] f32; byte_col [B,R] f32
-            byte_oh = (byte_col[..., None] == iota_c).astype(cdt)   # [B,R,256]
-            # per-state next-state given this byte: [R,S,256] × [B,R,256]
-            nxt_by_state = jnp.einsum(
-                "rsc,brc->brs", tables, byte_oh, preferred_element_type=f32
+    with jax.named_scope("dfa_scan"):
+        if params["dfa_tables"] is not None and attr_bytes is not None:
+            tables = mm["dfa_tables_f"]                          # [R, S, 256] bf16
+            R, S = tables.shape[0], tables.shape[1]
+            # spread each row's attr bytes from its slot: [B, NB, LB] → [B, R, LB]
+            row_bytes = jnp.einsum(
+                "bnl,nr->brl", attr_bytes.astype(cdt), mm["slot_row_oh"],
+                preferred_element_type=f32,
             )
-            st_oh = (state[..., None] == iota_s).astype(f32)
-            nxt = jnp.sum(st_oh * nxt_by_state, axis=-1)
-            return nxt, None
+            iota_s = jnp.arange(S, dtype=f32)
+            iota_c = jnp.arange(256, dtype=f32)
 
-        # derive the scan's init carry from a varying input (zero-multiplied)
-        # so its manual-mesh "varying" type matches inside shard_map
-        init = row_bytes[:, :, 0] * 0.0
-        final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
-        final_oh = (final[..., None] == iota_s).astype(cdt)
-        dfa_row_res = jnp.einsum(
-            "brs,rs->br", final_oh, mm["dfa_accept_f"], preferred_element_type=f32
-        ) > 0.5                                              # [B, R]
-        leaf_dfa = jnp.einsum(
-            "br,rl->bl", dfa_row_res.astype(cdt), mm["row_leaf_oh"],
-            preferred_element_type=f32,
-        ) > 0.5
-        leaf_bovf = jnp.einsum(
-            "bn,nl->bl", byte_ovf.astype(cdt), mm["slot_leaf_oh"],
-            preferred_element_type=f32,
-        ) > 0.5
-        # overflowed values: exact answer precomputed into the CPU lane
-        dfa_leaf_val = jnp.where(leaf_bovf, cpu_lane, leaf_dfa)
-    else:
-        dfa_leaf_val = cpu_lane  # regexes ride the CPU lane entirely
+            def dfa_step(state, byte_col):  # state [B,R] f32; byte_col [B,R] f32
+                byte_oh = (byte_col[..., None] == iota_c).astype(cdt)   # [B,R,256]
+                # per-state next-state given this byte: [R,S,256] × [B,R,256]
+                nxt_by_state = jnp.einsum(
+                    "rsc,brc->brs", tables, byte_oh, preferred_element_type=f32
+                )
+                st_oh = (state[..., None] == iota_s).astype(f32)
+                nxt = jnp.sum(st_oh * nxt_by_state, axis=-1)
+                return nxt, None
+
+            # derive the scan's init carry from a varying input (zero-multiplied)
+            # so its manual-mesh "varying" type matches inside shard_map
+            init = row_bytes[:, :, 0] * 0.0
+            final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
+            final_oh = (final[..., None] == iota_s).astype(cdt)
+            dfa_row_res = jnp.einsum(
+                "brs,rs->br", final_oh, mm["dfa_accept_f"], preferred_element_type=f32
+            ) > 0.5                                              # [B, R]
+            leaf_dfa = jnp.einsum(
+                "br,rl->bl", dfa_row_res.astype(cdt), mm["row_leaf_oh"],
+                preferred_element_type=f32,
+            ) > 0.5
+            leaf_bovf = jnp.einsum(
+                "bn,nl->bl", byte_ovf.astype(cdt), mm["slot_leaf_oh"],
+                preferred_element_type=f32,
+            ) > 0.5
+            # overflowed values: exact answer precomputed into the CPU lane
+            dfa_leaf_val = jnp.where(leaf_bovf, cpu_lane, leaf_dfa)
+        else:
+            dfa_leaf_val = cpu_lane  # regexes ride the CPU lane entirely
 
     # ---- numeric lane: slot-wise int32 compares, mask-spread (no gather,
     # no f32 round-trip of the values — exactness by construction) --------
@@ -512,29 +515,30 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
             member_ovf.astype(cdt), mm["memb_onehot"].astype(cdt),
             preferred_element_type=f32) > 0.5                # [B, L]
 
-    res = _leaf_op_cascade(params["leaf_op"], eq, incl, dfa_leaf_val,
-                           cpu_lane, num_cmp, rel_res, leaf_movf)
+    with jax.named_scope("circuit"):
+        res = _leaf_op_cascade(params["leaf_op"], eq, incl, dfa_leaf_val,
+                               cpu_lane, num_cmp, rel_res, leaf_movf)
 
-    # ---- boolean circuit: per-level count matmuls ------------------------
-    true_col = jnp.ones((B, 1), dtype=bool)
-    false_col = jnp.zeros((B, 1), dtype=bool)
-    buffer = jnp.concatenate([true_col, false_col, res], axis=1)
-    for m, (children, is_and) in zip(mm["level_mats"], params["levels"]):
-        width = children.shape[1]  # static: the level's padded child count
-        counts = jnp.matmul(
-            buffer.astype(cdt), m.T, preferred_element_type=f32
-        )                                                    # [B, rows]
-        # And-padding children point at TRUE (count includes them); Or-padding
-        # at FALSE (adds 0) — so count==width ≡ all, count>0 ≡ any
-        node = jnp.where(is_and[None, :], counts >= width - 0.5, counts > 0.5)
-        buffer = jnp.concatenate([buffer, node], axis=1)
+        # ---- boolean circuit: per-level count matmuls ------------------------
+        true_col = jnp.ones((B, 1), dtype=bool)
+        false_col = jnp.zeros((B, 1), dtype=bool)
+        buffer = jnp.concatenate([true_col, false_col, res], axis=1)
+        for m, (children, is_and) in zip(mm["level_mats"], params["levels"]):
+            width = children.shape[1]  # static: the level's padded child count
+            counts = jnp.matmul(
+                buffer.astype(cdt), m.T, preferred_element_type=f32
+            )                                                    # [B, rows]
+            # And-padding children point at TRUE (count includes them); Or-padding
+            # at FALSE (adds 0) — so count==width ≡ all, count>0 ≡ any
+            node = jnp.where(is_and[None, :], counts >= width - 0.5, counts > 0.5)
+            buffer = jnp.concatenate([buffer, node], axis=1)
 
-    # ---- per-config rule/cond extraction: one-hot matmuls ----------------
-    buf16 = buffer.astype(cdt)
-    G, E = params["eval_rule"].shape
-    rule = (jnp.matmul(buf16, mm["rule_m"].T, preferred_element_type=f32) > 0.5)
-    cond = (jnp.matmul(buf16, mm["cond_m"].T, preferred_element_type=f32) > 0.5)
-    return _verdict_from_tables(params, cond.reshape(B, G, E), rule.reshape(B, G, E))
+        # ---- per-config rule/cond extraction: one-hot matmuls ----------------
+        buf16 = buffer.astype(cdt)
+        G, E = params["eval_rule"].shape
+        rule = (jnp.matmul(buf16, mm["rule_m"].T, preferred_element_type=f32) > 0.5)
+        cond = (jnp.matmul(buf16, mm["cond_m"].T, preferred_element_type=f32) > 0.5)
+        return _verdict_from_tables(params, cond.reshape(B, G, E), rule.reshape(B, G, E))
 
 
 # ---------------------------------------------------------------------------
@@ -552,35 +556,38 @@ def _eval_verdicts_gather(params, attrs_val, members_c, cpu_dense,
     B = attrs_val.shape[0]
 
     # ---- leaf evaluation -------------------------------------------------
-    val = jnp.take(attrs_val, leaf_attr, axis=1)            # [B, L]
-    eq = val == leaf_const[None, :]
-    memb = jnp.take(members_c, params["member_slot_of_leaf"], axis=1)  # [B, L, K]
-    incl = jnp.any(memb == leaf_const[None, :, None], axis=-1)
+    with jax.named_scope("leaf_compares"):
+        val = jnp.take(attrs_val, leaf_attr, axis=1)            # [B, L]
+        eq = val == leaf_const[None, :]
+    with jax.named_scope("membership"):
+        memb = jnp.take(members_c, params["member_slot_of_leaf"], axis=1)  # [B, L, K]
+        incl = jnp.any(memb == leaf_const[None, :, None], axis=-1)
 
     cpu_lane = _cpu_full(params, cpu_dense)                 # [B, L]
 
     # ---- device regex lane: DFA scan over value bytes --------------------
-    if params["dfa_tables"] is not None and attr_bytes is not None:
-        tables = params["dfa_tables"]          # [T, S, 256] uint8 (deduped)
-        # per-row table index: rows sharing an automaton share one table
-        tab_idx = params["dfa_table_of_row"][None, :]        # [1, R]
-        row_bytes = jnp.take(attr_bytes, params["dfa_byte_slot"], axis=1)  # [B, R, LB]
+    with jax.named_scope("dfa_scan"):
+        if params["dfa_tables"] is not None and attr_bytes is not None:
+            tables = params["dfa_tables"]          # [T, S, 256] uint8 (deduped)
+            # per-row table index: rows sharing an automaton share one table
+            tab_idx = params["dfa_table_of_row"][None, :]        # [1, R]
+            row_bytes = jnp.take(attr_bytes, params["dfa_byte_slot"], axis=1)  # [B, R, LB]
 
-        def dfa_step(states, byte_col):  # states [B,R] i32, byte_col [B,R] u8
-            nxt = tables[tab_idx, states, byte_col.astype(jnp.int32)]
-            return nxt.astype(jnp.int32), None
+            def dfa_step(states, byte_col):  # states [B,R] i32, byte_col [B,R] u8
+                nxt = tables[tab_idx, states, byte_col.astype(jnp.int32)]
+                return nxt.astype(jnp.int32), None
 
-        # init carry derived from a varying input (zero-multiplied) so its
-        # manual-mesh "varying" type matches inside shard_map
-        init = (row_bytes[:, :, 0] * 0).astype(jnp.int32)
-        final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
-        dfa_row_res = params["dfa_accept"][tab_idx, final]   # [B, R]
-        leaf_dfa = jnp.take(dfa_row_res, params["leaf_dfa_row"], axis=1)  # [B, L]
-        leaf_slot = jnp.take(params["dfa_byte_slot"], params["leaf_dfa_row"])
-        leaf_bovf = jnp.take(byte_ovf, leaf_slot, axis=1)    # [B, L]
-        dfa_leaf_val = jnp.where(leaf_bovf, cpu_lane, leaf_dfa)
-    else:
-        dfa_leaf_val = cpu_lane  # regexes ride the CPU lane entirely
+            # init carry derived from a varying input (zero-multiplied) so its
+            # manual-mesh "varying" type matches inside shard_map
+            init = (row_bytes[:, :, 0] * 0).astype(jnp.int32)
+            final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
+            dfa_row_res = params["dfa_accept"][tab_idx, final]   # [B, R]
+            leaf_dfa = jnp.take(dfa_row_res, params["leaf_dfa_row"], axis=1)  # [B, L]
+            leaf_slot = jnp.take(params["dfa_byte_slot"], params["leaf_dfa_row"])
+            leaf_bovf = jnp.take(byte_ovf, leaf_slot, axis=1)    # [B, L]
+            dfa_leaf_val = jnp.where(leaf_bovf, cpu_lane, leaf_dfa)
+        else:
+            dfa_leaf_val = cpu_lane  # regexes ride the CPU lane entirely
 
     # ---- numeric lane: gather each leaf's slot value, compare int32 ------
     num_cmp = None
@@ -606,26 +613,27 @@ def _eval_verdicts_gather(params, attrs_val, members_c, cpu_dense,
         leaf_movf = jnp.take(member_ovf, params["member_slot_of_leaf"],
                              axis=1)                                  # [B, L]
 
-    res = _leaf_op_cascade(leaf_op, eq, incl, dfa_leaf_val, cpu_lane,
-                           num_cmp, rel_res, leaf_movf)
+    with jax.named_scope("circuit"):
+        res = _leaf_op_cascade(leaf_op, eq, incl, dfa_leaf_val, cpu_lane,
+                               num_cmp, rel_res, leaf_movf)
 
-    # ---- boolean-circuit reduction, level by level -----------------------
-    true_col = jnp.ones((B, 1), dtype=bool)
-    false_col = jnp.zeros((B, 1), dtype=bool)
-    buffer = jnp.concatenate([true_col, false_col, res], axis=1)
-    for children, is_and in params["levels"]:
-        ch = jnp.take(buffer, children.reshape(-1), axis=1)
-        ch = ch.reshape(B, children.shape[0], children.shape[1])
-        node = jnp.where(is_and[None, :], jnp.all(ch, axis=-1), jnp.any(ch, axis=-1))
-        buffer = jnp.concatenate([buffer, node], axis=1)
+        # ---- boolean-circuit reduction, level by level -----------------------
+        true_col = jnp.ones((B, 1), dtype=bool)
+        false_col = jnp.zeros((B, 1), dtype=bool)
+        buffer = jnp.concatenate([true_col, false_col, res], axis=1)
+        for children, is_and in params["levels"]:
+            ch = jnp.take(buffer, children.reshape(-1), axis=1)
+            ch = ch.reshape(B, children.shape[0], children.shape[1])
+            node = jnp.where(is_and[None, :], jnp.all(ch, axis=-1), jnp.any(ch, axis=-1))
+            buffer = jnp.concatenate([buffer, node], axis=1)
 
-    # ---- per-config verdicts ---------------------------------------------
-    cond = jnp.take(buffer, params["eval_cond"].reshape(-1), axis=1)
-    rule = jnp.take(buffer, params["eval_rule"].reshape(-1), axis=1)
-    G, E = params["eval_rule"].shape
-    return _verdict_from_tables(
-        params, cond.reshape(B, G, E), rule.reshape(B, G, E)
-    )
+        # ---- per-config verdicts ---------------------------------------------
+        cond = jnp.take(buffer, params["eval_cond"].reshape(-1), axis=1)
+        rule = jnp.take(buffer, params["eval_rule"].reshape(-1), axis=1)
+        G, E = params["eval_rule"].shape
+        return _verdict_from_tables(
+            params, cond.reshape(B, G, E), rule.reshape(B, G, E)
+        )
 
 
 def eval_verdicts(
@@ -791,10 +799,16 @@ def eval_bitpacked_jit(params, attrs_val, members_c, cpu_dense, config_id,
                        attr_bytes=None, byte_ovf=None, attrs_num=None,
                        num_valid=None, rel_rows=None, member_ovf=None):
     """eval_packed_jit with the result bit-packed on device: the D2H
-    readback is [B, ceil((1+2E)/8)] uint8 instead of [B, 1+2E] bool."""
-    return _bitpack_rows(eval_packed_jit(
-        params, attrs_val, members_c, cpu_dense, config_id,
-        attr_bytes, byte_ovf, attrs_num, num_valid, rel_rows, member_ovf))
+    readback is [B, ceil((1+2E)/8)] uint8 instead of [B, 1+2E] bool.
+    The phases carry named scopes (leaf_compares, membership, dfa_scan,
+    circuit, bitpack under pattern_eval), so a device trace can be grouped
+    by phase whatever the compiler calls its fusions."""
+    with jax.named_scope("pattern_eval"):
+        packed = eval_packed_jit(
+            params, attrs_val, members_c, cpu_dense, config_id,
+            attr_bytes, byte_ovf, attrs_num, num_valid, rel_rows, member_ovf)
+        with jax.named_scope("bitpack"):
+            return _bitpack_rows(packed)
 
 
 def _extra_operands(db) -> tuple:

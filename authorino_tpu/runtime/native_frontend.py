@@ -72,6 +72,7 @@ from ..utils.rpc import (
 from . import faults
 from . import provenance as prov_mod
 from .admission import AdmissionController
+from .batch_stages import StageClock
 from .breaker import CircuitBreaker
 from .flight_recorder import RECORDER
 from . import kernel_cost as kernel_cost_mod
@@ -820,6 +821,12 @@ class NativeFrontend:
         # (docs/tenancy.md names the fast-lane caveat).
         self.tenancy = getattr(engine, "tenancy", None)
         RECORDER.register_provider("native_frontend", self, "debug_vars")
+        # the batch stage clock (runtime/batch_stages.py): always on; its
+        # ring of batch timelines rides every flight bundle, so a dump on
+        # admission-overloaded or watchdog-timeout holds what led to it
+        self.batch_stages = StageClock("native")
+        RECORDER.register_provider("native_batches", self.batch_stages,
+                                   "to_json")
 
     # ------------------------------------------------------------------
     def start(self) -> int:
@@ -999,6 +1006,9 @@ class NativeFrontend:
             "window_us": self.window_us,
             "slots": self.slots,
             "dispatch_threads": self.dispatch_threads,
+            # {stage: {count, sum_ns, max_ns}}, cumulative: a reader takes
+            # the difference of two scrapes (runtime/batch_stages.py)
+            "stages": self.batch_stages.totals(),
             "inflight_batches": self._rb_inflight,
             "inflight_peak": self.rb_inflight_peak,
             "trace_sample_n": self.trace_sample_n,
@@ -2043,11 +2053,12 @@ class NativeFrontend:
     def _dispatch_loop(self) -> None:
         mod = self._mod
         while self._running:
-            kind, a, b, c = mod.fe_wait_batch(200)
+            kind, a, b, c, flush_ns = mod.fe_wait_batch(200)
             self._fold_fc_counts()
             if kind == EV_BATCH:
                 try:
-                    self._dispatch(int(a), int(b), int(c))
+                    self._dispatch(int(a), int(b), int(c),
+                                   flush_ns=int(flush_ns))
                 except Exception as e:
                     log.exception("native batch dispatch failed")
                     # retry once, then degrade (CPU-backend kernel) — fail
@@ -2134,7 +2145,8 @@ class NativeFrontend:
         return int(per)
 
     def _dispatch(self, snap_id: int, slot: int, count: int,
-                  attempt: int = 0, spill: bool = True) -> None:
+                  attempt: int = 0, spill: bool = True,
+                  flush_ns: int = 0) -> None:
         """Launch stage: non-blocking kernel dispatch for one C++-encoded
         slot, then park the in-flight batch on the readback queue.  The
         dispatcher thread is immediately free to launch the next slot, so
@@ -2150,84 +2162,91 @@ class NativeFrontend:
 
         ``attempt`` is the retry generation (0 = first dispatch, 1 = the
         one retry after a device failure); an OPEN circuit breaker skips
-        the device entirely and decides the slot on the CPU backend."""
+        the device entirely and decides the slot on the CPU backend.
+        ``flush_ns`` is when the C++ front end cut the slot (0 on a retry):
+        the start of the batch's stage clock (runtime/batch_stages.py)."""
         import jax.numpy as jnp
 
         from ..ops.pattern_eval import eval_bitpacked_jit
 
         rec = self._snaps[snap_id]
-        allowed, probe = self.breaker.admit_device()
-        if not allowed:
-            self._degrade_slot(rec, snap_id, slot, count)
-            return
-        # a claimed half-open PROBE must reach the device: routing it
-        # host-side (lane choice or brownout) would strand _probe_inflight
-        # forever — no breaker verdict ever lands, every later slot skips
-        # the device, and a transiently-sick device becomes a permanent
-        # host-only degrade.  (The engine lane turns probes into
-        # speculative dual-dispatch instead; this lane has no first-wins
-        # seam, so the probe simply rides the device alone.)
-        if (spill and not probe and self.lanes.enabled
-                and rec.sharded is None and rec.policy is not None
-                and count <= self.lanes.host_max_rows):
-            # slot-level lane choice (ISSUE 12): a small gathered slot the
-            # cost model says the CPU-backend twin answers FASTER than a
-            # device round trip rides the host lane — light-load latency
-            # stops paying the H2D/D2H trip.  Same worker-thread + live-
-            # counter discipline as brownout (stop() waits these out), but
-            # its own trigger and counters: this is a latency choice, not
-            # an overload spill.
-            which, why = self.lanes.decide(count, self._rb_inflight,
-                                           self.slots)
-            if which == L_HOST:
-                taken = False
+        bt = self.batch_stages.begin(snap_id, slot, count, flush_ns)
+        with bt.stage("plan"):
+            allowed, probe = self.breaker.admit_device()
+            if not allowed:
+                self._degrade_slot(rec, snap_id, slot, count)
+                return
+            # a claimed half-open PROBE must reach the device: routing it
+            # host-side (lane choice or brownout) would strand
+            # _probe_inflight forever — no breaker verdict ever lands, every
+            # later slot skips the device, and a transiently-sick device
+            # becomes a permanent host-only degrade.  (The engine lane turns
+            # probes into speculative dual-dispatch instead; this lane has
+            # no first-wins seam, so the probe simply rides the device
+            # alone.)
+            if (spill and not probe and self.lanes.enabled
+                    and rec.sharded is None and rec.policy is not None
+                    and count <= self.lanes.host_max_rows):
+                # slot-level lane choice (ISSUE 12): a small gathered slot
+                # the cost model says the CPU-backend twin answers FASTER
+                # than a device round trip rides the host lane — light-load
+                # latency stops paying the H2D/D2H trip.  Same worker-thread
+                # + live-counter discipline as brownout (stop() waits these
+                # out), but its own trigger and counters: this is a latency
+                # choice, not an overload spill.
+                which, why = self.lanes.decide(count, self._rb_inflight,
+                                               self.slots)
+                if which == L_HOST:
+                    taken = False
+                    with self._rb_lock:
+                        if self.lanes.host_inflight < self.lanes.host_limit:
+                            self.lanes.host_inflight += 1
+                            self._brownout_live += 1
+                            taken = True
+                    if taken:
+                        self.lanes.count(L_HOST, why)
+                        self._host_pool.submit(self._brownout_slot, rec,
+                                               snap_id, slot, count, why=why)
+                        return
+                    # a concurrent host worker filled the cap between
+                    # decide() and the under-lock re-check: the slot rides
+                    # the device — record THAT, or dispatched slots stop
+                    # summing up
+                    which, why = L_DEVICE, "host-busy"
+                self.lanes.count(L_DEVICE, why)
+            if (spill and not probe and self.brownout
+                    and count <= self.brownout_max_rows
+                    and self._rb_inflight >= self._brownout_threshold
+                    and rec.sharded is None and rec.policy is not None):
+                # device pipeline saturated (nearly every slot in flight)
+                # and this batch is small: answer it on the CPU-backend
+                # kernel instead of queueing it behind a full window — exact
+                # verdicts, bounded latency (brownout, docs/robustness.md).
+                # On its OWN worker thread: the first CPU eval of a new
+                # (pad, eff) shape jit-compiles, and that must never stall a
+                # dispatcher thread mid-saturation (mirrors _fail_async — at
+                # most one live thread per C++ slot, since a slot cannot
+                # re-fire until fe_complete_batch refills it).  Counted in
+                # _brownout_live so stop()'s drain waits the spill out
+                # before fe_stop.
                 with self._rb_lock:
-                    if self.lanes.host_inflight < self.lanes.host_limit:
-                        self.lanes.host_inflight += 1
-                        self._brownout_live += 1
-                        taken = True
-                if taken:
-                    self.lanes.count(L_HOST, why)
-                    self._host_pool.submit(self._brownout_slot, rec,
-                                           snap_id, slot, count, why=why)
-                    return
-                # a concurrent host worker filled the cap between decide()
-                # and the under-lock re-check: the slot rides the device —
-                # record THAT, or dispatched slots stop summing up
-                which, why = L_DEVICE, "host-busy"
-            self.lanes.count(L_DEVICE, why)
-        if (spill and not probe and self.brownout
-                and count <= self.brownout_max_rows
-                and self._rb_inflight >= self._brownout_threshold
-                and rec.sharded is None and rec.policy is not None):
-            # device pipeline saturated (nearly every slot in flight) and
-            # this batch is small: answer it on the CPU-backend kernel
-            # instead of queueing it behind a full window — exact verdicts,
-            # bounded latency (brownout, docs/robustness.md).  On its OWN
-            # worker thread: the first CPU eval of a new (pad, eff) shape
-            # jit-compiles, and that must never stall a dispatcher thread
-            # mid-saturation (mirrors _fail_async — at most one live
-            # thread per C++ slot, since a slot cannot re-fire until
-            # fe_complete_batch refills it).  Counted in _brownout_live so
-            # stop()'s drain waits the spill out before fe_stop.
-            with self._rb_lock:
-                self._brownout_live += 1
-            threading.Thread(target=self._brownout_slot,
-                             args=(rec, snap_id, slot, count),
-                             name="atpu-fe-brownout", daemon=True).start()
-            return
-        a = rec.arrays[slot]
-        # copy attribution rows BEFORE the slot can complete: once
-        # fe_complete_batch runs, the C++ encoder may refill them
-        rows = a["config_id"][:count].copy()
-        shards_arr = (a["shard_of"][:count].copy()
-                      if rec.sharded is not None else None)
-        fan = self._dedup_plan(rec, a, count, rows, shards_arr)
-        if fan is not None:
-            unique_rows = fan[4]
-            u = len(unique_rows)
-        else:
-            unique_rows, u = list(range(count)), count
+                    self._brownout_live += 1
+                threading.Thread(target=self._brownout_slot,
+                                 args=(rec, snap_id, slot, count),
+                                 name="atpu-fe-brownout", daemon=True).start()
+                return
+            a = rec.arrays[slot]
+            # copy attribution rows BEFORE the slot can complete: once
+            # fe_complete_batch runs, the C++ encoder may refill them
+            rows = a["config_id"][:count].copy()
+            shards_arr = (a["shard_of"][:count].copy()
+                          if rec.sharded is not None else None)
+            fan = self._dedup_plan(rec, a, count, rows, shards_arr)
+            if fan is not None:
+                unique_rows = fan[4]
+                u = len(unique_rows)
+            else:
+                unique_rows, u = list(range(count)), count
 
         def sel(name):
             """Unique-row operand view: the slot arrays sliced [:pad] when
@@ -2259,118 +2278,118 @@ class NativeFrontend:
                 dedup_avoided_rows=(len(fan[3]) if fan is not None else 0),
                 cache_avoided_rows=(len(fan[2]) if fan is not None else 0))
         else:
-            eff_need = (_trim_bytes(a["attr_bytes"][:count] if u == count
-                                    else a["attr_bytes"][unique_rows]
-                                    ).shape[-1]
-                        if has_dfa else 0)
-            eff = eff_need
-            # round the batch/byte buckets up to an already-compiled variant
-            # so XLA compiles never land on live requests (rows past the
-            # unique count carry stale/repeated operands; results discarded)
-            pad, eff = self._pick_warm_shape(rec, u, eff)
-            idx = (np.asarray(unique_rows + [unique_rows[0]] * (pad - u))
-                   if u != count else None)
-            t0 = time.monotonic()
-            t0_ns = time.time_ns()
-            if faults.ACTIVE:
-                faults.FAULTS.check("h2d", "native")
-                faults.FAULTS.check("kernel", "native")
-            if rec.sharded is not None:
-                from ..parallel.sharded_eval import _ShardedEncoded
+            with bt.stage("encode"):
+                eff_need = (_trim_bytes(a["attr_bytes"][:count] if u == count
+                                        else a["attr_bytes"][unique_rows]
+                                        ).shape[-1]
+                            if has_dfa else 0)
+                eff = eff_need
+                # round the batch/byte buckets up to an already-compiled
+                # variant so XLA compiles never land on live requests (rows
+                # past the unique count carry stale/repeated operands;
+                # results discarded)
+                pad, eff = self._pick_warm_shape(rec, u, eff)
+                idx = (np.asarray(unique_rows + [unique_rows[0]] * (pad - u))
+                       if u != count else None)
+                t0 = time.monotonic()
+                t0_ns = time.time_ns()
+                if faults.ACTIVE:
+                    faults.FAULTS.check("h2d", "native")
+                    faults.FAULTS.check("kernel", "native")
+                if rec.sharded is not None:
+                    from ..parallel.sharded_eval import _ShardedEncoded
 
-                # dispatch_full owns the step's operand list, the mesh
-                # ledger launch (+ exact operand bytes) and the per-device
-                # launch counts
-                packed = sh.dispatch_full(_ShardedEncoded(
-                    attrs_val=sel("attrs_val"),
-                    members_c=sel("members"),
-                    cpu_dense=sel("cpu_dense").view(bool),
-                    attr_bytes=np.ascontiguousarray(
-                        sel("attr_bytes")[..., :eff]) if has_dfa else None,
-                    byte_ovf=sel("byte_ovf").view(bool) if has_dfa else None,
-                    shard_of=sel("shard_of"),
-                    row_of=sel("config_id"),
-                    host_fallback=np.zeros((pad,), dtype=bool)))
-            elif rec.params.get("fused") is not None:
-                # fused lane (ISSUE 17): the ONE-launch mega-kernel entry
-                # (operands are already separate arrays here, so the
-                # per-operand variant stages them; compute + in-kernel
-                # bitpack are a single executable either way)
-                from ..ops import fused_kernel as fused_mod
+                    operands = _ShardedEncoded(
+                        attrs_val=sel("attrs_val"),
+                        members_c=sel("members"),
+                        cpu_dense=sel("cpu_dense").view(bool),
+                        attr_bytes=np.ascontiguousarray(
+                            sel("attr_bytes")[..., :eff]) if has_dfa else None,
+                        byte_ovf=(sel("byte_ovf").view(bool)
+                                  if has_dfa else None),
+                        shard_of=sel("shard_of"),
+                        row_of=sel("config_id"),
+                        host_fallback=np.zeros((pad,), dtype=bool))
+                else:
+                    # single corpus, either kernel lane: the same six
+                    # operands, handed to the runtime here so that `launch`
+                    # times the jitted call alone
+                    operands = (
+                        jnp.asarray(sel("attrs_val")),
+                        jnp.asarray(sel("members")),
+                        jnp.asarray(sel("cpu_dense").view(bool)),
+                        jnp.asarray(sel("config_id")),
+                        jnp.asarray(np.ascontiguousarray(
+                            sel("attr_bytes")[..., :eff]))
+                        if has_dfa else None,
+                        jnp.asarray(sel("byte_ovf").view(bool))
+                        if has_dfa else None,
+                    )
+            with bt.stage("launch"):
+                if rec.sharded is not None:
+                    # dispatch_full owns the step's operand list, the mesh
+                    # ledger launch (+ exact operand bytes) and the
+                    # per-device launch counts
+                    packed = sh.dispatch_full(operands)
+                elif rec.params.get("fused") is not None:
+                    # fused lane (ISSUE 17): the ONE-launch mega-kernel
+                    # entry (operands are already separate arrays here, so
+                    # the per-operand variant stages them; compute +
+                    # in-kernel bitpack are a single executable either way)
+                    from ..ops import fused_kernel as fused_mod
 
-                packed = fused_mod._fused_ops_jit(
-                    rec.params,
-                    jnp.asarray(sel("attrs_val")),
-                    jnp.asarray(sel("members")),
-                    jnp.asarray(sel("cpu_dense").view(bool)),
-                    jnp.asarray(sel("config_id")),
-                    jnp.asarray(np.ascontiguousarray(
-                        sel("attr_bytes")[..., :eff]))
-                    if has_dfa else None,
-                    jnp.asarray(sel("byte_ovf").view(bool))
-                    if has_dfa else None,
-                    None, None, None, None,
-                )
-            else:
-                packed = eval_bitpacked_jit(
-                    rec.params,
-                    jnp.asarray(sel("attrs_val")),
-                    jnp.asarray(sel("members")),
-                    jnp.asarray(sel("cpu_dense").view(bool)),
-                    jnp.asarray(sel("config_id")),
-                    jnp.asarray(np.ascontiguousarray(
-                        sel("attr_bytes")[..., :eff]))
-                    if has_dfa else None,
-                    jnp.asarray(sel("byte_ovf").view(bool))
-                    if has_dfa else None,
-                )
-            if faults.ACTIVE:
-                packed = faults.FAULTS.wrap_handle(packed, "native")
-            if rec.sharded is None:
+                    packed = fused_mod._fused_ops_jit(
+                        rec.params, *operands, None, None, None, None)
+                else:
+                    packed = eval_bitpacked_jit(rec.params, *operands)
+                if faults.ACTIVE:
+                    packed = faults.FAULTS.wrap_handle(packed, "native")
+                if rec.sharded is None:
+                    try:
+                        from ..ops.pattern_eval import kernel_lane_of
+
+                        metrics_mod.observe_kernel_lane(
+                            kernel_lane_of(rec.params))
+                    except Exception:
+                        pass  # metrics are advisory
                 try:
-                    from ..ops.pattern_eval import kernel_lane_of
-
-                    metrics_mod.observe_kernel_lane(
-                        kernel_lane_of(rec.params))
+                    packed.copy_to_host_async()
                 except Exception:
-                    pass  # metrics are advisory
-            try:
-                packed.copy_to_host_async()
-            except Exception:
-                pass
-            # structural cost fold (ISSUE 16): ONE launch per slot, the
-            # exact H2D operand bytes this (pad, eff) variant staged and
-            # the bitpacked [pad, W] readback.  eff-column slack is the
-            # warm-shape round-up (eff - eff_need); sharded slots fold the
-            # batch here, their collective launch and its bytes were
-            # counted on the mesh lane by dispatch_full
-            if rec.sharded is not None:
-                LEDGER.observe(
-                    "mesh", rows=count, device_rows=u, pad_rows=pad,
-                    eff_slack_cols=eff - eff_need,
-                    dedup_avoided_rows=(len(fan[3]) - u
-                                        if fan is not None else 0),
-                    cache_avoided_rows=(len(fan[2])
-                                        if fan is not None else 0))
-            else:
-                LEDGER.observe(
-                    "native", rows=count, device_rows=u, launches=1,
-                    h2d_bytes=pad * self._row_h2d_bytes(a, eff, has_dfa),
-                    d2h_bytes=int(packed.shape[0]) * int(packed.shape[1]),
-                    pad_rows=pad,
-                    eff_slack_cols=eff - eff_need,
-                    dedup_avoided_rows=(len(fan[3]) - u
-                                        if fan is not None else 0),
-                    cache_avoided_rows=(len(fan[2])
-                                        if fan is not None else 0))
+                    pass
+                # structural cost fold (ISSUE 16): ONE launch per slot, the
+                # exact H2D operand bytes this (pad, eff) variant staged and
+                # the bitpacked [pad, W] readback.  eff-column slack is the
+                # warm-shape round-up (eff - eff_need); sharded slots fold
+                # the batch here, their collective launch and its bytes were
+                # counted on the mesh lane by dispatch_full
+                if rec.sharded is not None:
+                    LEDGER.observe(
+                        "mesh", rows=count, device_rows=u, pad_rows=pad,
+                        eff_slack_cols=eff - eff_need,
+                        dedup_avoided_rows=(len(fan[3]) - u
+                                            if fan is not None else 0),
+                        cache_avoided_rows=(len(fan[2])
+                                            if fan is not None else 0))
+                else:
+                    LEDGER.observe(
+                        "native", rows=count, device_rows=u, launches=1,
+                        h2d_bytes=pad * self._row_h2d_bytes(a, eff, has_dfa),
+                        d2h_bytes=int(packed.shape[0]) * int(packed.shape[1]),
+                        pad_rows=pad,
+                        eff_slack_cols=eff - eff_need,
+                        dedup_avoided_rows=(len(fan[3]) - u
+                                            if fan is not None else 0),
+                        cache_avoided_rows=(len(fan[2])
+                                            if fan is not None else 0))
         with self._rb_lock:
             self._rb_inflight += 1
             if self._rb_inflight > self.rb_inflight_peak:
                 self.rb_inflight_peak = self._rb_inflight
             inflight = self._rb_inflight
         self._g_native_inflight.set(inflight)
+        bt.device_rows, bt.pad, bt.eff, bt.inflight = u, pad, eff, inflight
         self._rb_q.append((rec, snap_id, slot, count, pad, eff, rows,
-                           shards_arr, packed, t0, t0_ns, fan, attempt))
+                           shards_arr, packed, t0, t0_ns, fan, attempt, bt))
         self._rb_evt.set()
 
     def _brownout_slot(self, rec: _SnapRec, snap_id: int, slot: int,
@@ -2490,6 +2509,7 @@ class NativeFrontend:
                     continue
                 pending.remove(item)
                 progressed = True
+                item[13].ready()
                 try:
                     self._complete_device_batch(*item)
                 except Exception as e:
@@ -2517,93 +2537,97 @@ class NativeFrontend:
                                rows: np.ndarray,
                                shards_arr: Optional[np.ndarray],
                                packed, t0: float, t0_ns: int,
-                               fan=None, attempt: int = 0) -> None:
+                               fan, attempt: int, bt) -> None:
         if self._fe_stopped:
             # stop()'s drain deadline expired with this batch still on the
             # wire and fe_stop has run: completing into the torn-down C++
             # server would be a native use-after-stop
             return
-        if faults.ACTIVE:
-            faults.FAULTS.check("readback", "native")
-        packed = np.asarray(packed)
-        if pad:
-            # the device answered (cache-only batches with pad == 0 never
-            # touched it): clear the breaker's consecutive-failure count
-            self.breaker.record_success()
-        else:
-            # a cache-only batch proves nothing about the device — just
-            # release a half-open probe slot it may have claimed
-            self.breaker.release_probe()
-        dispatch_s = time.monotonic() - t0
-        # attribution (ISSUE 9): the packed readback already carries the
-        # per-rule result/skip columns — ONE vectorized unpack per batch
-        # recovers the firing column next to the verdict bit (zero
-        # per-request Python, pinned by tests/test_provenance.py)
-        from ..ops.pattern_eval import unpack_attribution
-
-        heat = rec.heat
-        E = heat.E if heat is not None else 0
-        if fan is None:
-            # dedup/cache off: packed is the bit-masked result of the full
-            # slot; own verdict = bit 0 of byte 0
-            if E:
-                verdict, firing = unpack_attribution(packed[:count], E)
-                verdict = np.ascontiguousarray(verdict)
+        with bt.stage("resolve"):
+            if faults.ACTIVE:
+                faults.FAULTS.check("readback", "native")
+            packed = np.asarray(packed)
+            if pad:
+                # the device answered (cache-only batches with pad == 0
+                # never touched it): clear the breaker's consecutive-failure
+                # count
+                self.breaker.record_success()
             else:
-                verdict = np.ascontiguousarray(
-                    packed[:count, 0] & 1).astype(np.uint8)
-                firing = None
-            u = count
-            cached_n = elig_miss_n = evict_d = 0
-        else:
-            keys, eligible, cached, miss_rows, unique_rows, inverse, \
-                elig_miss_n = fan
-            u = len(unique_rows)
-            verdict = np.zeros((count,), dtype=np.uint8)
-            firing = np.full((count,), -1, dtype=np.int32) if E else None
-            if u:
+                # a cache-only batch proves nothing about the device — just
+                # release a half-open probe slot it may have claimed
+                self.breaker.release_probe()
+            dispatch_s = time.monotonic() - t0
+            # attribution (ISSUE 9): the packed readback already carries the
+            # per-rule result/skip columns — ONE vectorized unpack per batch
+            # recovers the firing column next to the verdict bit (zero
+            # per-request Python, pinned by tests/test_provenance.py)
+            from ..ops.pattern_eval import unpack_attribution
+
+            heat = rec.heat
+            E = heat.E if heat is not None else 0
+            if fan is None:
+                # dedup/cache off: packed is the bit-masked result of the
+                # full slot; own verdict = bit 0 of byte 0
                 if E:
-                    uniq_v, uniq_f = unpack_attribution(packed[:u], E)
+                    verdict, firing = unpack_attribution(packed[:count], E)
+                    verdict = np.ascontiguousarray(verdict)
                 else:
-                    uniq_v = (packed[:, 0] & 1).astype(np.uint8)
-                    uniq_f = None
-                mr = np.asarray(miss_rows)
-                verdict[mr] = uniq_v[inverse]
-                if firing is not None and uniq_f is not None:
-                    firing[mr] = uniq_f[inverse]
-            for r, v in cached.items():
-                # cached value = (verdict, firing): a cache hit attributes
-                # identically to the device evaluation it memoized
-                verdict[r] = v[0]
-                if firing is not None:
-                    firing[r] = v[1]
-            verdict = np.ascontiguousarray(verdict)
-            cached_n = len(cached)
-            evict_d = 0
-        self._mod.fe_complete_batch(snap_id, slot, verdict.ctypes.data)
+                    verdict = np.ascontiguousarray(
+                        packed[:count, 0] & 1).astype(np.uint8)
+                    firing = None
+                u = count
+                cached_n = elig_miss_n = 0
+            else:
+                keys, eligible, cached, miss_rows, unique_rows, inverse, \
+                    elig_miss_n = fan
+                u = len(unique_rows)
+                verdict = np.zeros((count,), dtype=np.uint8)
+                firing = np.full((count,), -1, dtype=np.int32) if E else None
+                if u:
+                    if E:
+                        uniq_v, uniq_f = unpack_attribution(packed[:u], E)
+                    else:
+                        uniq_v = (packed[:, 0] & 1).astype(np.uint8)
+                        uniq_f = None
+                    mr = np.asarray(miss_rows)
+                    verdict[mr] = uniq_v[inverse]
+                    if firing is not None and uniq_f is not None:
+                        firing[mr] = uniq_f[inverse]
+                for r, v in cached.items():
+                    # cached value = (verdict, firing): a cache hit
+                    # attributes identically to the device evaluation it
+                    # memoized
+                    verdict[r] = v[0]
+                    if firing is not None:
+                        firing[r] = v[1]
+                verdict = np.ascontiguousarray(verdict)
+                cached_n = len(cached)
+            self._mod.fe_complete_batch(snap_id, slot, verdict.ctypes.data)
         # the slot is COMPLETED from here on: an exception below must not
         # propagate to the readback loop's fail-closed deny, which would
         # fe_complete_batch the same slot twice — by then possibly refilled
         # with a fresh live batch
         try:
-            cache = self._verdict_cache
-            if fan is not None and cache is not None:
-                evict0 = cache.evictions
-                for r in fan[4]:  # unique rows: freshly evaluated
-                    if fan[1][r]:
-                        # fan[0] carries the FULL cache key (per-config
-                        # token or snap_id already folded in — captured
-                        # from the batch's pinned snapshot at dispatch)
-                        cache.put(fan[0][r], (
-                            int(verdict[r]),
-                            int(firing[r]) if firing is not None else -1))
-                evict_d = cache.evictions - evict0
-            metrics_mod.observe_dedup("native", count, u, cached_n,
-                                      elig_miss_n, evict_d)
-            self._post_complete_telemetry(rec, count, pad, eff, rows,
-                                          shards_arr, verdict, dispatch_s,
-                                          t0_ns, device_rows=u,
-                                          firing=firing)
+            with bt.stage("post"):
+                evict_d = 0
+                cache = self._verdict_cache
+                if fan is not None and cache is not None:
+                    evict0 = cache.evictions
+                    for r in fan[4]:  # unique rows: freshly evaluated
+                        if fan[1][r]:
+                            # fan[0] carries the FULL cache key (per-config
+                            # token or snap_id already folded in — captured
+                            # from the batch's pinned snapshot at dispatch)
+                            cache.put(fan[0][r], (
+                                int(verdict[r]),
+                                int(firing[r]) if firing is not None else -1))
+                    evict_d = cache.evictions - evict0
+                metrics_mod.observe_dedup("native", count, u, cached_n,
+                                          elig_miss_n, evict_d)
+                self._post_complete_telemetry(rec, count, pad, eff, rows,
+                                              shards_arr, verdict, dispatch_s,
+                                              t0_ns, device_rows=u,
+                                              firing=firing)
         except Exception:
             log.exception("post-completion telemetry failed")
 
@@ -2830,7 +2854,6 @@ class NativeFrontend:
             self.lanes.count_rows(L_DEVICE, count)
             metrics_mod.observe_batch("native", count, pad, None, dispatch_s,
                                       device_rows=device_rows)
-            metrics_mod.observe_pipeline_stage("native", "device", dispatch_s)
         if device and tracing_mod.tracing_active():
             # fast-lane requests have no Python spans to link (only sampled
             # slow-lane ones do) — the DeviceBatch span still carries the

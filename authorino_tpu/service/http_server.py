@@ -258,10 +258,15 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
         data = {
             "engine": engine.debug_vars(),
             # what the kernels run on: platform, device kind + count,
-            # library versions, compile-cache placement and traffic
+            # library versions, compile-cache placement and traffic, the
+            # boot stamps and each device's memory; `time_ns` places the
+            # reading on the clock a running capture's `started_unix_ns` is on
             "process": {"pid": os.getpid(), "time": _time.time(),
+                        "time_ns": _time.time_ns(),
                         **jax_process_info()},
         }
+        if profile_state["capture"] is not None:
+            data["process"]["profile"] = profile_state["capture"]
         fe = _frontend()
         if fe is not None:
             try:
@@ -364,12 +369,32 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
             "change_safety": engine.change_safety_vars(),
         })
 
-    profile_state = {"busy": False}
+    async def debug_batches(request: web.Request):
+        """The native lane's ring of batch timelines (docs/observability.md
+        "Debug surface"): the newest ``?n=K`` batches (default 64, the ring
+        holds 2,048), newest first, each with its eight stamps on
+        ``time.monotonic_ns()``."""
+        fe = _frontend()
+        if fe is None:
+            return web.json_response({"error": "no native frontend"},
+                                     status=404)
+        try:
+            n = int(request.query.get("n", 64))
+        except ValueError:
+            return web.Response(status=400, text="bad n")
+        return web.json_response(fe.batch_stages.to_json(n))
+
+    # `capture`: {trace_dir, started_unix_ns} while a capture runs
+    profile_state = {"busy": False, "capture": None}
 
     async def debug_profile(request: web.Request):
         """Opt-in on-demand device profile: captures a jax.profiler trace
         for ?seconds=N (cap 60) into a fresh temp dir and returns its path.
-        Single-flight — a capture in progress answers 409."""
+        Host TraceMe events (the program's own `atpu/...` spans among them)
+        and the device tracer, no Python frames: the Python tracer slows
+        the host it measures severalfold.  ``?python=1`` turns it on for
+        the operator who wants frames.  Single-flight — a capture in
+        progress answers 409."""
         if not enable_profile:
             return web.Response(
                 status=403,
@@ -391,16 +416,25 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
         try:
             import asyncio
             import tempfile
+            import time as _time
 
             import jax.profiler
 
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = int(
+                request.query.get("python", "0") not in ("", "0"))
             trace_dir = tempfile.mkdtemp(prefix="authorino-tpu-profile-")
-            jax.profiler.start_trace(trace_dir)
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
+            profile_state["capture"] = {"trace_dir": trace_dir,
+                                        "started_unix_ns": _time.time_ns()}
             try:
                 await asyncio.sleep(seconds)
             finally:
+                profile_state["capture"] = None
                 jax.profiler.stop_trace()
-            return web.json_response({"trace_dir": trace_dir, "seconds": seconds})
+            return web.json_response({
+                "trace_dir": trace_dir, "seconds": seconds,
+                "python_tracer_level": options.python_tracer_level})
         except Exception as e:
             return web.Response(status=500, text=f"profile capture failed: {e}")
         finally:
@@ -418,6 +452,7 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
     app.router.add_get("/debug/replay", debug_replay)
     app.router.add_post("/debug/replay", debug_replay)
     app.router.add_get("/debug/profile", debug_profile)
+    app.router.add_get("/debug/batches", debug_batches)
     # catch-all LAST: Envoy's HTTP ext_authz filter forwards the ORIGINAL
     # request path (path_prefix + :path), so /check is just the conventional
     # prefix — any path must evaluate (ref: pkg/service/auth.go:89-177

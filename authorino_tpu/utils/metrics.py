@@ -330,7 +330,11 @@ pipeline_stage_duration = _histogram(
     "Per-batch wall time of each async-dispatch pipeline stage: encode = "
     "host encode/pack + fused staging build; launch = non-blocking kernel "
     "dispatch call (operand H2D enqueue); device = launch to readback "
-    "arrival (link RTT + kernel); resolve = readback to future resolution.",
+    "arrival (link RTT + kernel); resolve = readback to future resolution.  "
+    "The native lane's batch stage clock (runtime/batch_stages.py) adds "
+    "pickup = front-end flush to dispatch entry, plan = breaker, lane "
+    "choice, cache probe and dedup, post = cache puts and per-batch "
+    "telemetry after the answers left.",
     _LANE_LABELS + ("stage",),
     buckets=STAGE_BUCKETS,
 )
@@ -400,7 +404,7 @@ _stage_children: dict = {}
 
 def observe_pipeline_stage(lane, stage, seconds) -> None:
     """Record one pipeline-stage wall-time sample (cached label children:
-    this runs up to four times per micro-batch)."""
+    this runs up to seven times per micro-batch)."""
     ch = _stage_children.get((lane, stage))
     if ch is None:
         ch = _stage_children[(lane, stage)] = (

@@ -617,7 +617,9 @@ struct SlowPending {
 
 // events to Python
 enum EvKind { EV_TIMEOUT = 0, EV_BATCH = 1, EV_SNAP_RETIRED = 3, EV_STOPPED = 4 };
-struct Event { int kind; int64_t a, b, c; };
+// d: for EV_BATCH, the slot's flush time (CLOCK_MONOTONIC ns, the clock of
+// Python's time.monotonic_ns()) — the start of the batch's `pickup` stage
+struct Event { int kind; int64_t a, b, c, d; };
 
 struct Server {
   // config
@@ -1014,6 +1016,7 @@ static void flush_batch(Server* S, bool from_timer = false) {
   int slot = S->fill_slot, count = S->fill_count;
   std::vector<int64_t> retired;
   bool flushed = false;
+  int64_t flush_ns = 0;
   {
     // fill_slot/fill_snap transitions stay under mu: Python threads read
     // fill_snap in maybe_retire_locked (an unsynchronized shared_ptr
@@ -1034,7 +1037,8 @@ static void flush_batch(Server* S, bool from_timer = false) {
       // the slow lane.  Let the batch keep filling; re-check next window.
     } else {
       snap->slot_count[slot] = count;
-      snap->slot_flush_ns[slot] = now_mono_ns();
+      flush_ns = now_mono_ns();
+      snap->slot_flush_ns[slot] = flush_ns;
       snap->pending_batches++;
       S->fill_slot = -1;
       S->fill_count = 0;
@@ -1051,7 +1055,7 @@ static void flush_batch(Server* S, bool from_timer = false) {
   if (flushed) {
     {
       std::lock_guard<std::mutex> lk(S->batch_mu);
-      S->batch_events.push_back({EV_BATCH, snap->id, slot, count});
+      S->batch_events.push_back({EV_BATCH, snap->id, slot, count, flush_ns});
     }
     S->batch_cv.notify_all();
   }
@@ -1587,7 +1591,7 @@ static void epoll_loop(Server* S) {
   }
   {
     std::lock_guard<std::mutex> lk(S->batch_mu);
-    S->batch_events.push_back({EV_STOPPED, 0, 0, 0});
+    S->batch_events.push_back({EV_STOPPED, 0, 0, 0, 0});
   }
   S->batch_cv.notify_all();
   S->slow_cv.notify_all();
@@ -1746,7 +1750,7 @@ static void emit_retired(Server* S, const std::vector<int64_t>& retired) {
   if (retired.empty()) return;
   {
     std::lock_guard<std::mutex> lk(S->batch_mu);
-    for (int64_t id : retired) S->batch_events.push_back({EV_SNAP_RETIRED, id, 0, 0});
+    for (int64_t id : retired) S->batch_events.push_back({EV_SNAP_RETIRED, id, 0, 0, 0});
   }
   S->batch_cv.notify_all();
 }
