@@ -33,6 +33,10 @@ Checks and their finding kinds (catalogue: docs/static_analysis.md):
                      through the wrong comparison)
   fused-pack-width   fused_pack_w == packed_width(1 + 2E) — the in-kernel
                      bitpack readback width the dispatchers decode against
+  own-dfa-rows       config_dfa_rows[g] is exactly the set of DFA rows
+                     reachable from config g's evaluators (ascending, -1
+                     padded) — the own-row scan evaluates nothing else, so a
+                     missing row reads False where the regex would match
 """
 
 from __future__ import annotations
@@ -319,6 +323,54 @@ def _check_fused_layout(policy: CompiledPolicy, out: List[Finding]) -> None:
                 f"packed_width(1+2E) = {want}", "fused_pack_w"))
 
 
+def _check_own_rows(policy: CompiledPolicy, out: List[Finding]) -> None:
+    """ISSUE 26 own-row scan layout, audited against its SOURCES by a walk of
+    its own (top-down from each config's evaluator references; the compiler
+    derives the table bottom-up).  Runs after circuit-order passed, so the
+    recursion is bounded by the level count."""
+    table = getattr(policy, "config_dfa_rows", None)
+    G = int(policy.eval_rule.shape[0])
+    if table is None or table.ndim != 2 or table.shape[0] != G \
+            or table.shape[1] < 1:
+        out.append(_err(
+            "own-dfa-rows",
+            f"config_dfa_rows must be [G={G}, D>=1], got "
+            f"{None if table is None else table.shape}", "config_dfa_rows"))
+        return
+    base, L = _leaf_base(), policy.n_leaves
+    is_dfa = policy.leaf_op == OP_REGEX_DFA
+    starts = [base + L]
+    for children, _ in policy.levels:
+        starts.append(starts[-1] + int(children.shape[0]))
+    memo: dict = {}
+
+    def reach(buf: int) -> frozenset:
+        if buf < base:
+            return frozenset()
+        if buf < base + L:
+            return frozenset((int(policy.leaf_dfa_row[buf - base]),)) \
+                if is_dfa[buf - base] else frozenset()
+        hit = memo.get(buf)
+        if hit is None:
+            level = int(np.searchsorted(starts, buf, side="right")) - 1
+            kids = policy.levels[level][0][buf - starts[level]]
+            hit = memo[buf] = frozenset().union(
+                *(reach(int(k)) for k in set(kids.tolist())))
+        return hit
+
+    for g in range(G):
+        refs = set(policy.eval_cond[g].tolist()) | set(policy.eval_rule[g].tolist())
+        want = sorted(frozenset().union(*(reach(r) for r in refs)))
+        got = table[g].tolist()
+        if got != want + [-1] * (len(got) - len(want)):
+            out.append(_err(
+                "own-dfa-rows",
+                f"config_dfa_rows[{g}] = {got} but config {g}'s evaluators "
+                f"reach DFA rows {want} (ascending, -1 padded)",
+                "config_dfa_rows", config=g))
+            return
+
+
 def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
     """Dtype/shape contracts of the device operand pytrees, for ALL lanes.
     Host-only build (to_device(host=True)): no device, no transfer."""
@@ -541,6 +593,8 @@ def tensor_lint(policy: CompiledPolicy,
     _check_circuit(policy, out)
     _check_dfa(policy, out)
     _check_fused_layout(policy, out)
+    if not out:
+        _check_own_rows(policy, out)
     if check_lanes and not out:
         # lane builds index through the arrays checked above; skip when the
         # base layout is already broken (they would raise, not report)
@@ -554,7 +608,7 @@ def _shard_grid_sig(p: CompiledPolicy) -> tuple:
     return (
         p.n_attrs, p.n_leaves, p.n_member_attrs, p.members_k,
         p.n_cpu_leaves, p.n_byte_attrs, p.buffer_size,
-        tuple(p.eval_rule.shape),
+        tuple(p.eval_rule.shape), tuple(p.config_dfa_rows.shape),
         tuple((tuple(children.shape), int(is_and.shape[0]))
               for children, is_and in p.levels),
         int(getattr(p, "n_num_attrs", 0) or 0),

@@ -644,6 +644,20 @@ def _rows_in_range(policy: CompiledPolicy) -> bool:
     return int(rows.min()) >= 0 and int(rows.max()) < T
 
 
+def _own_rows_findings(policy: CompiledPolicy) -> List[Finding]:
+    """Own-row scan audit (ISSUE 26, once per snapshot): ``config_dfa_rows``
+    against the circuit, by the tensor lint's own walk.  The served kernel
+    scans only the rows the table names for a request's config; a row the
+    circuit reaches but the table lacks reads False, which a truth-table
+    over atoms cannot see."""
+    from .tensor_lint import _check_own_rows
+
+    found: List[Finding] = []
+    _check_own_rows(policy, found)
+    return [_err("own-rows-layout", f.message, f.location, **f.detail)
+            for f in found]
+
+
 def _fused_layout_findings(policy: CompiledPolicy) -> List[Finding]:
     """Fused-layout audit (ISSUE 17, once per snapshot): the mega-kernel's
     packed operand layouts against their sources.  A corrupted row
@@ -1039,6 +1053,9 @@ def certify_snapshot(policy: CompiledPolicy, use_cache: bool = True,
     # fused packed-layout audit (ISSUE 17): same corpus-global, never-
     # cached treatment — the fused lane is a first-class certified peer
     failures += _fused_layout_findings(policy)
+    # own-row scan layout (ISSUE 26): the served entries evaluate only the
+    # DFA rows this table names, so it is certified like the circuit itself
+    failures += _own_rows_findings(policy)
     for name in sorted(policy.config_ids, key=policy.config_ids.get):
         row = policy.config_ids[name]
         fp = config_fingerprint(policy, row, circ=circ, memo=digest_memo)
@@ -1347,6 +1364,19 @@ def _mut_fused_perm_corrupt(p: CompiledPolicy) -> None:
     p.dfa_row_perm[0] = p.dfa_row_perm[1]
 
 
+def _mut_own_row_dropped(p: CompiledPolicy) -> None:
+    """Drop one DFA row from one config's own-row table (ISSUE 26): the
+    served kernel would skip that regex for that config's requests and read
+    it False — a deny for a plain rule, an ALLOW under a negation."""
+    table = p.config_dfa_rows
+    owners = np.nonzero(table[:, 0] >= 0)[0] if table is not None else ()
+    if not len(owners):
+        raise AssertionError("no config owns a DFA row")
+    g = int(owners[0])
+    p.config_dfa_rows = table.copy()
+    p.config_dfa_rows[g, int((table[g] >= 0).sum()) - 1] = -1
+
+
 def _mut_fused_int8_corrupt(p: CompiledPolicy) -> None:
     """Nudge one packed int8 op code so it no longer mirrors leaf_op (the
     affected leaf routes through the wrong comparison in the fused lane
@@ -1376,6 +1406,8 @@ _MUTANTS = (
     ("fused-perm-corrupt", _mut_fused_perm_corrupt),
     ("fused-int8-corrupt", _mut_fused_int8_corrupt),
     ("fused-packw-corrupt", _mut_fused_packw_corrupt),
+    # ISSUE 26 own-row scan layout (caught by _own_rows_findings)
+    ("own-row-dropped", _mut_own_row_dropped),
 )
 
 

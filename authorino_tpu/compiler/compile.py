@@ -128,6 +128,8 @@ class ShapeTargets:
     # unique DFA transition tables (rows sharing a determinized automaton
     # point at one table through dfa_table_of_row — rule-tensor compaction)
     n_dfa_tables: int = 1
+    # most DFA rows any one config's circuit reaches (config_dfa_rows' D)
+    n_own_dfa_rows: int = 1
     # eval-table rows (configs per shard) — unified so per-shard device
     # pytrees (incl. the matmul lane's [G*E, cursor] one-hots) stack
     n_configs: int = 1
@@ -159,6 +161,7 @@ class ShapeTargets:
             n_dfa_states=max(s.n_dfa_states for s in shapes),
             n_byte_attrs=max(s.n_byte_attrs for s in shapes),
             n_dfa_tables=max(s.n_dfa_tables for s in shapes),
+            n_own_dfa_rows=max(s.n_own_dfa_rows for s in shapes),
             n_configs=max(s.n_configs for s in shapes),
             n_num_attrs=max(s.n_num_attrs for s in shapes),
             n_rel_slots=max(s.n_rel_slots for s in shapes),
@@ -305,7 +308,19 @@ class CompiledPolicy:
     leaf_op_i8: np.ndarray = None        # [L] int8 packed op codes (ops < 2^7)
     fused_pack_w: int = 0                # in-kernel bitpack width, packed_width(1+2E)
 
+    # --- own-row DFA scan layout (ISSUE 26) ---
+    # per config row, the DFA rows its evaluators' circuits reach, ascending,
+    # padded with -1 to D = the most any one config reaches (>= 1).  Leaves
+    # are deduplicated across configs, so a DFA row may appear under several
+    # configs: a per-config table, not a partition.  Derived like
+    # dfa_row_perm (deterministic, stored, audited by the lint and the
+    # certifier); only ShapeTargets.n_own_dfa_rows widens it, so shards stack.
+    config_dfa_rows: np.ndarray = None   # [G, D] int32 (-1 = no row)
+
     def __post_init__(self) -> None:
+        if self.config_dfa_rows is None and self.eval_rule is not None \
+                and self.leaf_dfa_row is not None:
+            self.config_dfa_rows = derive_config_dfa_rows(self)
         if self.dfa_row_perm is None and self.dfa_table_of_row is not None:
             self.dfa_row_perm = np.argsort(
                 self.dfa_table_of_row, kind="stable").astype(np.int32)
@@ -377,6 +392,7 @@ class CompiledPolicy:
             self.n_rel_slots,
             tuple(self.rel_bits.shape) if self.rel_bits is not None else (),
             bool(self.ovf_assist),
+            int(self.config_dfa_rows.shape[1]),
         )
 
     def shape_targets(self) -> ShapeTargets:
@@ -391,6 +407,7 @@ class CompiledPolicy:
             n_dfa_states=int(self.dfa_tables.shape[1]),
             n_byte_attrs=self.n_byte_attrs,
             n_dfa_tables=int(self.dfa_tables.shape[0]),
+            n_own_dfa_rows=int(self.config_dfa_rows.shape[1]),
             n_configs=self.n_configs,
             n_num_attrs=self.n_num_attrs,
             n_rel_slots=self.n_rel_slots,
@@ -399,6 +416,37 @@ class CompiledPolicy:
             n_rel_width=int(self.rel_bits.shape[1])
             if self.rel_bits is not None else 1,
         )
+
+
+def derive_config_dfa_rows(policy: "CompiledPolicy") -> np.ndarray:
+    """[G, D] int32: for each config row the DFA rows reachable from its
+    ``eval_cond`` / ``eval_rule`` references through ``levels`` down to
+    ``OP_REGEX_DFA`` leaves, ascending, padded with -1.  D is the natural
+    maximum (at least 1): a bucket would multiply the own-row scan.
+    Children reference strictly earlier buffer slots (tensor_lint
+    circuit-order), so one pass over the levels closes the reachability."""
+    L = policy.n_leaves
+    dfa_leaves = np.nonzero(policy.leaf_op == OP_REGEX_DFA)[0]
+    if not dfa_leaves.size:
+        return np.full((policy.n_configs, 1), -1, dtype=np.int32)
+    none: frozenset = frozenset()
+    reach: List[frozenset] = [none] * (_LEAF_BASE + L)
+    for leaf in dfa_leaves:
+        reach[_LEAF_BASE + int(leaf)] = frozenset((int(policy.leaf_dfa_row[leaf]),))
+    for children, _is_and in policy.levels:
+        for kids in children.tolist():
+            acc = none
+            for k in kids:
+                if reach[k]:
+                    acc = acc | reach[k]
+            reach.append(acc)
+    refs = np.concatenate([policy.eval_cond, policy.eval_rule], axis=1).tolist()
+    own = [sorted(frozenset().union(*(reach[r] for r in row))) for row in refs]
+    D = max(max((len(o) for o in own), default=0), 1)
+    out = np.full((len(own), D), -1, dtype=np.int32)
+    for g, o in enumerate(own):
+        out[g, : len(o)] = o
+    return out
 
 
 def _round_up(n: int, multiple: int = 8, minimum: int = 8) -> int:
@@ -936,7 +984,7 @@ def compile_corpus(
     C = targets.n_cpu_leaves if targets is not None else max(len(cpu_leaf_list_), 1)
     assert C >= max(len(cpu_leaf_list_), 1), "targets.n_cpu_leaves too small"
 
-    return CompiledPolicy(
+    policy = CompiledPolicy(
         leaf_op=leaf_op,
         leaf_attr=leaf_attr,
         leaf_const=leaf_const,
@@ -982,3 +1030,11 @@ def compile_corpus(
         rel_col_names=rel_col_names_list,
         ovf_assist=bool(ovf_assist),
     )
+    if targets is not None:
+        own = policy.config_dfa_rows
+        assert targets.n_own_dfa_rows >= own.shape[1], \
+            "targets.n_own_dfa_rows too small"
+        policy.config_dfa_rows = np.pad(
+            own, ((0, 0), (0, targets.n_own_dfa_rows - own.shape[1])),
+            constant_values=-1)
+    return policy

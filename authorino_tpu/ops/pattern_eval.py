@@ -41,6 +41,24 @@ Lane dispatch is structural: ``to_device`` builds the matmul operands (or
 not), and ``eval_verdicts`` branches on their presence at trace time, so the
 two lanes jit-cache independently.
 
+Which DFA scan runs is structural too.  A caller that reads only each row's
+own config (``eval_full_jit`` and, through it, ``eval_packed_jit``,
+``eval_bitpacked_jit`` — the served entry — and ``eval_fused_jit``) passes
+``own_config`` to ``eval_verdicts``, and both lax lanes then run ONE body,
+``_own_dfa_row_res``: the scan covers ``config_dfa_rows[config_id]``, the
+[B, D] DFA rows the request's own config reaches (D = the most any one
+config reaches), not the corpus's [B, R].  Their [S, 256] tables are
+gathered once a launch from the deduplicated ``dfa_tables``; one batched
+matmul with the byte one-hots gives every byte position's S -> S map; the
+``lax.scan`` carries [B, D] and reads [B, D, S] a step; the accepts are
+placed back on the [B, R] row axis, False elsewhere, so everything
+downstream is the same code.  The other configs' columns are then NOT their
+verdicts — ``_select_own`` discards them.  Callers that want every config's
+column (``forward`` / ``_eval_jit`` / ``eval_batch_jit``, the mesh step in
+parallel/sharded_eval.py, models/policy_model.py) pass no ``own_config`` and
+get the dense scan bodies below; the fused lane (ops/fused_kernel.py) has
+one body and is dense.
+
 Membership overflow (arrays longer than K) and DFA byte overflow cannot be
 answered from the compact payload per-leaf; overflowed *requests* are flagged
 host_fallback by pack_batch and re-decided on host by the expression oracle
@@ -320,6 +338,8 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
         if policy.n_byte_attrs else None,
         "dfa_byte_slot": put(dfa_byte_slot.astype(np.int32)) if policy.n_byte_attrs else None,
         "leaf_dfa_row": put(policy.leaf_dfa_row) if policy.n_byte_attrs else None,
+        # own-row scan: per config, the DFA rows its circuits reach (-1 pad)
+        "config_dfa_rows": put(policy.config_dfa_rows) if policy.n_byte_attrs else None,
         # numeric comparator lane (ISSUE 14): leaf → compact value slot;
         # the constants ride leaf_const (folded int32 at compile time)
         "leaf_num_slot": put(np.maximum(
@@ -404,6 +424,69 @@ def _verdict_from_tables(params, cond, rule):
     return verdict, (rule, skipped)
 
 
+def dfa_scan_width(params, own: bool = True) -> dict:
+    """What /debug/vars reports of the DFA scan: ``dfa_rows_total`` is the
+    corpus's R; ``dfa_rows_per_row`` is what one request row has scanned —
+    D on the own-row scan (``own``: an entry that returns own-config results
+    on a lax lane), R on the dense scan (``own=False``; the fused lane has no
+    own-row body).  Both 0 without a device DFA lane."""
+    if params.get("dfa_tables") is None:
+        return {"dfa_rows_per_row": 0, "dfa_rows_total": 0}
+    R = int(params["dfa_table_of_row"].shape[-1])
+    D = int(params["config_dfa_rows"].shape[-1])
+    return {"dfa_rows_per_row": D if own and params.get("fused") is None else R,
+            "dfa_rows_total": R}
+
+
+def _own_dfa_row_res(params, own_config, attr_bytes, cdt):
+    """Own-row DFA scan, shared by both lax lanes: evaluates only the DFA
+    rows ``config_dfa_rows[own_config]`` names ([B, D]) and returns their
+    accepts placed on the [B, R] row axis, False elsewhere.  Rows of other
+    configs feed only circuits whose results ``_select_own`` discards, so
+    own verdict / rule results / skipped flags equal the dense scan's.  A
+    config id outside [0, G) owns no row."""
+    f32 = jnp.float32
+    cfg_rows = params["config_dfa_rows"]                     # [G, D] i32, -1 pad
+    G = cfg_rows.shape[0]
+    R = params["dfa_table_of_row"].shape[0]
+    tables = params["dfa_tables"]                            # [T, S, 256] u8
+    S = tables.shape[1]
+    in_range = (own_config >= 0) & (own_config < G)
+    own = jnp.where(
+        in_range[:, None],
+        jnp.take(cfg_rows, jnp.clip(own_config, 0, G - 1), axis=0), -1)  # [B, D]
+    row = jnp.maximum(own, 0)
+    tab = jnp.take(params["dfa_table_of_row"], row)          # [B, D]
+    slot = jnp.take(params["dfa_byte_slot"], row)            # [B, D]
+    own_bytes = jnp.take_along_axis(
+        attr_bytes, slot[:, :, None], axis=1)                # [B, D, LB] u8
+    # whole [S, 256] tables fetched once a launch, from the deduped axis
+    own_tables = jnp.take(tables, tab, axis=0)               # [B, D, S, 256] u8
+    # every byte position's S -> S transition map at once (next-state values
+    # <= 255 and 0/1 one-hots: exact in bf16), so the sequential part below
+    # carries [B, D] and touches [B, D, S] a step
+    byte_oh = own_bytes[..., None] == jnp.arange(256, dtype=own_bytes.dtype)
+    step_maps = jnp.einsum(
+        "bdsc,bdlc->lbds", own_tables.astype(cdt), byte_oh.astype(cdt),
+        preferred_element_type=f32)                          # [LB, B, D, S]
+    iota_s = jnp.arange(S, dtype=f32)
+
+    def dfa_step(state, step_map):  # state [B, D] f32; step_map [B, D, S] f32
+        nxt = jnp.sum(
+            jnp.where(state[..., None] == iota_s, step_map, 0.0), axis=-1)
+        return nxt, None
+
+    # init carry derived from a varying input (zero-multiplied) so its
+    # manual-mesh "varying" type matches inside shard_map
+    init = own_bytes[:, :, 0].astype(f32) * 0.0
+    final, _ = jax.lax.scan(dfa_step, init, step_maps)
+    accept = jnp.take(params["dfa_accept"], tab, axis=0)     # [B, D, S] bool
+    own_res = (own >= 0) & jnp.any(
+        accept & (final[..., None] == iota_s), axis=-1)      # [B, D]
+    hit = own[:, :, None] == jnp.arange(R, dtype=own.dtype)  # [B, D, R]
+    return jnp.any(hit & own_res[:, :, None], axis=1)        # [B, R]
+
+
 # ---------------------------------------------------------------------------
 # matmul lane (MXU)
 # ---------------------------------------------------------------------------
@@ -411,7 +494,8 @@ def _verdict_from_tables(params, cond, rule):
 
 def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
                           attr_bytes, byte_ovf, attrs_num=None,
-                          num_valid=None, rel_rows=None, member_ovf=None):
+                          num_valid=None, rel_rows=None, member_ovf=None,
+                          own_config=None):
     mm = params["matmul"]
     f32 = jnp.float32
     cdt = mm["rule_m"].dtype
@@ -437,34 +521,38 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
     # ---- device regex lane: DFA scan, transitions as batched matmuls -----
     with jax.named_scope("dfa_scan"):
         if params["dfa_tables"] is not None and attr_bytes is not None:
-            tables = mm["dfa_tables_f"]                          # [R, S, 256] bf16
-            R, S = tables.shape[0], tables.shape[1]
-            # spread each row's attr bytes from its slot: [B, NB, LB] → [B, R, LB]
-            row_bytes = jnp.einsum(
-                "bnl,nr->brl", attr_bytes.astype(cdt), mm["slot_row_oh"],
-                preferred_element_type=f32,
-            )
-            iota_s = jnp.arange(S, dtype=f32)
-            iota_c = jnp.arange(256, dtype=f32)
-
-            def dfa_step(state, byte_col):  # state [B,R] f32; byte_col [B,R] f32
-                byte_oh = (byte_col[..., None] == iota_c).astype(cdt)   # [B,R,256]
-                # per-state next-state given this byte: [R,S,256] × [B,R,256]
-                nxt_by_state = jnp.einsum(
-                    "rsc,brc->brs", tables, byte_oh, preferred_element_type=f32
+            if own_config is not None:
+                dfa_row_res = _own_dfa_row_res(
+                    params, own_config, attr_bytes, cdt)         # [B, R]
+            else:
+                tables = mm["dfa_tables_f"]                          # [R, S, 256] bf16
+                R, S = tables.shape[0], tables.shape[1]
+                # spread each row's attr bytes from its slot: [B, NB, LB] → [B, R, LB]
+                row_bytes = jnp.einsum(
+                    "bnl,nr->brl", attr_bytes.astype(cdt), mm["slot_row_oh"],
+                    preferred_element_type=f32,
                 )
-                st_oh = (state[..., None] == iota_s).astype(f32)
-                nxt = jnp.sum(st_oh * nxt_by_state, axis=-1)
-                return nxt, None
+                iota_s = jnp.arange(S, dtype=f32)
+                iota_c = jnp.arange(256, dtype=f32)
 
-            # derive the scan's init carry from a varying input (zero-multiplied)
-            # so its manual-mesh "varying" type matches inside shard_map
-            init = row_bytes[:, :, 0] * 0.0
-            final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
-            final_oh = (final[..., None] == iota_s).astype(cdt)
-            dfa_row_res = jnp.einsum(
-                "brs,rs->br", final_oh, mm["dfa_accept_f"], preferred_element_type=f32
-            ) > 0.5                                              # [B, R]
+                def dfa_step(state, byte_col):  # state [B,R] f32; byte_col [B,R] f32
+                    byte_oh = (byte_col[..., None] == iota_c).astype(cdt)   # [B,R,256]
+                    # per-state next-state given this byte: [R,S,256] × [B,R,256]
+                    nxt_by_state = jnp.einsum(
+                        "rsc,brc->brs", tables, byte_oh, preferred_element_type=f32
+                    )
+                    st_oh = (state[..., None] == iota_s).astype(f32)
+                    nxt = jnp.sum(st_oh * nxt_by_state, axis=-1)
+                    return nxt, None
+
+                # derive the scan's init carry from a varying input (zero-multiplied)
+                # so its manual-mesh "varying" type matches inside shard_map
+                init = row_bytes[:, :, 0] * 0.0
+                final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
+                final_oh = (final[..., None] == iota_s).astype(cdt)
+                dfa_row_res = jnp.einsum(
+                    "brs,rs->br", final_oh, mm["dfa_accept_f"], preferred_element_type=f32
+                ) > 0.5                                              # [B, R]
             leaf_dfa = jnp.einsum(
                 "br,rl->bl", dfa_row_res.astype(cdt), mm["row_leaf_oh"],
                 preferred_element_type=f32,
@@ -548,7 +636,8 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
 
 def _eval_verdicts_gather(params, attrs_val, members_c, cpu_dense,
                           attr_bytes, byte_ovf, attrs_num=None,
-                          num_valid=None, rel_rows=None, member_ovf=None):
+                          num_valid=None, rel_rows=None, member_ovf=None,
+                          own_config=None):
     leaf_op = params["leaf_op"]          # [L]
     leaf_attr = params["leaf_attr"]      # [L]
     leaf_const = params["leaf_const"]    # [L]
@@ -568,20 +657,24 @@ def _eval_verdicts_gather(params, attrs_val, members_c, cpu_dense,
     # ---- device regex lane: DFA scan over value bytes --------------------
     with jax.named_scope("dfa_scan"):
         if params["dfa_tables"] is not None and attr_bytes is not None:
-            tables = params["dfa_tables"]          # [T, S, 256] uint8 (deduped)
-            # per-row table index: rows sharing an automaton share one table
-            tab_idx = params["dfa_table_of_row"][None, :]        # [1, R]
-            row_bytes = jnp.take(attr_bytes, params["dfa_byte_slot"], axis=1)  # [B, R, LB]
+            if own_config is not None:
+                dfa_row_res = _own_dfa_row_res(
+                    params, own_config, attr_bytes, jnp.float32)  # [B, R]
+            else:
+                tables = params["dfa_tables"]          # [T, S, 256] uint8 (deduped)
+                # per-row table index: rows sharing an automaton share one table
+                tab_idx = params["dfa_table_of_row"][None, :]        # [1, R]
+                row_bytes = jnp.take(attr_bytes, params["dfa_byte_slot"], axis=1)  # [B, R, LB]
 
-            def dfa_step(states, byte_col):  # states [B,R] i32, byte_col [B,R] u8
-                nxt = tables[tab_idx, states, byte_col.astype(jnp.int32)]
-                return nxt.astype(jnp.int32), None
+                def dfa_step(states, byte_col):  # states [B,R] i32, byte_col [B,R] u8
+                    nxt = tables[tab_idx, states, byte_col.astype(jnp.int32)]
+                    return nxt.astype(jnp.int32), None
 
-            # init carry derived from a varying input (zero-multiplied) so its
-            # manual-mesh "varying" type matches inside shard_map
-            init = (row_bytes[:, :, 0] * 0).astype(jnp.int32)
-            final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
-            dfa_row_res = params["dfa_accept"][tab_idx, final]   # [B, R]
+                # init carry derived from a varying input (zero-multiplied) so its
+                # manual-mesh "varying" type matches inside shard_map
+                init = (row_bytes[:, :, 0] * 0).astype(jnp.int32)
+                final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
+                dfa_row_res = params["dfa_accept"][tab_idx, final]   # [B, R]
             leaf_dfa = jnp.take(dfa_row_res, params["leaf_dfa_row"], axis=1)  # [B, L]
             leaf_slot = jnp.take(params["dfa_byte_slot"], params["leaf_dfa_row"])
             leaf_bovf = jnp.take(byte_ovf, leaf_slot, axis=1)    # [B, L]
@@ -647,8 +740,15 @@ def eval_verdicts(
     num_valid: Optional[jnp.ndarray] = None,   # [B, NN] bool
     rel_rows: Optional[jnp.ndarray] = None,    # [B, NR] int32 (relation lane)
     member_ovf: Optional[jnp.ndarray] = None,  # [B, M] bool (ovf_assist)
+    own_config: Optional[jnp.ndarray] = None,  # [B] int32 (config_id)
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Returns (verdict [B, G] bool, (rule_results [B, G, E], skipped [B, G, E]))."""
+    """Returns (verdict [B, G] bool, (rule_results [B, G, E], skipped [B, G, E])).
+
+    With ``own_config`` the caller promises to read only each row's own
+    config column, and the lax lanes scan only that config's DFA rows
+    (``_own_dfa_row_res``): the other configs' columns are then not their
+    verdicts.  Without it every column is exact (the dense scan).  The fused
+    lane has one body and ignores it."""
     # ids travel as int16 when the interner fits (compiler/pack.py
     # wire_dtype); upcast on device AFTER the transfer
     if attrs_val.dtype != jnp.int32:
@@ -665,11 +765,11 @@ def eval_verdicts(
     if params.get("matmul") is not None:
         return _eval_verdicts_matmul(
             params, attrs_val, members_c, cpu_dense, attr_bytes, byte_ovf,
-            attrs_num, num_valid, rel_rows, member_ovf
+            attrs_num, num_valid, rel_rows, member_ovf, own_config
         )
     return _eval_verdicts_gather(
         params, attrs_val, members_c, cpu_dense, attr_bytes, byte_ovf,
-        attrs_num, num_valid, rel_rows, member_ovf
+        attrs_num, num_valid, rel_rows, member_ovf, own_config
     )
 
 
@@ -703,10 +803,11 @@ def eval_full_jit(params, attrs_val, members_c, cpu_dense, config_id,
                   num_valid=None, rel_rows=None, member_ovf=None):
     """Like _eval_jit but also returns each request's own per-evaluator rule
     results + skipped flags [B, E] — what the pipeline's batched
-    pattern-matching evaluators consume (runtime/engine.py)."""
+    pattern-matching evaluators consume (runtime/engine.py).  Own-config
+    results only, so the DFA scan runs over the request's own rows."""
     verdict, (rule, skipped) = eval_verdicts(
         params, attrs_val, members_c, cpu_dense, attr_bytes, byte_ovf,
-        attrs_num, num_valid, rel_rows, member_ovf
+        attrs_num, num_valid, rel_rows, member_ovf, own_config=config_id
     )
     own_mask = _select_own(config_id, verdict.shape[1])
     own = jnp.any(verdict & own_mask, axis=1)

@@ -1081,18 +1081,25 @@ class NativeFrontend:
         return out
 
     @staticmethod
-    def _kernel_of(rec: _SnapRec) -> Optional[Dict[str, str]]:
-        from ..ops.pattern_eval import kernel_body_of, kernel_lane_of
+    def _kernel_of(rec: _SnapRec) -> Optional[Dict[str, Any]]:
+        from ..ops.pattern_eval import (dfa_scan_width, kernel_body_of,
+                                        kernel_lane_of)
 
         if rec.sharded is not None:
-            return {"lane": kernel_lane_of(rec.sharded.host_view),
-                    "body": "lax", "entry": "sharded_step"}
+            # the mesh step wants every config's column: the dense scan
+            view = rec.sharded.host_view
+            return {"lane": kernel_lane_of(view),
+                    "body": "lax", "entry": "sharded_step",
+                    **dfa_scan_width(view, own=False)}
         if rec.params is None:
             return None
         lane = kernel_lane_of(rec.params)
         return {"lane": lane, "body": kernel_body_of(rec.params),
                 "entry": ("fused_kernel" if lane == "fused"
-                          else "eval_bitpacked")}
+                          else "eval_bitpacked"),
+                # what the served entry scans for ONE request row (its own
+                # config's DFA rows on the lax lanes) against the corpus's
+                **dfa_scan_width(rec.params)}
 
     @property
     def warm_error(self) -> Optional[str]:

@@ -153,3 +153,209 @@ def test_interner_overflow_falls_back_to_gather(monkeypatch):
     monkeypatch.setattr(pe, "_F32_EXACT", len(policy.interner))
     params = pe.to_device(policy)
     assert params["matmul"] is None  # ids no longer exact in f32
+
+
+# ---------------------------------------------------------------------------
+# own-row DFA scan (ISSUE 26): the entries that return own-config results
+# scan config_dfa_rows[config_id], not the corpus's DFA rows
+# ---------------------------------------------------------------------------
+
+_RX = Operator.MATCHES
+
+
+def _tenant_like(i, extra=()):
+    """The benchmark's tenant_rules shape: two per-config regexes among
+    eq / neq / incl / excl leaves under one All."""
+    return All(
+        Pattern("request.method", Operator.NEQ, "DELETE"),
+        Pattern("request.url_path", _RX, rf"^/api/v[0-9]+/t{i}/[a-z0-9/_-]*$"),
+        Pattern("request.headers.x-request-id", _RX, rf"^r{i}-[0-9a-f]{{8}}$"),
+        Pattern("auth.identity.roles", Operator.INCL, f"role-{i % 5}"),
+        Pattern("auth.identity.groups", Operator.EXCL, f"banned-{i}"),
+        Pattern("request.headers.x-org", Operator.EQ, f"org-{i}"),
+        *extra)
+
+
+def _tenant_doc(i, rng, deny=None):
+    """A request config ``i`` allows; ``deny`` breaks one rule of it."""
+    doc = {
+        "request": {
+            "method": "GET",
+            "url_path": f"/api/v{rng.randrange(1, 10)}/t{i}/items/{rng.randrange(10**6)}",
+            "headers": {"x-request-id": f"r{i}-{rng.getrandbits(32):08x}",
+                        "x-org": f"org-{i}", "x-env": "prod"},
+        },
+        "auth": {"identity": {"roles": [f"role-{i % 5}"], "groups": ["ok"]}},
+    }
+    if deny == "path":
+        doc["request"]["url_path"] = f"/api/v1/t{i + 1}/items/1"
+    elif deny == "rid":
+        doc["request"]["headers"]["x-request-id"] += "Z"
+    elif deny == "org":
+        doc["request"]["headers"]["x-org"] = "nobody"
+    elif deny == "long":
+        # past the 64-byte tensor: the regex is answered from the CPU lane
+        doc["request"]["url_path"] = f"/api/v1/t{i}/" + "a" * 80
+    elif deny == "long-bad":
+        doc["request"]["url_path"] = f"/api/v1/t{i}/" + "a" * 80 + "!"
+    return doc
+
+
+def _own_case(case):
+    """(configs, docs, rows, config_id overrides, batch_pad, targets?) for
+    one shape of corpus the own-row scan must not get wrong."""
+    rng = random.Random(26)
+    n = 12
+    denies = [None, "path", "rid", "org"]
+    cfgs = [ConfigRules(name=f"t-{i}", evaluators=[(None, _tenant_like(i))])
+            for i in range(n)]
+    docs = [_tenant_doc(i % n, rng, denies[(i // n) % 4]) for i in range(4 * n)]
+    rows = [i % n for i in range(4 * n)]
+    cid, pad, targets = {}, 64, False
+    if case == "shared-leaf":
+        # one regex leaf (deduplicated by the lowerer) under an `any` in a
+        # third of the configs: the table is per config, not a partition
+        shared = Pattern("request.url_path", _RX, r"^/shared/")
+        for i in range(0, n, 3):
+            cfgs[i] = ConfigRules(name=f"t-{i}", evaluators=[
+                (None, Any_(shared, _tenant_like(i)))])
+        docs += [{"request": {"method": "GET", "url_path": "/shared/x",
+                              "headers": {}}, "auth": {"identity": {}}}] * 4
+        rows += [0, 3, 1, 2]  # 0, 3 allow through the shared leaf; 1, 2 deny
+    elif case == "no-regex-config":
+        cfgs[5] = ConfigRules(name="t-5", evaluators=[
+            (None, Pattern("request.headers.x-org", Operator.EQ, "org-5"))])
+    elif case == "one-heavy-config":
+        many = [Pattern("request.url_path", _RX, rf"^/api/v[0-9]+/t7/i{{1,{v + 1}}}tems")
+                for v in range(11)]
+        cfgs[7] = ConfigRules(name="t-7", evaluators=[
+            (None, _tenant_like(7, extra=many))])
+    elif case == "regex-guards-allow":
+        # a regex in a `when` (false condition = evaluator skipped = allow)
+        # and inside an `any`: with the own row missing, the regex would
+        # read False and turn these denies into allows
+        for i in range(0, n, 2):
+            cfgs[i] = ConfigRules(name=f"t-{i}", evaluators=[
+                (Pattern("request.url_path", _RX, rf"^/api/v[0-9]+/t{i}/"),
+                 Pattern("request.headers.x-org", Operator.EQ, "nobody")),
+                (None, Any_(Pattern("request.headers.x-org", Operator.EQ, "nobody"),
+                            Pattern("request.url_path", _RX, r"^/api/")))])
+    elif case == "byte-overflow":
+        docs = [_tenant_doc(i % n, rng, ["long", "long-bad", None][i % 3])
+                for i in range(3 * n)]
+        rows = [i % n for i in range(3 * n)]
+    elif case == "shape-targets":
+        targets = True
+    elif case == "config-id-out-of-range":
+        cid = {0: -1, 1: n, 2: 10**6, 3: -(10**6)}
+        pad = 128  # pad rows: config 0, no bytes
+    return cfgs, docs, rows, cid, pad, targets
+
+
+@pytest.mark.parametrize("lane", ["matmul", "gather"])
+@pytest.mark.parametrize("case", [
+    "tenant-rules", "shared-leaf", "no-regex-config", "one-heavy-config",
+    "regex-guards-allow", "byte-overflow", "shape-targets",
+    "config-id-out-of-range"])
+def test_own_row_scan_equals_dense_and_oracle(case, lane):
+    """eval_full_jit (own-row scan) against the dense scan's results selected
+    by config, bit for bit, and against the host expression oracle."""
+    cfgs, docs, rows, cid_over, pad, targets = _own_case(case)
+    policy = compile_corpus(cfgs, members_k=4)
+    natural_d = policy.config_dfa_rows.shape[1]
+    if targets:
+        from authorino_tpu.compiler.compile import ShapeTargets
+        other = compile_corpus(_mixed_corpus(29), members_k=4)
+        wide = ShapeTargets.union([policy.shape_targets(), other.shape_targets()])
+        wide.n_own_dfa_rows = natural_d + 3
+        wide.n_dfa_rows += 5
+        policy = compile_corpus(cfgs, members_k=4, targets=wide)
+        assert policy.config_dfa_rows.shape == (wide.n_configs, natural_d + 3)
+    if case == "one-heavy-config":
+        assert natural_d >= 12 and (policy.config_dfa_rows[0] >= 0).sum() == 2
+    if case == "shared-leaf":
+        shared_rows = [r for r in policy.config_dfa_rows[0] if r >= 0
+                       and r in policy.config_dfa_rows[3]]
+        assert len(shared_rows) == 1
+    params = pe.to_device(policy, lane=lane)
+    db = pack_batch(policy, encode_batch_py(policy, docs, rows, batch_pad=pad))
+    if case == "byte-overflow":
+        assert np.asarray(db.byte_ovf)[: len(docs)].any()
+    config_id = np.asarray(db.config_id).copy()
+    for i, v in cid_over.items():
+        config_id[i] = v
+    head = (jnp.asarray(db.attrs_val), jnp.asarray(db.members_c),
+            jnp.asarray(db.cpu_dense))
+    tail = (jnp.asarray(db.attr_bytes), jnp.asarray(db.byte_ovf))
+    own, own_rule, own_skipped = (np.asarray(x) for x in pe.eval_full_jit(
+        params, *head, jnp.asarray(config_id), *tail))
+    verdict, (rule, skipped) = pe.eval_verdicts(params, *head, *tail)
+    mask = config_id[:, None] == np.arange(policy.n_configs)[None, :]
+    np.testing.assert_array_equal(own, (np.asarray(verdict) & mask).any(axis=1))
+    np.testing.assert_array_equal(
+        own_rule, (np.asarray(rule) & mask[:, :, None]).any(axis=1))
+    np.testing.assert_array_equal(
+        own_skipped, (np.asarray(skipped) & mask[:, :, None]).any(axis=1))
+    # a config id that names no config owns nothing: every own column false
+    for i in cid_over:
+        assert not own[i] and not own_rule[i].any() and not own_skipped[i].any()
+    assert not np.asarray(db.host_fallback).any()
+    n_allow = 0
+    for i, (doc, row) in enumerate(zip(docs, rows)):
+        if i in cid_over:
+            continue
+        want = all(rule_.matches(doc) for cond, rule_ in policy.config_exprs[row]
+                   if cond is None or cond.matches(doc))
+        assert bool(own[i]) == want, (case, lane, i)
+        n_allow += want
+    assert 0 < n_allow < len(docs) - len(cid_over)  # both answers occur
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            if hasattr(j, "jaxpr"):
+                j = j.jaxpr
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk_eqns(sub)
+
+
+def test_served_entry_holds_no_dense_dfa_intermediate():
+    """Structure of the served entry for a corpus with R >> D: nothing of
+    B x R x LB (the spread bytes) or B x R x 256 (the byte one-hot) elements
+    is built, and the one scan carries [B, D] — so a later edit cannot fall
+    back to the dense scan unseen."""
+    n, B = 23, 16
+    policy = compile_corpus(
+        [ConfigRules(name=f"t-{i}", evaluators=[(None, _tenant_like(i))])
+         for i in range(n)], members_k=4)
+    R, D = policy.dfa_table_of_row.shape[0], policy.config_dfa_rows.shape[1]
+    assert (R, D) == (2 * n, 2)
+    rng = random.Random(3)
+    db = pack_batch(policy, encode_batch_py(
+        policy, [_tenant_doc(i % n, rng) for i in range(B)],
+        [i % n for i in range(B)], batch_pad=B))
+    LB = db.attr_bytes.shape[2]
+    for lane in ("matmul", "gather"):
+        params = pe.to_device(policy, lane=lane)
+        jaxpr = jax.make_jaxpr(pe.eval_bitpacked_jit)(
+            params, jnp.asarray(db.attrs_val), jnp.asarray(db.members_c),
+            jnp.asarray(db.cpu_dense), jnp.asarray(db.config_id),
+            jnp.asarray(db.attr_bytes), jnp.asarray(db.byte_ovf))
+        scans = []
+        for eqn in _walk_eqns(jaxpr.jaxpr):
+            for v in eqn.outvars:
+                shape = tuple(getattr(v.aval, "shape", ()))
+                assert not (R in shape and np.prod(shape) >= B * R * min(LB, 256)), \
+                    (lane, eqn.primitive.name, shape)
+            if eqn.primitive.name == "scan":
+                nc, k = eqn.params["num_consts"], eqn.params["num_carry"]
+                scans.append([tuple(v.aval.shape) for v in eqn.invars[nc:nc + k]])
+        assert scans == [[(B, D)]], (lane, scans)

@@ -1534,6 +1534,21 @@ def test_differential_vs_python_server(stack):
     assert stats["slow"] > 0, "slow lane never engaged"
 
 
+def test_debug_vars_reports_what_the_served_kernel_scans(stack):
+    """ISSUE 26: snapshot.kernel names the DFA rows one request row has
+    scanned (its own config's, D) beside the corpus's (R); the entry keeps
+    the name the trace reader finds its XLA module by."""
+    engine, fe, _, _ = stack
+    assert fe.wait_warm(180) and fe.warm_error is None
+    policy = engine._snapshot.policy
+    R = int(policy.dfa_table_of_row.shape[0]) if policy.n_byte_attrs else 0
+    D = int(policy.config_dfa_rows.shape[1]) if policy.n_byte_attrs else 0
+    kernel = fe.debug_vars()["snapshot"]["kernel"]
+    assert kernel["entry"] == "eval_bitpacked" and kernel["body"] == "lax"
+    assert kernel["dfa_rows_total"] == R
+    assert kernel["dfa_rows_per_row"] == D <= R
+
+
 def test_fast_lane_classification(stack):
     engine, _, _, _ = stack
     snap = engine._snapshot

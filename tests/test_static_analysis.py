@@ -174,6 +174,34 @@ def test_corrupt_eval_table_range():
     assert kinds == {"operand-range"}
 
 
+@pytest.mark.parametrize("corruption", ["row-dropped", "foreign-row",
+                                        "unsorted", "wrong-shape"])
+def test_corrupt_own_dfa_rows(corruption):
+    """config_dfa_rows[g] must be exactly the DFA rows config g's evaluators
+    reach: the served kernel scans nothing else for g's requests."""
+    rx = [Pattern("request.url_path", Operator.MATCHES, rf"^/t{i}/")
+          for i in range(4)]
+    p = compile_corpus([
+        ConfigRules(name="two", evaluators=[(rx[0], All(rx[1], rx[2]))]),
+        ConfigRules(name="one", evaluators=[(None, Any_(rx[1], rx[3]))]),
+        ConfigRules(name="none", evaluators=[
+            (None, Pattern("request.method", Operator.EQ, "GET"))])])
+    table = p.config_dfa_rows.copy()
+    assert table.tolist() == [[0, 1, 2], [1, 3, -1], [-1, -1, -1]]
+    assert tensor_lint(p) == []
+    if corruption == "row-dropped":
+        table[1, 1] = -1
+    elif corruption == "foreign-row":
+        table[2, 0] = 3
+    elif corruption == "unsorted":
+        table[0, :2] = table[0, :2][::-1]
+    else:
+        table = table[:-1]
+    p.config_dfa_rows = table
+    kinds = {f.kind for f in tensor_lint(p)}
+    assert kinds == {"own-dfa-rows"}
+
+
 # ---------------------------------------------------------------------------
 # packer: typed PackError instead of silent clamp/wrap
 # ---------------------------------------------------------------------------

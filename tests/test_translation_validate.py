@@ -111,6 +111,15 @@ def test_sampled_tier_catches_redirected_rule():
 # ---------------------------------------------------------------------------
 
 
+def test_own_row_dropped_rejected():
+    """ISSUE 26: a DFA row missing from one config's own-row table is a
+    corpus-global layout finding (the truth table cannot see it)."""
+    _, failures, _ = certify_snapshot(_mutate("own-row-dropped"),
+                                      use_cache=False)
+    assert {f.kind for f in failures} == {"own-rows-layout"}
+    assert failures[0].detail["config"] == 0
+
+
 def _mutate(name):
     p = deepcopy(fixture_policy())
     dict(_MUTANTS)[name](p)
