@@ -37,11 +37,16 @@ Checks and their finding kinds (catalogue: docs/static_analysis.md):
                      reachable from config g's evaluators (ascending, -1
                      padded) — the own-row scan evaluates nothing else, so a
                      missing row reads False where the regex would match
+  own-layout         every position of the own-config tables (OwnLayout)
+                     maps back to the corpus slot it stands for: evaluator
+                     references, each node's children and kind, each leaf's
+                     fields, the row payload's CPU columns — the served
+                     entry evaluates these tables and nothing else
 """
 
 from __future__ import annotations
 
-from typing import Any, List, Sequence
+from typing import Any, List, Optional, Sequence
 
 import numpy as np
 
@@ -371,6 +376,128 @@ def _check_own_rows(policy: CompiledPolicy, out: List[Finding]) -> None:
             return
 
 
+def _check_own_layout(policy: CompiledPolicy, out: List[Finding]) -> None:
+    """ISSUE 28 own-config layout, audited against its SOURCES without a
+    walk: every own position is mapped back to the corpus's buffer slot and
+    (1) each evaluator reference, (2) each own node's children and kind,
+    (3) each own leaf's fields must equal the corpus's.  By induction over
+    the levels the own circuit then computes the corpus circuit's value for
+    every evaluator; padding maps back to -1 and so can be referenced by
+    nothing.  The served entry evaluates only these tables, and the row
+    payload's CPU columns mean what ``cpu_leaves`` says."""
+    from ..compiler.compile import (OP_ERROR, OP_RELATION, OWN_ATTR, OWN_BYTE,
+                                    OWN_CONST, OWN_CPU, OWN_DFA, OWN_MEMBER,
+                                    OWN_NUM, OWN_OP, OWN_REL_COL,
+                                    OWN_REL_SLOT)
+
+    own = getattr(policy, "own", None)
+    G, E = policy.eval_rule.shape
+    base, L = _leaf_base(), policy.n_leaves
+
+    def bad(msg: str, config: Optional[int] = None) -> None:
+        out.append(_err("own-layout", msg, "own",
+                        **({} if config is None else {"config": config})))
+
+    n_levels = len(policy.levels)
+    if own is None or own.leaves.ndim != 2 or own.leaves.shape[0] != G \
+            or len(own.levels) != n_levels or len(own.nodes) != n_levels \
+            or own.evals.shape != (G, 3, E) \
+            or own.leaf_tab.shape[:2] != own.leaves.shape \
+            or own.cpu_leaves.ndim != 2 or own.cpu_leaves.shape[0] != G:
+        bad("own-config tables do not match the corpus's [G, E] and levels")
+        return
+    # own position -> corpus buffer slot, -1 on padding
+    parts = [np.tile(np.asarray([0, 1], dtype=np.int64), (G, 1)),
+             np.where(own.leaves >= 0, own.leaves.astype(np.int64) + base, -1)]
+    start = base + L
+    for (children, _), rows in zip(policy.levels, own.nodes):
+        if rows.size and int(rows.max()) >= children.shape[0]:
+            bad("own node row outside its level")
+            return
+        parts.append(np.where(rows >= 0, rows.astype(np.int64) + start, -1))
+        start += int(children.shape[0])
+    to_corpus = np.concatenate(parts, axis=1)                    # [G, P]
+    P = to_corpus.shape[1]
+
+    def back(pos: np.ndarray) -> np.ndarray:
+        """[G, ...] own positions -> corpus slots (-2 when out of range)."""
+        flat = pos.reshape(G, -1).astype(np.int64)
+        ok = (flat >= 0) & (flat < P)
+        got = np.take_along_axis(to_corpus, np.clip(flat, 0, P - 1), axis=1)
+        return np.where(ok, got, -2).reshape(pos.shape)
+
+    def first_bad(mask: np.ndarray) -> int:
+        return int(np.nonzero(mask.reshape(G, -1).any(axis=1))[0][0])
+
+    wrong = (back(own.evals[:, 0]) != policy.eval_rule) \
+        | (back(own.evals[:, 1]) != policy.eval_cond) \
+        | ((own.evals[:, 2] != 0) != policy.eval_has_cond)
+    if wrong.any():
+        g = first_bad(wrong)
+        bad(f"own evaluator references of config {g} do not map back to "
+            "eval_rule / eval_cond / eval_has_cond", g)
+        return
+    for k, ((children, is_and), rows, (own_ch, own_and)) in enumerate(
+            zip(policy.levels, own.nodes, own.levels)):
+        if own_ch.shape != rows.shape + (children.shape[1],) \
+                or own_and.shape != rows.shape:
+            bad(f"own level {k} tables are not [G, n_own, width]")
+            return
+        real = rows >= 0
+        want = children[np.maximum(rows, 0)]                     # [G, n, w]
+        wrong = real & ((back(own_ch) != want).any(axis=-1)
+                        | (own_and != is_and[np.maximum(rows, 0)]))
+        if wrong.any():
+            g = first_bad(wrong)
+            bad(f"own level {k} node of config {g} does not map back to its "
+                "corpus node's children / kind", g)
+            return
+    has = own.leaves >= 0
+    lf = np.maximum(own.leaves, 0)
+    tab = own.leaf_tab
+    attr = policy.leaf_attr[lf]
+    op = policy.leaf_op[lf]
+    is_dfa = has & (op == OP_REGEX_DFA)
+    dfa_row = np.take_along_axis(
+        policy.config_dfa_rows, np.clip(tab[..., OWN_DFA], 0, None), axis=1)
+    cpu_leaf = np.take_along_axis(
+        own.cpu_leaves, np.clip(tab[..., OWN_CPU], 0, None), axis=1)
+    in_cpu = np.isin(own.leaves, policy.cpu_leaf_list) & has
+    checks = [
+        ("op", tab[..., OWN_OP] != np.where(has, op, OP_ERROR)),
+        ("attr", has & (tab[..., OWN_ATTR] != attr)),
+        ("const", has & (tab[..., OWN_CONST] != policy.leaf_const[lf])),
+        ("member slot", has & (tab[..., OWN_MEMBER]
+                               != policy.member_attr_slot[attr])),
+        ("dfa row", is_dfa & ((tab[..., OWN_DFA] < 0)
+                              | (dfa_row != policy.leaf_dfa_row[lf]))),
+        ("byte slot", is_dfa & (tab[..., OWN_BYTE] != np.maximum(
+            policy.attr_byte_slot[attr], 0))),
+        ("cpu column", (in_cpu & ((tab[..., OWN_CPU] < 0)
+                                  | (cpu_leaf != own.leaves)))
+         | (~in_cpu & (tab[..., OWN_CPU] >= 0))),
+    ]
+    if policy.n_num_attrs:
+        checks.append(("numeric slot", has & (
+            tab[..., OWN_NUM] != policy.num_attr_slot[attr])))
+    if policy.n_rel_slots:
+        is_rel = has & (op == OP_RELATION)
+        checks.append(("relation binding", is_rel & (
+            (tab[..., OWN_REL_SLOT] != policy.leaf_rel_slot[lf])
+            | (tab[..., OWN_REL_COL] != policy.leaf_rel_col[lf]))))
+    for what, wrong in checks:
+        if wrong.any():
+            g = first_bad(wrong)
+            bad(f"own leaf table of config {g}: {what} differs from the "
+                "corpus leaf it stands for", g)
+            return
+    cl = own.cpu_leaves
+    if (np.sort(np.where(cl >= 0, cl, L), axis=1)
+            != np.sort(np.where(in_cpu, own.leaves, L), axis=1)[:, :cl.shape[1]]
+            ).any() or in_cpu.sum(axis=1).max(initial=0) > cl.shape[1]:
+        bad("own CPU columns are not the config's own CPU-lane leaves")
+
+
 def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
     """Dtype/shape contracts of the device operand pytrees, for ALL lanes.
     Host-only build (to_device(host=True)): no device, no transfer."""
@@ -380,7 +507,7 @@ def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
     G, E = policy.eval_rule.shape
     for lane in ("gather", "matmul", "fused"):
         try:
-            params = to_device(policy, host=True, lane=lane)
+            params = to_device(policy, host=True, lane=lane, dense=True)
         except Exception as e:
             out.append(_err("lane-contract",
                             f"{lane} lane operand build failed: {e!r}",
@@ -393,12 +520,14 @@ def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
                             f"leaf_op must be int32 [L={L}], got "
                             f"{params['leaf_op'].dtype} "
                             f"{params['leaf_op'].shape}", loc))
-        csi = params["cpu_scatter_idx"]
+        csi = params["own_cpu_leaf"]
         # padding columns target the dump slot at L (sliced off on device);
         # anything past it clobbers memory the kernel never wrote
-        if csi.size and (int(csi.min()) < 0 or int(csi.max()) > L):
+        if csi.shape != (G, policy.n_own_cpu) or (csi.size and (
+                int(csi.min()) < 0 or int(csi.max()) > L)):
             out.append(_err("lane-contract",
-                            f"cpu_scatter_idx outside [0, L={L}]", loc))
+                            f"own_cpu_leaf must index [0, L={L}] over "
+                            f"[G={G}, c_own={policy.n_own_cpu}]", loc))
         msl = params["member_slot_of_leaf"]
         if msl.shape != (L,) or (msl.size and (
                 int(msl.min()) < 0
@@ -449,7 +578,6 @@ def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
         expect = {
             "attr_onehot": (A, L),
             "memb_onehot": (policy.n_member_attrs, L),
-            "cpu_oh": (policy.n_cpu_leaves, L),
             "rule_m": (G * E, B),
             "cond_m": (G * E, B),
         }
@@ -541,7 +669,7 @@ def lint_device_batch(policy: CompiledPolicy, db: Any) -> List[Finding]:
     grid = {
         "attrs_val": (B, policy.n_attrs),
         "members_c": (B, policy.n_member_attrs, policy.members_k),
-        "cpu_dense": (B, policy.n_cpu_leaves),
+        "cpu_dense": (B, policy.n_own_cpu),
         "config_id": (B,),
         "host_fallback": (B,),
     }
@@ -595,6 +723,8 @@ def tensor_lint(policy: CompiledPolicy,
     _check_fused_layout(policy, out)
     if not out:
         _check_own_rows(policy, out)
+    if not out:
+        _check_own_layout(policy, out)
     if check_lanes and not out:
         # lane builds index through the arrays checked above; skip when the
         # base layout is already broken (they would raise, not report)
@@ -609,6 +739,7 @@ def _shard_grid_sig(p: CompiledPolicy) -> tuple:
         p.n_attrs, p.n_leaves, p.n_member_attrs, p.members_k,
         p.n_cpu_leaves, p.n_byte_attrs, p.buffer_size,
         tuple(p.eval_rule.shape), tuple(p.config_dfa_rows.shape),
+        p.own.shape_key(),
         tuple((tuple(children.shape), int(is_and.shape[0]))
               for children, is_and in p.levels),
         int(getattr(p, "n_num_attrs", 0) or 0),

@@ -645,15 +645,19 @@ def _rows_in_range(policy: CompiledPolicy) -> bool:
 
 
 def _own_rows_findings(policy: CompiledPolicy) -> List[Finding]:
-    """Own-row scan audit (ISSUE 26, once per snapshot): ``config_dfa_rows``
-    against the circuit, by the tensor lint's own walk.  The served kernel
-    scans only the rows the table names for a request's config; a row the
-    circuit reaches but the table lacks reads False, which a truth-table
-    over atoms cannot see."""
-    from .tensor_lint import _check_own_rows
+    """Own-config audit (ISSUEs 26 and 28, once per snapshot):
+    ``config_dfa_rows`` and the ``OwnLayout`` tables against the circuit, by
+    the tensor lint's own checks.  The served kernel evaluates only what
+    these tables name for a request's config; a DFA row the table lacks
+    reads False, and an own leaf or node that stands for the wrong corpus
+    slot answers another question — neither of which a truth-table over the
+    corpus arrays can see."""
+    from .tensor_lint import _check_own_layout, _check_own_rows
 
     found: List[Finding] = []
     _check_own_rows(policy, found)
+    if not found:
+        _check_own_layout(policy, found)
     return [_err("own-rows-layout", f.message, f.location, **f.detail)
             for f in found]
 
@@ -1377,6 +1381,34 @@ def _mut_own_row_dropped(p: CompiledPolicy) -> None:
     p.config_dfa_rows[g, int((table[g] >= 0).sum()) - 1] = -1
 
 
+def _mut_own_leaf_rebound(p: CompiledPolicy) -> None:
+    """Rebind one own-table leaf to another constant (ISSUE 28): the corpus
+    arrays stay right and the served entry, which reads only the own tables,
+    compares that config's requests against the wrong value."""
+    from ..compiler.compile import OWN_CONST
+
+    own = p.own
+    g, j = (int(x[0]) for x in np.nonzero(own.leaves >= 0))
+    own.leaf_tab = own.leaf_tab.copy()
+    own.leaf_tab[g, j, OWN_CONST] += 1
+
+
+def _upstream(mutate):
+    """A miscompile planted in the corpus arrays, upstream of the layout the
+    compiler derives from them: the own-config tables follow, as they would
+    in a real compile, so it is the certifier and not the layout lint that
+    has to see it."""
+    def planted(p: CompiledPolicy) -> None:
+        from ..compiler.compile import derive_own_layout
+
+        mutate(p)
+        p.own = derive_own_layout(p)
+
+    planted.__name__ = mutate.__name__
+    planted.__doc__ = mutate.__doc__
+    return planted
+
+
 def _mut_fused_int8_corrupt(p: CompiledPolicy) -> None:
     """Nudge one packed int8 op code so it no longer mirrors leaf_op (the
     affected leaf routes through the wrong comparison in the fused lane
@@ -1394,10 +1426,10 @@ def _mut_fused_packw_corrupt(p: CompiledPolicy) -> None:
 
 
 _MUTANTS = (
-    ("circuit-child-flip", _mut_circuit_child_flip),
-    ("eval-rule-redirect", _mut_eval_rule_redirect),
-    ("leaf-attr-swap", _mut_leaf_attr_swap),
-    ("leaf-const-swap", _mut_leaf_const_swap),
+    ("circuit-child-flip", _upstream(_mut_circuit_child_flip)),
+    ("eval-rule-redirect", _upstream(_mut_eval_rule_redirect)),
+    ("leaf-attr-swap", _upstream(_mut_leaf_attr_swap)),
+    ("leaf-const-swap", _upstream(_mut_leaf_const_swap)),
     ("dfa-transition-corrupt", _mut_dfa_transition),
     ("dfa-accept-flip", _mut_dfa_accept_flip),
     ("dfa-pad-corrupt", _mut_dfa_pad_corrupt),
@@ -1408,6 +1440,8 @@ _MUTANTS = (
     ("fused-packw-corrupt", _mut_fused_packw_corrupt),
     # ISSUE 26 own-row scan layout (caught by _own_rows_findings)
     ("own-row-dropped", _mut_own_row_dropped),
+    # ISSUE 28 own-config tables (caught by _own_rows_findings)
+    ("own-leaf-rebound", _mut_own_leaf_rebound),
 )
 
 
@@ -1487,10 +1521,10 @@ def _mut_numeric_slot_collision(p: CompiledPolicy) -> None:
 
 _RELATION_MUTANTS = (
     ("relation-bit-flip", _mut_relation_bit_flip),
-    ("relation-col-redirect", _mut_relation_col_redirect),
-    ("numeric-const-corrupt", _mut_numeric_const),
-    ("numeric-op-flip", _mut_numeric_op_flip),
-    ("numeric-slot-collision", _mut_numeric_slot_collision),
+    ("relation-col-redirect", _upstream(_mut_relation_col_redirect)),
+    ("numeric-const-corrupt", _upstream(_mut_numeric_const)),
+    ("numeric-op-flip", _upstream(_mut_numeric_op_flip)),
+    ("numeric-slot-collision", _upstream(_mut_numeric_slot_collision)),
 )
 
 

@@ -55,7 +55,7 @@ __all__ = [
     "OP_EQ", "OP_NEQ", "OP_INCL", "OP_EXCL", "OP_CPU", "OP_ERROR", "OP_TREE_CPU",
     "OP_REGEX_DFA", "OP_NUM_GT", "OP_NUM_GE", "OP_NUM_LT", "OP_NUM_LE",
     "OP_RELATION", "NUMERIC_OPS",
-    "ConfigRules", "CompiledPolicy", "ShapeTargets", "compile_corpus",
+    "ConfigRules", "CompiledPolicy", "ShapeTargets", "OwnLayout", "compile_corpus",
     "TRUE_SLOT", "FALSE_SLOT", "DFA_VALUE_BYTES",
 ]
 
@@ -130,6 +130,11 @@ class ShapeTargets:
     n_dfa_tables: int = 1
     # most DFA rows any one config's circuit reaches (config_dfa_rows' D)
     n_own_dfa_rows: int = 1
+    # own-config layout (OwnLayout): most leaves, CPU columns and per-level
+    # nodes any one config's circuit reaches
+    n_own_leaves: int = 1
+    n_own_cpu: int = 1
+    own_level_rows: Tuple[int, ...] = ()
     # eval-table rows (configs per shard) — unified so per-shard device
     # pytrees (incl. the matmul lane's [G*E, cursor] one-hots) stack
     n_configs: int = 1
@@ -162,6 +167,12 @@ class ShapeTargets:
             n_byte_attrs=max(s.n_byte_attrs for s in shapes),
             n_dfa_tables=max(s.n_dfa_tables for s in shapes),
             n_own_dfa_rows=max(s.n_own_dfa_rows for s in shapes),
+            n_own_leaves=max(s.n_own_leaves for s in shapes),
+            n_own_cpu=max(s.n_own_cpu for s in shapes),
+            own_level_rows=tuple(
+                max((s.own_level_rows[l] for s in shapes
+                     if l < len(s.own_level_rows)), default=1)
+                for l in range(n_levels)),
             n_configs=max(s.n_configs for s in shapes),
             n_num_attrs=max(s.n_num_attrs for s in shapes),
             n_rel_slots=max(s.n_rel_slots for s in shapes),
@@ -317,10 +328,21 @@ class CompiledPolicy:
     # certifier); only ShapeTargets.n_own_dfa_rows widens it, so shards stack.
     config_dfa_rows: np.ndarray = None   # [G, D] int32 (-1 = no row)
 
+    # --- own-config layout (ISSUE 28) ---
+    # per config row, the leaves, circuit nodes, evaluator references and
+    # CPU-lane columns its evaluators reach, in a buffer of the config's
+    # own: what the served entry evaluates for a request and what the row
+    # payload's CPU columns mean.  Derived like config_dfa_rows.
+    own: "OwnLayout" = None
+
     def __post_init__(self) -> None:
-        if self.config_dfa_rows is None and self.eval_rule is not None \
-                and self.leaf_dfa_row is not None:
-            self.config_dfa_rows = derive_config_dfa_rows(self)
+        if self.eval_rule is not None and self.leaf_dfa_row is not None \
+                and (self.config_dfa_rows is None or self.own is None):
+            reach = own_reach(self)
+            if self.config_dfa_rows is None:
+                self.config_dfa_rows = derive_config_dfa_rows(self, reach)
+            if self.own is None:
+                self.own = derive_own_layout(self, reach)
         if self.dfa_row_perm is None and self.dfa_table_of_row is not None:
             self.dfa_row_perm = np.argsort(
                 self.dfa_table_of_row, kind="stable").astype(np.int32)
@@ -375,6 +397,11 @@ class CompiledPolicy:
         return int(self.eval_rule.shape[0])
 
     @property
+    def n_own_cpu(self) -> int:
+        """Columns of the row payload's CPU lane (c_own)."""
+        return int(self.own.cpu_leaves.shape[1])
+
+    @property
     def buffer_size(self) -> int:
         return _LEAF_BASE + self.n_leaves + sum(lv[0].shape[0] for lv in self.levels)
 
@@ -393,6 +420,7 @@ class CompiledPolicy:
             tuple(self.rel_bits.shape) if self.rel_bits is not None else (),
             bool(self.ovf_assist),
             int(self.config_dfa_rows.shape[1]),
+            self.own.shape_key(),
         )
 
     def shape_targets(self) -> ShapeTargets:
@@ -408,6 +436,9 @@ class CompiledPolicy:
             n_byte_attrs=self.n_byte_attrs,
             n_dfa_tables=int(self.dfa_tables.shape[0]),
             n_own_dfa_rows=int(self.config_dfa_rows.shape[1]),
+            n_own_leaves=int(self.own.leaves.shape[1]),
+            n_own_cpu=self.n_own_cpu,
+            own_level_rows=tuple(int(n.shape[1]) for n in self.own.nodes),
             n_configs=self.n_configs,
             n_num_attrs=self.n_num_attrs,
             n_rel_slots=self.n_rel_slots,
@@ -418,35 +449,174 @@ class CompiledPolicy:
         )
 
 
-def derive_config_dfa_rows(policy: "CompiledPolicy") -> np.ndarray:
-    """[G, D] int32: for each config row the DFA rows reachable from its
-    ``eval_cond`` / ``eval_rule`` references through ``levels`` down to
-    ``OP_REGEX_DFA`` leaves, ascending, padded with -1.  D is the natural
-    maximum (at least 1): a bucket would multiply the own-row scan.
+# fields of OwnLayout.leaf_tab's last axis
+(OWN_OP, OWN_ATTR, OWN_CONST, OWN_MEMBER, OWN_DFA, OWN_BYTE, OWN_CPU,
+ OWN_NUM, OWN_REL_SLOT, OWN_REL_COL) = range(10)
+OWN_FIELDS = 10
+
+
+@dataclass
+class OwnLayout:
+    """One config's slice of the corpus, for every config: the served entry
+    gathers row ``config_id`` of each table and evaluates [B, l_own] leaves,
+    [B, n_own] nodes a level and [B, E] evaluators in a buffer of the
+    config's own: TRUE, FALSE, its leaves (ascending global index), then
+    its nodes level by level (ascending global row).  Leaves and nodes are
+    shared across configs, so these are per-config tables, not a partition.
+    Every axis is the natural maximum over configs; only ShapeTargets widens
+    them, so shards stack.  Padding: leaves read OP_ERROR (False), nodes are
+    an Or of FALSE, and nothing references either."""
+
+    leaves: np.ndarray      # [G, l_own] int32 global leaf idx (-1 pad)
+    nodes: Tuple[np.ndarray, ...]  # per level [G, n_own] int32 global row (-1 pad)
+    # per own leaf: op, attr, const, member slot, position in
+    # config_dfa_rows[g], byte slot, column of the row's CPU payload,
+    # numeric slot, relation slot, relation column (-1: the leaf has none)
+    leaf_tab: np.ndarray    # [G, l_own, OWN_FIELDS] int32
+    # per level (children [G, n_own, width] int32, is_and [G, n_own] bool),
+    # children in own-buffer coordinates
+    levels: Tuple[Tuple[np.ndarray, np.ndarray], ...]
+    evals: np.ndarray       # [G, 3, E] int32: rule, cond (own coords), has_cond
+    cpu_leaves: np.ndarray  # [G, c_own] int32 global leaf idx (-1 pad)
+
+    def shape_key(self) -> tuple:
+        return (self.leaf_tab.shape, self.cpu_leaves.shape,
+                tuple(c.shape for c, _ in self.levels))
+
+
+def _level_starts(policy: "CompiledPolicy") -> List[int]:
+    """Buffer slot of each level's first node (and one past the last)."""
+    starts = [_LEAF_BASE + policy.n_leaves]
+    for children, _ in policy.levels:
+        starts.append(starts[-1] + int(children.shape[0]))
+    return starts
+
+
+def own_reach(policy: "CompiledPolicy") -> List[Tuple[List[int], List[List[int]]]]:
+    """Per config row: (leaves, nodes per level), ascending, reachable from
+    its ``eval_cond`` / ``eval_rule`` references through ``levels``.
     Children reference strictly earlier buffer slots (tensor_lint
-    circuit-order), so one pass over the levels closes the reachability."""
-    L = policy.n_leaves
-    dfa_leaves = np.nonzero(policy.leaf_op == OP_REGEX_DFA)[0]
-    if not dfa_leaves.size:
-        return np.full((policy.n_configs, 1), -1, dtype=np.int32)
-    none: frozenset = frozenset()
-    reach: List[frozenset] = [none] * (_LEAF_BASE + L)
-    for leaf in dfa_leaves:
-        reach[_LEAF_BASE + int(leaf)] = frozenset((int(policy.leaf_dfa_row[leaf]),))
-    for children, _is_and in policy.levels:
-        for kids in children.tolist():
-            acc = none
-            for k in kids:
-                if reach[k]:
-                    acc = acc | reach[k]
-            reach.append(acc)
+    circuit-order), so the walk ends."""
+    from bisect import bisect_right
+
+    starts = _level_starts(policy)
+    first_node = starts[0]
+    kids = [children.tolist() for children, _ in policy.levels]
     refs = np.concatenate([policy.eval_cond, policy.eval_rule], axis=1).tolist()
-    own = [sorted(frozenset().union(*(reach[r] for r in row))) for row in refs]
-    D = max(max((len(o) for o in own), default=0), 1)
-    out = np.full((len(own), D), -1, dtype=np.int32)
-    for g, o in enumerate(own):
-        out[g, : len(o)] = o
+    out = []
+    for row in refs:
+        leaves: set = set()
+        nodes: List[set] = [set() for _ in kids]
+        stack = [r for r in set(row) if r >= _LEAF_BASE]
+        while stack:
+            slot = stack.pop()
+            if slot < first_node:
+                leaves.add(slot - _LEAF_BASE)
+                continue
+            level = bisect_right(starts, slot) - 1
+            r = slot - starts[level]
+            if r not in nodes[level]:
+                nodes[level].add(r)
+                stack.extend(k for k in kids[level][r] if k >= _LEAF_BASE)
+        out.append((sorted(leaves), [sorted(n) for n in nodes]))
     return out
+
+
+def _padded(lists: Sequence[Sequence[int]], width: int) -> np.ndarray:
+    out = np.full((len(lists), width), -1, dtype=np.int32)
+    for g, l in enumerate(lists):
+        out[g, : len(l)] = l
+    return out
+
+
+def derive_config_dfa_rows(policy: "CompiledPolicy", reach=None) -> np.ndarray:
+    """[G, D] int32: for each config row the DFA rows of the
+    ``OP_REGEX_DFA`` leaves it reaches, ascending, padded with -1.  D is the
+    natural maximum (at least 1): a bucket would multiply the own-row scan."""
+    reach = own_reach(policy) if reach is None else reach
+    is_dfa = policy.leaf_op == OP_REGEX_DFA
+    own = [sorted({int(policy.leaf_dfa_row[l]) for l in leaves if is_dfa[l]})
+           for leaves, _ in reach]
+    return _padded(own, max(max((len(o) for o in own), default=0), 1))
+
+
+def derive_own_layout(policy: "CompiledPolicy", reach=None,
+                      targets: Optional[ShapeTargets] = None) -> OwnLayout:
+    """The per-config tables of ``OwnLayout`` from the corpus's own arrays
+    (needs ``config_dfa_rows``).  ``targets`` widens the axes."""
+    reach = own_reach(policy) if reach is None else reach
+    G, E = policy.eval_rule.shape
+    n_levels = len(policy.levels)
+    cpu_set = set(policy.cpu_leaf_list.tolist())
+    own_cpu = [[l for l in leaves if l in cpu_set] for leaves, _ in reach]
+    l_own = max(max((len(lv) for lv, _ in reach), default=0), 1)
+    c_own = max(max((len(c) for c in own_cpu), default=0), 1)
+    n_own = [max(max((len(nd[k]) for _, nd in reach), default=0), 1)
+             for k in range(n_levels)]
+    if targets is not None:
+        assert targets.n_own_leaves >= l_own and targets.n_own_cpu >= c_own \
+            and all(t >= n for t, n in zip(targets.own_level_rows, n_own)), \
+            "targets' own-config axes too small"
+        l_own, c_own = targets.n_own_leaves, targets.n_own_cpu
+        n_own = list(targets.own_level_rows[:n_levels])
+    leaves = _padded([lv for lv, _ in reach], l_own)
+    nodes = tuple(_padded([nd[k] for _, nd in reach], n_own[k])
+                  for k in range(n_levels))
+    cpu_leaves = _padded(own_cpu, c_own)
+
+    # per-leaf fields, gathered from the corpus arrays
+    has = leaves >= 0
+    lf = np.maximum(leaves, 0)
+    attr = policy.leaf_attr[lf]
+    op = policy.leaf_op[lf]
+    tab = np.full((G, l_own, OWN_FIELDS), -1, dtype=np.int32)
+    tab[..., OWN_OP] = np.where(has, op, OP_ERROR)
+    tab[..., OWN_ATTR] = np.where(has, attr, -1)
+    tab[..., OWN_CONST] = np.where(has, policy.leaf_const[lf], 0)
+    tab[..., OWN_MEMBER] = np.where(has, policy.member_attr_slot[attr], -1)
+    is_dfa = has & (op == OP_REGEX_DFA)
+    if is_dfa.any():
+        row = policy.leaf_dfa_row[lf]
+        pos = (policy.config_dfa_rows[:, None, :] == row[:, :, None]).argmax(-1)
+        tab[..., OWN_DFA] = np.where(is_dfa, pos, -1)
+        tab[..., OWN_BYTE] = np.where(
+            is_dfa, np.maximum(policy.attr_byte_slot[attr], 0), -1)
+    cpu_pos = (cpu_leaves[:, None, :] == leaves[:, :, None])
+    tab[..., OWN_CPU] = np.where(has & cpu_pos.any(-1), cpu_pos.argmax(-1), -1)
+    if policy.n_num_attrs:
+        tab[..., OWN_NUM] = np.where(has, policy.num_attr_slot[attr], -1)
+    if policy.n_rel_slots:
+        is_rel = has & (op == OP_RELATION)
+        tab[..., OWN_REL_SLOT] = np.where(is_rel, policy.leaf_rel_slot[lf], -1)
+        tab[..., OWN_REL_COL] = np.where(is_rel, policy.leaf_rel_col[lf], 0)
+
+    # global buffer slot -> own-buffer position, one config at a time
+    starts = _level_starts(policy)
+    bases = [_LEAF_BASE + l_own]
+    for n in n_own:
+        bases.append(bases[-1] + n)
+    own_levels = [
+        (np.full((G, n_own[k], int(policy.levels[k][0].shape[1])), FALSE_SLOT,
+                 dtype=np.int32), np.zeros((G, n_own[k]), dtype=bool))
+        for k in range(n_levels)]
+    evals = np.empty((G, 3, E), dtype=np.int32)
+    evals[:, 2] = policy.eval_has_cond
+    for g, (lv, nd) in enumerate(reach):
+        pos = {TRUE_SLOT: TRUE_SLOT, FALSE_SLOT: FALSE_SLOT}
+        pos.update((_LEAF_BASE + l, _LEAF_BASE + j) for j, l in enumerate(lv))
+        for k in range(n_levels):
+            pos.update((starts[k] + r, bases[k] + j)
+                       for j, r in enumerate(nd[k]))
+        for k in range(n_levels):
+            children, is_and = policy.levels[k]
+            for j, r in enumerate(nd[k]):
+                own_levels[k][0][g, j] = [pos[c] for c in children[r].tolist()]
+                own_levels[k][1][g, j] = is_and[r]
+        evals[g, 0] = [pos[r] for r in policy.eval_rule[g].tolist()]
+        evals[g, 1] = [pos[r] for r in policy.eval_cond[g].tolist()]
+    return OwnLayout(leaves=leaves, nodes=nodes, leaf_tab=tab,
+                     levels=tuple(own_levels), evals=evals,
+                     cpu_leaves=cpu_leaves)
 
 
 def _round_up(n: int, multiple: int = 8, minimum: int = 8) -> int:
@@ -790,7 +960,11 @@ def compile_corpus(
     # stack (padded rows/tables are never referenced; padded states
     # self-loop).
     R = len(dfa_rows)
-    S = max((d.n_states for _, d in dfa_rows), default=1)
+    # the state axis in whole device tiles (8 sublanes): the served entry
+    # gathers [S, 256] tables by row, and a store whose S is not a multiple
+    # of 8 is copied whole into a padded layout on every launch (87 MB a
+    # launch at 20,000 tables of 17 states: PERF.md, PR 28)
+    S = -(-max((d.n_states for _, d in dfa_rows), default=1) // 8) * 8
     Rp = max(R, 1)
     if targets is not None:
         assert targets.n_dfa_rows >= Rp, "targets.n_dfa_rows too small"
@@ -1037,4 +1211,5 @@ def compile_corpus(
         policy.config_dfa_rows = np.pad(
             own, ((0, 0), (0, targets.n_own_dfa_rows - own.shape[1])),
             constant_values=-1)
+        policy.own = derive_own_layout(policy, targets=targets)
     return policy

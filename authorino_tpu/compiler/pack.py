@@ -6,8 +6,10 @@ semantic clarity — [B, A, K] membership for every attr, a [B, L] CPU lane —
 but the kernel can only ever *read*:
 
   - membership vectors of attrs with an incl/excl leaf  → [B, M, K], M ≤ A
-  - CPU-lane booleans of true-CPU leaves (regex fallback, whole-tree
-    oracle) and DFA leaves' byte-overflow columns        → [B, C], C ≪ L
+  - CPU-lane booleans of the request's OWN config's true-CPU leaves
+    (regex fallback, whole-tree oracle) and DFA leaves' byte-overflow
+    columns: column j of row b is leaf own.cpu_leaves[config_id[b], j]
+    (compiler/compile.py OwnLayout)                      → [B, c_own]
 
 Everything else is dead weight on the wire (the [B, L] lane alone is ~8KB per
 request at 10k rules).  This module slices the payload down to what the
@@ -50,7 +52,7 @@ class DeviceBatch:
 
     attrs_val: np.ndarray      # [B, A] int16/int32 (wire dtype)
     members_c: np.ndarray      # [B, M, K] int16/int32 — compact membership
-    cpu_dense: np.ndarray      # [B, C] bool — dense CPU-lane columns
+    cpu_dense: np.ndarray      # [B, c_own] bool — own config's CPU-lane columns
     config_id: np.ndarray      # [B] int32
     attr_bytes: Optional[np.ndarray]  # [B, NB, LB] uint8 (None: no DFA lane)
     byte_ovf: Optional[np.ndarray]    # [B, NB] bool
@@ -91,19 +93,15 @@ def pack_batch(policy: CompiledPolicy, enc: EncodedBatch,
     skips the byte-column trim — the sharded model assembles per-shard
     batches into one tensor and trims once at the end instead."""
     B = enc.attrs_val.shape[0]
-    M, C, K = policy.n_member_attrs, policy.n_cpu_leaves, policy.members_k
+    M, K = policy.n_member_attrs, policy.members_k
     dt = wire_dtype(policy)
 
     member_attrs = policy.member_attrs
     m_real = member_attrs.shape[0]
-    c_real = policy.cpu_leaf_list.shape[0]
     if m_real > M:
         raise PackError(
             f"{m_real} member attrs exceed the padded grid M={M} "
             "(compile targets too small for this corpus)")
-    if c_real > C:
-        raise PackError(
-            f"{c_real} CPU-lane leaves exceed the padded grid C={C}")
     if dt == np.int16:
         # the wire narrows ids to int16 when the interner fits; an id past
         # that range would silently WRAP on .astype — a wrong operand, not
@@ -127,12 +125,13 @@ def pack_batch(policy: CompiledPolicy, enc: EncodedBatch,
         members_c = np.full((B, M, K), PAD, dtype=dt)
         members_c[:, :m_real] = enc.attrs_members[:, member_attrs]
 
-    cpu_list = policy.cpu_leaf_list
-    if C == c_real:
-        cpu_dense = np.ascontiguousarray(enc.cpu_lane[:, cpu_list])
-    else:
-        cpu_dense = np.zeros((B, C), dtype=bool)
-        cpu_dense[:, :c_real] = enc.cpu_lane[:, cpu_list]
+    # the own config's CPU columns; a config id outside [0, G) owns none
+    G = policy.n_configs
+    cfg = np.asarray(enc.config_id)
+    cols = policy.own.cpu_leaves[np.clip(cfg, 0, G - 1)]          # [B, c_own]
+    cols = np.where(((cfg >= 0) & (cfg < G))[:, None], cols, -1)
+    cpu_dense = np.take_along_axis(
+        enc.cpu_lane, np.maximum(cols, 0), axis=1) & (cols >= 0)
 
     # membership overflow on an attr the kernel reads: without the assist
     # the compact form is lossy for this request → host oracle; WITH the

@@ -85,7 +85,7 @@ class PolicyModel:
 
     def __init__(self, policy: CompiledPolicy, device=None):
         self.policy = policy
-        self.params = to_device(policy, device=device)
+        self.params = to_device(policy, device=device, dense=True)
         # module-level jit: identical-shape models share one trace cache
         self._apply = _eval_jit
 

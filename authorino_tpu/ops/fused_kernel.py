@@ -82,7 +82,7 @@ def fused_operands(policy: CompiledPolicy, dfa_byte_slot: np.ndarray) -> dict:
     return fz
 
 
-def _eval_verdicts_fused(params, attrs_val, members_c, cpu_dense,
+def _eval_verdicts_fused(params, attrs_val, members_c, cpu_dense, config_id,
                          attr_bytes=None, byte_ovf=None, attrs_num=None,
                          num_valid=None, rel_rows=None, member_ovf=None):
     """Gather-lane semantics on the fused layout.  Differences from
@@ -102,7 +102,7 @@ def _eval_verdicts_fused(params, attrs_val, members_c, cpu_dense,
     eq = val == leaf_const[None, :]
     memb = jnp.take(members_c, params["member_slot_of_leaf"], axis=1)
     incl = jnp.any(memb == leaf_const[None, :, None], axis=-1)
-    cpu_lane = pe._cpu_full(params, cpu_dense)
+    cpu_lane = pe._cpu_full(params, cpu_dense, config_id)
 
     if params["dfa_tables"] is not None and attr_bytes is not None:
         tables = params["dfa_tables"]            # [T, S, 256] uint8 (deduped)
@@ -177,7 +177,8 @@ def _fused_packed(params, ops: dict):
     the per-operand staging) produces; absent lanes are absent keys."""
     verdict, (rule, skipped) = _eval_verdicts_fused(
         params, ops["attrs_val"], ops["members_c"], ops["cpu_dense"],
-        ops.get("attr_bytes"), ops.get("byte_ovf"), ops.get("attrs_num"),
+        ops["config_id"], ops.get("attr_bytes"), ops.get("byte_ovf"),
+        ops.get("attrs_num"),
         ops.get("num_valid"), ops.get("rel_rows"), ops.get("member_ovf"))
     own_mask = pe._select_own(ops["config_id"], verdict.shape[1])
     own = jnp.any(verdict & own_mask, axis=1)
@@ -308,7 +309,7 @@ def dispatch_megakernel(params, db) -> "jax.Array":
 
 
 @jax.jit
-def _stage_leaves(params, attrs_val, members_c, cpu_dense):
+def _stage_leaves(params, attrs_val, members_c, cpu_dense, config_id):
     if attrs_val.dtype != jnp.int32:
         attrs_val = attrs_val.astype(jnp.int32)
     if members_c.dtype != jnp.int32:
@@ -317,7 +318,7 @@ def _stage_leaves(params, attrs_val, members_c, cpu_dense):
     eq = val == params["leaf_const"][None, :]
     memb = jnp.take(members_c, params["member_slot_of_leaf"], axis=1)
     incl = jnp.any(memb == params["leaf_const"][None, :, None], axis=-1)
-    return eq, incl, pe._cpu_full(params, cpu_dense)
+    return eq, incl, pe._cpu_full(params, cpu_dense, config_id)
 
 
 @jax.jit
@@ -419,7 +420,7 @@ def dispatch_staged(params, db, ledger_lane: Optional[str] = None):
 
     eq, incl, cpu_lane = _stage_leaves(
         params, jnp.asarray(db.attrs_val), jnp.asarray(db.members_c),
-        jnp.asarray(db.cpu_dense))
+        jnp.asarray(db.cpu_dense), jnp.asarray(db.config_id))
     obs()
     if params["dfa_tables"] is not None and db.attr_bytes is not None:
         dfa_leaf_val = _stage_dfa(params, jnp.asarray(db.attr_bytes),
@@ -456,7 +457,7 @@ def _zero_db(policy: CompiledPolicy, pad: int, eff: int):
 
     dt = wire_dtype(policy)
     A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
-    C, NB = policy.n_cpu_leaves, max(policy.n_byte_attrs, 1)
+    C, NB = policy.n_own_cpu, max(policy.n_byte_attrs, 1)
     NN = getattr(policy, "n_num_attrs", 0)
     NR = getattr(policy, "n_rel_slots", 0)
     return SimpleNamespace(

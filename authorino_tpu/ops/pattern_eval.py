@@ -1,21 +1,22 @@
 """Batched policy-evaluation kernel (pure JAX/XLA; static shapes, no
 data-dependent control flow).
 
-One call evaluates a micro-batch of requests against the *entire* compiled
-rule corpus and returns per-request per-config allow verdicts.  This replaces
+One call evaluates a micro-batch of requests against the compiled rule
+corpus: every config's verdict for every request (the dense bodies), or each
+request's own config's alone (``eval_own``, what serves).  This replaces
 the reference's per-request goroutine fan-out + per-pattern gjson walk
 (ref: pkg/service/auth_pipeline.go:150-182, pkg/jsonexp/expressions.go:59):
 equal-priority rules across all configs fuse into one kernel launch
 (SURVEY.md §2 P1/P2 mapping).
 
 Inputs are the *compact* device payload (compiler/pack.py): [B, A] attr ids,
-[B, M, K] membership rows for incl/excl attrs only, a [B, C] dense CPU lane
-(C = true-CPU + DFA leaves, not the full leaf axis), and the DFA byte
-tensors.  The wire format carries only what the kernel reads, and results
+[B, M, K] membership rows for incl/excl attrs only, a [B, c_own] CPU lane
+(the request's own config's true-CPU + DFA leaves, not the leaf axis), and
+the DFA byte tensors.  The wire format carries only what the kernel reads, and results
 return as one packed matrix: every byte crosses the host↔device link
 (PCIe), whose share of a batch has not been measured on the current code.
 
-Two lanes:
+Two lanes of the dense body:
 
   - ``matmul`` (default): gathers are pathological on TPU (they lower to
     scalar-unit loops), so every gather is reformulated as a one-hot matmul
@@ -37,27 +38,33 @@ Two lanes:
     for differential tests, and the automatic fallback when the interner
     outgrows exact-f32 range (ids ≥ 2^24).
 
-Lane dispatch is structural: ``to_device`` builds the matmul operands (or
-not), and ``eval_verdicts`` branches on their presence at trace time, so the
-two lanes jit-cache independently.
+Lane dispatch is structural: ``to_device(dense=True)`` builds the matmul
+operands (or not), and ``eval_verdicts`` branches on their presence at trace
+time, so the two lanes jit-cache independently.
 
-Which DFA scan runs is structural too.  A caller that reads only each row's
+Which circuit runs is structural too.  A caller that reads only each row's
 own config (``eval_full_jit`` and, through it, ``eval_packed_jit``,
-``eval_bitpacked_jit`` — the served entry — and ``eval_fused_jit``) passes
-``own_config`` to ``eval_verdicts``, and both lax lanes then run ONE body,
-``_own_dfa_row_res``: the scan covers ``config_dfa_rows[config_id]``, the
-[B, D] DFA rows the request's own config reaches (D = the most any one
-config reaches), not the corpus's [B, R].  Their [S, 256] tables are
-gathered once a launch from the deduplicated ``dfa_tables``; one batched
-matmul with the byte one-hots gives every byte position's S -> S map; the
-``lax.scan`` carries [B, D] and reads [B, D, S] a step; the accepts are
-placed back on the [B, R] row axis, False elsewhere, so everything
-downstream is the same code.  The other configs' columns are then NOT their
-verdicts — ``_select_own`` discards them.  Callers that want every config's
-column (``forward`` / ``_eval_jit`` / ``eval_batch_jit``, the mesh step in
-parallel/sharded_eval.py, models/policy_model.py) pass no ``own_config`` and
-get the dense scan bodies below; the fused lane (ops/fused_kernel.py) has
-one body and is dense.
+``eval_bitpacked_jit`` — the served entry — and ``eval_fused_jit``) runs
+``eval_own`` on both lax lanes: ONE body that gathers row ``config_id`` of
+the corpus's per-config tables (compiler/compile.py ``OwnLayout``) and
+evaluates the request's own [B, l_own] leaves, [B, n_own] circuit nodes a
+level and [B, E] evaluators.  Its DFA scan covers ``config_dfa_rows
+[config_id]``, the [B, D] rows the config reaches: their [S, 256] tables are
+gathered once a launch from the deduplicated ``dfa_tables``, one batched
+matmul with the byte one-hots gives every byte position's S -> S map, the
+``lax.scan`` carries [B, D], and the accepts feed the own leaves directly.
+Reads inside a row (attribute of a leaf, child of a node) are one-hot
+mask-reduces over the small own axes — integer-exact, no gather.  Nothing in
+it grows with the corpus but the tables it gathers one row from, so
+``to_device`` builds the G x L one-hot operands only for callers that want
+every config's column (``dense=True``: ``forward`` / ``_eval_jit`` /
+``eval_batch_jit`` through models/policy_model.py, the mesh step in
+parallel/sharded_eval.py); the fused lane (ops/fused_kernel.py) has one body
+and is dense.
+
+The row payload's CPU lane is own-config too: ``cpu_dense`` is [B, c_own],
+column j the answer of leaf ``own.cpu_leaves[config_id, j]``; the dense
+bodies spread it onto the leaf axis with ``_cpu_full``.
 
 Membership overflow (arrays longer than K) and DFA byte overflow cannot be
 answered from the compact payload per-leaf; overflowed *requests* are flagged
@@ -78,6 +85,17 @@ import numpy as np
 from ..compiler.compile import (
     FALSE_SLOT,
     NUMERIC_OPS,
+    OWN_ATTR,
+    OWN_BYTE,
+    OWN_CONST,
+    OWN_CPU,
+    OWN_DFA,
+    OWN_FIELDS,
+    OWN_MEMBER,
+    OWN_NUM,
+    OWN_OP,
+    OWN_REL_COL,
+    OWN_REL_SLOT,
     OP_CPU,
     OP_EQ,
     OP_ERROR,
@@ -95,7 +113,8 @@ from ..compiler.compile import (
     CompiledPolicy,
 )
 
-__all__ = ["DevicePolicy", "to_device", "eval_verdicts", "eval_batch_jit",
+__all__ = ["DevicePolicy", "to_device", "eval_verdicts", "eval_own",
+           "eval_batch_jit", "kernel_widths", "operand_bytes",
            "fuse_batch", "eval_fused_jit", "dispatch_fused",
            "fused_h2d_supported", "eval_bitpacked_jit", "unpack_verdicts",
            "packed_width", "firing_columns", "unpack_attribution",
@@ -152,14 +171,14 @@ def _mm_dtype(device=None):
 
 
 def _matmul_operands(policy: CompiledPolicy, row_slot: np.ndarray, device=None) -> dict:
-    """One-hot / count matrices for the MXU lane (see module doc).
+    """One-hot / count matrices of the DENSE MXU body (see module doc): they
+    grow with G x L, so ``to_device`` builds them only on request.
     ``row_slot`` is the per-DFA-row byte-tensor slot (shared with the gather
     lane's ``dfa_byte_slot`` so the two lanes can never disagree on which
     byte tensor a row scans)."""
     L = policy.n_leaves
     A = policy.n_attrs
     M = policy.n_member_attrs
-    C = policy.n_cpu_leaves
     cdt = _mm_dtype(device)
     attr_onehot = np.zeros((A, L), dtype=np.float32)
     attr_onehot[policy.leaf_attr, np.arange(L)] = 1.0
@@ -170,12 +189,6 @@ def _matmul_operands(policy: CompiledPolicy, row_slot: np.ndarray, device=None) 
     if is_memb.any():
         slots = policy.member_attr_slot[policy.leaf_attr[is_memb]]
         memb_onehot[slots, np.nonzero(is_memb)[0]] = 1.0
-
-    # dense CPU lane spread: [C] columns → [L] leaf axis
-    cpu_oh = np.zeros((C, L), dtype=np.float32)
-    cl = policy.cpu_leaf_list
-    if cl.shape[0]:
-        cpu_oh[np.arange(cl.shape[0]), cl] = 1.0
 
     # per-level count matrices over the buffer prefix visible to that level
     # (the count threshold — the level's child width — is recovered at trace
@@ -200,7 +213,6 @@ def _matmul_operands(policy: CompiledPolicy, row_slot: np.ndarray, device=None) 
     out = {
         "attr_onehot": attr_onehot,  # f32: exact selection via HIGHEST
         "memb_onehot": memb_onehot,  # f32: exact selection via HIGHEST
-        "cpu_oh": cpu_oh.astype(cdt),
         "level_mats": tuple(level_mats),
         "rule_m": rule_m.astype(cdt),
         "cond_m": cond_m.astype(cdt),
@@ -266,14 +278,17 @@ def _matmul_operands(policy: CompiledPolicy, row_slot: np.ndarray, device=None) 
 
 
 def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
-              host: bool = False) -> dict:
+              host: bool = False, dense: bool = False) -> dict:
     """Upload a compiled corpus's operands as a pytree of device arrays.
     The engine double-buffers these and swaps atomically on reconcile
     (SURVEY.md §3.4: rule-tensor compile + device upload on index Set).
     ``lane`` overrides the env-var lane selection; ``host=True`` keeps the
     operands as host numpy arrays — the sharded model stacks per-shard
     pytrees host-side and transfers each shard's slice exactly once via a
-    mesh-sharded device_put, instead of staging everything on device 0."""
+    mesh-sharded device_put, instead of staging everything on device 0.
+    ``dense=True`` adds the matmul lane's G x L one-hot operands, for
+    callers of the dense body (``eval_verdicts``); without them that body
+    runs the gather formulation, and ``eval_own`` needs neither."""
     if host:
         put = np.asarray
     else:
@@ -286,11 +301,14 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
     # per-dfa-row byte-tensor slot (attr → slot mapping folded in here);
     # shared by all lanes
     dfa_byte_slot = np.maximum(policy.attr_byte_slot[policy.dfa_leaf_attr], 0)
-    mm = (
-        jax.tree.map(put, _matmul_operands(policy, dfa_byte_slot, device=device))
-        if lane == "matmul"
-        else None
-    )
+    mm = None
+    if lane == "matmul":
+        # "mxu": the lane's compute dtype for the target device, as a
+        # zero-length operand (a traced body cannot ask for the platform)
+        mm = {"mxu": put(np.zeros((0,), dtype=_mm_dtype(device)))}
+        if dense:
+            mm.update(jax.tree.map(
+                put, _matmul_operands(policy, dfa_byte_slot, device=device)))
     if lane == "fused":
         from . import fused_kernel as _fk  # lazy: fused_kernel imports us
 
@@ -302,11 +320,12 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
     member_slot_of_leaf = np.maximum(
         policy.member_attr_slot[policy.leaf_attr], 0
     ).astype(np.int32)
-    # scatter targets: dense CPU cols → leaf axis; padding cols land in a
-    # dump slot at L (sliced off) so they can never clobber a real leaf
-    C = policy.n_cpu_leaves
-    cpu_scatter_idx = np.full((C,), L, dtype=np.int32)
-    cpu_scatter_idx[: policy.cpu_leaf_list.shape[0]] = policy.cpu_leaf_list
+    own = policy.own
+    G_own = own.leaves.shape[0]
+    # scatter targets of the row payload's CPU columns, per config; padding
+    # columns land in a dump slot at L (sliced off) so they can never
+    # clobber a real leaf
+    own_cpu_leaf = np.where(own.cpu_leaves >= 0, own.cpu_leaves, L).astype(np.int32)
     # operands are numpy throughout: `put` is the ONLY device transfer (or a
     # no-op for host=True), so nothing ever stages on the default device
     return {
@@ -318,7 +337,18 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
         "leaf_attr": put(policy.leaf_attr),
         "leaf_const": put(policy.leaf_const),
         "member_slot_of_leaf": put(member_slot_of_leaf),
-        "cpu_scatter_idx": put(cpu_scatter_idx),
+        "own_cpu_leaf": put(own_cpu_leaf),
+        # per-config tables of eval_own (compiler/compile.py OwnLayout), one
+        # ROW a config: the device pads the two minor axes of an array to
+        # its tiles, so [G, 10, 10] would take 20 times its bytes and be
+        # copied whole by every launch's gather.  The fused lane has no own
+        # body
+        "own": None if lane == "fused" else {
+            "leaf": put(own.leaf_tab.reshape(G_own, -1)),        # [G, l_own * F]
+            "levels": tuple((put(c.reshape(G_own, -1)), put(a))  # [G, n * w], [G, n]
+                            for c, a in own.levels),
+            "evals": put(own.evals.reshape(G_own, -1)),          # [G, 3 * E]
+        },
         "levels": tuple(
             (put(children), put(is_and))
             for children, is_and in policy.levels
@@ -359,19 +389,27 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
 DevicePolicy = dict
 
 
-def _cpu_full(params, cpu_dense):
-    """Spread the dense [B, C] CPU lane onto the [B, L] leaf axis."""
+def _cpu_full(params, cpu_dense, config_id):
+    """Spread the row payload's [B, c_own] CPU columns onto the [B, L] leaf
+    axis: column j of row b is leaf ``own_cpu_leaf[config_id[b], j]``.  A
+    config id outside [0, G) owns no column."""
     B = cpu_dense.shape[0]
     L = params["leaf_op"].shape[0]
+    table = params["own_cpu_leaf"]                           # [G, c_own]
+    G = table.shape[0]
+    in_range = (config_id >= 0) & (config_id < G)
+    idx = jnp.where(in_range[:, None],
+                    jnp.take(table, jnp.clip(config_id, 0, G - 1), axis=0), L)
     buf = jnp.zeros((B, L + 1), dtype=bool)
-    buf = buf.at[:, params["cpu_scatter_idx"]].set(cpu_dense)
+    buf = buf.at[jnp.arange(B)[:, None], idx].set(cpu_dense)
     return buf[:, :L]
 
 
 def _leaf_op_cascade(leaf_op, eq, incl, dfa_leaf_val, cpu_lane,
                      num_cmp=None, rel_res=None, leaf_movf=None):
     """Shared op-code dispatch: per-leaf boolean results from the lane's
-    primitive comparisons (identical semantics in both lanes).
+    primitive comparisons (identical semantics in every body).  ``leaf_op``
+    is the corpus's [L] or a batch's own [B, l_own].
 
     ``num_cmp`` is the numeric lane's (gt, ge, lt, le) [B, L] quadruple
     (None: no numeric leaves); ``rel_res`` the relation lane's [B, L]
@@ -380,7 +418,7 @@ def _leaf_op_cascade(leaf_op, eq, incl, dfa_leaf_val, cpu_lane,
     their exact precomputed answer from the dense CPU columns — note the
     EXCL branch reads ``cpu_lane`` directly (the encoder stores the final
     excl answer, not the membership bit)."""
-    op = leaf_op[None, :]
+    op = leaf_op[None, :] if leaf_op.ndim == 1 else leaf_op
     if leaf_movf is None:
         incl_eff, excl_eff = incl, ~incl
     else:
@@ -424,37 +462,50 @@ def _verdict_from_tables(params, cond, rule):
     return verdict, (rule, skipped)
 
 
-def dfa_scan_width(params, own: bool = True) -> dict:
-    """What /debug/vars reports of the DFA scan: ``dfa_rows_total`` is the
-    corpus's R; ``dfa_rows_per_row`` is what one request row has scanned —
-    D on the own-row scan (``own``: an entry that returns own-config results
-    on a lax lane), R on the dense scan (``own=False``; the fused lane has no
-    own-row body).  Both 0 without a device DFA lane."""
-    if params.get("dfa_tables") is None:
-        return {"dfa_rows_per_row": 0, "dfa_rows_total": 0}
-    R = int(params["dfa_table_of_row"].shape[-1])
-    D = int(params["config_dfa_rows"].shape[-1])
-    return {"dfa_rows_per_row": D if own and params.get("fused") is None else R,
-            "dfa_rows_total": R}
+def kernel_widths(params, own: bool = True) -> dict:
+    """What /debug/vars reports of one served row's work: ``dfa_rows_total``
+    is the corpus's R and ``dfa_rows_per_row`` what one request row has
+    scanned (D on ``eval_own``, R on a dense body; both 0 without a device
+    DFA lane); ``leaf_cols_per_row`` is the leaf columns evaluated for it
+    (l_own, or L while dense).  ``own``: an entry that returns own-config
+    results on a lax lane (the fused lane has no own body)."""
+    own = own and params.get("own") is not None
+    out = {"dfa_rows_per_row": 0, "dfa_rows_total": 0,
+           "leaf_cols_per_row": int(params["own"]["leaf"].shape[-1]) // OWN_FIELDS if own
+           else int(params["leaf_op"].shape[-1])}
+    if params.get("dfa_tables") is not None:
+        R = int(params["dfa_table_of_row"].shape[-1])
+        out.update(dfa_rows_total=R, dfa_rows_per_row=int(
+            params["config_dfa_rows"].shape[-1]) if own else R)
+    return out
 
 
-def _own_dfa_row_res(params, own_config, attr_bytes, cdt):
-    """Own-row DFA scan, shared by both lax lanes: evaluates only the DFA
-    rows ``config_dfa_rows[own_config]`` names ([B, D]) and returns their
-    accepts placed on the [B, R] row axis, False elsewhere.  Rows of other
-    configs feed only circuits whose results ``_select_own`` discards, so
-    own verdict / rule results / skipped flags equal the dense scan's.  A
-    config id outside [0, G) owns no row."""
+def operand_bytes(params) -> int:
+    """Bytes of an operand pytree, summed over its arrays."""
+    return int(sum(a.nbytes for a in jax.tree_util.tree_leaves(params)))
+
+
+def _pick(x, idx):
+    """``x[b, idx[b, j]]`` for x [B, N, ...] and idx [B, J] -> [B, J, ...];
+    zero / False where idx is outside [0, N).  A one-hot mask-reduce over
+    the (small, own-config) axis N: exact in any dtype and gather-free
+    (gathers serialize on TPU)."""
+    hit = idx[:, :, None] == jnp.arange(x.shape[1], dtype=idx.dtype)  # [B, J, N]
+    hit = hit.reshape(hit.shape + (1,) * (x.ndim - 2))
+    if x.dtype == jnp.bool_:
+        return jnp.any(hit & x[:, None], axis=2)
+    return jnp.sum(jnp.where(hit, x[:, None], 0), axis=2, dtype=x.dtype)
+
+
+def _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt):
+    """Own-row DFA scan: evaluates only the DFA rows ``config_dfa_rows[cfg]``
+    names and returns their accepts [B, D], False on the -1 padding and on
+    rows whose config id was out of range (``cfg`` is the clipped id)."""
     f32 = jnp.float32
-    cfg_rows = params["config_dfa_rows"]                     # [G, D] i32, -1 pad
-    G = cfg_rows.shape[0]
-    R = params["dfa_table_of_row"].shape[0]
     tables = params["dfa_tables"]                            # [T, S, 256] u8
     S = tables.shape[1]
-    in_range = (own_config >= 0) & (own_config < G)
-    own = jnp.where(
-        in_range[:, None],
-        jnp.take(cfg_rows, jnp.clip(own_config, 0, G - 1), axis=0), -1)  # [B, D]
+    own = jnp.where(in_range[:, None],
+                    jnp.take(params["config_dfa_rows"], cfg, axis=0), -1)  # [B, D]
     row = jnp.maximum(own, 0)
     tab = jnp.take(params["dfa_table_of_row"], row)          # [B, D]
     slot = jnp.take(params["dfa_byte_slot"], row)            # [B, D]
@@ -481,10 +532,85 @@ def _own_dfa_row_res(params, own_config, attr_bytes, cdt):
     init = own_bytes[:, :, 0].astype(f32) * 0.0
     final, _ = jax.lax.scan(dfa_step, init, step_maps)
     accept = jnp.take(params["dfa_accept"], tab, axis=0)     # [B, D, S] bool
-    own_res = (own >= 0) & jnp.any(
+    return (own >= 0) & jnp.any(
         accept & (final[..., None] == iota_s), axis=-1)      # [B, D]
-    hit = own[:, :, None] == jnp.arange(R, dtype=own.dtype)  # [B, D, R]
-    return jnp.any(hit & own_res[:, :, None], axis=1)        # [B, R]
+
+
+def eval_own(params, attrs_val, members_c, cpu_dense, config_id,
+             attr_bytes=None, byte_ovf=None, attrs_num=None, num_valid=None,
+             rel_rows=None, member_ovf=None):
+    """Each request against its OWN config only (see module doc): returns
+    (own verdict [B], own rule results [B, E], own skipped flags [B, E]),
+    bit for bit what selecting row ``config_id`` of the dense body's
+    results gives; a config id outside [0, G) reads False throughout."""
+    if attrs_val.dtype != jnp.int32:
+        attrs_val = attrs_val.astype(jnp.int32)
+    if members_c.dtype != jnp.int32:
+        members_c = members_c.astype(jnp.int32)
+    own = params["own"]
+    B = attrs_val.shape[0]
+    G = own["leaf"].shape[0]
+    in_range = (config_id >= 0) & (config_id < G)
+    cfg = jnp.clip(config_id, 0, G - 1)
+
+    with jax.named_scope("own_gather"):
+        tab = jnp.take(own["leaf"], cfg, axis=0).reshape(B, -1, OWN_FIELDS)
+        levels = []
+        for children, is_and in own["levels"]:
+            node_and = jnp.take(is_and, cfg, axis=0)         # [B, n]
+            levels.append((jnp.take(children, cfg, axis=0).reshape(
+                node_and.shape + (-1,)), node_and))          # [B, n, w]
+        evals = jnp.take(own["evals"], cfg, axis=0).reshape(B, 3, -1)
+    const = tab[..., OWN_CONST]                              # [B, l_own]
+
+    with jax.named_scope("own_leaf_compares"):
+        eq = _pick(attrs_val, tab[..., OWN_ATTR]) == const
+        cpu_lane = _pick(cpu_dense, tab[..., OWN_CPU])
+    with jax.named_scope("membership"):
+        memb = _pick(members_c, tab[..., OWN_MEMBER])        # [B, l_own, K]
+        incl = jnp.any(memb == const[..., None], axis=-1)
+        leaf_movf = None
+        if member_ovf is not None:
+            leaf_movf = _pick(member_ovf, tab[..., OWN_MEMBER])
+    with jax.named_scope("dfa_scan"):
+        if params["dfa_tables"] is not None and attr_bytes is not None:
+            mm = params.get("matmul")
+            cdt = mm["mxu"].dtype if mm is not None else jnp.float32
+            own_res = _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt)
+            # overflowed values: exact answer precomputed into the CPU lane
+            dfa_leaf_val = jnp.where(_pick(byte_ovf, tab[..., OWN_BYTE]),
+                                     cpu_lane, _pick(own_res, tab[..., OWN_DFA]))
+        else:
+            dfa_leaf_val = cpu_lane  # regexes ride the CPU lane entirely
+
+    num_cmp = None
+    if params.get("leaf_num_slot") is not None and attrs_num is not None:
+        lv = _pick(attrs_num, tab[..., OWN_NUM])
+        lok = _pick(num_valid, tab[..., OWN_NUM])
+        num_cmp = (lok & (lv > const), lok & (lv >= const),
+                   lok & (lv < const), lok & (lv <= const))
+    rel_res = None
+    if params.get("rel_bits") is not None and rel_rows is not None:
+        col = tab[..., OWN_REL_COL]
+        byte = params["rel_bits"][_pick(rel_rows, tab[..., OWN_REL_SLOT]),
+                                  col >> 3].astype(jnp.int32)
+        rel_res = ((byte >> (col & 7)) & 1) != 0
+
+    with jax.named_scope("own_circuit"):
+        res = _leaf_op_cascade(tab[..., OWN_OP], eq, incl, dfa_leaf_val,
+                               cpu_lane, num_cmp, rel_res, leaf_movf)
+        buffer = jnp.concatenate(
+            [jnp.ones((B, 1), dtype=bool), jnp.zeros((B, 1), dtype=bool), res],
+            axis=1)
+        for children, is_and in levels:                      # [B, n, w], [B, n]
+            ch = _pick(buffer, children.reshape(B, -1)).reshape(children.shape)
+            node = jnp.where(is_and, jnp.all(ch, axis=-1), jnp.any(ch, axis=-1))
+            buffer = jnp.concatenate([buffer, node], axis=1)
+        rule = _pick(buffer, evals[:, 0])                    # [B, E]
+        skipped = (evals[:, 2] != 0) & ~_pick(buffer, evals[:, 1])
+        verdict = jnp.all(skipped | rule, axis=-1)
+        return (verdict & in_range, rule & in_range[:, None],
+                skipped & in_range[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -492,10 +618,9 @@ def _own_dfa_row_res(params, own_config, attr_bytes, cdt):
 # ---------------------------------------------------------------------------
 
 
-def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
+def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense, config_id,
                           attr_bytes, byte_ovf, attrs_num=None,
-                          num_valid=None, rel_rows=None, member_ovf=None,
-                          own_config=None):
+                          num_valid=None, rel_rows=None, member_ovf=None):
     mm = params["matmul"]
     f32 = jnp.float32
     cdt = mm["rule_m"].dtype
@@ -513,46 +638,39 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
         )                                                        # [B, K, L]
         incl = jnp.any(memb == const[None, None, :], axis=1)     # [B, L]
 
-    # ---- dense CPU lane spread onto the leaf axis ------------------------
-    cpu_lane = jnp.matmul(
-        cpu_dense.astype(cdt), mm["cpu_oh"], preferred_element_type=f32
-    ) > 0.5                                                  # [B, L]
+    cpu_lane = _cpu_full(params, cpu_dense, config_id)       # [B, L]
 
     # ---- device regex lane: DFA scan, transitions as batched matmuls -----
     with jax.named_scope("dfa_scan"):
         if params["dfa_tables"] is not None and attr_bytes is not None:
-            if own_config is not None:
-                dfa_row_res = _own_dfa_row_res(
-                    params, own_config, attr_bytes, cdt)         # [B, R]
-            else:
-                tables = mm["dfa_tables_f"]                          # [R, S, 256] bf16
-                R, S = tables.shape[0], tables.shape[1]
-                # spread each row's attr bytes from its slot: [B, NB, LB] → [B, R, LB]
-                row_bytes = jnp.einsum(
-                    "bnl,nr->brl", attr_bytes.astype(cdt), mm["slot_row_oh"],
-                    preferred_element_type=f32,
+            tables = mm["dfa_tables_f"]                          # [R, S, 256] bf16
+            R, S = tables.shape[0], tables.shape[1]
+            # spread each row's attr bytes from its slot: [B, NB, LB] → [B, R, LB]
+            row_bytes = jnp.einsum(
+                "bnl,nr->brl", attr_bytes.astype(cdt), mm["slot_row_oh"],
+                preferred_element_type=f32,
+            )
+            iota_s = jnp.arange(S, dtype=f32)
+            iota_c = jnp.arange(256, dtype=f32)
+
+            def dfa_step(state, byte_col):  # state [B,R] f32; byte_col [B,R] f32
+                byte_oh = (byte_col[..., None] == iota_c).astype(cdt)   # [B,R,256]
+                # per-state next-state given this byte: [R,S,256] × [B,R,256]
+                nxt_by_state = jnp.einsum(
+                    "rsc,brc->brs", tables, byte_oh, preferred_element_type=f32
                 )
-                iota_s = jnp.arange(S, dtype=f32)
-                iota_c = jnp.arange(256, dtype=f32)
+                st_oh = (state[..., None] == iota_s).astype(f32)
+                nxt = jnp.sum(st_oh * nxt_by_state, axis=-1)
+                return nxt, None
 
-                def dfa_step(state, byte_col):  # state [B,R] f32; byte_col [B,R] f32
-                    byte_oh = (byte_col[..., None] == iota_c).astype(cdt)   # [B,R,256]
-                    # per-state next-state given this byte: [R,S,256] × [B,R,256]
-                    nxt_by_state = jnp.einsum(
-                        "rsc,brc->brs", tables, byte_oh, preferred_element_type=f32
-                    )
-                    st_oh = (state[..., None] == iota_s).astype(f32)
-                    nxt = jnp.sum(st_oh * nxt_by_state, axis=-1)
-                    return nxt, None
-
-                # derive the scan's init carry from a varying input (zero-multiplied)
-                # so its manual-mesh "varying" type matches inside shard_map
-                init = row_bytes[:, :, 0] * 0.0
-                final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
-                final_oh = (final[..., None] == iota_s).astype(cdt)
-                dfa_row_res = jnp.einsum(
-                    "brs,rs->br", final_oh, mm["dfa_accept_f"], preferred_element_type=f32
-                ) > 0.5                                              # [B, R]
+            # derive the scan's init carry from a varying input (zero-multiplied)
+            # so its manual-mesh "varying" type matches inside shard_map
+            init = row_bytes[:, :, 0] * 0.0
+            final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
+            final_oh = (final[..., None] == iota_s).astype(cdt)
+            dfa_row_res = jnp.einsum(
+                "brs,rs->br", final_oh, mm["dfa_accept_f"], preferred_element_type=f32
+            ) > 0.5                                              # [B, R]
             leaf_dfa = jnp.einsum(
                 "br,rl->bl", dfa_row_res.astype(cdt), mm["row_leaf_oh"],
                 preferred_element_type=f32,
@@ -634,10 +752,9 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense,
 # ---------------------------------------------------------------------------
 
 
-def _eval_verdicts_gather(params, attrs_val, members_c, cpu_dense,
+def _eval_verdicts_gather(params, attrs_val, members_c, cpu_dense, config_id,
                           attr_bytes, byte_ovf, attrs_num=None,
-                          num_valid=None, rel_rows=None, member_ovf=None,
-                          own_config=None):
+                          num_valid=None, rel_rows=None, member_ovf=None):
     leaf_op = params["leaf_op"]          # [L]
     leaf_attr = params["leaf_attr"]      # [L]
     leaf_const = params["leaf_const"]    # [L]
@@ -652,29 +769,25 @@ def _eval_verdicts_gather(params, attrs_val, members_c, cpu_dense,
         memb = jnp.take(members_c, params["member_slot_of_leaf"], axis=1)  # [B, L, K]
         incl = jnp.any(memb == leaf_const[None, :, None], axis=-1)
 
-    cpu_lane = _cpu_full(params, cpu_dense)                 # [B, L]
+    cpu_lane = _cpu_full(params, cpu_dense, config_id)      # [B, L]
 
     # ---- device regex lane: DFA scan over value bytes --------------------
     with jax.named_scope("dfa_scan"):
         if params["dfa_tables"] is not None and attr_bytes is not None:
-            if own_config is not None:
-                dfa_row_res = _own_dfa_row_res(
-                    params, own_config, attr_bytes, jnp.float32)  # [B, R]
-            else:
-                tables = params["dfa_tables"]          # [T, S, 256] uint8 (deduped)
-                # per-row table index: rows sharing an automaton share one table
-                tab_idx = params["dfa_table_of_row"][None, :]        # [1, R]
-                row_bytes = jnp.take(attr_bytes, params["dfa_byte_slot"], axis=1)  # [B, R, LB]
+            tables = params["dfa_tables"]          # [T, S, 256] uint8 (deduped)
+            # per-row table index: rows sharing an automaton share one table
+            tab_idx = params["dfa_table_of_row"][None, :]        # [1, R]
+            row_bytes = jnp.take(attr_bytes, params["dfa_byte_slot"], axis=1)  # [B, R, LB]
 
-                def dfa_step(states, byte_col):  # states [B,R] i32, byte_col [B,R] u8
-                    nxt = tables[tab_idx, states, byte_col.astype(jnp.int32)]
-                    return nxt.astype(jnp.int32), None
+            def dfa_step(states, byte_col):  # states [B,R] i32, byte_col [B,R] u8
+                nxt = tables[tab_idx, states, byte_col.astype(jnp.int32)]
+                return nxt.astype(jnp.int32), None
 
-                # init carry derived from a varying input (zero-multiplied) so its
-                # manual-mesh "varying" type matches inside shard_map
-                init = (row_bytes[:, :, 0] * 0).astype(jnp.int32)
-                final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
-                dfa_row_res = params["dfa_accept"][tab_idx, final]   # [B, R]
+            # init carry derived from a varying input (zero-multiplied) so its
+            # manual-mesh "varying" type matches inside shard_map
+            init = (row_bytes[:, :, 0] * 0).astype(jnp.int32)
+            final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
+            dfa_row_res = params["dfa_accept"][tab_idx, final]   # [B, R]
             leaf_dfa = jnp.take(dfa_row_res, params["leaf_dfa_row"], axis=1)  # [B, L]
             leaf_slot = jnp.take(params["dfa_byte_slot"], params["leaf_dfa_row"])
             leaf_bovf = jnp.take(byte_ovf, leaf_slot, axis=1)    # [B, L]
@@ -733,44 +846,35 @@ def eval_verdicts(
     params: DevicePolicy,
     attrs_val: jnp.ndarray,      # [B, A] int32
     members_c: jnp.ndarray,      # [B, M, K] int32 (compact membership)
-    cpu_dense: jnp.ndarray,      # [B, C] bool (dense CPU lane)
+    cpu_dense: jnp.ndarray,      # [B, c_own] bool (own config's CPU columns)
+    config_id: jnp.ndarray,      # [B] int32: whose columns cpu_dense carries
     attr_bytes: Optional[jnp.ndarray] = None,  # [B, NB, LB] uint8
     byte_ovf: Optional[jnp.ndarray] = None,    # [B, NB] bool
     attrs_num: Optional[jnp.ndarray] = None,   # [B, NN] int32 (numeric lane)
     num_valid: Optional[jnp.ndarray] = None,   # [B, NN] bool
     rel_rows: Optional[jnp.ndarray] = None,    # [B, NR] int32 (relation lane)
     member_ovf: Optional[jnp.ndarray] = None,  # [B, M] bool (ovf_assist)
-    own_config: Optional[jnp.ndarray] = None,  # [B] int32 (config_id)
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Returns (verdict [B, G] bool, (rule_results [B, G, E], skipped [B, G, E])).
-
-    With ``own_config`` the caller promises to read only each row's own
-    config column, and the lax lanes scan only that config's DFA rows
-    (``_own_dfa_row_res``): the other configs' columns are then not their
-    verdicts.  Without it every column is exact (the dense scan).  The fused
-    lane has one body and ignores it."""
+    """The dense body: (verdict [B, G] bool, (rule_results [B, G, E],
+    skipped [B, G, E])), every config's column exact.  Runs the matmul
+    formulation where ``to_device(dense=True)`` built its operands, the
+    gather formulation otherwise; the fused lane has one body."""
     # ids travel as int16 when the interner fits (compiler/pack.py
     # wire_dtype); upcast on device AFTER the transfer
     if attrs_val.dtype != jnp.int32:
         attrs_val = attrs_val.astype(jnp.int32)
     if members_c.dtype != jnp.int32:
         members_c = members_c.astype(jnp.int32)
+    operands = (params, attrs_val, members_c, cpu_dense, config_id, attr_bytes,
+                byte_ovf, attrs_num, num_valid, rel_rows, member_ovf)
     if params.get("fused") is not None:
         from . import fused_kernel as _fk  # lazy: fused_kernel imports us
 
-        return _fk._eval_verdicts_fused(
-            params, attrs_val, members_c, cpu_dense, attr_bytes, byte_ovf,
-            attrs_num, num_valid, rel_rows, member_ovf
-        )
-    if params.get("matmul") is not None:
-        return _eval_verdicts_matmul(
-            params, attrs_val, members_c, cpu_dense, attr_bytes, byte_ovf,
-            attrs_num, num_valid, rel_rows, member_ovf, own_config
-        )
-    return _eval_verdicts_gather(
-        params, attrs_val, members_c, cpu_dense, attr_bytes, byte_ovf,
-        attrs_num, num_valid, rel_rows, member_ovf, own_config
-    )
+        return _fk._eval_verdicts_fused(*operands)
+    mm = params.get("matmul")
+    if mm is not None and "rule_m" in mm:
+        return _eval_verdicts_matmul(*operands)
+    return _eval_verdicts_gather(*operands)
 
 
 def _select_own(config_id: jnp.ndarray, n_configs: int) -> jnp.ndarray:
@@ -786,8 +890,8 @@ def forward(params, attrs_val, members_c, cpu_dense, config_id,
     full verdict matrix [B, G]).  The single source of truth for
     verdict-selection logic (PolicyModel and the engine both use it)."""
     verdict, _ = eval_verdicts(
-        params, attrs_val, members_c, cpu_dense, attr_bytes, byte_ovf,
-        attrs_num, num_valid, rel_rows, member_ovf
+        params, attrs_val, members_c, cpu_dense, config_id, attr_bytes,
+        byte_ovf, attrs_num, num_valid, rel_rows, member_ovf
     )
     own_mask = _select_own(config_id, verdict.shape[1])
     own = jnp.any(verdict & own_mask, axis=1)
@@ -801,14 +905,15 @@ _eval_jit = jax.jit(forward)
 def eval_full_jit(params, attrs_val, members_c, cpu_dense, config_id,
                   attr_bytes=None, byte_ovf=None, attrs_num=None,
                   num_valid=None, rel_rows=None, member_ovf=None):
-    """Like _eval_jit but also returns each request's own per-evaluator rule
-    results + skipped flags [B, E] — what the pipeline's batched
-    pattern-matching evaluators consume (runtime/engine.py).  Own-config
-    results only, so the DFA scan runs over the request's own rows."""
-    verdict, (rule, skipped) = eval_verdicts(
-        params, attrs_val, members_c, cpu_dense, attr_bytes, byte_ovf,
-        attrs_num, num_valid, rel_rows, member_ovf, own_config=config_id
-    )
+    """Like _eval_jit but returns only each request's own verdict and
+    per-evaluator rule results + skipped flags [B, E] — what the pipeline's
+    batched pattern-matching evaluators consume (runtime/engine.py).  The
+    lax lanes evaluate the request's own config alone (``eval_own``)."""
+    operands = (params, attrs_val, members_c, cpu_dense, config_id, attr_bytes,
+                byte_ovf, attrs_num, num_valid, rel_rows, member_ovf)
+    if params.get("own") is not None:
+        return eval_own(*operands)
+    verdict, (rule, skipped) = eval_verdicts(*operands)
     own_mask = _select_own(config_id, verdict.shape[1])
     own = jnp.any(verdict & own_mask, axis=1)
     own_rule = jnp.any(rule & own_mask[:, :, None], axis=1)
@@ -901,9 +1006,10 @@ def eval_bitpacked_jit(params, attrs_val, members_c, cpu_dense, config_id,
                        num_valid=None, rel_rows=None, member_ovf=None):
     """eval_packed_jit with the result bit-packed on device: the D2H
     readback is [B, ceil((1+2E)/8)] uint8 instead of [B, 1+2E] bool.
-    The phases carry named scopes (leaf_compares, membership, dfa_scan,
-    circuit, bitpack under pattern_eval), so a device trace can be grouped
-    by phase whatever the compiler calls its fusions."""
+    The phases carry named scopes (own_gather, own_leaf_compares,
+    membership, dfa_scan, own_circuit, bitpack under pattern_eval), so a
+    device trace can be grouped by phase whatever the compiler calls its
+    fusions."""
     with jax.named_scope("pattern_eval"):
         packed = eval_packed_jit(
             params, attrs_val, members_c, cpu_dense, config_id,

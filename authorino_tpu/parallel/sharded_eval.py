@@ -140,11 +140,15 @@ def _sharded_step(mesh: Mesh, has_dfa: bool, has_matmul: bool, n_levels: int,
                    member_ovf, shard_of, row_of):
         # params leading axis is the local S slice (size 1 per mp shard)
         sq = jax.tree_util.tree_map(lambda a: a[0], params)
+        mp_idx = jax.lax.axis_index("mp")
         verdict, (rule, skipped) = eval_verdicts(
             sq,
             attrs_val[:, 0],
             members_c[:, 0],
             cpu_dense[:, 0],
+            # whose CPU columns the slice carries: the owning shard's row,
+            # nobody's elsewhere (the slice is the empty encoding there)
+            jnp.where(shard_of == mp_idx, row_of, -1),
             attr_bytes[:, 0] if has_dfa else None,
             byte_ovf[:, 0] if has_dfa else None,
             attrs_num[:, 0] if has_num else None,
@@ -155,7 +159,6 @@ def _sharded_step(mesh: Mesh, has_dfa: bool, has_matmul: bool, n_levels: int,
         # own-config one-hot rows local to this shard (other shards see all-
         # False masks for the request); psum over mp merges the disjoint parts
         G = verdict.shape[1]
-        mp_idx = jax.lax.axis_index("mp")
         mask = (shard_of == mp_idx)[:, None] & (
             row_of[:, None] == jnp.arange(G, dtype=row_of.dtype)[None, :]
         )                                                        # [B_l, G]
@@ -211,14 +214,19 @@ def _eval_stacked(params, attrs_val, members_c, cpu_dense,
     mesh partition, the own-config mask-reduce replaces the psum.  Same
     operands, same bit-packed [B, ceil((1+2E)/8)] readback, bit-identical
     verdicts (the kernel is a pure per-row function and vmap is exact)."""
-    def per_shard(sq, av, mc, cd, ab, bo, an, nv, rr, mo):
+    def per_shard(sq, av, mc, cd, cfg, ab, bo, an, nv, rr, mo):
         verdict, (rule, skipped) = eval_verdicts(
-            sq, av, mc, cd, ab, bo, an, nv, rr, mo)
+            sq, av, mc, cd, cfg, ab, bo, an, nv, rr, mo)
         return verdict, rule, skipped
 
+    n_shards = attrs_val.shape[1]
+    # whose CPU columns each shard's slice carries (see local_eval)
+    cfg = jnp.where(
+        shard_of[None, :] == jnp.arange(n_shards, dtype=shard_of.dtype)[:, None],
+        row_of[None, :], -1)                                     # [S, B]
     ops = [jnp.moveaxis(attrs_val, 1, 0), jnp.moveaxis(members_c, 1, 0),
-           jnp.moveaxis(cpu_dense, 1, 0)]
-    axes = [0, 0, 0, 0]
+           jnp.moveaxis(cpu_dense, 1, 0), cfg]
+    axes = [0, 0, 0, 0, 0]
     for a in (attr_bytes, byte_ovf, attrs_num, num_valid, rel_rows,
               member_ovf):
         if a is not None:
@@ -395,7 +403,7 @@ class MeshRoute:
 class _ShardedEncoded:
     attrs_val: np.ndarray      # [B, S, A]
     members_c: np.ndarray      # [B, S, M, K] — compact membership rows
-    cpu_dense: np.ndarray      # [B, S, C] — dense CPU-lane columns
+    cpu_dense: np.ndarray      # [B, S, c_own] — own config's CPU-lane columns
     attr_bytes: Optional[np.ndarray]  # [B, S, NB, LB] uint8 (None: no DFA lane)
     byte_ovf: Optional[np.ndarray]    # [B, S, NB] bool
     shard_of: np.ndarray       # [B] which shard owns the request's config
@@ -483,7 +491,7 @@ class ShardedPolicyModel:
         # The stacked view is retained: the next reconcile diffs against it
         # for the per-shard delta upload, and the failover path device_puts
         # it onto a single healthy device.
-        per_shard_params = [to_device(p, host=True, lane=kernel_lane)
+        per_shard_params = [to_device(p, host=True, lane=kernel_lane, dense=True)
                             for p in self.shards]
         self.host_view = jax.tree.map(
             lambda *xs: np.stack(xs), *per_shard_params
@@ -638,7 +646,7 @@ class ShardedPolicyModel:
         S = self.n_shards
         p0 = self.shards[0]
         A, K = p0.n_attrs, p0.members_k
-        M, C = p0.n_member_attrs, p0.n_cpu_leaves
+        M, C = p0.n_member_attrs, p0.n_own_cpu
         attrs_val = np.full((B, S, A), EMPTY_ID, dtype=np.int32)
         members_c = np.full((B, S, M, K), PAD, dtype=np.int32)
         cpu_dense = np.zeros((B, S, C), dtype=bool)

@@ -224,7 +224,7 @@ def _bitpacked_zero_args(policy, params, pad: int, eff: int) -> tuple:
 
     dt = wire_dtype(policy)
     A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
-    C, NB = policy.n_cpu_leaves, max(policy.n_byte_attrs, 1)
+    C, NB = policy.n_own_cpu, max(policy.n_byte_attrs, 1)
     return (
         params,
         jnp.asarray(np.zeros((pad, A), dtype=dt)),

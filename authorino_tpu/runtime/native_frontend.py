@@ -104,6 +104,24 @@ _SIMPLE = {
 }
 
 
+def _cfg_dfa_refs(policy: CompiledPolicy,
+                  row_base: int = 0) -> List[List[Tuple[int, int, int]]]:
+    """Per config row, its DFA leaves as (attr, dfa row, column of the row's
+    own cpu_dense payload): what the C++ encoder evaluates exactly on the
+    host when a value overflows the byte tensor."""
+    from ..compiler.compile import OWN_ATTR, OWN_CPU, OWN_OP
+
+    own = policy.own
+    tab = own.leaf_tab
+    out: List[List[Tuple[int, int, int]]] = [[] for _ in range(tab.shape[0])]
+    g, j = np.nonzero((tab[..., OWN_OP] == OP_REGEX_DFA) & (tab[..., OWN_CPU] >= 0))
+    rows = policy.leaf_dfa_row[own.leaves[g, j]] + row_base
+    for gi, attr, row, col in zip(g.tolist(), tab[g, j, OWN_ATTR].tolist(),
+                                  rows.tolist(), tab[g, j, OWN_CPU].tolist()):
+        out[gi].append((attr, row, col))
+    return out
+
+
 def _classify_selector(selector_str: str):
     """("req", kind, key) for a request-derived attr, ("auth",) for one that
     resolves over the identity-dependent ``auth.*`` subtree (constant per
@@ -1082,24 +1100,29 @@ class NativeFrontend:
 
     @staticmethod
     def _kernel_of(rec: _SnapRec) -> Optional[Dict[str, Any]]:
-        from ..ops.pattern_eval import (dfa_scan_width, kernel_body_of,
-                                        kernel_lane_of)
+        from ..ops.pattern_eval import (kernel_body_of, kernel_lane_of,
+                                        kernel_widths, operand_bytes)
 
         if rec.sharded is not None:
-            # the mesh step wants every config's column: the dense scan
+            # the mesh step wants every config's column: the dense body
             view = rec.sharded.host_view
             return {"lane": kernel_lane_of(view),
                     "body": "lax", "entry": "sharded_step",
-                    **dfa_scan_width(view, own=False)}
+                    "operand_bytes": operand_bytes(view),
+                    **kernel_widths(view, own=False)}
         if rec.params is None:
             return None
         lane = kernel_lane_of(rec.params)
         return {"lane": lane, "body": kernel_body_of(rec.params),
                 "entry": ("fused_kernel" if lane == "fused"
                           else "eval_bitpacked"),
-                # what the served entry scans for ONE request row (its own
-                # config's DFA rows on the lax lanes) against the corpus's
-                **dfa_scan_width(rec.params)}
+                # bytes of the serving snapshot's device operands, summed
+                # over the uploaded pytree
+                "operand_bytes": operand_bytes(rec.params),
+                # what the served entry evaluates for ONE request row (its
+                # own config's leaves and DFA rows on the lax lanes)
+                # against the corpus's
+                **kernel_widths(rec.params)}
 
     @property
     def warm_error(self) -> Optional[str]:
@@ -1267,7 +1290,7 @@ class NativeFrontend:
             sh = rec.sharded
             p0 = sh.shards[0]
             S, A, M, K = sh.n_shards, p0.n_attrs, p0.n_member_attrs, p0.members_k
-            C, NB = p0.n_cpu_leaves, max(p0.n_byte_attrs, 1)
+            C, NB = p0.n_own_cpu, max(p0.n_byte_attrs, 1)
             out = sh.launch(_ShardedEncoded(
                 attrs_val=np.zeros((pad, S, A), dtype=np.int32),
                 members_c=np.full((pad, S, M, K), PAD, dtype=np.int32),
@@ -1284,7 +1307,7 @@ class NativeFrontend:
         policy = rec.policy
         dt = wire_dtype(policy)
         A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
-        C, NB = policy.n_cpu_leaves, max(policy.n_byte_attrs, 1)
+        C, NB = policy.n_own_cpu, max(policy.n_byte_attrs, 1)
         out = eval_bitpacked_jit(
             rec.params,
             jnp.asarray(np.zeros((pad, A), dtype=dt)),
@@ -1396,7 +1419,7 @@ class NativeFrontend:
         policy = rec.policy
         dt = wire_dtype(policy)
         A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
-        C, NB = policy.n_cpu_leaves, max(policy.n_byte_attrs, 1)
+        C, NB = policy.n_own_cpu, max(policy.n_byte_attrs, 1)
         with jax.default_device(cpu):
             out = eval_bitpacked_jit(
                 rec.host_params,
@@ -1604,7 +1627,7 @@ class NativeFrontend:
                 spec["policy"] = enc._handle
                 dt = wire_dtype(policy)
                 A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
-                C, NB = policy.n_cpu_leaves, max(policy.n_byte_attrs, 1)
+                C, NB = policy.n_own_cpu, max(policy.n_byte_attrs, 1)
                 spec.update(A=A, M=M, K=K, C=C, NB=NB,
                             elem16=1 if dt == np.int16 else 0)
                 ams = np.ascontiguousarray(policy.member_attr_slot, dtype=np.int32)
@@ -1626,14 +1649,8 @@ class NativeFrontend:
                     spec.update(dfa_R=int(dt_tr.shape[0]), dfa_S=int(dt_tr.shape[1]),
                                 dfa_trans_addr=dt_tr.ctypes.data,
                                 dfa_accept_addr=dt_ac.ctypes.data)
-                # per-attr DFA leaves → (dfa row, dense cpu column)
-                cpu_col = {int(l): i for i, l in enumerate(policy.cpu_leaf_list)}
-                attr_dfas: List[List[Tuple[int, int]]] = [[] for _ in range(A)]
-                for leaf in range(policy.n_leaves):
-                    if int(policy.leaf_op[leaf]) == OP_REGEX_DFA and leaf in cpu_col:
-                        attr_dfas[int(policy.leaf_attr[leaf])].append(
-                            (int(policy.leaf_dfa_row[leaf]), cpu_col[leaf]))
-                spec["attr_dfas"] = attr_dfas
+                spec["G"] = policy.n_configs
+                spec["cfg_dfas"] = _cfg_dfa_refs(policy)
 
                 # batch slots (numpy-owned; freed on SNAP_RETIRED)
                 B = self.max_batch
@@ -1669,7 +1686,7 @@ class NativeFrontend:
                 p0 = sharded.shards[0]
                 S_sh = sharded.n_shards
                 A, M, K = p0.n_attrs, p0.n_member_attrs, p0.members_k
-                C, NB = p0.n_cpu_leaves, max(p0.n_byte_attrs, 1)
+                C, NB = p0.n_own_cpu, max(p0.n_byte_attrs, 1)
                 # the sharded step takes int32 operands (parallel/sharded_eval
                 # encode contract), so elem16 stays off
                 spec.update(A=A, M=M, K=K, C=C, NB=NB, S=S_sh, elem16=0)
@@ -1686,8 +1703,7 @@ class NativeFrontend:
                 # R and the state count); attr_dfas rows are globalized
                 rec.cacheable = np.stack(
                     [p.config_cacheable for p in sharded.shards])
-                attr_dfas: List[List[Tuple[int, int]]] = [
-                    [] for _ in range(S_sh * A)]
+                cfg_dfas: List[List[Tuple[int, int, int]]] = []
                 if p0.n_byte_attrs > 0 and p0.dfa_tables.size:
                     # per-row expansion of the deduped table store, stacked
                     # on the (shard-globalized) row axis for C++
@@ -1706,15 +1722,9 @@ class NativeFrontend:
                                 dfa_trans_addr=dt_tr.ctypes.data,
                                 dfa_accept_addr=dt_ac.ctypes.data)
                     for s, p in enumerate(sharded.shards):
-                        cpu_col = {int(l): i
-                                   for i, l in enumerate(p.cpu_leaf_list)}
-                        for leaf in range(p.n_leaves):
-                            if (int(p.leaf_op[leaf]) == OP_REGEX_DFA
-                                    and leaf in cpu_col):
-                                attr_dfas[s * A + int(p.leaf_attr[leaf])].append(
-                                    (s * R + int(p.leaf_dfa_row[leaf]),
-                                     cpu_col[leaf]))
-                spec["attr_dfas"] = attr_dfas
+                        cfg_dfas += _cfg_dfa_refs(p, row_base=s * R)
+                spec["G"] = p0.n_configs
+                spec["cfg_dfas"] = cfg_dfas
 
                 B = self.max_batch
                 for _ in range(self.slots):

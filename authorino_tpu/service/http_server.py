@@ -8,6 +8,7 @@ source.certificate) and runs through the identical engine/pipeline."""
 
 from __future__ import annotations
 
+import asyncio
 import json
 import os
 import time
@@ -234,11 +235,20 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
 
             from ..runtime import provenance as prov_mod
 
-            # rule heat maps accumulate in-process and flush on a cadence;
-            # flushing here makes the rule-fired series current on THIS
-            # scrape (collector ordering alone lags it by one)
-            prov_mod.flush_heatmaps()
-            return web.Response(body=generate_latest(), content_type="text/plain")
+            def exposition() -> bytes:
+                # rule heat maps accumulate in-process and flush on a
+                # cadence; flushing here makes the rule-fired series current
+                # on THIS scrape (collector ordering alone lags it by one)
+                prov_mod.flush_heatmaps()
+                return generate_latest()
+
+            # off the event loop: the per-AuthConfig families make the text
+            # 27 MB at 10,000 configs, seconds of Python during which this
+            # loop would answer nothing else (/readyz, /debug/vars, a
+            # /debug/profile asked for NOW would start seconds late)
+            body = await asyncio.get_running_loop().run_in_executor(
+                None, exposition)
+            return web.Response(body=body, content_type="text/plain")
         except Exception:
             return web.Response(status=501, text="prometheus_client unavailable")
 

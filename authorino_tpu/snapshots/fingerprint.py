@@ -63,12 +63,15 @@ def encoding_epoch(policy: CompiledPolicy) -> str:
     if cached is not None:
         return cached
     tree_memo: Dict[int, str] = {}
-    # dense CPU-lane columns: the [B, C] booleans are positional — column j
-    # IS the leaf cpu_leaf_list[j], identified canonically (op, selector,
-    # pattern / whole-tree digest), never by leaf index
-    cpu_desc = []
+    # CPU-lane columns: the [B, c_own] booleans are positional — column j of
+    # a row of config g IS the leaf own.cpu_leaves[g, j], identified
+    # canonically (op, selector, pattern / whole-tree digest), never by leaf
+    # index
     rev = None
-    for leaf in policy.cpu_leaf_list.tolist():
+    leaf_desc: Dict[int, tuple] = {}
+
+    def describe(leaf: int) -> tuple:
+        nonlocal rev
         rx = policy.leaf_regex[leaf]
         tree = policy.leaf_tree[leaf]
         # ovf_assist membership columns are identified by their CONSTANT
@@ -79,13 +82,23 @@ def encoding_epoch(policy: CompiledPolicy) -> str:
                 rev = policy.interner.reverse()
             const_s = rev.get(int(policy.leaf_const[leaf]),
                               f"<id:{int(policy.leaf_const[leaf])}>")
-        cpu_desc.append((
+        return (
             int(policy.leaf_op[leaf]),
             policy.attr_selectors[int(policy.leaf_attr[leaf])],
             rx.pattern if rx is not None else None,
             _tree_digest(tree, tree_memo) if tree is not None else None,
             const_s,
-        ))
+        )
+
+    cpu_desc = []
+    for row in policy.own.cpu_leaves.tolist():
+        cols = []
+        for leaf in row:
+            if leaf >= 0:
+                if leaf not in leaf_desc:
+                    leaf_desc[leaf] = describe(leaf)
+                cols.append(leaf_desc[leaf])
+        cpu_desc.append(tuple(cols))
     # byte-tensor slots: slot → selector (positional [B, NB, LB] axes)
     byte_slots: Dict[int, str] = {}
     for a_i, slot in enumerate(policy.attr_byte_slot.tolist()):
@@ -117,7 +130,7 @@ def encoding_epoch(policy: CompiledPolicy) -> str:
         tuple(policy.attr_selectors),
         (tuple(policy.attr_selectors[a] for a in policy.member_attrs.tolist()),
          int(policy.n_member_attrs)),
-        (tuple(cpu_desc), int(policy.n_cpu_leaves)),
+        (tuple(cpu_desc), policy.n_own_cpu),
         (tuple(byte_slots.get(s) for s in range(policy.n_byte_attrs)),
          DFA_VALUE_BYTES),
         (tuple(num_slots.get(s)

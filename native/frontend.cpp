@@ -494,7 +494,9 @@ static inline int stage_bucket(int64_t ns) {
   return N_STAGE_BUCKETS - 1;
 }
 
-struct DfaRef { int32_t row; int32_t col; };  // dfa table row, cpu_dense column
+// one DFA leaf of a config: the attr it reads, its dfa table row, its column
+// in the config's own cpu_dense payload
+struct DfaRef { int32_t attr; int32_t row; int32_t col; };
 
 struct Entry {
   uint32_t conn_id;
@@ -518,7 +520,7 @@ struct Slot {
   // single-corpus layout is the S=1 special case of the same strides
   char* attrs_val = nullptr;     // [Bmax, S, A] int16/int32
   char* members = nullptr;       // [Bmax, S, M, K] int16/int32
-  uint8_t* cpu_dense = nullptr;  // [Bmax, S, C] bool
+  uint8_t* cpu_dense = nullptr;  // [Bmax, S, C] bool, C = c_own: the row's own config's columns
   int32_t* config_id = nullptr;  // [Bmax] row within the owning shard
   int32_t* shard_of = nullptr;   // [Bmax] owning shard (null for S=1)
   uint8_t* attr_bytes = nullptr; // [Bmax, S, NB, DVB]
@@ -533,7 +535,8 @@ struct Snapshot {
   bool elem16 = false;
   std::vector<int32_t> attr_member_slot;  // [S*A] → M row or -1
   std::vector<int32_t> attr_byte_slot_v;  // [S*A] → NB row or -1
-  std::vector<std::vector<DfaRef>> attr_dfas;  // [S*A]; rows globalized
+  int G = 0;  // config rows per shard
+  std::vector<std::vector<DfaRef>> cfg_dfas;  // [S*G]; rows globalized
   std::vector<uint8_t> dfa_trans;  // [S*R, St, 256]
   std::vector<uint8_t> dfa_accept; // [S*R, St]
   int dfa_S = 0;
@@ -945,12 +948,16 @@ static bool encode_fast(Server* S, Snapshot* snap, Slot& sl, int b,
       if (ovf) {
         sl.byte_ovf[bs * NB + bslot] = 1;
         S->n_dfa_ovf.fetch_add(1, std::memory_order_relaxed);
-        // exact host evaluation of every DFA leaf reading this attr (the
-        // DFA is length-agnostic; only the device tensor is fixed-width)
+        // exact host evaluation of every DFA leaf of this config reading
+        // this attr (the DFA is length-agnostic; only the device tensor is
+        // fixed-width)
         const char* sp = missing ? "" : vp;
         size_t sn = missing ? 0 : vn;
-        for (const DfaRef& d : snap->attr_dfas[meta0 + attr])
-          sl.cpu_dense[bs * snap->C + d.col] = dfa_scan(snap, d.row, sp, sn) ? 1 : 0;
+        const size_t ci = (size_t)fc.shard * snap->G + fc.row;
+        if (ci >= snap->cfg_dfas.size()) return false;
+        for (const DfaRef& d : snap->cfg_dfas[ci])
+          if (d.attr == attr)
+            sl.cpu_dense[bs * snap->C + d.col] = dfa_scan(snap, d.row, sp, sn) ? 1 : 0;
       } else if (vn) {
         memcpy(sl.attr_bytes + (bs * NB + bslot) * DVB, vp, vn);
       }

@@ -410,7 +410,7 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
     snap->attr_byte_slot_v.assign(abs_v, abs_v + SA);
   snap->attr_member_slot.resize(SA, -1);
   snap->attr_byte_slot_v.resize(SA, -1);
-  // dfa_R counts TOTAL stacked rows (S*R for sharded corpora); attr_dfas
+  // dfa_R counts TOTAL stacked rows (S*R for sharded corpora); cfg_dfas
   // rows arrive globalized by the Python side
   long dfa_R = dict_int(d, "dfa_R");
   snap->dfa_S = (int)dict_int(d, "dfa_S");
@@ -420,16 +420,19 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
     snap->dfa_trans.assign(tr, tr + (size_t)dfa_R * snap->dfa_S * 256);
     snap->dfa_accept.assign(ac, ac + (size_t)dfa_R * snap->dfa_S);
   }
-  snap->attr_dfas.resize(SA);
-  PyObject* adfas = PyDict_GetItemString(d, "attr_dfas");
-  if (adfas != nullptr) {
-    for (Py_ssize_t a = 0; a < PyList_GET_SIZE(adfas) && a < SA; ++a) {
-      PyObject* lst = PyList_GET_ITEM(adfas, a);
+  snap->G = (int)dict_int(d, "G", 0);
+  snap->cfg_dfas.resize((size_t)snap->S * snap->G);
+  PyObject* cdfas = PyDict_GetItemString(d, "cfg_dfas");
+  if (cdfas != nullptr) {
+    for (Py_ssize_t g = 0; g < PyList_GET_SIZE(cdfas) &&
+                           g < (Py_ssize_t)snap->cfg_dfas.size(); ++g) {
+      PyObject* lst = PyList_GET_ITEM(cdfas, g);
       for (Py_ssize_t j = 0; j < PyList_GET_SIZE(lst); ++j) {
         PyObject* t = PyList_GET_ITEM(lst, j);
-        snap->attr_dfas[a].push_back(
+        snap->cfg_dfas[g].push_back(
             {(int32_t)PyLong_AsLong(PyTuple_GET_ITEM(t, 0)),
-             (int32_t)PyLong_AsLong(PyTuple_GET_ITEM(t, 1))});
+             (int32_t)PyLong_AsLong(PyTuple_GET_ITEM(t, 1)),
+             (int32_t)PyLong_AsLong(PyTuple_GET_ITEM(t, 2))});
       }
     }
   }

@@ -370,14 +370,14 @@ def test_lowered_kernel_carries_its_phase_scopes(frontend):
         rec.params,
         jnp.zeros((pad, policy.n_attrs), dtype=dt),
         jnp.full((pad, policy.n_member_attrs, policy.members_k), PAD, dtype=dt),
-        jnp.zeros((pad, policy.n_cpu_leaves), dtype=bool),
+        jnp.zeros((pad, policy.n_own_cpu), dtype=bool),
         jnp.zeros((pad,), dtype=np.int32),
         jnp.zeros((pad, nb, eff), dtype=np.uint8),
         jnp.zeros((pad, nb), dtype=bool),
     ).compile().as_text()
     # what a device trace's events carry: op_name, the scopes in it
     names = set(re.findall(r'op_name="([^"]+)"', hlo))
-    for scope in ("leaf_compares", "membership", "dfa_scan", "circuit",
-                  "bitpack"):
+    for scope in ("own_gather", "own_leaf_compares", "membership", "dfa_scan",
+                  "own_circuit", "bitpack"):
         assert any(n.startswith("jit(eval_bitpacked_jit)/pattern_eval/")
                    and f"/{scope}/" in n for n in names), scope

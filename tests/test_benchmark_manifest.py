@@ -1,0 +1,98 @@
+"""BENCHMARK.json and the files it names, held in tier-1 (benchmark/tests is
+outside it): the manifest's own checker finds no problem on the tree, and the
+newest configuration's generator, reference and the program's host expression
+oracle agree on a seeded sample of its rows."""
+
+import os
+import random
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+for p in (BENCH, ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import harness  # noqa: E402
+import manifest_check  # noqa: E402
+from reference import OK, PERMISSION_DENIED, Reference  # noqa: E402
+
+import chip_smoke  # noqa: E402
+
+
+def _manifest():
+    return harness._load_json(os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def test_manifest_check_finds_no_problem_on_the_tree():
+    problems = [c for c in manifest_check.cases(ROOT) if c[1] is not None]
+    assert problems == []
+    assert sum(1 for _ in manifest_check.cases(ROOT)) > 50
+
+
+@pytest.mark.parametrize("workload", [
+    w["name"] for w in _manifest()["workloads"]])
+def test_every_cell_loads_with_its_files(workload):
+    manifest = _manifest()
+    cell = harness.load_cell(manifest, ROOT, workload)
+    entry = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
+    assert cell["config_file"]["source"] == entry["source"]
+    assert cell["config_file"]["reduced"] == entry["reduced"]
+    reported = {m["name"] for m in cell["end_to_end"]}
+    assert "setup_s" in reported and len(reported) >= 2
+    assert cell["per_layer"] and all(
+        m["moves"] in reported for m in cell["per_layer"])
+
+
+def test_tenants_10k_rows_agree_with_reference_and_host_oracle():
+    cell = harness.load_cell(_manifest(), ROOT, "tenants-10k.unique-sat")
+    config = cell["config_file"]
+    assert config["params"] == {"n_configs": 10000} and config["reduced"] == []
+    assert (cell["traffic"], cell["chips"]) == ("unique-sat", 1)
+    assert config["generator"] == "tenant_rules_guarded"
+    generator = harness.load_module("corpora", config["generator"])
+    manifests = generator.manifests(config["params"])
+    assert len(manifests) == 10000
+    assert len({h for m in manifests for h in m["spec"]["hosts"]}) == 10000
+    rows = generator.requests(dict(config["params"], **config["requests"]),
+                              512, random.Random(2147493647))
+    by_host = {m["spec"]["hosts"][0]: i for i, m in enumerate(manifests)}
+    reference = Reference(manifests)
+    answers = [reference.decide(r) for r in rows]
+    oracle = chip_smoke.oracle_verdicts(
+        manifests, [dict(r, config=by_host[r["host"]]) for r in rows])
+    assert answers == [OK if allow else PERMISSION_DENIED for allow in oracle]
+    # half of the rows break one rule; hosts spread over the whole corpus
+    assert 0.4 < answers.count(PERMISSION_DENIED) / len(rows) < 0.6
+    assert len({r["host"] for r in rows}) > 450
+
+
+@pytest.mark.parametrize("sources, runs", [
+    ({"ops/pattern_eval.py": 'widths = {"leaf_cols_per_row": 10}'}, True),
+    ({"ops/pattern_eval.py": 'widths = {"dfa_rows_per_row": 2}'}, False),
+    ({"README.md": "leaf_cols_per_row"}, False),
+    ({}, False),
+], ids=["own-config", "dense", "not-in-code", "no-package"])
+def test_tenants_10k_generator_refuses_a_program_with_dense_operands(
+        tmp_path, sources, runs):
+    """The parent of PR 28 is killed for its memory at 10,000 configs; the
+    cell's generator refuses it before the child starts, so that side of the
+    driver's check fails cleanly.  The rows and manifests are tenant_rules'."""
+    guarded = harness.load_module("corpora", "tenant_rules_guarded")
+    plain = harness.load_module("corpora", "tenant_rules")
+    for rel, text in sources.items():
+        path = tmp_path / "authorino_tpu" / rel
+        path.parent.mkdir(parents=True, exist_ok=True)
+        path.write_text(text)
+    params = {"n_configs": 12}
+    if runs:
+        assert guarded.manifests(params, str(tmp_path)) == plain.manifests(params)
+    else:
+        with pytest.raises(harness.Refused, match="leaf_cols_per_row"):
+            guarded.manifests(params, str(tmp_path))
+    assert guarded.manifests(params) == plain.manifests(params)  # this tree
+    args = (dict(params, deny_share=0.5), 64)
+    assert guarded.requests(*args, random.Random(7)) == \
+        plain.requests(*args, random.Random(7))

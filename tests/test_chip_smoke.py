@@ -84,11 +84,14 @@ def test_smoke_passes_end_to_end_on_cpu_when_told_to_expect_one(tmp_path):
     assert summary["native_frontend"]["stats"]["fast"] == 384
     assert summary["native_frontend"]["source_digest"] == \
         summary["native_source_digest"]
-    # two configs a shard, two regexes a config: the mesh step scans every
-    # DFA row of its shard for every request row (the dense scan)
-    assert summary["kernel"] == {"lane": "matmul", "body": "lax",
-                                 "entry": "sharded_step",
-                                 "dfa_rows_per_row": 4, "dfa_rows_total": 4}
+    # two configs a shard, two regexes a config: the mesh step evaluates
+    # every leaf and scans every DFA row of its shard for every request row
+    # (the dense body)
+    kernel = dict(summary["kernel"])
+    assert kernel.pop("operand_bytes") > 0
+    assert kernel == {"lane": "matmul", "body": "lax",
+                      "entry": "sharded_step", "leaf_cols_per_row": 32,
+                      "dfa_rows_per_row": 4, "dfa_rows_total": 4}
     assert summary["wire_device_rows"] >= 0.9 * 384
     assert summary["warm_grid"] and summary["exit_code"] == 0
     assert summary["compile_cache"]["dir"]

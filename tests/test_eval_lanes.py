@@ -63,10 +63,10 @@ def _docs(n, seed=11):
 
 def _both_lane_params(policy, monkeypatch):
     monkeypatch.setenv("AUTHORINO_TPU_EVAL_LANE", "matmul")
-    params_mm = pe.to_device(policy)
+    params_mm = pe.to_device(policy, dense=True)
     monkeypatch.setenv("AUTHORINO_TPU_EVAL_LANE", "gather")
-    params_g = pe.to_device(policy)
-    assert params_mm["matmul"] is not None
+    params_g = pe.to_device(policy, dense=True)
+    assert "rule_m" in params_mm["matmul"]
     assert params_g["matmul"] is None
     return params_mm, params_g
 
@@ -81,6 +81,7 @@ def test_matmul_lane_matches_gather_lane(monkeypatch):
         jnp.asarray(db.attrs_val),
         jnp.asarray(db.members_c),
         jnp.asarray(db.cpu_dense),
+        jnp.asarray(db.config_id),
         jnp.asarray(db.attr_bytes),
         jnp.asarray(db.byte_ovf),
     )
@@ -105,6 +106,7 @@ def test_matmul_lane_bf16_matches_gather_lane(monkeypatch):
         jnp.asarray(db.attrs_val),
         jnp.asarray(db.members_c),
         jnp.asarray(db.cpu_dense),
+        jnp.asarray(db.config_id),
         jnp.asarray(db.attr_bytes),
         jnp.asarray(db.byte_ovf),
     )
@@ -157,7 +159,8 @@ def test_interner_overflow_falls_back_to_gather(monkeypatch):
 
 # ---------------------------------------------------------------------------
 # own-row DFA scan (ISSUE 26): the entries that return own-config results
-# scan config_dfa_rows[config_id], not the corpus's DFA rows
+# scan config_dfa_rows[config_id], not the corpus's DFA rows (and since
+# ISSUE 28 evaluate the own leaves and circuit only: test_own_config_eval.py)
 # ---------------------------------------------------------------------------
 
 _RX = Operator.MATCHES
@@ -258,8 +261,8 @@ def _own_case(case):
     "regex-guards-allow", "byte-overflow", "shape-targets",
     "config-id-out-of-range"])
 def test_own_row_scan_equals_dense_and_oracle(case, lane):
-    """eval_full_jit (own-row scan) against the dense scan's results selected
-    by config, bit for bit, and against the host expression oracle."""
+    """eval_full_jit (own config only) against the dense body's results
+    selected by config, bit for bit, and against the host expression oracle."""
     cfgs, docs, rows, cid_over, pad, targets = _own_case(case)
     policy = compile_corpus(cfgs, members_k=4)
     natural_d = policy.config_dfa_rows.shape[1]
@@ -278,6 +281,7 @@ def test_own_row_scan_equals_dense_and_oracle(case, lane):
                        and r in policy.config_dfa_rows[3]]
         assert len(shared_rows) == 1
     params = pe.to_device(policy, lane=lane)
+    dense = pe.to_device(policy, lane=lane, dense=True)
     db = pack_batch(policy, encode_batch_py(policy, docs, rows, batch_pad=pad))
     if case == "byte-overflow":
         assert np.asarray(db.byte_ovf)[: len(docs)].any()
@@ -289,7 +293,8 @@ def test_own_row_scan_equals_dense_and_oracle(case, lane):
     tail = (jnp.asarray(db.attr_bytes), jnp.asarray(db.byte_ovf))
     own, own_rule, own_skipped = (np.asarray(x) for x in pe.eval_full_jit(
         params, *head, jnp.asarray(config_id), *tail))
-    verdict, (rule, skipped) = pe.eval_verdicts(params, *head, *tail)
+    verdict, (rule, skipped) = pe.eval_verdicts(
+        dense, *head, jnp.asarray(config_id), *tail)
     mask = config_id[:, None] == np.arange(policy.n_configs)[None, :]
     np.testing.assert_array_equal(own, (np.asarray(verdict) & mask).any(axis=1))
     np.testing.assert_array_equal(

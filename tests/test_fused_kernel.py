@@ -418,8 +418,12 @@ def test_kernel_lane_auto_is_matmul_on_every_platform(monkeypatch):
     # f32 for the CPU backend (the native lane's host twin in a TPU process)
     tpu = pe.to_device(policy, device=_Dev("tpu"), host=True)
     cpu = pe.to_device(policy, device=_Dev("cpu"), host=True)
-    assert str(tpu["matmul"]["rule_m"].dtype) == "bfloat16"
-    assert str(cpu["matmul"]["rule_m"].dtype) == "float32"
+    assert str(tpu["matmul"]["mxu"].dtype) == "bfloat16"
+    assert str(cpu["matmul"]["mxu"].dtype) == "float32"
+    # the dense body's one-hot operands are built only on request
+    assert "rule_m" not in tpu["matmul"]
+    dense = pe.to_device(policy, device=_Dev("tpu"), host=True, dense=True)
+    assert str(dense["matmul"]["rule_m"].dtype) == "bfloat16"
 
 
 def test_occupancy_pad_shapes():

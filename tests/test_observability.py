@@ -123,6 +123,46 @@ def collected_spans(got):
 # ---------------------------------------------------------------------------
 
 class TestEngineTelemetry:
+    def test_metrics_exposition_does_not_hold_the_event_loop(self, monkeypatch):
+        """PR 28: at 10,000 AuthConfigs the exposition is seconds of Python
+        (27 MB of per-AuthConfig series); the loop that serves it also
+        answers /readyz, /debug/vars and starts /debug/profile, so it runs
+        off the loop: a slow scrape must not delay the others."""
+        import threading
+
+        import prometheus_client
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from authorino_tpu.service.http_server import build_app
+
+        scraping, release = threading.Event(), threading.Event()
+
+        def slow_exposition(*a, **k):
+            scraping.set()
+            assert release.wait(30)
+            return b"# slow\n"
+
+        monkeypatch.setattr(prometheus_client, "generate_latest", slow_exposition)
+
+        async def body():
+            client = TestClient(TestServer(build_app(build_engine())))
+            await client.start_server()
+            try:
+                scrape = asyncio.ensure_future(client.get("/metrics"))
+                while not scraping.is_set():
+                    await asyncio.sleep(0.01)
+                # the scrape is in flight and blocked: the loop still answers
+                resp = await asyncio.wait_for(client.get("/debug/vars"), 10)
+                assert resp.status == 200 and not scrape.done()
+                release.set()
+                resp = await scrape
+                return resp.status, await resp.read()
+            finally:
+                release.set()
+                await client.close()
+
+        assert run(body()) == (200, b"# slow\n")
+
     def test_batch_histograms_and_debug_vars_via_http(self):
         """Acceptance: requests through the engine surface batch-occupancy /
         device-dispatch histograms on /metrics, drained native-frontend

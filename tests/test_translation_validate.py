@@ -120,6 +120,16 @@ def test_own_row_dropped_rejected():
     assert failures[0].detail["config"] == 0
 
 
+def test_own_leaf_rebound_rejected():
+    """ISSUE 28: an own-table leaf that stands for another constant than its
+    corpus leaf's is a corpus-global layout finding: the corpus arrays, all
+    the truth tables read, are still right."""
+    _, failures, _ = certify_snapshot(_mutate("own-leaf-rebound"),
+                                      use_cache=False)
+    assert {f.kind for f in failures} == {"own-rows-layout"}
+    assert "const" in failures[0].message and failures[0].detail["config"] == 0
+
+
 def _mutate(name):
     p = deepcopy(fixture_policy())
     dict(_MUTANTS)[name](p)
