@@ -5,7 +5,8 @@ telemetry, cut into seven stages, each measured where its work happens with
 
   - a cumulative table ``{stage: {count, sum_ns, max_ns}}`` (/debug/vars
     ``native_frontend.stages``): a reader takes the difference of two
-    scrapes, with no profiler running;
+    scrapes, with no profiler running.  Beside the seven stages it holds the
+    row ``drain``, which is no batch's: see ``StageClock.record_drain``;
   - ``auth_server_pipeline_stage_seconds{lane, stage}``;
   - a ``jax.profiler.TraceAnnotation("atpu/<lane>/<stage>", batch=<seq>)``
     around each same-thread stage, which costs nothing without a profiler
@@ -106,6 +107,11 @@ class StageClock:
         # dispatcher threads record plan/encode/launch side by side
         self._lock = threading.Lock()
         self._totals = [[0, 0, 0] for _ in STAGES]  # count, sum_ns, max_ns
+        # not a batch's stage: the drains of what `post` keeps as arrays
+        # into their Prometheus children (utils.metrics.drain), whoever ran
+        # them.  A row of the cumulative table alone: no ring column, no
+        # span, no histogram sample
+        self._drain = [0, 0, 0]
         self._ring = np.zeros((RING, len(FIELDS)), dtype=np.int64)
         self._committed = 0  # one writer: the thread that runs `post`
 
@@ -134,6 +140,14 @@ class StageClock:
                 row[2] = dur_ns
         metrics_mod.observe_pipeline_stage(self.lane, STAGES[k], dur_ns * 1e-9)
 
+    def record_drain(self, dur_ns: int) -> None:
+        with self._lock:
+            row = self._drain
+            row[0] += 1
+            row[1] += dur_ns
+            if dur_ns > row[2]:
+                row[2] = dur_ns
+
     def commit(self, b: BatchTimeline) -> None:
         self._ring[self._committed % RING] = (
             b.seq, b.snap, b.slot, b.rows, b.device_rows, b.pad, b.eff,
@@ -143,7 +157,8 @@ class StageClock:
     def totals(self) -> Dict[str, Dict[str, int]]:
         with self._lock:
             return {s: {"count": c, "sum_ns": total, "max_ns": longest}
-                    for s, (c, total, longest) in zip(STAGES, self._totals)}
+                    for s, (c, total, longest) in zip(
+                        STAGES + ("drain",), self._totals + [self._drain])}
 
     def to_json(self, n: Optional[int] = None) -> Dict[str, Any]:
         """The newest ``n`` batches of the ring (all of it by default),

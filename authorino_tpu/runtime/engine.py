@@ -1053,6 +1053,10 @@ class PolicyEngine:
         RECORDER.record("snapshot-swap", lane="engine", detail={
             "generation": snap.generation, "configs": len(snap.by_id)})
         self._record_control_plane(snap)
+        # a snapshot leaves the history some swaps after its last batch:
+        # draining at each swap names what it still holds as arrays before
+        # then, scraped or not
+        metrics_mod.drain()
         # listeners (the native frontend rebuilding its C++ snapshot) fire
         # BEFORE the advisory analysis: a revoking reconcile must propagate
         # at swap speed, not wait out a bounded-evaluation pass

@@ -31,6 +31,7 @@ pkg/service/auth.go:239-310 (Check flow incl. host override + port strip).
 
 from __future__ import annotations
 
+import itertools
 import logging
 import os
 import threading
@@ -845,6 +846,12 @@ class NativeFrontend:
         self.batch_stages = StageClock("native")
         RECORDER.register_provider("native_batches", self.batch_stages,
                                    "to_json")
+        # how often the named side of `post` still runs: decision records
+        # its sampling gate let through, and Prometheus children the drain
+        # touched (utils.metrics.drain: `post` itself only adds into arrays)
+        self._sampled_decisions = 0
+        self._drained_children = 0
+        self._post_counts_lock = threading.Lock()
 
     # ------------------------------------------------------------------
     def start(self) -> int:
@@ -878,6 +885,7 @@ class NativeFrontend:
         self._threads.append(
             threading.Thread(target=self._metrics_drain_loop,
                              name="atpu-fe-metrics-drain", daemon=True))
+        metrics_mod.DRAIN_OBSERVERS.append(self._observe_drain)
         for t in self._threads:
             t.start()
         self.refresh()
@@ -920,6 +928,7 @@ class NativeFrontend:
                 self._fold_fc_counts()
                 self.drain_histograms()  # final fold: short runs lose nothing
                 self.drain_native_stats()
+                metrics_mod.drain()
             except Exception:
                 log.exception("final metric drain failed")
             self._fe_stopped = True
@@ -933,6 +942,8 @@ class NativeFrontend:
             pass
         for t in self._threads:
             t.join(timeout=5)
+        if self._observe_drain in metrics_mod.DRAIN_OBSERVERS:
+            metrics_mod.DRAIN_OBSERVERS.remove(self._observe_drain)
         # pre-warm compiles can't be interrupted mid-XLA; they bail between
         # variants (self._running) — wait them out so interpreter teardown
         # never force-unwinds a thread inside native code
@@ -970,10 +981,17 @@ class NativeFrontend:
         with self._drain_lock:
             self._stats_drain.fold(self.stats())
 
+    def _observe_drain(self, dur_ns: int, children: int) -> None:
+        self.batch_stages.record_drain(dur_ns)
+        with self._post_counts_lock:
+            self._drained_children += children
+
     def _metrics_drain_loop(self) -> None:
-        """Periodic telemetry drain: fe_stats() deltas → Prometheus on the
-        histogram cadence, independent of traffic (the dispatch loop only
-        drains when batch events wake it)."""
+        """Housekeeping, independent of traffic (the dispatch loop only
+        drains when batch events wake it): fe_stats() deltas → Prometheus,
+        and the cadence drain of what `post` keeps as arrays (per-AuthConfig
+        counters, rule heat map, tenant plane) into their children, so the
+        thread that completes batches never does it."""
         while self._running:
             self._drain_wake.wait(self.hist_drain_s)
             if not self._running:
@@ -981,6 +999,7 @@ class NativeFrontend:
             try:
                 self.drain_native_stats()
                 self._feed_admission()
+                metrics_mod.drain()
             except Exception:
                 log.exception("native stats drain failed")
 
@@ -1015,6 +1034,7 @@ class NativeFrontend:
         rec = self._cur_rec
         from ..native import loaded_digest
 
+        metrics_mod.drain()  # a read: the counters are exact from here
         out: Dict[str, Any] = {
             "running": self._running,
             # sha256 of the C++ sources the loaded extension was built from
@@ -1027,6 +1047,8 @@ class NativeFrontend:
             # {stage: {count, sum_ns, max_ns}}, cumulative: a reader takes
             # the difference of two scrapes (runtime/batch_stages.py)
             "stages": self.batch_stages.totals(),
+            "post": {"sampled_decisions": self._sampled_decisions,
+                     "drained_children": self._drained_children},
             "inflight_batches": self._rb_inflight,
             "inflight_peak": self.rb_inflight_peak,
             "trace_sample_n": self.trace_sample_n,
@@ -1848,6 +1870,8 @@ class NativeFrontend:
             for host in entry.hosts:
                 hosts.append((host, fc_idx))
         rec.fc_rows = np.asarray(fc_rows or [0], dtype=np.int64)
+        if rec.heat is not None:
+            rec.heat.bind_authconfigs(rec.row_labels, rec.hybrid_rows)
 
         # non-fast hosts route to the Python pipeline (slow lane)
         fast_hosts = {h for h, _ in hosts}
@@ -2088,7 +2112,11 @@ class NativeFrontend:
                 # GIL-atomic pop, deliberately NOT under _lock: refresh holds
                 # _lock across its swap-gate jit compile, and blocking here
                 # would stall every batch completion queued behind this event
-                self._snaps.pop(int(a), None)
+                retired = self._snaps.pop(int(a), None)
+                if retired is not None and retired.heat is not None:
+                    # no batch of it is left in flight: name what it still
+                    # holds as arrays before the last reference goes
+                    retired.heat.flush()
             elif kind == EV_STOPPED:
                 break
 
@@ -2628,16 +2656,19 @@ class NativeFrontend:
             with bt.stage("post"):
                 evict_d = 0
                 cache = self._verdict_cache
-                if fan is not None and cache is not None:
+                if fan is not None and cache is not None and u:
                     evict0 = cache.evictions
-                    for r in fan[4]:  # unique rows: freshly evaluated
-                        if fan[1][r]:
-                            # fan[0] carries the FULL cache key (per-config
-                            # token or snap_id already folded in — captured
-                            # from the batch's pinned snapshot at dispatch)
-                            cache.put(fan[0][r], (
-                                int(verdict[r]),
-                                int(firing[r]) if firing is not None else -1))
+                    # unique rows are freshly evaluated: the cacheable ones
+                    # go in at once.  fan[0] carries the FULL cache key
+                    # (per-config token or snap_id already folded in —
+                    # captured from the batch's pinned snapshot at dispatch)
+                    fresh = np.asarray(fan[4], dtype=np.int64)
+                    fresh = fresh[fan[1][fresh]]
+                    cache.put_many(
+                        map(fan[0].__getitem__, fresh.tolist()),
+                        zip(verdict[fresh].tolist(),
+                            firing[fresh].tolist() if firing is not None
+                            else itertools.repeat(-1)))
                     evict_d = cache.evictions - evict0
                 metrics_mod.observe_dedup("native", count, u, cached_n,
                                           elig_miss_n, evict_d)
@@ -2816,10 +2847,11 @@ class NativeFrontend:
         # head-sampled decision record — never per-request Python
         heat = rec.heat
         if heat is not None and firing is not None and count:
-            prov_mod.fold_and_sample(heat, rows, firing, count,
-                                     lane="native", shards=shards_arr,
-                                     latency_ms=dispatch_s * 1e3,
-                                     generation=rec.snap_id)
+            sampled = prov_mod.fold_and_sample(
+                heat, rows, firing, count, lane="native", shards=shards_arr,
+                latency_ms=dispatch_s * 1e3, generation=rec.snap_id)
+            with self._post_counts_lock:  # host-lane workers post too
+                self._sampled_decisions += sampled
         # tenant axis (ISSUE 15): every completed slot — device, lane-
         # selected host AND brownout spill alike (device=False paths
         # included) — folds per-tenant requests/denies/SLO into the shared
@@ -2878,38 +2910,10 @@ class NativeFrontend:
             tracing_mod.export_device_batch_span(count, pad, eff, [],
                                                  t0_ns, dispatch_s)
         # per-authconfig request metrics, same counters + labels the
-        # pipeline bumps (ref pkg/service/auth_pipeline.go:26-36)
-        if shards_arr is not None:
-            from ..parallel.sharded_eval import flat_config_rows
-
-            G = rec.sharded.configs_per_shard
-            flat = flat_config_rows(shards_arr, rows, G)
-            n_per = np.bincount(flat)
-            ok_per = np.bincount(flat, weights=verdict).astype(np.int64)
-            keys = [(int(f // G), int(f % G)) for f in np.nonzero(n_per)[0]]
-            idxs = np.nonzero(n_per)[0]
-        else:
-            n_per = np.bincount(rows)
-            ok_per = np.bincount(rows, weights=verdict).astype(np.int64)
-            idxs = np.nonzero(n_per)[0]
-            keys = [int(f) for f in idxs]
-        for f, key in zip(idxs, keys):
-            n, n_ok = int(n_per[f]), int(ok_per[f])
-            ns, name = rec.row_labels.get(key, ("", ""))
-            if key in rec.hybrid_rows:
-                # kernel-allowed hybrid requests continue into the
-                # pipeline, which observes them itself — only the native
-                # denials are final here
-                n = n - n_ok
-                n_ok = 0
-                if not n:
-                    continue
-            metrics_mod.authconfig_total.labels(ns, name).inc(n)
-            if n_ok:
-                metrics_mod.authconfig_response_status.labels(ns, name, "OK").inc(n_ok)
-            if n - n_ok:
-                metrics_mod.authconfig_response_status.labels(
-                    ns, name, "PERMISSION_DENIED").inc(n - n_ok)
+        # pipeline bumps (ref pkg/service/auth_pipeline.go:26-36), as two
+        # bincounts into the heat map's arrays: the drain names them
+        if heat is not None and count:
+            heat.fold_requests(rows, verdict, shards=shards_arr)
 
     # ------------------------------------------------------------------
     def _completer_loop(self) -> None:

@@ -14,18 +14,21 @@ thrown away.  Here they become:
   degrade) attribute identically because they all reproduce the same
   (rule, skipped) columns;
 - **rule heat map**: ``auth_server_rule_fired_total{authconfig,rule}``,
-  folded per batch via column-sum (``np.bincount`` over a composite
-  (config row, firing column) key — the per-batch Python cost is bounded
-  by the number of DISTINCT (config, rule) pairs in the batch, never the
-  batch size).  The never-fired set cross-references the static
-  constant/shadowed findings (PR 4 policy analysis) in the dead-rule
-  report on ``/debug/vars``;
+  folded per batch into a dense count array indexed by the composite
+  (config row, firing column) key: O(1) Python per batch.  The same heat
+  map keeps the native lane's per-AuthConfig request counters and the
+  decision log's sampling gate as arrays indexed by config row; the named
+  side (Prometheus children) is pushed by the drain
+  (``utils.metrics.drain``), never by the thread that completes batches.
+  The never-fired set cross-references the static constant/shadowed
+  findings (PR 4 policy analysis) in the dead-rule report on
+  ``/debug/vars``;
 - **decision log**: a bounded ring of head-sampled structured decision
   records (host, authconfig, verdict, firing rule, lane, latency, snapshot
   generation) served on ``/debug/decisions`` and pretty-printed by
   ``python -m authorino_tpu.analysis --decisions``.  Sampling is 1-in-N
-  *decisions* with at most one record per batch, so the native fast lane
-  pays one counter compare per batch and a dict build only when sampled.
+  *decisions* per tenant, gated by two arrays on the heat map: a batch pays
+  one vector compare, and a dict build only for the tenants that fire.
 
 Privacy: rule SOURCE strings reach clients (X-Ext-Auth-Reason) only behind
 ``--expose-deny-reason`` (module flag ``EXPOSE_DENY_REASON``); Envoy
@@ -36,9 +39,8 @@ from __future__ import annotations
 
 import threading
 import time
-import weakref
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -47,8 +49,7 @@ from ..utils import metrics as metrics_mod
 __all__ = ["EXPOSE_DENY_REASON", "RULE_LABEL_MAX", "HeatMap", "DecisionLog",
            "DECISIONS", "DecisionSchemaError", "check_decision_schema",
            "rule_label", "deny_provenance", "deny_reason",
-           "dead_rule_report", "fired_pairs", "fold_and_sample",
-           "flush_heatmaps"]
+           "dead_rule_report", "fired_pairs", "fold_and_sample"]
 
 # --expose-deny-reason: when False (default), deny responses keep the
 # generic "Unauthorized" reason and attribution rides only dynamic_metadata
@@ -87,59 +88,22 @@ def _reset_fired_for_tests() -> None:
         _FIRED.clear()
 
 
-# live heat maps, flushed at Prometheus scrape time by _FlushCollector (so
-# rule-fired counters are current on every scrape even when traffic — and
-# with it the amortized in-fold flush — has stopped)
-_LIVE_HEATMAPS: "weakref.WeakSet" = weakref.WeakSet()
-
-
-def flush_heatmaps() -> None:
-    """Flush every live heat map's accumulated deltas into their Prometheus
-    children.  The HTTP /metrics handler calls this BEFORE exposition:
-    collector iteration order puts the registered _FlushCollector after the
-    counter families, so relying on it alone would lag the rule-fired
-    series by one scrape once traffic (and the in-fold flush) stops."""
-    for heat in list(_LIVE_HEATMAPS):
-        try:
-            heat.flush()
-        except Exception:
-            pass
-
-
-class _FlushCollector:
-    """Zero-series collector whose collect() flushes every live heat map —
-    registering it ties scrape time to flush time for registry consumers
-    that bypass the HTTP handler (one-scrape lag at worst)."""
-
-    def collect(self):
-        flush_heatmaps()
-        return []
-
-
-try:
-    from prometheus_client import REGISTRY as _PROM_REGISTRY
-
-    _PROM_REGISTRY.register(_FlushCollector())
-except Exception:  # pragma: no cover - prometheus is baked in, but stay safe
-    pass
-
-
 class HeatMap:
     """Per-snapshot attribution folder: kernel config rows → (authconfig
-    name, per-evaluator rule sources), with cached Prometheus label
-    children per (row, firing column).
+    name, per-evaluator rule sources).  Everything a batch's completion
+    records per config lives here as dense arrays indexed by the flat
+    config row (``shard * configs_per_shard + row`` on a mesh corpus):
 
-    ``fold(rows, firing)`` is the one entry point both lanes call once per
-    batch: rows/firing are int arrays; the composite-key bincount keeps the
-    Python work bounded by distinct (config, rule) pairs."""
+    - rule-fired counts, by (row, firing column): ``fold``;
+    - the native lane's per-AuthConfig request counters: ``fold_requests``;
+    - the decision log's per-tenant sampling gate: ``sample_gate``.
 
-    # Prometheus flush cadence: fold() accumulates into a plain int64 array
-    # (one vectorized np.add.at per batch — Python work is O(1) per batch);
-    # the per-(config,rule) counter children only see the accumulated
-    # deltas every FLUSH_S seconds, on a /debug read, or at scrape time
-    # (the registered _FlushCollector).  Counters may lag a flush period;
-    # they never lose counts.
-    FLUSH_S = 2.0
+    Each is a few vector operations a batch.  ``flush()`` pushes what moved
+    since the last flush into cached Prometheus children; the drain
+    (``utils.metrics.drain``) calls it on the native frontend's
+    housekeeping cadence (``hist_drain_s``) and before every read, so
+    counters may lag a cadence inside the process and are exact wherever
+    they are read."""
 
     def __init__(self, names_by_row: Sequence[str],
                  sources_by_row: Sequence[Sequence[str]], n_evaluators: int,
@@ -152,13 +116,27 @@ class HeatMap:
         self.configs_per_shard = configs_per_shard
         self._children: Dict[int, Any] = {}   # composite key -> counter child
         self._lock = threading.Lock()
-        n_keys = max(1, len(self.names_by_row)) * (self.E + 1)
-        self._counts = np.zeros(n_keys, dtype=np.int64)
-        self._flushed = np.zeros(n_keys, dtype=np.int64)
-        self._last_flush = time.monotonic()
+        G = max(1, len(self.names_by_row))
+        self._counts = np.zeros(G * (self.E + 1), dtype=np.int64)
+        self._flushed = np.zeros_like(self._counts)
+        # per-AuthConfig request counters: requests and OKs by row, the
+        # (namespace, name) labels and the hybrid mask bound by the native
+        # frontend at snapshot build, children minted on a row's first flush
+        self.requests = np.zeros(G, dtype=np.int64)
+        self.ok = np.zeros(G, dtype=np.int64)
+        self._requests_flushed = np.zeros(G, dtype=np.int64)
+        self._ok_flushed = np.zeros(G, dtype=np.int64)
+        self.hybrid = np.zeros(G, dtype=bool)
+        self._authconfig_labels: List[Tuple[str, str]] = [("", "")] * G
+        self._authconfig_children: Dict[int, list] = {}
+        # the sampling gate: decisions seen by row, and the count at which
+        # the row next samples (1: a row's first decision always does)
+        self.seen = np.zeros(G, dtype=np.int64)
+        self.next_fire = np.ones(G, dtype=np.int64)
+        self._gate_epoch = 0
         self.fold_calls = 0       # per-batch evidence for the perf guard
         self.fold_seconds = 0.0   # cumulative fold cost (bench overhead delta)
-        _LIVE_HEATMAPS.add(self)
+        metrics_mod.register_drainable(self)
 
     # -- construction ------------------------------------------------------
 
@@ -195,12 +173,18 @@ class HeatMap:
 
     # -- folding -----------------------------------------------------------
 
+    def flat_rows(self, rows, shards=None) -> np.ndarray:
+        rows = np.asarray(rows, dtype=np.int64)
+        if shards is not None and self.configs_per_shard:
+            return np.asarray(shards, dtype=np.int64) * \
+                self.configs_per_shard + rows
+        return rows
+
     def fold(self, rows, firing, shards=None) -> None:
         """Fold one batch's attribution into the heat map: ONE vectorized
         np.add.at into the composite-key count array — Python work is O(1)
         per batch, independent of batch size AND of the number of distinct
-        rules.  Prometheus children are refreshed by flush() (amortized
-        here on the FLUSH_S cadence, and forced by scrapes/debug reads).
+        rules.
 
         fold_seconds meters THREAD CPU time, not wall: on a saturated box
         the encode-pool thread gets preempted mid-fold, and a wall meter
@@ -208,40 +192,93 @@ class HeatMap:
         inflation on the CPU-only bench image, where the 'device' kernel
         competes for the same cores)."""
         t0 = time.thread_time()
-        rows = np.asarray(rows, dtype=np.int64)
+        rows = self.flat_rows(rows, shards)
         firing = np.asarray(firing, dtype=np.int64)
-        if shards is not None and self.configs_per_shard:
-            rows = np.asarray(shards, dtype=np.int64) * \
-                self.configs_per_shard + rows
         self.fold_calls += 1
         denied = firing >= 0
         if denied.any():
             comp = rows[denied] * (self.E + 1) + firing[denied]
             with self._lock:
                 np.add.at(self._counts, comp, 1)
-        if time.monotonic() - self._last_flush > self.FLUSH_S:
-            self._flush_locked_free()
         self.fold_seconds += time.thread_time() - t0
 
-    def flush(self) -> None:
-        """Push accumulated deltas into the per-(config,rule) Prometheus
-        children and the process-wide fired set.  Cost is bounded by the
-        number of distinct pairs that moved since the last flush — paid on
-        the flush cadence / scrape, never per batch."""
-        self._flush_locked_free()
+    def bind_authconfigs(self, labels: Dict[Any, Tuple[str, str]],
+                         hybrid=()) -> None:
+        """Snapshot build: the (namespace, name) labels of the kernel rows
+        the native lane answers, and which of them are HYBRID configs (a
+        kernel-allowed hybrid request continues into the pipeline, which
+        observes it itself: only its native denials count here).  Keys are
+        rows, or (shard, row) on a mesh corpus."""
+        mask = np.zeros_like(self.hybrid)
+        for key in hybrid:
+            mask[self._flat_key(key)] = True
+        for key, label in labels.items():
+            self._authconfig_labels[self._flat_key(key)] = label
+        self.hybrid = mask
 
-    def _flush_locked_free(self) -> None:
+    def _flat_key(self, key) -> int:
+        if isinstance(key, tuple):
+            return key[0] * self.configs_per_shard + key[1]
+        return key
+
+    def fold_requests(self, rows, verdict, shards=None) -> None:
+        """One batch's per-AuthConfig request counters (the counters and
+        labels the pipeline bumps, ref pkg/service/auth_pipeline.go:26-36):
+        two bincounts into the dense arrays."""
+        flat = self.flat_rows(rows, shards)
+        ok = np.asarray(verdict) != 0
+        hybrid = self.hybrid[flat]
+        counted = ~(hybrid & ok)
+        ok &= ~hybrid
+        G = self.requests.size
+        with self._lock:
+            self.requests += np.bincount(flat[counted], minlength=G)
+            self.ok += np.bincount(flat[ok], minlength=G)
+
+    def sample_gate(self, flat: np.ndarray, sample_n: int, epoch: int):
+        """The decision log's stratified 1-in-N gate, as arrays: advance
+        each distinct row's decision count by its rows in the batch; a row
+        fires on its first decision in this snapshot and then once every
+        ``sample_n`` decisions.  Returns (rows that fire, index in the batch
+        of each one's first request).  ``epoch`` re-arms every row when the
+        log is reconfigured."""
+        uniq, first, counts = np.unique(flat, return_index=True,
+                                        return_counts=True)
+        with self._lock:
+            if epoch != self._gate_epoch:
+                self._gate_epoch = epoch
+                self.seen[:] = 0
+                self.next_fire[:] = 1
+            seen = self.seen[uniq] + counts
+            self.seen[uniq] = seen
+            hit = seen >= self.next_fire[uniq]
+            self.next_fire[uniq[hit]] = seen[hit] + sample_n
+        return uniq[hit], first[hit]
+
+    def flush(self) -> int:
+        """Push what moved since the last flush into the Prometheus
+        children (and the process-wide fired set); returns the children
+        touched.  Cost is bounded by the distinct (config, rule) pairs and
+        configs that moved — paid by the drain, never per batch."""
         with self._lock:
             delta = self._counts - self._flushed
-            moved = np.nonzero(delta)[0]
-            if moved.size == 0:
-                self._last_flush = time.monotonic()
-                return
+            fired = np.nonzero(delta)[0]
+            fired_n = delta[fired]
             np.copyto(self._flushed, self._counts)
-            self._last_flush = time.monotonic()
-            amounts = delta[moved]
-        for key, n in zip(moved, amounts):
-            self._bump(int(key), int(n))
+            # an OK is a request, so a row whose OKs moved is among these
+            delta = self.requests - self._requests_flushed
+            rows = np.nonzero(delta)[0]
+            rows_n = delta[rows]
+            rows_ok = (self.ok - self._ok_flushed)[rows]
+            np.copyto(self._requests_flushed, self.requests)
+            np.copyto(self._ok_flushed, self.ok)
+        for key, n in zip(fired.tolist(), fired_n.tolist()):
+            self._bump(key, n)
+        children = int(fired.size)
+        for row, n, n_ok in zip(rows.tolist(), rows_n.tolist(),
+                                rows_ok.tolist()):
+            children += self._bump_authconfig(row, n, n_ok)
+        return children
 
     def _bump(self, comp_key: int, n: int) -> None:
         child = self._children.get(comp_key)
@@ -262,6 +299,29 @@ class HeatMap:
             with _FIRED_LOCK:
                 _FIRED.add((name, col))
         child.inc(n)
+
+    def _bump_authconfig(self, row: int, n: int, n_ok: int) -> int:
+        """A series appears with its first count, as where the pipeline
+        bumps the same families: children are minted lazily, each once."""
+        children = self._authconfig_children.get(row)
+        if children is None:
+            children = self._authconfig_children[row] = [None, None, None]
+        touched = 0
+        for k, amount in enumerate((n, n_ok, n - n_ok)):
+            if amount:
+                child = children[k]
+                if child is None:
+                    child = children[k] = self._mint_authconfig(row, k)
+                child.inc(amount)
+                touched += 1
+        return touched
+
+    def _mint_authconfig(self, row: int, k: int):
+        ns, name = self._authconfig_labels[row]
+        if k == 0:
+            return metrics_mod.authconfig_total.labels(ns, name)
+        return metrics_mod.authconfig_response_status.labels(
+            ns, name, "OK" if k == 1 else "PERMISSION_DENIED")
 
     # -- attribution lookups ----------------------------------------------
 
@@ -392,11 +452,13 @@ class DecisionLog:
     every cold-tenant record from the bounded ring, so /debug/decisions
     showed exactly one tenant.  Now:
 
-    - ``should_sample_tenant(tenant, n)`` keeps an independent 1-in-N
-      counter PER tenant (bounded LRU table), and ``fold_and_sample``
-      fires it once per distinct tenant in the batch — at most one record
-      per tenant per batch, Python work bounded by distinct tenants (the
-      same composite-key discipline as the heat-map fold);
+    - every tenant has its own 1-in-N counter, and ``fold_and_sample``
+      steps it once per distinct tenant in the batch: at most one record
+      per tenant per batch.  The counters of a snapshot's tenants are two
+      arrays on its heat map (``HeatMap.sample_gate``: bounded by the
+      corpus, per snapshot; Python runs only for the tenants that fire);
+      ``should_sample_tenant(tenant, n)`` is the same gate by name, over a
+      bounded LRU table, for callers that have no heat map;
     - alongside the global ring, each tenant keeps a small per-tenant
       sub-ring (``tenant_capacity`` newest records, LRU-bounded tenants),
       so a hot tenant filling the global ring can never evict a cold
@@ -422,6 +484,8 @@ class DecisionLog:
         self._next_fire = 1  # first decision samples (head of the stream)
         # tenant -> [seen, next_fire]; insertion order is the LRU axis
         self._tenant_gate: Dict[str, list] = {}
+        # bumped when the rate changes: every heat map's gate re-arms
+        self.gate_epoch = 0
         # tenant -> deque(maxlen=tenant_capacity) of its newest records
         self._tenant_ring: Dict[str, deque] = {}
         self.records_total = 0
@@ -439,6 +503,7 @@ class DecisionLog:
             self._next_fire = self._seen + self.sample_n
             with self._lock:
                 self._tenant_gate.clear()
+                self.gate_epoch += 1
 
     def should_sample(self, n_decisions: int) -> bool:
         """Advance the decision counter by this batch's size; True when the
@@ -539,33 +604,25 @@ DECISIONS = DecisionLog()
 def fold_and_sample(heat: HeatMap, rows, firing, n: int, *, lane: str,
                     shards=None, host: str = "", latency_ms: float = 0.0,
                     generation: Any = None, host_of=None,
-                    latency_of=None) -> None:
+                    latency_of=None) -> int:
     """The one per-batch observability sequence every lane's completion
     runs: fold the batch's attribution into the heat map, then sample
     decision records STRATIFIED per tenant — at most one record per
     distinct tenant (authconfig) per batch, each tenant gated by its own
     1-in-N counter, so a zipf-hot tenant can neither win every sample nor
-    evict the cold tenants' records (ISSUE 15 satellite).  Python work is
-    bounded by distinct tenants in the batch, never the batch size.
-    Keeping it here means a schema or sampling change lands once, not once
-    per lane."""
+    evict the cold tenants' records (ISSUE 15 satellite).  The gate is a
+    vector compare on the heat map's arrays; Python runs only for the
+    tenants that fire: a tenant's first decision in a snapshot, then one in
+    ``sample_n``.  Returns the records made.  Keeping it here means a schema
+    or sampling change lands once, not once per lane."""
     heat.fold(rows, firing, shards=shards)
     if not n:
-        return
-    rows_a = np.asarray(rows, dtype=np.int64)
-    flat = rows_a
-    if shards is not None and heat.configs_per_shard:
-        flat = np.asarray(shards, dtype=np.int64) * \
-            heat.configs_per_shard + rows_a
-    uniq, first, counts = np.unique(flat, return_index=True,
-                                    return_counts=True)
-    for u, i, k in zip(uniq, first, counts):
-        name = heat.name(int(u))
-        if not DECISIONS.should_sample_tenant(name, int(k)):
-            continue
-        i = int(i)
+        return 0
+    hit_rows, hit_first = heat.sample_gate(
+        heat.flat_rows(rows, shards), DECISIONS.sample_n,
+        DECISIONS.gate_epoch)
+    for u, i in zip(hit_rows.tolist(), hit_first.tolist()):
         col = int(firing[i])
-        row_i = int(rows_a[i])
         shard_i = int(shards[i]) if shards is not None else None
         # per-record resolvers (``host_of``/``latency_of``, called only
         # for SAMPLED tenants): each tenant's record carries ITS OWN
@@ -575,14 +632,16 @@ def fold_and_sample(heat: HeatMap, rows, firing, n: int, *, lane: str,
         DECISIONS.record(
             lane=lane,
             host=(host_of(i) if host_of is not None else host),
-            authconfig=name,
+            authconfig=heat.name(u),
             verdict=col < 0,
-            rule=(rule_label(col, heat.source(row_i, col, shard=shard_i))
+            rule=(rule_label(col, heat.source(int(rows[i]), col,
+                                              shard=shard_i))
                   if col >= 0 else None),
             rule_index=col,
             latency_ms=(latency_of(i) if latency_of is not None
                         else latency_ms),
             generation=generation)
+    return len(hit_rows)
 
 
 # ---------------------------------------------------------------------------

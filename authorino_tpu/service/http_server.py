@@ -233,21 +233,16 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
         try:
             from prometheus_client import CONTENT_TYPE_LATEST, generate_latest
 
-            from ..runtime import provenance as prov_mod
-
-            def exposition() -> bytes:
-                # rule heat maps accumulate in-process and flush on a
-                # cadence; flushing here makes the rule-fired series current
-                # on THIS scrape (collector ordering alone lags it by one)
-                prov_mod.flush_heatmaps()
-                return generate_latest()
-
-            # off the event loop: the per-AuthConfig families make the text
-            # 27 MB at 10,000 configs, seconds of Python during which this
-            # loop would answer nothing else (/readyz, /debug/vars, a
-            # /debug/profile asked for NOW would start seconds late)
+            # the registry drains what is kept as arrays (per-AuthConfig
+            # counters, rule heat maps, the tenant plane) before it collects
+            # a family (utils.metrics._DrainCollector): the series are
+            # current on THIS scrape.  Off the event loop: the per-AuthConfig
+            # families make the text 27 MB at 10,000 configs, seconds of
+            # Python during which this loop would answer nothing else
+            # (/readyz, /debug/vars, a /debug/profile asked for NOW would
+            # start seconds late)
             body = await asyncio.get_running_loop().run_in_executor(
-                None, exposition)
+                None, generate_latest)
             return web.Response(body=body, content_type="text/plain")
         except Exception:
             return web.Response(status=501, text="prometheus_client unavailable")

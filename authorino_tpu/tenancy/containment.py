@@ -97,7 +97,6 @@ class NoisyNeighborDetector:
 
     def check(self, now: Optional[float] = None) -> None:
         now = time.monotonic() if now is None else now
-        shares = self.stats.shares()
         wait_hot = self.wait_ewma() > self.target_s()
         if self.reject_count is not None:
             try:
@@ -107,6 +106,12 @@ class NoisyNeighborDetector:
             if r > self._last_rejects:
                 wait_hot = True
             self._last_rejects = r
+        if not wait_hot and not self._contained:
+            # no pressure and nobody to release: the shares are not needed
+            with self._lock:
+                self._hot_since.clear()
+            return
+        shares = self.stats.shares()
         weights_among = list(shares) or None
         with self._lock:
             # --- containment candidates

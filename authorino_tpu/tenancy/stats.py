@@ -1,56 +1,47 @@
 """Per-tenant observability folds (ISSUE 15): the tenant axis of PR 9's
 vectorized provenance/SLO folds.
 
-One call per micro-batch (never per request): ``fold`` groups the batch's
-kernel rows by tenant with one ``np.unique`` + ``np.bincount`` pass — the
-Python work is bounded by DISTINCT tenants in the batch, exactly the
-composite-key discipline the rule heat map set — and accumulates per-tenant
-requests, denies, queue-wait means, SLO bad counts and a served-rate EWMA
-(the noisy-neighbor detector's share signal).
+One call per micro-batch (never per request), and no Python per tenant: the
+plane keeps every tenant's state in arrays indexed by a slot (``name ->
+slot``, minted on the tenant's first row), a heat map's ``row -> slot``
+vector is resolved once a row, and ``fold`` is a gather, a few bincounts and
+the EWMA steps under masks.  It accumulates per-tenant requests, denies,
+queue-wait means, SLO bad counts and burn, and a served-rate EWMA (the
+noisy-neighbor detector's share signal).
 
 Prometheus exposition is bounded-cardinality by construction: the flush
-(amortized on a cadence, forced by /debug reads) assigns real tenant label
-values only to the top-K tenants by cumulative request volume and folds
-everyone else into the reserved ``other`` bucket.  K is clamped to the
-family's declared hard bound in ``utils.metrics.TENANT_LABEL_BOUNDS`` —
-the table the metrics-catalog cardinality lint enforces."""
+(run by the drain: ``utils.metrics.drain``, on the housekeeping cadence and
+before every read) assigns real tenant label values only to the top-K
+tenants by cumulative request volume and folds everyone else into the
+reserved ``other`` bucket, summing the deltas by label before it touches a
+child.  K is clamped to the family's declared hard bound in
+``utils.metrics.TENANT_LABEL_BOUNDS`` — the table the metrics-catalog
+cardinality lint enforces."""
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, Optional
+import weakref
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 from ..utils import metrics as metrics_mod
-from ..utils.slo import KeyedBurn
 
 __all__ = ["TenantStats"]
 
-
-class _TenantCounters:
-    __slots__ = ("requests", "denies", "slo_bad", "wait_ewma", "rate_ewma",
-                 "rate_t", "rate_pend", "last_seen")
-
-    def __init__(self, now: float):
-        self.requests = 0
-        self.denies = 0
-        self.slo_bad = 0
-        self.wait_ewma = 0.0
-        self.rate_ewma = 0.0   # served rows/s (decaying)
-        self.rate_t = now
-        # rows folded since the last rate-EWMA step: batches can land far
-        # faster than the 50ms rate window, and dividing only the LAST
-        # batch's rows by the full elapsed dt would silently undercount
-        # exactly the hot tenants the detector's share signal exists for
-        self.rate_pend = 0
-        self.last_seen = now
+# per-slot state, one array each.  Counts are int64; clocks and EWMAs
+# float64.  The burn columns are one coarse sliding window a tenant (the
+# tenant axis of utils.slo's burn-rate fold): two half-window buckets,
+# current (burn_total, burn_bad, since burn_t) and previous (burn_prev_*),
+# rotated in place, so burn reads the sum of both.
+_INT_COLUMNS = ("requests", "denies", "slo_bad", "rate_pend", "burn_total",
+                "burn_bad", "burn_prev_total", "burn_prev_bad", "label")
+_FLOAT_COLUMNS = ("rate_t", "rate_ewma", "wait_ewma", "last_seen", "burn_t")
 
 
 class TenantStats:
-    FLUSH_S = 2.0
-
     def __init__(self, lane: str, top_k: int = 16, max_tenants: int = 8192,
                  burn_window_s: float = 60.0, gc_idle_s: float = 600.0):
         self.lane = lane
@@ -59,20 +50,78 @@ class TenantStats:
         self.top_k = max(1, min(int(top_k), bound))
         self.max_tenants = int(max_tenants)
         self.gc_idle_s = float(gc_idle_s)
+        self.burn_window_s = float(burn_window_s)
+        self.burn_budget = 1.0 - 0.999  # the error budget of a 99.9 % SLO
+        self._burn_swept = 0.0
         self._lock = threading.Lock()
-        self._t: Dict[str, _TenantCounters] = {}
-        # Prometheus deltas keyed by the FOLD's lane (the plane is shared
-        # across engine + native; the aggregate _t table serves shares/
-        # waits, but exported counters must say which lane served)
-        self._lane_delta: Dict[str, Dict[str, list]] = {}
-        self.burn = KeyedBurn(window_s=burn_window_s)
-        self._last_flush = time.monotonic()
+        self._slot: Dict[str, int] = {}
+        self._names: List[str] = []
+        # Prometheus deltas by the FOLD's lane, [requests, denies, slo_bad]
+        # x slot (the plane is shared across engine + native; the slot
+        # arrays serve shares/waits, but exported counters must say which
+        # lane served)
+        self._lane_delta: Dict[str, np.ndarray] = {}
+        self._grow(64)
+        # heat map -> its row -> slot vector (-2: row not met yet, -1: a
+        # padded row with no name); cleared when slots are compacted
+        self._row_slots: "weakref.WeakKeyDictionary" = \
+            weakref.WeakKeyDictionary()
         self._label_of: Dict[str, str] = {}  # tenant -> prometheus label
+        self._label_names: List[str] = [metrics_mod.TENANT_OTHER]
+        self._children: Dict[Any, Any] = {}
         self.fold_calls = 0
         self.total_requests = 0
         # wait-observation sink (TenantAdmission.observe_waits), attached
         # by the plane so the per-tenant CoDel signal rides this same fold
         self.wait_sink = None
+        metrics_mod.register_drainable(self)
+
+    def _grow(self, capacity: int) -> None:
+        for columns, dtype in ((_INT_COLUMNS, np.int64),
+                               (_FLOAT_COLUMNS, np.float64)):
+            for column in columns:
+                grown = np.zeros(capacity, dtype=dtype)
+                old = getattr(self, column, None)
+                if old is not None:
+                    grown[:old.size] = old
+                setattr(self, column, grown)
+        for lane, delta in self._lane_delta.items():
+            grown = np.zeros((3, capacity), dtype=np.int64)
+            grown[:, :delta.shape[1]] = delta
+            self._lane_delta[lane] = grown
+
+    def _mint(self, name: str, now: float) -> int:
+        slot = self._slot.get(name)
+        if slot is None:
+            slot = self._slot[name] = len(self._names)
+            self._names.append(name)
+            if slot >= self.requests.size:
+                self._grow(2 * self.requests.size)
+            self.rate_t[slot] = self.last_seen[slot] = now
+            if name in self._label_of:  # dropped idle, and back
+                self.label[slot] = self._label_names.index(name)
+        return slot
+
+    def _slots_of(self, heat, flat: np.ndarray, now: float) -> np.ndarray:
+        """The batch's rows as tenant slots.  A row costs Python once a
+        snapshot: its name is looked up, and minted, the first time it
+        appears."""
+        vec = self._row_slots.get(heat)
+        top = int(flat.max()) + 1
+        if vec is None or vec.size < top:
+            grown = np.full(max(top, len(getattr(heat, "names_by_row", ()))),
+                            -2, dtype=np.int64)
+            if vec is not None:
+                grown[:vec.size] = vec
+            vec = self._row_slots[heat] = grown
+        slots = vec[flat]
+        unmet = slots == -2
+        if unmet.any():
+            for row in np.unique(flat[unmet]).tolist():
+                name = heat.name(row)
+                vec[row] = self._mint(name, now) if name else -1
+            slots = vec[flat]
+        return slots
 
     # -- folding (one call per batch) ---------------------------------------
 
@@ -96,74 +145,96 @@ class TenantStats:
             return
         now = time.monotonic() if now is None else now
         lane = lane or self.lane
-        self.fold_calls += 1
-        self.total_requests += n
         flat = rows
         cps = getattr(heat, "configs_per_shard", None)
         if shards is not None and cps:
             flat = np.asarray(shards, dtype=np.int64) * cps + rows
         if denied_mask is None and firing is not None:
             denied_mask = np.asarray(firing, dtype=np.int64) >= 0
-        uniq, inv = np.unique(flat, return_inverse=True)
-        tot = np.bincount(inv, minlength=len(uniq))
-        den = (np.bincount(inv[denied_mask], minlength=len(uniq))
-               if denied_mask is not None and np.any(denied_mask)
-               else np.zeros(len(uniq), dtype=np.int64))
         if waits is not None:
             waits = np.asarray(waits, dtype=np.float64)
-            if waits.size == n:
-                wsum = np.bincount(inv, weights=waits, minlength=len(uniq))
-                wmin = np.full(len(uniq), np.inf)
-                np.minimum.at(wmin, inv, waits)
-            else:
+            if waits.size != n:
                 waits = None
-        bad = None
-        if bad_mask is not None:
-            bad_mask = np.asarray(bad_mask, dtype=bool)
-            bad = (np.bincount(inv[bad_mask], minlength=len(uniq))
-                   if np.any(bad_mask)
-                   else np.zeros(len(uniq), dtype=np.int64))
         with self._lock:
-            per_lane = self._lane_delta.setdefault(lane, {})
-            for i, u in enumerate(uniq):
-                name = heat.name(int(u))
-                if not name:
-                    continue
-                c = self._t.get(name)
-                if c is None:
-                    c = self._t[name] = _TenantCounters(now)
-                k = int(tot[i])
-                c.requests += k
-                c.denies += int(den[i])
-                c.last_seen = now
-                # served-rate EWMA: rows accumulate across folds inside
-                # the 50ms window, then the whole window's rows divide
-                # the elapsed dt (never just the last batch's)
-                c.rate_pend += k
-                dt = now - c.rate_t
-                if dt > 0.05:
-                    inst = c.rate_pend / dt
-                    c.rate_ewma = inst if not c.rate_ewma else \
-                        0.7 * c.rate_ewma + 0.3 * inst
-                    c.rate_t = now
-                    c.rate_pend = 0
-                if waits is not None:
-                    mean = float(wsum[i]) / k
-                    c.wait_ewma = mean if not c.wait_ewma else \
-                        0.8 * c.wait_ewma + 0.2 * mean
-                    if self.wait_sink is not None:
-                        self.wait_sink(name, mean, float(wmin[i]), now)
-                b = int(bad[i]) if bad is not None else 0
-                if b:
-                    c.slo_bad += b
-                if bad is not None:
-                    self.burn.fold(name, k, b, now=now)
-                d = per_lane.setdefault(name, [0, 0, 0])
-                d[0] += k
-                d[1] += int(den[i])
-                d[2] += b
-        if now - self._last_flush > self.FLUSH_S:
-            self.flush(now=now)
+            self.fold_calls += 1
+            self.total_requests += n
+            slots = self._slots_of(heat, flat, now)
+            named = slots >= 0
+            u, inv = np.unique(slots[named], return_inverse=True)
+            if not u.size:
+                return
+            k = np.bincount(inv, minlength=u.size)
+
+            def per_slot(mask):
+                return np.bincount(inv[np.asarray(mask, dtype=bool)[named]],
+                                   minlength=u.size)
+
+            den = per_slot(denied_mask) if denied_mask is not None else 0
+            bad = per_slot(bad_mask) if bad_mask is not None else 0
+            self.requests[u] += k
+            self.denies[u] += den
+            self.slo_bad[u] += bad
+            self.last_seen[u] = now
+            delta = self._lane_delta.get(lane)
+            if delta is None:
+                delta = self._lane_delta[lane] = np.zeros(
+                    (3, self.requests.size), dtype=np.int64)
+            delta[0, u] += k
+            delta[1, u] += den
+            delta[2, u] += bad
+            # served-rate EWMA: rows accumulate across folds inside the
+            # 50ms window, then the whole window's rows divide the elapsed
+            # dt (never just the last batch's: batches land far faster than
+            # the window, and that would undercount exactly the hot tenants
+            # the detector's share signal exists for)
+            self.rate_pend[u] += k
+            dt = now - self.rate_t[u]
+            due = dt > 0.05
+            if due.any():
+                s = u[due]
+                inst = self.rate_pend[s] / dt[due]
+                old = self.rate_ewma[s]
+                self.rate_ewma[s] = np.where(old == 0.0, inst,
+                                             0.7 * old + 0.3 * inst)
+                self.rate_t[s] = now
+                self.rate_pend[s] = 0
+            if waits is not None:
+                mean = np.bincount(inv, weights=waits[named],
+                                   minlength=u.size) / k
+                old = self.wait_ewma[u]
+                self.wait_ewma[u] = np.where(old == 0.0, mean,
+                                             0.8 * old + 0.2 * mean)
+                if self.wait_sink is not None:
+                    # the per-tenant CoDel signal: the one call a tenant
+                    # left, on the lanes that clock a queue (the engine's)
+                    least = np.full(u.size, np.inf)
+                    np.minimum.at(least, inv, waits[named])
+                    for slot, m, w in zip(u.tolist(), mean.tolist(),
+                                          least.tolist()):
+                        self.wait_sink(self._names[slot], m, w, now)
+            if bad_mask is not None:
+                self._fold_burn(u, k, bad, now)
+
+    def _fold_burn(self, u, k, bad, now: float) -> None:
+        age = now - self.burn_t[u]
+        turn = age >= self.burn_window_s / 2.0
+        if turn.any():
+            s = u[turn]
+            # past a whole window both halves are stale
+            fresh = age[turn] < self.burn_window_s
+            self.burn_prev_total[s] = np.where(fresh, self.burn_total[s], 0)
+            self.burn_prev_bad[s] = np.where(fresh, self.burn_bad[s], 0)
+            self.burn_total[s] = self.burn_bad[s] = 0
+            self.burn_t[s] = now
+        self.burn_total[u] += k
+        self.burn_bad[u] += bad
+        if now - self._burn_swept > self.burn_window_s:
+            # once a window: a tenant idle for a whole one reads nothing
+            self._burn_swept = now
+            idle = now - self.burn_t > self.burn_window_s
+            for column in ("burn_total", "burn_bad", "burn_prev_total",
+                           "burn_prev_bad", "burn_t"):
+                getattr(self, column)[idle] = 0
 
     # -- shares (the detector's signal) -------------------------------------
 
@@ -171,24 +242,25 @@ class TenantStats:
         """This tenant's share of the lane's recently-served rows (rate
         EWMAs — decays as traffic shifts)."""
         with self._lock:
-            c = self._t.get(tenant)
-            if c is None or not c.rate_ewma:
+            slot = self._slot.get(tenant)
+            if slot is None or not self.rate_ewma[slot]:
                 return 0.0
-            total = sum(x.rate_ewma for x in self._t.values())
-            return c.rate_ewma / total if total > 0 else 0.0
+            total = float(self.rate_ewma.sum())
+            return float(self.rate_ewma[slot]) / total if total > 0 else 0.0
 
     def shares(self) -> Dict[str, float]:
         with self._lock:
-            total = sum(x.rate_ewma for x in self._t.values())
+            total = float(self.rate_ewma.sum())
             if total <= 0:
                 return {}
-            return {t: c.rate_ewma / total for t, c in self._t.items()
-                    if c.rate_ewma > 0}
+            live = np.nonzero(self.rate_ewma > 0)[0]
+            return dict(zip((self._names[s] for s in live.tolist()),
+                            (self.rate_ewma[live] / total).tolist()))
 
     def rate(self, tenant: str) -> float:
         with self._lock:
-            c = self._t.get(tenant)
-            return c.rate_ewma if c is not None else 0.0
+            slot = self._slot.get(tenant)
+            return float(self.rate_ewma[slot]) if slot is not None else 0.0
 
     def export_fold(self) -> Dict[str, Dict[str, float]]:
         """Raw per-tenant counters for the fleet fold publisher (ISSUE 18):
@@ -200,57 +272,91 @@ class TenantStats:
         entitled on every replica at once — the exact blindness the global
         fold exists to remove)."""
         with self._lock:
-            return {name: {
-                "requests": c.requests,
-                "denies": c.denies,
-                "slo_bad": c.slo_bad,
-                "rate": c.rate_ewma,
-            } for name, c in self._t.items()}
+            n = len(self._names)
+            return {name: {"requests": r, "denies": d, "slo_bad": b,
+                           "rate": e}
+                    for name, r, d, b, e in zip(
+                        self._names, self.requests[:n].tolist(),
+                        self.denies[:n].tolist(), self.slo_bad[:n].tolist(),
+                        self.rate_ewma[:n].tolist())}
 
     # -- prometheus flush (top-K + other) -----------------------------------
 
-    def _labels(self) -> Dict[str, str]:
-        """Tenant -> label value: the top-K tenants by cumulative volume
-        get their own value, everyone else folds into `other`.  A tenant
-        that falls OUT of the top-K keeps its minted label (monotonic
-        counters must not teleport into `other`); the hard bound holds
-        because minted labels only grow to the bound and then stop."""
-        ranked = sorted(self._t.items(), key=lambda kv: -kv[1].requests)
+    def _labels(self) -> None:
+        """Mint label values: the top-K tenants by cumulative volume get
+        their own, everyone else folds into `other` (label index 0).  A
+        tenant that falls OUT of the top-K keeps its minted label
+        (monotonic counters must not teleport into `other`); the hard bound
+        holds because minted labels only grow to the bound and then
+        stop."""
+        n = len(self._names)
         bound = min(metrics_mod.TENANT_LABEL_BOUNDS.get(
             "auth_server_tenant_requests_total", 32), 32)
-        for name, _ in ranked[:self.top_k]:
+        ranked = np.argsort(-self.requests[:n], kind="stable")[:self.top_k]
+        for slot in ranked.tolist():
+            name = self._names[slot]
             if name not in self._label_of and len(self._label_of) < bound:
                 self._label_of[name] = name
-        return self._label_of
+                self.label[slot] = len(self._label_names)
+                self._label_names.append(name)
 
-    def flush(self, now: Optional[float] = None) -> None:
+    def _child(self, family, *labels):
+        child = self._children.get((family, labels))
+        if child is None:
+            child = self._children[(family, labels)] = family.labels(*labels)
+        return child
+
+    def flush(self, now: Optional[float] = None) -> int:
+        """Push the deltas since the last flush into the Prometheus
+        children, summed by label first: a child is touched once a flush,
+        however many tenants share its label.  Returns the children
+        touched."""
         now = time.monotonic() if now is None else now
+        families = (metrics_mod.tenant_requests, metrics_mod.tenant_denied,
+                    metrics_mod.tenant_slo_bad)
         with self._lock:
-            self._last_flush = now
-            labels = self._labels()
-            deltas = []
-            for lane, per in self._lane_delta.items():
-                for name, (dr, dd, db) in per.items():
-                    deltas.append((lane,
-                                   labels.get(name,
-                                              metrics_mod.TENANT_OTHER),
-                                   dr, dd, db))
-            self._lane_delta.clear()
-            gauges = [(labels[name], c.wait_ewma) for name, c in
-                      self._t.items() if name in labels]
-            if len(self._t) > self.max_tenants:
-                for t in [t for t, c in self._t.items()
-                          if now - c.last_seen > self.gc_idle_s]:
-                    self._t.pop(t, None)
-        for lane, label, dr, dd, db in deltas:
-            if dr:
-                metrics_mod.tenant_requests.labels(lane, label).inc(dr)
-            if dd:
-                metrics_mod.tenant_denied.labels(lane, label).inc(dd)
-            if db:
-                metrics_mod.tenant_slo_bad.labels(lane, label).inc(db)
+            n = len(self._names)
+            self._labels()
+            n_labels = len(self._label_names)
+            moved = []
+            for lane, delta in self._lane_delta.items():
+                for family, row in zip(families, delta):
+                    if not row.any():
+                        continue
+                    by_label = np.bincount(self.label[:n], weights=row[:n],
+                                           minlength=n_labels)
+                    for at in np.nonzero(by_label)[0].tolist():
+                        moved.append((family, lane, self._label_names[at],
+                                      int(by_label[at])))
+                delta[:] = 0
+            labelled = np.nonzero(self.label[:n])[0]
+            gauges = [(self._names[s], w) for s, w in zip(
+                labelled.tolist(), self.wait_ewma[labelled].tolist())]
+            if n > self.max_tenants:
+                self._drop_idle(now)
+        for family, lane, label, amount in moved:
+            self._child(family, lane, label).inc(amount)
         for label, w in gauges:
-            metrics_mod.tenant_queue_wait.labels(label).set(round(w, 6))
+            self._child(metrics_mod.tenant_queue_wait, label).set(
+                round(w, 6))
+        return len(moved) + len(gauges)
+
+    def _drop_idle(self, now: float) -> None:
+        """Past ``max_tenants``: forget tenants idle for ``gc_idle_s`` and
+        compact the slots (the deltas were just taken, so none is lost;
+        every heat map resolves its rows again)."""
+        n = len(self._names)
+        keep = np.nonzero(now - self.last_seen[:n] <= self.gc_idle_s)[0]
+        if keep.size == n:
+            return
+        for column in _INT_COLUMNS + _FLOAT_COLUMNS:
+            old = getattr(self, column)
+            kept = np.zeros_like(old)
+            kept[:keep.size] = old[keep]
+            setattr(self, column, kept)
+        self._names = [self._names[s] for s in keep.tolist()]
+        self._slot = {name: s for s, name in enumerate(self._names)}
+        self._row_slots.clear()
 
     def count_reject(self, tenant: str, reason: str) -> None:
         with self._lock:
@@ -259,19 +365,39 @@ class TenantStats:
 
     # -- introspection -------------------------------------------------------
 
+    def _burn_json(self, top: int) -> Dict[str, Any]:
+        n = len(self._names)
+        total = self.burn_total[:n] + self.burn_prev_total[:n]
+        bad = self.burn_bad[:n] + self.burn_prev_bad[:n]
+        seen = np.nonzero(total)[0]
+        rate = bad[seen] / total[seen] / self.burn_budget
+        order = np.argsort(-rate, kind="stable")[:top]
+        return {
+            "window_s": self.burn_window_s,
+            "keys": int(seen.size),
+            "top_burn": [{"key": self._names[s], "burn_rate": round(r, 4),
+                          "total": t, "bad": d}
+                         for s, r, t, d in zip(
+                             seen[order].tolist(), rate[order].tolist(),
+                             total[seen][order].tolist(),
+                             bad[seen][order].tolist())],
+        }
+
     def to_json(self, top: int = 16) -> Dict[str, Any]:
         with self._lock:
-            ranked = sorted(self._t.items(), key=lambda kv: -kv[1].requests)
-            total_rate = sum(c.rate_ewma for _, c in ranked) or 1.0
+            n = len(self._names)
+            ranked = np.argsort(-self.requests[:n], kind="stable")[:top]
+            total_rate = float(self.rate_ewma.sum()) or 1.0
             rows = [{
-                "tenant": name,
-                "requests": c.requests,
-                "denies": c.denies,
-                "slo_bad": c.slo_bad,
-                "queue_wait_ewma_ms": round(c.wait_ewma * 1e3, 3),
-                "share": round(c.rate_ewma / total_rate, 4),
-            } for name, c in ranked[:top]]
-            n = len(self._t)
+                "tenant": self._names[s],
+                "requests": int(self.requests[s]),
+                "denies": int(self.denies[s]),
+                "slo_bad": int(self.slo_bad[s]),
+                "queue_wait_ewma_ms": round(float(self.wait_ewma[s]) * 1e3,
+                                            3),
+                "share": round(float(self.rate_ewma[s]) / total_rate, 4),
+            } for s in ranked.tolist()]
+            burn = self._burn_json(top=8)
         return {
             "lane": self.lane,
             "tenants_seen": n,
@@ -279,5 +405,5 @@ class TenantStats:
             "fold_calls": self.fold_calls,
             "requests_total": self.total_requests,
             "top": rows,
-            "slo_burn": self.burn.to_json(top=8),
+            "slo_burn": burn,
         }

@@ -9,6 +9,8 @@ always being cheap; recording is unconditional on the aggregate metrics."""
 from __future__ import annotations
 
 import logging
+import time
+import weakref
 
 try:
     from prometheus_client import Counter, Gauge, Histogram, REGISTRY
@@ -87,6 +89,60 @@ def _gauge(name, doc, labels):
         return _existing_collector(name) or _NoopMetric()
 
 
+# ---------------------------------------------------------------------------
+# The drain: what a batch's completion records per config (per-AuthConfig
+# counters, the rule heat map, the tenant plane) is kept in dense arrays by
+# the thread that completes batches, and pushed into Prometheus children by
+# whoever reads: a scrape, /debug/vars, the native frontend's housekeeping
+# cadence, a snapshot's retirement, stop().  The counters are exact at every
+# read, and the per-child Python runs off the completion path.
+# ---------------------------------------------------------------------------
+
+_drainables: "weakref.WeakSet" = weakref.WeakSet()
+# callables (dur_ns, children) told of every drain, whoever ran it (the
+# native frontend's stage clock keeps the `drain` row from them)
+DRAIN_OBSERVERS: list = []
+
+
+def register_drainable(obj) -> None:
+    """``obj.flush()`` pushes its accumulated deltas into its Prometheus
+    children and returns how many children it touched."""
+    _drainables.add(obj)
+
+
+def drain() -> int:
+    t0 = time.monotonic_ns()
+    children = 0
+    for obj in list(_drainables):
+        try:
+            children += obj.flush() or 0
+        except Exception:
+            logging.getLogger("authorino_tpu.metrics").exception(
+                "metric drain failed (counts stay pending)")
+    dur_ns = time.monotonic_ns() - t0
+    for observe in list(DRAIN_OBSERVERS):
+        observe(dur_ns, children)
+    return children
+
+
+class _DrainCollector:
+    """Zero-series collector whose collect() runs the drain.  Registered
+    BEFORE every family of this module, and a registry collects in
+    registration order: whoever reads the registry (generate_latest, a
+    push gateway, a test) reads drained counters, not last scrape's."""
+
+    def describe(self):
+        return []
+
+    def collect(self):
+        drain()
+        return []
+
+
+if _PROM:
+    REGISTRY.register(_DrainCollector())
+
+
 evaluator_total = _counter(
     "auth_server_evaluator_total",
     "Total number of evaluations of individual authconfig rule performed by the auth server.",
@@ -132,6 +188,7 @@ response_status = _counter(
     "Status of HTTP response sent by the auth server.",
     ("status",),
 )
+
 # µs-scale on-box stage bounds — MUST match native/frontend.cpp
 # STAGE_BOUNDS_NS (the C++ frontend buckets in ns; drains map 1:1)
 STAGE_BUCKETS = (

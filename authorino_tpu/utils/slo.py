@@ -28,7 +28,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 from . import metrics as metrics_mod
 
-__all__ = ["SloTracker", "KeyedBurn", "DEFAULT_WINDOWS"]
+__all__ = ["SloTracker", "DEFAULT_WINDOWS"]
 
 # (seconds, label) — short windows page, long windows confirm
 DEFAULT_WINDOWS: Tuple[Tuple[int, str], ...] = (
@@ -145,89 +145,3 @@ class SloTracker:
                     if total else 0.0,
                 }
         return out
-
-
-class KeyedBurn:
-    """Per-KEY SLO burn over one coarse sliding window (ISSUE 15: the
-    tenant axis of the burn-rate fold).
-
-    The per-lane :class:`SloTracker` keeps a per-second ring — affordable
-    once per lane, not once per tenant.  Here each key holds exactly TWO
-    half-window buckets (current + previous) that rotate in place, so the
-    whole table is O(live keys) memory and O(1) per fold: burn reads the
-    sum of both buckets — a sliding window with half-window granularity,
-    plenty for the noisy-neighbor detector and the /debug/tenants view.
-    Keys idle past a full window are dropped on the amortized sweep."""
-
-    def __init__(self, window_s: float = 60.0, objective: float = 0.999,
-                 max_keys: int = 8192):
-        self.window_s = float(window_s)
-        self.half_s = self.window_s / 2.0
-        self.budget = 1.0 - min(max(float(objective), 0.0), 0.999999)
-        self.max_keys = int(max_keys)
-        self._lock = threading.Lock()
-        # key -> [bucket_start, total, bad, prev_total, prev_bad]
-        self._k: Dict[str, list] = {}
-        self._last_gc = 0.0
-
-    def _rotate(self, rec: list, now: float) -> None:
-        if now - rec[0] < self.half_s:
-            return
-        if now - rec[0] >= self.window_s:
-            rec[3] = rec[4] = 0  # both halves stale
-        else:
-            rec[3], rec[4] = rec[1], rec[2]
-        rec[0], rec[1], rec[2] = now, 0, 0
-
-    def fold(self, key: str, n: int, bad: int,
-             now: Optional[float] = None) -> None:
-        if n <= 0:
-            return
-        now = time.monotonic() if now is None else now
-        with self._lock:
-            rec = self._k.get(key)
-            if rec is None:
-                rec = self._k[key] = [now, 0, 0, 0, 0]
-            self._rotate(rec, now)
-            rec[1] += int(n)
-            rec[2] += int(bad)
-            if len(self._k) > self.max_keys or \
-                    now - self._last_gc > self.window_s:
-                self._last_gc = now
-                for k in [k for k, r in self._k.items()
-                          if now - r[0] > self.window_s]:
-                    self._k.pop(k, None)
-
-    def counts(self, key: str, now: Optional[float] = None):
-        now = time.monotonic() if now is None else now
-        with self._lock:
-            rec = self._k.get(key)
-            if rec is None:
-                return 0, 0
-            self._rotate(rec, now)
-            return rec[1] + rec[3], rec[2] + rec[4]
-
-    def burn(self, key: str, now: Optional[float] = None) -> float:
-        total, bad = self.counts(key, now=now)
-        if not total:
-            return 0.0
-        return (bad / total) / self.budget
-
-    def to_json(self, top: int = 8,
-                now: Optional[float] = None) -> Dict[str, Any]:
-        now = time.monotonic() if now is None else now
-        rows = []
-        with self._lock:
-            for k, rec in self._k.items():
-                total = rec[1] + rec[3]
-                bad = rec[2] + rec[4]
-                if total:
-                    rows.append((k, round((bad / total) / self.budget, 4),
-                                 total, bad))
-        rows.sort(key=lambda r: -r[1])
-        return {
-            "window_s": self.window_s,
-            "keys": len(rows),
-            "top_burn": [{"key": k, "burn_rate": b, "total": t, "bad": d}
-                         for k, b, t, d in rows[:top]],
-        }

@@ -57,15 +57,27 @@ class VerdictCache:
 
     def put(self, key: Hashable, value: Any) -> None:
         with self._lock:
-            if key in self._entries:
-                self._entries.move_to_end(key)
-                self._entries[key] = value
-                return
-            self._entries[key] = value
-            self.adds += 1
-            while len(self._entries) > self.max_entries:
-                self._entries.popitem(last=False)
-                self.evictions += 1
+            self._put(key, value)
+
+    def put_many(self, keys, values) -> None:
+        """``put`` for each pair, in order, under one lock round: the same
+        entries, LRU order, ``adds`` and ``evictions`` as the puts in
+        sequence (a batch's completion inserts its unique rows at once)."""
+        with self._lock:
+            for key, value in zip(keys, values):
+                self._put(key, value)
+
+    def _put(self, key: Hashable, value: Any) -> None:
+        entries = self._entries
+        if key in entries:
+            entries.move_to_end(key)
+            entries[key] = value
+            return
+        entries[key] = value
+        self.adds += 1
+        while len(entries) > self.max_entries:
+            entries.popitem(last=False)
+            self.evictions += 1
 
     def counts(self) -> dict:
         return {"hits": self.hits, "misses": self.misses,

@@ -149,6 +149,65 @@ def test_clock_counts_every_batch_under_contention():
     assert clock.totals()["plan"]["count"] == per * workers
 
 
+def test_drain_row_counts_drains_and_is_no_batch_stage():
+    """`drain` is there from start-up with zeros, is fed by drains and not
+    by batches, and has no column in the per-batch ring."""
+    clock = StageClock("native")
+    zero = {"count": 0, "sum_ns": 0, "max_ns": 0}
+    assert clock.totals()["drain"] == zero
+    assert list(clock.totals()) == list(STAGES) + ["drain"]
+    for _ in range(3):
+        walk(clock, time.monotonic_ns())
+    assert clock.totals()["drain"] == zero
+    assert clock.totals()["post"]["count"] == 3
+    clock.record_drain(500)
+    clock.record_drain(2000)
+    assert clock.totals()["drain"] == {"count": 2, "sum_ns": 2500,
+                                       "max_ns": 2000}
+    assert clock.totals()["post"]["count"] == 3
+    assert "drain" not in STAGES
+    assert not any("drain" in f for f in FIELDS)
+    assert len(clock.to_json(1)["batches"][0]) == len(FIELDS)
+
+
+@needs_native
+def test_every_drain_is_recorded_whoever_runs_it(frontend):
+    """A registry read (a scrape), /debug/vars and the housekeeping cadence
+    each run the drain and the row counts each; a batch runs none.  (The
+    cadence may add one of its own anywhere in between.)"""
+    from prometheus_client import REGISTRY
+
+    from authorino_tpu.utils import metrics as metrics_mod
+
+    fe, port, _ = frontend
+
+    def drains():
+        return fe.batch_stages.totals()["drain"]["count"]
+
+    before = drains()
+    REGISTRY.get_sample_value("auth_server_authconfig_total",
+                              {"namespace": "ns", "authconfig": "fast-eq"})
+    assert before + 1 <= drains() <= before + 2
+    before = drains()
+    seen = fe.debug_vars()["stages"]["drain"]  # the read drains, then reads
+    assert before + 1 <= seen["count"] <= before + 2
+    assert seen["sum_ns"] >= seen["max_ns"] > 0
+    before, posts = drains(), counts(fe)["post"]
+    for k in range(3):
+        grpc_call(port, make_req("fast-eq.test",
+                                 headers={"x-org": f"drain-row-{k}"}))
+    settle(fe, "post", posts + 3)
+    assert drains() <= before + 1
+    before = drains()
+    fe.hist_drain_s = 0.02  # the housekeeping thread's cadence, shortened
+    deadline = time.monotonic() + 10
+    while drains() < before + 3 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert drains() >= before + 3
+    fe.stop()
+    assert fe._observe_drain not in metrics_mod.DRAIN_OBSERVERS
+
+
 def test_clock_cost_a_batch_is_small():
     """All seven stages of one batch, no profiler session: tens of
     microseconds at most (PERF.md section 6 gives the reading; the bound
@@ -271,7 +330,7 @@ def test_debug_surface_carries_stages_boot_profile_and_batches(frontend):
             await client.close()
 
     quiet, during, answer, after, batches, bad = run(body())
-    assert set(quiet["native_frontend"]["stages"]) == set(STAGES)
+    assert set(quiet["native_frontend"]["stages"]) == set(STAGES) | {"drain"}
     assert quiet["native_frontend"]["stages"]["post"]["count"] >= 3
     proc = quiet["process"]
     assert abs(proc["time_ns"] * 1e-9 - proc["time"]) < 1.0

@@ -483,7 +483,11 @@ class TestSpeculativeDualDispatch:
         assert outs == [True, True, True]
         # the host twin answered: clients never waited out the 0.3s probe
         assert took < 0.25, f"clients waited out the probe: {took:.3f}s"
-        assert stub.launched_batches == 1  # the device half DID launch
+        # the device half DID launch: the dispatcher hands the batch to the
+        # host twin first, and the twin can answer before that same thread
+        # has reached the launch
+        run(wait_until(lambda: stub.launched_batches == 1))
+        assert stub.launched_batches == 1
         spec = engine.lanes.to_json()["speculative_outcomes"]
         assert spec.get("launched") == 1
         assert spec.get("host-win") == 1

@@ -1369,6 +1369,59 @@ def test_stop_drains_inflight_slow_requests():
             fe.stop()
 
 
+# (these start a server of their own, so they stand before the module's
+# shared `stack`: one C++ server a process at a time)
+def _child_value(family, *labels):
+    """The child itself, read without the registry: no drain runs."""
+    return family.labels(*labels)._value.get()
+
+
+@pytest.mark.parametrize("how", ["retire", "stop"])
+def test_counts_folded_before_retirement_or_stop_are_named(how):
+    """A count that sits in a snapshot's arrays when the snapshot is retired,
+    or the server stopped, is in its Prometheus child afterwards with no
+    scrape in between (the cadence is out of the way)."""
+    from authorino_tpu.utils import metrics as metrics_mod
+
+    engine = PolicyEngine(max_batch=64, mesh=None)
+    name = f"drained-{how}"
+    rule = Pattern("request.headers.x-org", Operator.EQ, "acme")
+    entry = make_pattern_entry(engine, f"ns29/{name}", [f"{name}.test"], rule)
+    engine.apply_snapshot([entry])
+    fe = NativeFrontend(engine, port=0, max_batch=16, window_us=500,
+                        lane_select=False)
+    fe.hist_drain_s = 3600.0
+    port = fe.start()
+    try:
+        assert fe.wait_warm(300.0)
+        rec = fe._cur_rec
+        base = _child_value(metrics_mod.authconfig_total, "ns29", name)
+        for org in ("acme", "evil", "acme"):
+            grpc_call(port, make_req(f"{name}.test", headers={"x-org": org}))
+        deadline = time.monotonic() + 10
+        while rec.heat.requests.sum() < 3 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert rec.heat.requests.sum() == 3 and rec.heat.ok.sum() == 2
+        # in the arrays, not yet in the child
+        assert _child_value(metrics_mod.authconfig_total, "ns29", name) == base
+        if how == "retire":
+            other = make_pattern_entry(engine, "ns29/other", ["other29.test"],
+                                       rule)
+            engine.apply_snapshot([entry, other])
+            wait_for_snap_retire(fe)
+    finally:
+        fe.stop()
+    assert _child_value(metrics_mod.authconfig_total, "ns29", name) == base + 3
+    assert _child_value(metrics_mod.authconfig_response_status, "ns29", name,
+                        "OK") == 2
+    assert _child_value(metrics_mod.authconfig_response_status, "ns29", name,
+                        "PERMISSION_DENIED") == 1
+    vars_ = fe.debug_vars()
+    assert vars_["stages"]["drain"]["count"] >= 1
+    assert vars_["post"]["drained_children"] >= 3
+    assert vars_["post"]["sampled_decisions"] >= 1
+
+
 def test_mtls_fast_lane_cert_cache():
     """mTLS identities ride the fast lane too (round 4): the forwarded
     client certificate is the credential key of the verified-credential
@@ -1887,7 +1940,16 @@ def test_fast_lane_metrics_labeled_per_config(stack):
         v = prom.REGISTRY.get_sample_value(name, labels)
         return v or 0.0
 
-    _, _, native_port, _ = stack
+    _, fe, native_port, _ = stack
+    # the tests before this one left batches whose `post` ends after their
+    # answers: let them land before the base is read
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        stages = fe.batch_stages.totals()
+        if (not fe._rb_inflight
+                and stages["post"]["count"] == stages["resolve"]["count"]):
+            break
+        time.sleep(0.01)
     base_total = sample("auth_server_authconfig_total",
                         {"namespace": "ns", "authconfig": "fast-eq"})
     base_ok = sample("auth_server_authconfig_response_status_total",
@@ -2077,3 +2139,105 @@ def test_randomized_differential_sweep(stack):
         if native != python:
             mismatches.append((i, native, python))
     assert not mismatches, f"{len(mismatches)} diverged, first: {mismatches[0]}"
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 29: `post` records per config into arrays; the drain names them
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.perf_guard
+@pytest.mark.parametrize("path", ["device", "host-lane", "sharded"])
+def test_post_runs_no_per_config_python(path, monkeypatch):
+    """Structural pin, in the style of the zero-per-request-Python guards:
+    `post` of a 256-row batch over 256 distinct configs mints and looks up
+    no Prometheus child (`.labels`), makes no `VerdictCache.put` and no
+    decision record once the tenants' first sightings are behind it, on the
+    device path, the host-lane path and the sharded path."""
+    import types
+
+    import numpy as np
+    from prometheus_client.metrics import MetricWrapperBase
+
+    from authorino_tpu.runtime import provenance as prov_mod
+    from authorino_tpu.runtime.native_frontend import _SnapRec
+    from authorino_tpu.utils.verdict_cache import VerdictCache
+
+    G = B = 256
+    E = 2
+    engine = PolicyEngine(max_batch=64, mesh=None)
+    fe = NativeFrontend(engine, port=0, max_batch=B, slo_ms=250.0)
+    fe._mod = types.SimpleNamespace(fe_complete_batch=lambda *a: None)
+    sharded = None
+    if path == "sharded":
+        sharded = types.SimpleNamespace(configs_per_shard=G // 2)
+    heat = prov_mod.HeatMap(
+        [f"pin-{path}/c{i}" for i in range(G)], [["r0", "r1"]] * G, E,
+        configs_per_shard=G // 2 if sharded else None)
+    keys = ([(s, r) for s in range(2) for r in range(G // 2)] if sharded
+            else list(range(G)))
+    labels = {key: (f"pin-{path}", f"c{i}") for i, key in enumerate(keys)}
+    heat.bind_authconfigs(labels, hybrid=keys[::7])
+    rec = _SnapRec(snap_id=1, policy=None, params=None, encoder=None,
+                   sharded=sharded, heat=heat, row_labels=labels,
+                   hybrid_rows=set(keys[::7]))
+    rng = np.random.default_rng(29)
+
+    def one_batch():
+        flat = rng.permutation(G)
+        rows = flat % (G // 2) if sharded else flat
+        shards = flat // (G // 2) if sharded else None
+        cols = np.zeros((B, 8), dtype=bool)
+        denied = rng.random(B) < 0.5
+        cols[:, 0] = ~denied
+        cols[:, 1] = ~denied         # rule 0 false = it fires
+        cols[:, 2] = True
+        packed = np.packbits(cols, axis=1, bitorder="little")
+        if path == "host-lane":
+            fe._post_complete_telemetry(
+                rec, B, 0, 0, rows, None, cols[:, 0].astype(np.uint8), 0.001,
+                time.time_ns(), device_rows=0, device=False,
+                firing=np.where(denied, 0, -1).astype(np.int32))
+            return
+        cache_keys = [(1, bytes([int(f)])) for f in flat]
+        fan = (cache_keys, np.ones(B, dtype=bool), {}, list(range(B)),
+               list(range(B)), np.arange(B), B)
+        bt = fe.batch_stages.begin(1, 0, B)
+        bt.ready()
+        fe._complete_device_batch(rec, 1, 0, B, B, 0, rows, shards, packed,
+                                  time.monotonic(), time.time_ns(), fan, 0, bt)
+
+    one_batch()  # first sightings sample; per-batch children are minted
+    calls = {"labels": 0, "put": 0, "record": 0}
+
+    me = threading.get_ident()
+
+    def counting(name, real):
+        def wrapper(*a, **k):
+            # this thread's calls: another server's housekeeping thread may
+            # drain meanwhile, and a drain is where `.labels` belongs
+            calls[name] += threading.get_ident() == me
+            return real(*a, **k)
+        return wrapper
+
+    monkeypatch.setattr(MetricWrapperBase, "labels",
+                        counting("labels", MetricWrapperBase.labels))
+    monkeypatch.setattr(VerdictCache, "put", counting("put", VerdictCache.put))
+    monkeypatch.setattr(prov_mod.DECISIONS, "record",
+                        counting("record", prov_mod.DECISIONS.record))
+    posts = fe.batch_stages.totals()["post"]["count"]
+    one_batch()
+    assert calls == {"labels": 0, "put": 0, "record": 0}
+    monkeypatch.undo()
+    if path != "host-lane":
+        assert fe.batch_stages.totals()["post"]["count"] == posts + 1
+        assert fe._verdict_cache.counts()["adds"] == B
+    # and the drain names all of it: every non-hybrid row counted twice,
+    # a hybrid row once a denial
+    from authorino_tpu.utils import metrics as metrics_mod
+
+    metrics_mod.drain()
+    total = sum(_child_value(metrics_mod.authconfig_total, *labels[key])
+                for key in keys)
+    assert total == heat.requests.sum() > B
+    assert fe.tenancy.stats.to_json()["tenants_seen"] >= G

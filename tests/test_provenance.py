@@ -691,3 +691,235 @@ def test_rule_label_truncates_but_never_merges():
     assert len(la) <= prov_mod.RULE_LABEL_MAX + 4
     assert la != lb or long_a == long_b
     assert prov_mod.rule_label(1, "short") == "1:short"
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 29: what `post` records per config is kept as arrays on the heat map
+# and named by the drain.  The loops they replaced stay here as the plain
+# reference.
+# ---------------------------------------------------------------------------
+
+
+def _sample(name, labels):
+    from prometheus_client import REGISTRY
+
+    return REGISTRY.get_sample_value(name, labels) or 0.0
+
+
+def _authconfig_reading(labels):
+    """(total, OK, PERMISSION_DENIED) of every (namespace, name): the
+    registry's collect runs the drain first, as a scrape does."""
+    out = {}
+    for ns, name in sorted(set(labels)):
+        conf = {"namespace": ns, "authconfig": name}
+        out[(ns, name)] = (
+            _sample("auth_server_authconfig_total", conf),
+            _sample("auth_server_authconfig_response_status_total",
+                    dict(conf, status="OK")),
+            _sample("auth_server_authconfig_response_status_total",
+                    dict(conf, status="PERMISSION_DENIED")))
+    return out
+
+
+def _authconfig_loop(expect, row_labels, hybrid_rows, rows, verdict,
+                     shards=None, per_shard=None):
+    """The per-config loop `_post_complete_telemetry` ran until ISSUE 29
+    (native_frontend.py at PR 28), adding into a dict where it called
+    `.labels(...).inc(...)`."""
+    if shards is not None:
+        flat = shards * per_shard + rows
+        n_per = np.bincount(flat)
+        ok_per = np.bincount(flat, weights=verdict).astype(np.int64)
+        idxs = np.nonzero(n_per)[0]
+        keys = [(int(f // per_shard), int(f % per_shard)) for f in idxs]
+    else:
+        n_per = np.bincount(rows)
+        ok_per = np.bincount(rows, weights=verdict).astype(np.int64)
+        idxs = np.nonzero(n_per)[0]
+        keys = [int(f) for f in idxs]
+    for f, key in zip(idxs, keys):
+        n, n_ok = int(n_per[f]), int(ok_per[f])
+        label = row_labels.get(key, ("", ""))
+        if key in hybrid_rows:
+            n, n_ok = n - n_ok, 0
+            if not n:
+                continue
+        got = expect.setdefault(label, [0, 0, 0])
+        got[0] += n
+        got[1] += n_ok
+        got[2] += n - n_ok
+
+
+@pytest.mark.parametrize("seed", [11, 12, 13])
+@pytest.mark.parametrize("n_shards", [None, 2])
+def test_authconfig_counters_as_arrays_equal_the_loop(seed, n_shards):
+    rng = np.random.default_rng(seed)
+    G = 40
+    tag = f"ac{seed}-{n_shards}"
+    keys = ([(s, r) for s in range(n_shards) for r in range(G)]
+            if n_shards else list(range(G)))
+    row_labels = {key: (tag, f"cfg-{i}") for i, key in enumerate(keys)}
+    # a config the frontend gave no labels counts under ("", ""), as before
+    del row_labels[keys[3]]
+    hybrid_rows = {keys[i] for i in rng.choice(len(keys), 6, replace=False)}
+    heat = prov_mod.HeatMap(
+        [f"{tag}/cfg-{i}" for i in range(len(keys))],
+        [["r0", "r1"]] * len(keys), 2, configs_per_shard=G if n_shards else None)
+    heat.bind_authconfigs(row_labels, hybrid_rows)
+    labels = list(row_labels.values()) + [("", "")]
+    before = _authconfig_reading(labels)
+    expect = {}
+    for _ in range(25):
+        B = int(rng.integers(1, 257))
+        rows = rng.integers(0, G, B)
+        shards = rng.integers(0, n_shards, B) if n_shards else None
+        verdict = (rng.random(B) < 0.6).astype(np.uint8)
+        heat.fold_requests(rows, verdict, shards=shards)
+        _authconfig_loop(expect, row_labels, hybrid_rows, rows, verdict,
+                         shards=shards, per_shard=G)
+    after = _authconfig_reading(labels)
+    moved = {k: tuple(a - b for a, b in zip(after[k], before[k]))
+             for k in after}
+    assert moved == {k: tuple(expect.get(k, (0, 0, 0))) for k in after}
+    # a second read finds nothing new, and no count twice
+    assert _authconfig_reading(labels) == after
+    assert heat.flush() == 0
+
+
+@pytest.mark.parametrize("sample_n", [4, 64])
+def test_gate_samples_first_sighting_then_one_in_n(sample_n):
+    """2,000 tenants cycled, 250 a batch: the case in which the 512-entry
+    gate table forgot most tenants between two sightings and sampled them as
+    new every time.  The array gate is bounded by the corpus."""
+    saved = prov_mod.DECISIONS.sample_n
+    prov_mod.DECISIONS.configure(sample_n=sample_n)
+    try:
+        G, B = 2000, 250
+        heat = prov_mod.HeatMap([f"gate{sample_n}/t{i}" for i in range(G)],
+                                [["r"]] * G, 1)
+        firing = np.full(B, -1)
+        records = np.zeros(G, dtype=np.int64)
+        rounds = 2 * sample_n + 1
+        for sighting in range(rounds):
+            for lo in range(0, G, B):
+                rows = np.arange(lo, lo + B)
+                before = prov_mod.DECISIONS.records_total
+                made = prov_mod.fold_and_sample(heat, rows, firing, B,
+                                                lane="gate-test")
+                assert made == prov_mod.DECISIONS.records_total - before
+                # every tenant of a batch is at the same point of its count
+                fires = sighting % sample_n == 0
+                assert made == (B if fires else 0), (sighting, lo)
+                records[rows] += made // B
+        assert (records == 3).all()  # decisions 1, 1 + N and 1 + 2N
+        # a tenant with many rows in one batch still makes one record
+        rows = np.zeros(5 * sample_n, dtype=np.int64)
+        assert prov_mod.fold_and_sample(heat, rows, np.full(rows.size, -1),
+                                        rows.size, lane="gate-test") == 1
+        # the record is the tenant's own: its name, its row's rule
+        heat2 = prov_mod.HeatMap(["g/a", "g/b"], [["ra"], ["rb"]], 1)
+        assert prov_mod.fold_and_sample(
+            heat2, np.array([0, 1, 1]), np.array([-1, 0, -1]), 3,
+            lane="gate-test", latency_ms=7.0) == 2
+        a, b = prov_mod.DECISIONS.to_json(n=2)["records"]
+        assert (a["authconfig"], a["verdict"], a["rule"]) == ("g/a", "allow", None)
+        assert (b["authconfig"], b["verdict"], b["rule"]) == ("g/b", "deny", "0:rb")
+        assert a["latency_ms"] == b["latency_ms"] == 7.0
+        # a new rate re-arms every tenant of every heat map
+        prov_mod.DECISIONS.configure(sample_n=sample_n + 1)
+        assert prov_mod.fold_and_sample(heat2, np.array([0, 1]),
+                                        np.array([-1, -1]), 2,
+                                        lane="gate-test") == 2
+    finally:
+        prov_mod.DECISIONS.configure(sample_n=saved)
+
+
+@pytest.mark.parametrize("capacity,span", [(8, 6), (8, 40), (64, 40)])
+def test_put_many_equals_the_puts_in_sequence(capacity, span):
+    """Same entries, same LRU order, same `adds` and `evictions`: keys that
+    repeat inside a batch, keys already cached, and batches that evict
+    entries of their own."""
+    from authorino_tpu.utils.verdict_cache import VerdictCache
+
+    rng = np.random.default_rng(capacity * 100 + span)
+    one, many = VerdictCache(capacity), VerdictCache(capacity)
+    for _ in range(30):
+        keys = [("tok", int(k)) for k in rng.integers(0, span, 12)]
+        values = [(int(v), int(f)) for v, f in
+                  zip(rng.integers(0, 2, 12), rng.integers(-1, 3, 12))]
+        for key, value in zip(keys, values):
+            one.put(key, value)
+        many.put_many(iter(keys), iter(values))
+        assert list(many._entries.items()) == list(one._entries.items())
+        assert many.counts() == one.counts()
+        probe = ("tok", int(rng.integers(0, span)))
+        assert many.get(probe) == one.get(probe)
+
+
+def test_folds_and_drains_side_by_side_lose_no_count():
+    """The readback thread, host-lane workers and the engine lane fold into
+    one heat map and one tenant plane while the housekeeping thread and
+    scrapes drain them: more threads than cores, a shortened switch
+    interval, and every folded row is in a child at the end, once."""
+    import sys
+    import threading
+
+    from authorino_tpu.tenancy import TenantStats
+    from authorino_tpu.utils import metrics as metrics_mod
+
+    G, B, per, folders = 16, 64, 150, 12
+    heat = prov_mod.HeatMap([f"race/c{i}" for i in range(G)], [["r0"]] * G, 1)
+    heat.bind_authconfigs({i: ("race", f"c{i}") for i in range(G)})
+    stats = TenantStats("race-lane", top_k=4)
+    stop = threading.Event()
+
+    def fold(seed):
+        rng = np.random.default_rng(seed)
+        for _ in range(per):
+            rows = rng.integers(0, G, B)
+            heat.fold_requests(rows, np.ones(B, dtype=np.uint8))
+            heat.fold(rows, np.zeros(B, dtype=np.int64))
+            stats.fold(heat, rows, firing=np.zeros(B, dtype=np.int64))
+
+    def drain():
+        while not stop.is_set():
+            metrics_mod.drain()
+
+    def named():
+        total = sum(_sample("auth_server_authconfig_total",
+                            {"namespace": "race", "authconfig": f"c{i}"})
+                    for i in range(G))
+        fired = sum(_sample("auth_server_rule_fired_total",
+                            {"authconfig": f"race/c{i}", "rule": "0:r0"})
+                    for i in range(G))
+        from prometheus_client import REGISTRY
+
+        tenants = sum(s.value for m in REGISTRY.collect()
+                      if m.name == "auth_server_tenant_requests"
+                      for s in m.samples
+                      if s.name.endswith("_total")
+                      and s.labels["lane"] == "race-lane")
+        return total, fired, tenants
+
+    assert named() == (0, 0, 0)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        workers = [threading.Thread(target=fold, args=(k,))
+                   for k in range(folders)]
+        drainers = [threading.Thread(target=drain) for _ in range(2)]
+        for t in workers + drainers:
+            t.start()
+        for t in workers:
+            t.join(120)
+        stop.set()
+        for t in drainers:
+            t.join(30)
+        assert not any(t.is_alive() for t in workers + drainers)
+    finally:
+        stop.set()
+        sys.setswitchinterval(old)
+    want = folders * per * B
+    assert named() == (want, want, want)
+    assert heat.requests.sum() == want
+    assert stats.to_json()["requests_total"] == want

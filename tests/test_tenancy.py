@@ -31,7 +31,6 @@ from authorino_tpu.tenancy import (
     WeightBook,
 )
 from authorino_tpu.utils.rpc import RESOURCE_EXHAUSTED, CheckAbort
-from authorino_tpu.utils.slo import KeyedBurn
 
 
 def run(coro):
@@ -349,7 +348,7 @@ class TestTenantQuota:
 
 
 # ---------------------------------------------------------------------------
-# per-tenant stats folds + KeyedBurn
+# per-tenant stats folds and the burn window
 # ---------------------------------------------------------------------------
 
 
@@ -389,14 +388,33 @@ class TestTenantStats:
         shares = stats.shares()
         assert shares["hot"] > 5 * shares["cold"]
 
-    def test_keyed_burn_window(self):
+    def test_burn_window(self):
+        """A tenant's burn reads both half-window buckets; a full window
+        later the old halves age out.  The per-key reference agrees."""
+        stats = TenantStats("burn-lane", burn_window_s=10.0)
+        stats.burn_budget = 1.0 - 0.9
         burn = KeyedBurn(window_s=10.0, objective=0.9)
+        heat = _StubHeat(["t"])
         t0 = 1000.0
+        bad = np.arange(100) < 50
+        stats.fold(heat, np.zeros(100, dtype=int), bad_mask=bad, now=t0)
         burn.fold("t", 100, 50, now=t0)
+        (row,) = stats._burn_json(top=8)["top_burn"]
+        assert row == {"key": "t", "burn_rate": pytest.approx(5.0),
+                       "total": 100, "bad": 50}
         assert burn.burn("t", now=t0) == pytest.approx(5.0)
-        # a full window later the old halves age out
-        burn.fold("t", 100, 0, now=t0 + 11.0)
-        assert burn.burn("t", now=t0 + 11.0) == pytest.approx(0.0)
+        # half a window later the first bucket is the previous one
+        stats.fold(heat, np.zeros(100, dtype=int), bad_mask=~bad | bad,
+                   now=t0 + 6.0)
+        (row,) = stats._burn_json(top=8)["top_burn"]
+        assert (row["total"], row["bad"]) == (200, 150)
+        # a full window after that both halves are stale
+        stats.fold(heat, np.zeros(100, dtype=int),
+                   bad_mask=np.zeros(100, dtype=bool), now=t0 + 17.0)
+        burn.fold("t", 100, 0, now=t0 + 17.0)
+        (row,) = stats._burn_json(top=8)["top_burn"]
+        assert (row["total"], row["bad"], row["burn_rate"]) == (100, 0, 0.0)
+        assert burn.burn("t", now=t0 + 17.0) == pytest.approx(0.0)
 
     def test_top_k_bound_caps_minted_labels(self):
         from authorino_tpu.utils import metrics as metrics_mod
@@ -408,6 +426,354 @@ class TestTenantStats:
         bound = metrics_mod.TENANT_LABEL_BOUNDS[
             "auth_server_tenant_requests_total"]
         assert len(stats._label_of) <= bound
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 29: the plane's state as arrays by slot.  The loop over a batch's
+# distinct tenants that it replaced (tenancy/stats.py at PR 28) stays here as
+# the plain reference: same numbers for the same folds.
+# ---------------------------------------------------------------------------
+
+
+import threading
+from typing import Any, Dict, Optional
+
+
+class KeyedBurn:
+    """The per-key burn window `TenantStats` folded into, one call a tenant,
+    until ISSUE 29 (utils/slo.py at PR 28): the plain reference for the
+    burn arrays it keeps now.
+
+    The per-lane :class:`SloTracker` keeps a per-second ring — affordable
+    once per lane, not once per tenant.  Here each key holds exactly TWO
+    half-window buckets (current + previous) that rotate in place, so the
+    whole table is O(live keys) memory and O(1) per fold: burn reads the
+    sum of both buckets — a sliding window with half-window granularity,
+    plenty for the noisy-neighbor detector and the /debug/tenants view.
+    Keys idle past a full window are dropped on the amortized sweep."""
+
+    def __init__(self, window_s: float = 60.0, objective: float = 0.999,
+                 max_keys: int = 8192):
+        self.window_s = float(window_s)
+        self.half_s = self.window_s / 2.0
+        self.budget = 1.0 - min(max(float(objective), 0.0), 0.999999)
+        self.max_keys = int(max_keys)
+        self._lock = threading.Lock()
+        # key -> [bucket_start, total, bad, prev_total, prev_bad]
+        self._k: Dict[str, list] = {}
+        self._last_gc = 0.0
+
+    def _rotate(self, rec: list, now: float) -> None:
+        if now - rec[0] < self.half_s:
+            return
+        if now - rec[0] >= self.window_s:
+            rec[3] = rec[4] = 0  # both halves stale
+        else:
+            rec[3], rec[4] = rec[1], rec[2]
+        rec[0], rec[1], rec[2] = now, 0, 0
+
+    def fold(self, key: str, n: int, bad: int,
+             now: Optional[float] = None) -> None:
+        if n <= 0:
+            return
+        now = time.monotonic() if now is None else now
+        with self._lock:
+            rec = self._k.get(key)
+            if rec is None:
+                rec = self._k[key] = [now, 0, 0, 0, 0]
+            self._rotate(rec, now)
+            rec[1] += int(n)
+            rec[2] += int(bad)
+            if len(self._k) > self.max_keys or \
+                    now - self._last_gc > self.window_s:
+                self._last_gc = now
+                for k in [k for k, r in self._k.items()
+                          if now - r[0] > self.window_s]:
+                    self._k.pop(k, None)
+
+    def counts(self, key: str, now: Optional[float] = None):
+        now = time.monotonic() if now is None else now
+        with self._lock:
+            rec = self._k.get(key)
+            if rec is None:
+                return 0, 0
+            self._rotate(rec, now)
+            return rec[1] + rec[3], rec[2] + rec[4]
+
+    def burn(self, key: str, now: Optional[float] = None) -> float:
+        total, bad = self.counts(key, now=now)
+        if not total:
+            return 0.0
+        return (bad / total) / self.budget
+
+    def to_json(self, top: int = 8,
+                now: Optional[float] = None) -> Dict[str, Any]:
+        now = time.monotonic() if now is None else now
+        rows = []
+        with self._lock:
+            for k, rec in self._k.items():
+                total = rec[1] + rec[3]
+                bad = rec[2] + rec[4]
+                if total:
+                    rows.append((k, round((bad / total) / self.budget, 4),
+                                 total, bad))
+        rows.sort(key=lambda r: -r[1])
+        return {
+            "window_s": self.window_s,
+            "keys": len(rows),
+            "top_burn": [{"key": k, "burn_rate": b, "total": t, "bad": d}
+                         for k, b, t, d in rows[:top]],
+        }
+
+
+class _LoopStats:
+    """`TenantStats` at PR 28, cut to what a fold writes and a reader
+    reads; where it called `.labels(...).inc()` it adds into `counted`."""
+
+    def __init__(self, lane, top_k, burn_window_s):
+        self.lane, self.top_k = lane, top_k
+        self.t = {}       # name -> dict of the old _TenantCounters slots
+        self.lane_delta = {}
+        self.burn = KeyedBurn(window_s=burn_window_s)
+        self.label_of = {}
+        self.counted = {}
+        self.sunk = []
+        self.total_requests = 0
+
+    def fold(self, heat, rows, firing=None, shards=None, waits=None,
+             bad_mask=None, denied_mask=None, lane=None, now=0.0):
+        rows = np.asarray(rows, dtype=np.int64)
+        n = int(rows.size)
+        lane = lane or self.lane
+        self.total_requests += n
+        flat = rows
+        if shards is not None and heat.configs_per_shard:
+            flat = np.asarray(shards) * heat.configs_per_shard + rows
+        if denied_mask is None and firing is not None:
+            denied_mask = np.asarray(firing) >= 0
+        uniq, inv = np.unique(flat, return_inverse=True)
+        tot = np.bincount(inv, minlength=len(uniq))
+        den = (np.bincount(inv[denied_mask], minlength=len(uniq))
+               if denied_mask is not None and np.any(denied_mask)
+               else np.zeros(len(uniq), dtype=np.int64))
+        if waits is not None:
+            wsum = np.bincount(inv, weights=waits, minlength=len(uniq))
+            wmin = np.full(len(uniq), np.inf)
+            np.minimum.at(wmin, inv, waits)
+        bad = None
+        if bad_mask is not None:
+            bad = (np.bincount(inv[bad_mask], minlength=len(uniq))
+                   if np.any(bad_mask)
+                   else np.zeros(len(uniq), dtype=np.int64))
+        per_lane = self.lane_delta.setdefault(lane, {})
+        for i, u in enumerate(uniq):
+            name = heat.name(int(u))
+            if not name:
+                continue
+            c = self.t.get(name)
+            if c is None:
+                c = self.t[name] = dict(
+                    requests=0, denies=0, slo_bad=0, wait_ewma=0.0,
+                    rate_ewma=0.0, rate_t=now, rate_pend=0, last_seen=now)
+            k = int(tot[i])
+            c["requests"] += k
+            c["denies"] += int(den[i])
+            c["last_seen"] = now
+            c["rate_pend"] += k
+            dt = now - c["rate_t"]
+            if dt > 0.05:
+                inst = c["rate_pend"] / dt
+                c["rate_ewma"] = inst if not c["rate_ewma"] else \
+                    0.7 * c["rate_ewma"] + 0.3 * inst
+                c["rate_t"] = now
+                c["rate_pend"] = 0
+            if waits is not None:
+                mean = float(wsum[i]) / k
+                c["wait_ewma"] = mean if not c["wait_ewma"] else \
+                    0.8 * c["wait_ewma"] + 0.2 * mean
+                self.sunk.append((name, mean, float(wmin[i]), now))
+            b = int(bad[i]) if bad is not None else 0
+            if b:
+                c["slo_bad"] += b
+            if bad is not None:
+                self.burn.fold(name, k, b, now=now)
+            d = per_lane.setdefault(name, [0, 0, 0])
+            d[0] += k
+            d[1] += int(den[i])
+            d[2] += b
+
+    def shares(self):
+        total = sum(c["rate_ewma"] for c in self.t.values())
+        if total <= 0:
+            return {}
+        return {t: c["rate_ewma"] / total for t, c in self.t.items()
+                if c["rate_ewma"] > 0}
+
+    def flush(self):
+        ranked = sorted(self.t.items(), key=lambda kv: -kv[1]["requests"])
+        for name, _ in ranked[:self.top_k]:
+            if name not in self.label_of and len(self.label_of) < 32:
+                self.label_of[name] = name
+        for lane, per in self.lane_delta.items():
+            for name, amounts in per.items():
+                label = self.label_of.get(name, "other")
+                for family, amount in zip(("requests", "denied", "slo_bad"),
+                                          amounts):
+                    if amount:
+                        key = (family, lane, label)
+                        self.counted[key] = self.counted.get(key, 0) + amount
+        self.lane_delta.clear()
+        return {self.label_of[name]: round(c["wait_ewma"], 6)
+                for name, c in self.t.items() if name in self.label_of}
+
+    def top(self, top=16):
+        ranked = sorted(self.t.items(), key=lambda kv: -kv[1]["requests"])
+        total_rate = sum(c["rate_ewma"] for _, c in ranked) or 1.0
+        return [{
+            "tenant": name, "requests": c["requests"],
+            "denies": c["denies"], "slo_bad": c["slo_bad"],
+            "queue_wait_ewma_ms": round(c["wait_ewma"] * 1e3, 3),
+            "share": round(c["rate_ewma"] / total_rate, 4),
+        } for name, c in ranked[:top]]
+
+
+def _tenant_counters(lane, labels):
+    from prometheus_client import REGISTRY
+
+    out = {}
+    for family in ("requests", "denied", "slo_bad"):
+        for label in labels:
+            v = REGISTRY.get_sample_value(
+                f"auth_server_tenant_{family}_total",
+                {"lane": lane, "tenant": label})
+            if v:
+                out[(family, lane, label)] = int(v)
+    return out
+
+
+@pytest.mark.parametrize("seed", [21, 22])
+@pytest.mark.parametrize("n_shards", [None, 2])
+@pytest.mark.parametrize("denied_as", ["firing", "denied_mask"])
+def test_array_fold_equals_the_loop_it_replaced(seed, n_shards, denied_as):
+    rng = np.random.default_rng(seed)
+    G = 60
+    tag = f"eq{seed}-{n_shards}-{denied_as}"
+    n_rows = G * (n_shards or 1)
+    names = [f"{tag}/t{i}" for i in range(n_rows)]
+    names[5] = ""  # a padded row: no tenant
+    heat = prov_mod.HeatMap(names, [["r0", "r1"]] * n_rows, 2,
+                            configs_per_shard=G if n_shards else None)
+    stats = TenantStats(tag, top_k=4, burn_window_s=4.0)
+    loop = _LoopStats(tag, top_k=4, burn_window_s=4.0)
+    sunk = []
+    stats.wait_sink = lambda *a: sunk.append(a)
+    lanes = [None, f"{tag}-host"]
+    now = 100.0
+    labels_seen = set()
+    for step in range(120):
+        # inside the 50 ms rate window, past it, and past half and whole
+        # burn windows
+        now += float(rng.choice([0.004, 0.02, 0.08, 0.5, 2.5, 5.0],
+                                p=[0.3, 0.3, 0.2, 0.1, 0.05, 0.05]))
+        B = int(rng.integers(1, 200))
+        # zipf-ish rows: a few tenants hot, most cold
+        rows = np.minimum(rng.zipf(1.3, B) - 1, G - 1)
+        shards = rng.integers(0, n_shards, B) if n_shards else None
+        firing = np.where(rng.random(B) < 0.4, rng.integers(0, 2, B), -1)
+        kw = dict(shards=shards, lane=lanes[step % 2], now=now)
+        if denied_as == "firing":
+            kw["firing"] = firing
+        else:
+            kw["denied_mask"] = firing >= 0
+        if step % 3:
+            kw["waits"] = rng.random(B) * 0.1
+        if step % 4:
+            kw["bad_mask"] = rng.random(B) < (0.5 if step % 8 == 1 else 0.0)
+        stats.fold(heat, rows, **kw)
+        loop.fold(heat, rows, **kw)
+        if step % 25 == 24:
+            stats.flush(now=now)
+            gauges = loop.flush()
+            labels_seen |= set(loop.label_of) | {"other"}
+            assert stats._label_of == loop.label_of
+            for label, wait in gauges.items():
+                from prometheus_client import REGISTRY
+
+                assert REGISTRY.get_sample_value(
+                    "auth_server_tenant_queue_wait_seconds",
+                    {"tenant": label}) == pytest.approx(wait, abs=1e-6)
+    got = stats.to_json()
+    assert got["tenants_seen"] == len(loop.t)
+    assert got["requests_total"] == loop.total_requests
+    assert [r["tenant"] for r in got["top"]] == \
+        [r["tenant"] for r in loop.top()]
+    for mine, theirs in zip(got["top"], loop.top()):
+        assert mine == pytest.approx(theirs, rel=1e-9, abs=1e-9)
+    # the whole burn table (the top 8 of /debug/tenants are cut from it;
+    # ties at one rate may be cut differently)
+    want = {r["key"]: r for r in loop.burn.to_json(top=n_rows)["top_burn"]}
+    mine = {r["key"]: r for r in stats._burn_json(top=n_rows)["top_burn"]}
+    assert got["slo_burn"]["keys"] == len(want) == len(mine) > 8
+    assert len(got["slo_burn"]["top_burn"]) == 8
+    assert got["slo_burn"]["top_burn"][0]["burn_rate"] == \
+        max(r["burn_rate"] for r in want.values())
+    for key, r in want.items():
+        assert mine[key] == pytest.approx(r), key
+    assert stats.shares() == pytest.approx(loop.shares(), rel=1e-9)
+    hot = names[0] or names[1]
+    assert stats.share(hot) == pytest.approx(loop.shares().get(hot, 0.0))
+    assert {k: v["requests"] for k, v in stats.export_fold().items()} == \
+        {k: c["requests"] for k, c in loop.t.items()}
+    # the wait sink heard the same (tenant, mean, least, now) calls; inside
+    # a batch the arrays go by slot where the loop went by row
+    assert len(sunk) == len(loop.sunk)
+    for mine, theirs in zip(sorted(sunk, key=lambda c: (c[3], c[0])),
+                            sorted(loop.sunk, key=lambda c: (c[3], c[0]))):
+        assert mine[0] == theirs[0]
+        assert mine[1:] == pytest.approx(theirs[1:])
+    # the counters, as a scrape reads them: the registry drains first
+    loop.flush()
+    labels_seen |= set(loop.label_of) | {"other"}
+    read = {}
+    for lane in (tag, f"{tag}-host"):
+        read.update(_tenant_counters(lane, labels_seen))
+    assert read == loop.counted
+
+
+def test_idle_tenants_are_dropped_and_rows_resolve_again():
+    stats = TenantStats("gc-lane", max_tenants=4, gc_idle_s=10.0)
+    heat = _StubHeat([f"gc/t{i}" for i in range(8)])
+    stats.fold(heat, np.arange(8), firing=np.full(8, -1), now=1.0)
+    stats.fold(heat, np.array([6, 7, 7]), firing=np.array([0, -1, 0]),
+               now=50.0)
+    stats.flush(now=50.0)  # over max_tenants: the six idle ones go
+    got = stats.to_json()
+    assert got["tenants_seen"] == 2
+    assert {r["tenant"]: (r["requests"], r["denies"]) for r in got["top"]} \
+        == {"gc/t6": (2, 1), "gc/t7": (3, 1)}
+    # a dropped tenant comes back as new; a kept one keeps its counts
+    stats.fold(heat, np.array([0, 7]), firing=np.array([-1, -1]), now=51.0)
+    by = {r["tenant"]: r["requests"] for r in stats.to_json()["top"]}
+    assert by == {"gc/t0": 1, "gc/t6": 2, "gc/t7": 4}
+
+
+def test_detector_reads_no_shares_without_pressure():
+    """`check` runs every 0.1 s on the thread that completes batches: with
+    no pressure and nobody contained it needs no share, and asks for none."""
+    book = WeightBook()
+    book.rebuild({"a": None})
+    stats = TenantStats("idle-detector")
+    asked = []
+    stats.shares = lambda: asked.append(1) or {}
+    wait = [0.0]
+    det = NoisyNeighborDetector(book, stats, wait_ewma=lambda: wait[0],
+                                target_s=lambda: 0.05, lane="idle-detector")
+    det._hot_since["a"] = 1.0
+    det.check(now=10.0)
+    assert not asked and not det._hot_since
+    wait[0] = 1.0
+    det.check(now=11.0)
+    assert asked
 
 
 # ---------------------------------------------------------------------------
