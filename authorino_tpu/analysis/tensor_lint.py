@@ -24,15 +24,6 @@ Checks and their finding kinds (catalogue: docs/static_analysis.md):
                      stacked [S]-axis device pytree silently truncates or
                      misaligns operands if the ShapeTargets union missed an
                      axis (mesh lane, ISSUE 11)
-  fused-perm         dfa_row_perm is a bijection over the DFA rows AND
-                     groups rows by owning table (dfa_table_of_row composed
-                     with the permutation is nondecreasing) — the fused
-                     lane's contiguous-gather layout (ISSUE 17)
-  fused-int8         leaf_op_i8 round-trips leaf_op losslessly (all op
-                     codes < 2^7; a lossy cast reroutes every affected leaf
-                     through the wrong comparison)
-  fused-pack-width   fused_pack_w == packed_width(1 + 2E) — the in-kernel
-                     bitpack readback width the dispatchers decode against
   own-dfa-rows       config_dfa_rows[g] is exactly the set of DFA rows
                      reachable from config g's evaluators (ascending, -1
                      padded) — the own-row scan evaluates nothing else, so a
@@ -276,58 +267,6 @@ def _check_operands(policy: CompiledPolicy, out: List[Finding]) -> None:
 _INT_DTYPES = (np.int32, np.int64)
 
 
-def _check_fused_layout(policy: CompiledPolicy, out: List[Finding]) -> None:
-    """ISSUE 17 packed-layout invariants, audited against their SOURCES
-    (the fused fields are stored on the policy, so a corrupted layout is a
-    real miscompile, not a stale cache)."""
-    from ..ops.pattern_eval import packed_width
-
-    perm = getattr(policy, "dfa_row_perm", None)
-    if policy.dfa_table_of_row is not None:
-        R = int(policy.dfa_table_of_row.shape[0])
-        if perm is None or perm.shape != (R,) or \
-                not np.array_equal(np.sort(np.asarray(perm)), np.arange(R)):
-            out.append(_err(
-                "fused-perm",
-                f"dfa_row_perm must be a bijection over [0, R={R}) "
-                f"(got {None if perm is None else perm.tolist()[:8]}...)",
-                "dfa_row_perm"))
-        else:
-            # grouping is only meaningful over a VALID table map: when
-            # dfa_table_of_row itself is out of range, dfa-table-index owns
-            # the finding — re-reporting it here as fused-perm would blame
-            # the (correct) permutation for the corrupted source.
-            rows = np.asarray(policy.dfa_table_of_row)
-            T = int(policy.dfa_tables.shape[0]) \
-                if policy.dfa_tables is not None else 0
-            rows_valid = (not rows.size) or \
-                (int(rows.min()) >= 0 and int(rows.max()) < T)
-            grouped = rows[np.asarray(perm)]
-            if rows_valid and grouped.size and np.any(np.diff(grouped) < 0):
-                out.append(_err(
-                    "fused-perm",
-                    "dfa_row_perm does not group rows by owning table "
-                    "(dfa_table_of_row[perm] is not nondecreasing)",
-                    "dfa_row_perm"))
-    i8 = getattr(policy, "leaf_op_i8", None)
-    if policy.leaf_op is not None:
-        if i8 is None or i8.dtype != np.int8 or \
-                not np.array_equal(i8.astype(np.int64),
-                                   policy.leaf_op.astype(np.int64)):
-            out.append(_err(
-                "fused-int8",
-                "leaf_op_i8 is not a lossless int8 image of leaf_op",
-                "leaf_op_i8"))
-    if policy.eval_rule is not None:
-        E = int(policy.eval_rule.shape[1])
-        want = packed_width(1 + 2 * E)
-        if int(getattr(policy, "fused_pack_w", 0)) != want:
-            out.append(_err(
-                "fused-pack-width",
-                f"fused_pack_w {getattr(policy, 'fused_pack_w', 0)} != "
-                f"packed_width(1+2E) = {want}", "fused_pack_w"))
-
-
 def _check_own_rows(policy: CompiledPolicy, out: List[Finding]) -> None:
     """ISSUE 26 own-row scan layout, audited against its SOURCES by a walk of
     its own (top-down from each config's evaluator references; the compiler
@@ -505,7 +444,7 @@ def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
 
     L, A, B = policy.n_leaves, policy.n_attrs, policy.buffer_size
     G, E = policy.eval_rule.shape
-    for lane in ("gather", "matmul", "fused"):
+    for lane in ("gather", "matmul"):
         try:
             params = to_device(policy, host=True, lane=lane, dense=True)
         except Exception as e:
@@ -535,33 +474,6 @@ def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
             out.append(_err("lane-contract",
                             f"member_slot_of_leaf must index [0, M="
                             f"{policy.n_member_attrs}) over [L={L}]", loc))
-        if lane == "fused":
-            fz = params.get("fused")
-            if fz is None:
-                out.append(_err("lane-contract",
-                                "fused lane requested but operands missing",
-                                loc))
-                continue
-            i8 = fz.get("leaf_op_i8")
-            if i8 is None or i8.dtype != np.int8 or i8.shape != (L,):
-                out.append(_err(
-                    "lane-contract",
-                    f"leaf_op_i8 must be int8 [L={L}], got "
-                    f"{None if i8 is None else (i8.dtype, i8.shape)}", loc))
-            if policy.n_byte_attrs:
-                R = int(policy.dfa_table_of_row.shape[0])
-                for name, n in (("dfa_table_of_row_g", R),
-                                ("dfa_byte_slot_g", R),
-                                ("leaf_dfa_pos", L)):
-                    a = fz.get(name)
-                    if a is None or a.shape != (n,) or \
-                            a.dtype not in _INT_DTYPES:
-                        out.append(_err(
-                            "lane-contract",
-                            f"fused operand {name} must be int32 [{n}], "
-                            f"got {None if a is None else (a.dtype, a.shape)}",
-                            loc))
-            continue
         mm = params.get("matmul")
         if lane == "matmul" and mm is None:
             # large interners legitimately force the gather lane; only a
@@ -720,7 +632,6 @@ def tensor_lint(policy: CompiledPolicy,
     _check_operands(policy, out)
     _check_circuit(policy, out)
     _check_dfa(policy, out)
-    _check_fused_layout(policy, out)
     if not out:
         _check_own_rows(policy, out)
     if not out:

@@ -633,17 +633,6 @@ def _numeric_lane_findings(policy: CompiledPolicy) -> List[Finding]:
     return findings
 
 
-def _rows_in_range(policy: CompiledPolicy) -> bool:
-    """True when every dfa_table_of_row entry indexes a real table.  The
-    grouping audit below only runs on a valid map: an out-of-range entry is
-    the dfa-table-index lint's finding, not the permutation's fault."""
-    rows = np.asarray(policy.dfa_table_of_row)
-    if not rows.size:
-        return True
-    T = int(policy.dfa_tables.shape[0]) if policy.dfa_tables is not None else 0
-    return int(rows.min()) >= 0 and int(rows.max()) < T
-
-
 def _own_rows_findings(policy: CompiledPolicy) -> List[Finding]:
     """Own-config audit (ISSUEs 26 and 28, once per snapshot):
     ``config_dfa_rows`` and the ``OwnLayout`` tables against the circuit, by
@@ -660,51 +649,6 @@ def _own_rows_findings(policy: CompiledPolicy) -> List[Finding]:
         _check_own_layout(policy, found)
     return [_err("own-rows-layout", f.message, f.location, **f.detail)
             for f in found]
-
-
-def _fused_layout_findings(policy: CompiledPolicy) -> List[Finding]:
-    """Fused-layout audit (ISSUE 17, once per snapshot): the mega-kernel's
-    packed operand layouts against their sources.  A corrupted row
-    permutation silently evaluates every affected regex leaf against the
-    WRONG automaton; a lossy int8 op cast reroutes leaves through the
-    wrong comparison; a wrong bitpack width truncates (or pads) the
-    readback the dispatchers decode — none of which a truth-table over
-    atoms can see, so the certifier checks the layouts symbolically."""
-    from ..ops.pattern_eval import packed_width
-
-    findings: List[Finding] = []
-    if policy.dfa_table_of_row is not None:
-        R = int(policy.dfa_table_of_row.shape[0])
-        perm = getattr(policy, "dfa_row_perm", None)
-        if perm is None or perm.shape != (R,) or \
-                not np.array_equal(np.sort(np.asarray(perm)), np.arange(R)):
-            findings.append(_err(
-                "fused-layout",
-                f"dfa_row_perm is not a bijection over [0, R={R})",
-                "dfa_row_perm"))
-        elif R and _rows_in_range(policy) and np.any(np.diff(
-                policy.dfa_table_of_row[np.asarray(perm)]) < 0):
-            findings.append(_err(
-                "fused-layout",
-                "dfa_row_perm does not group DFA rows by owning table",
-                "dfa_row_perm"))
-    i8 = getattr(policy, "leaf_op_i8", None)
-    if policy.leaf_op is not None and (
-            i8 is None or i8.dtype != np.int8
-            or not np.array_equal(i8.astype(np.int64),
-                                  policy.leaf_op.astype(np.int64))):
-        findings.append(_err(
-            "fused-layout",
-            "leaf_op_i8 is not a lossless int8 image of leaf_op",
-            "leaf_op_i8"))
-    if policy.eval_rule is not None:
-        want = packed_width(1 + 2 * int(policy.eval_rule.shape[1]))
-        if int(getattr(policy, "fused_pack_w", 0)) != want:
-            findings.append(_err(
-                "fused-layout",
-                f"fused_pack_w {getattr(policy, 'fused_pack_w', 0)} != "
-                f"packed_width(1+2E) = {want}", "fused_pack_w"))
-    return findings
 
 
 # ---------------------------------------------------------------------------
@@ -1054,9 +998,6 @@ def certify_snapshot(policy: CompiledPolicy, use_cache: bool = True,
     # numeric-lane binding audit (once per snapshot, never cached: slot
     # layout is corpus-global, not per-config semantic)
     failures += _numeric_lane_findings(policy)
-    # fused packed-layout audit (ISSUE 17): same corpus-global, never-
-    # cached treatment — the fused lane is a first-class certified peer
-    failures += _fused_layout_findings(policy)
     # own-row scan layout (ISSUE 26): the served entries evaluate only the
     # DFA rows this table names, so it is certified like the circuit itself
     failures += _own_rows_findings(policy)
@@ -1359,15 +1300,6 @@ def _mut_dfa_pad_corrupt(p: CompiledPolicy) -> None:
     p.dfa_tables[0, 0, 0] = 1
 
 
-def _mut_fused_perm_corrupt(p: CompiledPolicy) -> None:
-    """Duplicate one entry of the fused DFA row permutation (no longer a
-    bijection: one row evaluates twice, another never — ISSUE 17)."""
-    if p.dfa_row_perm is None or p.dfa_row_perm.shape[0] < 2:
-        raise AssertionError("corpus has fewer than two DFA rows")
-    p.dfa_row_perm = p.dfa_row_perm.copy()
-    p.dfa_row_perm[0] = p.dfa_row_perm[1]
-
-
 def _mut_own_row_dropped(p: CompiledPolicy) -> None:
     """Drop one DFA row from one config's own-row table (ISSUE 26): the
     served kernel would skip that regex for that config's requests and read
@@ -1409,22 +1341,6 @@ def _upstream(mutate):
     return planted
 
 
-def _mut_fused_int8_corrupt(p: CompiledPolicy) -> None:
-    """Nudge one packed int8 op code so it no longer mirrors leaf_op (the
-    affected leaf routes through the wrong comparison in the fused lane
-    only — invisible to every unfused check)."""
-    if p.leaf_op_i8 is None or p.leaf_op_i8.shape[0] == 0:
-        raise AssertionError("corpus has no leaves")
-    p.leaf_op_i8 = p.leaf_op_i8.copy()
-    p.leaf_op_i8[0] += 1
-
-
-def _mut_fused_packw_corrupt(p: CompiledPolicy) -> None:
-    """Grow the in-kernel bitpack width by one byte: the readback the
-    dispatchers decode no longer matches packed_width(1+2E)."""
-    p.fused_pack_w = int(p.fused_pack_w) + 1
-
-
 _MUTANTS = (
     ("circuit-child-flip", _upstream(_mut_circuit_child_flip)),
     ("eval-rule-redirect", _upstream(_mut_eval_rule_redirect)),
@@ -1433,11 +1349,6 @@ _MUTANTS = (
     ("dfa-transition-corrupt", _mut_dfa_transition),
     ("dfa-accept-flip", _mut_dfa_accept_flip),
     ("dfa-pad-corrupt", _mut_dfa_pad_corrupt),
-    # ISSUE 17 fused packed-layout classes (caught by
-    # _fused_layout_findings, not the truth-table layer)
-    ("fused-perm-corrupt", _mut_fused_perm_corrupt),
-    ("fused-int8-corrupt", _mut_fused_int8_corrupt),
-    ("fused-packw-corrupt", _mut_fused_packw_corrupt),
     # ISSUE 26 own-row scan layout (caught by _own_rows_findings)
     ("own-row-dropped", _mut_own_row_dropped),
     # ISSUE 28 own-config tables (caught by _own_rows_findings)

@@ -326,18 +326,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "cpu-grid-overflow lowerability caveat drops for "
                         "assisted corpora (the host-fallback lane remains "
                         "the degrade backstop)")
-    s.add_argument("--kernel-lane",
-                   choices=("auto", "fused", "gather", "matmul"),
-                   default=env_var("AUTHORINO_TPU_KERNEL_LANE", "auto"),
-                   help="ISSUE 17: device-eval kernel lane.  'fused' runs "
-                        "the whole hot path (DFA byte scan, relation "
-                        "gathers, numeric compares, overflow-assist "
-                        "selects, the And/Or circuit, and the bitpacked "
-                        "verdict readback) in ONE launch — Pallas on TPU, "
-                        "interpret-mode Pallas on CPU, single-jit lax "
-                        "fallback otherwise.  'auto' (default) picks fused "
-                        "on a TPU backend and the classic per-stage lane "
-                        "elsewhere")
     s.add_argument("--no-metadata-prefetch", action="store_true",
                    default=not env_var("AUTHORINO_TPU_METADATA_PREFETCH",
                                        True),
@@ -614,11 +602,6 @@ async def run_server(args) -> None:
     # NOTE: --batch-window-us no longer reaches the engine (the old
     # max_delay_s mirror was a documented no-op since the pipelined
     # dispatcher landed); it still feeds the native C++ gather window below
-    kernel_lane_arg = str(getattr(args, "kernel_lane", "auto") or "auto")
-    if kernel_lane_arg != "auto":
-        # mirror the flag into the env so lane-unaware to_device() calls
-        # (mesh shard uploads, tooling) resolve the same kernel lane
-        os.environ["AUTHORINO_TPU_KERNEL_LANE"] = kernel_lane_arg
     engine = PolicyEngine(
         max_batch=args.batch_size,
         timeout_s=(args.timeout / 1000.0) if args.timeout else None,
@@ -650,7 +633,6 @@ async def run_server(args) -> None:
         corpus_pregate_budget_s=float(
             getattr(args, "corpus_pregate_budget_ms", 2000.0)) / 1e3,
         ovf_assist=bool(getattr(args, "ovf_assist", False)) or None,
-        kernel_lane=kernel_lane_arg if kernel_lane_arg != "auto" else None,
         metadata_prefetch=not getattr(args, "no_metadata_prefetch", False),
         metadata_prefetch_max_age_s=float(
             getattr(args, "metadata_max_age", 300.0)),
@@ -893,8 +875,6 @@ async def run_server(args) -> None:
                 lane_host_max_rows=int(getattr(args, "lane_host_max_rows",
                                                64)),
                 slo_ms=float(getattr(args, "slo_ms", 0.0)),
-                kernel_lane=(kernel_lane_arg
-                             if kernel_lane_arg != "auto" else None),
             )
             native_fe.start()
             # /debug/vars picks the frontend up; /readyz is 503 until warmed

@@ -306,26 +306,17 @@ class CompiledPolicy:
     # overflow rows stay on the device lane instead of host_fallback
     ovf_assist: bool = False
 
-    # --- fused mega-kernel layout (ISSUE 17) ---
-    # Derived deterministically in __post_init__ (so deserialized snapshots
-    # rebuild byte-identical layouts without a format bump) but STORED as
-    # fields: the fused lane's operand build consumes them directly, and the
-    # tensor lint + translation certifier audit them against their sources —
-    # a corrupted layout is a real miscompile, not a stale cache.
-    # dfa rows re-keyed for contiguous gathers: stable argsort by owning
-    # table, so per-byte transition gathers walk the deduped table axis
-    # sequentially instead of hopping through the compile-order row map
-    dfa_row_perm: np.ndarray = None      # [R] int32 (bijection over rows)
-    leaf_op_i8: np.ndarray = None        # [L] int8 packed op codes (ops < 2^7)
-    fused_pack_w: int = 0                # in-kernel bitpack width, packed_width(1+2E)
-
     # --- own-row DFA scan layout (ISSUE 26) ---
     # per config row, the DFA rows its evaluators' circuits reach, ascending,
     # padded with -1 to D = the most any one config reaches (>= 1).  Leaves
     # are deduplicated across configs, so a DFA row may appear under several
-    # configs: a per-config table, not a partition.  Derived like
-    # dfa_row_perm (deterministic, stored, audited by the lint and the
-    # certifier); only ShapeTargets.n_own_dfa_rows widens it, so shards stack.
+    # configs: a per-config table, not a partition.  Derived
+    # deterministically in __post_init__ (so deserialized snapshots rebuild
+    # byte-identical layouts without a format bump) but STORED as a field:
+    # the operand build consumes it directly, and the tensor lint + the
+    # translation certifier audit it against its sources — a corrupted
+    # layout is a real miscompile, not a stale cache.  Only
+    # ShapeTargets.n_own_dfa_rows widens it, so shards stack.
     config_dfa_rows: np.ndarray = None   # [G, D] int32 (-1 = no row)
 
     # --- own-config layout (ISSUE 28) ---
@@ -343,13 +334,6 @@ class CompiledPolicy:
                 self.config_dfa_rows = derive_config_dfa_rows(self, reach)
             if self.own is None:
                 self.own = derive_own_layout(self, reach)
-        if self.dfa_row_perm is None and self.dfa_table_of_row is not None:
-            self.dfa_row_perm = np.argsort(
-                self.dfa_table_of_row, kind="stable").astype(np.int32)
-        if self.leaf_op_i8 is None and self.leaf_op is not None:
-            self.leaf_op_i8 = self.leaf_op.astype(np.int8)
-        if not self.fused_pack_w and self.eval_rule is not None:
-            self.fused_pack_w = (1 + 2 * int(self.eval_rule.shape[1]) + 7) // 8
 
     def rule_sources(self) -> List[List[str]]:
         """Decision provenance (ISSUE 9): per config row, the source string

@@ -16,11 +16,30 @@ the DFA byte tensors.  The wire format carries only what the kernel reads, and r
 return as one packed matrix: every byte crosses the host↔device link
 (PCIe), whose share of a batch has not been measured on the current code.
 
-Two lanes of the dense body:
+Three bodies, one selection:
 
-  - ``matmul`` (default): gathers are pathological on TPU (they lower to
-    scalar-unit loops), so every gather is reformulated as a one-hot matmul
-    on the MXU:
+  - ``eval_own`` is what serves.  Every entry that reads only each row's
+    own config (``eval_full_jit`` and, through it, ``eval_packed_jit``,
+    ``eval_bitpacked_jit`` — the served entry — and ``eval_fused_jit``, the
+    engine lane's single-staging-buffer entry) runs it: ONE body that
+    gathers row ``config_id`` of the corpus's per-config tables
+    (compiler/compile.py ``OwnLayout``) and evaluates the request's own
+    [B, l_own] leaves, [B, n_own] circuit nodes a level and [B, E]
+    evaluators.  Its DFA scan covers ``config_dfa_rows[config_id]``, the
+    [B, D] rows the config reaches: their [S, 256] tables are gathered once
+    a launch from the deduplicated ``dfa_tables``, one batched matmul with
+    the byte one-hots gives every byte position's S -> S map, the
+    ``lax.scan`` carries [B, D], and the accepts feed the own leaves
+    directly.  Reads inside a row (attribute of a leaf, child of a node) are
+    one-hot mask-reduces over the small own axes — integer-exact, no gather.
+    Nothing in it grows with the corpus but the tables it gathers one row
+    from.
+  - ``_eval_verdicts_matmul`` is the dense body of the callers that want
+    every config's column (``to_device(dense=True)``: ``forward`` /
+    ``_eval_jit`` / ``eval_batch_jit`` through models/policy_model.py, the
+    mesh step in parallel/sharded_eval.py).  Gathers are pathological on
+    TPU (they lower to scalar-unit loops), so every gather is reformulated
+    as a one-hot matmul on the MXU:
       * leaf operand selection rides ``attrs @ attr_onehot`` with
         ``Precision.HIGHEST`` — XLA's 3-pass bf16 decomposition makes the
         f32 product exact, and selecting through an exact 0/1 one-hot
@@ -34,33 +53,17 @@ Two lanes of the dense body:
         step's transition lookup becomes a batched
         (byte-one-hot × transition-table) matmul — values ≤ 255 are exact
         in bf16.
-  - ``gather``: the direct jnp.take formulation — the semantic reference
-    for differential tests, and the automatic fallback when the interner
-    outgrows exact-f32 range (ids ≥ 2^24).
+  - ``_eval_verdicts_gather`` is the direct jnp.take formulation of the
+    dense body — the semantic reference the differential tests compare
+    against, and the dense body of a corpus whose interner outgrows
+    exact-f32 range (ids ≥ 2^24).
 
-Lane dispatch is structural: ``to_device(dense=True)`` builds the matmul
-operands (or not), and ``eval_verdicts`` branches on their presence at trace
-time, so the two lanes jit-cache independently.
-
-Which circuit runs is structural too.  A caller that reads only each row's
-own config (``eval_full_jit`` and, through it, ``eval_packed_jit``,
-``eval_bitpacked_jit`` — the served entry — and ``eval_fused_jit``) runs
-``eval_own`` on both lax lanes: ONE body that gathers row ``config_id`` of
-the corpus's per-config tables (compiler/compile.py ``OwnLayout``) and
-evaluates the request's own [B, l_own] leaves, [B, n_own] circuit nodes a
-level and [B, E] evaluators.  Its DFA scan covers ``config_dfa_rows
-[config_id]``, the [B, D] rows the config reaches: their [S, 256] tables are
-gathered once a launch from the deduplicated ``dfa_tables``, one batched
-matmul with the byte one-hots gives every byte position's S -> S map, the
-``lax.scan`` carries [B, D], and the accepts feed the own leaves directly.
-Reads inside a row (attribute of a leaf, child of a node) are one-hot
-mask-reduces over the small own axes — integer-exact, no gather.  Nothing in
-it grows with the corpus but the tables it gathers one row from, so
-``to_device`` builds the G x L one-hot operands only for callers that want
-every config's column (``dense=True``: ``forward`` / ``_eval_jit`` /
-``eval_batch_jit`` through models/policy_model.py, the mesh step in
-parallel/sharded_eval.py); the fused lane (ops/fused_kernel.py) has one body
-and is dense.
+The selection is structural and taken from the input alone: ``to_device``
+builds the ``matmul`` subtree unless the interner is past 2^24 strings (or a
+test or the lint's per-lane loop names the reference body with ``lane=``),
+``dense=True`` adds the G x L one-hot operands to it, and ``eval_verdicts``
+branches on their presence at trace time, so the bodies jit-cache
+independently.  No flag or environment variable names a body.
 
 The row payload's CPU lane is own-config too: ``cpu_dense`` is [B, c_own],
 column j the answer of leaf ``own.cpu_leaves[config_id, j]``; the dense
@@ -74,7 +77,6 @@ host_fallback by pack_batch and re-decided on host by the expression oracle
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional, Tuple
 
@@ -118,7 +120,7 @@ __all__ = ["DevicePolicy", "to_device", "eval_verdicts", "eval_own",
            "fuse_batch", "eval_fused_jit", "dispatch_fused",
            "fused_h2d_supported", "eval_bitpacked_jit", "unpack_verdicts",
            "packed_width", "firing_columns", "unpack_attribution",
-           "kernel_lane_of", "kernel_body_of"]
+           "kernel_lane_of"]
 
 # exact integer range of f32 accumulation — larger interners must use the
 # gather lane
@@ -127,39 +129,10 @@ _F32_EXACT = 1 << 24
 _HIGH = jax.lax.Precision.HIGHEST
 
 
-def _eval_lane() -> str:
-    return os.environ.get("AUTHORINO_TPU_EVAL_LANE", "matmul")
-
-
-def _kernel_lane() -> str:
-    """Env mirror of ``--kernel-lane``: ``fused`` arms the ISSUE 17
-    mega-kernel, ``gather``/``matmul`` force those lanes, ``auto``
-    (default) is the ``_eval_lane()`` lane — matmul — on every platform.
-    On a TPU v5e matmul is the body that compiled, agreed with the host
-    oracle and served the 1k-AuthConfig corpus (CHANGES.md, PR 21); the
-    fused lane's Pallas body does not lower there, so only an explicit
-    request arms it, and then a lowering failure is an error, never a
-    switch to another body."""
-    return os.environ.get("AUTHORINO_TPU_KERNEL_LANE", "auto")
-
-
 def kernel_lane_of(params) -> str:
-    """Which kernel lane a params pytree dispatches through — structural,
-    mirroring eval_verdicts' trace-time branch order."""
-    if params.get("fused") is not None:
-        return "fused"
-    if params.get("matmul") is not None:
-        return "matmul"
-    return "gather"
-
-
-def kernel_body_of(params) -> str:
-    """What executes a single-corpus device dispatch of ``params``: the
-    fused lane's one launch is a Pallas kernel (ops/fused_kernel.py); every
-    other lane — and every lane inside the mesh step — is lax ops compiled
-    by XLA.  Reported next to the lane label, so what ran is what is
-    named."""
-    return "pallas" if params.get("fused") is not None else "lax"
+    """Which lane ``to_device`` built a params pytree for — structural,
+    mirroring eval_verdicts' trace-time branch."""
+    return "matmul" if params.get("matmul") is not None else "gather"
 
 
 def _mm_dtype(device=None):
@@ -282,7 +255,9 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
     """Upload a compiled corpus's operands as a pytree of device arrays.
     The engine double-buffers these and swaps atomically on reconcile
     (SURVEY.md §3.4: rule-tensor compile + device upload on index Set).
-    ``lane`` overrides the env-var lane selection; ``host=True`` keeps the
+    ``lane`` names the reference body (``gather``) for the callers that
+    compare against it; left None it is ``matmul``, or ``gather`` once the
+    interner is past exact-f32 range.  ``host=True`` keeps the
     operands as host numpy arrays — the sharded model stacks per-shard
     pytrees host-side and transfers each shard's slice exactly once via a
     mesh-sharded device_put, instead of staging everything on device 0.
@@ -294,8 +269,7 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
     else:
         put = partial(jax.device_put, device=device) if device is not None else jax.device_put
     if lane is None:
-        kl = _kernel_lane()
-        lane = kl if kl in ("fused", "gather", "matmul") else _eval_lane()
+        lane = "matmul"
     if lane == "matmul" and len(policy.interner) + 4 >= _F32_EXACT:
         lane = "gather"  # ids no longer exact in f32 accumulation
     # per-dfa-row byte-tensor slot (attr → slot mapping folded in here);
@@ -309,12 +283,6 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
         if dense:
             mm.update(jax.tree.map(
                 put, _matmul_operands(policy, dfa_byte_slot, device=device)))
-    if lane == "fused":
-        from . import fused_kernel as _fk  # lazy: fused_kernel imports us
-
-        fz = jax.tree.map(put, _fk.fused_operands(policy, dfa_byte_slot))
-    else:
-        fz = None
     # gather-lane helpers for the compact payload
     L = policy.n_leaves
     member_slot_of_leaf = np.maximum(
@@ -330,9 +298,6 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
     # no-op for host=True), so nothing ever stages on the default device
     return {
         "matmul": mm,
-        # fused mega-kernel subtree (ISSUE 17): int8 op codes + the
-        # table-grouped DFA row layout; None (structural) on other lanes
-        "fused": fz,
         "leaf_op": put(policy.leaf_op),
         "leaf_attr": put(policy.leaf_attr),
         "leaf_const": put(policy.leaf_const),
@@ -341,9 +306,8 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
         # per-config tables of eval_own (compiler/compile.py OwnLayout), one
         # ROW a config: the device pads the two minor axes of an array to
         # its tiles, so [G, 10, 10] would take 20 times its bytes and be
-        # copied whole by every launch's gather.  The fused lane has no own
-        # body
-        "own": None if lane == "fused" else {
+        # copied whole by every launch's gather
+        "own": {
             "leaf": put(own.leaf_tab.reshape(G_own, -1)),        # [G, l_own * F]
             "levels": tuple((put(c.reshape(G_own, -1)), put(a))  # [G, n * w], [G, n]
                             for c, a in own.levels),
@@ -468,8 +432,7 @@ def kernel_widths(params, own: bool = True) -> dict:
     scanned (D on ``eval_own``, R on a dense body; both 0 without a device
     DFA lane); ``leaf_cols_per_row`` is the leaf columns evaluated for it
     (l_own, or L while dense).  ``own``: an entry that returns own-config
-    results on a lax lane (the fused lane has no own body)."""
-    own = own and params.get("own") is not None
+    results (False: the mesh step, which is dense)."""
     out = {"dfa_rows_per_row": 0, "dfa_rows_total": 0,
            "leaf_cols_per_row": int(params["own"]["leaf"].shape[-1]) // OWN_FIELDS if own
            else int(params["leaf_op"].shape[-1])}
@@ -858,7 +821,7 @@ def eval_verdicts(
     """The dense body: (verdict [B, G] bool, (rule_results [B, G, E],
     skipped [B, G, E])), every config's column exact.  Runs the matmul
     formulation where ``to_device(dense=True)`` built its operands, the
-    gather formulation otherwise; the fused lane has one body."""
+    gather formulation otherwise."""
     # ids travel as int16 when the interner fits (compiler/pack.py
     # wire_dtype); upcast on device AFTER the transfer
     if attrs_val.dtype != jnp.int32:
@@ -867,10 +830,6 @@ def eval_verdicts(
         members_c = members_c.astype(jnp.int32)
     operands = (params, attrs_val, members_c, cpu_dense, config_id, attr_bytes,
                 byte_ovf, attrs_num, num_valid, rel_rows, member_ovf)
-    if params.get("fused") is not None:
-        from . import fused_kernel as _fk  # lazy: fused_kernel imports us
-
-        return _fk._eval_verdicts_fused(*operands)
     mm = params.get("matmul")
     if mm is not None and "rule_m" in mm:
         return _eval_verdicts_matmul(*operands)
@@ -907,18 +866,11 @@ def eval_full_jit(params, attrs_val, members_c, cpu_dense, config_id,
                   num_valid=None, rel_rows=None, member_ovf=None):
     """Like _eval_jit but returns only each request's own verdict and
     per-evaluator rule results + skipped flags [B, E] — what the pipeline's
-    batched pattern-matching evaluators consume (runtime/engine.py).  The
-    lax lanes evaluate the request's own config alone (``eval_own``)."""
-    operands = (params, attrs_val, members_c, cpu_dense, config_id, attr_bytes,
-                byte_ovf, attrs_num, num_valid, rel_rows, member_ovf)
-    if params.get("own") is not None:
-        return eval_own(*operands)
-    verdict, (rule, skipped) = eval_verdicts(*operands)
-    own_mask = _select_own(config_id, verdict.shape[1])
-    own = jnp.any(verdict & own_mask, axis=1)
-    own_rule = jnp.any(rule & own_mask[:, :, None], axis=1)
-    own_skipped = jnp.any(skipped & own_mask[:, :, None], axis=1)
-    return own, own_rule, own_skipped
+    batched pattern-matching evaluators consume (runtime/engine.py): the
+    request's own config alone (``eval_own``)."""
+    return eval_own(params, attrs_val, members_c, cpu_dense, config_id,
+                    attr_bytes, byte_ovf, attrs_num, num_valid, rel_rows,
+                    member_ovf)
 
 
 @partial(jax.jit, static_argnames=())
@@ -1167,16 +1119,6 @@ def dispatch_fused(params, db) -> "jax.Array":
     [B, W] uint8 readback (decode with ``unpack_verdicts``); the device→
     host copy starts eagerly so a later np.asarray only waits, never
     initiates."""
-    try:
-        from ..utils.metrics import observe_kernel_lane
-
-        observe_kernel_lane(kernel_lane_of(params))
-    except Exception:
-        pass  # metrics are advisory; never fail a dispatch over them
-    if params.get("fused") is not None:
-        from . import fused_kernel as _fk
-
-        return _fk.dispatch_megakernel(params, db)
     if fused_h2d_supported():
         buf, layout = fuse_batch(db)
         out = eval_fused_jit(params, jnp.asarray(buf), layout)

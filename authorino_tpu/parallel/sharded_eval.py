@@ -114,14 +114,13 @@ class MeshUnavailable(RuntimeError):
 # module-level eval_packed_jit cache on the single-corpus path.  The flags
 # pin the params/specs pytree STRUCTURE (lane presence changes it), so a
 # gather-lane model can never reuse a matmul-traced step.  ``extras`` is
-# the (has_num, has_rel, has_ovf, has_fused) tuple of the ISSUE 14 operand
-# lanes plus the ISSUE 17 fused-layout subtree (structure-changing too).
+# the (has_num, has_rel, has_ovf) tuple of the ISSUE 14 operand lanes.
 _STEP_CACHE: Dict[Tuple[Mesh, bool, bool, int, tuple], Any] = {}
 
 
 def _sharded_step(mesh: Mesh, has_dfa: bool, has_matmul: bool, n_levels: int,
                   specs,
-                  extras: tuple = (False, False, False, False)):
+                  extras: tuple = (False, False, False)):
     """Own-config evaluation step over the mesh: each mp shard evaluates its
     sub-corpus, selects the rows of requests whose config it owns, and the
     tiny [B], [B, E] results combine with one psum over 'mp' — so the
@@ -129,7 +128,7 @@ def _sharded_step(mesh: Mesh, has_dfa: bool, has_matmul: bool, n_levels: int,
     (the sharded analog of eval_packed_jit's one-small-readback contract).
     ``specs`` mirrors the stacked-params structure (P('mp') on every leaf);
     the cache key's flags pin that structure."""
-    has_num, has_rel, has_ovf = extras[:3]
+    has_num, has_rel, has_ovf = extras
     key = (mesh, has_dfa, has_matmul, n_levels, extras)
     step = _STEP_CACHE.get(key)
     if step is not None:
@@ -429,10 +428,8 @@ class ShardedPolicyModel:
                  members_k: int = 16, interner: Optional[StringInterner] = None,
                  defer_upload: bool = False, grid_relief: bool = True,
                  breaker_threshold: int = 3, breaker_reset_s: float = 5.0,
-                 ovf_assist: Optional[bool] = None,
-                 kernel_lane: Optional[str] = None):
+                 ovf_assist: Optional[bool] = None):
         self.mesh = mesh
-        self.kernel_lane = kernel_lane
         S = mesh.shape["mp"]
         self.n_shards = S
         self.members_k = members_k  # requested (single-corpus-equivalent) K
@@ -491,13 +488,12 @@ class ShardedPolicyModel:
         # The stacked view is retained: the next reconcile diffs against it
         # for the per-shard delta upload, and the failover path device_puts
         # it onto a single healthy device.
-        per_shard_params = [to_device(p, host=True, lane=kernel_lane, dense=True)
+        per_shard_params = [to_device(p, host=True, dense=True)
                             for p in self.shards]
         self.host_view = jax.tree.map(
             lambda *xs: np.stack(xs), *per_shard_params
         )
         self.has_matmul = self.host_view.get("matmul") is not None
-        self.has_fused = self.host_view.get("fused") is not None
         self.params = None            # set by upload()
         self.upload_report: Optional[Dict[str, Any]] = None
         self._step = None
@@ -614,8 +610,7 @@ class ShardedPolicyModel:
         n_levels = len(self.shards[0].levels)
         self._step = _sharded_step(
             self.mesh, self.has_dfa, self.has_matmul, n_levels, specs,
-            extras=(self.has_num, self.has_rel, self.has_ovf,
-                    self.has_fused),
+            extras=(self.has_num, self.has_rel, self.has_ovf),
         )
         return report
 

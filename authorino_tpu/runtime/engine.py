@@ -126,20 +126,12 @@ class _Snapshot:
     successor of the reference's label-selector instance sharding
     (ref: controllers/label_selector.go:14-45)."""
 
-    # class-level default: the replica/clone paths construct via
-    # ``__new__`` (from_serialized, clone) and never run ``__init__``,
-    # yet ``_upload`` still reads the lane — a loaded snapshot resolves
-    # it from the environment like any lane-unaware caller.
-    kernel_lane: Optional[str] = None
-
     def __init__(self, entries: Sequence[EngineEntry], members_k: int = 16,
                  mesh=None, strict_verify: bool = False,
                  compile_cache=None, prev: "Optional[_Snapshot]" = None,
                  breaker_threshold: int = 3, breaker_reset_s: float = 5.0,
-                 ovf_assist: Optional[bool] = None,
-                 kernel_lane: Optional[str] = None):
+                 ovf_assist: Optional[bool] = None):
         self.by_id: Dict[str, EngineEntry] = {e.id: e for e in entries}
-        self.kernel_lane = kernel_lane
         rules = [e.rules for e in entries if e.rules is not None]
         self.policy: Optional[CompiledPolicy] = None
         self.params = None
@@ -188,8 +180,7 @@ class _Snapshot:
             if mesh is not None:
                 self._compile_mesh(rules, members_k, mesh, strict_verify,
                                    prev, breaker_threshold, breaker_reset_s,
-                                   ovf_assist=ovf_assist,
-                                   kernel_lane=kernel_lane)
+                                   ovf_assist=ovf_assist)
             else:
                 self._compile_single(rules, members_k, strict_verify,
                                      compile_cache, prev,
@@ -200,8 +191,7 @@ class _Snapshot:
                       prev: "Optional[_Snapshot]",
                       breaker_threshold: int = 3,
                       breaker_reset_s: float = 5.0,
-                      ovf_assist: Optional[bool] = None,
-                      kernel_lane: Optional[str] = None) -> None:
+                      ovf_assist: Optional[bool] = None) -> None:
         """Mesh compile → verify → delta upload, each phase timed (the
         control-plane parity half of ISSUE 11):
 
@@ -225,8 +215,7 @@ class _Snapshot:
             rules, mesh, members_k=members_k,
             interner=(prev.sharded.interner if prev_ok else None),
             defer_upload=True, breaker_threshold=breaker_threshold,
-            breaker_reset_s=breaker_reset_s, ovf_assist=ovf_assist,
-            kernel_lane=kernel_lane)
+            breaker_reset_s=breaker_reset_s, ovf_assist=ovf_assist)
         self.phase_s["compile"] = time.monotonic() - t0
         memo: Dict[int, str] = {}
         self.fingerprints = {c.name: rules_fingerprint(c, memo)
@@ -309,7 +298,7 @@ class _Snapshot:
         from ..snapshots.diff import plan_delta
 
         t0 = time.monotonic()
-        host_view = to_device(self.policy, host=True, lane=self.kernel_lane)
+        host_view = to_device(self.policy, host=True)
         self.host_view = host_view
         plan = None
         if (prev is not None and prev.params is not None
@@ -547,7 +536,6 @@ class PolicyEngine:
         corpus_pregate: str = "",
         corpus_pregate_budget_s: float = 2.0,
         ovf_assist: Optional[bool] = None,
-        kernel_lane: Optional[str] = None,
         metadata_prefetch: bool = True,
         metadata_prefetch_max_age_s: float = 300.0,
         metadata_prefetch_refresh_s: float = 60.0,
@@ -696,13 +684,6 @@ class PolicyEngine:
         # metadata prefetch cache (request-independent external documents
         # pinned at reconcile cadence; relations/prefetch.py)
         self.ovf_assist = ovf_assist
-        # ISSUE 17: kernel lane override (None = env default
-        # AUTHORINO_TPU_KERNEL_LANE; "fused" arms the one-launch
-        # mega-kernel, ops/fused_kernel.py)
-        self.kernel_lane = kernel_lane
-        # the serving snapshot's kernel compile failure at swap-time
-        # prewarm, if any (/debug/vars, /readyz)
-        self.warm_error: Optional[str] = None
         self.metadata_prefetcher = None
         if metadata_prefetch:
             from ..relations.prefetch import MetadataPrefetcher
@@ -942,8 +923,7 @@ class PolicyEngine:
                              prev=self._snapshot,
                              breaker_threshold=self.breaker.threshold,
                              breaker_reset_s=self.breaker.reset_s,
-                             ovf_assist=self.ovf_assist,
-                             kernel_lane=self.kernel_lane)
+                             ovf_assist=self.ovf_assist)
         except SnapshotRejected as e:
             metrics_mod.snapshot_rejected.labels("engine").inc()
             RECORDER.record("snapshot-rejected", lane="engine", detail={
@@ -1138,22 +1118,6 @@ class PolicyEngine:
                 phase.kernel_cost = cost_rec
         except Exception:
             log.exception("kernel cost analysis failed (swap unaffected)")
-        # fused mega-kernel pre-warm (ISSUE 17): compile the one-launch
-        # entry at a small warm-grid pad at swap so the first
-        # post-reconcile batch pays no XLA/Pallas compile.  The swap has
-        # already happened (the degrade path answers exactly if dispatch
-        # fails too), but a kernel that does not lower is surfaced:
-        # warm_error rides /debug/vars and turns /readyz 503.
-        try:
-            if snap.policy is not None and snap.params is not None:
-                from ..ops import fused_kernel as fused_mod
-
-                fused_mod.prewarm_fused(snap.policy, snap.params, pad=16)
-            self.warm_error = None
-        except Exception as e:
-            log.exception("fused kernel failed to compile at swap "
-                          "(generation %d)", snap.generation)
-            self.warm_error = f"{type(e).__name__}: {e}"
 
     def _build_heat(self, snap: "_Snapshot") -> None:
         if snap.heat is not None:
@@ -1989,7 +1953,6 @@ class PolicyEngine:
             "translation_validation": (getattr(snap, "translation", None)
                                        if snap is not None else None),
             "breaker": self.breaker.to_json(),
-            "warm_error": self.warm_error,
             "draining": self._draining,
             "device_timeout_s": self.device_timeout_s,
             "device_rtt_ewma_s": self._device_ewma,
@@ -3331,21 +3294,6 @@ class PolicyEngine:
         from ..ops.pattern_eval import unpack_verdicts
 
         sharded = snap.sharded
-        # occupancy-shaped padding (ISSUE 17, fused lane only): the stacked
-        # pad bucket follows the BUSIEST shard's row count replicated over
-        # the dp axis, so a shard-skewed batch pads each dp slice to uniform
-        # per-shard work instead of the global cut size.  Opt-in with the
-        # fused layout — the unfused mesh path keeps its exact pad pins.
-        if getattr(sharded, "has_fused", False) and n:
-            from ..ops.fused_kernel import occupancy_pad
-
-            counts = [0] * sharded.n_shards
-            for nm in names[:n]:
-                loc = sharded.locator.get(nm)
-                if loc is not None:
-                    counts[loc[0]] += 1
-            pad = occupancy_pad(counts, sharded.mesh.shape["dp"], n,
-                                floor=16)
         enc = sharded.encode(docs, names, batch_pad=pad)
         keys = (sharded.row_keys(enc, n)
                 if n and (self.batch_dedup or self._verdict_cache is not None)

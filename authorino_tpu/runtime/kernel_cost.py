@@ -280,14 +280,6 @@ def entry_points(policy=None, sharded=None) -> List[Dict[str, Any]]:
                     "backend bitcast probe fails)",
             "operands": ops,
         })
-        out.append({
-            "entry": "fused_kernel",
-            "kind": "one-launch mega-kernel (ISSUE 17): one Pallas call "
-                    "(interpret mode off-TPU); every lane + circuit + "
-                    "in-kernel bitpack in one executable, armed only by "
-                    "an explicit --kernel-lane fused",
-            "operands": ops,
-        })
     return out
 
 

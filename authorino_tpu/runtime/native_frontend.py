@@ -670,13 +670,8 @@ class NativeFrontend:
                  admission_target_s: float = 0.05,
                  brownout: bool = True, brownout_max_rows: int = 64,
                  lane_select: bool = True, lane_host_max_rows: int = 64,
-                 slo_ms: float = 0.0,
-                 kernel_lane: Optional[str] = None):
+                 slo_ms: float = 0.0):
         self.engine = engine
-        # ISSUE 17: kernel lane override (None = env default
-        # AUTHORINO_TPU_KERNEL_LANE) applied when refresh() builds params
-        # for snapshots the engine did not already upload
-        self.kernel_lane = kernel_lane
         # fault tolerance (ISSUE 5, docs/robustness.md): a failed device
         # batch retries once, then degrades to the SAME kernel on the CPU
         # backend (fail-closed deny only if that fails too); consecutive
@@ -1111,8 +1106,8 @@ class NativeFrontend:
                 "warm_done": (rec.warm_done.is_set()
                               and rec.warm_error is None),
                 "warm_error": rec.warm_error,
-                # what a device dispatch of this snapshot runs: the lane
-                # (operand layout + math) and the body that executes it
+                # what a device dispatch of this snapshot runs: the jitted
+                # entry, the operand lane and the widths of one row's work
                 "kernel": self._kernel_of(rec),
                 "fast_configs": len(rec.row_labels),
                 "hybrid_configs": len(rec.hybrid_rows),
@@ -1122,28 +1117,23 @@ class NativeFrontend:
 
     @staticmethod
     def _kernel_of(rec: _SnapRec) -> Optional[Dict[str, Any]]:
-        from ..ops.pattern_eval import (kernel_body_of, kernel_lane_of,
-                                        kernel_widths, operand_bytes)
+        from ..ops.pattern_eval import (kernel_lane_of, kernel_widths,
+                                        operand_bytes)
 
         if rec.sharded is not None:
             # the mesh step wants every config's column: the dense body
             view = rec.sharded.host_view
-            return {"lane": kernel_lane_of(view),
-                    "body": "lax", "entry": "sharded_step",
+            return {"lane": kernel_lane_of(view), "entry": "sharded_step",
                     "operand_bytes": operand_bytes(view),
                     **kernel_widths(view, own=False)}
         if rec.params is None:
             return None
-        lane = kernel_lane_of(rec.params)
-        return {"lane": lane, "body": kernel_body_of(rec.params),
-                "entry": ("fused_kernel" if lane == "fused"
-                          else "eval_bitpacked"),
+        return {"lane": kernel_lane_of(rec.params), "entry": "eval_bitpacked",
                 # bytes of the serving snapshot's device operands, summed
                 # over the uploaded pytree
                 "operand_bytes": operand_bytes(rec.params),
                 # what the served entry evaluates for ONE request row (its
-                # own config's leaves and DFA rows on the lax lanes)
-                # against the corpus's
+                # own config's leaves and DFA rows) against the corpus's
                 **kernel_widths(rec.params)}
 
     @property
@@ -1340,28 +1330,6 @@ class NativeFrontend:
             jnp.asarray(np.zeros((pad, NB), dtype=bool)) if eff else None,
         )
         jax.block_until_ready(out)
-        # fused mega-kernel entry (ISSUE 17): the bitpacked warm above
-        # compiles the routed compute, but the serving dispatch enters
-        # through the one-launch per-operand fused entry — warm that
-        # executable too so the first post-swap batch pays no Pallas
-        # lowering (same (pad, eff) bucket, same operand signature as
-        # _dispatch's fused branch)
-        if rec.params is not None and rec.params.get("fused") is not None:
-            from ..ops import fused_kernel as fused_mod
-
-            out = fused_mod._fused_ops_jit(
-                rec.params,
-                jnp.asarray(np.zeros((pad, A), dtype=dt)),
-                jnp.asarray(np.full((pad, M, K), PAD, dtype=dt)),
-                jnp.asarray(np.zeros((pad, C), dtype=bool)),
-                jnp.asarray(np.zeros((pad,), dtype=np.int32)),
-                jnp.asarray(np.zeros((pad, NB, eff), dtype=np.uint8))
-                if eff else None,
-                jnp.asarray(np.zeros((pad, NB), dtype=bool))
-                if eff else None,
-                None, None, None, None,
-            )
-            jax.block_until_ready(out)
         rec.warm.add((pad, eff))
 
     def _prewarm_rest(self, rec: _SnapRec, grid: List[Tuple[int, int]]) -> None:
@@ -1645,7 +1613,7 @@ class NativeFrontend:
             if enc is not None:
                 rec.encoder = enc
                 rec.params = (snap.params if snap.params is not None
-                              else to_device(policy, lane=self.kernel_lane))
+                              else to_device(policy))
                 spec["policy"] = enc._handle
                 dt = wire_dtype(policy)
                 A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
@@ -2356,9 +2324,8 @@ class NativeFrontend:
                         row_of=sel("config_id"),
                         host_fallback=np.zeros((pad,), dtype=bool))
                 else:
-                    # single corpus, either kernel lane: the same six
-                    # operands, handed to the runtime here so that `launch`
-                    # times the jitted call alone
+                    # single corpus: the six operands, handed to the
+                    # runtime here so that `launch` times the jitted call alone
                     operands = (
                         jnp.asarray(sel("attrs_val")),
                         jnp.asarray(sel("members")),
@@ -2376,27 +2343,10 @@ class NativeFrontend:
                     # ledger launch (+ exact operand bytes) and the
                     # per-device launch counts
                     packed = sh.dispatch_full(operands)
-                elif rec.params.get("fused") is not None:
-                    # fused lane (ISSUE 17): the ONE-launch mega-kernel
-                    # entry (operands are already separate arrays here, so
-                    # the per-operand variant stages them; compute +
-                    # in-kernel bitpack are a single executable either way)
-                    from ..ops import fused_kernel as fused_mod
-
-                    packed = fused_mod._fused_ops_jit(
-                        rec.params, *operands, None, None, None, None)
                 else:
                     packed = eval_bitpacked_jit(rec.params, *operands)
                 if faults.ACTIVE:
                     packed = faults.FAULTS.wrap_handle(packed, "native")
-                if rec.sharded is None:
-                    try:
-                        from ..ops.pattern_eval import kernel_lane_of
-
-                        metrics_mod.observe_kernel_lane(
-                            kernel_lane_of(rec.params))
-                    except Exception:
-                        pass  # metrics are advisory
                 try:
                     packed.copy_to_host_async()
                 except Exception:

@@ -1241,14 +1241,6 @@ kernel_pad_waste_rows = _counter(
     "ratio.  Eff-column slack rides the ledger's /debug/vars block.",
     _LANE_LABELS,
 )
-kernel_lane = _counter(
-    "auth_server_kernel_lane_total",
-    "Batches dispatched per kernel lane (ISSUE 17): fused = the one-launch "
-    "mega-kernel, matmul = MXU one-hot lane, gather = jnp.take reference.  "
-    "Selection is --kernel-lane / AUTHORINO_TPU_KERNEL_LANE (auto arms "
-    "fused only on a real TPU backend).",
-    ("lane",),
-)
 kernel_modeled_flops_per_row = _gauge(
     "auth_server_kernel_modeled_flops_per_row",
     "XLA-modeled FLOPs per padded row of the serving snapshot's kernel "
@@ -1282,18 +1274,6 @@ def observe_kernel_cost(lane, launches, h2d_bytes, d2h_bytes,
         ch[2].inc(d2h_bytes)
     if pad_waste_rows:
         ch[3].inc(pad_waste_rows)
-
-
-_kernel_lane_children: dict = {}
-
-
-def observe_kernel_lane(lane: str) -> None:
-    """Count one dispatched batch on its kernel lane (cached label child —
-    once per micro-batch)."""
-    ch = _kernel_lane_children.get(lane)
-    if ch is None:
-        ch = _kernel_lane_children[lane] = kernel_lane.labels(lane)
-    ch.inc()
 
 
 # ---------------------------------------------------------------------------

@@ -294,27 +294,11 @@ def run_kernel_cost_grid(args):
     CostLedger at the engine dispatch site, plus the XLA-modeled
     flops/bytes per row at each shape.  Deliberately cryptography-free
     (no FakeIdP): everything here is compile + device dispatch.  The
-    numbers are STRUCTURAL — exact on any platform; no RPS claims.
-
-    Also emits the ISSUE 17 fused-vs-unfused comparison
-    (KERNELCOST_r02.json): per cell, the mega-kernel lane (ONE launch)
-    against the staged pre-fusion baseline (one launch per stage) —
-    launches/batch, H2D+D2H bytes/row, and the wall ratio of each device
-    lane RELATIVE to the host lane on the same rows.  Ratios only: on
-    this image the device is interpret-mode Pallas on CPU, so absolute
-    wall numbers would be meaningless."""
-    import time
-
+    numbers are STRUCTURAL — exact on any platform; no RPS claims."""
     import jax
 
     from authorino_tpu.compiler import ConfigRules
-    from authorino_tpu.compiler.encode import encode_batch
-    from authorino_tpu.compiler.pack import pack_batch
     from authorino_tpu.expressions import All, Operator, Pattern
-    from authorino_tpu.models.policy_model import host_results
-    from authorino_tpu.ops import fused_kernel as fkmod
-    from authorino_tpu.ops import pattern_eval as pe
-    from authorino_tpu.ops.pattern_eval import staged_h2d_bytes
     from authorino_tpu.runtime import EngineEntry, PolicyEngine
     from authorino_tpu.runtime.kernel_cost import LEDGER
 
@@ -343,59 +327,9 @@ def run_kernel_cost_grid(args):
         await asyncio.gather(*(engine.submit(d, f"cfg-{j % 8}")
                                for j, d in enumerate(docs)))
 
-    def cell_docs(batch):
-        return [{"request": {"method": "GET", "host": "cfg-0",
-                             "url_path": f"/api/v0/x{j % 8}",
-                             "headers": {"x-row": f"r{j}"}},
-                 "auth": {"identity": {"roles": [f"role-{j % 8}"],
-                                       "org": f"org-{j}"}}}
-                for j in range(batch)]
-
-    def wall(fn, reps=5):
-        fn()  # warm: jit/Pallas compile paid outside the timed loop
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            fn()
-        return (time.perf_counter() - t0) / reps
-
-    def fused_vs_unfused_cell(policy, batch, members_k):
-        """ISSUE 17 column: ONE mega-kernel launch vs the staged
-        pre-fusion baseline (bit-exact twins — tests pin it), wall
-        measured only RELATIVE to the host lane on the same rows."""
-        docs = cell_docs(batch)
-        rows = [policy.config_ids[f"cfg-{j % 8}"] for j in range(batch)]
-        db = pack_batch(policy, encode_batch(policy, docs, rows,
-                                             batch_pad=batch))
-        params = pe.to_device(policy, lane="fused")
-        pad = int(db.attrs_val.shape[0])
-        t_host = wall(lambda: [host_results(policy, d, r)
-                               for d, r in zip(docs, rows)], reps=3)
-        t_fused = wall(lambda: jax.block_until_ready(
-            fkmod.eval_fused_kernel(params, db)))
-        t_staged = wall(lambda: jax.block_until_ready(
-            fkmod.dispatch_staged(params, db)))
-        return {
-            "batch": batch,
-            "members_k": members_k,
-            "n_dfa_tables": int(policy.dfa_tables.shape[0]
-                                if policy.n_byte_attrs else 0),
-            "h2d_bytes_per_row": round(staged_h2d_bytes(db) / pad, 2),
-            "d2h_bytes_per_row": int(policy.fused_pack_w),
-            "fused": {
-                "launches_per_batch": 1.0,
-                "wall_vs_host_lane": round(t_fused / t_host, 3),
-            },
-            "unfused_staged": {
-                "launches_per_batch": float(
-                    fkmod.staged_launches(params, db)),
-                "wall_vs_host_lane": round(t_staged / t_host, 3),
-            },
-        }
-
     raw = ("batches", "launches", "rows", "device_rows", "pad_rows",
            "pad_waste_rows", "h2d_bytes", "d2h_bytes")
     grid = []
-    fused_grid = []
     for members_k in args.grid_members_k:
         for n_dfa in args.grid_dfa:
             configs = cell_configs(n_dfa)
@@ -441,12 +375,6 @@ def run_kernel_cost_grid(args):
                     f"h2d/row={cell['h2d_bytes_per_device_row']} "
                     f"d2h/pad-row={cell['d2h_bytes_per_pad_row']} "
                     f"occupancy={cell['pad_occupancy']}")
-                fcell = fused_vs_unfused_cell(policy, batch, members_k)
-                fused_grid.append(fcell)
-                log(f"  fused-vs-unfused: 1 launch vs "
-                    f"{fcell['unfused_staged']['launches_per_batch']:.0f}; "
-                    f"wall-vs-host {fcell['fused']['wall_vs_host_lane']} "
-                    f"vs {fcell['unfused_staged']['wall_vs_host_lane']}")
 
     artifact = {
         "round": "r01",
@@ -468,32 +396,8 @@ def run_kernel_cost_grid(args):
     with open(path, "w") as f:
         json.dump(artifact, f, indent=1, sort_keys=True)
     log(f"wrote {path}")
-    artifact2 = {
-        "round": "r02",
-        "issue": 17,
-        "metric": "kernel_cost_fused_vs_unfused",
-        "platform": f"jax {jax.__version__} {jax.devices()}",
-        "load_model": "closed-loop",
-        "caveat": "RATIOS ONLY: launches/batch, H2D+D2H bytes/row, and "
-                  "device-lane wall relative to the host lane on the same "
-                  "rows — the device here is interpret-mode Pallas on "
-                  "CPU, so absolute wall numbers (and any RPS headline) "
-                  "would be meaningless; fused and staged lanes are "
-                  "bit-exact twins (tests/test_fused_kernel.py)",
-        "grid_axes": {"batch": list(args.grid_batches),
-                      "members_k": list(args.grid_members_k),
-                      "n_dfa_regexes_per_config": list(args.grid_dfa)},
-        "grid": fused_grid,
-    }
-    path2 = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         "KERNELCOST_r02.json")
-    with open(path2, "w") as f:
-        json.dump(artifact2, f, indent=1, sort_keys=True)
-    log(f"wrote {path2}")
     print(json.dumps({"metric": "kernel_cost_structural",
-                      "cells": len(grid), "artifact": path,
-                      "fused_cells": len(fused_grid),
-                      "fused_artifact": path2}))
+                      "cells": len(grid), "artifact": path}))
     return artifact
 
 

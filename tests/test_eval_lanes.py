@@ -20,6 +20,8 @@ from authorino_tpu.compiler.pack import pack_batch
 from authorino_tpu.expressions import All, Any_, Operator, Pattern
 from authorino_tpu.ops import pattern_eval as pe
 
+from test_own_config_eval import _operands, all_operand_corpus, all_operand_docs
+
 
 def _mixed_corpus(n_configs=23, seed=5):
     rng = random.Random(seed)
@@ -61,19 +63,17 @@ def _docs(n, seed=11):
     return docs
 
 
-def _both_lane_params(policy, monkeypatch):
-    monkeypatch.setenv("AUTHORINO_TPU_EVAL_LANE", "matmul")
-    params_mm = pe.to_device(policy, dense=True)
-    monkeypatch.setenv("AUTHORINO_TPU_EVAL_LANE", "gather")
-    params_g = pe.to_device(policy, dense=True)
+def _both_lane_params(policy):
+    params_mm = pe.to_device(policy, lane="matmul", dense=True)
+    params_g = pe.to_device(policy, lane="gather", dense=True)
     assert "rule_m" in params_mm["matmul"]
     assert params_g["matmul"] is None
     return params_mm, params_g
 
 
-def test_matmul_lane_matches_gather_lane(monkeypatch):
+def test_matmul_lane_matches_gather_lane():
     policy = compile_corpus(_mixed_corpus(), members_k=4)
-    params_mm, params_g = _both_lane_params(policy, monkeypatch)
+    params_mm, params_g = _both_lane_params(policy)
     docs = _docs(64)
     rows = [i % policy.n_configs for i in range(len(docs))]
     db = pack_batch(policy, encode_batch_py(policy, docs, rows, batch_pad=64))
@@ -92,12 +92,12 @@ def test_matmul_lane_matches_gather_lane(monkeypatch):
     np.testing.assert_array_equal(np.asarray(s_mm), np.asarray(s_g))
 
 
-def test_matmul_lane_bf16_matches_gather_lane(monkeypatch):
+def test_matmul_lane_bf16_matches_gather_lane():
     """bf16 operand numerics (the real TPU configuration)."""
     if jax.default_backend() == "cpu":
         pytest.skip("CPU dot kernels lack BF16xBF16->F32")
     policy = compile_corpus(_mixed_corpus(31), members_k=4)
-    params_mm, params_g = _both_lane_params(policy, monkeypatch)
+    params_mm, params_g = _both_lane_params(policy)
     assert params_mm["matmul"]["rule_m"].dtype == jnp.bfloat16
     docs = _docs(128, seed=17)
     rows = [i % policy.n_configs for i in range(len(docs))]
@@ -115,13 +115,14 @@ def test_matmul_lane_bf16_matches_gather_lane(monkeypatch):
     np.testing.assert_array_equal(np.asarray(v_mm), np.asarray(v_g))
 
 
-def test_bitpacked_readback_roundtrips_both_lanes(monkeypatch):
+@pytest.mark.parametrize("lane", ["matmul", "gather"])
+def test_bitpacked_readback_roundtrips_both_lanes(lane):
     """The packed u8 bitmask readback (8 verdicts/byte, little bit order)
     must round-trip exactly against the unpacked [B, 1+2E] verdict arrays
     on BOTH the matmul and gather lanes — the D2H compression can never
     change an answer."""
     policy = compile_corpus(_mixed_corpus(), members_k=4)
-    params_mm, params_g = _both_lane_params(policy, monkeypatch)
+    params = pe.to_device(policy, lane=lane)
     docs = _docs(64)
     rows = [i % policy.n_configs for i in range(len(docs))]
     db = pack_batch(policy, encode_batch_py(policy, docs, rows, batch_pad=64))
@@ -135,13 +136,11 @@ def test_bitpacked_readback_roundtrips_both_lanes(monkeypatch):
     )
     E = int(policy.eval_rule.shape[1])
     cols = 1 + 2 * E
-    for params in (params_mm, params_g):
-        reference = np.asarray(pe.eval_packed_jit(params, *args))
-        packed = np.asarray(pe.eval_bitpacked_jit(params, *args))
-        assert packed.dtype == np.uint8
-        assert packed.shape == (reference.shape[0], pe.packed_width(cols))
-        np.testing.assert_array_equal(
-            pe.unpack_verdicts(packed, cols), reference)
+    reference = np.asarray(pe.eval_packed_jit(params, *args))
+    packed = np.asarray(pe.eval_bitpacked_jit(params, *args))
+    assert packed.dtype == np.uint8
+    assert packed.shape == (reference.shape[0], pe.packed_width(cols))
+    np.testing.assert_array_equal(pe.unpack_verdicts(packed, cols), reference)
     # bits past the verdict columns are zero padding (byte-stable wire)
     tail_bits = pe.packed_width(cols) * 8 - cols
     if tail_bits:
@@ -151,10 +150,24 @@ def test_bitpacked_readback_roundtrips_both_lanes(monkeypatch):
 
 def test_interner_overflow_falls_back_to_gather(monkeypatch):
     policy = compile_corpus(_mixed_corpus(5), members_k=4)
-    monkeypatch.setenv("AUTHORINO_TPU_EVAL_LANE", "matmul")
     monkeypatch.setattr(pe, "_F32_EXACT", len(policy.interner))
     params = pe.to_device(policy)
     assert params["matmul"] is None  # ids no longer exact in f32
+    assert pe.kernel_lane_of(params) == "gather"
+
+
+def test_environment_cannot_select_a_kernel_body(monkeypatch):
+    """Which body evaluates a batch is decided by ``to_device`` from its
+    input alone: the variables that once named a body change nothing."""
+    policy = compile_corpus(_mixed_corpus(5), members_k=4)
+    plain = pe.to_device(policy, host=True)
+    # the retired names, spelled in parts: a search for them finds no use
+    for knob, value in (("KERNEL", "fused"), ("EVAL", "gather")):
+        monkeypatch.setenv(f"AUTHORINO_TPU_{knob}_LANE", value)
+    params = pe.to_device(policy, host=True)
+    assert jax.tree.structure(params) == jax.tree.structure(plain)
+    assert pe.kernel_lane_of(params) == pe.kernel_lane_of(plain) == "matmul"
+    assert "fused" not in params and params["own"] is not None
 
 
 # ---------------------------------------------------------------------------
@@ -332,28 +345,33 @@ def _walk_eqns(jaxpr):
             yield from _walk_eqns(sub)
 
 
-def test_served_entry_holds_no_dense_dfa_intermediate():
+@pytest.mark.parametrize("corpus", ["tenant-rules", "all-operand-lanes"])
+def test_served_entry_holds_no_dense_dfa_intermediate(corpus):
     """Structure of the served entry for a corpus with R >> D: nothing of
     B x R x LB (the spread bytes) or B x R x 256 (the byte one-hot) elements
     is built, and the one scan carries [B, D] — so a later edit cannot fall
-    back to the dense scan unseen."""
+    back to the dense scan unseen, with every operand lane present or not."""
     n, B = 23, 16
-    policy = compile_corpus(
-        [ConfigRules(name=f"t-{i}", evaluators=[(None, _tenant_like(i))])
-         for i in range(n)], members_k=4)
-    R, D = policy.dfa_table_of_row.shape[0], policy.config_dfa_rows.shape[1]
-    assert (R, D) == (2 * n, 2)
     rng = random.Random(3)
+    if corpus == "tenant-rules":
+        policy = compile_corpus(
+            [ConfigRules(name=f"t-{i}", evaluators=[(None, _tenant_like(i))])
+             for i in range(n)], members_k=4)
+        docs = [_tenant_doc(i % n, rng) for i in range(B)]
+        want = (2 * n, 2)
+    else:
+        policy = compile_corpus(all_operand_corpus(rng, n_configs=n),
+                                members_k=4, ovf_assist=True)
+        docs = all_operand_docs(rng, n=B)
+        want = (3, 1)  # three distinct regexes, one a config
+    R, D = policy.dfa_table_of_row.shape[0], policy.config_dfa_rows.shape[1]
+    assert (R, D) == want
     db = pack_batch(policy, encode_batch_py(
-        policy, [_tenant_doc(i % n, rng) for i in range(B)],
-        [i % n for i in range(B)], batch_pad=B))
+        policy, docs, [i % n for i in range(B)], batch_pad=B))
     LB = db.attr_bytes.shape[2]
     for lane in ("matmul", "gather"):
         params = pe.to_device(policy, lane=lane)
-        jaxpr = jax.make_jaxpr(pe.eval_bitpacked_jit)(
-            params, jnp.asarray(db.attrs_val), jnp.asarray(db.members_c),
-            jnp.asarray(db.cpu_dense), jnp.asarray(db.config_id),
-            jnp.asarray(db.attr_bytes), jnp.asarray(db.byte_ovf))
+        jaxpr = jax.make_jaxpr(pe.eval_bitpacked_jit)(params, *_operands(db))
         scans = []
         for eqn in _walk_eqns(jaxpr.jaxpr):
             for v in eqn.outvars:

@@ -220,7 +220,7 @@ class TestEngineLane:
         assert set(kc) == {"ledger", "modeled", "entry_points"}
         assert kc["ledger"].keys() <= {"engine", "host", "mesh", "native"}
         names = [e["entry"] for e in kc["entry_points"]]
-        assert names == ["eval_bitpacked", "eval_fused", "fused_kernel"]
+        assert names == ["eval_bitpacked", "eval_fused"]
         for e in kc["entry_points"]:
             assert e["operands"][:4] == ["attrs_val", "members_c",
                                          "cpu_dense", "config_id"]
@@ -379,8 +379,7 @@ class TestEntryPointAudit:
             Pattern("m", Operator.EQ, "GET"))],
             members_k=4, ovf_assist=False)
         ep = entry_points(policy=pol)
-        assert [e["entry"] for e in ep] == ["eval_bitpacked", "eval_fused",
-                                            "fused_kernel"]
+        assert [e["entry"] for e in ep] == ["eval_bitpacked", "eval_fused"]
         for e in ep:
             assert e["operands"] == ["attrs_val", "members_c",
                                      "cpu_dense", "config_id"]

@@ -42,6 +42,7 @@ from authorino_tpu.runtime import EngineEntry, PolicyEngine
 from authorino_tpu.runtime.faults import FAULTS
 
 from test_compiler_differential import oracle_verdict, random_doc, random_expr
+from test_own_config_eval import _operands, all_operand_corpus, all_operand_docs
 
 pytestmark = pytest.mark.mesh
 
@@ -152,29 +153,65 @@ def test_bit_exact_parity_across_shapes(dp, mp, mesh_devices):
         assert got == want, (dp, mp, r, name)
 
 
-@pytest.mark.parametrize("seed", [101, 202, 303])
-def test_attribution_parity_property(seed, mesh_devices):
+def _served_single_corpus(configs, docs, names):
+    """(verdict, firing) of the single-corpus served entry on the whole
+    corpus, every operand lane the batch carries handed to it."""
+    from authorino_tpu.compiler import compile_corpus
+    from authorino_tpu.compiler.encode import encode_batch_py
+    from authorino_tpu.compiler.pack import pack_batch
+    from authorino_tpu.ops import pattern_eval as pe
+
+    policy = compile_corpus(configs, members_k=4, ovf_assist=True)
+    db = pack_batch(policy, encode_batch_py(
+        policy, docs, [policy.config_ids[n] for n in names]))
+    assert not db.host_fallback.any()  # the assist keeps every row exact
+    packed = pe.eval_bitpacked_jit(pe.to_device(policy), *_operands(db))
+    return unpack_attribution(np.asarray(packed),
+                              int(policy.eval_rule.shape[1]))
+
+
+@pytest.mark.parametrize("corpus,seed", [
+    ("random", 101), ("random", 202), ("random", 303),
+    # relation, numeric, overflow-assist, regex and CPU-fallback leaves in
+    # one circuit, on a 2 x 2 mesh
+    ("all-operand-lanes", 13), ("all-operand-lanes", 37)])
+def test_attribution_parity_property(corpus, seed, mesh_devices):
     """Provenance parity (ISSUE 11 satellite): firing_columns /
     unpack_attribution over the shard-stacked bitpacked readback must match
     the host oracle — and the degrade lane (host_decide_many) must
     attribute identically to the device lane it replaces."""
     rng = random.Random(seed)
-    configs = []
-    for i in range(11):
-        evaluators = []
-        for _ in range(rng.randint(1, 3)):
-            cond = random_expr(rng) if rng.random() < 0.3 else None
-            evaluators.append((cond, random_expr(rng)))
-        configs.append(ConfigRules(name=f"cfg-{i}", evaluators=evaluators))
-    mesh = build_mesh(n_devices=8, dp=2)
-    model = ShardedPolicyModel(configs, mesh, members_k=8)
-    docs = [random_doc(rng) for _ in range(48)]
-    names = [f"cfg-{rng.randrange(len(configs))}" for _ in docs]
+    if corpus == "random":
+        configs = []
+        for i in range(11):
+            evaluators = []
+            for _ in range(rng.randint(1, 3)):
+                cond = random_expr(rng) if rng.random() < 0.3 else None
+                evaluators.append((cond, random_expr(rng)))
+            configs.append(ConfigRules(name=f"cfg-{i}", evaluators=evaluators))
+        model = ShardedPolicyModel(configs, build_mesh(n_devices=8, dp=2),
+                                   members_k=8)
+        docs = [random_doc(rng) for _ in range(48)]
+        names = [f"cfg-{rng.randrange(len(configs))}" for _ in docs]
+    else:
+        configs = all_operand_corpus(rng)
+        model = ShardedPolicyModel(configs, build_mesh(n_devices=4, dp=2),
+                                   members_k=4, ovf_assist=True)
+        assert model.has_num and model.has_rel and model.has_ovf and model.has_dfa
+        docs = all_operand_docs(rng)
+        names = [rng.choice([c.name for c in configs]) for _ in docs]
+    by_name = {c.name: c for c in configs}
 
     enc = model.encode(docs, names)
     packed = np.asarray(model.dispatch_full(enc))
     E = int(model.shards[0].eval_rule.shape[1])
     verdict, firing = unpack_attribution(packed, E)
+    if corpus == "all-operand-lanes":
+        assert not enc.host_fallback.any()
+        n = len(docs)
+        s_verdict, s_firing = _served_single_corpus(configs, docs, names)
+        np.testing.assert_array_equal(verdict[:n], s_verdict[:n])
+        np.testing.assert_array_equal(firing[:n], s_firing[:n])
 
     degraded = model.host_decide_many(names, docs)
     for r, (doc, name) in enumerate(zip(docs, names)):
@@ -188,8 +225,8 @@ def test_attribution_parity_property(seed, mesh_devices):
         if not enc.host_fallback[r]:
             # device lane: bit-identical attribution for non-lossy rows
             assert int(firing[r]) == want_fire, (r, name)
-            assert bool(verdict[r]) == oracle_verdict(
-                configs[int(name.split("-")[1])], doc), (r, name)
+            assert bool(verdict[r]) == oracle_verdict(by_name[name], doc), (
+                r, name)
 
 
 def test_attribution_parity_through_dedup_fanout(mesh_devices):

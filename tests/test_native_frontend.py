@@ -1597,7 +1597,7 @@ def test_debug_vars_reports_what_the_served_kernel_scans(stack):
     R = int(policy.dfa_table_of_row.shape[0]) if policy.n_byte_attrs else 0
     D = int(policy.config_dfa_rows.shape[1]) if policy.n_byte_attrs else 0
     kernel = fe.debug_vars()["snapshot"]["kernel"]
-    assert kernel["entry"] == "eval_bitpacked" and kernel["body"] == "lax"
+    assert kernel["entry"] == "eval_bitpacked" and kernel["lane"] == "matmul"
     assert kernel["dfa_rows_total"] == R
     assert kernel["dfa_rows_per_row"] == D <= R
 

@@ -20,8 +20,10 @@ from authorino_tpu.analysis.tensor_lint import tensor_lint
 from authorino_tpu.compiler import ConfigRules, compile_corpus
 from authorino_tpu.compiler.encode import encode_batch_py
 from authorino_tpu.compiler.pack import pack_batch
-from authorino_tpu.expressions import All, Any_, Operator, Pattern
+from authorino_tpu.expressions import All, Any_, InGroup, Operator, Pattern
+from authorino_tpu.models.policy_model import host_results
 from authorino_tpu.ops import pattern_eval as pe
+from authorino_tpu.relations.closure import RelationClosure
 from authorino_tpu.snapshots.diff import plan_delta
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
@@ -116,6 +118,58 @@ def _doc(rng):
     }
 
 
+def all_operand_corpus(rng: random.Random, n_configs=6):
+    """Every operand lane in one circuit: relations (deep chain), numeric
+    compares, membership (overflow-capable at K=4), eq, device-DFA regex rows
+    (two distinct tables) and one CPU-regex config (backreference: outside
+    the DFA subset).  Shared with the mesh, lane and staging-buffer tests."""
+    deep = [(f"d{i}", f"d{i + 1}") for i in range(6)]
+    rel = RelationClosure(deep + [("u", "left"), ("left", "mid"),
+                                  ("mid", "top")])
+    groups = ["mid", "top", "left", "d3", "d5"]
+    cfgs = []
+    for i in range(n_configs):
+        leaves = [
+            InGroup("auth.identity.sub", rng.choice(groups), rel),
+            Pattern("req.n", rng.choice(
+                [Operator.GT, Operator.GE, Operator.LT, Operator.LE]),
+                str(rng.randrange(-5, 30))),
+            Pattern("auth.identity.roles", Operator.INCL, f"r{i % 3}"),
+            Pattern("req.m", Operator.EQ, rng.choice(["GET", "POST"])),
+            Pattern("req.path", Operator.MATCHES, rf"^/svc-{i % 3}/"),
+        ]
+        rng.shuffle(leaves)
+        rule = All(leaves[0], Any_(*leaves[1:4]))
+        cond = leaves[4] if rng.random() < 0.5 else None
+        cfgs.append(ConfigRules(name=f"cfg-{i}",
+                                evaluators=[(cond, rule), (None, leaves[4])]))
+    cfgs.append(ConfigRules(name="cfg-cpu", evaluators=[
+        (None, Pattern("req.q", Operator.MATCHES, r"^(a+)\1$"))]))
+    return cfgs
+
+
+def all_operand_docs(rng: random.Random, n=48):
+    ents = [f"d{i}" for i in range(7)] + ["u", "left", "mid", "top",
+                                          "stranger"]
+    return [{
+        "req": {"n": rng.choice([-10, 0, 3, 29, 30, "x", None]),
+                "m": rng.choice(["GET", "POST", "PUT"]),
+                # the long path exceeds DFA_VALUE_BYTES -> byte overflow
+                "path": rng.choice(["/svc-0/a", "/svc-1/b", "/zzz",
+                                    "/svc-2/" + "x" * 200]),
+                "q": rng.choice(["aaaa", "aaa", "ab"])},
+        "auth": {"identity": {
+            "sub": rng.choice(ents),
+            # K + 3 roles overflow the membership vector
+            "roles": [f"r{rng.randrange(4)}"
+                      for _ in range(rng.choice([1, 2, K + 3]))],
+        }},
+    } for _ in range(n)]
+
+
+_ALL_OPERAND = ("all-operand-lanes", "host-fallback")
+
+
 def _operands(db, config_id=None):
     def opt(a):
         return jnp.asarray(a) if a is not None else None
@@ -141,26 +195,35 @@ def _dense_own(policy, dense_params, operands):
 @pytest.mark.parametrize("lane", ["matmul", "gather"])
 @pytest.mark.parametrize("case,seed", [
     ("random", 1), ("random", 2), ("random", 3), ("numeric", 4),
-    ("no-regex-corpus", 5), ("one-config", 6), ("ovf-assist", 7)])
+    ("no-regex-corpus", 5), ("one-config", 6), ("ovf-assist", 7),
+    # relation, numeric, membership, regex and CPU-fallback leaves in one
+    # circuit, with the overflow assist and (host-fallback) without it
+    ("all-operand-lanes", 7), ("all-operand-lanes", 19),
+    ("all-operand-lanes", 31), ("host-fallback", 5)])
 def test_served_entry_equals_dense_body_and_oracle(case, seed, lane):
-    cfgs = _corpus(case, seed)
-    assist = case == "ovf-assist"
+    B = 96
+    assist = case in ("ovf-assist", "all-operand-lanes")
+    if case in _ALL_OPERAND:
+        rng = random.Random(seed)
+        cfgs = all_operand_corpus(rng)
+        docs = all_operand_docs(rng, n=B)
+    else:
+        cfgs = _corpus(case, seed)
+        rng = random.Random(seed + 100)
+        docs = [_doc(rng) for _ in range(B)]
     policy = compile_corpus(cfgs, members_k=K, ovf_assist=assist)
     assert tensor_lint(policy) == []
     own = policy.own
-    if len(cfgs) > 2:
+    if len(cfgs) > 2 and case not in _ALL_OPERAND:
         counts = (own.leaves >= 0).sum(axis=1)
         assert counts[0] == 1 and counts[2] > 10 and counts.max() == own.leaves.shape[1]
-    rng = random.Random(seed + 100)
-    B = 96
-    docs = [_doc(rng) for _ in range(B)]
     rows = [rng.randrange(len(cfgs)) for _ in range(B)]
     db = pack_batch(policy, encode_batch_py(policy, docs, rows, batch_pad=128))
     assert db.cpu_dense.shape == (128, policy.n_own_cpu)
     if case != "no-regex-corpus":
         assert np.asarray(db.byte_ovf)[:B].any()
     fallback = np.asarray(db.host_fallback)[:B]
-    if case in ("random", "ovf-assist"):
+    if case in ("random", "ovf-assist") + _ALL_OPERAND:
         overflowed = np.asarray(
             encode_batch_py(policy, docs, rows).overflow).any(axis=1)
         assert overflowed.any() and fallback.any() != assist
@@ -183,6 +246,7 @@ def test_served_entry_equals_dense_body_and_oracle(case, seed, lane):
     assert not off[:4].any()
 
     answers = set()
+    firing = pe.firing_columns(served[:, 1:1 + E], served[:, 1 + E:])
     for i, (doc, row) in enumerate(zip(docs, rows)):
         if fallback[i]:
             continue  # membership overflow: the host oracle re-decides the row
@@ -190,6 +254,11 @@ def test_served_entry_equals_dense_body_and_oracle(case, seed, lane):
                    if cond is None or cond.matches(doc))
         assert bool(served[i, 0]) == want, (case, seed, lane, i)
         answers.add(want)
+        # which rule fired, against the oracle the engine re-decides with
+        w_own, w_rule, w_skip = host_results(policy, doc, row)
+        assert w_own == want
+        assert firing[i] == pe.firing_columns(w_rule[None], w_skip[None])[0], (
+            case, seed, lane, i)
     assert answers == {True, False} or case == "one-config"
 
 

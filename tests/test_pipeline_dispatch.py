@@ -415,7 +415,10 @@ def test_inflight_gauge_and_pipeline_stages_exported():
 # satellite: fused H2D staging is bit-exact vs per-operand transfers
 # ---------------------------------------------------------------------------
 
-def test_fused_h2d_staging_matches_per_operand_path():
+@pytest.mark.parametrize("corpus", ["one-rule", "all-operand-lanes"])
+def test_fused_h2d_staging_matches_per_operand_path(corpus):
+    import random
+
     import jax.numpy as jnp
 
     from authorino_tpu.compiler.compile import compile_corpus
@@ -423,6 +426,7 @@ def test_fused_h2d_staging_matches_per_operand_path():
     from authorino_tpu.compiler.pack import pack_batch
     from authorino_tpu.expressions import All, Any_
     from authorino_tpu.ops.pattern_eval import (
+        _FUSED_FIELDS,
         dispatch_packed,
         eval_fused_jit,
         fuse_batch,
@@ -432,25 +436,39 @@ def test_fused_h2d_staging_matches_per_operand_path():
     )
 
     assert fused_h2d_supported()  # little-endian bitcast probe
-    rule = All(
-        Pattern("request.method", Operator.EQ, "GET"),
-        Any_(Pattern("auth.identity.roles", Operator.INCL, "admin"),
-             Pattern("request.url_path", Operator.MATCHES, r"^/api/v\d+")),
-    )
-    policy = compile_corpus(
-        [ConfigRules(name="c", evaluators=[(None, rule)])], members_k=4)
+    if corpus == "one-rule":
+        rule = All(
+            Pattern("request.method", Operator.EQ, "GET"),
+            Any_(Pattern("auth.identity.roles", Operator.INCL, "admin"),
+                 Pattern("request.url_path", Operator.MATCHES, r"^/api/v\d+")),
+        )
+        policy = compile_corpus(
+            [ConfigRules(name="c", evaluators=[(None, rule)])], members_k=4)
+        docs = [
+            {"request": {"method": "GET", "url_path": "/api/v1"},
+             "auth": {"identity": {"roles": ["admin"]}}},
+            {"request": {"method": "POST", "url_path": "/nope"},
+             "auth": {"identity": {"roles": ["dev"]}}},
+        ] * 6
+        rows = [0] * len(docs)
+    else:
+        from test_own_config_eval import all_operand_corpus, all_operand_docs
+
+        rng = random.Random(3)
+        policy = compile_corpus(all_operand_corpus(rng), members_k=4,
+                                ovf_assist=True)
+        docs = all_operand_docs(rng, n=12)
+        rows = [rng.randrange(policy.n_configs) for _ in docs]
     params = to_device(policy)
-    docs = [
-        {"request": {"method": "GET", "url_path": "/api/v1"},
-         "auth": {"identity": {"roles": ["admin"]}}},
-        {"request": {"method": "POST", "url_path": "/nope"},
-         "auth": {"identity": {"roles": ["dev"]}}},
-    ] * 6
-    enc = encode_batch(policy, docs, [0] * len(docs), batch_pad=16)
+    enc = encode_batch(policy, docs, rows, batch_pad=16)
     db = pack_batch(policy, enc)
     reference = np.asarray(dispatch_packed(params, db))
+    assert reference[:len(docs), 0].any() and not reference[:len(docs), 0].all()
     buf, layout = fuse_batch(db)
     assert buf.dtype == np.uint8 and buf.ndim == 1  # ONE staging buffer
+    if corpus == "all-operand-lanes":
+        # every operand the staging buffer can carry is in it
+        assert tuple(f[0] for f in layout) == _FUSED_FIELDS
     fused = np.asarray(eval_fused_jit(params, jnp.asarray(buf), layout))
     # the fused readback is the BIT-PACKED u8 bitmask (8 verdicts/byte);
     # decoding it must reproduce the per-operand bool result exactly
