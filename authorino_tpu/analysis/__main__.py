@@ -917,7 +917,7 @@ def _print_kernel_cost(report: dict) -> None:
     print("kernel cost ledger (structural, per lane):")
     cols = ("batches", "launches", "launches_per_batch",
             "zero_launch_batches", "rows", "device_rows", "pad_rows",
-            "pad_waste_rows", "h2d_bytes", "d2h_bytes",
+            "pad_waste_rows", "h2d_transfers", "h2d_bytes", "d2h_bytes",
             "dedup_avoided_rows", "cache_avoided_rows")
     print(f"  {'lane':<8}" + "".join(f" {c:>19}" for c in cols))
     for lane, lc in sorted(ledger.items()):

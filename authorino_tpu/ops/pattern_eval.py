@@ -117,8 +117,10 @@ from ..compiler.compile import (
 
 __all__ = ["DevicePolicy", "to_device", "eval_verdicts", "eval_own",
            "eval_batch_jit", "kernel_widths", "operand_bytes",
-           "fuse_batch", "eval_fused_jit", "dispatch_fused",
-           "fused_h2d_supported", "eval_bitpacked_jit", "unpack_verdicts",
+           "fuse_layout", "fuse_bytes", "fuse_batch", "eval_fused_jit",
+           "dispatch_fused",
+           "fused_h2d_supported", "eval_bitpacked_jit",
+           "eval_bitpacked_staged_jit", "unpack_verdicts",
            "packed_width", "firing_columns", "unpack_attribution",
            "kernel_lane_of"]
 
@@ -1019,24 +1021,34 @@ _FUSED_FIELDS = ("attrs_val", "members_c", "cpu_dense", "config_id",
                  "rel_rows", "member_ovf")
 
 
-def fuse_batch(db) -> Tuple[np.ndarray, tuple]:
-    """(staging buffer [N] uint8, static layout) for one DeviceBatch.  The
-    layout — (field, dtype, shape, offset, nbytes) per operand — is
-    hashable and static per (pad, eff) bucket, so it adds no jit variants
-    beyond the existing shape grid."""
-    segs = []
+def fuse_layout(fields) -> tuple:
+    """The static layout of one staging buffer: (field, dtype, shape,
+    offset, nbytes) per operand, laid end to end in the order given
+    (``fields`` yields (name, dtype, shape)).  Hashable, and a function of
+    the operand shapes alone, so one jit variant per (pad, eff) bucket as
+    without it."""
     layout = []
     off = 0
-    for name in _FUSED_FIELDS:
-        arr = getattr(db, name)
-        if arr is None:
-            continue
-        a = np.ascontiguousarray(arr)
-        flat = a.view(np.uint8).reshape(-1)
-        layout.append((name, str(a.dtype), tuple(a.shape), off, flat.size))
-        segs.append(flat)
-        off += flat.size
-    return np.concatenate(segs), tuple(layout)
+    for name, dtype, shape in fields:
+        shape = tuple(int(n) for n in shape)
+        size = int(np.prod(shape, dtype=np.int64)) * np.dtype(dtype).itemsize
+        layout.append((name, str(np.dtype(dtype)), shape, off, size))
+        off += size
+    return tuple(layout)
+
+
+def fuse_bytes(arrays) -> np.ndarray:
+    """The staging buffer itself: the (C-contiguous) operands' bytes end to
+    end in one uint8 array, as ``fuse_layout`` of their shapes lays them."""
+    return np.concatenate([a.view(np.uint8).reshape(-1) for a in arrays])
+
+
+def fuse_batch(db) -> Tuple[np.ndarray, tuple]:
+    """(staging buffer [N] uint8, static layout) for one DeviceBatch."""
+    arrs = [(name, np.ascontiguousarray(getattr(db, name)))
+            for name in _FUSED_FIELDS if getattr(db, name) is not None]
+    layout = fuse_layout((name, a.dtype, a.shape) for name, a in arrs)
+    return fuse_bytes(a for _, a in arrs), layout
 
 
 def staged_h2d_bytes(db) -> int:
@@ -1063,24 +1075,43 @@ def _defuse(buf, layout):
         elif dt == "uint8":
             out[name] = seg.reshape(shape)
         else:
+            # bytes -> ids as a flat [n, itemsize] -> [n], then the shape:
+            # with the byte axis behind the operand's own axes the TPU pads
+            # every row of 2 or 4 bytes to a whole tile, and the decode
+            # takes twice as long (PERF.md section 6, PR 31)
             npdt = np.dtype(dt)
             out[name] = jax.lax.bitcast_convert_type(
-                seg.reshape(shape + (npdt.itemsize,)), npdt)
+                seg.reshape(-1, npdt.itemsize), npdt).reshape(shape)
     return out
+
+
+def _eval_staged(params, buf, layout):
+    """Decode the staged operands and evaluate them: the body of both
+    single-staging-buffer entries, under the kernel's named scopes."""
+    with jax.named_scope("pattern_eval"):
+        with jax.named_scope("defuse"):
+            ops = _defuse(buf, layout)
+        packed = eval_packed_jit(
+            params, *(ops.get(name) for name in _FUSED_FIELDS))
+        with jax.named_scope("bitpack"):
+            return _bitpack_rows(packed)
 
 
 @partial(jax.jit, static_argnames=("layout",))
 def eval_fused_jit(params, buf, layout):
     """eval over a fused staging buffer: ONE H2D transfer in, one
     bit-packed [B, ceil((1+2E)/8)] uint8 readback out (decode host-side
-    with ``unpack_verdicts``)."""
-    ops = _defuse(buf, layout)
-    return _bitpack_rows(eval_packed_jit(
-        params, ops["attrs_val"], ops["members_c"], ops["cpu_dense"],
-        ops["config_id"], ops.get("attr_bytes"), ops.get("byte_ovf"),
-        ops.get("attrs_num"), ops.get("num_valid"), ops.get("rel_rows"),
-        ops.get("member_ovf"),
-    ))
+    with ``unpack_verdicts``).  The engine lane's entry."""
+    return _eval_staged(params, buf, layout)
+
+
+@partial(jax.jit, static_argnames=("layout",))
+def eval_bitpacked_staged_jit(params, buf, layout):
+    """What the native lane serves: ``eval_bitpacked_jit`` with its
+    operands decoded on the device out of ONE staged uint8 buffer (layout
+    from ``fuse_layout``, static per (pad, eff)).  A function of its own
+    so that a device trace names the served module."""
+    return _eval_staged(params, buf, layout)
 
 
 _FUSED_OK: Optional[bool] = None

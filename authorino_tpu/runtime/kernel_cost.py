@@ -49,7 +49,7 @@ REGRESSION_FACTOR = 2.0
 
 _FIELDS = (
     "batches", "launches", "zero_launch_batches", "rows", "device_rows",
-    "h2d_bytes", "d2h_bytes", "pad_rows", "pad_waste_rows",
+    "h2d_transfers", "h2d_bytes", "d2h_bytes", "pad_rows", "pad_waste_rows",
     "eff_slack_cols", "dedup_avoided_rows", "cache_avoided_rows",
 )
 
@@ -83,14 +83,18 @@ class CostLedger:
         self._lanes: Dict[str, _LaneCost] = {}
 
     def observe(self, lane: str, *, rows: int, device_rows: int = 0,
-                launches: int = 0, h2d_bytes: int = 0, d2h_bytes: int = 0,
+                launches: int = 0, h2d_transfers: int = 0,
+                h2d_bytes: int = 0, d2h_bytes: int = 0,
                 pad_rows: int = 0, eff_slack_cols: int = 0,
                 dedup_avoided_rows: int = 0,
                 cache_avoided_rows: int = 0) -> None:
         """Fold one batch: ``rows`` real requests in the cut, of which
         ``device_rows`` actually shipped (``pad_rows`` after padding) in
-        ``launches`` device calls.  Host/degrade evals and fully cache/
-        dedup-resolved cuts fold with launches=0 and zero byte counts.
+        ``launches`` device calls, their request operands handed to the
+        runtime in ``h2d_transfers`` host-to-device transfers (1 when the
+        launch staged one buffer, one an operand otherwise).  Host/degrade
+        evals and fully cache/dedup-resolved cuts fold with launches=0 and
+        zero byte counts.
         The mesh lane folds its batch here with launches=0 and counts the
         actual shard-step launches at the dispatch site instead
         (``observe_launch``) — failover re-dispatches then show up as
@@ -106,6 +110,7 @@ class CostLedger:
                 lc.zero_launch_batches += 1
             lc.rows += rows
             lc.device_rows += device_rows
+            lc.h2d_transfers += h2d_transfers
             lc.h2d_bytes += h2d_bytes
             lc.d2h_bytes += d2h_bytes
             lc.pad_rows += pad_rows
@@ -214,8 +219,9 @@ def modeled_entry_cost(entry: str, fn, args: tuple, pad: int,
 
 def _bitpacked_zero_args(policy, params, pad: int, eff: int) -> tuple:
     """Throwaway zero operands for eval_bitpacked_jit at one (pad, eff)
-    bucket — the _warm_one recipe, shapes only (PR 14 operand tail rides
-    on the params' structural Nones)."""
+    bucket, shapes only (PR 14 operand tail rides on the params'
+    structural Nones): what the cost model lowers it with, and the native
+    lane's warm grid where it compiles the six-operand entry."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -278,6 +284,13 @@ def entry_points(policy=None, sharded=None) -> List[Dict[str, Any]]:
             "kind": "single fused H2D staging buffer (same compute as "
                     "eval_bitpacked; per-operand fallback when the "
                     "backend bitcast probe fails)",
+            "operands": ops,
+        })
+        out.append({
+            "entry": "eval_bitpacked_staged",
+            "kind": "the native lane's served entry: one staged uint8 "
+                    "buffer decoded on the device (same compute as "
+                    "eval_bitpacked, which serves when the probe fails)",
             "operands": ops,
         })
     return out

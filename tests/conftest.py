@@ -30,3 +30,14 @@ def mesh_devices():
         "(XLA_FLAGS=--xla_force_host_platform_device_count=8 was exported "
         f"too late?), got {len(devices)}")
     return devices[:8]
+
+
+def pytest_configure(config):
+    """Build the native library once, on the controller, before xdist starts
+    its workers: on a tree with no ``_build/`` six workers would otherwise
+    all compile into the same ``_atpuenc.so.tmp``, most would lose, and the
+    native tests of those workers would skip (or fail) for the whole run."""
+    if not hasattr(config, "workerinput"):
+        from authorino_tpu.native import load_library
+
+        load_library()

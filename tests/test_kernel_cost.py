@@ -220,7 +220,8 @@ class TestEngineLane:
         assert set(kc) == {"ledger", "modeled", "entry_points"}
         assert kc["ledger"].keys() <= {"engine", "host", "mesh", "native"}
         names = [e["entry"] for e in kc["entry_points"]]
-        assert names == ["eval_bitpacked", "eval_fused"]
+        assert names == ["eval_bitpacked", "eval_fused",
+                         "eval_bitpacked_staged"]
         for e in kc["entry_points"]:
             assert e["operands"][:4] == ["attrs_val", "members_c",
                                          "cpu_dense", "config_id"]
@@ -379,7 +380,8 @@ class TestEntryPointAudit:
             Pattern("m", Operator.EQ, "GET"))],
             members_k=4, ovf_assist=False)
         ep = entry_points(policy=pol)
-        assert [e["entry"] for e in ep] == ["eval_bitpacked", "eval_fused"]
+        assert [e["entry"] for e in ep] == [
+            "eval_bitpacked", "eval_fused", "eval_bitpacked_staged"]
         for e in ep:
             assert e["operands"] == ["attrs_val", "members_c",
                                      "cpu_dense", "config_id"]

@@ -1589,15 +1589,16 @@ def test_differential_vs_python_server(stack):
 
 def test_debug_vars_reports_what_the_served_kernel_scans(stack):
     """ISSUE 26: snapshot.kernel names the DFA rows one request row has
-    scanned (its own config's, D) beside the corpus's (R); the entry keeps
-    the name the trace reader finds its XLA module by."""
+    scanned (its own config's, D) beside the corpus's (R); the entry is
+    the name the trace reader finds the served XLA module by."""
     engine, fe, _, _ = stack
     assert fe.wait_warm(180) and fe.warm_error is None
     policy = engine._snapshot.policy
     R = int(policy.dfa_table_of_row.shape[0]) if policy.n_byte_attrs else 0
     D = int(policy.config_dfa_rows.shape[1]) if policy.n_byte_attrs else 0
     kernel = fe.debug_vars()["snapshot"]["kernel"]
-    assert kernel["entry"] == "eval_bitpacked" and kernel["lane"] == "matmul"
+    assert kernel["entry"] == "eval_bitpacked_staged"
+    assert kernel["lane"] == "matmul"
     assert kernel["dfa_rows_total"] == R
     assert kernel["dfa_rows_per_row"] == D <= R
 
@@ -1687,7 +1688,7 @@ def test_warm_time_lowering_failure_is_not_ready(stack, monkeypatch):
     try:
         # the swap gate (largest shape, compiled before the swap goes live)
         with monkeypatch.context() as m:
-            m.setattr(pattern_eval, "eval_bitpacked_jit", refuse)
+            m.setattr(pattern_eval, "eval_bitpacked_staged_jit", refuse)
             fe.refresh()
         assert fe.wait_warm(30) is False
         snap = fe.debug_vars()["snapshot"]
@@ -1699,7 +1700,7 @@ def test_warm_time_lowering_failure_is_not_ready(stack, monkeypatch):
 
         # the background rest of the grid: the gate compiles, a later
         # shape does not
-        real = pattern_eval.eval_bitpacked_jit
+        real = pattern_eval.eval_bitpacked_staged_jit
         calls = []
 
         def second_refuses(*a, **k):
@@ -1709,7 +1710,8 @@ def test_warm_time_lowering_failure_is_not_ready(stack, monkeypatch):
             return real(*a, **k)
 
         with monkeypatch.context() as m:
-            m.setattr(pattern_eval, "eval_bitpacked_jit", second_refuses)
+            m.setattr(pattern_eval, "eval_bitpacked_staged_jit",
+                      second_refuses)
             fe.refresh()
             assert fe.wait_warm(60) is False
         snap = fe.debug_vars()["snapshot"]
