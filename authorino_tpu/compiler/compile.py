@@ -642,8 +642,10 @@ class _Lowerer:
         hit = self._dfa_cache.get(pattern, _DFA_MISS)
         if hit is not _DFA_MISS:
             return hit
-        from .redfa import compile_regex_dfa
+        from .redfa import compile_regex_dfa, reserve_memo
 
+        # the process-wide memo keeps room for the corpus being lowered
+        reserve_memo(len(self._dfa_cache) + 1)
         dfa = compile_regex_dfa(pattern)
         self._dfa_cache[pattern] = dfa
         return dfa

@@ -28,7 +28,7 @@ from typing import Dict, FrozenSet, List, Optional, Set, Tuple
 
 import numpy as np
 
-__all__ = ["DFA", "compile_regex_dfa", "MAX_STATES"]
+__all__ = ["DFA", "compile_regex_dfa", "reserve_memo", "MAX_STATES"]
 
 MAX_STATES = 96
 MAX_REPEAT = 16
@@ -345,6 +345,29 @@ def _eps_closure(nfa: _NFA, states: FrozenSet[int]) -> FrozenSet[int]:
     return frozenset(seen)
 
 
+_NO_TARGETS: FrozenSet[int] = frozenset()
+
+
+def _byte_classes(nfa: _NFA) -> Tuple[np.ndarray, List[int]]:
+    """([256] class id of each byte, the first byte of each class): two bytes
+    share a class when every NFA state sends them to the same target set.
+    Ids go in the order of each class's first byte from byte 1 on; byte 0,
+    the pad byte, rides class 0 and the caller overwrites its column."""
+    sig: List[List[Tuple[int, int]]] = [[] for _ in range(256)]
+    for s, by_byte in enumerate(nfa.trans):
+        groups: Dict[FrozenSet[int], int] = {}
+        for b, targets in by_byte.items():
+            sig[b].append((s, groups.setdefault(frozenset(targets), len(groups))))
+    ids: Dict[Tuple[Tuple[int, int], ...], int] = {}
+    firsts: List[int] = []
+    out = [0] * 256
+    for b in range(1, 256):
+        out[b] = ids.setdefault(tuple(sig[b]), len(ids))
+        if out[b] == len(firsts):
+            firsts.append(b)
+    return np.asarray(out, dtype=np.int64), firsts
+
+
 # process-wide determinization memo: subset construction is the most
 # expensive compile step, and reconcile-time snapshot rebuilds re-lower the
 # same patterns over and over.  The per-compile dfa_cache (compiler/
@@ -354,8 +377,17 @@ def _eps_closure(nfa: _NFA, states: FrozenSet[int]) -> FrozenSet[int]:
 # object across snapshots is safe — and it is exactly what lets the
 # compiler's table dedup collapse identical patterns to one [S, 256] table.
 _DFA_MEMO: Dict[str, Optional[DFA]] = {}
+# the bound follows the largest corpus compiled (``reserve_memo``): a memo
+# smaller than one corpus forgets most of it before the corpus is done
 _DFA_MEMO_MAX = 8192
 _DFA_MEMO_MISS = object()
+
+
+def reserve_memo(n_patterns: int) -> None:
+    """Make room for a corpus of ``n_patterns`` regexes, twice over (the
+    outgoing snapshot's and the incoming one's)."""
+    global _DFA_MEMO_MAX
+    _DFA_MEMO_MAX = max(_DFA_MEMO_MAX, 2 * n_patterns)
 
 
 def compile_regex_dfa(pattern: str) -> Optional[DFA]:
@@ -366,8 +398,10 @@ def compile_regex_dfa(pattern: str) -> Optional[DFA]:
     if hit is not _DFA_MEMO_MISS:
         return hit
     dfa = _compile_regex_dfa(pattern)
-    if len(_DFA_MEMO) >= _DFA_MEMO_MAX:  # unbounded hostile corpora: reset
-        _DFA_MEMO.clear()
+    if len(_DFA_MEMO) >= _DFA_MEMO_MAX:
+        # unbounded hostile corpora: forget the older half (insertion order)
+        for stale in list(_DFA_MEMO)[: len(_DFA_MEMO) // 2]:
+            del _DFA_MEMO[stale]
     _DFA_MEMO[pattern] = dfa
     return dfa
 
@@ -386,32 +420,39 @@ def _compile_regex_dfa(pattern: str) -> Optional[DFA]:
         nfa.add_eps(frag_e, accept_state)
         start_set = _eps_closure(nfa, frozenset([frag_s]))
 
-        # subset construction; unanchored start = self-loop on every byte
+        # subset construction; unanchored start = self-loop on every byte.
+        # Bytes that every NFA state sends to the same targets are one class
+        # (a path regex has a dozen or two), and a DFA state's successor is
+        # worked out once a class: the first byte of a class is met in the
+        # order the bytes are, so states are numbered as a byte-by-byte
+        # walk numbers them.
+        byte_class, class_firsts = _byte_classes(nfa)
         dfa_states: Dict[FrozenSet[int], int] = {start_set: 0}
         order: List[FrozenSet[int]] = [start_set]
         trans_rows: List[np.ndarray] = []
         i = 0
         while i < len(order):
             cur = order[i]
-            row = np.zeros(256, dtype=np.int64)
             cur_accepting = accept_state in cur
-            for b in range(1, 256):
+            of_class: List[int] = []
+            for b in class_firsts:
                 if cur_accepting and not anchored_end:
                     # absorbing accept (search semantics: match found)
                     nxt = cur
                 else:
                     targets: Set[int] = set()
                     for s in cur:
-                        targets |= nfa.trans[s].get(b, set())
+                        targets |= nfa.trans[s].get(b, _NO_TARGETS)
                     if not anchored_start:
-                        targets |= set(start_set)  # implicit leading .*
+                        targets |= start_set  # implicit leading .*
                     nxt = _eps_closure(nfa, frozenset(targets)) if targets else frozenset()
                 if nxt not in dfa_states:
                     dfa_states[nxt] = len(order)
                     order.append(nxt)
                     if len(order) > MAX_STATES:
                         return None
-                row[b] = dfa_states[nxt]
+                of_class.append(dfa_states[nxt])
+            row = np.asarray(of_class, dtype=np.int64)[byte_class]
             row[0] = i  # pad byte: identity self-loop
             trans_rows.append(row)
             i += 1

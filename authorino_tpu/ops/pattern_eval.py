@@ -29,9 +29,10 @@ Three bodies, one selection:
     [B, D] rows the config reaches: their [S, 256] tables are gathered once
     a launch from the deduplicated ``dfa_tables``, one batched matmul with
     the byte one-hots gives every byte position's S -> S map, the
-    ``lax.scan`` carries [B, D], and the accepts feed the own leaves
-    directly.  Reads inside a row (attribute of a leaf, child of a node) are
-    one-hot mask-reduces over the small own axes — integer-exact, no gather.
+    ``lax.scan`` carries [D, B] (the batch on the lanes), and the accepts
+    feed the own leaves directly.  Reads inside a row (attribute of a leaf,
+    child of a node) are one-hot mask-reduces over the small own axes —
+    integer-exact, no gather.
     Nothing in it grows with the corpus but the tables it gathers one row
     from.
   - ``_eval_verdicts_matmul`` is the dense body of the callers that want
@@ -433,15 +434,18 @@ def kernel_widths(params, own: bool = True) -> dict:
     is the corpus's R and ``dfa_rows_per_row`` what one request row has
     scanned (D on ``eval_own``, R on a dense body; both 0 without a device
     DFA lane); ``leaf_cols_per_row`` is the leaf columns evaluated for it
-    (l_own, or L while dense).  ``own``: an entry that returns own-config
+    (l_own, or L while dense); ``dfa_states`` is S, the state axis of the
+    served table store (every table padded to the largest DFA's count, in
+    whole tiles of 8).  ``own``: an entry that returns own-config
     results (False: the mesh step, which is dense)."""
-    out = {"dfa_rows_per_row": 0, "dfa_rows_total": 0,
+    out = {"dfa_rows_per_row": 0, "dfa_rows_total": 0, "dfa_states": 0,
            "leaf_cols_per_row": int(params["own"]["leaf"].shape[-1]) // OWN_FIELDS if own
            else int(params["leaf_op"].shape[-1])}
     if params.get("dfa_tables") is not None:
         R = int(params["dfa_table_of_row"].shape[-1])
         out.update(dfa_rows_total=R, dfa_rows_per_row=int(
-            params["config_dfa_rows"].shape[-1]) if own else R)
+            params["config_dfa_rows"].shape[-1]) if own else R,
+            dfa_states=int(params["dfa_tables"].shape[-2]))
     return out
 
 
@@ -465,8 +469,15 @@ def _pick(x, idx):
 def _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt):
     """Own-row DFA scan: evaluates only the DFA rows ``config_dfa_rows[cfg]``
     names and returns their accepts [B, D], False on the -1 padding and on
-    rows whose config id was out of range (``cfg`` is the clipped id)."""
-    f32 = jnp.float32
+    rows whose config id was out of range (``cfg`` is the clipped id).
+
+    The sequential part keeps the batch on the minor axis: its carry is
+    [D, B] and a step reads [D, S, B], so B fills the lanes and S (whole
+    tiles of 8, compiler/compile.py) the sublanes with no padding, and the
+    per-step reduce over S stays inside a lane.  With [B, D, S] a step's
+    two minor axes (18 x 72) were padded to 24 x 128 and reduced across
+    lanes: 2.9 ms a launch of 256 rows at D 18, S 72 against 0.8 ms (chip
+    micro-run, PERF.md section 6, PR 32)."""
     tables = params["dfa_tables"]                            # [T, S, 256] u8
     S = tables.shape[1]
     own = jnp.where(in_range[:, None],
@@ -479,26 +490,27 @@ def _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt):
     # whole [S, 256] tables fetched once a launch, from the deduped axis
     own_tables = jnp.take(tables, tab, axis=0)               # [B, D, S, 256] u8
     # every byte position's S -> S transition map at once (next-state values
-    # <= 255 and 0/1 one-hots: exact in bf16), so the sequential part below
-    # carries [B, D] and touches [B, D, S] a step
+    # <= 255 and 0/1 one-hots: one non-zero term a sum, exact in bf16, maps
+    # and carry alike), so the sequential part below carries [D, B] and
+    # touches [D, S, B] a step
     byte_oh = own_bytes[..., None] == jnp.arange(256, dtype=own_bytes.dtype)
     step_maps = jnp.einsum(
-        "bdsc,bdlc->lbds", own_tables.astype(cdt), byte_oh.astype(cdt),
-        preferred_element_type=f32)                          # [LB, B, D, S]
-    iota_s = jnp.arange(S, dtype=f32)
+        "bdsc,bdlc->ldsb", own_tables.astype(cdt), byte_oh.astype(cdt),
+        preferred_element_type=cdt)                          # [LB, D, S, B]
+    iota_s = jnp.arange(S, dtype=cdt)
 
-    def dfa_step(state, step_map):  # state [B, D] f32; step_map [B, D, S] f32
-        nxt = jnp.sum(
-            jnp.where(state[..., None] == iota_s, step_map, 0.0), axis=-1)
-        return nxt, None
+    def dfa_step(state, step_map):  # state [D, B]; step_map [D, S, B]
+        nxt = jnp.sum(jnp.where(
+            state[:, None, :] == iota_s[:, None], step_map, 0), axis=1)
+        return nxt.astype(cdt), None
 
     # init carry derived from a varying input (zero-multiplied) so its
     # manual-mesh "varying" type matches inside shard_map
-    init = own_bytes[:, :, 0].astype(f32) * 0.0
+    init = (own_bytes[:, :, 0].astype(cdt) * 0).T
     final, _ = jax.lax.scan(dfa_step, init, step_maps)
     accept = jnp.take(params["dfa_accept"], tab, axis=0)     # [B, D, S] bool
     return (own >= 0) & jnp.any(
-        accept & (final[..., None] == iota_s), axis=-1)      # [B, D]
+        accept & (final.T[..., None] == iota_s), axis=-1)    # [B, D]
 
 
 def eval_own(params, attrs_val, members_c, cpu_dense, config_id,

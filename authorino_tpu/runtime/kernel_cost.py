@@ -51,6 +51,7 @@ _FIELDS = (
     "batches", "launches", "zero_launch_batches", "rows", "device_rows",
     "h2d_transfers", "h2d_bytes", "d2h_bytes", "pad_rows", "pad_waste_rows",
     "eff_slack_cols", "dedup_avoided_rows", "cache_avoided_rows",
+    "dfa_ovf_rows",
 )
 
 
@@ -87,12 +88,16 @@ class CostLedger:
                 h2d_bytes: int = 0, d2h_bytes: int = 0,
                 pad_rows: int = 0, eff_slack_cols: int = 0,
                 dedup_avoided_rows: int = 0,
-                cache_avoided_rows: int = 0) -> None:
+                cache_avoided_rows: int = 0,
+                dfa_ovf_rows: int = 0) -> None:
         """Fold one batch: ``rows`` real requests in the cut, of which
         ``device_rows`` actually shipped (``pad_rows`` after padding) in
         ``launches`` device calls, their request operands handed to the
         runtime in ``h2d_transfers`` host-to-device transfers (1 when the
-        launch staged one buffer, one an operand otherwise).  Host/degrade
+        launch staged one buffer, one an operand otherwise);
+        ``dfa_ovf_rows`` of the cut's rows carried a value past
+        DFA_VALUE_BYTES, whose DFAs the encoder scanned on the host
+        (the native lane counts them).  Host/degrade
         evals and fully cache/dedup-resolved cuts fold with launches=0 and
         zero byte counts.
         The mesh lane folds its batch here with launches=0 and counts the
@@ -118,6 +123,7 @@ class CostLedger:
             lc.eff_slack_cols += eff_slack_cols
             lc.dedup_avoided_rows += dedup_avoided_rows
             lc.cache_avoided_rows += cache_avoided_rows
+            lc.dfa_ovf_rows += dfa_ovf_rows
         metrics_mod.observe_kernel_cost(
             lane, launches, h2d_bytes, d2h_bytes, pad_waste)
 
@@ -190,6 +196,19 @@ def _cost_numbers(compiled) -> Tuple[float, float]:
         return 0.0, 0.0
     return (float(ca.get("flops", 0.0) or 0.0),
             float(ca.get("bytes accessed", 0.0) or 0.0))
+
+
+def launch_temp_bytes(fn, *args) -> int:
+    """Bytes of temporaries one launch of jitted ``fn`` allocates at these
+    operands, from the compiled entry's ``memory_analysis()``; 0 where the
+    backend gives none.  The lowering it compiles is the one the jitted
+    call then finds compiled."""
+    try:
+        analysis = fn.lower(*args).compile().memory_analysis()
+        return int(getattr(analysis, "temp_size_in_bytes", 0) or 0)
+    except Exception as e:  # pragma: no cover - backend-dependent
+        log.debug("memory_analysis unavailable: %r", e)
+        return 0
 
 
 def modeled_entry_cost(entry: str, fn, args: tuple, pad: int,

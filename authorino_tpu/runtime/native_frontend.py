@@ -77,7 +77,8 @@ from .batch_stages import StageClock
 from .breaker import CircuitBreaker
 from .flight_recorder import RECORDER
 from . import kernel_cost as kernel_cost_mod
-from .kernel_cost import LEDGER, CostModel, _bitpacked_zero_args
+from .kernel_cost import (LEDGER, CostModel, _bitpacked_zero_args,
+                          launch_temp_bytes)
 from .lane_select import DEVICE as L_DEVICE, HOST as L_HOST, LaneSelector
 
 log = logging.getLogger("authorino_tpu.native_frontend")
@@ -613,6 +614,9 @@ class _SnapRec:
     # ref pkg/evaluators/authorization/opa.go:141)
     warm: set = field(default_factory=set)
     warm_done: threading.Event = field(default_factory=threading.Event)
+    # temporaries one launch of the largest warm variant allocates, from the
+    # compiled entry's memory_analysis() at the swap gate (0: not given)
+    launch_temp_bytes: int = 0
     # (batch_pad, byte_eff) -> the static layout of that variant's staging
     # buffer (_stage_layout): built once, read by the warm grid and by
     # every launch, so both name the same jit variant
@@ -1141,8 +1145,11 @@ class NativeFrontend:
                 # bytes of the serving snapshot's device operands, summed
                 # over the uploaded pytree
                 "operand_bytes": operand_bytes(rec.params),
+                # temporaries of one launch of the largest warm variant
+                "launch_temp_bytes": rec.launch_temp_bytes,
                 # what the served entry evaluates for ONE request row (its
-                # own config's leaves and DFA rows) against the corpus's
+                # own config's leaves and DFA rows) against the corpus's,
+                # and the state axis of the DFA table store
                 **kernel_widths(rec.params)}
 
     @property
@@ -1299,7 +1306,9 @@ class NativeFrontend:
 
     def _warm_one(self, rec: _SnapRec, pad: int, eff: int) -> None:
         """Compile (and cache) the jit variant for one bucket shape using
-        throwaway zero operands."""
+        throwaway zero operands.  The snapshot's first (the swap gate's: the
+        largest variant) also records what one launch of it allocates in
+        temporaries (``rec.launch_temp_bytes``)."""
         import jax
         import jax.numpy as jnp
 
@@ -1329,12 +1338,15 @@ class NativeFrontend:
         layout = self._stage_layout(rec, pad, eff)
         if layout is not None:
             size = layout[-1][3] + layout[-1][4]
-            out = eval_bitpacked_staged_jit(
-                rec.params, jnp.asarray(np.zeros(size, dtype=np.uint8)),
-                layout)
+            fn = eval_bitpacked_staged_jit
+            args = (rec.params, jnp.asarray(np.zeros(size, dtype=np.uint8)),
+                    layout)
         else:
-            out = eval_bitpacked_jit(
-                *_bitpacked_zero_args(rec.policy, rec.params, pad, eff))
+            fn = eval_bitpacked_jit
+            args = _bitpacked_zero_args(rec.policy, rec.params, pad, eff)
+        if not rec.warm:
+            rec.launch_temp_bytes = launch_temp_bytes(fn, *args)
+        out = fn(*args)
         jax.block_until_ready(out)
         rec.warm.add((pad, eff))
 
@@ -2093,12 +2105,13 @@ class NativeFrontend:
     def _dispatch_loop(self) -> None:
         mod = self._mod
         while self._running:
-            kind, a, b, c, flush_ns = mod.fe_wait_batch(200)
+            kind, a, b, c, flush_ns, ovf_rows = mod.fe_wait_batch(200)
             self._fold_fc_counts()
             if kind == EV_BATCH:
                 try:
                     self._dispatch(int(a), int(b), int(c),
-                                   flush_ns=int(flush_ns))
+                                   flush_ns=int(flush_ns),
+                                   ovf_rows=int(ovf_rows))
                 except Exception as e:
                     log.exception("native batch dispatch failed")
                     # retry once, then degrade (CPU-backend kernel) — fail
@@ -2190,7 +2203,7 @@ class NativeFrontend:
 
     def _dispatch(self, snap_id: int, slot: int, count: int,
                   attempt: int = 0, spill: bool = True,
-                  flush_ns: int = 0) -> None:
+                  flush_ns: int = 0, ovf_rows: int = 0) -> None:
         """Launch stage: non-blocking kernel dispatch for one C++-encoded
         slot, then park the in-flight batch on the readback queue.  The
         dispatcher thread is immediately free to launch the next slot, so
@@ -2208,7 +2221,10 @@ class NativeFrontend:
         one retry after a device failure); an OPEN circuit breaker skips
         the device entirely and decides the slot on the CPU backend.
         ``flush_ns`` is when the C++ front end cut the slot (0 on a retry):
-        the start of the batch's stage clock (runtime/batch_stages.py)."""
+        the start of the batch's stage clock (runtime/batch_stages.py);
+        ``ovf_rows`` how many of the cut's rows carried a value past
+        DFA_VALUE_BYTES, as the encoder counted them (0 on a retry: the
+        ledger's ``dfa_ovf_rows`` counts a cut once)."""
         import jax.numpy as jnp
 
         from ..ops.pattern_eval import (eval_bitpacked_jit,
@@ -2321,7 +2337,8 @@ class NativeFrontend:
             LEDGER.observe(
                 cost_lane, rows=count,
                 dedup_avoided_rows=(len(fan[3]) if fan is not None else 0),
-                cache_avoided_rows=(len(fan[2]) if fan is not None else 0))
+                cache_avoided_rows=(len(fan[2]) if fan is not None else 0),
+                dfa_ovf_rows=ovf_rows)
         else:
             with bt.stage("encode"):
                 eff_need = (_trim_bytes(a["attr_bytes"][:count] if u == count
@@ -2396,7 +2413,8 @@ class NativeFrontend:
                         dedup_avoided_rows=(len(fan[3]) - u
                                             if fan is not None else 0),
                         cache_avoided_rows=(len(fan[2])
-                                            if fan is not None else 0))
+                                            if fan is not None else 0),
+                        dfa_ovf_rows=ovf_rows)
                 else:
                     LEDGER.observe(
                         "native", rows=count, device_rows=u, launches=1,
@@ -2408,7 +2426,8 @@ class NativeFrontend:
                         dedup_avoided_rows=(len(fan[3]) - u
                                             if fan is not None else 0),
                         cache_avoided_rows=(len(fan[2])
-                                            if fan is not None else 0))
+                                            if fan is not None else 0),
+                        dfa_ovf_rows=ovf_rows)
         with self._rb_lock:
             self._rb_inflight += 1
             if self._rb_inflight > self.rb_inflight_peak:

@@ -150,7 +150,7 @@ class CompileCache:
 
     def _build(self, fp: str, cfg: ConfigRules) -> ConfigArtifact:
         from ..compiler.compile import _has_invalid_regex
-        from ..compiler.redfa import compile_regex_dfa
+        from ..compiler.redfa import compile_regex_dfa, reserve_memo
 
         patterns: set = set()
         for cond, rule in cfg.evaluators:
@@ -165,6 +165,7 @@ class CompileCache:
                 self._intern_consts(expr)
         for pat in patterns:
             if pat not in self.dfa_cache:
+                reserve_memo(len(self.dfa_cache) + 1)
                 try:
                     self.dfa_cache[pat] = compile_regex_dfa(pat)
                 except Exception:

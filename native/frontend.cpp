@@ -621,8 +621,10 @@ struct SlowPending {
 // events to Python
 enum EvKind { EV_TIMEOUT = 0, EV_BATCH = 1, EV_SNAP_RETIRED = 3, EV_STOPPED = 4 };
 // d: for EV_BATCH, the slot's flush time (CLOCK_MONOTONIC ns, the clock of
-// Python's time.monotonic_ns()) — the start of the batch's `pickup` stage
-struct Event { int kind; int64_t a, b, c, d; };
+// Python's time.monotonic_ns()) — the start of the batch's `pickup` stage;
+// e: for EV_BATCH, the cut's rows with at least one value past DVB, whose
+// DFAs the encoder scanned here (the ledger's `dfa_ovf_rows`)
+struct Event { int kind; int64_t a, b, c, d, e; };
 
 struct Server {
   // config
@@ -653,6 +655,8 @@ struct Server {
   // current filling batch (epoll thread only, but slot recycle under mu)
   int fill_slot = -1;
   int fill_count = 0;
+  int fill_ovf_rows = 0;      // rows of the filling batch with an overflowed value
+  bool fill_row_ovf = false;  // the row being encoded has one
   std::shared_ptr<Snapshot> fill_snap;
   bool timer_armed = false;
 
@@ -948,6 +952,7 @@ static bool encode_fast(Server* S, Snapshot* snap, Slot& sl, int b,
       if (ovf) {
         sl.byte_ovf[bs * NB + bslot] = 1;
         S->n_dfa_ovf.fetch_add(1, std::memory_order_relaxed);
+        S->fill_row_ovf = true;
         // exact host evaluation of every DFA leaf of this config reading
         // this attr (the DFA is length-agnostic; only the device tensor is
         // fixed-width)
@@ -1021,6 +1026,7 @@ static void flush_batch(Server* S, bool from_timer = false) {
   }
   std::shared_ptr<Snapshot> snap = S->fill_snap;
   int slot = S->fill_slot, count = S->fill_count;
+  const int ovf_rows = S->fill_ovf_rows;
   std::vector<int64_t> retired;
   bool flushed = false;
   int64_t flush_ns = 0;
@@ -1049,6 +1055,7 @@ static void flush_batch(Server* S, bool from_timer = false) {
       snap->pending_batches++;
       S->fill_slot = -1;
       S->fill_count = 0;
+      S->fill_ovf_rows = 0;
       S->fill_snap.reset();
       flushed = true;
     }
@@ -1062,7 +1069,7 @@ static void flush_batch(Server* S, bool from_timer = false) {
   if (flushed) {
     {
       std::lock_guard<std::mutex> lk(S->batch_mu);
-      S->batch_events.push_back({EV_BATCH, snap->id, slot, count, flush_ns});
+      S->batch_events.push_back({EV_BATCH, snap->id, slot, count, flush_ns, ovf_rows});
     }
     S->batch_cv.notify_all();
   }
@@ -1085,6 +1092,7 @@ static Slot* ensure_fill(Server* S, std::shared_ptr<Snapshot>& snap_out) {
     cur->free_slots.pop_back();
     S->fill_snap = cur;
     S->fill_count = 0;
+    S->fill_ovf_rows = 0;
     cur->slot_entries[S->fill_slot].clear();
   }
   snap_out = S->fill_snap;
@@ -1326,10 +1334,12 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
   }
   int b = S->fill_count;
   zero_row(snap.get(), *sl, b);
+  S->fill_row_ovf = false;
   if (!encode_fast(S, snap.get(), *sl, b, fc, extra, rv)) {
     push_slow(S, c, stream_id, msg, mlen);
     return;
   }
+  if (S->fill_row_ovf) S->fill_ovf_rows++;
   snap->slot_entries[S->fill_slot].push_back(
       {c->id, stream_id, fc_idx, t_start, ok_override, std::move(ok_hold),
        deny_override, std::move(deny_hold),
@@ -1598,7 +1608,7 @@ static void epoll_loop(Server* S) {
   }
   {
     std::lock_guard<std::mutex> lk(S->batch_mu);
-    S->batch_events.push_back({EV_STOPPED, 0, 0, 0, 0});
+    S->batch_events.push_back({EV_STOPPED, 0, 0, 0, 0, 0});
   }
   S->batch_cv.notify_all();
   S->slow_cv.notify_all();
@@ -1757,7 +1767,7 @@ static void emit_retired(Server* S, const std::vector<int64_t>& retired) {
   if (retired.empty()) return;
   {
     std::lock_guard<std::mutex> lk(S->batch_mu);
-    for (int64_t id : retired) S->batch_events.push_back({EV_SNAP_RETIRED, id, 0, 0, 0});
+    for (int64_t id : retired) S->batch_events.push_back({EV_SNAP_RETIRED, id, 0, 0, 0, 0});
   }
   S->batch_cv.notify_all();
 }

@@ -559,14 +559,14 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
   return PyLong_FromLong(0);
 }
 
-// fe_wait_batch(timeout_ms) -> (kind, a, b, c, d)
+// fe_wait_batch(timeout_ms) -> (kind, a, b, c, d, e)
 PyObject* fe_wait_batch_py(PyObject*, PyObject* args) {
   long timeout_ms;
   if (!PyArg_ParseTuple(args, "l", &timeout_ms)) return nullptr;
   fe::Server* S = fe::g_srv;
   if (S == nullptr)
-    return Py_BuildValue("(iLLLL)", (int)fe::EV_STOPPED, 0LL, 0LL, 0LL, 0LL);
-  fe::Event ev = {fe::EV_TIMEOUT, 0, 0, 0, 0};
+    return Py_BuildValue("(iLLLLL)", (int)fe::EV_STOPPED, 0LL, 0LL, 0LL, 0LL, 0LL);
+  fe::Event ev = {fe::EV_TIMEOUT, 0, 0, 0, 0, 0};
   Py_BEGIN_ALLOW_THREADS {
     std::unique_lock<std::mutex> lk(S->batch_mu);
     if (S->batch_events.empty())
@@ -578,8 +578,8 @@ PyObject* fe_wait_batch_py(PyObject*, PyObject* args) {
     }
   }
   Py_END_ALLOW_THREADS
-  return Py_BuildValue("(iLLLL)", ev.kind, (long long)ev.a, (long long)ev.b,
-                       (long long)ev.c, (long long)ev.d);
+  return Py_BuildValue("(iLLLLL)", ev.kind, (long long)ev.a, (long long)ev.b,
+                       (long long)ev.c, (long long)ev.d, (long long)ev.e);
 }
 
 // fe_take_slow(timeout_ms, max_n) -> list[(req_id, bytes)]

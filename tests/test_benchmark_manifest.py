@@ -69,6 +69,35 @@ def test_tenants_10k_rows_agree_with_reference_and_host_oracle():
     assert len({r["host"] for r in rows}) > 450
 
 
+def test_routes_1k_cell_is_as_the_issue_states_and_its_files_agree():
+    manifest = _manifest()
+    cell = harness.load_cell(manifest, ROOT, "routes-1k.unique-sat")
+    config = cell["config_file"]
+    assert (cell["traffic"], cell["chips"]) == ("unique-sat", 1)
+    assert config["params"] == {"n_configs": 1000} and config["reduced"] == []
+    assert config["requests"] == {"deny_share": 0.5, "unrouted_share": 0.1,
+                                  "long_path_share": 0.15}
+    assert config["generator"] == "route_rules" and len(config["source"]) <= 200
+    assert cell["mix"] == {"loop": "closed", "conns": 8, "depth": 128, "warm_s": 10.0,
+                           "distinct_rows": 131072, "order": "cycle"}
+    reads = {m["name"] for m in cell["per_layer"]}
+    assert {"dfa_states", "launch_temp_bytes", "dfa_ovf_rows_pct", "dfa_scan_roofline",
+            "dfa_rows_per_row", "kernel_ms_per_launch", "device_idle_pct"} <= reads
+    # the roofline share that lists its cells lists this one alone; the
+    # counters are read in every cell that reports checks_per_s
+    assert "pattern_eval_roofline" not in reads
+    for other in ("tenants-1k.unique-sat", "tenants-10k.unique-sat"):
+        names = {m["name"] for m in harness.load_cell(manifest, ROOT, other)["per_layer"]}
+        assert {"dfa_states", "launch_temp_bytes", "dfa_ovf_rows_pct"} <= names
+        assert "dfa_scan_roofline" not in names
+    generator = harness.load_module("corpora", config["generator"])
+    manifests = generator.manifests({"n_configs": 3})
+    evaluators = manifests[2]["spec"]["authorization"]
+    assert len(evaluators) == 17 and "when" not in manifests[2]["spec"]
+    assert sum("when" in ev for ev in evaluators.values()) == 16
+    Reference(manifests)  # the plain reference, unedited, takes the corpus
+
+
 @pytest.mark.parametrize("sources, runs", [
     ({"ops/pattern_eval.py": 'widths = {"leaf_cols_per_row": 10}'}, True),
     ({"ops/pattern_eval.py": 'widths = {"dfa_rows_per_row": 2}'}, False),

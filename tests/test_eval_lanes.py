@@ -349,7 +349,8 @@ def _walk_eqns(jaxpr):
 def test_served_entry_holds_no_dense_dfa_intermediate(corpus):
     """Structure of the served entry for a corpus with R >> D: nothing of
     B x R x LB (the spread bytes) or B x R x 256 (the byte one-hot) elements
-    is built, and the one scan carries [B, D] — so a later edit cannot fall
+    is built, and the one scan carries [D, B] (the batch on the minor axis,
+    ISSUE 32) — so a later edit cannot fall
     back to the dense scan unseen, with every operand lane present or not."""
     n, B = 23, 16
     rng = random.Random(3)
@@ -381,4 +382,4 @@ def test_served_entry_holds_no_dense_dfa_intermediate(corpus):
             if eqn.primitive.name == "scan":
                 nc, k = eqn.params["num_consts"], eqn.params["num_carry"]
                 scans.append([tuple(v.aval.shape) for v in eqn.invars[nc:nc + k]])
-        assert scans == [[(B, D)]], (lane, scans)
+        assert scans == [[(D, B)]], (lane, scans)

@@ -287,7 +287,7 @@ def test_tenant_rules_operands_grow_with_configs_not_configs_times_leaves(lane):
         sizes[n] = pe.operand_bytes(view)
         assert pe.kernel_widths(view) == {
             "leaf_cols_per_row": 10, "dfa_rows_per_row": 2,
-            "dfa_rows_total": 2 * n}
+            "dfa_rows_total": 2 * n, "dfa_states": 16}
         assert policy.n_own_cpu == 2  # the two regexes' overflow columns
     assert sizes[128] <= 2.2 * sizes[64]
     if lane == "matmul":

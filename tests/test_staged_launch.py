@@ -291,6 +291,32 @@ def test_probe_failure_keeps_six_transfers_and_the_same_answers(
     assert d["h2d_transfers"] == d["launches"] >= 1
 
 
+@needs_native
+def test_overflowed_values_count_rows_and_the_kernel_reports_its_widths(served):
+    """ISSUE 32: the ledger's `dfa_ovf_rows` counts a row once, however
+    many of its values passed DFA_VALUE_BYTES and whatever a retry does,
+    beside the encoder's count of values; /debug/vars names the state axis
+    of the served table store and one launch's temporaries."""
+    fe, port, engine = served
+    long_path = "/api/v3/ok" + "c" * (DFA_VALUE_BYTES + 9)
+    assert response_key(grpc_call(port, make_req("fast-rx.test", path="/api/v3/ok")))[0] == 0
+    rows0, values0 = native_ledger("dfa_ovf_rows"), fe.stats()["dfa_overflow"]
+    all_rows0 = native_ledger("rows")
+    for k in range(3):
+        req = make_req("fast-rx.test", path=long_path + str(k))
+        assert response_key(grpc_call(port, req))[0] == 0
+    assert response_key(grpc_call(port, make_req("fast-rx.test", path="/api/nope")))[0] != 0
+    assert native_ledger("dfa_ovf_rows") - rows0 == 3
+    assert fe.stats()["dfa_overflow"] - values0 == 3
+    assert native_ledger("rows") - all_rows0 == 4
+    kernel = fe.debug_vars()["snapshot"]["kernel"]
+    policy = engine._snapshot.policy
+    assert kernel["dfa_states"] == policy.dfa_tables.shape[1] > 0
+    assert kernel["dfa_states"] % 8 == 0
+    assert kernel["launch_temp_bytes"] > 0
+    assert kernel["launch_temp_bytes"] == _current(fe).launch_temp_bytes
+
+
 def _burst(port, reqs):
     """The requests at once on one channel, so that they share cuts."""
     import grpc
