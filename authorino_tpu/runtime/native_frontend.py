@@ -31,7 +31,6 @@ pkg/service/auth.go:239-310 (Check flow incl. host override + port strip).
 
 from __future__ import annotations
 
-import itertools
 import logging
 import os
 import threading
@@ -58,11 +57,12 @@ from ..evaluators.authorization import PatternMatching
 from ..evaluators.identity import APIKey, KubernetesAuth, MTLS, Noop, OAuth2
 from ..evaluators.identity.api_key import INVALID_API_KEY_MSG
 from ..evaluators.identity.oidc import OIDC
+from ..native.verdict_cache import (NativeVerdictCache, key_segments,
+                                    plan_cut)
 from ..pipeline.pipeline import AuthPipeline, AuthResult
 from ..utils import bucket_pow2
 from ..utils import metrics as metrics_mod
 from ..utils import tracing as tracing_mod
-from ..utils.verdict_cache import VerdictCache
 from ..utils.rpc import (
     INVALID_ARGUMENT,
     NOT_FOUND,
@@ -641,13 +641,16 @@ class _SnapRec:
     # verdict-cache eligibility per kernel row: [G] bool (single corpus) or
     # [S, G] (mesh) — compiler/compile.py config_cacheable
     cacheable: Optional[np.ndarray] = None
-    # per-config verdict-cache key tokens (ISSUE 8): (encoding epoch,
-    # config source fingerprint) per kernel row, inherited from the engine
-    # snapshot.  Entries of configs a reconcile did NOT touch stay
-    # reachable across fe snapshots — the cache survives churn.  None
-    # (mesh corpora, or pre-fingerprint snapshots) falls back to PR 3's
-    # snap_id keying.
-    cache_tokens: Optional[list] = None
+    # what `plan` hands the native cache, built once a snapshot
+    # (_bind_cache_keys).  tok_ids: the verdict-cache key token of each
+    # kernel row as a u64 — the engine snapshot's per-config (encoding
+    # epoch, source fingerprint) tokens (ISSUE 8), interned by the frontend,
+    # so entries of configs a reconcile did NOT touch stay reachable across
+    # fe snapshots; mesh corpora and pre-fingerprint snapshots give every
+    # row one snapshot-wide token (PR 3's snap_id keying).  key_segs: the
+    # key descriptor of each batch slot, parallel to ``arrays``.
+    tok_ids: Optional[np.ndarray] = None
+    key_segs: List[bytes] = field(default_factory=list)
     # host (numpy) operand pytree for the host serving lane (ISSUE 12) and
     # the degraded lane: the same kernel on the CPU backend.  Built eagerly
     # by the pre-warm thread at snapshot swap (lazily as a fallback), so
@@ -699,8 +702,12 @@ class NativeFrontend:
         # Cache hits/misses/adds are folded into the frontend's dyn_hit/
         # dyn_miss/dyn_add stats keys (see stats()).
         self.batch_dedup = bool(batch_dedup)
-        self._verdict_cache = (VerdictCache(verdict_cache_size)
+        self._verdict_cache = (NativeVerdictCache(verdict_cache_size)
                                if verdict_cache_size else None)
+        # (encoding epoch, config fingerprint) -> u64, for the frontend's
+        # life: a config a reconcile did not touch keeps its id, so its
+        # cache entries stay reachable across snapshots (ISSUE 8)
+        self._cache_token_ids: Dict[Any, int] = {}
         # verified-token cache entries live at most this long (and never
         # past the token's own exp claim)
         self.dyn_ttl_s = float(dyn_ttl_s)
@@ -1669,7 +1676,6 @@ class NativeFrontend:
                 spec["attr_member_slot_addr"] = ams.ctypes.data
                 spec["attr_byte_slot_addr"] = abs_v.ctypes.data
                 rec.cacheable = policy.config_cacheable
-                rec.cache_tokens = getattr(snap, "cache_tokens", None)
                 if policy.n_byte_attrs > 0 and policy.dfa_tables.size:
                     # C++ indexes transition tables BY ROW: expand the
                     # compiler's deduped [T, S, 256] store through
@@ -1698,7 +1704,7 @@ class NativeFrontend:
                     }
                     rec.arrays.append(a)
                     spec["slots"].append({k: v.ctypes.data for k, v in a.items()})
-
+                self._bind_cache_keys(rec, getattr(snap, "cache_tokens", None))
             else:
                 policy = None  # no native encoder → kernel fast lane off
         elif sharded is not None:
@@ -1773,6 +1779,7 @@ class NativeFrontend:
                     }
                     rec.arrays.append(a)
                     spec["slots"].append({k: v.ctypes.data for k, v in a.items()})
+                self._bind_cache_keys(rec, None)
             else:
                 sharded = None  # no native encoder → kernel fast lane off
 
@@ -2132,60 +2139,48 @@ class NativeFrontend:
             elif kind == EV_STOPPED:
                 break
 
-    def _dedup_plan(self, rec: _SnapRec, a: Dict[str, np.ndarray],
-                    count: int, rows: np.ndarray,
-                    shards_arr: Optional[np.ndarray]):
-        """Cache-lookup + within-batch row collapse for one C++-encoded
-        slot.  Keys are the raw encoded operand bytes of each row (exact:
-        the kernel is a pure per-row function; the native path has no
-        lossy host-fallback rows).  Single-corpus snapshots key the cache
-        per config — (encoding epoch, config fingerprint, row bytes), so
-        entries for configs a reconcile did not touch SURVIVE the swap
-        (ISSUE 8); mesh corpora fall back to snap_id keying.  Returns
-        (cache_keys, eligible [count] bool, cached {row: verdict},
-        miss_rows, unique_rows, inverse, eligible_misses) — or None when
-        both features are off."""
+    def _bind_cache_keys(self, rec: _SnapRec,
+                         cache_tokens: Optional[list]) -> None:
+        """Once a snapshot: each kernel row's cache token as a u64 and each
+        slot's key descriptor.  The key is the row's encoded operand bytes,
+        ``shard_of`` first on a mesh corpus, then ``config_id`` and the
+        operands in this order (compiler/pack.py batch_row_keys's)."""
+        if cache_tokens is not None:
+            ids = self._cache_token_ids
+            rec.tok_ids = np.fromiter(
+                (ids.setdefault(t, len(ids)) for t in cache_tokens),
+                dtype=np.uint64, count=len(cache_tokens))
+        else:
+            # the top bit keeps snapshot-wide tokens off the interned ids
+            rec.tok_ids = np.full(rec.cacheable.shape[-1],
+                                  (1 << 63) | rec.snap_id, dtype=np.uint64)
+        order = ["config_id", "attrs_val", "members", "cpu_dense",
+                 "attr_bytes", "byte_ovf"]
+        if rec.sharded is not None:
+            order.insert(0, "shard_of")
+        rec.key_segs = [key_segments([a[k] for k in order])
+                        for a in rec.arrays]
+
+    def _dedup_plan(self, rec: _SnapRec, slot: int, count: int,
+                    rows: np.ndarray, shards_arr: Optional[np.ndarray]):
+        """Cache lookup + within-batch row collapse for one C++-encoded
+        slot: one native call, outside the interpreter lock
+        (native/verdict_cache.cpp).  Keys are the raw encoded operand bytes
+        of each row (exact: the kernel is a pure per-row function; the
+        native path has no lossy host-fallback rows) under the row's token
+        (``_SnapRec.tok_ids``).  Returns a ``CutPlan`` — or None when both
+        features are off."""
         cache = self._verdict_cache
         if not self.batch_dedup and cache is None:
             return None
-        from ..compiler.pack import dedup_rows, row_key_bytes
-
-        arrays = [a["config_id"], a["attrs_val"], a["members"],
-                  a["cpu_dense"], a["attr_bytes"], a["byte_ovf"]]
-        if shards_arr is not None:
-            arrays.insert(0, a["shard_of"])
-        keys = row_key_bytes(arrays, count)
-        tok = rec.cache_tokens if shards_arr is None else None
-        if tok is not None:
-            ckeys = [(tok[rows[r]], keys[r]) for r in range(count)]
-        else:
-            snap_id = rec.snap_id
-            ckeys = [(snap_id, keys[r]) for r in range(count)]
         if rec.cacheable is None:
             eligible = np.zeros((count,), dtype=bool)
         elif shards_arr is not None:
             eligible = rec.cacheable[shards_arr, rows]
         else:
             eligible = rec.cacheable[rows]
-        cached: Dict[int, int] = {}
-        elig_miss = 0
-        if cache is not None:
-            miss_rows: List[int] = []
-            for r in range(count):
-                if eligible[r]:
-                    v = cache.get(ckeys[r])
-                    if v is not None:
-                        cached[r] = v
-                        continue
-                    elig_miss += 1
-                miss_rows.append(r)
-        else:
-            miss_rows = list(range(count))
-        if self.batch_dedup:
-            unique_rows, inverse = dedup_rows(keys, miss_rows)
-        else:
-            unique_rows, inverse = miss_rows, np.arange(len(miss_rows))
-        return ckeys, eligible, cached, miss_rows, unique_rows, inverse, elig_miss
+        return plan_cut(cache, rec.key_segs[slot], count, rec.tok_ids[rows],
+                        eligible, self.batch_dedup)
 
     @staticmethod
     def _row_h2d_bytes(a: Dict[str, np.ndarray], eff: int,
@@ -2302,12 +2297,12 @@ class NativeFrontend:
             rows = a["config_id"][:count].copy()
             shards_arr = (a["shard_of"][:count].copy()
                           if rec.sharded is not None else None)
-            fan = self._dedup_plan(rec, a, count, rows, shards_arr)
+            fan = self._dedup_plan(rec, slot, count, rows, shards_arr)
             if fan is not None:
-                unique_rows = fan[4]
+                unique_rows = fan.unique_rows
                 u = len(unique_rows)
             else:
-                unique_rows, u = list(range(count)), count
+                unique_rows, u = None, count
 
         def sel(name):
             """Unique-row operand view: the slot arrays sliced [:pad] when
@@ -2336,8 +2331,10 @@ class NativeFrontend:
             # the parity the perf_guard tests pin exactly
             LEDGER.observe(
                 cost_lane, rows=count,
-                dedup_avoided_rows=(len(fan[3]) if fan is not None else 0),
-                cache_avoided_rows=(len(fan[2]) if fan is not None else 0),
+                dedup_avoided_rows=(len(fan.miss_rows)
+                                    if fan is not None else 0),
+                cache_avoided_rows=(len(fan.cached_rows)
+                                    if fan is not None else 0),
                 dfa_ovf_rows=ovf_rows)
         else:
             with bt.stage("encode"):
@@ -2351,8 +2348,10 @@ class NativeFrontend:
                 # past the unique count carry stale/repeated operands;
                 # results discarded)
                 pad, eff = self._pick_warm_shape(rec, u, eff)
-                idx = (np.asarray(unique_rows + [unique_rows[0]] * (pad - u))
-                       if u != count else None)
+                idx = None
+                if u != count:
+                    idx = np.full((pad,), unique_rows[0], dtype=np.int32)
+                    idx[:u] = unique_rows
                 t0 = time.monotonic()
                 t0_ns = time.time_ns()
                 if faults.ACTIVE:
@@ -2410,9 +2409,9 @@ class NativeFrontend:
                     LEDGER.observe(
                         "mesh", rows=count, device_rows=u, pad_rows=pad,
                         eff_slack_cols=eff - eff_need,
-                        dedup_avoided_rows=(len(fan[3]) - u
+                        dedup_avoided_rows=(len(fan.miss_rows) - u
                                             if fan is not None else 0),
-                        cache_avoided_rows=(len(fan[2])
+                        cache_avoided_rows=(len(fan.cached_rows)
                                             if fan is not None else 0),
                         dfa_ovf_rows=ovf_rows)
                 else:
@@ -2423,9 +2422,9 @@ class NativeFrontend:
                         d2h_bytes=int(packed.shape[0]) * int(packed.shape[1]),
                         pad_rows=pad,
                         eff_slack_cols=eff - eff_need,
-                        dedup_avoided_rows=(len(fan[3]) - u
+                        dedup_avoided_rows=(len(fan.miss_rows) - u
                                             if fan is not None else 0),
-                        cache_avoided_rows=(len(fan[2])
+                        cache_avoided_rows=(len(fan.cached_rows)
                                             if fan is not None else 0),
                         dfa_ovf_rows=ovf_rows)
         with self._rb_lock:
@@ -2625,9 +2624,8 @@ class NativeFrontend:
                 u = count
                 cached_n = elig_miss_n = 0
             else:
-                keys, eligible, cached, miss_rows, unique_rows, inverse, \
-                    elig_miss_n = fan
-                u = len(unique_rows)
+                u = len(fan.unique_rows)
+                elig_miss_n = fan.eligible_misses
                 verdict = np.zeros((count,), dtype=np.uint8)
                 firing = np.full((count,), -1, dtype=np.int32) if E else None
                 if u:
@@ -2636,19 +2634,15 @@ class NativeFrontend:
                     else:
                         uniq_v = (packed[:, 0] & 1).astype(np.uint8)
                         uniq_f = None
-                    mr = np.asarray(miss_rows)
-                    verdict[mr] = uniq_v[inverse]
+                    verdict[fan.miss_rows] = uniq_v[fan.inverse]
                     if firing is not None and uniq_f is not None:
-                        firing[mr] = uniq_f[inverse]
-                for r, v in cached.items():
-                    # cached value = (verdict, firing): a cache hit
-                    # attributes identically to the device evaluation it
-                    # memoized
-                    verdict[r] = v[0]
-                    if firing is not None:
-                        firing[r] = v[1]
-                verdict = np.ascontiguousarray(verdict)
-                cached_n = len(cached)
+                        firing[fan.miss_rows] = uniq_f[fan.inverse]
+                # cached value = (verdict, firing): a cache hit attributes
+                # identically to the device evaluation it memoized
+                verdict[fan.cached_rows] = fan.cached_verdict
+                if firing is not None:
+                    firing[fan.cached_rows] = fan.cached_firing
+                cached_n = len(fan.cached_rows)
             self._mod.fe_complete_batch(snap_id, slot, verdict.ctypes.data)
         # the slot is COMPLETED from here on: an exception below must not
         # propagate to the readback loop's fail-closed deny, which would
@@ -2659,19 +2653,11 @@ class NativeFrontend:
                 evict_d = 0
                 cache = self._verdict_cache
                 if fan is not None and cache is not None and u:
-                    evict0 = cache.evictions
                     # unique rows are freshly evaluated: the cacheable ones
-                    # go in at once.  fan[0] carries the FULL cache key
-                    # (per-config token or snap_id already folded in —
-                    # captured from the batch's pinned snapshot at dispatch)
-                    fresh = np.asarray(fan[4], dtype=np.int64)
-                    fresh = fresh[fan[1][fresh]]
-                    cache.put_many(
-                        map(fan[0].__getitem__, fresh.tolist()),
-                        zip(verdict[fresh].tolist(),
-                            firing[fresh].tolist() if firing is not None
-                            else itertools.repeat(-1)))
-                    evict_d = cache.evictions - evict0
+                    # go in at once, under the keys (token and row bytes)
+                    # the ticket copied at plan time: the slot may have
+                    # been refilled since
+                    evict_d = cache.commit(fan.ticket, verdict, firing)
                 metrics_mod.observe_dedup("native", count, u, cached_n,
                                           elig_miss_n, evict_d)
                 self._post_complete_telemetry(rec, count, pad, eff, rows,

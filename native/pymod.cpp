@@ -17,6 +17,7 @@
 
 #include "encoder.cpp"
 #include "frontend.cpp"
+#include "verdict_cache.cpp"
 
 namespace {
 
@@ -831,6 +832,142 @@ PyObject* fe_stats_py(PyObject*, PyObject*) {
   return d;
 }
 
+// ---------------------------------------------------------------------------
+// verdict cache + batch dedup (verdict_cache.cpp): one call a cut on each
+// side of the launch.  Both release the interpreter lock before the cache's
+// mutex is taken, and touch no Python object until they hold it again.
+// ---------------------------------------------------------------------------
+
+constexpr const char* VC_CACHE = "atpu.VerdictCache";
+constexpr const char* VC_TICKET = "atpu.VerdictTicket";
+
+void vc_cache_free(PyObject* cap) {
+  delete (vc::Cache*)PyCapsule_GetPointer(cap, VC_CACHE);
+}
+
+// a ticket keeps its cache alive: a batch may complete after the frontend
+// that planned it has gone
+struct VcTicket {
+  vc::Ticket t;
+  PyObject* cache_cap = nullptr;
+};
+
+void vc_ticket_free(PyObject* cap) {
+  VcTicket* t = (VcTicket*)PyCapsule_GetPointer(cap, VC_TICKET);
+  if (t == nullptr) return;
+  Py_XDECREF(t->cache_cap);
+  delete t;
+}
+
+// vc_new(max_entries, buckets=0) -> cache
+PyObject* vc_new_py(PyObject*, PyObject* args) {
+  int max_entries;
+  long long buckets = 0;
+  if (!PyArg_ParseTuple(args, "i|L", &max_entries, &buckets)) return nullptr;
+  if (max_entries < 1 || buckets < 0) {
+    PyErr_SetString(PyExc_ValueError, "vc_new: max_entries >= 1, buckets >= 0");
+    return nullptr;
+  }
+  return PyCapsule_New(new vc::Cache(max_entries, buckets), VC_CACHE, vc_cache_free);
+}
+
+// vc_plan(cache | None, segments, count, tokens, eligible, dedup)
+//   -> (arrays, n_cached, n_miss, n_unique, eligible_misses, ticket | None)
+// segments: u64 triples (address, bytes a row, rows), one an operand array
+// of the slot, in key order; tokens u64[count]; eligible u8[count].  arrays
+// is vc::Plan::out as int32 bytes.
+PyObject* vc_plan_py(PyObject*, PyObject* args) {
+  PyObject* cache_o;
+  Py_buffer segs, tokens, eligible;
+  int count, dedup;
+  if (!PyArg_ParseTuple(args, "Oy*iy*y*p", &cache_o, &segs, &count, &tokens,
+                        &eligible, &dedup))
+    return nullptr;
+  PyObject* result = nullptr;
+  VcTicket* ticket = nullptr;
+  vc::Cache* cache = nullptr;
+  if (cache_o != Py_None) {
+    cache = (vc::Cache*)PyCapsule_GetPointer(cache_o, VC_CACHE);
+    if (cache == nullptr) goto done;
+  }
+  {
+    bool ok = count >= 0 && segs.len % sizeof(vc::Seg) == 0 &&
+              tokens.len >= (Py_ssize_t)(count * sizeof(uint64_t)) && eligible.len >= count;
+    for (size_t s = 0; ok && s < segs.len / sizeof(vc::Seg); ++s)
+      ok = (uint64_t)count <= ((const vc::Seg*)segs.buf)[s].rows;
+    if (!ok) {
+      PyErr_SetString(PyExc_ValueError, "vc_plan: tokens, eligible or a segment shorter than "
+                                        "count, or segments not (address, bytes, rows) triples");
+      goto done;
+    }
+  }
+  {
+    vc::Plan plan;
+    ticket = new VcTicket();
+    Py_BEGIN_ALLOW_THREADS
+    vc::plan(cache, (const vc::Seg*)segs.buf, (size_t)segs.len / sizeof(vc::Seg), count,
+             (const uint64_t*)tokens.buf, (const uint8_t*)eligible.buf, dedup != 0,
+             plan, ticket->t);
+    Py_END_ALLOW_THREADS
+    PyObject* ticket_o;
+    if (cache != nullptr) {
+      ticket_o = PyCapsule_New(ticket, VC_TICKET, vc_ticket_free);
+      if (ticket_o == nullptr) goto done;
+      Py_INCREF(cache_o);
+      ticket->cache_cap = cache_o;
+      ticket = nullptr;  // the capsule's from here
+    } else {
+      ticket_o = Py_NewRef(Py_None);
+    }
+    result = Py_BuildValue("(y#iiiiN)", (const char*)plan.out.data(),
+                           (Py_ssize_t)(plan.out.size() * sizeof(int32_t)),
+                           plan.n_cached, plan.n_miss, plan.n_unique, plan.elig_miss,
+                           ticket_o);
+  }
+done:
+  delete ticket;
+  PyBuffer_Release(&segs);
+  PyBuffer_Release(&tokens);
+  PyBuffer_Release(&eligible);
+  return result;
+}
+
+// vc_commit(ticket, verdict u8[count], firing i32[count] | None) -> evictions
+PyObject* vc_commit_py(PyObject*, PyObject* args) {
+  PyObject* ticket_o;
+  Py_buffer verdict, firing;
+  if (!PyArg_ParseTuple(args, "Oy*z*", &ticket_o, &verdict, &firing)) return nullptr;
+  PyObject* result = nullptr;
+  VcTicket* ticket = (VcTicket*)PyCapsule_GetPointer(ticket_o, VC_TICKET);
+  if (ticket != nullptr) {
+    if (verdict.len < ticket->t.count ||
+        (firing.buf != nullptr &&
+         firing.len < (Py_ssize_t)(ticket->t.count * sizeof(int32_t)))) {
+      PyErr_SetString(PyExc_ValueError, "vc_commit: verdict/firing shorter than the cut");
+    } else {
+      vc::Cache* cache = (vc::Cache*)PyCapsule_GetPointer(ticket->cache_cap, VC_CACHE);
+      long long evicted;
+      Py_BEGIN_ALLOW_THREADS
+      evicted = vc::commit(cache, ticket->t, (const uint8_t*)verdict.buf,
+                           (const int32_t*)firing.buf);
+      Py_END_ALLOW_THREADS
+      result = PyLong_FromLongLong(evicted);
+    }
+  }
+  PyBuffer_Release(&verdict);
+  if (firing.buf != nullptr) PyBuffer_Release(&firing);
+  return result;
+}
+
+// vc_counts(cache) -> {hits, misses, adds, evictions, entries}
+PyObject* vc_counts_py(PyObject*, PyObject* cache_o) {
+  vc::Cache* c = (vc::Cache*)PyCapsule_GetPointer(cache_o, VC_CACHE);
+  if (c == nullptr) return nullptr;
+  return Py_BuildValue("{s:K,s:K,s:K,s:K,s:K}", "hits", c->hits.load(), "misses",
+                       c->misses.load(), "adds", c->adds.load(), "evictions",
+                       c->evictions.load(), "entries", c->entries.load());
+}
+
 PyMethodDef methods[] = {
     {"policy_new", policy_new_py, METH_VARARGS, "build native policy tables"},
     {"encode_docs", encode_docs, METH_VARARGS, "encode a batch of dict docs"},
@@ -854,6 +991,11 @@ PyMethodDef methods[] = {
      "drain per-authconfig duration histograms"},
     {"fe_stage_hist", fe_stage_hist_py, METH_NOARGS,
      "drain the on-box per-request stage histograms"},
+    {"vc_new", vc_new_py, METH_VARARGS, "new native verdict cache"},
+    {"vc_plan", vc_plan_py, METH_VARARGS,
+     "probe the cache for a cut's rows and collapse its misses"},
+    {"vc_commit", vc_commit_py, METH_VARARGS, "insert a planned cut's verdicts"},
+    {"vc_counts", vc_counts_py, METH_O, "verdict cache counters"},
     {nullptr, nullptr, 0, nullptr},
 };
 

@@ -98,6 +98,32 @@ def test_routes_1k_cell_is_as_the_issue_states_and_its_files_agree():
     Reference(manifests)  # the plain reference, unedited, takes the corpus
 
 
+def test_cache_hit_rows_pct_reads_ledger_counters_the_program_has():
+    """ISSUE 33's metric is data only: its file names the `ledger_ratio`
+    reader and [lane, field] pairs the kernel-cost ledger folds, and every
+    cell that reports `checks_per_s` reads it."""
+    from authorino_tpu.runtime import kernel_cost
+
+    manifest = _manifest()
+    entry = [m for m in manifest["per_layer"] if m["name"] == "cache_hit_rows_pct"]
+    assert entry == [{"name": "cache_hit_rows_pct", "unit": "%", "better": "higher",
+                      "source": "program_counter", "moves": "checks_per_s",
+                      "layer": "batch cut, dedup and verdict cache"}]
+    assert manifest["per_layer"][-1] is entry[0]        # appended, not inserted
+    spec = harness._load_json(
+        os.path.join(BENCH, "metrics", "cache_hit_rows_pct.json"))
+    assert spec["reader"] == "ledger_ratio" and spec["args"]["scale"] == 100.0
+    assert os.path.isfile(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
+    assert spec["args"]["num"] == [["native", "cache_avoided_rows"]]
+    assert spec["args"]["den"] == [["native", "rows"], ["host", "rows"]]
+    for lane, field in spec["args"]["num"] + spec["args"]["den"]:
+        assert lane in kernel_cost.LANES and field in kernel_cost._FIELDS
+    for cell in manifest["workloads"]:
+        names = {m["name"] for m in
+                 harness.load_cell(manifest, ROOT, cell["name"])["per_layer"]}
+        assert "cache_hit_rows_pct" in names
+
+
 @pytest.mark.parametrize("sources, runs", [
     ({"ops/pattern_eval.py": 'widths = {"leaf_cols_per_row": 10}'}, True),
     ({"ops/pattern_eval.py": 'widths = {"dfa_rows_per_row": 2}'}, False),

@@ -2148,6 +2148,71 @@ def test_randomized_differential_sweep(stack):
 # ---------------------------------------------------------------------------
 
 
+def _pinned_lane(path):
+    """A frontend with one hand-built snapshot record of 256 configs and a
+    256-row batch slot, without the server: what `plan` and `post` read."""
+    import types
+
+    import numpy as np
+
+    from authorino_tpu.runtime import provenance as prov_mod
+    from authorino_tpu.runtime.native_frontend import _SnapRec
+
+    G = B = 256
+    engine = PolicyEngine(max_batch=64, mesh=None)
+    fe = NativeFrontend(engine, port=0, max_batch=B, slo_ms=250.0)
+    fe._mod = types.SimpleNamespace(fe_complete_batch=lambda *a: None)
+    sharded = None
+    if path == "sharded":
+        sharded = types.SimpleNamespace(configs_per_shard=G // 2)
+    heat = prov_mod.HeatMap(
+        [f"pin-{path}/c{i}" for i in range(G)], [["r0", "r1"]] * G, 2,
+        configs_per_shard=G // 2 if sharded else None)
+    keys = ([(s, r) for s in range(2) for r in range(G // 2)] if sharded
+            else list(range(G)))
+    labels = {key: (f"pin-{path}", f"c{i}") for i, key in enumerate(keys)}
+    heat.bind_authconfigs(labels, hybrid=keys[::7])
+    rec = _SnapRec(snap_id=1, policy=None, params=None, encoder=None,
+                   sharded=sharded, heat=heat, row_labels=labels,
+                   hybrid_rows=set(keys[::7]),
+                   cacheable=np.ones((2, G // 2) if sharded else (G,), bool))
+    a = {"config_id": np.zeros((B,), np.int32),
+         "attrs_val": np.zeros((B, 4), np.int16),
+         "members": np.zeros((B, 1, 2), np.int16),
+         "cpu_dense": np.zeros((B, 2), np.uint8),
+         "attr_bytes": np.zeros((B, 1, 64), np.uint8),
+         "byte_ovf": np.zeros((B, 1), np.uint8)}
+    if sharded:
+        a["shard_of"] = np.zeros((B,), np.int32)
+    rec.arrays.append(a)
+    fe._bind_cache_keys(rec, None)
+    rng = np.random.default_rng(29)
+
+    def plan_cut():
+        """Encode one cut of the 256 configs, shuffled, and plan it."""
+        flat = rng.permutation(G)
+        rows = (flat % (G // 2) if sharded else flat).astype(np.int32)
+        shards = (flat // (G // 2)).astype(np.int32) if sharded else None
+        a["config_id"][:] = rows
+        if sharded:
+            a["shard_of"][:] = shards
+        return rows, shards, fe._dedup_plan(rec, 0, B, rows, shards)
+
+    def complete(rows, shards, fan):
+        cols = np.zeros((B, 8), dtype=bool)
+        denied = rng.random(B) < 0.5
+        cols[:, 0] = ~denied
+        cols[:, 1] = ~denied         # rule 0 false = it fires
+        cols[:, 2] = True
+        packed = np.packbits(cols, axis=1, bitorder="little")
+        bt = fe.batch_stages.begin(1, 0, B)
+        bt.ready()
+        fe._complete_device_batch(rec, 1, 0, B, B, 0, rows, shards, packed,
+                                  time.monotonic(), time.time_ns(), fan, 0, bt)
+
+    return fe, rec, heat, labels, keys, plan_cut, complete
+
+
 @pytest.mark.perf_guard
 @pytest.mark.parametrize("path", ["device", "host-lane", "sharded"])
 def test_post_runs_no_per_config_python(path, monkeypatch):
@@ -2156,58 +2221,29 @@ def test_post_runs_no_per_config_python(path, monkeypatch):
     no Prometheus child (`.labels`), makes no `VerdictCache.put` and no
     decision record once the tenants' first sightings are behind it, on the
     device path, the host-lane path and the sharded path."""
-    import types
-
     import numpy as np
     from prometheus_client.metrics import MetricWrapperBase
 
     from authorino_tpu.runtime import provenance as prov_mod
-    from authorino_tpu.runtime.native_frontend import _SnapRec
     from authorino_tpu.utils.verdict_cache import VerdictCache
 
     G = B = 256
-    E = 2
-    engine = PolicyEngine(max_batch=64, mesh=None)
-    fe = NativeFrontend(engine, port=0, max_batch=B, slo_ms=250.0)
-    fe._mod = types.SimpleNamespace(fe_complete_batch=lambda *a: None)
-    sharded = None
-    if path == "sharded":
-        sharded = types.SimpleNamespace(configs_per_shard=G // 2)
-    heat = prov_mod.HeatMap(
-        [f"pin-{path}/c{i}" for i in range(G)], [["r0", "r1"]] * G, E,
-        configs_per_shard=G // 2 if sharded else None)
-    keys = ([(s, r) for s in range(2) for r in range(G // 2)] if sharded
-            else list(range(G)))
-    labels = {key: (f"pin-{path}", f"c{i}") for i, key in enumerate(keys)}
-    heat.bind_authconfigs(labels, hybrid=keys[::7])
-    rec = _SnapRec(snap_id=1, policy=None, params=None, encoder=None,
-                   sharded=sharded, heat=heat, row_labels=labels,
-                   hybrid_rows=set(keys[::7]))
+    fe, rec, heat, labels, keys, plan_cut, complete = _pinned_lane(path)
     rng = np.random.default_rng(29)
+    # two cuts of the same 256 rows in flight, planned while the cache is
+    # empty: the first's `post` inserts them, the second's refreshes them
+    cuts = [plan_cut() for _ in range(2)] if path != "host-lane" else []
 
     def one_batch():
-        flat = rng.permutation(G)
-        rows = flat % (G // 2) if sharded else flat
-        shards = flat // (G // 2) if sharded else None
-        cols = np.zeros((B, 8), dtype=bool)
-        denied = rng.random(B) < 0.5
-        cols[:, 0] = ~denied
-        cols[:, 1] = ~denied         # rule 0 false = it fires
-        cols[:, 2] = True
-        packed = np.packbits(cols, axis=1, bitorder="little")
-        if path == "host-lane":
-            fe._post_complete_telemetry(
-                rec, B, 0, 0, rows, None, cols[:, 0].astype(np.uint8), 0.001,
-                time.time_ns(), device_rows=0, device=False,
-                firing=np.where(denied, 0, -1).astype(np.int32))
+        if path != "host-lane":
+            complete(*cuts.pop(0))
             return
-        cache_keys = [(1, bytes([int(f)])) for f in flat]
-        fan = (cache_keys, np.ones(B, dtype=bool), {}, list(range(B)),
-               list(range(B)), np.arange(B), B)
-        bt = fe.batch_stages.begin(1, 0, B)
-        bt.ready()
-        fe._complete_device_batch(rec, 1, 0, B, B, 0, rows, shards, packed,
-                                  time.monotonic(), time.time_ns(), fan, 0, bt)
+        rows = rng.permutation(G)
+        denied = rng.random(B) < 0.5
+        fe._post_complete_telemetry(
+            rec, B, 0, 0, rows, None, (~denied).astype(np.uint8), 0.001,
+            time.time_ns(), device_rows=0, device=False,
+            firing=np.where(denied, 0, -1).astype(np.int32))
 
     one_batch()  # first sightings sample; per-batch children are minted
     calls = {"labels": 0, "put": 0, "record": 0}
@@ -2233,7 +2269,9 @@ def test_post_runs_no_per_config_python(path, monkeypatch):
     monkeypatch.undo()
     if path != "host-lane":
         assert fe.batch_stages.totals()["post"]["count"] == posts + 1
-        assert fe._verdict_cache.counts()["adds"] == B
+        assert fe._verdict_cache.counts() == {
+            "hits": 0, "misses": 2 * B, "adds": B, "evictions": 0,
+            "entries": B}
     # and the drain names all of it: every non-hybrid row counted twice,
     # a hybrid row once a denial
     from authorino_tpu.utils import metrics as metrics_mod
@@ -2243,3 +2281,58 @@ def test_post_runs_no_per_config_python(path, monkeypatch):
                 for key in keys)
     assert total == heat.requests.sum() > B
     assert fe.tenancy.stats.to_json()["tenants_seen"] >= G
+
+
+@pytest.mark.perf_guard
+@pytest.mark.parametrize("path", ["device", "sharded"])
+def test_plan_and_post_run_no_per_row_cache_python(path):
+    """ISSUE 33's structural pin: a 256-row cut through `_dedup_plan` and
+    `_complete_device_batch` builds no `bytes` a row (`ndarray.tobytes`),
+    calls no `VerdictCache.get` / `put` / `put_many`, no `row_key_bytes` and
+    no `dedup_rows`; `plan` as a whole makes fewer calls than a tenth of the
+    cut has rows.  Counted by the interpreter's profile hook, which sees
+    every Python and C function this thread calls."""
+    import sys
+
+    from authorino_tpu.compiler import pack
+    from authorino_tpu.utils.verdict_cache import VerdictCache
+
+    B = 256
+    fe, rec, heat, labels, keys, plan_cut, complete = _pinned_lane(path)
+    complete(*plan_cut())  # first sightings; the cache now holds the 256 rows
+    forbidden = {f.__code__: f.__qualname__ for f in (
+        VerdictCache.get, VerdictCache.put, VerdictCache.put_many,
+        VerdictCache._put, pack.row_key_bytes, pack.dedup_rows)}
+    seen = {"tobytes": 0, "calls": 0}
+
+    def hook(frame, event, arg):
+        if event == "call":
+            seen["calls"] += 1
+            name = forbidden.get(frame.f_code)
+            if name is not None:
+                seen[name] = seen.get(name, 0) + 1
+        elif event == "c_call":
+            seen["calls"] += 1
+            seen["tobytes"] += getattr(arg, "__name__", "") == "tobytes"
+
+    def profiled(fn, *args):
+        seen["calls"] = 0
+        sys.setprofile(hook)
+        try:
+            return fn(*args), seen["calls"]
+        finally:
+            sys.setprofile(None)
+
+    # the hit path: every row answered by the cache, with its LRU move
+    hit, hit_calls = profiled(plan_cut)
+    assert len(hit[2].cached_rows) == B and len(hit[2].unique_rows) == 0
+    # the miss path: new rows (another attribute value), inserted by `post`
+    rec.arrays[0]["attrs_val"][:, 0] = 1
+    miss, miss_calls = profiled(plan_cut)
+    assert len(miss[2].unique_rows) == miss[2].eligible_misses == B
+    profiled(complete, *miss)
+    assert seen == {"tobytes": 0, "calls": seen["calls"]}
+    assert hit_calls < B // 10 and miss_calls < B // 10
+    assert fe._verdict_cache.counts() == {
+        "hits": B, "misses": 2 * B, "adds": 2 * B, "evictions": 0,
+        "entries": 2 * B}
