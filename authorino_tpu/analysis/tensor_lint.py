@@ -32,7 +32,13 @@ Checks and their finding kinds (catalogue: docs/static_analysis.md):
                      maps back to the corpus slot it stands for: evaluator
                      references, each node's children and kind, each leaf's
                      fields, the row payload's CPU columns — the served
-                     entry evaluates these tables and nothing else
+                     entry evaluates these tables and nothing else.  Held
+                     for the corpus-wide layout AND for every size class's
+                     (ISSUE 34): each config is in exactly one class, every
+                     own position of every class maps back to its corpus
+                     slot, a class's DFA rows are its members' and its
+                     table store holds their tables, cut to states no
+                     transition leaves
 """
 
 from __future__ import annotations
@@ -315,8 +321,14 @@ def _check_own_rows(policy: CompiledPolicy, out: List[Finding]) -> None:
             return
 
 
-def _check_own_layout(policy: CompiledPolicy, out: List[Finding]) -> None:
-    """ISSUE 28 own-config layout, audited against its SOURCES without a
+def _check_own_layout(policy: CompiledPolicy, out: List[Finding],
+                      own=None, cfgs=None, dfa_rows=None,
+                      where: str = "own") -> None:
+    """(``own``, ``cfgs``, ``dfa_rows``: one size class's tables, its member
+    config rows and their [G_c, D_c] corpus DFA rows; left None, the
+    corpus-wide layout of every config.)
+
+    ISSUE 28 own-config layout, audited against its SOURCES without a
     walk: every own position is mapped back to the corpus's buffer slot and
     (1) each evaluator reference, (2) each own node's children and kind,
     (3) each own leaf's fields must equal the corpus's.  By induction over
@@ -329,15 +341,32 @@ def _check_own_layout(policy: CompiledPolicy, out: List[Finding]) -> None:
                                     OWN_NUM, OWN_OP, OWN_REL_COL,
                                     OWN_REL_SLOT)
 
-    own = getattr(policy, "own", None)
-    G, E = policy.eval_rule.shape
+    if own is None:
+        own = getattr(policy, "own", None)
+    if cfgs is None:
+        cfgs = np.arange(policy.eval_rule.shape[0])
+    if dfa_rows is None:
+        dfa_rows = policy.config_dfa_rows
+    G = int(cfgs.shape[0])
     base, L = _leaf_base(), policy.n_leaves
 
     def bad(msg: str, config: Optional[int] = None) -> None:
-        out.append(_err("own-layout", msg, "own",
-                        **({} if config is None else {"config": config})))
+        out.append(_err("own-layout", msg, where, **(
+            {} if config is None else {"config": int(cfgs[config])})))
 
     n_levels = len(policy.levels)
+    E = int(own.evals.shape[2]) if own is not None and own.evals.ndim == 3 \
+        else 0
+    eval_rule, eval_cond = policy.eval_rule[cfgs], policy.eval_cond[cfgs]
+    eval_has_cond = policy.eval_has_cond[cfgs]
+    if not 1 <= E <= eval_rule.shape[1] or (
+            eval_rule[:, E:] != 0).any() or eval_has_cond[:, E:].any():
+        # an evaluator column the tables lack must be the padding's: rule
+        # TRUE_SLOT (0) and no condition
+        bad("own evaluator columns do not cover the configs' evaluators")
+        return
+    eval_rule, eval_cond = eval_rule[:, :E], eval_cond[:, :E]
+    eval_has_cond = eval_has_cond[:, :E]
     if own is None or own.leaves.ndim != 2 or own.leaves.shape[0] != G \
             or len(own.levels) != n_levels or len(own.nodes) != n_levels \
             or own.evals.shape != (G, 3, E) \
@@ -368,9 +397,9 @@ def _check_own_layout(policy: CompiledPolicy, out: List[Finding]) -> None:
     def first_bad(mask: np.ndarray) -> int:
         return int(np.nonzero(mask.reshape(G, -1).any(axis=1))[0][0])
 
-    wrong = (back(own.evals[:, 0]) != policy.eval_rule) \
-        | (back(own.evals[:, 1]) != policy.eval_cond) \
-        | ((own.evals[:, 2] != 0) != policy.eval_has_cond)
+    wrong = (back(own.evals[:, 0]) != eval_rule) \
+        | (back(own.evals[:, 1]) != eval_cond) \
+        | ((own.evals[:, 2] != 0) != eval_has_cond)
     if wrong.any():
         g = first_bad(wrong)
         bad(f"own evaluator references of config {g} do not map back to "
@@ -398,7 +427,7 @@ def _check_own_layout(policy: CompiledPolicy, out: List[Finding]) -> None:
     op = policy.leaf_op[lf]
     is_dfa = has & (op == OP_REGEX_DFA)
     dfa_row = np.take_along_axis(
-        policy.config_dfa_rows, np.clip(tab[..., OWN_DFA], 0, None), axis=1)
+        dfa_rows, np.clip(tab[..., OWN_DFA], 0, dfa_rows.shape[1] - 1), axis=1)
     cpu_leaf = np.take_along_axis(
         own.cpu_leaves, np.clip(tab[..., OWN_CPU], 0, None), axis=1)
     in_cpu = np.isin(own.leaves, policy.cpu_leaf_list) & has
@@ -435,6 +464,71 @@ def _check_own_layout(policy: CompiledPolicy, out: List[Finding]) -> None:
             != np.sort(np.where(in_cpu, own.leaves, L), axis=1)[:, :cl.shape[1]]
             ).any() or in_cpu.sum(axis=1).max(initial=0) > cl.shape[1]:
         bad("own CPU columns are not the config's own CPU-lane leaves")
+
+
+def _check_classes(policy: CompiledPolicy, out: List[Finding]) -> None:
+    """ISSUE 34 size classes, audited against the corpus arrays: the
+    classes partition the configs; each class's own-config tables pass the
+    own-layout audit over its members (so every own position of every
+    class maps back to its corpus slot, at whatever widths the class has);
+    its DFA rows are exactly its members' and its store's tables are the
+    corpus's, cut to a state axis no transition leaves.  The served entry
+    gathers a request's row from these tables and no other."""
+    classes = getattr(policy, "classes", None)
+    G = int(policy.eval_rule.shape[0])
+
+    def bad(msg: str, c: int) -> None:
+        out.append(_err("own-layout", msg, f"classes[{c}]"))
+
+    if not classes:
+        out.append(_err("own-layout", "the corpus has no size class",
+                        "classes"))
+        return
+    seen = np.concatenate([np.asarray(c.configs) for c in classes])
+    if sorted(seen.tolist()) != list(range(G)):
+        out.append(_err("own-layout", "the size classes do not hold every "
+                        "config exactly once", "classes"))
+        return
+    has_dfa = bool(policy.n_byte_attrs) and bool(policy.dfa_tables.size)
+    for c, cls in enumerate(classes):
+        cfgs = np.asarray(cls.configs)
+        local = np.full((G,), -1, dtype=np.int64)
+        local[cfgs] = np.arange(cfgs.shape[0])
+        if cls.cfg_local.shape != (G,) or (cls.cfg_local != local).any():
+            bad("cfg_local is not the class's config row -> table row map", c)
+            return
+        rows, store = cls.config_dfa_rows, np.asarray(cls.dfa_rows)
+        if rows.ndim != 2 or rows.shape[0] != cfgs.shape[0] or rows.shape[1] < 1 \
+                or (rows.size and int(rows.max()) >= max(store.shape[0], 1)):
+            bad("config_dfa_rows is not [G_c, D_c] over the class's store", c)
+            return
+        corpus_rows = np.where(rows >= 0, store[np.maximum(rows, 0)]
+                               if store.size else -1, -1)
+        want = policy.config_dfa_rows[cfgs]
+        D = rows.shape[1]
+        if D > want.shape[1] or (corpus_rows != want[:, :D]).any() \
+                or (want[:, D:] >= 0).any():
+            bad("the class's DFA rows are not its members' own rows", c)
+            return
+        if has_dfa and store.size:
+            tab = cls.dfa_table_of_row
+            src = policy.dfa_table_of_row[store]
+            S = cls.dfa_tables.shape[1]
+            if int(src.min()) < 0 or int(src.max()) >= policy.dfa_tables.shape[0] \
+                    or tab.shape != store.shape \
+                    or int(tab.max()) >= cls.dfa_tables.shape[0] \
+                    or S > policy.dfa_tables.shape[1] \
+                    or (cls.dfa_tables[tab] != policy.dfa_tables[src][:, :S]).any() \
+                    or (cls.dfa_accept[tab] != policy.dfa_accept[src][:, :S]).any() \
+                    or int(cls.dfa_tables[tab].max(initial=0)) >= S:
+                bad("the class's DFA table store does not hold its rows' "
+                    "tables, or cuts a state a transition reaches", c)
+                return
+        before = len(out)
+        _check_own_layout(policy, out, own=cls.own, cfgs=cfgs,
+                          dfa_rows=corpus_rows, where=f"classes[{c}].own")
+        if len(out) > before:
+            return
 
 
 def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
@@ -636,6 +730,8 @@ def tensor_lint(policy: CompiledPolicy,
         _check_own_rows(policy, out)
     if not out:
         _check_own_layout(policy, out)
+    if not out:
+        _check_classes(policy, out)
     if check_lanes and not out:
         # lane builds index through the arrays checked above; skip when the
         # base layout is already broken (they would raise, not report)
@@ -650,7 +746,7 @@ def _shard_grid_sig(p: CompiledPolicy) -> tuple:
         p.n_attrs, p.n_leaves, p.n_member_attrs, p.members_k,
         p.n_cpu_leaves, p.n_byte_attrs, p.buffer_size,
         tuple(p.eval_rule.shape), tuple(p.config_dfa_rows.shape),
-        p.own.shape_key(),
+        p.own.shape_key(), tuple(c.shape_key() for c in p.classes),
         tuple((tuple(children.shape), int(is_and.shape[0]))
               for children, is_and in p.levels),
         int(getattr(p, "n_num_attrs", 0) or 0),

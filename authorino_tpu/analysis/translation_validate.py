@@ -635,18 +635,23 @@ def _numeric_lane_findings(policy: CompiledPolicy) -> List[Finding]:
 
 def _own_rows_findings(policy: CompiledPolicy) -> List[Finding]:
     """Own-config audit (ISSUEs 26 and 28, once per snapshot):
-    ``config_dfa_rows`` and the ``OwnLayout`` tables against the circuit, by
+    ``config_dfa_rows``, the ``OwnLayout`` tables and every size class's
+    (ISSUE 34: its members, its own tables, its DFA store) against the circuit, by
     the tensor lint's own checks.  The served kernel evaluates only what
     these tables name for a request's config; a DFA row the table lacks
     reads False, and an own leaf or node that stands for the wrong corpus
     slot answers another question — neither of which a truth-table over the
     corpus arrays can see."""
-    from .tensor_lint import _check_own_layout, _check_own_rows
+    from .tensor_lint import (_check_classes, _check_own_layout,
+                              _check_own_rows)
 
     found: List[Finding] = []
     _check_own_rows(policy, found)
     if not found:
         _check_own_layout(policy, found)
+    if not found:
+        # the size classes' tables are what the served entry gathers from
+        _check_classes(policy, found)
     return [_err("own-rows-layout", f.message, f.location, **f.detail)
             for f in found]
 
@@ -1325,16 +1330,44 @@ def _mut_own_leaf_rebound(p: CompiledPolicy) -> None:
     own.leaf_tab[g, j, OWN_CONST] += 1
 
 
+def _mut_class_leaf_rebound(p: CompiledPolicy) -> None:
+    """Rebind one leaf of one size class's own table to another constant
+    (ISSUE 34): the corpus arrays and the corpus-wide layout stay right, and
+    the served entry, which gathers from the class's tables alone, compares
+    that config's requests against the wrong value."""
+    from ..compiler.compile import OWN_CONST
+
+    own = p.classes[-1].own
+    g, j = (int(x[0]) for x in np.nonzero(own.leaves >= 0))
+    own.leaf_tab = own.leaf_tab.copy()
+    own.leaf_tab[g, j, OWN_CONST] += 1
+
+
+def _mut_class_row_dropped(p: CompiledPolicy) -> None:
+    """Drop one DFA row from one member's row of a size class's own-row
+    table (ISSUE 34): the served scan would skip that regex for that
+    config's requests, whatever ``config_dfa_rows`` says."""
+    for cls in p.classes:
+        owners = np.nonzero(cls.config_dfa_rows[:, 0] >= 0)[0]
+        if len(owners) and cls.dfa_rows.size:
+            g = int(owners[0])
+            cls.config_dfa_rows = cls.config_dfa_rows.copy()
+            cls.config_dfa_rows[g, int((cls.config_dfa_rows[g] >= 0).sum()) - 1] = -1
+            return
+    raise AssertionError("no class member owns a DFA row")
+
+
 def _upstream(mutate):
     """A miscompile planted in the corpus arrays, upstream of the layout the
     compiler derives from them: the own-config tables follow, as they would
     in a real compile, so it is the certifier and not the layout lint that
     has to see it."""
     def planted(p: CompiledPolicy) -> None:
-        from ..compiler.compile import derive_own_layout
+        from ..compiler.compile import derive_classes, derive_own_layout
 
         mutate(p)
         p.own = derive_own_layout(p)
+        p.classes = derive_classes(p)
 
     planted.__name__ = mutate.__name__
     planted.__doc__ = mutate.__doc__
@@ -1346,13 +1379,16 @@ _MUTANTS = (
     ("eval-rule-redirect", _upstream(_mut_eval_rule_redirect)),
     ("leaf-attr-swap", _upstream(_mut_leaf_attr_swap)),
     ("leaf-const-swap", _upstream(_mut_leaf_const_swap)),
-    ("dfa-transition-corrupt", _mut_dfa_transition),
-    ("dfa-accept-flip", _mut_dfa_accept_flip),
-    ("dfa-pad-corrupt", _mut_dfa_pad_corrupt),
+    ("dfa-transition-corrupt", _upstream(_mut_dfa_transition)),
+    ("dfa-accept-flip", _upstream(_mut_dfa_accept_flip)),
+    ("dfa-pad-corrupt", _upstream(_mut_dfa_pad_corrupt)),
     # ISSUE 26 own-row scan layout (caught by _own_rows_findings)
     ("own-row-dropped", _mut_own_row_dropped),
     # ISSUE 28 own-config tables (caught by _own_rows_findings)
     ("own-leaf-rebound", _mut_own_leaf_rebound),
+    # ISSUE 34 size classes' tables (caught by _own_rows_findings)
+    ("class-leaf-rebound", _mut_class_leaf_rebound),
+    ("class-row-dropped", _mut_class_row_dropped),
 )
 
 

@@ -55,7 +55,8 @@ __all__ = [
     "OP_EQ", "OP_NEQ", "OP_INCL", "OP_EXCL", "OP_CPU", "OP_ERROR", "OP_TREE_CPU",
     "OP_REGEX_DFA", "OP_NUM_GT", "OP_NUM_GE", "OP_NUM_LT", "OP_NUM_LE",
     "OP_RELATION", "NUMERIC_OPS",
-    "ConfigRules", "CompiledPolicy", "ShapeTargets", "OwnLayout", "compile_corpus",
+    "ConfigRules", "CompiledPolicy", "ShapeTargets", "OwnLayout", "SizeClass",
+    "compile_corpus", "derive_layouts", "CLASS_RATIO", "CLASS_FLOOR_BYTES",
     "TRUE_SLOT", "FALSE_SLOT", "DFA_VALUE_BYTES",
 ]
 
@@ -326,14 +327,21 @@ class CompiledPolicy:
     # payload's CPU columns mean.  Derived like config_dfa_rows.
     own: "OwnLayout" = None
 
+    # --- size classes (ISSUE 34) ---
+    # the configs cut into classes of like size, each with tables of its own
+    # widths ([G_c, ...], its own l_own, c_own, n_own a level, E, D and DFA
+    # state axis S): what the served entry gathers a request's row from, so
+    # that a row pays for its own config's size and not for the corpus's
+    # largest.  ``own`` and ``config_dfa_rows`` above stay the corpus-wide
+    # padded form on the host (the encoders' CPU columns, the dense bodies,
+    # the single layout the classes are cut from).  Derived like them.
+    classes: Tuple["SizeClass", ...] = None
+
     def __post_init__(self) -> None:
         if self.eval_rule is not None and self.leaf_dfa_row is not None \
-                and (self.config_dfa_rows is None or self.own is None):
-            reach = own_reach(self)
-            if self.config_dfa_rows is None:
-                self.config_dfa_rows = derive_config_dfa_rows(self, reach)
-            if self.own is None:
-                self.own = derive_own_layout(self, reach)
+                and (self.config_dfa_rows is None or self.own is None
+                     or self.classes is None):
+            derive_layouts(self, keep=True)
 
     def rule_sources(self) -> List[List[str]]:
         """Decision provenance (ISSUE 9): per config row, the source string
@@ -405,6 +413,7 @@ class CompiledPolicy:
             bool(self.ovf_assist),
             int(self.config_dfa_rows.shape[1]),
             self.own.shape_key(),
+            tuple(c.shape_key() for c in self.classes),
         )
 
     def shape_targets(self) -> ShapeTargets:
@@ -441,15 +450,18 @@ OWN_FIELDS = 10
 
 @dataclass
 class OwnLayout:
-    """One config's slice of the corpus, for every config: the served entry
-    gathers row ``config_id`` of each table and evaluates [B, l_own] leaves,
-    [B, n_own] nodes a level and [B, E] evaluators in a buffer of the
-    config's own: TRUE, FALSE, its leaves (ascending global index), then
-    its nodes level by level (ascending global row).  Leaves and nodes are
-    shared across configs, so these are per-config tables, not a partition.
-    Every axis is the natural maximum over configs; only ShapeTargets widens
-    them, so shards stack.  Padding: leaves read OP_ERROR (False), nodes are
-    an Or of FALSE, and nothing references either."""
+    """One config's slice of the corpus, for every config of the table: the
+    served entry gathers a request's config's row of each table and
+    evaluates [B, l_own] leaves, [B, n_own] nodes a level and [B, E]
+    evaluators in a buffer of the config's own: TRUE, FALSE, its leaves
+    (ascending global index), then its nodes level by level (ascending
+    global row).  Leaves and nodes are shared across configs, so these are
+    per-config tables, not a partition.  Two forms: ``CompiledPolicy.own``
+    covers every config, every axis the natural maximum over the corpus
+    (only ShapeTargets widens it, so shards stack); ``SizeClass.own`` covers
+    a class's members at the class's maxima and is what serves.  Padding:
+    leaves read OP_ERROR (False), nodes are an Or of FALSE, and nothing
+    references either."""
 
     leaves: np.ndarray      # [G, l_own] int32 global leaf idx (-1 pad)
     nodes: Tuple[np.ndarray, ...]  # per level [G, n_own] int32 global row (-1 pad)
@@ -516,7 +528,8 @@ def _padded(lists: Sequence[Sequence[int]], width: int) -> np.ndarray:
 def derive_config_dfa_rows(policy: "CompiledPolicy", reach=None) -> np.ndarray:
     """[G, D] int32: for each config row the DFA rows of the
     ``OP_REGEX_DFA`` leaves it reaches, ascending, padded with -1.  D is the
-    natural maximum (at least 1): a bucket would multiply the own-row scan."""
+    natural maximum over the corpus (at least 1); the served scan runs a
+    class's own D (``SizeClass.config_dfa_rows``)."""
     reach = own_reach(policy) if reach is None else reach
     is_dfa = policy.leaf_op == OP_REGEX_DFA
     own = [sorted({int(policy.leaf_dfa_row[l]) for l in leaves if is_dfa[l]})
@@ -601,6 +614,239 @@ def derive_own_layout(policy: "CompiledPolicy", reach=None,
     return OwnLayout(leaves=leaves, nodes=nodes, leaf_tab=tab,
                      levels=tuple(own_levels), evals=evals,
                      cpu_leaves=cpu_leaves)
+
+
+# The class rule (ISSUE 34).  A config's size is the bytes of corpus tables
+# one request row of it gathers: its own leaf, node, evaluator and DFA-row
+# table rows and the DFA transition tables it scans.  Configs are taken in
+# ascending size and a class is closed where the next config is CLASS_RATIO
+# times the class's smallest or more, so no row is evaluated at more than
+# that many times its own config's size.  Sizes under CLASS_FLOOR_BYTES
+# count as the floor: below it a launch costs its fixed overhead, not its
+# bytes, and a class of its own (one more launch a cut, one more warm grid)
+# buys nothing.  Both are properties of the rule, read from no flag and no
+# environment variable; a corpus of one size is one class.
+CLASS_RATIO = 4
+CLASS_FLOOR_BYTES = 64 * 1024
+
+
+@dataclass
+class SizeClass:
+    """The configs of one size class and their tables, at the class's own
+    widths (the natural maximum over its members on every axis): what
+    ``ops/pattern_eval.py`` ``eval_own`` gathers a member's row from.  A
+    config is in exactly one class; leaves, nodes and DFA rows are shared
+    across configs, so a DFA row may sit in several classes' stores."""
+
+    configs: np.ndarray           # [G_c] int32 config rows, ascending
+    cfg_local: np.ndarray         # [G] int32 config row -> table row (-1: not a member)
+    own: OwnLayout                # [G_c, ...] tables; evals [G_c, 3, E_c]
+    dfa_rows: np.ndarray          # [R_c] int32 corpus DFA rows of the store, ascending
+    config_dfa_rows: np.ndarray   # [G_c, D_c] int32 positions in dfa_rows (-1 pad)
+    dfa_tables: np.ndarray        # [T_c, S_c, 256] uint8
+    dfa_accept: np.ndarray        # [T_c, S_c] bool
+    dfa_table_of_row: np.ndarray  # [R_c] int32 store row -> table of the store
+
+    def shape_key(self) -> tuple:
+        return (self.own.shape_key(), self.own.evals.shape,
+                self.config_dfa_rows.shape, self.dfa_tables.shape)
+
+    def widths(self) -> Dict[str, int]:
+        """What /debug/vars lists of a class."""
+        has_dfa = bool(self.dfa_rows.size)
+        return {
+            "configs": int(self.configs.shape[0]),
+            "leaf_cols_per_row": int(self.own.leaves.shape[1]),
+            "dfa_rows_per_row": int(self.config_dfa_rows.shape[1]) if has_dfa else 0,
+            "dfa_states": int(self.dfa_tables.shape[1]) if has_dfa else 0,
+            "cpu_cols": int(self.own.cpu_leaves.shape[1]),
+            "evaluators": int(self.own.evals.shape[2]),
+        }
+
+
+def dfa_table_states(policy: "CompiledPolicy") -> np.ndarray:
+    """[T] int: each table's own state count, found from the table (padding
+    states self-loop and nothing real reaches them): 1 + the largest state
+    reachable from state 0."""
+    tables = policy.dfa_tables
+    T = tables.shape[0]
+    reach_max = np.maximum.accumulate(
+        tables.max(axis=2).astype(np.int64), axis=1)            # [T, S]
+    n = np.ones((T,), dtype=np.int64)
+    while True:
+        nxt = np.maximum(n, reach_max[np.arange(T), n - 1] + 1)
+        if (nxt == n).all():
+            return n
+        n = nxt
+
+
+def _natural_sizes(policy: "CompiledPolicy") -> Dict[str, Any]:
+    """Per config, what its own circuit takes on every axis of the own
+    layout, read off the corpus-wide padded tables (and each DFA table's
+    own state count, ``table_states``)."""
+    own = policy.own
+    trivial = (own.evals[:, 0] == TRUE_SLOT) & (own.evals[:, 2] == 0)  # [G, E]
+    E = trivial.shape[1]
+    last = np.where(trivial, 0, np.arange(1, E + 1)).max(axis=1, initial=0)
+    rows = policy.config_dfa_rows
+    table_states = dfa_table_states(policy)
+    states = np.zeros(rows.shape, dtype=np.int64)
+    if policy.n_byte_attrs and policy.dfa_tables.size:
+        per_row = table_states[policy.dfa_table_of_row]
+        states = np.where(rows >= 0, per_row[np.maximum(rows, 0)], 0)
+    return {
+        "table_states": table_states,
+        "leaves": (own.leaves >= 0).sum(axis=1),
+        "cpu": (own.cpu_leaves >= 0).sum(axis=1),
+        "nodes": [(n >= 0).sum(axis=1) for n in own.nodes],
+        "evals": np.maximum(last, 1),
+        "dfa": (rows >= 0).sum(axis=1),
+        "states": states.max(axis=1, initial=0),
+    }
+
+
+def _tile8(n) -> Any:
+    """The DFA state axis in whole device tiles (see compile_corpus)."""
+    return -(-np.maximum(n, 1) // 8) * 8
+
+
+def config_row_bytes(policy: "CompiledPolicy", sizes=None) -> np.ndarray:
+    """[G] int64: the class rule's size of each config (see CLASS_RATIO)."""
+    sizes = _natural_sizes(policy) if sizes is None else sizes
+    out = sizes["leaves"] * (OWN_FIELDS * 4) + sizes["evals"] * 12 \
+        + sizes["dfa"] * (4 + _tile8(sizes["states"]) * 256)
+    for n, (children, _) in zip(sizes["nodes"], policy.own.levels):
+        out = out + n * (int(children.shape[2]) * 4 + 1)
+    return out.astype(np.int64)
+
+
+def split_classes(row_bytes: np.ndarray) -> np.ndarray:
+    """[G] int32 class of each config by the class rule, classes numbered by
+    ascending size."""
+    w = np.maximum(np.asarray(row_bytes, dtype=np.int64), CLASS_FLOOR_BYTES)
+    order = np.argsort(w, kind="stable")
+    ws = w[order]
+    class_of = np.zeros(w.shape, dtype=np.int32)
+    i, c = 0, 0
+    while i < ws.shape[0]:
+        j = int(np.searchsorted(ws, CLASS_RATIO * ws[i], side="left"))
+        class_of[order[i:j]] = c
+        i, c = j, c + 1
+    return class_of
+
+
+def _class_of(policy: "CompiledPolicy", cfgs: np.ndarray, sizes,
+              natural: bool) -> SizeClass:
+    """Cut one class out of the corpus-wide padded layout: the member rows,
+    every axis narrowed to the members' natural maximum (``natural`` False:
+    left as the corpus's, which ShapeTargets forced so that shards stack),
+    own-buffer positions moved to the narrower buffer, and a DFA table store
+    of the rows the members reach, at their own state axis."""
+    own = policy.own
+    G = own.leaves.shape[0]
+    E = own.evals.shape[2]
+    wide_n = [int(n.shape[1]) for n in own.nodes]
+    l_w, c_w = int(own.leaves.shape[1]), int(own.cpu_leaves.shape[1])
+    D_w = int(policy.config_dfa_rows.shape[1])
+    if natural:
+        def most(a):
+            return max(int(a[cfgs].max(initial=0)), 1)
+
+        l_c, c_c, D_c = most(sizes["leaves"]), most(sizes["cpu"]), most(sizes["dfa"])
+        n_c = [most(n) for n in sizes["nodes"]]
+        E_c = min(_round_up(most(sizes["evals"]), minimum=2), E)
+    else:
+        l_c, c_c, D_c, n_c, E_c = l_w, c_w, D_w, wide_n, E
+    # corpus-wide own-buffer position -> the class's (a real reference names
+    # a real position, and those are a prefix of every region)
+    lut = np.full((_LEAF_BASE + l_w + sum(wide_n),), FALSE_SLOT, dtype=np.int32)
+    lut[TRUE_SLOT], lut[FALSE_SLOT] = TRUE_SLOT, FALSE_SLOT
+    lut[_LEAF_BASE:_LEAF_BASE + l_c] = np.arange(_LEAF_BASE, _LEAF_BASE + l_c)
+    wide_base, base = _LEAF_BASE + l_w, _LEAF_BASE + l_c
+    for n_wide, n in zip(wide_n, n_c):
+        lut[wide_base:wide_base + n] = np.arange(base, base + n)
+        wide_base, base = wide_base + n_wide, base + n
+    evals = own.evals[cfgs][:, :, :E_c].copy()
+    evals[:, :2] = lut[evals[:, :2]]
+    layout = OwnLayout(
+        leaves=np.ascontiguousarray(own.leaves[cfgs][:, :l_c]),
+        nodes=tuple(np.ascontiguousarray(n[cfgs][:, :k])
+                    for n, k in zip(own.nodes, n_c)),
+        leaf_tab=np.ascontiguousarray(own.leaf_tab[cfgs][:, :l_c]),
+        levels=tuple((lut[ch[cfgs][:, :k]], np.ascontiguousarray(a[cfgs][:, :k]))
+                     for (ch, a), k in zip(own.levels, n_c)),
+        evals=evals,
+        cpu_leaves=np.ascontiguousarray(own.cpu_leaves[cfgs][:, :c_c]))
+    cfg_local = np.full((G,), -1, dtype=np.int32)
+    cfg_local[cfgs] = np.arange(cfgs.shape[0], dtype=np.int32)
+
+    # the class's DFA store: the rows its members reach, the tables those
+    # rows name, the state axis of the largest of them
+    rows = policy.config_dfa_rows[cfgs][:, :D_c]
+    if natural:
+        dfa_rows = np.unique(rows[rows >= 0]).astype(np.int32)
+    else:
+        dfa_rows = np.arange(policy.dfa_table_of_row.shape[0], dtype=np.int32)
+    local_rows = np.where(
+        rows >= 0, np.searchsorted(dfa_rows, np.maximum(rows, 0)), -1
+    ).astype(np.int32)
+    if natural:
+        tabs, table_of_row = np.unique(policy.dfa_table_of_row[dfa_rows],
+                                       return_inverse=True)
+        S_c = int(_tile8(sizes["table_states"][tabs].max(initial=1))) \
+            if tabs.size else int(policy.dfa_tables.shape[1])
+        if not tabs.size:
+            tabs = np.zeros((1,), dtype=np.int64)   # no DFA row: never read
+    else:
+        tabs = np.arange(policy.dfa_tables.shape[0])
+        table_of_row = policy.dfa_table_of_row
+        S_c = int(policy.dfa_tables.shape[1])
+    return SizeClass(
+        configs=cfgs.astype(np.int32), cfg_local=cfg_local, own=layout,
+        dfa_rows=dfa_rows, config_dfa_rows=local_rows,
+        dfa_tables=np.ascontiguousarray(policy.dfa_tables[tabs][:, :S_c]),
+        dfa_accept=np.ascontiguousarray(policy.dfa_accept[tabs][:, :S_c]),
+        dfa_table_of_row=np.asarray(table_of_row, dtype=np.int32).reshape(-1))
+
+
+def derive_classes(policy: "CompiledPolicy",
+                   natural: bool = True) -> Tuple[SizeClass, ...]:
+    """The corpus's size classes, by the class rule, from ``policy.own`` and
+    ``policy.config_dfa_rows``.  ``natural`` False (a compile under
+    ShapeTargets): one class at the forced widths."""
+    sizes = _natural_sizes(policy)
+    G = policy.own.leaves.shape[0]
+    class_of = split_classes(config_row_bytes(policy, sizes)) if natural \
+        else np.zeros((G,), dtype=np.int32)
+    return tuple(
+        _class_of(policy, np.nonzero(class_of == c)[0], sizes, natural)
+        for c in range(int(class_of.max(initial=0)) + 1))
+
+
+def derive_layouts(policy: "CompiledPolicy", keep: bool = False,
+                   targets: Optional[ShapeTargets] = None) -> None:
+    """(Re)build everything the compiler derives from the corpus arrays:
+    ``config_dfa_rows``, the own-config layout and the size classes.
+    ``keep`` leaves a table that is already there as it is (a deserialized
+    or hand-built policy); ``targets`` widens the own axes and yields one
+    class."""
+    reach = None
+    if policy.config_dfa_rows is None or not keep:
+        reach = own_reach(policy)
+        rows = derive_config_dfa_rows(policy, reach)
+        if targets is not None:
+            assert targets.n_own_dfa_rows >= rows.shape[1], \
+                "targets.n_own_dfa_rows too small"
+            rows = np.pad(
+                rows, ((0, 0), (0, targets.n_own_dfa_rows - rows.shape[1])),
+                constant_values=-1)
+        policy.config_dfa_rows = rows
+    if policy.own is None or not keep:
+        policy.own = derive_own_layout(
+            policy, own_reach(policy) if reach is None else reach,
+            targets=targets)
+    if policy.classes is None or not keep:
+        policy.classes = derive_classes(policy, natural=targets is None)
 
 
 def _round_up(n: int, multiple: int = 8, minimum: int = 8) -> int:
@@ -1191,11 +1437,5 @@ def compile_corpus(
         ovf_assist=bool(ovf_assist),
     )
     if targets is not None:
-        own = policy.config_dfa_rows
-        assert targets.n_own_dfa_rows >= own.shape[1], \
-            "targets.n_own_dfa_rows too small"
-        policy.config_dfa_rows = np.pad(
-            own, ((0, 0), (0, targets.n_own_dfa_rows - own.shape[1])),
-            constant_values=-1)
-        policy.own = derive_own_layout(policy, targets=targets)
+        derive_layouts(policy, targets=targets)
     return policy

@@ -119,7 +119,7 @@ class PolicyModel:
     def apply(self, db: DeviceBatch) -> Tuple[np.ndarray, np.ndarray]:
         from ..ops.pattern_eval import _extra_operands
 
-        has_dfa = self.params["dfa_tables"] is not None
+        has_dfa = self.policy.n_byte_attrs > 0
         own, verdict = self._apply(
             self.params,
             jnp.asarray(db.attrs_val),
@@ -149,7 +149,7 @@ class PolicyModel:
     def forward_fn_and_args(self, batch: int = 64):
         """A jittable forward fn + realistic example args (for compile checks)."""
         db = self.encode([], [], batch_pad=batch)
-        has_dfa = self.params["dfa_tables"] is not None
+        has_dfa = self.policy.n_byte_attrs > 0
         attr_bytes = db.attr_bytes
         if has_dfa:
             # re-pad to the full byte budget: an empty batch trims to the

@@ -22,19 +22,27 @@ Three bodies, one selection:
     own config (``eval_full_jit`` and, through it, ``eval_packed_jit``,
     ``eval_bitpacked_jit`` — the served entry — and ``eval_fused_jit``, the
     engine lane's single-staging-buffer entry) runs it: ONE body that
-    gathers row ``config_id`` of the corpus's per-config tables
-    (compiler/compile.py ``OwnLayout``) and evaluates the request's own
-    [B, l_own] leaves, [B, n_own] circuit nodes a level and [B, E]
-    evaluators.  Its DFA scan covers ``config_dfa_rows[config_id]``, the
-    [B, D] rows the config reaches: their [S, 256] tables are gathered once
-    a launch from the deduplicated ``dfa_tables``, one batched matmul with
+    gathers the request's config's row of its SIZE CLASS's per-config
+    tables (compiler/compile.py ``SizeClass``: the configs cut into classes
+    of like size, each with tables of its own widths, so that a row pays
+    for its own config's size and not for the corpus's largest) and
+    evaluates the request's own [B, l_own] leaves, [B, n_own] circuit nodes
+    a level and [B, E] evaluators, at the class's l_own, n_own, E, D and S.
+    The native lane launches a cut's rows one class at a time
+    (``class_view``); handed the whole operands, every class evaluates every
+    row at its own widths, answers False for rows of no member, and the
+    answers are OR-ed.  Its DFA scan covers the class's
+    ``config_dfa_rows[row]``, the [B, D] rows the config reaches: their
+    [S, 256] tables are gathered once a launch from the class's
+    deduplicated ``dfa_tables``, one batched matmul with
     the byte one-hots gives every byte position's S -> S map, the
     ``lax.scan`` carries [D, B] (the batch on the lanes), and the accepts
     feed the own leaves directly.  Reads inside a row (attribute of a leaf,
     child of a node) are one-hot mask-reduces over the small own axes —
     integer-exact, no gather.
     Nothing in it grows with the corpus but the tables it gathers one row
-    from.
+    from, nor with the corpus's largest config but the largest class's
+    launches.
   - ``_eval_verdicts_matmul`` is the dense body of the callers that want
     every config's column (``to_device(dense=True)``: ``forward`` /
     ``_eval_jit`` / ``eval_batch_jit`` through models/policy_model.py, the
@@ -67,8 +75,10 @@ branches on their presence at trace time, so the bodies jit-cache
 independently.  No flag or environment variable names a body.
 
 The row payload's CPU lane is own-config too: ``cpu_dense`` is [B, c_own],
-column j the answer of leaf ``own.cpu_leaves[config_id, j]``; the dense
-bodies spread it onto the leaf axis with ``_cpu_full``.
+column j the answer of leaf ``own.cpu_leaves[config_id, j]`` (a config's
+columns are the first of its row, so a launch of one size class stages the
+class's c_own and the host encoders fill the corpus's); the dense bodies
+spread it onto the leaf axis with ``_cpu_full``.
 
 Membership overflow (arrays longer than K) and DFA byte overflow cannot be
 answered from the compact payload per-leaf; overflowed *requests* are flagged
@@ -117,7 +127,8 @@ from ..compiler.compile import (
 )
 
 __all__ = ["DevicePolicy", "to_device", "eval_verdicts", "eval_own",
-           "eval_batch_jit", "kernel_widths", "operand_bytes",
+           "eval_batch_jit", "kernel_widths", "class_widths", "class_view",
+           "has_dfa", "operand_bytes",
            "fuse_layout", "fuse_bytes", "fuse_batch", "eval_fused_jit",
            "dispatch_fused",
            "fused_h2d_supported", "eval_bitpacked_jit",
@@ -292,11 +303,56 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
         policy.member_attr_slot[policy.leaf_attr], 0
     ).astype(np.int32)
     own = policy.own
-    G_own = own.leaves.shape[0]
     # scatter targets of the row payload's CPU columns, per config; padding
     # columns land in a dump slot at L (sliced off) so they can never
     # clobber a real leaf
     own_cpu_leaf = np.where(own.cpu_leaves >= 0, own.cpu_leaves, L).astype(np.int32)
+    has_dfa_lane = bool(policy.n_byte_attrs)
+    # the dense gather body scans every class's DFA store and reads a leaf's
+    # accept from the stores laid end to end: leaf -> position there (its
+    # first store's; a row shared by two classes reads the same either way)
+    leaf_dfa_pos = np.zeros((L,), dtype=np.int32)
+    if has_dfa_lane:
+        store_rows = np.concatenate([c.dfa_rows for c in policy.classes])
+        pos_of_row = np.zeros((policy.dfa_table_of_row.shape[0],), dtype=np.int32)
+        # last to first, so that a shared row keeps its first store's place
+        pos_of_row[store_rows[::-1]] = np.arange(store_rows.shape[0])[::-1]
+        is_dfa = policy.leaf_op == OP_REGEX_DFA
+        leaf_dfa_pos[is_dfa] = pos_of_row[policy.leaf_dfa_row[is_dfa]]
+
+    def class_operands(cls) -> dict:
+        """One size class's tables (compiler/compile.py SizeClass): what
+        ``eval_own`` gathers a member's row from.  One ROW a config: the
+        device pads the two minor axes of an array to its tiles, so
+        [G, 10, 10] would take 20 times its bytes and be copied whole by
+        every launch's gather."""
+        o = cls.own
+        G_c = o.leaves.shape[0]
+        dfa = has_dfa_lane and bool(cls.dfa_rows.size)
+        return {
+            # config row -> row of this class's tables (-1: another class's)
+            "cfg_local": put(cls.cfg_local),
+            "own": {
+                "leaf": put(o.leaf_tab.reshape(G_c, -1)),           # [G_c, l_own * F]
+                "levels": tuple((put(c.reshape(G_c, -1)), put(a))   # [G_c, n * w], [G_c, n]
+                                for c, a in o.levels),
+                "evals": put(o.evals.reshape(G_c, -1)),             # [G_c, 3 * E_c]
+            },
+            # device regex lane of the class; None (a static pytree node,
+            # not a traced leaf) when its members reach no DFA row, so the
+            # kernel's python-level `is None` check specializes at trace
+            # time.  Tables travel DEDUPED ([T_c, S_c, 256] + the store's
+            # row -> table map): identical regexes across the class's
+            # AuthConfigs upload exactly one transition table.
+            "dfa_tables": put(cls.dfa_tables) if dfa else None,
+            "dfa_accept": put(cls.dfa_accept) if dfa else None,
+            "dfa_table_of_row": put(cls.dfa_table_of_row) if dfa else None,
+            "dfa_byte_slot": put(dfa_byte_slot[cls.dfa_rows].astype(np.int32))
+            if dfa else None,
+            # own-row scan: per member, the store rows its circuits reach
+            "config_dfa_rows": put(cls.config_dfa_rows) if dfa else None,
+        }
+
     # operands are numpy throughout: `put` is the ONLY device transfer (or a
     # no-op for host=True), so nothing ever stages on the default device
     return {
@@ -306,16 +362,9 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
         "leaf_const": put(policy.leaf_const),
         "member_slot_of_leaf": put(member_slot_of_leaf),
         "own_cpu_leaf": put(own_cpu_leaf),
-        # per-config tables of eval_own (compiler/compile.py OwnLayout), one
-        # ROW a config: the device pads the two minor axes of an array to
-        # its tiles, so [G, 10, 10] would take 20 times its bytes and be
-        # copied whole by every launch's gather
-        "own": {
-            "leaf": put(own.leaf_tab.reshape(G_own, -1)),        # [G, l_own * F]
-            "levels": tuple((put(c.reshape(G_own, -1)), put(a))  # [G, n * w], [G, n]
-                            for c, a in own.levels),
-            "evals": put(own.evals.reshape(G_own, -1)),          # [G, 3 * E]
-        },
+        # the size classes' tables: eval_own evaluates a row at its own
+        # config's class's widths (``class_view`` hands a launch one class)
+        "classes": tuple(class_operands(cls) for cls in policy.classes),
         "levels": tuple(
             (put(children), put(is_and))
             for children, is_and in policy.levels
@@ -323,20 +372,9 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
         "eval_cond": put(policy.eval_cond),
         "eval_rule": put(policy.eval_rule),
         "eval_has_cond": put(policy.eval_has_cond),
-        # device regex lane; None (a static pytree node, not a traced leaf)
-        # when the corpus has no DFA-compilable regexes, so the kernel's
-        # python-level `is None` check specializes at trace time.  Tables
-        # travel DEDUPED ([T, S, 256] + dfa_table_of_row): the gather lane
-        # indexes through the row→table map on device, so identical regexes
-        # across AuthConfigs upload exactly one transition table.
-        "dfa_tables": put(policy.dfa_tables) if policy.n_byte_attrs else None,
-        "dfa_accept": put(policy.dfa_accept) if policy.n_byte_attrs else None,
-        "dfa_table_of_row": put(policy.dfa_table_of_row)
-        if policy.n_byte_attrs else None,
-        "dfa_byte_slot": put(dfa_byte_slot.astype(np.int32)) if policy.n_byte_attrs else None,
-        "leaf_dfa_row": put(policy.leaf_dfa_row) if policy.n_byte_attrs else None,
-        # own-row scan: per config, the DFA rows its circuits reach (-1 pad)
-        "config_dfa_rows": put(policy.config_dfa_rows) if policy.n_byte_attrs else None,
+        # dense gather body: leaf -> row of the classes' DFA stores laid end
+        # to end; None when the corpus has no DFA-compilable regexes
+        "leaf_dfa_row": put(leaf_dfa_pos) if has_dfa_lane else None,
         # numeric comparator lane (ISSUE 14): leaf → compact value slot;
         # the constants ride leaf_const (folded int32 at compile time)
         "leaf_num_slot": put(np.maximum(
@@ -351,6 +389,27 @@ def to_device(policy: CompiledPolicy, device=None, lane: Optional[str] = None,
         "leaf_rel_col": put(policy.leaf_rel_col)
         if getattr(policy, "n_rel_slots", 0) else None,
     }
+
+
+def has_dfa(params) -> bool:
+    """Whether the operands carry a device regex lane (structural)."""
+    return params.get("leaf_dfa_row") is not None or any(
+        c["dfa_tables"] is not None for c in params["classes"])
+
+
+# what eval_own reads beside a class's tables
+_VIEW_KEYS = ("matmul", "leaf_num_slot", "rel_bits")
+
+
+def class_view(params, c: int) -> dict:
+    """The operands of ONE size class, for a launch whose rows are all that
+    class's: the served entries run it at the class's widths and return the
+    class's [B, 1 + 2 * E_c] columns.  Config ids stay the corpus's."""
+    view = {k: params.get(k) for k in _VIEW_KEYS}
+    if view["matmul"] is not None:
+        view["matmul"] = {"mxu": view["matmul"]["mxu"]}
+    view["classes"] = (params["classes"][c],)
+    return view
 
 
 DevicePolicy = dict
@@ -429,23 +488,45 @@ def _verdict_from_tables(params, cond, rule):
     return verdict, (rule, skipped)
 
 
+def class_widths(cp) -> dict:
+    """One class's widths, off its operands (``to_device``'s class dict)."""
+    own = cp["own"]
+    dfa = cp["dfa_tables"] is not None
+    return {
+        "configs": int(own["leaf"].shape[-2]),
+        "leaf_cols_per_row": int(own["leaf"].shape[-1]) // OWN_FIELDS,
+        "dfa_rows_per_row": int(cp["config_dfa_rows"].shape[-1]) if dfa else 0,
+        "dfa_states": int(cp["dfa_tables"].shape[-2]) if dfa else 0,
+        "evaluators": int(own["evals"].shape[-1]) // 3,
+        "operand_bytes": operand_bytes(cp),
+    }
+
+
 def kernel_widths(params, own: bool = True) -> dict:
     """What /debug/vars reports of one served row's work: ``dfa_rows_total``
-    is the corpus's R and ``dfa_rows_per_row`` what one request row has
-    scanned (D on ``eval_own``, R on a dense body; both 0 without a device
-    DFA lane); ``leaf_cols_per_row`` is the leaf columns evaluated for it
-    (l_own, or L while dense); ``dfa_states`` is S, the state axis of the
-    served table store (every table padded to the largest DFA's count, in
-    whole tiles of 8).  ``own``: an entry that returns own-config
-    results (False: the mesh step, which is dense)."""
-    out = {"dfa_rows_per_row": 0, "dfa_rows_total": 0, "dfa_states": 0,
-           "leaf_cols_per_row": int(params["own"]["leaf"].shape[-1]) // OWN_FIELDS if own
-           else int(params["leaf_op"].shape[-1])}
-    if params.get("dfa_tables") is not None:
-        R = int(params["dfa_table_of_row"].shape[-1])
-        out.update(dfa_rows_total=R, dfa_rows_per_row=int(
-            params["config_dfa_rows"].shape[-1]) if own else R,
-            dfa_states=int(params["dfa_tables"].shape[-2]))
+    is the rows of the DFA stores and ``dfa_rows_per_row`` what one request
+    row has scanned (its class's D on ``eval_own``, R on a dense body; both
+    0 without a device DFA lane); ``leaf_cols_per_row`` is the leaf columns
+    evaluated for it (its class's l_own, or L while dense); ``dfa_states``
+    is S, the state axis of its class's table store (every table of a class
+    padded to the class's largest DFA's count, in whole tiles of 8).  With
+    more than one size class the scalars read the LARGEST class (what the
+    costliest row pays; one class: the corpus's) and ``classes`` lists every
+    class's own.  ``own``: an entry that returns own-config results (False:
+    the mesh step, which is dense)."""
+    classes = [class_widths(cp) for cp in params["classes"]]
+    R = sum(int(cp["dfa_table_of_row"].shape[-1]) for cp in params["classes"]
+            if cp["dfa_tables"] is not None)
+    out = {"dfa_rows_per_row": 0, "dfa_rows_total": R,
+           "dfa_states": max(c["dfa_states"] for c in classes),
+           "leaf_cols_per_row": int(params["leaf_op"].shape[-1])}
+    if own:
+        out.update(
+            leaf_cols_per_row=max(c["leaf_cols_per_row"] for c in classes),
+            dfa_rows_per_row=max(c["dfa_rows_per_row"] for c in classes),
+            classes=classes)
+    elif R:
+        out["dfa_rows_per_row"] = R
     return out
 
 
@@ -467,9 +548,10 @@ def _pick(x, idx):
 
 
 def _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt):
-    """Own-row DFA scan: evaluates only the DFA rows ``config_dfa_rows[cfg]``
-    names and returns their accepts [B, D], False on the -1 padding and on
-    rows whose config id was out of range (``cfg`` is the clipped id).
+    """Own-row DFA scan of one size class (``params``: its operands):
+    evaluates only the DFA rows ``config_dfa_rows[cfg]`` names and returns
+    their accepts [B, D], False on the -1 padding and on rows of no member
+    of the class (``cfg`` is the clipped table row).
 
     The sequential part keeps the batch on the minor axis: its carry is
     [D, B] and a step reads [D, S, B], so B fills the lanes and S (whole
@@ -519,16 +601,54 @@ def eval_own(params, attrs_val, members_c, cpu_dense, config_id,
     """Each request against its OWN config only (see module doc): returns
     (own verdict [B], own rule results [B, E], own skipped flags [B, E]),
     bit for bit what selecting row ``config_id`` of the dense body's
-    results gives; a config id outside [0, G) reads False throughout."""
+    results gives; a config id outside [0, G) reads False throughout.
+
+    One class at a time: every class of ``params["classes"]`` evaluates the
+    batch at its own widths and answers False for rows of no member, and
+    the answers are OR-ed.  The served launches hand it ``class_view``s (one
+    class, that class's rows, E = the class's); whole operands evaluate
+    every row at every class's widths and return the corpus's E columns."""
     if attrs_val.dtype != jnp.int32:
         attrs_val = attrs_val.astype(jnp.int32)
     if members_c.dtype != jnp.int32:
         members_c = members_c.astype(jnp.int32)
-    own = params["own"]
+    mm = params.get("matmul")
+    cdt = mm["mxu"].dtype if mm is not None else jnp.float32
+    operands = (attrs_val, members_c, cpu_dense, config_id, attr_bytes,
+                byte_ovf, attrs_num, num_valid, rel_rows, member_ovf)
+    parts = [_eval_own_class(params, cp, cdt, *operands)
+             for cp in params["classes"]]
+    E = max(rule.shape[1] for _, rule, _, _ in parts)
+    if params.get("eval_rule") is not None:
+        E = params["eval_rule"].shape[-1]
+    out = None
+    for verdict, rule, skipped, member in parts:
+        extra = E - rule.shape[1]
+        if extra:
+            # evaluator columns past the class's read TRUE_SLOT in the
+            # corpus-wide layout: rule True, never skipped
+            B = rule.shape[0]
+            rule = jnp.concatenate(
+                [rule, jnp.broadcast_to(member[:, None], (B, extra))], axis=1)
+            skipped = jnp.concatenate(
+                [skipped, jnp.zeros((B, extra), dtype=bool)], axis=1)
+        out = (verdict, rule, skipped) if out is None else (
+            out[0] | verdict, out[1] | rule, out[2] | skipped)
+    return out
+
+
+def _eval_own_class(params, cp, cdt, attrs_val, members_c, cpu_dense,
+                    config_id, attr_bytes, byte_ovf, attrs_num, num_valid,
+                    rel_rows, member_ovf):
+    """``eval_own`` at one class's widths: (verdict, rule [B, E_c], skipped
+    [B, E_c], member [B]), all False on rows whose config is no member."""
+    own = cp["own"]
     B = attrs_val.shape[0]
-    G = own["leaf"].shape[0]
-    in_range = (config_id >= 0) & (config_id < G)
-    cfg = jnp.clip(config_id, 0, G - 1)
+    local = cp["cfg_local"]                                  # [G]
+    G = local.shape[0]
+    row = jnp.take(local, jnp.clip(config_id, 0, G - 1))     # [B] table row
+    in_range = (config_id >= 0) & (config_id < G) & (row >= 0)
+    cfg = jnp.maximum(row, 0)
 
     with jax.named_scope("own_gather"):
         tab = jnp.take(own["leaf"], cfg, axis=0).reshape(B, -1, OWN_FIELDS)
@@ -550,10 +670,8 @@ def eval_own(params, attrs_val, members_c, cpu_dense, config_id,
         if member_ovf is not None:
             leaf_movf = _pick(member_ovf, tab[..., OWN_MEMBER])
     with jax.named_scope("dfa_scan"):
-        if params["dfa_tables"] is not None and attr_bytes is not None:
-            mm = params.get("matmul")
-            cdt = mm["mxu"].dtype if mm is not None else jnp.float32
-            own_res = _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt)
+        if cp["dfa_tables"] is not None and attr_bytes is not None:
+            own_res = _own_dfa_row_res(cp, cfg, in_range, attr_bytes, cdt)
             # overflowed values: exact answer precomputed into the CPU lane
             dfa_leaf_val = jnp.where(_pick(byte_ovf, tab[..., OWN_BYTE]),
                                      cpu_lane, _pick(own_res, tab[..., OWN_DFA]))
@@ -587,7 +705,7 @@ def eval_own(params, attrs_val, members_c, cpu_dense, config_id,
         skipped = (evals[:, 2] != 0) & ~_pick(buffer, evals[:, 1])
         verdict = jnp.all(skipped | rule, axis=-1)
         return (verdict & in_range, rule & in_range[:, None],
-                skipped & in_range[:, None])
+                skipped & in_range[:, None], in_range)
 
 
 # ---------------------------------------------------------------------------
@@ -619,7 +737,7 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense, config_id,
 
     # ---- device regex lane: DFA scan, transitions as batched matmuls -----
     with jax.named_scope("dfa_scan"):
-        if params["dfa_tables"] is not None and attr_bytes is not None:
+        if "dfa_tables_f" in mm and attr_bytes is not None:
             tables = mm["dfa_tables_f"]                          # [R, S, 256] bf16
             R, S = tables.shape[0], tables.shape[1]
             # spread each row's attr bytes from its slot: [B, NB, LB] → [B, R, LB]
@@ -750,23 +868,32 @@ def _eval_verdicts_gather(params, attrs_val, members_c, cpu_dense, config_id,
 
     # ---- device regex lane: DFA scan over value bytes --------------------
     with jax.named_scope("dfa_scan"):
-        if params["dfa_tables"] is not None and attr_bytes is not None:
-            tables = params["dfa_tables"]          # [T, S, 256] uint8 (deduped)
-            # per-row table index: rows sharing an automaton share one table
-            tab_idx = params["dfa_table_of_row"][None, :]        # [1, R]
-            row_bytes = jnp.take(attr_bytes, params["dfa_byte_slot"], axis=1)  # [B, R, LB]
+        if params["leaf_dfa_row"] is not None and attr_bytes is not None:
+            # every size class's store in turn, their rows laid end to end
+            # (``leaf_dfa_row`` indexes that axis)
+            row_res, row_slot = [], []
+            for cp in params["classes"]:
+                if cp["dfa_tables"] is None:
+                    continue
+                tables = cp["dfa_tables"]       # [T_c, S_c, 256] uint8 (deduped)
+                # per-row table index: rows sharing an automaton share one table
+                tab_idx = cp["dfa_table_of_row"][None, :]        # [1, R_c]
+                row_bytes = jnp.take(attr_bytes, cp["dfa_byte_slot"], axis=1)  # [B, R_c, LB]
 
-            def dfa_step(states, byte_col):  # states [B,R] i32, byte_col [B,R] u8
-                nxt = tables[tab_idx, states, byte_col.astype(jnp.int32)]
-                return nxt.astype(jnp.int32), None
+                def dfa_step(states, byte_col, tables=tables, tab_idx=tab_idx):
+                    # states [B,R_c] i32, byte_col [B,R_c] u8
+                    nxt = tables[tab_idx, states, byte_col.astype(jnp.int32)]
+                    return nxt.astype(jnp.int32), None
 
-            # init carry derived from a varying input (zero-multiplied) so its
-            # manual-mesh "varying" type matches inside shard_map
-            init = (row_bytes[:, :, 0] * 0).astype(jnp.int32)
-            final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
-            dfa_row_res = params["dfa_accept"][tab_idx, final]   # [B, R]
+                # init carry derived from a varying input (zero-multiplied) so
+                # its manual-mesh "varying" type matches inside shard_map
+                init = (row_bytes[:, :, 0] * 0).astype(jnp.int32)
+                final, _ = jax.lax.scan(dfa_step, init, jnp.transpose(row_bytes, (2, 0, 1)))
+                row_res.append(cp["dfa_accept"][tab_idx, final])  # [B, R_c]
+                row_slot.append(cp["dfa_byte_slot"])
+            dfa_row_res = jnp.concatenate(row_res, axis=1)
             leaf_dfa = jnp.take(dfa_row_res, params["leaf_dfa_row"], axis=1)  # [B, L]
-            leaf_slot = jnp.take(params["dfa_byte_slot"], params["leaf_dfa_row"])
+            leaf_slot = jnp.take(jnp.concatenate(row_slot), params["leaf_dfa_row"])
             leaf_bovf = jnp.take(byte_ovf, leaf_slot, axis=1)    # [B, L]
             dfa_leaf_val = jnp.where(leaf_bovf, cpu_lane, leaf_dfa)
         else:
@@ -998,7 +1125,7 @@ def dispatch_packed(params, db, bitpack: bool = False) -> "jax.Array":
     [B, W] uint8 bitmask with ``bitpack=True`` — for a deferred readback
     (jax async dispatch = transfer/compute of batch N+1 overlaps the
     readback of batch N)."""
-    has_dfa = params["dfa_tables"] is not None
+    dfa = has_dfa(params)
     fn = eval_bitpacked_jit if bitpack else eval_packed_jit
     return fn(
         params,
@@ -1006,8 +1133,8 @@ def dispatch_packed(params, db, bitpack: bool = False) -> "jax.Array":
         jnp.asarray(db.members_c),
         jnp.asarray(db.cpu_dense),
         jnp.asarray(db.config_id),
-        jnp.asarray(db.attr_bytes) if has_dfa else None,
-        jnp.asarray(db.byte_ovf) if has_dfa else None,
+        jnp.asarray(db.attr_bytes) if dfa else None,
+        jnp.asarray(db.byte_ovf) if dfa else None,
         *_extra_operands(db),
     )
 
@@ -1177,15 +1304,15 @@ def dispatch_fused(params, db) -> "jax.Array":
 def eval_batch_jit(params, db) -> Tuple[np.ndarray, np.ndarray]:
     """Convenience wrapper: compact batch (compiler/pack.py DeviceBatch) →
     (own verdicts [B], full verdict matrix [B, G]) as numpy."""
-    has_dfa = params["dfa_tables"] is not None
+    dfa = has_dfa(params)
     own, verdict = _eval_jit(
         params,
         jnp.asarray(db.attrs_val),
         jnp.asarray(db.members_c),
         jnp.asarray(db.cpu_dense),
         jnp.asarray(db.config_id),
-        jnp.asarray(db.attr_bytes) if has_dfa else None,
-        jnp.asarray(db.byte_ovf) if has_dfa else None,
+        jnp.asarray(db.attr_bytes) if dfa else None,
+        jnp.asarray(db.byte_ovf) if dfa else None,
         *_extra_operands(db),
     )
     return np.asarray(own), np.asarray(verdict)

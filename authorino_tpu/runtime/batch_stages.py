@@ -68,7 +68,12 @@ class BatchTimeline:
         self._span = None
 
     def stage(self, name: str) -> "BatchTimeline":
-        self._k = _INDEX[name]
+        k = self._k = _INDEX[name]
+        if self.t[k + 1]:
+            # the stage runs again (a cut's second launch has an `encode`
+            # and a `launch` of its own): it starts where the last one
+            # ended, and counts once more
+            self.t[k] = max(self.t)
         return self
 
     def __enter__(self) -> "BatchTimeline":

@@ -3190,7 +3190,7 @@ class PolicyEngine:
         rows = [policy.config_ids[name] for name in names]
         enc = encode_batch(policy, docs, rows, batch_pad=pad)
         db = pack_batch(policy, enc)
-        has_dfa = snap.params["dfa_tables"] is not None
+        has_dfa = policy.n_byte_attrs > 0
         cacheable = policy.config_cacheable
         keys = (batch_row_keys(db, n)
                 if n and (self.batch_dedup or self._verdict_cache is not None)

@@ -51,7 +51,7 @@ _FIELDS = (
     "batches", "launches", "zero_launch_batches", "rows", "device_rows",
     "h2d_transfers", "h2d_bytes", "d2h_bytes", "pad_rows", "pad_waste_rows",
     "eff_slack_cols", "dedup_avoided_rows", "cache_avoided_rows",
-    "dfa_ovf_rows",
+    "dfa_ovf_rows", "own_dfa_slots", "own_dfa_rows",
 )
 
 
@@ -89,7 +89,8 @@ class CostLedger:
                 pad_rows: int = 0, eff_slack_cols: int = 0,
                 dedup_avoided_rows: int = 0,
                 cache_avoided_rows: int = 0,
-                dfa_ovf_rows: int = 0) -> None:
+                dfa_ovf_rows: int = 0, own_dfa_slots: int = 0,
+                own_dfa_rows: int = 0) -> None:
         """Fold one batch: ``rows`` real requests in the cut, of which
         ``device_rows`` actually shipped (``pad_rows`` after padding) in
         ``launches`` device calls, their request operands handed to the
@@ -97,7 +98,11 @@ class CostLedger:
         launch staged one buffer, one an operand otherwise);
         ``dfa_ovf_rows`` of the cut's rows carried a value past
         DFA_VALUE_BYTES, whose DFAs the encoder scanned on the host
-        (the native lane counts them).  Host/degrade
+        (the native lane counts them); ``own_dfa_slots`` DFA rows were
+        scanned by the cut's launches (pad rows x the launch's size class's
+        D), of which ``own_dfa_rows`` are DFA rows the launched rows' own
+        configs have: the rest is padding to the class's largest member
+        (the native lane counts both).  Host/degrade
         evals and fully cache/dedup-resolved cuts fold with launches=0 and
         zero byte counts.
         The mesh lane folds its batch here with launches=0 and counts the
@@ -124,6 +129,8 @@ class CostLedger:
             lc.dedup_avoided_rows += dedup_avoided_rows
             lc.cache_avoided_rows += cache_avoided_rows
             lc.dfa_ovf_rows += dfa_ovf_rows
+            lc.own_dfa_slots += own_dfa_slots
+            lc.own_dfa_rows += own_dfa_rows
         metrics_mod.observe_kernel_cost(
             lane, launches, h2d_bytes, d2h_bytes, pad_waste)
 
@@ -236,11 +243,13 @@ def modeled_entry_cost(entry: str, fn, args: tuple, pad: int,
     }
 
 
-def _bitpacked_zero_args(policy, params, pad: int, eff: int) -> tuple:
+def _bitpacked_zero_args(policy, params, pad: int, eff: int,
+                         n_cpu: Optional[int] = None) -> tuple:
     """Throwaway zero operands for eval_bitpacked_jit at one (pad, eff)
     bucket, shapes only (PR 14 operand tail rides on the params'
     structural Nones): what the cost model lowers it with, and the native
-    lane's warm grid where it compiles the six-operand entry."""
+    lane's warm grid where it compiles the six-operand entry.  ``n_cpu``:
+    the CPU columns staged (a size class's c_own; None: the corpus's)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -249,7 +258,8 @@ def _bitpacked_zero_args(policy, params, pad: int, eff: int) -> tuple:
 
     dt = wire_dtype(policy)
     A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
-    C, NB = policy.n_own_cpu, max(policy.n_byte_attrs, 1)
+    C = policy.n_own_cpu if n_cpu is None else n_cpu
+    NB = max(policy.n_byte_attrs, 1)
     return (
         params,
         jnp.asarray(np.zeros((pad, A), dtype=dt)),
@@ -377,10 +387,9 @@ class CostModel:
         if policy is None or params is None:
             return {}
         from ..compiler.compile import DFA_VALUE_BYTES
-        from ..ops.pattern_eval import eval_bitpacked_jit
+        from ..ops.pattern_eval import eval_bitpacked_jit, has_dfa
 
-        has_dfa = params.get("dfa_tables") is not None
-        eff = DFA_VALUE_BYTES if has_dfa else 0
+        eff = DFA_VALUE_BYTES if has_dfa(params) else 0
         fp = params_fingerprint(params)
         args = _bitpacked_zero_args(policy, params, pad, eff)
         cost = modeled_entry_cost("eval_bitpacked", eval_bitpacked_jit,

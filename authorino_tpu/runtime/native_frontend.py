@@ -596,6 +596,48 @@ def fast_lane_eligible(entry, policy: Optional[CompiledPolicy]) -> Optional[Fast
     return spec
 
 
+class _Launched:
+    """The device launches of one single-corpus cut, one a size class
+    present: ``parts`` holds (result handle [pad, W_c] uint8, positions
+    among the cut's launched rows or None = all of them in order, rows
+    launched, the class's evaluator columns E_c)."""
+
+    __slots__ = ("parts",)
+
+    def __init__(self) -> None:
+        self.parts: List[tuple] = []
+
+    def is_ready(self) -> bool:
+        for handle, _, _, _ in self.parts:
+            ready = getattr(handle, "is_ready", None)
+            if ready is not None and not ready():
+                return False
+        return True
+
+    def unpack(self, u: int, attribute: bool):
+        """(verdict [u] uint8, firing [u] int32 or None) of the ``u``
+        launched rows, each part decoded at its class's width and put back
+        at its rows' positions."""
+        from ..ops.pattern_eval import unpack_attribution
+
+        verdict = firing = None
+        for handle, at, n, E in self.parts:
+            packed = np.asarray(handle)[:n]
+            if attribute:
+                v, f = unpack_attribution(packed, E)
+            else:
+                v, f = (packed[:, 0] & 1).astype(np.uint8), None
+            if at is None:
+                return np.ascontiguousarray(v), f
+            if verdict is None:
+                verdict = np.zeros((u,), dtype=np.uint8)
+                firing = np.full((u,), -1, dtype=np.int32) if attribute else None
+            verdict[at] = v
+            if f is not None:
+                firing[at] = f
+        return verdict, firing
+
+
 @dataclass
 class _SnapRec:
     snap_id: int
@@ -607,8 +649,10 @@ class _SnapRec:
     keepalive: List[np.ndarray] = field(default_factory=list)
     fc_rows: Optional[np.ndarray] = None
     row_labels: Dict[int, Tuple[str, str]] = field(default_factory=dict)
-    # jit bucket variants already compiled for this snapshot's params:
-    # (batch_pad, byte_eff) pairs; 0 byte_eff = no DFA lane.  _dispatch only
+    # jit bucket variants already compiled for this snapshot's params, for
+    # EVERY size class: (batch_pad, byte_eff) pairs; 0 byte_eff = no DFA
+    # lane (a class whose members reach no DFA row stages no bytes at any
+    # byte_eff).  _dispatch only
     # uses warmed shapes (rounding up) so XLA compiles never land on live
     # requests (the precompile-at-reconcile discipline,
     # ref pkg/evaluators/authorization/opa.go:141)
@@ -617,10 +661,22 @@ class _SnapRec:
     # temporaries one launch of the largest warm variant allocates, from the
     # compiled entry's memory_analysis() at the swap gate (0: not given)
     launch_temp_bytes: int = 0
-    # (batch_pad, byte_eff) -> the static layout of that variant's staging
-    # buffer (_stage_layout): built once, read by the warm grid and by
-    # every launch, so both name the same jit variant
-    layouts: Dict[Tuple[int, int], tuple] = field(default_factory=dict)
+    # (size class, batch_pad, byte_eff) -> the static layout of that
+    # variant's staging buffer (_stage_layout): built once, read by the warm
+    # grid and by every launch, so both name the same jit variant
+    layouts: Dict[Tuple[int, int, int], tuple] = field(default_factory=dict)
+    # size classes (compiler/compile.py SizeClass), single corpus: a launch
+    # carries the rows of ONE class and runs that class's operands
+    # (``views[c]``, ops/pattern_eval.py class_view) at its widths.
+    # class_of: config row -> class; classes: each class's widths
+    # (SizeClass.widths(): `cpu_cols` the CPU columns staged a row,
+    # `dfa_rows_per_row` the own-row scan's D, 0 = its members reach no DFA
+    # row, `evaluators` the evaluator columns read back); cfg_dfa_n: config
+    # row -> DFA rows its own circuit reaches
+    views: List[Any] = field(default_factory=list)
+    class_of: Optional[np.ndarray] = None
+    classes: List[Dict[str, int]] = field(default_factory=list)
+    cfg_dfa_n: Optional[np.ndarray] = None
     # first kernel lowering/compile failure of this snapshot's warm grid
     # (swap gate or background rest): surfaced on /debug/vars and /readyz,
     # never only in the log — a kernel that cannot compile must not look
@@ -1143,21 +1199,27 @@ class NativeFrontend:
                     **kernel_widths(view, own=False)}
         if rec.params is None:
             return None
-        return {"lane": kernel_lane_of(rec.params),
-                # the jitted function a launch runs, which a device trace
-                # finds the served XLA module by: the staged entry, or the
-                # six-operand one where the staging probe failed
-                "entry": ("eval_bitpacked_staged" if rec.layouts
-                          else "eval_bitpacked"),
-                # bytes of the serving snapshot's device operands, summed
-                # over the uploaded pytree
-                "operand_bytes": operand_bytes(rec.params),
-                # temporaries of one launch of the largest warm variant
-                "launch_temp_bytes": rec.launch_temp_bytes,
-                # what the served entry evaluates for ONE request row (its
-                # own config's leaves and DFA rows) against the corpus's,
-                # and the state axis of the DFA table store
-                **kernel_widths(rec.params)}
+        out = {"lane": kernel_lane_of(rec.params),
+               # the jitted function a launch runs, which a device trace
+               # finds the served XLA module by: the staged entry, or the
+               # six-operand one where the staging probe failed
+               "entry": ("eval_bitpacked_staged" if rec.layouts
+                         else "eval_bitpacked"),
+               # bytes of the serving snapshot's device operands, summed
+               # over the uploaded pytree
+               "operand_bytes": operand_bytes(rec.params),
+               # temporaries of one launch of the largest warm variant of
+               # the largest size class
+               "launch_temp_bytes": rec.launch_temp_bytes,
+               # what the served entry evaluates for ONE request row (its
+               # own config's class's leaves and DFA rows) against the
+               # corpus's, and the state axis of the class's DFA table
+               # store: the scalars read the largest class, `classes`
+               # lists each
+               **kernel_widths(rec.params)}
+        for widths, mine in zip(out["classes"], rec.classes):
+            widths["cpu_cols"] = mine["cpu_cols"]
+        return out
 
     @property
     def warm_error(self) -> Optional[str]:
@@ -1299,7 +1361,7 @@ class NativeFrontend:
         if rec.sharded is not None:
             has_dfa = rec.sharded.has_dfa
         else:
-            has_dfa = rec.params is not None and rec.params["dfa_tables"] is not None
+            has_dfa = any(w["dfa_rows_per_row"] for w in rec.classes)
         effs: List[int] = [0]
         if has_dfa:
             effs = []
@@ -1342,55 +1404,68 @@ class NativeFrontend:
             jax.block_until_ready(out)
             rec.warm.add((pad, eff))
             return
-        layout = self._stage_layout(rec, pad, eff)
-        if layout is not None:
-            size = layout[-1][3] + layout[-1][4]
-            fn = eval_bitpacked_staged_jit
-            args = (rec.params, jnp.asarray(np.zeros(size, dtype=np.uint8)),
-                    layout)
-        else:
-            fn = eval_bitpacked_jit
-            args = _bitpacked_zero_args(rec.policy, rec.params, pad, eff)
-        if not rec.warm:
-            rec.launch_temp_bytes = launch_temp_bytes(fn, *args)
-        out = fn(*args)
-        jax.block_until_ready(out)
+        first = not rec.warm
+        for c, view in enumerate(rec.views):
+            # every size class's variant of the bucket: a cut's launch of
+            # any class then finds its shape compiled
+            eff_c = eff if rec.classes[c]["dfa_rows_per_row"] else 0
+            layout = self._stage_layout(rec, c, pad, eff_c)
+            if layout is not None:
+                size = layout[-1][3] + layout[-1][4]
+                fn = eval_bitpacked_staged_jit
+                args = (view, jnp.asarray(np.zeros(size, dtype=np.uint8)),
+                        layout)
+            else:
+                fn = eval_bitpacked_jit
+                args = _bitpacked_zero_args(rec.policy, view, pad, eff_c,
+                                            n_cpu=rec.classes[c]["cpu_cols"])
+            if first:
+                rec.launch_temp_bytes = max(rec.launch_temp_bytes,
+                                            launch_temp_bytes(fn, *args))
+            out = fn(*args)
+            jax.block_until_ready(out)
         rec.warm.add((pad, eff))
 
     @staticmethod
-    def _operand_views(a: Dict[str, np.ndarray], rows, eff: int) -> list:
+    def _operand_views(a: Dict[str, np.ndarray], rows, eff: int,
+                       n_cpu: int) -> list:
         """The request operands of one single-corpus launch as host arrays,
         in the order the jitted entries take them: rows ``rows`` of the slot
         arrays ``a`` (``slice(pad)`` for a full cut: views, stale pad rows
-        and all; the unique rows' indices after dedup: copies, since the
-        slot refills once the batch completes), ``attr_bytes`` cut to
-        ``eff`` columns (``eff`` 0 = no DFA operands)."""
+        and all; row indices after dedup or for one size class of a cut:
+        copies, since the slot refills once the batch completes),
+        ``cpu_dense`` cut to the launch's class's ``n_cpu`` columns (a
+        config's CPU columns are the first of its row, whatever the
+        corpus's widest) and ``attr_bytes`` to ``eff`` columns (``eff`` 0 =
+        no DFA operands)."""
         views = [a["attrs_val"][rows], a["members"][rows],
-                 a["cpu_dense"][rows].view(bool), a["config_id"][rows]]
+                 a["cpu_dense"][rows, :n_cpu].view(bool), a["config_id"][rows]]
         if eff:
             views += [np.ascontiguousarray(a["attr_bytes"][rows, :, :eff]),
                       a["byte_ovf"][rows].view(bool)]
         return views
 
     @staticmethod
-    def _stage_layout(rec: _SnapRec, pad: int,
+    def _stage_layout(rec: _SnapRec, c: int, pad: int,
                       eff: int) -> Optional[tuple]:
-        """The static layout of one single-corpus launch's staging buffer
-        at bucket (pad, eff): the slot arrays' operands, [:pad] rows each
-        and ``attr_bytes`` cut to ``eff`` columns, end to end in the order
-        the served entry decodes them (``eff`` 0 = no DFA operands).  A
-        function of the snapshot's operand shapes and wire dtype alone,
-        built once per snapshot and bucket.  None where the backend's byte
-        order failed the one-time probe: the six transfers remain."""
+        """The static layout of the staging buffer of one single-corpus
+        launch of size class ``c`` at bucket (pad, eff): the slot arrays'
+        operands, [:pad] rows each, ``cpu_dense`` cut to the class's columns
+        and ``attr_bytes`` to ``eff``, end to end in the order the served
+        entry decodes them (``eff`` 0 = no DFA operands).  A function of the
+        snapshot's operand shapes and wire dtype alone, built once per
+        snapshot, class and bucket.  None where the backend's byte order
+        failed the one-time probe: the six transfers remain."""
         from ..ops.pattern_eval import (_FUSED_FIELDS, fuse_layout,
                                         fused_h2d_supported)
 
-        layout = rec.layouts.get((pad, eff))
+        layout = rec.layouts.get((c, pad, eff))
         if layout is None and fused_h2d_supported():
             # the operands' own dtypes and row shapes, under the names the
             # entry decodes them by (its first four, or six, in order)
-            views = NativeFrontend._operand_views(rec.arrays[0], slice(0), eff)
-            layout = rec.layouts[(pad, eff)] = fuse_layout(
+            views = NativeFrontend._operand_views(
+                rec.arrays[0], slice(0), eff, rec.classes[c]["cpu_cols"])
+            layout = rec.layouts[(c, pad, eff)] = fuse_layout(
                 (name, v.dtype, (pad,) + v.shape[1:])
                 for name, v in zip(_FUSED_FIELDS, views))
         return layout
@@ -1430,8 +1505,7 @@ class NativeFrontend:
         shapes that only the saturated-brownout edge could ever hit."""
         if rec.sharded is not None or rec.policy is None:
             return
-        has_dfa = rec.params is not None and rec.params["dfa_tables"] is not None
-        effs = [DFA_VALUE_BYTES] if has_dfa else [0]
+        effs = [DFA_VALUE_BYTES] if rec.policy.n_byte_attrs else [0]
         for pad in (16, 32):
             if pad > self.max_batch:
                 break
@@ -1657,13 +1731,22 @@ class NativeFrontend:
         enc = None
         if policy is not None:
             from ..native.encoder import get_native_encoder
-            from ..ops.pattern_eval import to_device
+            from ..ops.pattern_eval import class_view, to_device
 
             enc = get_native_encoder(policy)
             if enc is not None:
                 rec.encoder = enc
                 rec.params = (snap.params if snap.params is not None
                               else to_device(policy))
+                classes = policy.classes
+                rec.views = [class_view(rec.params, c)
+                             for c in range(len(classes))]
+                rec.class_of = np.zeros((policy.n_configs,), dtype=np.int8)
+                for c, cls in enumerate(classes):
+                    rec.class_of[cls.configs] = c
+                rec.classes = [cls.widths() for cls in classes]
+                rec.cfg_dfa_n = (policy.config_dfa_rows >= 0).sum(
+                    axis=1).astype(np.int64)
                 spec["policy"] = enc._handle
                 dt = wire_dtype(policy)
                 A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
@@ -2183,15 +2266,16 @@ class NativeFrontend:
                         eligible, self.batch_dedup)
 
     @staticmethod
-    def _row_h2d_bytes(a: Dict[str, np.ndarray], eff: int,
-                       has_dfa: bool) -> int:
+    def _row_h2d_bytes(a: Dict[str, np.ndarray], eff: int, n_cpu: int) -> int:
         """Per-row operand bytes one single-corpus launch stages from this
-        slot's arrays at byte-width ``eff`` (pure shape arithmetic — numpy
-        basic indexing views, no copies): multiply by the pad bucket for
-        the ledger's exact H2D count."""
+        slot's arrays at byte-width ``eff`` (0 = no DFA operands) and
+        ``n_cpu`` CPU columns (pure shape arithmetic — numpy basic indexing
+        views, no copies): multiply by the pad bucket for the ledger's
+        exact H2D count."""
         per = (a["attrs_val"][0].nbytes + a["members"][0].nbytes
-               + a["cpu_dense"][0].nbytes + a["config_id"].dtype.itemsize)
-        if has_dfa:
+               + n_cpu * a["cpu_dense"].dtype.itemsize
+               + a["config_id"].dtype.itemsize)
+        if eff:
             per += (a["attr_bytes"][0][..., :eff].nbytes
                     + a["byte_ovf"][0].nbytes)
         return int(per)
@@ -2220,11 +2304,6 @@ class NativeFrontend:
         ``ovf_rows`` how many of the cut's rows carried a value past
         DFA_VALUE_BYTES, as the encoder counted them (0 on a retry: the
         ledger's ``dfa_ovf_rows`` counts a cut once)."""
-        import jax.numpy as jnp
-
-        from ..ops.pattern_eval import (eval_bitpacked_jit,
-                                        eval_bitpacked_staged_jit, fuse_bytes)
-
         rec = self._snaps[snap_id]
         bt = self.batch_stages.begin(snap_id, slot, count, flush_ns)
         with bt.stage("plan"):
@@ -2304,22 +2383,13 @@ class NativeFrontend:
             else:
                 unique_rows, u = None, count
 
-        def sel(name):
-            """Unique-row operand view: the slot arrays sliced [:pad] when
-            nothing collapsed (stale pad rows discarded, as before), else
-            fancy-indexed unique rows padded by repeating the first (a
-            copy — the slot refills once the batch completes)."""
-            return a[name][:pad] if u == count else a[name][idx]
-
-        if rec.sharded is not None:
-            # one shard_map dispatch per micro-batch: the C++ encoder
-            # already laid each request into its owning shard's [B, S, ...]
-            # slice (packed bit 0 = own verdict, psum-merged over 'mp')
-            sh = rec.sharded
-            has_dfa = sh.has_dfa
-        else:
-            has_dfa = rec.params["dfa_tables"] is not None
         cost_lane = "native" if rec.sharded is None else "mesh"
+        avoided = dict(
+            dedup_avoided_rows=(len(fan.miss_rows) - u
+                                if fan is not None else 0),
+            cache_avoided_rows=(len(fan.cached_rows)
+                                if fan is not None else 0),
+            dfa_ovf_rows=ovf_rows)
         if u == 0:
             # every row cache-resolved: complete through the readback queue
             # with no device work at all
@@ -2329,104 +2399,81 @@ class NativeFrontend:
             t0_ns = time.time_ns()
             # structural cost fold (ISSUE 16): ZERO launches, zero bytes —
             # the parity the perf_guard tests pin exactly
-            LEDGER.observe(
-                cost_lane, rows=count,
-                dedup_avoided_rows=(len(fan.miss_rows)
-                                    if fan is not None else 0),
-                cache_avoided_rows=(len(fan.cached_rows)
-                                    if fan is not None else 0),
-                dfa_ovf_rows=ovf_rows)
-        else:
+            LEDGER.observe(cost_lane, rows=count, **avoided)
+        elif rec.sharded is not None:
+            # one shard_map dispatch per micro-batch: the C++ encoder
+            # already laid each request into its owning shard's [B, S, ...]
+            # slice (packed bit 0 = own verdict, psum-merged over 'mp')
+            sh = rec.sharded
+            has_dfa = sh.has_dfa
             with bt.stage("encode"):
                 eff_need = (_trim_bytes(a["attr_bytes"][:count] if u == count
                                         else a["attr_bytes"][unique_rows]
                                         ).shape[-1]
                             if has_dfa else 0)
-                eff = eff_need
                 # round the batch/byte buckets up to an already-compiled
                 # variant so XLA compiles never land on live requests (rows
                 # past the unique count carry stale/repeated operands;
                 # results discarded)
-                pad, eff = self._pick_warm_shape(rec, u, eff)
+                pad, eff = self._pick_warm_shape(rec, u, eff_need)
                 idx = None
                 if u != count:
                     idx = np.full((pad,), unique_rows[0], dtype=np.int32)
                     idx[:u] = unique_rows
+
+                def sel(name):
+                    """Unique-row operand view: the slot arrays sliced
+                    [:pad] when nothing collapsed (stale pad rows discarded,
+                    as before), else fancy-indexed unique rows padded by
+                    repeating the first (a copy — the slot refills once the
+                    batch completes)."""
+                    return a[name][:pad] if u == count else a[name][idx]
+
                 t0 = time.monotonic()
                 t0_ns = time.time_ns()
                 if faults.ACTIVE:
                     faults.FAULTS.check("h2d", "native")
                     faults.FAULTS.check("kernel", "native")
-                if rec.sharded is not None:
-                    from ..parallel.sharded_eval import _ShardedEncoded
+                from ..parallel.sharded_eval import _ShardedEncoded
 
-                    operands = _ShardedEncoded(
-                        attrs_val=sel("attrs_val"),
-                        members_c=sel("members"),
-                        cpu_dense=sel("cpu_dense").view(bool),
-                        attr_bytes=np.ascontiguousarray(
-                            sel("attr_bytes")[..., :eff]) if has_dfa else None,
-                        byte_ovf=(sel("byte_ovf").view(bool)
-                                  if has_dfa else None),
-                        shard_of=sel("shard_of"),
-                        row_of=sel("config_id"),
-                        host_fallback=np.zeros((pad,), dtype=bool))
-                else:
-                    # single corpus: the operands as ONE staged buffer (the
-                    # served entry decodes them on the device), handed to
-                    # the runtime here, in one transfer, so that `launch`
-                    # times the jitted call alone
-                    operands = self._operand_views(
-                        a, slice(pad) if u == count else idx, eff)
-                    layout = self._stage_layout(rec, pad, eff)
-                    if layout is not None:
-                        operands = [fuse_bytes(operands)]
-                    operands = [jnp.asarray(o) for o in operands]
+                operands = _ShardedEncoded(
+                    attrs_val=sel("attrs_val"),
+                    members_c=sel("members"),
+                    cpu_dense=sel("cpu_dense").view(bool),
+                    attr_bytes=np.ascontiguousarray(
+                        sel("attr_bytes")[..., :eff]) if has_dfa else None,
+                    byte_ovf=(sel("byte_ovf").view(bool)
+                              if has_dfa else None),
+                    shard_of=sel("shard_of"),
+                    row_of=sel("config_id"),
+                    host_fallback=np.zeros((pad,), dtype=bool))
             with bt.stage("launch"):
-                if rec.sharded is not None:
-                    # dispatch_full owns the step's operand list, the mesh
-                    # ledger launch (+ exact operand bytes) and the
-                    # per-device launch counts
-                    packed = sh.dispatch_full(operands)
-                elif layout is not None:
-                    packed = eval_bitpacked_staged_jit(
-                        rec.params, *operands, layout)
-                else:
-                    packed = eval_bitpacked_jit(rec.params, *operands)
+                # dispatch_full owns the step's operand list, the mesh
+                # ledger launch (+ exact operand bytes) and the per-device
+                # launch counts
+                packed = sh.dispatch_full(operands)
                 if faults.ACTIVE:
                     packed = faults.FAULTS.wrap_handle(packed, "native")
                 try:
                     packed.copy_to_host_async()
                 except Exception:
                     pass
-                # structural cost fold (ISSUE 16): ONE launch per slot, the
-                # exact H2D operand bytes this (pad, eff) variant staged and
-                # the bitpacked [pad, W] readback.  eff-column slack is the
-                # warm-shape round-up (eff - eff_need); sharded slots fold
-                # the batch here, their collective launch and its bytes were
-                # counted on the mesh lane by dispatch_full
-                if rec.sharded is not None:
-                    LEDGER.observe(
-                        "mesh", rows=count, device_rows=u, pad_rows=pad,
-                        eff_slack_cols=eff - eff_need,
-                        dedup_avoided_rows=(len(fan.miss_rows) - u
-                                            if fan is not None else 0),
-                        cache_avoided_rows=(len(fan.cached_rows)
-                                            if fan is not None else 0),
-                        dfa_ovf_rows=ovf_rows)
-                else:
-                    LEDGER.observe(
-                        "native", rows=count, device_rows=u, launches=1,
-                        h2d_transfers=len(operands),
-                        h2d_bytes=pad * self._row_h2d_bytes(a, eff, has_dfa),
-                        d2h_bytes=int(packed.shape[0]) * int(packed.shape[1]),
-                        pad_rows=pad,
-                        eff_slack_cols=eff - eff_need,
-                        dedup_avoided_rows=(len(fan.miss_rows) - u
-                                            if fan is not None else 0),
-                        cache_avoided_rows=(len(fan.cached_rows)
-                                            if fan is not None else 0),
-                        dfa_ovf_rows=ovf_rows)
+                # sharded slots fold the batch here; their collective launch
+                # and its bytes were counted on the mesh lane by
+                # dispatch_full.  eff-column slack is the warm-shape
+                # round-up (eff - eff_need)
+                LEDGER.observe("mesh", rows=count, device_rows=u,
+                               pad_rows=pad, eff_slack_cols=eff - eff_need,
+                               **avoided)
+        else:
+            t0 = time.monotonic()
+            t0_ns = time.time_ns()
+            if faults.ACTIVE:
+                faults.FAULTS.check("h2d", "native")
+                faults.FAULTS.check("kernel", "native")
+            packed, pad, eff = self._launch_classes(
+                rec, a, bt, count, rows, unique_rows if u != count else None,
+                avoided)
         with self._rb_lock:
             self._rb_inflight += 1
             if self._rb_inflight > self.rb_inflight_peak:
@@ -2437,6 +2484,104 @@ class NativeFrontend:
         self._rb_q.append((rec, snap_id, slot, count, pad, eff, rows,
                            shards_arr, packed, t0, t0_ns, fan, attempt, bt))
         self._rb_evt.set()
+
+    def _launch_classes(self, rec: _SnapRec, a: Dict[str, np.ndarray], bt,
+                        count: int, rows: np.ndarray,
+                        unique_rows: Optional[np.ndarray],
+                        avoided: Dict[str, int]):
+        """The device launches of one single-corpus cut: its rows to launch
+        (all ``count``, or ``unique_rows`` after dedup and cache) split by
+        their config's size class, ONE launch a class present, each the
+        class's operands (``rec.views``) over a staging buffer of the
+        class's rows at the class's widths.  A cut of one class is one
+        launch of every row, views and no copy, as a corpus of one class
+        always is.  `encode` and `launch` run once a launch; the ledger
+        folds the cut once, in its last launch.  Returns (the launches, the
+        pad rows launched in all, the widest byte bucket)."""
+        import jax.numpy as jnp
+
+        from ..ops.pattern_eval import (eval_bitpacked_jit,
+                                        eval_bitpacked_staged_jit, fuse_bytes)
+
+        cfg = rows if unique_rows is None else rows[unique_rows]
+        u = cfg.shape[0]
+        cls = rec.class_of[cfg]
+        present = []
+        for c in range(len(rec.views)):
+            mine = cls == c
+            n = int(np.count_nonzero(mine))
+            if n:
+                present.append((c, n, mine))
+        out = _Launched()
+        tally = dict(h2d_transfers=0, h2d_bytes=0, d2h_bytes=0, pad_rows=0,
+                     eff_slack_cols=0, own_dfa_slots=0)
+        eff_max = 0
+        for c, n, mine in present:
+            with bt.stage("encode"):
+                if n == u:
+                    at, src = None, unique_rows   # the whole selection
+                else:
+                    at = np.nonzero(mine)[0]
+                    src = at if unique_rows is None else unique_rows[at]
+                width = rec.classes[c]
+                n_dfa = width["dfa_rows_per_row"]
+                eff_need = (_trim_bytes(a["attr_bytes"][:count] if src is None
+                                        else a["attr_bytes"][src]).shape[-1]
+                            if n_dfa else 0)
+                # round the batch/byte buckets up to an already-compiled
+                # variant so XLA compiles never land on live requests (rows
+                # past the launch's carry stale/repeated operands; results
+                # discarded)
+                pad, eff = self._pick_warm_shape(rec, n, eff_need)
+                if not n_dfa:
+                    eff = 0
+                if src is None:
+                    idx = slice(pad)
+                else:
+                    idx = np.full((pad,), src[0], dtype=np.int32)
+                    idx[:n] = src
+                # the operands as ONE staged buffer (the served entry
+                # decodes them on the device), handed to the runtime here,
+                # in one transfer, so that `launch` times the jitted call
+                # alone
+                operands = self._operand_views(a, idx, eff, width["cpu_cols"])
+                layout = self._stage_layout(rec, c, pad, eff)
+                if layout is not None:
+                    operands = [fuse_bytes(operands)]
+                operands = [jnp.asarray(o) for o in operands]
+            with bt.stage("launch"):
+                if layout is not None:
+                    packed = eval_bitpacked_staged_jit(
+                        rec.views[c], *operands, layout)
+                else:
+                    packed = eval_bitpacked_jit(rec.views[c], *operands)
+                if faults.ACTIVE:
+                    packed = faults.FAULTS.wrap_handle(packed, "native")
+                try:
+                    packed.copy_to_host_async()
+                except Exception:
+                    pass
+                out.parts.append((packed, at, n, width["evaluators"]))
+                # structural cost (ISSUE 16): the exact H2D operand bytes
+                # this (class, pad, eff) variant staged and the bitpacked
+                # [pad, W] readback; eff-column slack is the warm-shape
+                # round-up (eff - eff_need); what the launch scanned: pad
+                # rows x the class's D
+                tally["h2d_transfers"] += len(operands)
+                tally["h2d_bytes"] += pad * self._row_h2d_bytes(
+                    a, eff, width["cpu_cols"])
+                tally["d2h_bytes"] += int(packed.shape[0]) * int(packed.shape[1])
+                tally["pad_rows"] += pad
+                tally["eff_slack_cols"] += eff - eff_need
+                tally["own_dfa_slots"] += pad * n_dfa
+                eff_max = max(eff_max, eff)
+                if c == present[-1][0]:
+                    LEDGER.observe(
+                        "native", rows=count, device_rows=u,
+                        launches=len(present),
+                        own_dfa_rows=int(rec.cfg_dfa_n[cfg].sum()),
+                        **tally, **avoided)
+        return out, tally["pad_rows"], eff_max
 
     def _brownout_slot(self, rec: _SnapRec, snap_id: int, slot: int,
                        count: int, why: str = "brownout") -> None:
@@ -2592,7 +2737,9 @@ class NativeFrontend:
         with bt.stage("resolve"):
             if faults.ACTIVE:
                 faults.FAULTS.check("readback", "native")
-            packed = np.asarray(packed)
+            launched = packed if isinstance(packed, _Launched) else None
+            if launched is None:
+                packed = np.asarray(packed)
             if pad:
                 # the device answered (cache-only batches with pad == 0
                 # never touched it): clear the breaker's consecutive-failure
@@ -2604,36 +2751,35 @@ class NativeFrontend:
                 self.breaker.release_probe()
             dispatch_s = time.monotonic() - t0
             # attribution (ISSUE 9): the packed readback already carries the
-            # per-rule result/skip columns — ONE vectorized unpack per batch
+            # per-rule result/skip columns — ONE vectorized unpack per launch
             # recovers the firing column next to the verdict bit (zero
             # per-request Python, pinned by tests/test_provenance.py)
             from ..ops.pattern_eval import unpack_attribution
 
             heat = rec.heat
             E = heat.E if heat is not None else 0
-            if fan is None:
-                # dedup/cache off: packed is the bit-masked result of the
-                # full slot; own verdict = bit 0 of byte 0
+            u = count if fan is None else len(fan.unique_rows)
+            uniq_v = uniq_f = None
+            if launched is not None:
+                # a single corpus's launches, one a size class present
+                uniq_v, uniq_f = launched.unpack(u, bool(E))
+            elif u:
+                # the mesh step's one result: own verdict = bit 0 of byte 0
                 if E:
-                    verdict, firing = unpack_attribution(packed[:count], E)
-                    verdict = np.ascontiguousarray(verdict)
+                    uniq_v, uniq_f = unpack_attribution(packed[:u], E)
+                    uniq_v = np.ascontiguousarray(uniq_v)
                 else:
-                    verdict = np.ascontiguousarray(
-                        packed[:count, 0] & 1).astype(np.uint8)
-                    firing = None
-                u = count
+                    uniq_v = np.ascontiguousarray(
+                        packed[:u, 0] & 1).astype(np.uint8)
+            if fan is None:
+                # dedup/cache off: the launched rows are the cut's rows
+                verdict, firing = uniq_v, uniq_f
                 cached_n = elig_miss_n = 0
             else:
-                u = len(fan.unique_rows)
                 elig_miss_n = fan.eligible_misses
                 verdict = np.zeros((count,), dtype=np.uint8)
                 firing = np.full((count,), -1, dtype=np.int32) if E else None
                 if u:
-                    if E:
-                        uniq_v, uniq_f = unpack_attribution(packed[:u], E)
-                    else:
-                        uniq_v = (packed[:, 0] & 1).astype(np.uint8)
-                        uniq_f = None
                     verdict[fan.miss_rows] = uniq_v[fan.inverse]
                     if firing is not None and uniq_f is not None:
                         firing[fan.miss_rows] = uniq_f[fan.inverse]
@@ -2779,7 +2925,7 @@ class NativeFrontend:
 
         a = rec.arrays[slot]
         cpu = self._host_twin(rec)
-        has_dfa = rec.host_params["dfa_tables"] is not None
+        has_dfa = rec.policy.n_byte_attrs > 0
         pad = min(bucket_pow2(count), self.max_batch)
         eff = (_trim_bytes(a["attr_bytes"][:count]).shape[-1]
                if has_dfa else 0)
@@ -2800,7 +2946,7 @@ class NativeFrontend:
             packed = eval_bitpacked_jit(
                 rec.host_params,
                 *(jnp.asarray(v) for v in self._operand_views(
-                    a, slice(pad), eff)))
+                    a, slice(pad), eff, rec.policy.n_own_cpu)))
             out = np.asarray(packed)
         rec.host_warm.add((pad, eff))  # compiled now, warm from here on
         E = rec.heat.E if rec.heat is not None else 0

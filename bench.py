@@ -1796,7 +1796,7 @@ def run_native_mode(args):
 
             a = snap_rec.arrays[0]
             pad = min(bucket_pow2(light_total), B)
-            has_dfa = snap_rec.params["dfa_tables"] is not None
+            has_dfa = snap_rec.policy.n_byte_attrs > 0
             for _ in range(14):
                 t0 = time.perf_counter()
                 np.asarray(eval_bitpacked_jit(
@@ -3181,7 +3181,7 @@ def run_relations_mode(args):
 
     from authorino_tpu.ops.pattern_eval import _extra_operands
 
-    has_dfa = model.params["dfa_tables"] is not None
+    has_dfa = model.policy.n_byte_attrs > 0
     own, own_rule, own_skip = eval_full_jit(
         model.params, jnp.asarray(db.attrs_val), jnp.asarray(db.members_c),
         jnp.asarray(db.cpu_dense), jnp.asarray(db.config_id),

@@ -98,6 +98,71 @@ def test_routes_1k_cell_is_as_the_issue_states_and_its_files_agree():
     Reference(manifests)  # the plain reference, unedited, takes the corpus
 
 
+def test_mixed_tenants_1k_cell_is_as_the_issue_states_and_its_files_agree():
+    manifest = _manifest()
+    cell = harness.load_cell(manifest, ROOT, "mixed-tenants-1k.unique-sat")
+    assert manifest["workloads"][-1]["name"] == cell["name"]    # appended
+    assert manifest["configs"][-1]["name"] == cell["config"] == "mixed-tenants-1k"
+    config = cell["config_file"]
+    assert (cell["traffic"], cell["chips"]) == ("unique-sat", 1)
+    assert config["params"] == {"n_configs": 1000, "n_large": 8, "services": 8}
+    assert config["requests"] == {"large_share": 0.4, "deny_share": 0.5,
+                                  "unrouted_share": 0.1, "long_path_share": 0.15}
+    assert config["reduced"] == [] and config["generator"] == "mixed_tenants"
+    assert len(config["source"]) <= 200 and len(cell["why"]) <= 200
+    # the other configurations' three guarantees, word for word, and its own
+    routes = harness.load_cell(manifest, ROOT, "routes-1k.unique-sat")["config_file"]
+    assert config["guarantees"][:3] == routes["guarantees"]
+    assert len(config["guarantees"]) == 4 and "class" in config["guarantees"][3]
+    reads = {m["name"] for m in cell["per_layer"]}
+    assert {"launches_per_cut", "dfa_slot_fill_pct", "own_class_roofline",
+            "kernel_ms_per_launch", "dfa_rows_per_row", "h2d_bytes_per_row",
+            "device_idle_pct", "host_lane_rows_pct"} <= reads
+    assert not {"pattern_eval_roofline", "dfa_scan_roofline"} & reads
+    for other in manifest["workloads"][:-1]:
+        names = {m["name"] for m in
+                 harness.load_cell(manifest, ROOT, other["name"])["per_layer"]}
+        assert {"launches_per_cut", "dfa_slot_fill_pct"} <= names
+        assert "own_class_roofline" not in names
+    generator = harness.load_module("corpora", config["generator"])
+    manifests = generator.manifests({"n_configs": 4, "n_large": 2, "services": 8})
+    assert [m["spec"]["hosts"][0] for m in manifests[4:]] == [
+        "api-0.bench.test", "api-1.bench.test"]
+    evaluators = manifests[5]["spec"]["authorization"]
+    assert len(evaluators) == 129 and "when" not in manifests[5]["spec"]
+    assert sum("when" in ev for ev in evaluators.values()) == 128
+    assert evaluators["s7-route-00"]["when"][0]["value"].startswith(
+        "^/api/v[0-9]+/t1/s7/orders/")
+    Reference(manifests)  # the plain reference, unedited, takes the corpus
+
+
+@pytest.mark.parametrize("name, num, den, scale", [
+    ("launches_per_cut", "launches", "batches", None),
+    ("dfa_slot_fill_pct", "own_dfa_rows", "own_dfa_slots", 100.0)])
+def test_size_class_metrics_read_ledger_counters_the_program_has(
+        name, num, den, scale):
+    """ISSUE 34's counters' metrics are data only, over a reader that was
+    there; a program without the counters gives them nothing to read."""
+    from authorino_tpu.runtime import kernel_cost
+
+    spec = harness._load_json(os.path.join(BENCH, "metrics", name + ".json"))
+    assert spec["reader"] == "ledger_ratio" and spec["args"].get("scale") == scale
+    assert spec["args"]["num"] == [["native", num]]
+    assert spec["args"]["den"] == [["native", den]]
+    assert {num, den} <= set(kernel_cost._FIELDS) and "workloads" not in spec
+    reader = harness.load_module("readers", spec["reader"])
+
+    def at(**fields):
+        return {"native_frontend": {"kernel_cost": {"ledger": {"native": fields}}}}
+
+    ctx = {"vars0": at(**{num: 10, den: 20}), "vars1": at(**{num: 40, den: 60})}
+    assert reader.read(ctx, **spec["args"]) == (scale or 1.0) * 30 / 40
+    # the parent's ledger has no such fields: nothing to read, no error
+    old = {"vars0": at(rows=1), "vars1": at(rows=9)}
+    if name == "dfa_slot_fill_pct":
+        assert reader.read(old, **spec["args"]) is None
+
+
 def test_cache_hit_rows_pct_reads_ledger_counters_the_program_has():
     """ISSUE 33's metric is data only: its file names the `ledger_ratio`
     reader and [lane, field] pairs the kernel-cost ledger folds, and every
@@ -109,7 +174,7 @@ def test_cache_hit_rows_pct_reads_ledger_counters_the_program_has():
     assert entry == [{"name": "cache_hit_rows_pct", "unit": "%", "better": "higher",
                       "source": "program_counter", "moves": "checks_per_s",
                       "layer": "batch cut, dedup and verdict cache"}]
-    assert manifest["per_layer"][-1] is entry[0]        # appended, not inserted
+    assert manifest["per_layer"][-4] is entry[0]        # appended, not inserted
     spec = harness._load_json(
         os.path.join(BENCH, "metrics", "cache_hit_rows_pct.json"))
     assert spec["reader"] == "ledger_ratio" and spec["args"]["scale"] == 100.0

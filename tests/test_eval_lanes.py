@@ -167,7 +167,7 @@ def test_environment_cannot_select_a_kernel_body(monkeypatch):
     params = pe.to_device(policy, host=True)
     assert jax.tree.structure(params) == jax.tree.structure(plain)
     assert pe.kernel_lane_of(params) == pe.kernel_lane_of(plain) == "matmul"
-    assert "fused" not in params and params["own"] is not None
+    assert "fused" not in params and params["classes"][0]["own"] is not None
 
 
 # ---------------------------------------------------------------------------

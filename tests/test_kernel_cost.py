@@ -344,10 +344,13 @@ class TestNativeRowBytes:
 
         a = self._arrays()
         base = 12 + 32 + 5 + 4
-        assert NativeFrontend._row_h2d_bytes(a, 0, False) == base
+        assert NativeFrontend._row_h2d_bytes(a, 0, 5) == base
         # DFA lane ships the eff-trimmed byte columns + overflow flags
-        assert NativeFrontend._row_h2d_bytes(a, 6, True) \
+        assert NativeFrontend._row_h2d_bytes(a, 6, 5) \
             == base + 2 * 6 + 2
+        # a size class stages its own CPU columns, not the corpus's widest
+        assert NativeFrontend._row_h2d_bytes(a, 6, 2) \
+            == base - 3 + 2 * 6 + 2
         # mesh slots are counted by the sharded model's own dispatch_full
         # (the native lane hands it the slot arrays): routing adds one
         # shard_of element per row

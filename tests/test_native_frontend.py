@@ -1603,6 +1603,41 @@ def test_debug_vars_reports_what_the_served_kernel_scans(stack):
     assert kernel["dfa_rows_per_row"] == D <= R
 
 
+@pytest.mark.parametrize("key", ["leaf_cols_per_row", "dfa_rows_per_row",
+                                 "dfa_states"])
+def test_debug_vars_lists_the_size_classes_and_one_class_reads_the_corpus(
+        stack, key):
+    """ISSUE 34: snapshot.kernel.classes has one entry a size class; this
+    corpus's configs are of one size, so there is one class, its widths are
+    the scalars', and the ledger's own_dfa counters say what its launches
+    scanned against what the launched rows' own configs have."""
+    from authorino_tpu.runtime.kernel_cost import LEDGER
+
+    engine, fe, port, _ = stack
+    assert fe.wait_warm(180) and fe.warm_error is None
+    policy = engine._snapshot.policy
+    kernel = fe.debug_vars()["snapshot"]["kernel"]
+    (only,) = kernel["classes"]
+    assert len(policy.classes) == 1 and only["configs"] == policy.n_configs
+    assert only[key] == kernel[key]
+    assert only["cpu_cols"] == policy.n_own_cpu
+    assert only["evaluators"] == policy.eval_rule.shape[1]
+    assert 0 < only["operand_bytes"] < kernel["operand_bytes"]
+    before = LEDGER.snapshot("native")
+    assert grpc_call(port, make_req(
+        "fast-rx.test", path=f"/api/v1/ok-{key}")).status.code == 0
+    after = LEDGER.snapshot("native")
+    d = {f: after[f] - before[f] for f in (
+        "launches", "batches", "pad_rows", "own_dfa_slots", "own_dfa_rows")}
+    # one row, one cut, one launch (or none: lane selection may answer a
+    # one-row cut on the host twin, which folds into the `host` lane and
+    # launches and scans nothing)
+    assert d["launches"] == d["batches"] <= 1
+    # a launch scanned D rows a pad row; the row's own config has one
+    assert d["own_dfa_slots"] == d["pad_rows"] * kernel["dfa_rows_per_row"]
+    assert d["own_dfa_rows"] == d["launches"]
+
+
 def test_fast_lane_classification(stack):
     engine, _, _, _ = stack
     snap = engine._snapshot

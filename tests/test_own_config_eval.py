@@ -263,6 +263,56 @@ def test_served_entry_equals_dense_body_and_oracle(case, seed, lane):
 
 
 # ---------------------------------------------------------------------------
+# size classes (ISSUE 34) on every operand lane
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lane", ["matmul", "gather"])
+@pytest.mark.parametrize("seed", [7, 19])
+def test_two_size_classes_equal_dense_body_on_every_operand_lane(seed, lane):
+    """The all-operand corpus beside one config of 80 regexes on the same
+    path: two size classes.  The whole operands (every class, every row)
+    and each class's view over its own rows equal the dense body, relation,
+    numeric, membership-overflow, DFA and CPU-regex leaves alike."""
+    B = 96
+    rng = random.Random(seed)
+    cfgs = all_operand_corpus(rng)
+    cfgs.append(ConfigRules(name="cfg-big", evaluators=[
+        (Pattern("req.m", Operator.EQ, "GET"),
+         Any_(*[Pattern("req.path", Operator.MATCHES, rf"^/svc-{i}/[a-z]{{{i % 5}}}")
+                for i in range(80)])),
+        (None, Pattern("req.n", Operator.GE, "0"))]))
+    docs = all_operand_docs(rng, n=B)
+    policy = compile_corpus(cfgs, members_k=K, ovf_assist=True)
+    assert tensor_lint(policy) == []
+    small, big = policy.classes
+    assert big.configs.tolist() == [len(cfgs) - 1]
+    assert (big.widths()["dfa_rows_per_row"], small.widths()["dfa_rows_per_row"]) == (80, 1)
+    assert small.widths()["leaf_cols_per_row"] < big.widths()["leaf_cols_per_row"]
+    rows = [rng.randrange(len(cfgs)) for _ in range(B)]
+    rows[:8] = [len(cfgs) - 1] * 8
+    db = pack_batch(policy, encode_batch_py(policy, docs, rows, batch_pad=128))
+    params = pe.to_device(policy, lane=lane)
+    dense = pe.to_device(policy, lane=lane, dense=True)
+    E = int(policy.eval_rule.shape[1])
+    cid = np.asarray(db.config_id).copy()
+    cid[-4:] = [-1, policy.n_configs, 10**6, -(10**6)]
+    operands = _operands(db, cid)
+    want = _dense_own(policy, dense, operands)
+    served = pe.unpack_verdicts(pe.eval_bitpacked_jit(params, *operands), 1 + 2 * E)
+    np.testing.assert_array_equal(served, want)
+    assert served[:B, 0].any() and not served[:B, 0].all()
+    for c, cls in enumerate(policy.classes):
+        # the class's view answers its members' rows and reads False on the rest
+        E_c = cls.own.evals.shape[2]
+        got = pe.unpack_verdicts(pe.eval_bitpacked_jit(
+            pe.class_view(params, c), *operands), 1 + 2 * E_c)
+        mine = np.isin(cid, cls.configs)
+        np.testing.assert_array_equal(got[mine, :1 + E_c], want[mine, :1 + E_c])
+        np.testing.assert_array_equal(got[mine, 1 + E_c:], want[mine, 1 + E:1 + E + E_c])
+        assert not got[~mine].any()
+
+
+# ---------------------------------------------------------------------------
 # tenant_rules: operands linear in the configs, ten leaf columns a row
 # ---------------------------------------------------------------------------
 
@@ -285,9 +335,16 @@ def test_tenant_rules_operands_grow_with_configs_not_configs_times_leaves(lane):
         policy = compile_corpus(_tenants(n), members_k=16)
         view = pe.to_device(policy, host=True, lane=lane)
         sizes[n] = pe.operand_bytes(view)
-        assert pe.kernel_widths(view) == {
+        widths = pe.kernel_widths(view)
+        # one size: one class, whose widths are the corpus's
+        (only,) = widths.pop("classes")
+        assert widths == {
             "leaf_cols_per_row": 10, "dfa_rows_per_row": 2,
             "dfa_rows_total": 2 * n, "dfa_states": 16}
+        assert only == {"configs": n, "evaluators": 2,
+                        "operand_bytes": pe.operand_bytes(view["classes"]),
+                        **{k: widths[k] for k in (
+                            "leaf_cols_per_row", "dfa_rows_per_row", "dfa_states")}}
         assert policy.n_own_cpu == 2  # the two regexes' overflow columns
     assert sizes[128] <= 2.2 * sizes[64]
     if lane == "matmul":
@@ -305,10 +362,10 @@ def test_one_config_change_is_a_rows_delta_on_the_own_layout():
     plan = plan_delta(old, new)
     assert plan is not None and plan.mode == "delta"
     touched = {e.name: e for e in plan.entries if e.mode != "reuse"}
-    # the changed leaf's row and config 17's row of the own tables
-    assert set(touched) == {"leaf_op", "own.leaf"}
+    # the changed leaf's row and config 17's row of its class's own tables
+    assert set(touched) == {"leaf_op", "classes.0.own.leaf"}
     assert all(e.mode == "rows" for e in touched.values())
-    assert touched["own.leaf"].rows.tolist() == [17]
+    assert touched["classes.0.own.leaf"].rows.tolist() == [17]
     assert plan.upload_bytes * 50 < plan.full_bytes
 
 

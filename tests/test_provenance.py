@@ -155,7 +155,7 @@ def test_attribution_matches_host_oracle_property(seed):
     params = to_device(policy)
     import jax.numpy as jnp
 
-    has_dfa = params["dfa_tables"] is not None
+    has_dfa = policy.n_byte_attrs > 0
     packed = np.asarray(eval_bitpacked_jit(
         params, jnp.asarray(db.attrs_val), jnp.asarray(db.members_c),
         jnp.asarray(db.cpu_dense), jnp.asarray(db.config_id),

@@ -219,7 +219,7 @@ def _kernel_full(policy, docs, rows, lane):
     params = pe.to_device(policy, lane=lane)
     enc = encode_batch_py(policy, docs, rows)
     db = pack_batch(policy, enc)
-    has_dfa = params["dfa_tables"] is not None
+    has_dfa = pe.has_dfa(params)
     own, own_rule, own_skip = pe.eval_full_jit(
         params, jnp.asarray(db.attrs_val), jnp.asarray(db.members_c),
         jnp.asarray(db.cpu_dense), jnp.asarray(db.config_id),

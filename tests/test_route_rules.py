@@ -187,9 +187,14 @@ def test_widths_are_18_dfa_rows_of_72_states_and_operands_grow_linearly():
         manifests = rr.manifests({"n_configs": n})
         policy = compile_corpus(_rules(manifests))
         view = pe.to_device(policy, host=True)
-        assert pe.kernel_widths(view) == {
+        widths = pe.kernel_widths(view)
+        (only,) = widths.pop("classes")     # one size: one class
+        assert widths == {
             "leaf_cols_per_row": 40, "dfa_rows_per_row": 18, "dfa_states": 72,
             "dfa_rows_total": 18 * n}
+        assert (only["configs"], only["evaluators"]) == (n, 32)
+        assert all(only[k] == widths[k] for k in (
+            "leaf_cols_per_row", "dfa_rows_per_row", "dfa_states"))
         assert (policy.n_own_cpu, policy.eval_rule.shape[1]) == (18, 32)
         sizes[n] = pe.operand_bytes(view)
     assert 1.9 * sizes[64] < sizes[128] < 2.1 * sizes[64]

@@ -89,7 +89,7 @@ def test_sharded_dfa_lane_rides_the_mesh():
         configs.append(ConfigRules(name=f"cfg-{i}", evaluators=[(None, All(*pats))]))
     mesh = build_mesh(n_devices=8, dp=2)
     m = ShardedPolicyModel(configs, mesh, members_k=4)
-    assert m.has_dfa and m.params["dfa_tables"] is not None
+    assert m.has_dfa and m.params["leaf_dfa_row"] is not None
 
     docs, names, expected = [], [], []
     for i in range(9):
@@ -121,7 +121,7 @@ def test_sharded_full_outputs_match_single_corpus():
     own_s, rule_s, skip_s = sharded.apply_full(enc_s)
 
     db = single.encode(docs, rows)
-    has_dfa = single.params["dfa_tables"] is not None
+    has_dfa = single.policy.n_byte_attrs > 0
     own_1, rule_1, skip_1 = (
         np.asarray(a)
         for a in eval_full_jit(

@@ -72,11 +72,11 @@ def _slot(case, dtype):
                                  DFA_VALUE_BYTES), dtype=np.uint8),
          "byte_ovf": np.zeros((MAX_BATCH, max(policy.n_byte_attrs, 1)),
                               dtype=np.uint8)}
-    has_dfa = params["dfa_tables"] is not None
+    has_dfa = pe.has_dfa(params)
     if has_dfa:
         a["attr_bytes"][:] = np.asarray(db.attr_bytes)
         a["byte_ovf"][:] = np.asarray(db.byte_ovf)
-    return params, a, count, has_dfa
+    return params, a, count, has_dfa, policy.n_own_cpu
 
 
 def _grid(has_dfa):
@@ -93,9 +93,11 @@ def test_staged_entry_equals_six_operand_entry_over_the_warm_grid(
 
     from authorino_tpu.runtime.native_frontend import NativeFrontend
 
-    params, a, count, has_dfa = _slot(case, dtype)
+    params, a, count, has_dfa, n_cpu = _slot(case, dtype)
     assert has_dfa == (case != "no-dfa")
-    rec = types.SimpleNamespace(arrays=[a], layouts={})
+    # one size: one class, whose view holds the same tables
+    (view,) = [pe.class_view(params, c) for c in range(len(params["classes"]))]
+    rec = types.SimpleNamespace(arrays=[a], layouts={}, classes=[{"cpu_cols": n_cpu}])
     answers = set()
     for pad, eff in _grid(has_dfa):
         if cut == "full":
@@ -105,19 +107,19 @@ def test_staged_entry_equals_six_operand_entry_over_the_warm_grid(
             # repeating the first
             unique = list(range(0, min(count, pad) - 3, 2))
             rows = np.asarray(unique + [unique[0]] * (pad - len(unique)))
-        views = NativeFrontend._operand_views(a, rows, eff)
+        views = NativeFrontend._operand_views(a, rows, eff, n_cpu)
         assert len(views) == (6 if has_dfa else 4)
-        layout = NativeFrontend._stage_layout(rec, pad, eff)
-        assert layout is rec.layouts[(pad, eff)]
-        assert NativeFrontend._stage_layout(rec, pad, eff) is layout
+        layout = NativeFrontend._stage_layout(rec, 0, pad, eff)
+        assert layout is rec.layouts[(0, pad, eff)]
+        assert NativeFrontend._stage_layout(rec, 0, pad, eff) is layout
         buf = pe.fuse_bytes(views)
         assert buf.dtype == np.uint8 and buf.ndim == 1
         # the same bytes, to the byte: what the ledger counts a launch
         assert buf.size == layout[-1][3] + layout[-1][4] == (
-            pad * NativeFrontend._row_h2d_bytes(a, eff, has_dfa))
+            pad * NativeFrontend._row_h2d_bytes(a, eff, n_cpu))
         assert [f[1] for f in layout[:2]] == [np.dtype(dtype).name] * 2
         staged = np.asarray(pe.eval_bitpacked_staged_jit(
-            params, jnp.asarray(buf), layout))
+            view, jnp.asarray(buf), layout))
         six = np.asarray(pe.eval_bitpacked_jit(
             params, *(jnp.asarray(v) for v in views)))
         np.testing.assert_array_equal(staged, six)
@@ -153,11 +155,11 @@ def test_the_decode_has_a_scope_of_its_own_in_both_staged_entries():
     body."""
     import jax.numpy as jnp
 
-    params, a, _, _ = _slot("tenant_rules", np.int16)
+    params, a, _, _, n_cpu = _slot("tenant_rules", np.int16)
     from authorino_tpu.runtime.native_frontend import NativeFrontend
 
-    rec = types.SimpleNamespace(arrays=[a], layouts={})
-    layout = NativeFrontend._stage_layout(rec, 16, 16)
+    rec = types.SimpleNamespace(arrays=[a], layouts={}, classes=[{"cpu_cols": n_cpu}])
+    layout = NativeFrontend._stage_layout(rec, 0, 16, 16)
     buf = jnp.zeros(layout[-1][3] + layout[-1][4], dtype=jnp.uint8)
     for entry, scopes in (
             (pe.eval_bitpacked_staged_jit,
@@ -242,7 +244,8 @@ def _same_bytes(fe, since, d):
     assert len(shapes) == d["launches"]
     a = _current(fe).arrays[0]
     assert d["h2d_bytes"] == sum(
-        pad * fe._row_h2d_bytes(a, eff, True) for pad, eff in shapes)
+        pad * fe._row_h2d_bytes(a, eff, _current(fe).classes[0]["cpu_cols"])
+        for pad, eff in shapes)
     assert d["pad_rows"] == sum(pad for pad, _ in shapes)
 
 
@@ -338,7 +341,9 @@ def test_served_batches_compile_nothing_after_the_warm_grid(served):
     fe, port, _ = served
     rec = _current(fe)
     assert set(fe._bucket_grid(rec)) <= rec.warm
-    assert set(rec.layouts) == set(fe._bucket_grid(rec))
+    # one layout a (size class, bucket): this corpus is one class
+    assert set(rec.layouts) == {(0, pad, eff)
+                                for pad, eff in fe._bucket_grid(rec)}
 
     def misses():
         return sum(ch._value.get() for (_, _, outcome), ch

@@ -130,6 +130,18 @@ def test_own_leaf_rebound_rejected():
     assert "const" in failures[0].message and failures[0].detail["config"] == 0
 
 
+@pytest.mark.parametrize("mutant, what", [
+    ("class-leaf-rebound", "const"), ("class-row-dropped", "DFA rows")])
+def test_size_class_table_miscompiles_rejected(mutant, what):
+    """ISSUE 34: a size class's table that stands for another thing than the
+    corpus slot it maps back to is a layout finding of that class: the
+    corpus arrays and the corpus-wide layout are still right."""
+    _, failures, _ = certify_snapshot(_mutate(mutant), use_cache=False)
+    assert {f.kind for f in failures} == {"own-rows-layout"}
+    assert what in failures[0].message
+    assert failures[0].location.startswith("classes[")
+
+
 def _mutate(name):
     p = deepcopy(fixture_policy())
     dict(_MUTANTS)[name](p)
