@@ -51,7 +51,7 @@ _FIELDS = (
     "batches", "launches", "zero_launch_batches", "rows", "device_rows",
     "h2d_transfers", "h2d_bytes", "d2h_bytes", "pad_rows", "pad_waste_rows",
     "eff_slack_cols", "dedup_avoided_rows", "cache_avoided_rows",
-    "dfa_ovf_rows", "own_dfa_slots", "own_dfa_rows",
+    "dfa_ovf_rows", "own_dfa_slots", "own_dfa_rows", "telemetry_folds",
 )
 
 
@@ -148,6 +148,17 @@ class CostLedger:
             lc.d2h_bytes += d2h_bytes
         metrics_mod.observe_kernel_cost(lane, launches, h2d_bytes,
                                         d2h_bytes, 0)
+
+    def observe_telemetry_fold(self, lane: str) -> None:
+        """One fold of the lane's kept cuts into the heat map, the tenant
+        plane and the batch series (the native lane folds the telemetry of
+        many completed cuts at once): ``batches`` / ``telemetry_folds`` is
+        the cuts a fold."""
+        with self._lock:
+            lc = self._lanes.get(lane)
+            if lc is None:
+                lc = self._lanes[lane] = _LaneCost()
+            lc.telemetry_folds += 1
 
     def snapshot(self, lane: str) -> Dict[str, Any]:
         """One lane's raw counters (zeros if the lane never folded) —

@@ -35,8 +35,9 @@ import logging
 import os
 import threading
 import time
+from bisect import bisect_right
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -90,6 +91,12 @@ K_CONST, K_METHOD, K_PATH, K_URL_PATH, K_QUERY, K_HOST, K_SCHEME = range(7)
 K_PROTOCOL, K_SIZE, K_FRAGMENT, K_HEADER, K_CTX_EXT = range(7, 12)
 
 EV_TIMEOUT, EV_BATCH, EV_SNAP_RETIRED, EV_STOPPED = 0, 1, 3, 4
+
+# the keep (`_post_complete_telemetry`, `_fold_kept`): how many completed
+# cuts `post` keeps before one fold takes them all, and how old the oldest
+# kept cut may grow while the readback thread has others to complete
+KEEP_CUTS = 16
+KEEP_AGE_S = 0.05
 
 _SIMPLE = {
     ("request", "method"): (K_METHOD, ""),
@@ -638,6 +645,27 @@ class _Launched:
         return verdict, firing
 
 
+class _KeptCut(NamedTuple):
+    """A completed cut as the keep holds it: the cut's own arrays and the
+    scalars its telemetry takes, folded with the other kept cuts'."""
+    rec: "_SnapRec"
+    rows: np.ndarray
+    verdict: np.ndarray
+    firing: Optional[np.ndarray]
+    shards: Optional[np.ndarray]
+    count: int
+    pad: int
+    eff: int
+    device_rows: Optional[int]
+    dispatch_s: float
+    t0_ns: int
+    device: bool
+    inflight: int             # `_rb_inflight` as the completion read it
+    dedup: Optional[tuple]    # observe_dedup's (device rows, hits, misses,
+    #                           evictions); None: no cache or dedup was asked
+    kept_at: float            # time.monotonic() at the append
+
+
 @dataclass
 class _SnapRec:
     snap_id: int
@@ -721,6 +749,10 @@ class _SnapRec:
     # engine snapshot's instance when one exists, so both lanes fold into
     # one label-children cache
     heat: Any = None
+    # the C++ side has no slot of this snapshot left (EV_SNAP_RETIRED): a
+    # fold of its last kept cuts names what its heat map holds at once, no
+    # drain will find the map again
+    retired: bool = False
 
 
 class NativeFrontend:
@@ -917,6 +949,17 @@ class NativeFrontend:
         # touched (utils.metrics.drain: `post` itself only adds into arrays)
         self._sampled_decisions = 0
         self._drained_children = 0
+        # the keep: completed cuts whose telemetry no fold has taken yet
+        # (appended by the readback thread and the host-lane workers under
+        # _post_counts_lock), the folds that took them, and the lock that
+        # makes a fold one at a time: a reader that folds before it reads
+        # waits for a fold under way (re-entrant: a fold may end in a flight
+        # dump, whose providers drain)
+        self._keep: List[_KeptCut] = []
+        self._folds = 0
+        self._folded_cuts = 0
+        self._keep_max = 0
+        self._fold_lock = threading.RLock()
         self._post_counts_lock = threading.Lock()
 
     # ------------------------------------------------------------------
@@ -952,6 +995,7 @@ class NativeFrontend:
             threading.Thread(target=self._metrics_drain_loop,
                              name="atpu-fe-metrics-drain", daemon=True))
         metrics_mod.DRAIN_OBSERVERS.append(self._observe_drain)
+        metrics_mod.KEEP_FOLDERS.append(self._fold_kept)
         for t in self._threads:
             t.start()
         self.refresh()
@@ -1010,6 +1054,9 @@ class NativeFrontend:
             t.join(timeout=5)
         if self._observe_drain in metrics_mod.DRAIN_OBSERVERS:
             metrics_mod.DRAIN_OBSERVERS.remove(self._observe_drain)
+        if self._fold_kept in metrics_mod.KEEP_FOLDERS:
+            self._fold_kept()  # a cut a late worker kept after the last drain
+            metrics_mod.KEEP_FOLDERS.remove(self._fold_kept)
         # pre-warm compiles can't be interrupted mid-XLA; they bail between
         # variants (self._running) — wait them out so interpreter teardown
         # never force-unwinds a thread inside native code
@@ -1113,8 +1160,13 @@ class NativeFrontend:
             # {stage: {count, sum_ns, max_ns}}, cumulative: a reader takes
             # the difference of two scrapes (runtime/batch_stages.py)
             "stages": self.batch_stages.totals(),
+            # the drain above folded the keep: `folded_cuts` is every cut
+            # `post` has kept, `keep_max` the most one fold took
             "post": {"sampled_decisions": self._sampled_decisions,
-                     "drained_children": self._drained_children},
+                     "drained_children": self._drained_children,
+                     "folds": self._folds,
+                     "folded_cuts": self._folded_cuts,
+                     "keep_max": self._keep_max},
             "inflight_batches": self._rb_inflight,
             "inflight_peak": self.rb_inflight_peak,
             "trace_sample_n": self.trace_sample_n,
@@ -2216,8 +2268,12 @@ class NativeFrontend:
                 # would stall every batch completion queued behind this event
                 retired = self._snaps.pop(int(a), None)
                 if retired is not None and retired.heat is not None:
-                    # no batch of it is left in flight: name what it still
-                    # holds as arrays before the last reference goes
+                    # no batch of it is left in flight: fold what the keep
+                    # holds of it and name what its heat map holds as
+                    # arrays before the last reference goes (a cut of it
+                    # kept after this is named by its own fold)
+                    retired.retired = True
+                    self._fold_kept()
                     retired.heat.flush()
             elif kind == EV_STOPPED:
                 break
@@ -2658,6 +2714,9 @@ class NativeFrontend:
             if not pending:
                 if not self._running:
                     return
+                # nothing in flight: whatever a failed or abandoned cut left
+                # in the keep folds now
+                self._fold_kept_if(0.0)
                 self._rb_evt.wait(0.2)
                 self._rb_evt.clear()
                 continue
@@ -2719,6 +2778,8 @@ class NativeFrontend:
                         inflight = self._rb_inflight
                     self._g_native_inflight.set(inflight)
             if not progressed:
+                # a trickle of traffic must not hold a kept cut back
+                self._fold_kept_if(KEEP_AGE_S)
                 # sub-ms poll while results ride the link (noise vs RTT)
                 self._rb_evt.wait(0.0005)
                 self._rb_evt.clear()
@@ -2804,12 +2865,12 @@ class NativeFrontend:
                     # the ticket copied at plan time: the slot may have
                     # been refilled since
                     evict_d = cache.commit(fan.ticket, verdict, firing)
-                metrics_mod.observe_dedup("native", count, u, cached_n,
-                                          elig_miss_n, evict_d)
-                self._post_complete_telemetry(rec, count, pad, eff, rows,
-                                              shards_arr, verdict, dispatch_s,
-                                              t0_ns, device_rows=u,
-                                              firing=firing)
+                # the commit has a reader waiting (a later cut's plan); the
+                # rest of the cut's telemetry has none inside the cut
+                self._post_complete_telemetry(
+                    rec, count, pad, eff, rows, shards_arr, verdict,
+                    dispatch_s, t0_ns, device_rows=u, firing=firing,
+                    dedup=(u, cached_n, elig_miss_n, evict_d))
         except Exception:
             log.exception("post-completion telemetry failed")
 
@@ -2963,43 +3024,151 @@ class NativeFrontend:
                                  t0_ns: int,
                                  device_rows: Optional[int] = None,
                                  device: bool = True,
-                                 firing: Optional[np.ndarray] = None) -> None:
-        # per-batch telemetry AFTER completion: responses are already on
-        # their way to the wire (queue wait is C++-clocked — stage hists).
-        # ``device=False`` (brownout spill) keeps the per-authconfig
-        # counters but stays out of the device-lane batch/RTT series — a
-        # sub-ms host eval must not read as a fast device round trip.
-        # which-rule-fired attribution (ISSUE 9): one composite-key
-        # bincount per batch into the rule heat map + at most one
-        # head-sampled decision record — never per-request Python
-        heat = rec.heat
-        if heat is not None and firing is not None and count:
-            sampled = prov_mod.fold_and_sample(
-                heat, rows, firing, count, lane="native", shards=shards_arr,
-                latency_ms=dispatch_s * 1e3, generation=rec.snap_id)
-            with self._post_counts_lock:  # host-lane workers post too
+                                 firing: Optional[np.ndarray] = None,
+                                 dedup: Optional[tuple] = None) -> None:
+        """Per-batch telemetry AFTER completion: responses are already on
+        their way to the wire (queue wait is C++-clocked — stage hists), and
+        nothing it feeds has a reader inside the cut, so the cut is KEPT
+        (one append) and `_fold_kept` folds the kept cuts together: when
+        this one fills the keep to KEEP_CUTS, when it leaves no cut in
+        flight (light load: every cut folds at once), or when the oldest
+        kept cut is older than KEEP_AGE_S; a drain folds the rest.
+        ``device=False`` (host lane, brownout spill: their workers call
+        this too) keeps the per-authconfig counters but stays out of the
+        device-lane batch/RTT series — a sub-ms host eval must not read as
+        a fast device round trip — and is not among the cuts in flight."""
+        now = time.monotonic()
+        inflight = self._rb_inflight
+        with self._post_counts_lock:
+            keep = self._keep
+            keep.append(_KeptCut(rec, rows, verdict, firing, shards_arr,
+                                 count, pad, eff, device_rows, dispatch_s,
+                                 t0_ns, device, inflight, dedup, now))
+            # the readback thread's own cut still counts as in flight
+            due = (len(keep) >= KEEP_CUTS or inflight <= int(device)
+                   or now - keep[0].kept_at > KEEP_AGE_S)
+        if due:
+            self._fold_kept()
+
+    def _fold_kept_if(self, age_s: float) -> None:
+        """The readback loop, with nothing ready: fold the keep if its oldest
+        cut is older than ``age_s``."""
+        keep = self._keep
+        try:
+            if keep and time.monotonic() - keep[0].kept_at >= age_s:
+                self._fold_kept()
+        except IndexError:
+            pass  # another thread's fold took the keep in between
+
+    def _fold_kept(self) -> None:
+        """Fold the telemetry of every kept cut, at once: the arrays of the
+        cuts of one snapshot concatenated and grouped by config row by ONE
+        sort, which the heat map's sampling gate, the tenant plane and the
+        per-AuthConfig request counters share; the scalars a cut in a plain
+        loop.  Whoever runs it (the thread that kept the cut that was due,
+        the readback loop's poll, a drain, a snapshot's retirement) holds
+        `_fold_lock` from taking the keep to the last add: a reader that
+        folds first finds every cut kept before its call in the arrays.
+        Every count is a sum and does not depend on how cuts were grouped.
+        Never raises: a cut's answers left long ago."""
+        with self._fold_lock:
+            with self._post_counts_lock:
+                kept, self._keep = self._keep, []
+            if not kept:
+                return
+            LEDGER.observe_telemetry_fold("native")
+            by_snapshot: Dict[tuple, List[_KeptCut]] = {}
+            for cut in kept:
+                if cut.rec.heat is not None and cut.count:
+                    by_snapshot.setdefault(
+                        (id(cut.rec), cut.firing is None, cut.shards is None),
+                        []).append(cut)
+            sampled = 0
+            for cuts in by_snapshot.values():
+                try:
+                    sampled += self._fold_cut_arrays(cuts)
+                    if cuts[0].rec.retired:
+                        cuts[0].rec.heat.flush()
+                except Exception:
+                    log.exception("kept cuts not folded (telemetry only)")
+            for cut in kept:
+                try:
+                    self._fold_cut_scalars(cut)
+                except Exception:
+                    log.exception("batch telemetry failed (one cut's series)")
+            with self._post_counts_lock:
                 self._sampled_decisions += sampled
-        # tenant axis (ISSUE 15): every completed slot — device, lane-
-        # selected host AND brownout spill alike (device=False paths
-        # included) — folds per-tenant requests/denies/SLO into the shared
-        # plane, so fast-lane traffic is never invisible to the
-        # noisy-neighbor detector or the per-tenant burn trackers
-        ten = self.tenancy
-        if ten is not None and ten.enabled and heat is not None and count:
+                self._folds += 1
+                self._folded_cuts += len(kept)
+                self._keep_max = max(self._keep_max, len(kept))
+
+    def _fold_cut_arrays(self, cuts: List[_KeptCut]) -> int:
+        """The array side of one snapshot's kept cuts (all with or all
+        without ``firing`` and ``shards``); returns the decision records
+        made.  What can fail on a malformed cut runs before the first add:
+        then the cuts fold one by one, and only that cut's counts are lost."""
+        rec = cuts[0].rec
+        heat = rec.heat
+        try:
+            def joined(field):
+                parts = [getattr(cut, field) for cut in cuts]
+                if parts[0] is None:
+                    return None
+                return parts[0] if len(parts) == 1 else np.concatenate(parts)
+
+            rows, verdict = joined("rows"), joined("verdict")
+            firing, shards = joined("firing"), joined("shards")
+            counts = [cut.count for cut in cuts]
+            for column in (rows, verdict, firing, shards):
+                if column is not None and column.shape != (sum(counts),):
+                    raise ValueError(
+                        f"kept cut's arrays are {column.shape}, its rows "
+                        f"{sum(counts)}")
+            groups = heat.group(rows, shards)
+            # which-rule-fired attribution (ISSUE 9) marks the denials;
+            # without it the verdict does
+            den = groups.per_row(firing >= 0 if firing is not None
+                                 else verdict == 0)
+            # the SLO bad mask keeps the lane's established SLI: the cut's
+            # on-box round trip, shared by every member of the cut
+            slo_s = self.slo.slo_s if self.slo is not None else 0.0
+            bad = None
+            if slo_s:
+                late = np.array([cut.dispatch_s > slo_s for cut in cuts])
+                bad = (groups.per_row(np.repeat(late, counts)) if late.any()
+                       else np.zeros(groups.uniq.size, dtype=np.int64))
+        except Exception:
+            if len(cuts) == 1:
+                log.exception("kept cut not folded (its telemetry is lost)")
+                return 0
+            return sum(self._fold_cut_arrays([cut]) for cut in cuts)
+        sampled = 0
+        if firing is not None:
+            # one composite-key add into the rule heat map, and at most one
+            # head-sampled decision record a tenant a fold, each with its
+            # own cut's latency — never per-request Python
+            ends = np.cumsum(counts).tolist()
             try:
-                # waits=None: the native lane's per-request queue waits
-                # are C++-clocked — feeding the batch ROUND TRIP as a
-                # "queue wait" would latch every tenant overloaded on
-                # normal device latency.  The SLO bad mask keeps the
-                # lane's established SLI (the batch's on-box round trip,
-                # shared by every member).
-                slo_s = self.slo.slo_s if self.slo is not None else 0.0
-                ten.fold(heat, rows, firing=firing, shards=shards_arr,
-                         bad_mask=(np.full(count, dispatch_s > slo_s)
-                                   if slo_s else None),
-                         denied_mask=(np.asarray(verdict) == 0)
-                         if firing is None else None,
-                         lane="native")
+                sampled = prov_mod.fold_and_sample(
+                    heat, rows, firing, rows.size, lane="native",
+                    shards=shards, generation=rec.snap_id, groups=groups,
+                    latency_of=lambda i: cuts[bisect_right(
+                        ends, i)].dispatch_s * 1e3)
+            except Exception:
+                log.exception("provenance fold failed (telemetry only)")
+        # tenant axis (ISSUE 15): every completed slot — device, lane-
+        # selected host AND brownout spill alike — folds per-tenant
+        # requests/denies/SLO into the shared plane, so fast-lane traffic
+        # is never invisible to the noisy-neighbor detector or the
+        # per-tenant burn trackers.  No waits: the native lane's per-request
+        # queue waits are C++-clocked — feeding the batch ROUND TRIP as a
+        # "queue wait" would latch every tenant overloaded on normal device
+        # latency.
+        ten = self.tenancy
+        if ten is not None and ten.enabled:
+            try:
+                ten.fold_grouped(heat, groups.uniq, groups.counts, den, bad,
+                                 lane="native")
             except Exception:
                 log.exception("tenant fold failed (telemetry only)")
             # change safety (ISSUE 10): during an engine canary the native
@@ -3008,39 +3177,49 @@ class NativeFrontend:
             # attribution strengthens the guard's baseline cohort
             if getattr(self.engine, "_canary", None) is not None:
                 self.engine.canary_observe_external(rows, firing, heat,
-                                                    shards=shards_arr)
+                                                    shards=shards)
+        # per-authconfig request metrics, same counters + labels the
+        # pipeline bumps (ref pkg/service/auth_pipeline.go:26-36), into the
+        # heat map's arrays: the drain names them
+        try:
+            heat.fold_requests(rows, verdict, groups=groups)
+        except Exception:
+            log.exception("request counters not folded (telemetry only)")
+        return sampled
+
+    def _fold_cut_scalars(self, cut: _KeptCut) -> None:
+        """The series that take one sample a cut."""
+        count = cut.count
+        if cut.dedup is not None:
+            metrics_mod.observe_dedup("native", count, *cut.dedup)
         if self.slo is not None and count:
             # the native SLI is the batch's on-box round trip (per-request
             # waits are C++-clocked): every member shares the batch verdict
-            n_bad = count if dispatch_s > self.slo.slo_s else 0
+            n_bad = count if cut.dispatch_s > self.slo.slo_s else 0
             self.slo.observe(count, n_bad)
             # per-lane burn bias feed (ISSUE 12): selection leans toward
             # the lane that is not burning budget
-            self.lanes.cost.observe_slo(L_DEVICE if device else L_HOST,
+            self.lanes.cost.observe_slo(L_DEVICE if cut.device else L_HOST,
                                         count, n_bad)
-        if device:
-            if device_rows is None or device_rows > 0:
-                # lane-selection cost model: every device completion feeds
-                # the RTT/occupancy EWMAs the next slot decision compares
-                # against (cache-only batches skip it — they never touched
-                # the link, and their sub-ms turnaround would read as a
-                # fast device)
-                self.lanes.cost.observe_device(dispatch_s, count, 0,
-                                               self._rb_inflight, self.slots)
-            self.lanes.count_rows(L_DEVICE, count)
-            metrics_mod.observe_batch("native", count, pad, None, dispatch_s,
-                                      device_rows=device_rows)
-        if device and tracing_mod.tracing_active():
+        if not cut.device:
+            return
+        if cut.device_rows is None or cut.device_rows > 0:
+            # lane-selection cost model: every device completion feeds
+            # the RTT/occupancy EWMAs the next slot decision compares
+            # against (cache-only batches skip it — they never touched
+            # the link, and their sub-ms turnaround would read as a
+            # fast device)
+            self.lanes.cost.observe_device(cut.dispatch_s, count, 0,
+                                           cut.inflight, self.slots)
+        self.lanes.count_rows(L_DEVICE, count)
+        metrics_mod.observe_batch("native", count, cut.pad, None,
+                                  cut.dispatch_s, device_rows=cut.device_rows)
+        if tracing_mod.tracing_active():
             # fast-lane requests have no Python spans to link (only sampled
             # slow-lane ones do) — the DeviceBatch span still carries the
             # launch's batch_size/pad/eff for pad-waste attribution
-            tracing_mod.export_device_batch_span(count, pad, eff, [],
-                                                 t0_ns, dispatch_s)
-        # per-authconfig request metrics, same counters + labels the
-        # pipeline bumps (ref pkg/service/auth_pipeline.go:26-36), as two
-        # bincounts into the heat map's arrays: the drain names them
-        if heat is not None and count:
-            heat.fold_requests(rows, verdict, shards=shards_arr)
+            tracing_mod.export_device_batch_span(count, cut.pad, cut.eff, [],
+                                                 cut.t0_ns, cut.dispatch_s)
 
     # ------------------------------------------------------------------
     def _completer_loop(self) -> None:

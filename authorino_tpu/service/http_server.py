@@ -260,6 +260,12 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
 
         from ..utils.jax_env import jax_process_info
 
+        fe = _frontend()
+        if fe is not None:
+            # before the engine's part is read: the lanes share the tenant
+            # plane and the heat maps, and the native lane folds its kept
+            # cuts into them when it is read
+            metrics_mod.fold_kept()
         data = {
             "engine": engine.debug_vars(),
             # what the kernels run on: platform, device kind + count,
@@ -272,7 +278,6 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
         }
         if profile_state["capture"] is not None:
             data["process"]["profile"] = profile_state["capture"]
-        fe = _frontend()
         if fe is not None:
             try:
                 fe.drain_native_stats()  # /metrics reflects this scrape too
@@ -299,6 +304,7 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
             except ValueError:
                 return web.Response(status=400, text="bad n")
         tenant = request.query.get("tenant") or None
+        metrics_mod.fold_kept()  # the native lane samples when it folds
         return web.json_response(
             prov_mod.DECISIONS.to_json(n=n, tenant=tenant))
 
@@ -309,6 +315,7 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
         plane = getattr(engine, "tenancy", None)
         if plane is None:
             return web.json_response({"enabled": False})
+        metrics_mod.fold_kept()  # the native lane's kept cuts, first
         return web.json_response(plane.to_json())
 
     async def debug_replay(request: web.Request):
@@ -347,6 +354,7 @@ def build_app(engine: PolicyEngine, readiness=None, max_body: int = DEFAULT_MAX_
 
         action = request.query.get("action", "")
         if not action:
+            metrics_mod.fold_kept()  # the baseline cohort's newest cuts
             return web.json_response(engine.change_safety_vars())
         if request.method != "POST":
             # promote/rollback/clear-quarantine change the serving

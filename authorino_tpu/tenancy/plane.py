@@ -156,6 +156,15 @@ class TenantPlane:
                         denied_mask=denied_mask, lane=lane)
         self.detector.maybe_check()
 
+    def fold_grouped(self, heat, uniq, k, den=None, bad=None,
+                     lane: Optional[str] = None) -> None:
+        """``fold`` over requests grouped by config row already (the native
+        lane's fold over its kept cuts: ``TenantStats.fold_grouped``)."""
+        if not self.enabled:
+            return
+        self.stats.fold_grouped(heat, uniq, k, den, bad, lane=lane)
+        self.detector.maybe_check()
+
     # -- introspection -------------------------------------------------------
 
     def to_json(self) -> Dict[str, Any]:

@@ -41,6 +41,18 @@ _INT_COLUMNS = ("requests", "denies", "slo_bad", "rate_pend", "burn_total",
 _FLOAT_COLUMNS = ("rate_t", "rate_ewma", "wait_ewma", "last_seen", "burn_t")
 
 
+class _RowSlots:
+    """One heat map's row -> slot vector (-2: row not met yet, -1: a padded
+    row with no name), the slots its rows have met, and whether two rows
+    met the same one (a config named on two shards)."""
+    __slots__ = ("vec", "slots", "shared")
+
+    def __init__(self, vec: np.ndarray):
+        self.vec = vec
+        self.slots: set = set()
+        self.shared = False
+
+
 class TenantStats:
     def __init__(self, lane: str, top_k: int = 16, max_tenants: int = 8192,
                  burn_window_s: float = 60.0, gc_idle_s: float = 600.0):
@@ -62,8 +74,7 @@ class TenantStats:
         # lane served)
         self._lane_delta: Dict[str, np.ndarray] = {}
         self._grow(64)
-        # heat map -> its row -> slot vector (-2: row not met yet, -1: a
-        # padded row with no name); cleared when slots are compacted
+        # heat map -> its _RowSlots; cleared when slots are compacted
         self._row_slots: "weakref.WeakKeyDictionary" = \
             weakref.WeakKeyDictionary()
         self._label_of: Dict[str, str] = {}  # tenant -> prometheus label
@@ -102,26 +113,35 @@ class TenantStats:
                 self.label[slot] = self._label_names.index(name)
         return slot
 
-    def _slots_of(self, heat, flat: np.ndarray, now: float) -> np.ndarray:
-        """The batch's rows as tenant slots.  A row costs Python once a
-        snapshot: its name is looked up, and minted, the first time it
-        appears."""
-        vec = self._row_slots.get(heat)
+    def _slots_of(self, heat, flat: np.ndarray, now: float):
+        """(the rows as tenant slots, whether two rows of this heat map have
+        met one tenant).  A row costs Python once a snapshot: its name is
+        looked up, and minted, the first time it appears."""
+        met = self._row_slots.get(heat)
         top = int(flat.max()) + 1
-        if vec is None or vec.size < top:
+        if met is None or met.vec.size < top:
             grown = np.full(max(top, len(getattr(heat, "names_by_row", ()))),
                             -2, dtype=np.int64)
-            if vec is not None:
-                grown[:vec.size] = vec
-            vec = self._row_slots[heat] = grown
+            if met is None:
+                met = self._row_slots[heat] = _RowSlots(grown)
+            else:
+                grown[:met.vec.size] = met.vec
+                met.vec = grown
+        vec = met.vec
         slots = vec[flat]
         unmet = slots == -2
         if unmet.any():
             for row in np.unique(flat[unmet]).tolist():
                 name = heat.name(row)
-                vec[row] = self._mint(name, now) if name else -1
+                if not name:
+                    vec[row] = -1
+                    continue
+                slot = vec[row] = self._mint(name, now)
+                if slot in met.slots:
+                    met.shared = True
+                met.slots.add(slot)
             slots = vec[flat]
-        return slots
+        return slots, met.shared
 
     # -- folding (one call per batch) ---------------------------------------
 
@@ -143,45 +163,81 @@ class TenantStats:
         n = int(rows.size)
         if not n:
             return
-        now = time.monotonic() if now is None else now
-        lane = lane or self.lane
         flat = rows
         cps = getattr(heat, "configs_per_shard", None)
         if shards is not None and cps:
             flat = np.asarray(shards, dtype=np.int64) * cps + rows
         if denied_mask is None and firing is not None:
             denied_mask = np.asarray(firing, dtype=np.int64) >= 0
+        uniq, inv, k = np.unique(flat, return_inverse=True,
+                                 return_counts=True)
+
+        def per_row(mask):
+            if mask is None:
+                return None
+            return np.bincount(inv[np.asarray(mask, dtype=bool)],
+                               minlength=uniq.size)
+
+        wait_sum = wait_least = None
         if waits is not None:
             waits = np.asarray(waits, dtype=np.float64)
-            if waits.size != n:
-                waits = None
+            if waits.size == n:
+                wait_sum = np.bincount(inv, weights=waits,
+                                       minlength=uniq.size)
+                wait_least = np.full(uniq.size, np.inf)
+                np.minimum.at(wait_least, inv, waits)
+        self.fold_grouped(heat, uniq, k, per_row(denied_mask),
+                          per_row(bad_mask), wait_sum=wait_sum,
+                          wait_least=wait_least, lane=lane, now=now)
+
+    def fold_grouped(self, heat, uniq, k, den=None, bad=None, wait_sum=None,
+                     wait_least=None, lane: Optional[str] = None,
+                     now: Optional[float] = None) -> None:
+        """``fold`` for a caller that grouped its requests by flat config
+        row already (``HeatMap.group``, over one batch or many): ``uniq`` the
+        distinct rows, and a row each ``k`` its requests, ``den`` its
+        denials, ``bad`` its SLO-budget burns, ``wait_sum`` and
+        ``wait_least`` the sum and the least of its queue waits.  No sort
+        here: a row is a tenant, but for a config named on two shards."""
+        now = time.monotonic() if now is None else now
+        lane = lane or self.lane
         with self._lock:
             self.fold_calls += 1
-            self.total_requests += n
-            slots = self._slots_of(heat, flat, now)
-            named = slots >= 0
-            u, inv = np.unique(slots[named], return_inverse=True)
+            self.total_requests += int(k.sum())
+            u, shared = self._slots_of(heat, uniq, now)
+            columns = [k, den, bad, wait_sum, wait_least]
+            named = u >= 0
+            if not named.all():
+                u = u[named]
+                columns = [c if c is None else c[named] for c in columns]
             if not u.size:
                 return
-            k = np.bincount(inv, minlength=u.size)
-
-            def per_slot(mask):
-                return np.bincount(inv[np.asarray(mask, dtype=bool)[named]],
-                                   minlength=u.size)
-
-            den = per_slot(denied_mask) if denied_mask is not None else 0
-            bad = per_slot(bad_mask) if bad_mask is not None else 0
+            if shared:
+                u, inv = np.unique(u, return_inverse=True)
+                least = columns.pop()
+                columns = [c if c is None else np.bincount(
+                    inv, weights=c, minlength=u.size).astype(c.dtype)
+                    for c in columns]
+                if least is not None:
+                    rows_least, least = least, np.full(u.size, np.inf)
+                    np.minimum.at(least, inv, rows_least)
+                columns.append(least)
+            k, den, bad, wait_sum, wait_least = columns
             self.requests[u] += k
-            self.denies[u] += den
-            self.slo_bad[u] += bad
+            if den is not None:
+                self.denies[u] += den
+            if bad is not None:
+                self.slo_bad[u] += bad
             self.last_seen[u] = now
             delta = self._lane_delta.get(lane)
             if delta is None:
                 delta = self._lane_delta[lane] = np.zeros(
                     (3, self.requests.size), dtype=np.int64)
             delta[0, u] += k
-            delta[1, u] += den
-            delta[2, u] += bad
+            if den is not None:
+                delta[1, u] += den
+            if bad is not None:
+                delta[2, u] += bad
             # served-rate EWMA: rows accumulate across folds inside the
             # 50ms window, then the whole window's rows divide the elapsed
             # dt (never just the last batch's: batches land far faster than
@@ -198,21 +254,18 @@ class TenantStats:
                                              0.7 * old + 0.3 * inst)
                 self.rate_t[s] = now
                 self.rate_pend[s] = 0
-            if waits is not None:
-                mean = np.bincount(inv, weights=waits[named],
-                                   minlength=u.size) / k
+            if wait_sum is not None:
+                mean = wait_sum / k
                 old = self.wait_ewma[u]
                 self.wait_ewma[u] = np.where(old == 0.0, mean,
                                              0.8 * old + 0.2 * mean)
                 if self.wait_sink is not None:
                     # the per-tenant CoDel signal: the one call a tenant
                     # left, on the lanes that clock a queue (the engine's)
-                    least = np.full(u.size, np.inf)
-                    np.minimum.at(least, inv, waits[named])
                     for slot, m, w in zip(u.tolist(), mean.tolist(),
-                                          least.tolist()):
+                                          wait_least.tolist()):
                         self.wait_sink(self._names[slot], m, w, now)
-            if bad_mask is not None:
+            if bad is not None:
                 self._fold_burn(u, k, bad, now)
 
     def _fold_burn(self, u, k, bad, now: float) -> None:

@@ -95,13 +95,31 @@ def _gauge(name, doc, labels):
 # the thread that completes batches, and pushed into Prometheus children by
 # whoever reads: a scrape, /debug/vars, the native frontend's housekeeping
 # cadence, a snapshot's retirement, stop().  The counters are exact at every
-# read, and the per-child Python runs off the completion path.
+# read, and the per-child Python runs off the completion path.  The native
+# lane keeps a completed cut's rows for some cuts before it folds them into
+# those arrays (runtime/native_frontend.py `_fold_kept`): the drain folds
+# what is kept first.
 # ---------------------------------------------------------------------------
 
 _drainables: "weakref.WeakSet" = weakref.WeakSet()
 # callables (dur_ns, children) told of every drain, whoever ran it (the
 # native frontend's stage clock keeps the `drain` row from them)
 DRAIN_OBSERVERS: list = []
+
+
+# callables that fold what a completion path keeps unfolded (the native
+# frontend's keep of completed cuts) into the drainables' arrays: every drain
+# runs them first, and so may a reader of those arrays that wants no drain
+KEEP_FOLDERS: list = []
+
+
+def fold_kept() -> None:
+    for fold in list(KEEP_FOLDERS):
+        try:
+            fold()
+        except Exception:
+            logging.getLogger("authorino_tpu.metrics").exception(
+                "fold of kept batches failed")
 
 
 def register_drainable(obj) -> None:
@@ -112,6 +130,7 @@ def register_drainable(obj) -> None:
 
 def drain() -> int:
     t0 = time.monotonic_ns()
+    fold_kept()
     children = 0
     for obj in list(_drainables):
         try:

@@ -138,11 +138,13 @@ def test_mixed_tenants_1k_cell_is_as_the_issue_states_and_its_files_agree():
 
 @pytest.mark.parametrize("name, num, den, scale", [
     ("launches_per_cut", "launches", "batches", None),
-    ("dfa_slot_fill_pct", "own_dfa_rows", "own_dfa_slots", 100.0)])
+    ("dfa_slot_fill_pct", "own_dfa_rows", "own_dfa_slots", 100.0),
+    ("cuts_per_fold", "batches", "telemetry_folds", None)])
 def test_size_class_metrics_read_ledger_counters_the_program_has(
         name, num, den, scale):
-    """ISSUE 34's counters' metrics are data only, over a reader that was
-    there; a program without the counters gives them nothing to read."""
+    """ISSUE 34's counters' metrics (and ISSUE 35's `cuts_per_fold`) are
+    data only, over a reader that was there; a program without the counters
+    gives them nothing to read."""
     from authorino_tpu.runtime import kernel_cost
 
     spec = harness._load_json(os.path.join(BENCH, "metrics", name + ".json"))
@@ -159,8 +161,14 @@ def test_size_class_metrics_read_ledger_counters_the_program_has(
     assert reader.read(ctx, **spec["args"]) == (scale or 1.0) * 30 / 40
     # the parent's ledger has no such fields: nothing to read, no error
     old = {"vars0": at(rows=1), "vars1": at(rows=9)}
-    if name == "dfa_slot_fill_pct":
+    if name != "launches_per_cut":
         assert reader.read(old, **spec["args"]) is None
+    if name == "cuts_per_fold":
+        entry = [m for m in _manifest()["per_layer"] if m["name"] == name]
+        assert entry == [_manifest()["per_layer"][-1]] == [{
+            "name": name, "unit": "cuts", "better": "higher",
+            "source": "program_counter", "moves": "checks_per_s",
+            "layer": "launch, readback and fan-out"}]
 
 
 def test_cache_hit_rows_pct_reads_ledger_counters_the_program_has():
@@ -174,7 +182,7 @@ def test_cache_hit_rows_pct_reads_ledger_counters_the_program_has():
     assert entry == [{"name": "cache_hit_rows_pct", "unit": "%", "better": "higher",
                       "source": "program_counter", "moves": "checks_per_s",
                       "layer": "batch cut, dedup and verdict cache"}]
-    assert manifest["per_layer"][-4] is entry[0]        # appended, not inserted
+    assert manifest["per_layer"][34] is entry[0]        # appended, not inserted
     spec = harness._load_json(
         os.path.join(BENCH, "metrics", "cache_hit_rows_pct.json"))
     assert spec["reader"] == "ledger_ratio" and spec["args"]["scale"] == 100.0

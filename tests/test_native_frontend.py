@@ -1996,7 +1996,8 @@ def test_fast_lane_metrics_labeled_per_config(stack):
                         "status": "PERMISSION_DENIED"})
     for org in ("acme", "evil", "acme"):
         grpc_call(native_port, make_req("fast-eq.test", headers={"x-org": org}))
-    # the dispatcher folds metrics after completing the batch — the last
+    # the readback thread keeps a cut's telemetry after completing the batch
+    # and, with no other cut in flight, folds it at once (ISSUE 35) — the last
     # response can reach the client a beat before its own increment lands
     deadline = time.monotonic() + 10
     while (sample("auth_server_authconfig_total",
