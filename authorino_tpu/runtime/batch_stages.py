@@ -1,20 +1,23 @@
 """The stage clock of one lane's batches (docs/observability.md "Batch/device
-series"): a batch's life from the front end's flush to the end of its
-telemetry, cut into seven stages, each measured where its work happens with
-``time.monotonic_ns()`` and recorded once, into four sinks:
+series"): a batch's life from the arrival of its first row to the end of
+its telemetry, cut into eight stages, each measured where its work happens
+with ``time.monotonic_ns()`` and recorded once, into four sinks:
 
   - a cumulative table ``{stage: {count, sum_ns, max_ns}}`` (/debug/vars
     ``native_frontend.stages``): a reader takes the difference of two
-    scrapes, with no profiler running.  Beside the seven stages it holds the
+    scrapes, with no profiler running.  Beside the eight stages it holds the
     row ``drain``, which is no batch's: see ``StageClock.record_drain``;
   - ``auth_server_pipeline_stage_seconds{lane, stage}``;
   - a ``jax.profiler.TraceAnnotation("atpu/<lane>/<stage>", batch=<seq>)``
     around each same-thread stage, which costs nothing without a profiler
     session.  ``device`` has none: it is the gap between ``launch`` and
-    ``resolve`` of one ``batch``.  ``pickup`` starts in C++ before Python
-    sees the batch, so its annotation is a mark at dispatch entry that
-    carries ``mono_ns`` (now) and ``flush_mono_ns``: the pair ties
-    CLOCK_MONOTONIC to the profiler's clock and places the flush in the trace;
+    ``resolve`` of one ``batch``.  ``fill`` and ``pickup`` run in C++
+    before Python sees the batch (the front end stamps the first row's
+    arrival and the flush, native/frontend.cpp), so ``fill`` has none and
+    ``pickup``'s is a mark at dispatch entry that carries ``mono_ns`` (now),
+    ``flush_mono_ns`` and ``first_mono_ns``: the first pair ties
+    CLOCK_MONOTONIC to the profiler's clock, and the three place the flush
+    and the start of the cut's filling in the trace;
   - a ring of the last ``RING`` batches (/debug/batches, flight-recorder
     bundles), written once a batch at the end of ``post``.
 
@@ -37,10 +40,12 @@ from ..utils import metrics as metrics_mod
 __all__ = ["STAGES", "STAMPS", "FIELDS", "RING", "StageClock",
            "BatchTimeline"]
 
-STAGES = ("pickup", "plan", "encode", "launch", "device", "resolve", "post")
-STAMPS = ("flush", "entry", "planned", "encoded", "launched", "ready",
-          "completed", "posted")
+STAGES = ("fill", "pickup", "plan", "encode", "launch", "device", "resolve",
+          "post")
+STAMPS = ("first", "flush", "entry", "planned", "encoded", "launched",
+          "ready", "completed", "posted")
 _INDEX = {name: k for k, name in enumerate(STAGES)}
+_FILL, _PICKUP = _INDEX["fill"], _INDEX["pickup"]
 _DEVICE, _POST = _INDEX["device"], _INDEX["post"]
 RING = 2048
 
@@ -120,20 +125,25 @@ class StageClock:
         self._ring = np.zeros((RING, len(FIELDS)), dtype=np.int64)
         self._committed = 0  # one writer: the thread that runs `post`
 
-    def begin(self, snap: int, slot: int, rows: int,
-              flush_ns: int = 0) -> BatchTimeline:
-        """Dispatch entry.  ``flush_ns``: when the front end cut the batch
-        (CLOCK_MONOTONIC); 0 where no cut led here (a retry), which records
-        no ``pickup``."""
+    def begin(self, snap: int, slot: int, rows: int, flush_ns: int = 0,
+              first_ns: int = 0) -> BatchTimeline:
+        """Dispatch entry.  ``flush_ns``: when the front end cut the batch,
+        ``first_ns``: when the cut's first row arrived (both
+        CLOCK_MONOTONIC); 0 where no cut led here (a retry), which records
+        neither ``fill`` nor ``pickup``."""
         now = time.monotonic_ns()
         b = BatchTimeline(self, next(self._seq), snap, slot, rows)
-        b.t[1] = now
+        b.t[_PICKUP + 1] = now
         if flush_ns:
-            b.t[0] = flush_ns
-            with self.annotate(self.span_names[0], batch=b.seq, mono_ns=now,
-                               flush_mono_ns=flush_ns):
+            b.t[_PICKUP] = flush_ns
+            b.t[_FILL] = first_ns
+            with self.annotate(self.span_names[_PICKUP], batch=b.seq,
+                               mono_ns=now, flush_mono_ns=flush_ns,
+                               first_mono_ns=first_ns):
                 pass
-            self.record(0, now - flush_ns)
+            if first_ns:
+                self.record(_FILL, flush_ns - first_ns)
+            self.record(_PICKUP, now - flush_ns)
         return b
 
     def record(self, k: int, dur_ns: int) -> None:

@@ -1093,6 +1093,8 @@ class NativeFrontend:
         callers (bench, /debug scrapes) must not interleave delta reads."""
         with self._drain_lock:
             self._stats_drain.fold(self.stats())
+            if self._mod is not None:
+                self._stats_drain.fold_loop_clock(self._mod.fe_loop_clock())
 
     def _observe_drain(self, dur_ns: int, children: int) -> None:
         self.batch_stages.record_drain(dur_ns)
@@ -1160,6 +1162,9 @@ class NativeFrontend:
             # {stage: {count, sum_ns, max_ns}}, cumulative: a reader takes
             # the difference of two scrapes (runtime/batch_stages.py)
             "stages": self.batch_stages.totals(),
+            # the C++ front end's loop clock (native/frontend.cpp "The loop
+            # clock"): where its one epoll thread's time goes, cumulative
+            "front": self._mod.fe_loop_clock() if self._mod else {},
             # the drain above folded the keep: `folded_cuts` is every cut
             # `post` has kept, `keep_max` the most one fold took
             "post": {"sampled_decisions": self._sampled_decisions,
@@ -2233,27 +2238,24 @@ class NativeFrontend:
             acc = self.stage_totals.setdefault(stage, [0] * len(counts))
             for i, n in enumerate(counts):
                 acc[i] += n
-            # sum approximated from bucket midpoints: the stage series is
-            # for shape/percentiles, not totals (bounds are µs-dense)
-            bounds = stages["bounds_ns"]
-            mids = [b / 2e9 if i == 0 else (bounds[i - 1] + b) / 2e9
-                    for i, b in enumerate(bounds)] + [bounds[-1] / 1e9]
-            est_sum = sum(n * mids[i] for i, n in enumerate(counts))
+            # the sum is exact: C++ keeps it beside the buckets
             metrics_mod.observe_bucketed(
                 metrics_mod.frontend_stage_duration.labels(stage),
-                counts, est_sum)
+                counts, stages["sum_ns"][stage] / 1e9)
         self.stage_totals["bounds_ns"] = stages["bounds_ns"]
 
     def _dispatch_loop(self) -> None:
         mod = self._mod
         while self._running:
-            kind, a, b, c, flush_ns, ovf_rows = mod.fe_wait_batch(200)
+            kind, a, b, c, flush_ns, ovf_rows, first_ns = \
+                mod.fe_wait_batch(200)
             self._fold_fc_counts()
             if kind == EV_BATCH:
                 try:
                     self._dispatch(int(a), int(b), int(c),
                                    flush_ns=int(flush_ns),
-                                   ovf_rows=int(ovf_rows))
+                                   ovf_rows=int(ovf_rows),
+                                   first_ns=int(first_ns))
                 except Exception as e:
                     log.exception("native batch dispatch failed")
                     # retry once, then degrade (CPU-backend kernel) — fail
@@ -2338,7 +2340,8 @@ class NativeFrontend:
 
     def _dispatch(self, snap_id: int, slot: int, count: int,
                   attempt: int = 0, spill: bool = True,
-                  flush_ns: int = 0, ovf_rows: int = 0) -> None:
+                  flush_ns: int = 0, ovf_rows: int = 0,
+                  first_ns: int = 0) -> None:
         """Launch stage: non-blocking kernel dispatch for one C++-encoded
         slot, then park the in-flight batch on the readback queue.  The
         dispatcher thread is immediately free to launch the next slot, so
@@ -2355,13 +2358,14 @@ class NativeFrontend:
         ``attempt`` is the retry generation (0 = first dispatch, 1 = the
         one retry after a device failure); an OPEN circuit breaker skips
         the device entirely and decides the slot on the CPU backend.
-        ``flush_ns`` is when the C++ front end cut the slot (0 on a retry):
-        the start of the batch's stage clock (runtime/batch_stages.py);
+        ``flush_ns`` is when the C++ front end cut the slot and ``first_ns``
+        when the slot's first row arrived (0 on a retry): the first two
+        stamps of the batch's stage clock (runtime/batch_stages.py);
         ``ovf_rows`` how many of the cut's rows carried a value past
         DFA_VALUE_BYTES, as the encoder counted them (0 on a retry: the
         ledger's ``dfa_ovf_rows`` counts a cut once)."""
         rec = self._snaps[snap_id]
-        bt = self.batch_stages.begin(snap_id, slot, count, flush_ns)
+        bt = self.batch_stages.begin(snap_id, slot, count, flush_ns, first_ns)
         with bt.stage("plan"):
             allowed, probe = self.breaker.admit_device()
             if not allowed:

@@ -408,6 +408,7 @@ pipeline_stage_duration = _histogram(
     "dispatch call (operand H2D enqueue); device = launch to readback "
     "arrival (link RTT + kernel); resolve = readback to future resolution.  "
     "The native lane's batch stage clock (runtime/batch_stages.py) adds "
+    "fill = the cut's first row to its flush, "
     "pickup = front-end flush to dispatch entry, plan = breaker, lane "
     "choice, cache probe and dedup, post = cache puts and per-batch "
     "telemetry after the answers left.",
@@ -480,7 +481,7 @@ _stage_children: dict = {}
 
 def observe_pipeline_stage(lane, stage, seconds) -> None:
     """Record one pipeline-stage wall-time sample (cached label children:
-    this runs up to seven times per micro-batch)."""
+    this runs up to eight times per micro-batch)."""
     ch = _stage_children.get((lane, stage))
     if ch is None:
         ch = _stage_children[(lane, stage)] = (
@@ -569,6 +570,13 @@ native_frontend_events = _counter(
     "trace sampling, parse errors.",
     ("event",),
 )
+frontend_loop_seconds = _counter(
+    "auth_server_frontend_loop_seconds_total",
+    "Seconds the native frontend's one epoll thread spent in each phase of "
+    "its loop (idle = inside epoll_wait); the phases add up to its wall "
+    "time, so 1 - rate(idle) is the thread's busy share.",
+    ("phase",),
+)
 native_frontend_queue_depth = _gauge(
     "auth_server_native_frontend_queue_depth",
     "Live backlog of the native frontend's slow lane (queued = awaiting "
@@ -610,6 +618,20 @@ class NativeStatsDrain:
                         native_frontend_events.labels(key))
                 child.inc(delta)
             self._last[key] = value
+
+    def fold_loop_clock(self, front) -> None:
+        """The loop clock's phases (fe_loop_clock()["phases"]: the thread's
+        own, which add up to its wall time) into
+        auth_server_frontend_loop_seconds_total as deltas."""
+        for phase, row in (front.get("phases") or {}).items():
+            key = ("loop", phase)
+            child = self._children.get(key)
+            if child is None:
+                child = self._children[key] = frontend_loop_seconds.labels(phase)
+            delta = row["sum_ns"] - self._last.get(key, 0)
+            if delta > 0:
+                child.inc(delta * 1e-9)
+            self._last[key] = row["sum_ns"]
 
 
 # ---------------------------------------------------------------------------

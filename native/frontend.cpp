@@ -494,6 +494,121 @@ static inline int stage_bucket(int64_t ns) {
   return N_STAGE_BUCKETS - 1;
 }
 
+// ---------------------------------------------------------------------------
+// The loop clock: where the one epoll thread's time goes, by phase
+// (docs/observability.md "The front end's loop clock").  The shape of the
+// batch stage clock (runtime/batch_stages.py): rows {count, sum_ns, max_ns},
+// cumulative, read as the difference of two scrapes, on CLOCK_MONOTONIC
+// (Python's time.monotonic_ns()), always on.  A stamp charges what lies
+// since the last stamp to the phase it names, so the thread's phases add up
+// to its wall time and a phase holds its self time: `read` is a connection
+// event's recv + mem_recv less the `parse`, `encode`, `ovf_scan` and `cut`
+// its callbacks stamped on the way.  The names are written here and nowhere
+// else: fe_loop_clock() carries them out, the thread's phases under
+// `phases` and the rows that are not phases of it under `rows`.
+// ---------------------------------------------------------------------------
+enum ClockRowId {
+  PH_IDLE = 0,   // inside epoll_wait; count: wakes
+  PH_READ,       // recv + nghttp2 framing, HPACK, callbacks' bookkeeping; count: recv calls
+  PH_PARSE,      // process_check entry -> the chosen FastConfig; count: Check requests
+  PH_ENCODE,     // ensure_fill + zero_row + encode_fast; count: rows encoded
+  PH_OVF_SCAN,   // dfa_scan of a value past DVB; count: such rows
+  PH_CUT,        // flush_batch; count: cuts flushed
+  PH_RESPOND,    // drain_done's submit loop; count: answers submitted
+  PH_WRITE,      // conn_pump (mem_send + send); count: send calls
+  PH_OTHER,      // accept, close, direct answers, push_slow, eventfd/timerfd reads; count: events
+  N_LOOP_PHASES,
+  // not phases of the thread: a wake's busy stretch, and the three
+  // per-request stages of the histograms with their exact sums
+  ROW_TURN = N_LOOP_PHASES,
+  ROW_REQ_WAIT,
+  ROW_REQ_EXEC,
+  ROW_REQ_RESPOND,
+  N_CLOCK_ROWS
+};
+static const char* const CLOCK_ROW_NAMES[N_CLOCK_ROWS] = {
+    "idle", "read", "parse", "encode", "ovf_scan", "cut", "respond", "write",
+    "other", "turn", "req_wait", "req_exec", "req_respond"};
+
+// two branches off the thread's path that say who holds it back, each with
+// its operator's use in docs/observability.md: the peer does not read
+// (`write` is then the peer's time), the pipeline behind the cut is full
+// (`fill` then passes the window)
+enum LoopCounterId { LC_SEND_BLOCKED = 0, LC_CUTS_DEFERRED, N_LOOP_COUNTERS };
+static const char* const LOOP_COUNTER_NAMES[N_LOOP_COUNTERS] = {
+    "send_blocked", "cuts_deferred"};
+
+// a turn is slow when its busy stretch passed SLOW_TURN_BUSY_NS, or when the
+// idle before it passed SLOW_TURN_IDLE_NS with a row in the filling slot or a
+// cut not yet completed (an idle server's 100 ms time-outs do not qualify)
+static const int64_t SLOW_TURN_BUSY_NS = 5000000LL;
+static const int64_t SLOW_TURN_IDLE_NS = 50000000LL;
+static const int N_SLOW_TURNS = 64;
+
+struct SlowTurn {
+  int64_t wake_mono_ns, idle_ns, busy_ns;
+  int64_t events, requests, answers;
+};
+
+struct ClockRow {
+  std::atomic<uint64_t> count{0}, sum_ns{0}, max_ns{0};
+  // one writer (the epoll thread): a load and a store, no locked instruction
+  void add(int64_t ns) {
+    const uint64_t v = ns > 0 ? (uint64_t)ns : 0;
+    sum_ns.store(sum_ns.load(std::memory_order_relaxed) + v, std::memory_order_relaxed);
+    if (v > max_ns.load(std::memory_order_relaxed)) max_ns.store(v, std::memory_order_relaxed);
+  }
+  void bump(uint64_t n = 1) {
+    count.store(count.load(std::memory_order_relaxed) + n, std::memory_order_relaxed);
+  }
+  // any thread (complete_batch runs on Python's): once a cut
+  void add_shared(uint64_t n, uint64_t total_ns, uint64_t longest_ns) {
+    count.fetch_add(n, std::memory_order_relaxed);
+    sum_ns.fetch_add(total_ns, std::memory_order_relaxed);
+    uint64_t seen = max_ns.load(std::memory_order_relaxed);
+    while (longest_ns > seen &&
+           !max_ns.compare_exchange_weak(seen, longest_ns, std::memory_order_relaxed)) {}
+  }
+};
+
+struct LoopClock {
+  ClockRow rows[N_CLOCK_ROWS];
+  std::atomic<uint64_t> counters[N_LOOP_COUNTERS] = {};
+  // the last stamp (written by the epoll thread alone).  For a reader: the
+  // table is whole up to here, and what the thread has spent since is
+  // charged at its next stamp
+  std::atomic<int64_t> mark{0};
+  // the ring of slow turns: written for such turns alone, so its lock is
+  // off the thread's path
+  std::mutex turns_mu;
+  SlowTurn turns[N_SLOW_TURNS];
+  uint64_t n_turns = 0;
+
+  // charge what lies since the last stamp to `row`; returns now
+  int64_t stamp(int row) {
+    const int64_t now = now_mono_ns();
+    rows[row].add(now - mark.load(std::memory_order_relaxed));
+    mark.store(now, std::memory_order_relaxed);
+    return now;
+  }
+  void count(int c, uint64_t n = 1) {
+    counters[c].store(counters[c].load(std::memory_order_relaxed) + n,
+                      std::memory_order_relaxed);
+  }
+  // a wake's busy stretch ended at the last stamp
+  void end_turn(int64_t woke, int64_t idle_ns, bool owed, int events,
+                uint64_t requests, uint64_t answers) {
+    const int64_t busy = mark.load(std::memory_order_relaxed) - woke;
+    rows[ROW_TURN].add(busy);
+    rows[ROW_TURN].bump();
+    if (busy > SLOW_TURN_BUSY_NS || (owed && idle_ns > SLOW_TURN_IDLE_NS)) {
+      std::lock_guard<std::mutex> lk(turns_mu);
+      turns[n_turns++ % N_SLOW_TURNS] = {woke, idle_ns, busy, events,
+                                         (int64_t)requests, (int64_t)answers};
+    }
+  }
+};
+
 // one DFA leaf of a config: the attr it reads, its dfa table row, its column
 // in the config's own cpu_dense payload
 struct DfaRef { int32_t attr; int32_t row; int32_t col; };
@@ -571,8 +686,10 @@ struct Snapshot {
   // buckets + sum_ns) — drained into
   // auth_server_authconfig_duration_seconds (fe_drain_durations)
   std::unique_ptr<std::atomic<uint64_t>[]> fc_durs;
-  // monotonic flush time of each slot's current batch
+  // monotonic flush time of each slot's current batch, and the arrival of
+  // its first row (process_check's entry stamp): the cut's `fill` stage
   std::vector<int64_t> slot_flush_ns;
+  std::vector<int64_t> slot_first_ns;
 };
 
 // ---------------------------------------------------------------------------
@@ -623,8 +740,10 @@ enum EvKind { EV_TIMEOUT = 0, EV_BATCH = 1, EV_SNAP_RETIRED = 3, EV_STOPPED = 4 
 // d: for EV_BATCH, the slot's flush time (CLOCK_MONOTONIC ns, the clock of
 // Python's time.monotonic_ns()) — the start of the batch's `pickup` stage;
 // e: for EV_BATCH, the cut's rows with at least one value past DVB, whose
-// DFAs the encoder scanned here (the ledger's `dfa_ovf_rows`)
-struct Event { int kind; int64_t a, b, c, d, e; };
+// DFAs the encoder scanned here (the ledger's `dfa_ovf_rows`);
+// f: for EV_BATCH, the arrival of the cut's first row, on d's clock — the
+// start of the batch's `fill` stage
+struct Event { int kind; int64_t a, b, c, d, e, f; };
 
 struct Server {
   // config
@@ -687,6 +806,14 @@ struct Server {
   std::atomic<uint64_t> stage_wait[N_STAGE_BUCKETS] = {};
   std::atomic<uint64_t> stage_exec[N_STAGE_BUCKETS] = {};
   std::atomic<uint64_t> stage_respond[N_STAGE_BUCKETS] = {};
+  // what fe_stage_hist() last handed out of the three req_* rows' sum_ns
+  // (its callers hold the interpreter lock: one at a time)
+  uint64_t hist_drained[3] = {};
+  // the loop clock, and the cuts flushed and not yet completed: it moves
+  // where a snapshot's pending_batches does, under mu, so it cannot drift
+  // from them.  What a long idle is held against
+  LoopClock clk;
+  std::atomic<int64_t> cuts_owed{0};
   // duration-histogram leftovers of retired snapshots (key ns+'\x1f'+name;
   // under mu)
   std::unordered_map<std::string, std::array<uint64_t, DUR_STRIDE>> dur_leftover;
@@ -960,9 +1087,11 @@ static bool encode_fast(Server* S, Snapshot* snap, Slot& sl, int b,
         size_t sn = missing ? 0 : vn;
         const size_t ci = (size_t)fc.shard * snap->G + fc.row;
         if (ci >= snap->cfg_dfas.size()) return false;
+        S->clk.stamp(PH_ENCODE);
         for (const DfaRef& d : snap->cfg_dfas[ci])
           if (d.attr == attr)
             sl.cpu_dense[bs * snap->C + d.col] = dfa_scan(snap, d.row, sp, sn) ? 1 : 0;
+        S->clk.stamp(PH_OVF_SCAN);
       } else if (vn) {
         memcpy(sl.attr_bytes + (bs * NB + bslot) * DVB, vp, vn);
       }
@@ -1019,6 +1148,8 @@ static void disarm_timer(Server* S) {
 static void maybe_retire_locked(Server* S, std::vector<int64_t>& retired);
 static void emit_retired(Server* S, const std::vector<int64_t>& retired);
 
+// The caller stamps the loop clock on both sides: what led here is its own
+// phase's, what runs here `cut`'s.
 static void flush_batch(Server* S, bool from_timer = false) {
   if (S->fill_slot < 0) {
     disarm_timer(S);
@@ -1029,7 +1160,7 @@ static void flush_batch(Server* S, bool from_timer = false) {
   const int ovf_rows = S->fill_ovf_rows;
   std::vector<int64_t> retired;
   bool flushed = false;
-  int64_t flush_ns = 0;
+  int64_t flush_ns = 0, first_ns = 0;
   {
     // fill_slot/fill_snap transitions stay under mu: Python threads read
     // fill_snap in maybe_retire_locked (an unsynchronized shared_ptr
@@ -1048,11 +1179,14 @@ static void flush_batch(Server* S, bool from_timer = false) {
       // partial flush would burn a whole slot on a part-filled batch —
       // slot capacity in *requests* collapses and fast traffic spills to
       // the slow lane.  Let the batch keep filling; re-check next window.
+      S->clk.count(LC_CUTS_DEFERRED);
     } else {
       snap->slot_count[slot] = count;
       flush_ns = now_mono_ns();
       snap->slot_flush_ns[slot] = flush_ns;
+      first_ns = snap->slot_first_ns[slot];
       snap->pending_batches++;
+      S->cuts_owed.fetch_add(1, std::memory_order_relaxed);
       S->fill_slot = -1;
       S->fill_count = 0;
       S->fill_ovf_rows = 0;
@@ -1069,9 +1203,11 @@ static void flush_batch(Server* S, bool from_timer = false) {
   if (flushed) {
     {
       std::lock_guard<std::mutex> lk(S->batch_mu);
-      S->batch_events.push_back({EV_BATCH, snap->id, slot, count, flush_ns, ovf_rows});
+      S->batch_events.push_back(
+          {EV_BATCH, snap->id, slot, count, flush_ns, ovf_rows, first_ns});
     }
     S->batch_cv.notify_all();
+    S->clk.rows[PH_CUT].bump();
   }
 }
 
@@ -1159,7 +1295,25 @@ static inline void record_direct_dur(Snapshot* snap, int32_t fc_idx, int64_t t0)
 }
 
 static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
-  const int64_t t_start = now_mono_ns();
+  LoopClock& clk = S->clk;
+  // the request's arrival, and the loop clock's stamp: what led here was
+  // framing, `read`'s.  From here two more stamps a request: where `parse`
+  // ends and where `encode` does
+  const int64_t t_start = clk.stamp(PH_READ);
+  clk.rows[PH_PARSE].bump();
+  // every way out ends the phase `ph` names by then (declared before the
+  // pin guard: the unpin is that phase's too)
+  struct PhaseEnd {
+    LoopClock& clk;
+    int ph;
+    ~PhaseEnd() { clk.stamp(ph); }
+  } at_exit{clk, PH_PARSE};
+  // a direct answer or the slow lane: `parse` ends, the rest is `other`
+  auto direct = [&] {
+    clk.stamp(PH_PARSE);
+    clk.rows[PH_OTHER].bump();
+    at_exit.ph = PH_OTHER;
+  };
   if (st.body.size() < 5) { submit_grpc_error(c, stream_id, 13); return; }
   if (st.body[0] != 0) { submit_grpc_error(c, stream_id, 12); return; }  // compressed
   uint32_t mlen = ((uint8_t)st.body[1] << 24) | ((uint8_t)st.body[2] << 16) |
@@ -1187,7 +1341,7 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
       emit_retired(S, retired);
     }
   } pin_guard{S};
-  if (!snap) { push_slow(S, c, stream_id, msg, mlen); return; }
+  if (!snap) { direct(); push_slow(S, c, stream_id, msg, mlen); return; }
 
   ReqView rv;
   if (!parse_check_request(msg, mlen, rv)) {
@@ -1197,6 +1351,7 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
   }
   if (!rv.has_attributes || !rv.has_request || !rv.has_http) {
     S->n_invalid.fetch_add(1, std::memory_order_relaxed);
+    direct();
     submit_grpc_response(c, stream_id, snap->invalid_msg);
     return;
   }
@@ -1213,15 +1368,17 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
   }
   if (!found) {
     S->n_notfound.fetch_add(1, std::memory_order_relaxed);
+    direct();
     submit_grpc_response(c, stream_id, snap->notfound_msg);
     return;
   }
-  if (fc_idx < 0) { push_slow(S, c, stream_id, msg, mlen); return; }
+  if (fc_idx < 0) { direct(); push_slow(S, c, stream_id, msg, mlen); return; }
   if (snap->trace_every > 0 &&
       (int64_t)(S->trace_ctr.fetch_add(1, std::memory_order_relaxed) %
                 (uint64_t)snap->trace_every) == 0) {
     // sampled: full pipeline + span export in Python
     S->n_trace_sampled.fetch_add(1, std::memory_order_relaxed);
+    direct();
     push_slow(S, c, stream_id, msg, mlen);
     return;
   }
@@ -1277,6 +1434,7 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
           // unknown/expired credential: the slow lane verifies (and
           // registers on success) — full pipeline semantics
           S->n_dyn_miss.fetch_add(1, std::memory_order_relaxed);
+          direct();
           push_slow(S, c, stream_id, msg, mlen);
           return;
         }
@@ -1304,6 +1462,7 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
       S->n_unauth.fetch_add(1, std::memory_order_relaxed);
       S->n_denied.fetch_add(1, std::memory_order_relaxed);
       record_direct_dur(snap.get(), fc_idx, t_start);
+      direct();
       submit_grpc_response(c, stream_id, fc.unauth_msgs[extracted_static]);
       return;
     }
@@ -1315,63 +1474,87 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
     S->n_direct_ok.fetch_add(1, std::memory_order_relaxed);
     S->n_allowed.fetch_add(1, std::memory_order_relaxed);
     record_direct_dur(snap.get(), fc_idx, t_start);
+    direct();
     submit_grpc_response(c, stream_id,
                          ok_override ? *ok_override : fc.ok_msg);
     return;
   }
+  // the FastConfig and its identity are chosen: `parse` ends, `encode` begins
+  clk.stamp(PH_PARSE);
+  at_exit.ph = PH_ENCODE;
+  // the slow lane after all: what `encode` spent stays its own
+  auto to_slow = [&] {
+    clk.stamp(PH_ENCODE);
+    clk.rows[PH_OTHER].bump();
+    at_exit.ph = PH_OTHER;
+    push_slow(S, c, stream_id, msg, mlen);
+  };
   std::shared_ptr<Snapshot> fsnap;
   Slot* sl = ensure_fill(S, fsnap);
   if (sl == nullptr) {
     // no slot (exhausted or snapshot raced): flush and retry once
+    clk.stamp(PH_ENCODE);
     flush_batch(S);
+    clk.stamp(PH_CUT);
     sl = ensure_fill(S, fsnap);
-    if (sl == nullptr) { push_slow(S, c, stream_id, msg, mlen); return; }
+    if (sl == nullptr) { to_slow(); return; }
   }
   if (fsnap != snap) {
     // snapshot swapped between lookup and slot acquire: redo via slow lane
-    push_slow(S, c, stream_id, msg, mlen);
+    to_slow();
     return;
   }
   int b = S->fill_count;
   zero_row(snap.get(), *sl, b);
   S->fill_row_ovf = false;
   if (!encode_fast(S, snap.get(), *sl, b, fc, extra, rv)) {
-    push_slow(S, c, stream_id, msg, mlen);
+    to_slow();
     return;
   }
-  if (S->fill_row_ovf) S->fill_ovf_rows++;
+  if (S->fill_row_ovf) {
+    S->fill_ovf_rows++;
+    clk.rows[PH_OVF_SCAN].bump();
+  }
+  if (b == 0) snap->slot_first_ns[S->fill_slot] = t_start;
   snap->slot_entries[S->fill_slot].push_back(
       {c->id, stream_id, fc_idx, t_start, ok_override, std::move(ok_hold),
        deny_override, std::move(deny_hold),
        fc.hybrid ? std::string(msg, mlen) : std::string()});
   S->fill_count++;
   S->n_fast.fetch_add(1, std::memory_order_relaxed);
-  if (S->fill_count >= S->bmax) flush_batch(S);
-  else if (S->fill_count == 1) arm_timer(S);
+  clk.rows[PH_ENCODE].bump();
+  if (S->fill_count >= S->bmax) {
+    clk.stamp(PH_ENCODE);
+    flush_batch(S);
+    at_exit.ph = PH_CUT;
+  } else if (S->fill_count == 1) {
+    arm_timer(S);
+  }
 }
 
 static void process_request(Server* S, Conn* c, int32_t stream_id) {
   auto it = c->streams.find(stream_id);
   if (it == c->streams.end()) return;
   StreamSt& st = it->second;
-  switch (st.kind) {
-    case SK_HEALTH: {
-      std::shared_ptr<Snapshot> snap;
-      {
-        std::lock_guard<std::mutex> lk(S->mu);
-        snap = S->cur;
-      }
-      S->n_health.fetch_add(1, std::memory_order_relaxed);
-      submit_grpc_response(c, stream_id, snap ? snap->health_msg : S->health_msg);
-      break;
-    }
-    case SK_CHECK:
-      if (st.compressed) { submit_grpc_error(c, stream_id, 12); break; }
-      process_check(S, c, stream_id, st);
-      break;
-    default:
-      submit_grpc_error(c, stream_id, 12);  // UNIMPLEMENTED
+  if (st.kind == SK_CHECK && !st.compressed) {
+    process_check(S, c, stream_id, st);
+    return;
   }
+  // a direct answer: the loop clock's `other`
+  S->clk.stamp(PH_READ);
+  if (st.kind == SK_HEALTH) {
+    std::shared_ptr<Snapshot> snap;
+    {
+      std::lock_guard<std::mutex> lk(S->mu);
+      snap = S->cur;
+    }
+    S->n_health.fetch_add(1, std::memory_order_relaxed);
+    submit_grpc_response(c, stream_id, snap ? snap->health_msg : S->health_msg);
+  } else {
+    submit_grpc_error(c, stream_id, 12);  // compressed Check, or UNIMPLEMENTED
+  }
+  S->clk.rows[PH_OTHER].bump();
+  S->clk.stamp(PH_OTHER);
 }
 
 // ---- nghttp2 callbacks ----------------------------------------------------
@@ -1439,7 +1622,8 @@ static void conn_close(Server* S, Conn* c) {
   delete c;
 }
 
-// drain nghttp2's send queue into conn.outbuf, write once
+// drain nghttp2's send queue into conn.outbuf, write once.  The loop
+// clock's `write`: the caller stamps it when the pump returns.
 static bool conn_pump(Server* S, Conn* c) {
   for (;;) {
     if (c->outbuf.size() < (size_t)256 << 10) {
@@ -1453,8 +1637,10 @@ static bool conn_pump(Server* S, Conn* c) {
     }
     if (c->outbuf.empty()) break;
     ssize_t w = send(c->fd, c->outbuf.data(), c->outbuf.size(), MSG_NOSIGNAL);
+    S->clk.rows[PH_WRITE].bump();
     if (w < 0) {
       if (errno == EAGAIN || errno == EWOULDBLOCK) {
+        S->clk.count(LC_SEND_BLOCKED);
         if (!c->want_eout) {
           struct epoll_event ev;
           ev.events = EPOLLIN | EPOLLOUT;
@@ -1482,7 +1668,7 @@ static bool conn_pump(Server* S, Conn* c) {
 static void accept_conns(Server* S) {
   for (;;) {
     int fd = accept4(S->listen_fd, nullptr, nullptr, SOCK_NONBLOCK);
-    if (fd < 0) return;
+    if (fd < 0) break;
     int one = 1;
     setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof one);
     Conn* c = new Conn();
@@ -1512,8 +1698,12 @@ static void accept_conns(Server* S) {
     ev.events = EPOLLIN;
     ev.data.u32 = c->id;
     epoll_ctl(S->epfd, EPOLL_CTL_ADD, fd, &ev);
+    S->clk.rows[PH_OTHER].bump();
+    S->clk.stamp(PH_OTHER);
     conn_pump(S, c);
+    S->clk.stamp(PH_WRITE);
   }
+  S->clk.stamp(PH_OTHER);
 }
 
 static void drain_done(Server* S) {
@@ -1523,6 +1713,7 @@ static void drain_done(Server* S) {
     q.swap(S->done_q);
   }
   std::vector<Conn*> touched;
+  uint64_t answers = 0, timed = 0, late_sum = 0, late_max = 0;
   for (Done& d : q) {
     Conn* c;
     {
@@ -1533,20 +1724,48 @@ static void drain_done(Server* S) {
     if (!c) continue;
     if (d.grpc_status) submit_grpc_error(c, d.stream_id, d.grpc_status);
     else submit_grpc_response(c, d.stream_id, d.msg);
-    if (d.t_done)
-      S->stage_respond[stage_bucket(now_mono_ns() - d.t_done)].fetch_add(
-          1, std::memory_order_relaxed);
+    answers++;
+    if (d.t_done) {
+      const int64_t late = now_mono_ns() - d.t_done;
+      S->stage_respond[stage_bucket(late)].fetch_add(1, std::memory_order_relaxed);
+      const uint64_t v = late > 0 ? (uint64_t)late : 0;
+      timed++;
+      late_sum += v;
+      if (v > late_max) late_max = v;
+    }
     if (std::find(touched.begin(), touched.end(), c) == touched.end())
       touched.push_back(c);
   }
-  for (Conn* c : touched)
-    if (!conn_pump(S, c)) conn_close(S, c);
+  // the respond histogram's exact sum, once a drain
+  if (timed) S->clk.rows[ROW_REQ_RESPOND].add_shared(timed, late_sum, late_max);
+  S->clk.rows[PH_RESPOND].bump(answers);
+  S->clk.stamp(PH_RESPOND);
+  for (Conn* c : touched) {
+    const bool alive = conn_pump(S, c);
+    S->clk.stamp(PH_WRITE);
+    if (!alive) {
+      conn_close(S, c);
+      S->clk.rows[PH_OTHER].bump();
+      S->clk.stamp(PH_OTHER);
+    }
+  }
 }
 
 static void epoll_loop(Server* S) {
+  LoopClock& clk = S->clk;
   struct epoll_event evs[64];
+  clk.mark.store(now_mono_ns(), std::memory_order_relaxed);
   while (S->running.load(std::memory_order_relaxed)) {
+    // no stamp on the way in: what lies between a wake's last stamp and
+    // epoll_wait's return is `idle`'s, and the wake's turn ended at that stamp
+    const bool owed = S->fill_count > 0 ||
+                      S->cuts_owed.load(std::memory_order_relaxed) > 0;
+    const int64_t slept = clk.mark.load(std::memory_order_relaxed);
     int n = epoll_wait(S->epfd, evs, 64, 100);
+    const int64_t woke = clk.stamp(PH_IDLE);
+    clk.rows[PH_IDLE].bump();
+    const uint64_t req0 = clk.rows[PH_PARSE].count.load(std::memory_order_relaxed);
+    const uint64_t ans0 = clk.rows[PH_RESPOND].count.load(std::memory_order_relaxed);
     for (int i = 0; i < n; ++i) {
       uint32_t id = evs[i].data.u32;
       if (id == 0xFFFFFFFFu) {  // listen fd
@@ -1556,13 +1775,18 @@ static void epoll_loop(Server* S) {
       if (id == 0xFFFFFFFEu) {  // eventfd: completions pending
         uint64_t v;
         while (read(S->evfd, &v, 8) == 8) {}
+        clk.rows[PH_OTHER].bump();
+        clk.stamp(PH_OTHER);
         drain_done(S);
         continue;
       }
       if (id == 0xFFFFFFFDu) {  // timerfd: micro-batch window expired
         uint64_t v;
         while (read(S->tfd, &v, 8) == 8) {}
+        clk.rows[PH_OTHER].bump();
+        clk.stamp(PH_OTHER);
         flush_batch(S, /*from_timer=*/true);
+        clk.stamp(PH_CUT);
         continue;
       }
       Conn* c;
@@ -1578,6 +1802,7 @@ static void epoll_loop(Server* S) {
         char buf[65536];
         for (;;) {
           ssize_t r = recv(c->fd, buf, sizeof buf, 0);
+          clk.rows[PH_READ].bump();
           if (r > 0) {
             ssize_t rc = ng::api.mem_recv(c->sess, (const uint8_t*)buf, (size_t)r);
             if (rc < 0) { dead = true; break; }
@@ -1589,9 +1814,20 @@ static void epoll_loop(Server* S) {
           }
         }
       }
-      if (!dead) dead = !conn_pump(S, c);
-      if (dead) conn_close(S, c);
+      clk.stamp(PH_READ);
+      if (!dead) {
+        dead = !conn_pump(S, c);
+        clk.stamp(PH_WRITE);
+      }
+      if (dead) {
+        conn_close(S, c);
+        clk.rows[PH_OTHER].bump();
+        clk.stamp(PH_OTHER);
+      }
     }
+    clk.end_turn(woke, woke - slept, owed, n > 0 ? n : 0,
+                 clk.rows[PH_PARSE].count.load(std::memory_order_relaxed) - req0,
+                 clk.rows[PH_RESPOND].count.load(std::memory_order_relaxed) - ans0);
   }
   // shutdown: close all conns, notify waiters
   std::vector<Conn*> all;
@@ -1608,7 +1844,7 @@ static void epoll_loop(Server* S) {
   }
   {
     std::lock_guard<std::mutex> lk(S->batch_mu);
-    S->batch_events.push_back({EV_STOPPED, 0, 0, 0, 0, 0});
+    S->batch_events.push_back({EV_STOPPED, 0, 0, 0, 0, 0, 0});
   }
   S->batch_cv.notify_all();
   S->slow_cv.notify_all();
@@ -1767,7 +2003,7 @@ static void emit_retired(Server* S, const std::vector<int64_t>& retired) {
   if (retired.empty()) return;
   {
     std::lock_guard<std::mutex> lk(S->batch_mu);
-    for (int64_t id : retired) S->batch_events.push_back({EV_SNAP_RETIRED, id, 0, 0, 0, 0});
+    for (int64_t id : retired) S->batch_events.push_back({EV_SNAP_RETIRED, id, 0, 0, 0, 0, 0});
   }
   S->batch_cv.notify_all();
 }
@@ -1812,6 +2048,7 @@ static void complete_batch(Server* S, int64_t snap_id, int slot, const uint8_t* 
     }
     snap->free_slots.push_back(slot);
     snap->pending_batches--;
+    S->cuts_owed.fetch_sub(1, std::memory_order_relaxed);
   }
   for (Handoff& h : handoffs) {
     uint64_t id = 0;
@@ -1845,11 +2082,15 @@ static void complete_batch(Server* S, int64_t snap_id, int slot, const uint8_t* 
   // (ref pkg/service/auth_pipeline.go:26-36): all clocked here, on the box.
   // Hybrid handoffs skip the duration series — the Python pipeline they
   // continue into observes them itself (no double counting)
+  uint64_t wait_sum = 0, wait_max = 0;
   for (size_t i = 0; i < entries.size(); ++i) {
     const Entry& e = entries[i];
-    S->stage_wait[stage_bucket(t_flush - e.t_enq)].fetch_add(
-        1, std::memory_order_relaxed);
+    const int64_t waited = t_flush - e.t_enq;
+    S->stage_wait[stage_bucket(waited)].fetch_add(1, std::memory_order_relaxed);
     S->stage_exec[exec_b].fetch_add(1, std::memory_order_relaxed);
+    const uint64_t w = waited > 0 ? (uint64_t)waited : 0;
+    wait_sum += w;
+    if (w > wait_max) wait_max = w;
     if (verdict[i] != 0 && snap->fcs[e.fc].hybrid) continue;
     if (snap->fc_durs) {
       int64_t dur = t_now - e.t_enq;
@@ -1858,6 +2099,10 @@ static void complete_batch(Server* S, int64_t snap_id, int slot, const uint8_t* 
       d[N_DUR_BUCKETS].fetch_add((uint64_t)dur, std::memory_order_relaxed);
     }
   }
+  // the two histograms' exact sums, once a cut
+  const uint64_t exec_ns = t_now > t_flush ? (uint64_t)(t_now - t_flush) : 0;
+  S->clk.rows[ROW_REQ_WAIT].add_shared(entries.size(), wait_sum, wait_max);
+  S->clk.rows[ROW_REQ_EXEC].add_shared(entries.size(), exec_ns * entries.size(), exec_ns);
   S->n_hybrid.fetch_add(handed_off, std::memory_order_relaxed);
   S->n_allowed.fetch_add(allowed, std::memory_order_relaxed);
   S->n_denied.fetch_add(entries.size() - handed_off - allowed,

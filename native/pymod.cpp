@@ -548,6 +548,7 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
   snap->slot_entries.resize(snap->slots.size());
   snap->slot_count.resize(snap->slots.size(), 0);
   snap->slot_flush_ns.resize(snap->slots.size(), 0);
+  snap->slot_first_ns.resize(snap->slots.size(), 0);
 
   std::vector<int64_t> retired;
   {
@@ -560,14 +561,14 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
   return PyLong_FromLong(0);
 }
 
-// fe_wait_batch(timeout_ms) -> (kind, a, b, c, d, e)
+// fe_wait_batch(timeout_ms) -> (kind, a, b, c, d, e, f)
 PyObject* fe_wait_batch_py(PyObject*, PyObject* args) {
   long timeout_ms;
   if (!PyArg_ParseTuple(args, "l", &timeout_ms)) return nullptr;
   fe::Server* S = fe::g_srv;
   if (S == nullptr)
-    return Py_BuildValue("(iLLLLL)", (int)fe::EV_STOPPED, 0LL, 0LL, 0LL, 0LL, 0LL);
-  fe::Event ev = {fe::EV_TIMEOUT, 0, 0, 0, 0, 0};
+    return Py_BuildValue("(iLLLLLL)", (int)fe::EV_STOPPED, 0LL, 0LL, 0LL, 0LL, 0LL, 0LL);
+  fe::Event ev = {fe::EV_TIMEOUT, 0, 0, 0, 0, 0, 0};
   Py_BEGIN_ALLOW_THREADS {
     std::unique_lock<std::mutex> lk(S->batch_mu);
     if (S->batch_events.empty())
@@ -579,8 +580,9 @@ PyObject* fe_wait_batch_py(PyObject*, PyObject* args) {
     }
   }
   Py_END_ALLOW_THREADS
-  return Py_BuildValue("(iLLLLL)", ev.kind, (long long)ev.a, (long long)ev.b,
-                       (long long)ev.c, (long long)ev.d, (long long)ev.e);
+  return Py_BuildValue("(iLLLLLL)", ev.kind, (long long)ev.a, (long long)ev.b,
+                       (long long)ev.c, (long long)ev.d, (long long)ev.e,
+                       (long long)ev.f);
 }
 
 // fe_take_slow(timeout_ms, max_n) -> list[(req_id, bytes)]
@@ -763,29 +765,90 @@ PyObject* fe_drain_durations_py(PyObject*, PyObject*) {
 }
 
 // fe_stage_hist() -> {"wait": [...], "exec": [...], "respond": [...],
-// "bounds_ns": [...]} — drains (resets) the on-box per-request stage
-// histograms: queue-wait (encode→flush), execute (flush→complete),
-// respond (complete→HTTP/2 submit)
+// "bounds_ns": [...], "sum_ns": {stage: ns}} — drains (resets) the on-box
+// per-request stage histograms: queue-wait (encode→flush), execute
+// (flush→complete), respond (complete→HTTP/2 submit).  sum_ns is exact:
+// what the loop clock's req_* rows gained since the last call.
 PyObject* fe_stage_hist_py(PyObject*, PyObject*) {
   fe::Server* S = fe::g_srv;
   PyObject* d = PyDict_New();
   if (S == nullptr || d == nullptr) return d;
-  auto dump = [&](const char* key, std::atomic<uint64_t>* arr) {
+  PyObject* sums = PyDict_New();
+  auto dump = [&](const char* key, std::atomic<uint64_t>* arr, int row) {
     PyObject* l = PyList_New(fe::N_STAGE_BUCKETS);
     for (int i = 0; i < fe::N_STAGE_BUCKETS; ++i)
       PyList_SET_ITEM(l, i, PyLong_FromUnsignedLongLong(arr[i].exchange(0)));
     PyDict_SetItemString(d, key, l);
     Py_DECREF(l);
+    uint64_t& last = S->hist_drained[row - fe::ROW_REQ_WAIT];
+    const uint64_t now = S->clk.rows[row].sum_ns.load();
+    PyObject* o = PyLong_FromUnsignedLongLong(now - last);
+    PyDict_SetItemString(sums, key, o);
+    Py_DECREF(o);
+    last = now;
   };
-  dump("wait", S->stage_wait);
-  dump("exec", S->stage_exec);
-  dump("respond", S->stage_respond);
+  dump("wait", S->stage_wait, fe::ROW_REQ_WAIT);
+  dump("exec", S->stage_exec, fe::ROW_REQ_EXEC);
+  dump("respond", S->stage_respond, fe::ROW_REQ_RESPOND);
+  PyDict_SetItemString(d, "sum_ns", sums);
+  Py_DECREF(sums);
   PyObject* b = PyList_New(fe::N_STAGE_BUCKETS - 1);
   for (int i = 0; i < fe::N_STAGE_BUCKETS - 1; ++i)
     PyList_SET_ITEM(b, i, PyLong_FromLongLong(fe::STAGE_BOUNDS_NS[i]));
   PyDict_SetItemString(d, "bounds_ns", b);
   Py_DECREF(b);
   return d;
+}
+
+// fe_loop_clock() -> {"phases": {phase: {count, sum_ns, max_ns}},
+// "rows": {row: {count, sum_ns, max_ns}}, "counters": {name: n},
+// "slow_turns": [{wake_mono_ns, idle_ns, busy_ns, events, requests,
+// answers}, oldest first], "mark_mono_ns": t} — the epoll thread's loop
+// clock (frontend.cpp "The loop clock"), cumulative.  `phases` are the
+// thread's own and add up to its wall time up to `mark_mono_ns`, its last
+// stamp; `rows` holds what is not a phase of it: `turn` and the three
+// per-request `req_*`.
+PyObject* fe_loop_clock_py(PyObject*, PyObject*) {
+  fe::Server* S = fe::g_srv;
+  if (S == nullptr) return PyDict_New();
+  fe::LoopClock& clk = S->clk;
+  PyObject* phases = PyDict_New();
+  PyObject* rest = PyDict_New();
+  for (int r = 0; r < fe::N_CLOCK_ROWS; ++r) {
+    PyObject* row = Py_BuildValue(
+        "{s:K,s:K,s:K}", "count", (unsigned long long)clk.rows[r].count.load(),
+        "sum_ns", (unsigned long long)clk.rows[r].sum_ns.load(),
+        "max_ns", (unsigned long long)clk.rows[r].max_ns.load());
+    PyDict_SetItemString(r < fe::N_LOOP_PHASES ? phases : rest,
+                         fe::CLOCK_ROW_NAMES[r], row);
+    Py_DECREF(row);
+  }
+  PyObject* counters = PyDict_New();
+  for (int c = 0; c < fe::N_LOOP_COUNTERS; ++c) {
+    PyObject* o = PyLong_FromUnsignedLongLong(clk.counters[c].load());
+    PyDict_SetItemString(counters, fe::LOOP_COUNTER_NAMES[c], o);
+    Py_DECREF(o);
+  }
+  fe::SlowTurn held[fe::N_SLOW_TURNS];
+  uint64_t n_turns;
+  {
+    std::lock_guard<std::mutex> lk(clk.turns_mu);
+    n_turns = clk.n_turns;
+    memcpy(held, clk.turns, sizeof held);
+  }
+  const uint64_t kept = n_turns < (uint64_t)fe::N_SLOW_TURNS ? n_turns : fe::N_SLOW_TURNS;
+  PyObject* turns = PyList_New((Py_ssize_t)kept);
+  for (uint64_t k = 0; k < kept; ++k) {
+    const fe::SlowTurn& t = held[(n_turns - kept + k) % fe::N_SLOW_TURNS];
+    PyList_SET_ITEM(turns, (Py_ssize_t)k, Py_BuildValue(
+        "{s:L,s:L,s:L,s:L,s:L,s:L}", "wake_mono_ns", (long long)t.wake_mono_ns,
+        "idle_ns", (long long)t.idle_ns, "busy_ns", (long long)t.busy_ns,
+        "events", (long long)t.events, "requests", (long long)t.requests,
+        "answers", (long long)t.answers));
+  }
+  return Py_BuildValue("{s:N,s:N,s:N,s:N,s:L}", "phases", phases, "rows", rest,
+                       "counters", counters, "slow_turns", turns,
+                       "mark_mono_ns", (long long)clk.mark.load());
 }
 
 PyObject* fe_stats_py(PyObject*, PyObject*) {
@@ -991,6 +1054,8 @@ PyMethodDef methods[] = {
      "drain per-authconfig duration histograms"},
     {"fe_stage_hist", fe_stage_hist_py, METH_NOARGS,
      "drain the on-box per-request stage histograms"},
+    {"fe_loop_clock", fe_loop_clock_py, METH_NOARGS,
+     "the epoll thread's loop clock, cumulative"},
     {"vc_new", vc_new_py, METH_VARARGS, "new native verdict cache"},
     {"vc_plan", vc_plan_py, METH_VARARGS,
      "probe the cache for a cut's rows and collapse its misses"},

@@ -30,8 +30,8 @@ needs_native = pytest.mark.skipif(
     not _native_available(), reason="native frontend unavailable")
 
 DEVICE_BATCH = STAGES
-CACHE_ONLY = ("pickup", "plan", "resolve", "post")
-HOST_LANE = ("pickup", "plan")
+CACHE_ONLY = ("fill", "pickup", "plan", "resolve", "post")
+HOST_LANE = ("fill", "pickup", "plan")
 
 
 def counts(fe):
@@ -70,7 +70,8 @@ def frontend():
 
 
 def walk(clock, flush_ns=0, launch=True):
-    b = clock.begin(7, 3, 5, flush_ns)
+    # the front end hands both stamps or neither: first row, then the flush
+    b = clock.begin(7, 3, 5, flush_ns, flush_ns - 500 if flush_ns else 0)
     with b.stage("plan"):
         pass
     if launch:
@@ -89,7 +90,7 @@ def walk(clock, flush_ns=0, launch=True):
 @pytest.mark.parametrize("flush,launch,want", [
     (True, True, DEVICE_BATCH),
     (True, False, CACHE_ONLY),
-    (False, True, tuple(s for s in STAGES if s != "pickup")),
+    (False, True, tuple(s for s in STAGES if s not in ("fill", "pickup"))),
 ])
 def test_clock_records_the_stages_that_ran(flush, launch, want):
     clock = StageClock("native")
@@ -102,6 +103,8 @@ def test_clock_records_the_stages_that_ran(flush, launch, want):
     assert taken == sorted(taken), "stamps run forward"
     if flush:
         assert totals["pickup"]["sum_ns"] >= 1000
+        assert totals["fill"]["sum_ns"] == 500
+        assert b.t[0] + 500 == b.t[1] < b.t[2], "first, flush, entry"
 
 
 def test_ring_keeps_the_newest_batches_newest_first():
@@ -117,7 +120,8 @@ def test_ring_keeps_the_newest_batches_newest_first():
     newest = dict(zip(whole["fields"], clock.to_json(3)["batches"][0]))
     assert newest["seq"] == RING + 5 and newest["snap"] == 7
     assert newest["slot"] == 3 and newest["rows"] == 5
-    assert newest["flush_ns"] == 0 < newest["entry_ns"] <= newest["posted_ns"]
+    assert newest["first_ns"] == newest["flush_ns"] == 0
+    assert 0 < newest["entry_ns"] <= newest["posted_ns"]
     assert len(clock.to_json(3)["batches"]) == 3
     assert clock.to_json(0)["batches"] == []
 
@@ -209,7 +213,7 @@ def test_every_drain_is_recorded_whoever_runs_it(frontend):
 
 
 def test_clock_cost_a_batch_is_small():
-    """All seven stages of one batch, no profiler session: tens of
+    """All eight stages of one batch, no profiler session: tens of
     microseconds at most (PERF.md section 6 gives the reading; the bound
     here only catches a clock that grew work per row or took a slow path)."""
     clock = StageClock("native")
@@ -266,7 +270,7 @@ def test_each_lane_records_its_stages_once_a_batch(frontend):
     assert older["device_rows"] == 1 and older["pad"] >= 1
     assert newest["device_rows"] == 0 and newest["pad"] == 0  # cache-only
     stamps = [older[s + "_ns"] for s in STAMPS]
-    assert all(stamps), "a device batch takes all eight stamps"
+    assert all(stamps), "a device batch takes all nine stamps"
     assert stamps == sorted(stamps)
     # the ring rides every flight bundle, whatever triggers it
     from authorino_tpu.runtime.flight_recorder import RECORDER
@@ -400,11 +404,14 @@ def test_capture_holds_the_native_spans_with_their_batch(frontend, tmp_path):
                 if e.name.startswith("atpu/native/"):
                     spans.setdefault(e.name.rsplit("/", 1)[1], []).append(
                         dict(e.stats))
-    assert set(spans) == set(STAGES) - {"device"}, sorted(spans)
+    # `fill` ran in C++ before the batch had a number and `device` crosses
+    # threads: neither has a span of its own
+    assert set(spans) == set(STAGES) - {"fill", "device"}, sorted(spans)
     seq = {s["batch"] for stage in spans.values() for s in stage}
     assert len(seq) == 1, "one batch, one sequence number on every span"
     pickup = spans["pickup"][0]
-    assert 0 < pickup["flush_mono_ns"] <= pickup["mono_ns"] <= time.monotonic_ns()
+    assert (0 < pickup["first_mono_ns"] <= pickup["flush_mono_ns"]
+            <= pickup["mono_ns"] <= time.monotonic_ns())
 
 
 # ---------------------------------------------------------------------------

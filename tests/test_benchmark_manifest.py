@@ -165,7 +165,8 @@ def test_size_class_metrics_read_ledger_counters_the_program_has(
         assert reader.read(old, **spec["args"]) is None
     if name == "cuts_per_fold":
         entry = [m for m in _manifest()["per_layer"] if m["name"] == name]
-        assert entry == [_manifest()["per_layer"][-1]] == [{
+        # PR 35 put it last of 39; later PRs append and move nothing
+        assert entry == [_manifest()["per_layer"][38]] == [{
             "name": name, "unit": "cuts", "better": "higher",
             "source": "program_counter", "moves": "checks_per_s",
             "layer": "launch, readback and fan-out"}]

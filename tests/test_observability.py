@@ -600,3 +600,68 @@ class TestNativeStatsDrain:
 
     def test_empty_fold_is_noop(self):
         metrics_mod.NativeStatsDrain().fold({})
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 36: the front end's loop clock — its rows are named once, in
+# native/frontend.cpp, and the drain folds the thread's own phases as deltas
+# ---------------------------------------------------------------------------
+
+def _cpp_list(name):
+    cpp = (Path(__file__).resolve().parent.parent
+           / "native" / "frontend.cpp").read_text()
+    m = re.search(name + r"\[[A-Z_]+\]\s*=\s*\{([^}]*)\}", cpp)
+    assert m, f"{name} not found in native/frontend.cpp"
+    return re.findall(r'"([a-z_]+)"', m.group(1))
+
+
+class TestLoopClockDrain:
+    # frontend.cpp: the phases come first, N_LOOP_PHASES = 9 of them
+    ROWS = _cpp_list("CLOCK_ROW_NAMES")
+    PHASES = ROWS[:9]
+
+    @staticmethod
+    def table(rows=None, **sum_ns):
+        def row(v):
+            return {"count": 1, "sum_ns": v, "max_ns": v}
+        return {"phases": {p: row(v) for p, v in sum_ns.items()},
+                "rows": {r: row(v) for r, v in (rows or {}).items()}}
+
+    def test_cpp_names_nine_phases_and_four_more_rows(self):
+        assert self.PHASES == ["idle", "read", "parse", "encode", "ovf_scan",
+                               "cut", "respond", "write", "other"]
+        assert self.ROWS[9:] == ["turn", "req_wait", "req_exec", "req_respond"]
+        # the two counters that have an operator's use (docs/observability.md)
+        assert _cpp_list("LOOP_COUNTER_NAMES") == ["send_blocked", "cuts_deferred"]
+
+    def test_fe_stats_keys_are_not_the_clocks(self):
+        # fe_stats() is folded as events: the clock has a call of its own
+        pymod = (Path(__file__).resolve().parent.parent
+                 / "native" / "pymod.cpp").read_text()
+        assert '{"fe_loop_clock", fe_loop_clock_py' in pymod
+        stats = pymod[pymod.index("PyObject* fe_stats_py"):]
+        stats = stats[:stats.index("return d;")]
+        for row in self.ROWS + _cpp_list("LOOP_COUNTER_NAMES"):
+            assert f'put("{row}"' not in stats
+
+    @pytest.mark.parametrize("phase", PHASES)
+    def test_phase_folds_as_deltas_in_seconds(self, phase):
+        drain = metrics_mod.NativeStatsDrain()
+        name, labels = "auth_server_frontend_loop_seconds_total", {"phase": phase}
+        base = sample(name, labels)
+        drain.fold_loop_clock(self.table(**{phase: 2_000_000_000}))
+        drain.fold_loop_clock(self.table(**{phase: 2_000_000_000}))
+        drain.fold_loop_clock(self.table(**{phase: 3_500_000_000}))
+        assert sample(name, labels) == pytest.approx(base + 3.5)
+
+    @pytest.mark.parametrize("row", ROWS[9:])
+    def test_rows_that_are_no_phase_of_the_thread_are_not_folded(self, row):
+        from prometheus_client import REGISTRY
+
+        drain = metrics_mod.NativeStatsDrain()
+        drain.fold_loop_clock(self.table(rows={row: 5_000_000_000}, idle=1))
+        assert REGISTRY.get_sample_value(
+            "auth_server_frontend_loop_seconds_total", {"phase": row}) is None
+
+    def test_a_stopped_frontend_folds_nothing(self):
+        metrics_mod.NativeStatsDrain().fold_loop_clock({})
