@@ -377,6 +377,13 @@ class CompiledPolicy:
         return self.dfa_accept[self.dfa_table_of_row]
 
     @property
+    def dfa_flags_by_row(self) -> np.ndarray:
+        """State flags per dfa row [R, S] uint8 (dfa_state_flags), for the
+        native encoder's host scan of values past DFA_VALUE_BYTES."""
+        return dfa_state_flags(self.dfa_tables,
+                               self.dfa_accept)[self.dfa_table_of_row]
+
+    @property
     def n_leaves(self) -> int:
         return int(self.leaf_op.shape[0])
 
@@ -678,6 +685,24 @@ def dfa_table_states(policy: "CompiledPolicy") -> np.ndarray:
         if (nxt == n).all():
             return n
         n = nxt
+
+
+DFA_ACCEPTS, DFA_ABSORBS = 1, 2
+
+
+def dfa_state_flags(tables: np.ndarray, accept: np.ndarray) -> np.ndarray:
+    """[T, S] uint8 read off the tables themselves, never off the regex:
+    DFA_ACCEPTS where the state accepts, DFA_ABSORBS where every one of its
+    256 transitions returns to it (the empty subset of an anchored pattern,
+    the accept of an unanchored one: compiler/redfa.py), so that no further
+    byte can change the verdict of a DFA that has reached it.  A padding
+    state self-loops too and is marked; nothing real reaches it."""
+    own = np.arange(tables.shape[1], dtype=tables.dtype)[None, :]
+    # the least and the largest target are the state itself: two reductions
+    # and no [T, S, 256] temporary (a sixth of the compare's time at 332 MB)
+    absorbs = (tables.min(axis=2) == own) & (tables.max(axis=2) == own)
+    return (accept.astype(np.uint8) * DFA_ACCEPTS
+            | absorbs.astype(np.uint8) * DFA_ABSORBS)
 
 
 def _natural_sizes(policy: "CompiledPolicy") -> Dict[str, Any]:

@@ -172,7 +172,9 @@ def _classify_selector(selector_str: str):
 def _const_plan(policy: CompiledPolicy, attr: int, const_doc: Dict[str, Any]):
     """K_CONST plan tuple for `attr` resolved against a constant auth doc,
     or None when the compact device payload can't hold the value (membership
-    overflow / DFA byte-tensor unfit) — which disqualifies the config."""
+    overflow, a NUL in a DFA operand) — which disqualifies the config.  A DFA
+    operand past DFA_VALUE_BYTES is flagged (the tuple's last field): the
+    C++ encoder scans it on the host as it does a request's long value."""
     from ..compiler.encode import _MISSING, _render
 
     res = sel.get(const_doc, policy.attr_selectors[attr])
@@ -189,11 +191,11 @@ def _const_plan(policy: CompiledPolicy, attr: int, const_doc: Dict[str, Any]):
     elif not missing:
         members = [vid]
     raw = rendered.encode("utf-8")
-    if int(policy.attr_byte_slot[attr]) >= 0 and (
-        len(raw) > DFA_VALUE_BYTES or 0 in raw
-    ):
-        return None  # const DFA operand the byte tensor can't hold
-    return (int(attr), K_CONST, "", int(vid), missing, members, raw, False)
+    dfa_operand = int(policy.attr_byte_slot[attr]) >= 0
+    if dfa_operand and 0 in raw:
+        return None  # byte 0 is the DFA's pad identity: host oracle only
+    return (int(attr), K_CONST, "", int(vid), missing, members, raw,
+            dfa_operand and len(raw) > DFA_VALUE_BYTES)
 
 
 def _const_doc(identity_obj) -> Dict[str, Any]:
@@ -1822,12 +1824,12 @@ class NativeFrontend:
                     # dfa_table_of_row for the native encoder
                     dt_tr = np.ascontiguousarray(policy.dfa_tables_by_row,
                                                  dtype=np.uint8)
-                    dt_ac = np.ascontiguousarray(policy.dfa_accept_by_row,
+                    dt_fl = np.ascontiguousarray(policy.dfa_flags_by_row,
                                                  dtype=np.uint8)
-                    rec.keepalive += [dt_tr, dt_ac]
+                    rec.keepalive += [dt_tr, dt_fl]
                     spec.update(dfa_R=int(dt_tr.shape[0]), dfa_S=int(dt_tr.shape[1]),
                                 dfa_trans_addr=dt_tr.ctypes.data,
-                                dfa_accept_addr=dt_ac.ctypes.data)
+                                dfa_flags_addr=dt_fl.ctypes.data)
                 spec["G"] = policy.n_configs
                 spec["cfg_dfas"] = _cfg_dfa_refs(policy)
 
@@ -1891,15 +1893,15 @@ class NativeFrontend:
                         np.concatenate([p.dfa_tables_by_row
                                         for p in sharded.shards]),
                         dtype=np.uint8)
-                    dt_ac = np.ascontiguousarray(
-                        np.concatenate([p.dfa_accept_by_row
+                    dt_fl = np.ascontiguousarray(
+                        np.concatenate([p.dfa_flags_by_row
                                         for p in sharded.shards]),
                         dtype=np.uint8)
-                    rec.keepalive += [dt_tr, dt_ac]
+                    rec.keepalive += [dt_tr, dt_fl]
                     spec.update(dfa_R=int(dt_tr.shape[0]),
                                 dfa_S=int(dt_tr.shape[1]),
                                 dfa_trans_addr=dt_tr.ctypes.data,
-                                dfa_accept_addr=dt_ac.ctypes.data)
+                                dfa_flags_addr=dt_fl.ctypes.data)
                     for s, p in enumerate(sharded.shards):
                         cfg_dfas += _cfg_dfa_refs(p, row_base=s * R)
                 spec["G"] = p0.n_configs

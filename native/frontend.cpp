@@ -512,7 +512,7 @@ enum ClockRowId {
   PH_READ,       // recv + nghttp2 framing, HPACK, callbacks' bookkeeping; count: recv calls
   PH_PARSE,      // process_check entry -> the chosen FastConfig; count: Check requests
   PH_ENCODE,     // ensure_fill + zero_row + encode_fast; count: rows encoded
-  PH_OVF_SCAN,   // dfa_scan of a value past DVB; count: such rows
+  PH_OVF_SCAN,   // scan_overflow of a value past DVB; count: such rows
   PH_CUT,        // flush_batch; count: cuts flushed
   PH_RESPOND,    // drain_done's submit loop; count: answers submitted
   PH_WRITE,      // conn_pump (mem_send + send); count: send calls
@@ -524,11 +524,17 @@ enum ClockRowId {
   ROW_REQ_WAIT,
   ROW_REQ_EXEC,
   ROW_REQ_RESPOND,
+  // counts alone, of the overflow scan (scan_overflow): the DFAs it entered
+  // and the table loads it made.  loads / dfas is the bytes a DFA read
+  // before its verdict was settled: the value's length where none absorbs
+  ROW_OVF_DFAS,
+  ROW_OVF_LOADS,
   N_CLOCK_ROWS
 };
 static const char* const CLOCK_ROW_NAMES[N_CLOCK_ROWS] = {
     "idle", "read", "parse", "encode", "ovf_scan", "cut", "respond", "write",
-    "other", "turn", "req_wait", "req_exec", "req_respond"};
+    "other", "turn", "req_wait", "req_exec", "req_respond", "ovf_dfas",
+    "ovf_loads"};
 
 // two branches off the thread's path that say who holds it back, each with
 // its operator's use in docs/observability.md: the peer does not read
@@ -613,6 +619,19 @@ struct LoopClock {
 // in the config's own cpu_dense payload
 struct DfaRef { int32_t attr; int32_t row; int32_t col; };
 
+// what the snapshot's [R, St] state flags say (compiler/compile.py
+// dfa_state_flags): the state accepts; every one of its 256 transitions
+// returns to it, so no further byte can change the DFA's verdict
+static const uint8_t DFA_ACCEPTS = 1, DFA_ABSORBS = 2;
+
+// one DFA of the overflow scan's live set
+struct ScanLane {
+  const uint8_t* trans;  // its [St, 256] table
+  const uint8_t* flags;  // its [St] state flags
+  int32_t col;           // its column of the row's cpu_dense
+  uint32_t state;
+};
+
 struct Entry {
   uint32_t conn_id;
   int32_t stream_id;
@@ -653,8 +672,11 @@ struct Snapshot {
   int G = 0;  // config rows per shard
   std::vector<std::vector<DfaRef>> cfg_dfas;  // [S*G]; rows globalized
   std::vector<uint8_t> dfa_trans;  // [S*R, St, 256]
-  std::vector<uint8_t> dfa_accept; // [S*R, St]
+  std::vector<uint8_t> dfa_flags;  // [S*R, St]: DFA_ACCEPTS | DFA_ABSORBS
   int dfa_S = 0;
+  // the overflow scan's live set (the epoll thread's alone), sized at
+  // install to the most DFA leaves any config has: no allocation a request
+  std::vector<ScanLane> scan_lanes;
   // head-based trace sampling: route every Nth fast-eligible request to
   // the slow lane for full span export (0 = tracing off → all fast).
   // The reference traces every request (ref pkg/service/auth.go:261); the
@@ -900,14 +922,51 @@ static inline void put_id(Snapshot* s, char* base, int64_t idx, int32_t v) {
   else ((int32_t*)base)[idx] = v;
 }
 
-// run one DFA over arbitrary-length bytes (exact overflow handling for the
-// device regex lane: the value doesn't fit the byte tensor, but the DFA
-// itself is length-agnostic — same tables, host scan)
-static bool dfa_scan(Snapshot* s, int32_t row, const char* p, size_t n) {
-  const uint8_t* t = s->dfa_trans.data() + (size_t)row * s->dfa_S * 256;
-  uint8_t state = 0;
-  for (size_t i = 0; i < n; ++i) state = t[(size_t)state * 256 + (uint8_t)p[i]];
-  return s->dfa_accept[(size_t)row * s->dfa_S + state] != 0;
+// the DFA leaves of config `ci` that read `attr`, over a value past the
+// device's byte tensor (exact: the DFA is length-agnostic, same tables, host
+// scan).  One pass over the value: every live DFA takes the same byte before
+// the next byte is read, so that their table loads (each table is its own
+// St x 256 bytes of a store far larger than the cache) are in flight
+// together and not one after another; a DFA leaves the live set in a state
+// that absorbs, where its verdict is settled.  Each DFA's cpu_dense column
+// is written from its last state's accept bit.
+static void scan_overflow(Server* S, Snapshot* s, size_t ci, int32_t attr,
+                          const char* p, size_t n, uint8_t* cpu_dense) {
+  ScanLane* lanes = s->scan_lanes.data();
+  const size_t St = (size_t)s->dfa_S;
+  const uint8_t first = n ? (uint8_t)p[0] : 0;
+  int live = 0;
+  for (const DfaRef& d : s->cfg_dfas[ci]) {
+    if (d.attr != attr) continue;
+    ScanLane& l = lanes[live++];
+    l.trans = s->dfa_trans.data() + (size_t)d.row * St * 256;
+    l.flags = s->dfa_flags.data() + (size_t)d.row * St;
+    l.col = d.col;
+    l.state = 0;
+    __builtin_prefetch(l.trans + first);
+    __builtin_prefetch(l.flags);
+  }
+  S->clk.rows[ROW_OVF_DFAS].bump((uint64_t)live);
+  uint64_t loads = 0;
+  for (size_t i = 0; i < n && live > 0; ++i) {
+    const uint8_t b = (uint8_t)p[i];
+    loads += (uint64_t)live;
+    for (int k = 0; k < live;) {
+      ScanLane& l = lanes[k];
+      const uint32_t next = l.trans[(size_t)l.state * 256 + b];
+      const uint8_t f = l.flags[next];
+      if (f & DFA_ABSORBS) {
+        cpu_dense[l.col] = f & DFA_ACCEPTS;
+        l = lanes[--live];  // not yet advanced by this byte: k stays
+      } else {
+        l.state = next;
+        ++k;
+      }
+    }
+  }
+  S->clk.rows[ROW_OVF_LOADS].bump(loads);
+  for (int k = 0; k < live; ++k)
+    cpu_dense[lanes[k].col] = lanes[k].flags[lanes[k].state] & DFA_ACCEPTS;
 }
 
 static void render_i64(int64_t v, std::string& out) {
@@ -1088,9 +1147,7 @@ static bool encode_fast(Server* S, Snapshot* snap, Slot& sl, int b,
         const size_t ci = (size_t)fc.shard * snap->G + fc.row;
         if (ci >= snap->cfg_dfas.size()) return false;
         S->clk.stamp(PH_ENCODE);
-        for (const DfaRef& d : snap->cfg_dfas[ci])
-          if (d.attr == attr)
-            sl.cpu_dense[bs * snap->C + d.col] = dfa_scan(snap, d.row, sp, sn) ? 1 : 0;
+        scan_overflow(S, snap, ci, attr, sp, sn, sl.cpu_dense + bs * snap->C);
         S->clk.stamp(PH_OVF_SCAN);
       } else if (vn) {
         memcpy(sl.attr_bytes + (bs * NB + bslot) * DVB, vp, vn);

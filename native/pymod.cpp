@@ -417,9 +417,9 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
   snap->dfa_S = (int)dict_int(d, "dfa_S");
   if (dfa_R > 0 && snap->dfa_S > 0) {
     const uint8_t* tr = (const uint8_t*)dict_addr(d, "dfa_trans_addr");
-    const uint8_t* ac = (const uint8_t*)dict_addr(d, "dfa_accept_addr");
+    const uint8_t* fl = (const uint8_t*)dict_addr(d, "dfa_flags_addr");
     snap->dfa_trans.assign(tr, tr + (size_t)dfa_R * snap->dfa_S * 256);
-    snap->dfa_accept.assign(ac, ac + (size_t)dfa_R * snap->dfa_S);
+    snap->dfa_flags.assign(fl, fl + (size_t)dfa_R * snap->dfa_S);
   }
   snap->G = (int)dict_int(d, "G", 0);
   snap->cfg_dfas.resize((size_t)snap->S * snap->G);
@@ -437,6 +437,9 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
       }
     }
   }
+  size_t most_dfas = 0;
+  for (const auto& refs : snap->cfg_dfas) most_dfas = std::max(most_dfas, refs.size());
+  snap->scan_lanes.resize(most_dfas);
   if (!dict_bytes(d, "invalid", snap->invalid_msg) ||
       !dict_bytes(d, "notfound", snap->notfound_msg) ||
       !dict_bytes(d, "health", snap->health_msg)) {

@@ -33,6 +33,7 @@ ROW = {"count", "sum_ns", "max_ns"}
 COUNTERS = {"send_blocked", "cuts_deferred"}
 STAGE_OF_ROW = {"req_wait": "wait", "req_exec": "exec",
                 "req_respond": "respond"}
+SCAN_COUNTS = ("ovf_dfas", "ovf_loads")  # counts alone: no time of their own
 
 
 @pytest.fixture(scope="module")
@@ -104,7 +105,7 @@ def test_table_splits_the_threads_phases_from_the_rows_that_are_not(frontend):
     assert list(table["phases"]) == ["idle", "read", "parse", "encode",
                                      "ovf_scan", "cut", "respond", "write",
                                      "other"]
-    assert set(table["rows"]) == {"turn"} | set(STAGE_OF_ROW)
+    assert set(table["rows"]) == {"turn"} | set(STAGE_OF_ROW) | set(SCAN_COUNTS)
     assert 0 < table["mark_mono_ns"] <= time.monotonic_ns()
 
 
@@ -185,6 +186,12 @@ def test_ovf_scan_counts_the_rows_the_batch_events_carry(frontend):
     assert delta(b, a, "ovf_scan") == native_ledger("dfa_ovf_rows") - l0 == 3
     assert delta(b, a, "ovf_scan", "sum_ns") > 0
     assert delta(b, a, "encode") == 4
+    # the config's one DFA entered once a scanned row; `^/api/v[0-9]+/ok`
+    # has no end anchor, so its accept absorbs after the tenth byte
+    assert delta(b, a, "ovf_dfas") == 3
+    assert delta(b, a, "ovf_loads") == 3 * len("/api/v2/ok")
+    assert all(row_of(b, r)["sum_ns"] == row_of(b, r)["max_ns"] == 0
+               for r in SCAN_COUNTS)
 
 
 # ---------------------------------------------------------------------------
@@ -312,27 +319,39 @@ def test_loop_seconds_family_carries_every_phase_after_one_drain(frontend):
 
     fe, port = frontend
 
-    def family():
-        table = clock(fe)
-        return {p: REGISTRY.get_sample_value(
-            "auth_server_frontend_loop_seconds_total", {"phase": p})
-            for p in (*table["phases"], *table["rows"])}
+    def drained():
+        """One drain of this front end, then what it has folded so far (its
+        own ledger of the table's sums, as its drain last read them) beside
+        the process's family, with no other drain of it in between."""
+        fe.drain_native_stats()
+        with fe._drain_lock:
+            table = clock(fe)
+            folded = {p: fe._stats_drain._last[("loop", p)]
+                      for p in table["phases"]}
+            family = {p: REGISTRY.get_sample_value(
+                "auth_server_frontend_loop_seconds_total", {"phase": p})
+                for p in (*table["phases"], *table["rows"])}
+        return folded, family
 
     grpc_call(port, fast_req("family"))
-    fe.drain_native_stats()
-    first = family()
-    assert {p for p, v in first.items() if v is not None} == set(clock(fe)["phases"])
     a = clock(fe)
+    folded0, first = drained()
+    assert {p for p, v in first.items() if v is not None} == set(a["phases"])
     time.sleep(0.25)
     grpc_call(port, fast_req("family-2"))
-    b = quiet(fe, 0)
-    fe.drain_native_stats()
-    second = family()
-    # deltas, not absolutes: idle grew by about what the table's did (a
-    # cadence drain may have folded part of it first, another frontend of
-    # this process none: the family is the process's)
-    grew = second["idle"] - first["idle"]
-    assert grew == pytest.approx(delta(b, a, "idle", "sum_ns") * 1e-9, rel=0.2)
+    quiet(fe, 0)
+    folded1, second = drained()
+    b = clock(fe)
+    for phase in a["phases"]:
+        # deltas, not absolutes: the family gained what this front end's
+        # drains read the table to have gained, to the float's rounding
+        assert second[phase] - first[phase] == pytest.approx(
+            (folded1[phase] - folded0[phase]) * 1e-9, abs=1e-6), phase
+        # and what a drain reads is the table: it lies between the readings
+        # taken before and after it
+        assert (row_of(a, phase)["sum_ns"] <= folded0[phase] <= folded1[phase]
+                <= row_of(b, phase)["sum_ns"]), phase
+    assert folded1["idle"] - folded0["idle"] >= 0.2e9
     assert second["idle"] >= 0.25
 
 
@@ -352,9 +371,10 @@ def check_stub(ch):
 
 
 def test_a_turn_held_past_5_ms_lands_in_slow_turns(frontend):
-    """No hook holds the thread: a request whose path is 4 MiB makes the
-    host DFA scan of its overflowed value a slow callback by itself (4 Mi
-    dependent table steps do not fit in 5 ms)."""
+    """No hook holds the thread: a request whose path is 4 MiB and keeps
+    the config's DFA alive to its end (digits inside `v[0-9]+`: no state on
+    the way absorbs) makes the host scan of its overflowed value a slow
+    callback by itself (4 Mi dependent table steps do not fit in 5 ms)."""
     fe, port = frontend
     a = quiet(fe, 0)
     time.sleep(0.35)  # an idle server: three 100 ms time-outs, none slow
@@ -364,7 +384,7 @@ def test_a_turn_held_past_5_ms_lands_in_slow_turns(frontend):
             f"127.0.0.1:{port}",
             options=[("grpc.max_send_message_length", -1)]) as ch:
         check_stub(ch)(make_req("fast-rx.test",
-                                path="/api/v2/ok/" + "x" * (4 << 20)),
+                                path="/api/v2" + "7" * (4 << 20)),
                        timeout=30)
     t1 = time.monotonic_ns()
     b = quiet(fe, a["phases"]["respond"]["count"] + 1)
@@ -553,5 +573,10 @@ def test_every_metric_of_the_issue_has_its_file():
     # generator stamps `sent` at its own queue: no metric reads it
     assert "client_turnaround_ms" not in per_layer
     assert all(per_layer[n]["moves"] == "checks_per_s" for n in want)
-    assert set(_front_metrics()) == {n for n in want if n.startswith("fe_")
-                                     and n != "fe_respond_p50_us"}
+    # ISSUE 37's two, data files over the same reader, in the two cells with
+    # rows past DFA_VALUE_BYTES
+    scan = {"fe_ovf_scan_us", "fe_ovf_loads_per_dfa"}
+    assert all(per_layer[n]["workloads"] == [
+        "routes-1k.unique-sat", "mixed-tenants-1k.unique-sat"] for n in scan)
+    assert set(_front_metrics()) == scan | {
+        n for n in want if n.startswith("fe_") and n != "fe_respond_p50_us"}

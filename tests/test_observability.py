@@ -630,7 +630,9 @@ class TestLoopClockDrain:
     def test_cpp_names_nine_phases_and_four_more_rows(self):
         assert self.PHASES == ["idle", "read", "parse", "encode", "ovf_scan",
                                "cut", "respond", "write", "other"]
-        assert self.ROWS[9:] == ["turn", "req_wait", "req_exec", "req_respond"]
+        # ... and, since ISSUE 37, the overflow scan's two counts
+        assert self.ROWS[9:] == ["turn", "req_wait", "req_exec", "req_respond",
+                                 "ovf_dfas", "ovf_loads"]
         # the two counters that have an operator's use (docs/observability.md)
         assert _cpp_list("LOOP_COUNTER_NAMES") == ["send_blocked", "cuts_deferred"]
 
