@@ -25,11 +25,11 @@ property — applied to the compiled artifact instead of the source policy):
      derived from BOTH the audited table and a fresh reference
      determinization (one reaching witness per state, plus an accepting and
      a rejecting extension per state, the empty string, and an exact
-     DFA_VALUE_BYTES-length boundary witness).  The audited-table witnesses
+     boundary witness as long as the row's size class's device width).  The audited-table witnesses
      catch transitions that accept too much; the fresh-table witnesses catch
      transitions that reject too much — a miscompiled row cannot hide on
      either side.  Simulation replays the kernel's semantics exactly (full
-     DFA_VALUE_BYTES scan, NUL padding as claimed-identity), so a corrupted
+     device-width scan, NUL padding as claimed-identity), so a corrupted
      pad column is caught too.
   3. **Lowerability report** — a static pass classifying every config as
      fast-lane or slow-lane with a reason code (catalogue below), surfaced
@@ -359,11 +359,30 @@ def _suffixes_to(trans: np.ndarray, targets: Set[int]) -> Dict[int, bytes]:
     return suf
 
 
-def _table_witnesses(trans: np.ndarray, accept: np.ndarray) -> Tuple[List[bytes], int]:
+def _dfa_row_widths(policy: CompiledPolicy) -> np.ndarray:
+    """[R] int: the widest device byte lane any size class that holds the
+    DFA row scans it at (compiler/compile.py SizeClass.device_width): the
+    length up to which a value of the row is decided on the device, so the
+    length the witnesses run to."""
+    memo = getattr(policy, "_dfa_row_widths", None)
+    if memo is None or memo[0] is not policy.classes:
+        out = np.full((policy.dfa_table_of_row.shape[0],), DFA_VALUE_BYTES,
+                      dtype=np.int64)
+        for cls in policy.classes or ():
+            out[cls.dfa_rows] = np.maximum(out[cls.dfa_rows],
+                                           int(cls.device_width))
+        memo = (policy.classes, out)
+        object.__setattr__(policy, "_dfa_row_widths", memo)
+    return memo[1]
+
+
+def _table_witnesses(trans: np.ndarray, accept: np.ndarray,
+                     width: int = DFA_VALUE_BYTES) -> Tuple[List[bytes], int]:
     """Witness strings derived from one transition table: a reaching
     witness per state plus an accepting and a rejecting extension per
-    state, the empty string, and one exact DFA_VALUE_BYTES boundary
-    witness.  Returns (witnesses, skipped_overlength)."""
+    state, the empty string, and one boundary witness of exactly ``width``
+    bytes (the row's class's device width).  Returns (witnesses,
+    skipped_overlength)."""
     wit = _state_witnesses(trans)
     acc_states = {s for s in wit if bool(accept[s])}
     rej_states = {s for s in wit if not bool(accept[s])}
@@ -378,33 +397,34 @@ def _table_witnesses(trans: np.ndarray, accept: np.ndarray) -> Tuple[List[bytes]
         if s in to_rej:
             cands.append(w + to_rej[s])
         for cand in cands:
-            if len(cand) > DFA_VALUE_BYTES:
+            if len(cand) > width:
                 skipped += 1
                 continue
             out.add(cand)
-    # boundary: pad some witness to EXACTLY DFA_VALUE_BYTES via a self-loop
+    # boundary: pad some witness to EXACTLY the device width via a self-loop
     # byte on its final state, proving the full-length scan path
     for s, w in sorted(wit.items()):
         row = trans[s]
         loop = next((b for b in _BYTE_ORDER[:0x5F] if int(row[b]) == s), None)
-        if loop is not None and len(w) < DFA_VALUE_BYTES:
-            out.add(w + bytes([loop]) * (DFA_VALUE_BYTES - len(w)))
+        if loop is not None and len(w) < width:
+            out.add(w + bytes([loop]) * (width - len(w)))
             break
     return sorted(out), skipped
 
 
 def _simulate_kernel_scan(trans: np.ndarray, accept: np.ndarray,
-                          witnesses: List[bytes]) -> np.ndarray:
+                          witnesses: List[bytes],
+                          width: int = DFA_VALUE_BYTES) -> np.ndarray:
     """Replay the kernel's DFA lane exactly: every value occupies a full
-    DFA_VALUE_BYTES buffer, NUL-padded, and the scan covers ALL bytes —
+    ``width``-byte buffer, NUL-padded, and the scan covers ALL bytes —
     NUL transitions come from the (claimed-identity) pad column, so a
     corrupted pad column changes results here just like on device."""
     n = len(witnesses)
-    buf = np.zeros((n, DFA_VALUE_BYTES), dtype=np.uint8)
+    buf = np.zeros((n, width), dtype=np.uint8)
     for i, w in enumerate(witnesses):
         buf[i, : len(w)] = np.frombuffer(w, dtype=np.uint8)
     state = np.zeros(n, dtype=np.int64)
-    for col in range(DFA_VALUE_BYTES):
+    for col in range(width):
         state = trans[state, buf[:, col]].astype(np.int64)
     return accept[state]
 
@@ -447,7 +467,9 @@ def _check_dfa_leaf(policy: CompiledPolicy, leaf: int,
             f"dfa row {row} points at table {t_i} outside the table axis "
             f"[0, {policy.dfa_tables.shape[0]})", loc, leaf=leaf,
             row=row)], 0, 0
-    key = (t_i, rx.pattern)
+    # the witnesses run to the widest lane a class scans this row at
+    width = int(_dfa_row_widths(policy)[row])
+    key = (t_i, rx.pattern, width)
     hit = memo.get(key)
     if hit is not None:
         f, w, sk = hit
@@ -482,7 +504,7 @@ def _check_dfa_leaf(policy: CompiledPolicy, leaf: int,
     else:
         sources.append((fresh.trans.astype(np.int64), fresh.accept))
     for src_trans, src_accept in sources:
-        wits, skipped = _table_witnesses(src_trans, src_accept)
+        wits, skipped = _table_witnesses(src_trans, src_accept, width)
         n_skip += skipped
         checked: List[bytes] = []
         texts: List[str] = []
@@ -495,7 +517,7 @@ def _check_dfa_leaf(policy: CompiledPolicy, leaf: int,
             checked.append(w)
         if not checked:
             continue
-        dev = _simulate_kernel_scan(trans, accept, checked)
+        dev = _simulate_kernel_scan(trans, accept, checked, width)
         n_wit += len(checked)
         for i, text in enumerate(texts):
             host = rx.search(text) is not None

@@ -57,7 +57,8 @@ __all__ = [
     "OP_RELATION", "NUMERIC_OPS",
     "ConfigRules", "CompiledPolicy", "ShapeTargets", "OwnLayout", "SizeClass",
     "compile_corpus", "derive_layouts", "CLASS_RATIO", "CLASS_FLOOR_BYTES",
-    "TRUE_SLOT", "FALSE_SLOT", "DFA_VALUE_BYTES",
+    "TRUE_SLOT", "FALSE_SLOT", "DFA_VALUE_BYTES", "DFA_WIDTHS",
+    "DFA_SCAN_BUDGET", "class_device_width",
 ]
 
 OP_EQ, OP_NEQ, OP_INCL, OP_EXCL, OP_CPU, OP_ERROR, OP_TREE_CPU, OP_REGEX_DFA = (
@@ -75,13 +76,42 @@ _NUM_OP_OF = {
     Operator.LE: OP_NUM_LE,
 }
 
-# max value length evaluated on the device regex lane; longer values (or
-# values containing NUL) fall back to the CPU regex lane per request — an
-# exactness-preserving overflow, so this is purely a transfer/compute vs
-# fallback-rate dial.  The byte tensor is [B, NB, DFA_VALUE_BYTES] on the
-# wire, the single biggest payload when regexes are present; 64 covers
-# typical URL paths/headers with headroom.
-DFA_VALUE_BYTES = int(os.environ.get("AUTHORINO_TPU_DFA_VALUE_BYTES", "64"))
+# The device regex lane's byte tensor is [B, NB, W]: a value of up to W bytes
+# is scanned on the device, a longer one (or one containing NUL) falls back to
+# an exact host scan per request, so W is purely a transfer/compute vs
+# fallback-rate dial.  W is a property of a config's SIZE CLASS
+# (``SizeClass.device_width``, ``class_device_width``), not of the corpus:
+# 64 covers URL paths and short headers and is the floor every class gets
+# (DFA_VALUE_BYTES); it does NOT cover what an Envoy edge forwards of a
+# browser (a user-agent is 105-135 bytes, a referer 45-200, a cookie 40-380),
+# so a class whose rows are cheap to scan takes 128 or 256 (DFA_WIDTHS).
+#
+# The width rule (ISSUE 38).  A row's scan costs D x S x W state-steps
+# (ops/pattern_eval.py _own_dfa_row_res: step maps [W, D, S, B], one-hot of
+# the bytes [B, D, W, 256]): the class's own size (the bytes of tables a row
+# gathers, D x S x 256) times W / 256.  A class takes the widest W of
+# DFA_WIDTHS with D x S x W <= DFA_SCAN_BUDGET, never under the floor.  The
+# budget is what the chip showed a launch can afford while the host still
+# paces the cell: at 82,944 state-steps a row (routes-1k: D 18, S 72, W 64)
+# a launch of 256 rows is 0.76 ms (PERF.md section 5, PR 37) against the
+# ~2.1 ms the front end takes to fill the cut at 120k Check()/s, the chip a
+# third busy; at 599,040 (mixed-tenants-1k's large class) the chip paces the
+# cell already, so doubling its W would halve the rate.  65,536 keeps a class
+# at the budget under routes-1k's cost a row.  Read from no flag and no
+# environment variable (AUTHORINO_TPU_DFA_VALUE_BYTES is gone: one width for
+# the whole corpus is what this rule replaces).
+DFA_VALUE_BYTES = 64
+DFA_WIDTHS = (64, 128, 256)
+DFA_SCAN_BUDGET = 65536
+
+
+def class_device_width(n_dfa_rows: int, n_states: int) -> int:
+    """The byte-lane width of a size class of D = ``n_dfa_rows`` DFA rows a
+    request row and a state axis S = ``n_states`` (the width rule above)."""
+    fit = [w for w in DFA_WIDTHS
+           if n_dfa_rows * n_states * w <= DFA_SCAN_BUDGET]
+    return max(fit, default=DFA_VALUE_BYTES)
+
 
 TRUE_SLOT = 0
 FALSE_SLOT = 1
@@ -384,6 +414,26 @@ class CompiledPolicy:
                                self.dfa_accept)[self.dfa_table_of_row]
 
     @property
+    def config_byte_width(self) -> np.ndarray:
+        """[G] int32: the byte-lane width of each config's size class; a
+        value longer than its config's overflows to the host scan."""
+        memo = getattr(self, "_config_byte_width", None)
+        if memo is None or memo[0] is not self.classes:
+            out = np.full((self.n_configs,), DFA_VALUE_BYTES, dtype=np.int32)
+            for cls in self.classes:
+                out[cls.configs] = cls.device_width
+            memo = (self.classes, out)
+            object.__setattr__(self, "_config_byte_width", memo)
+        return memo[1]
+
+    @property
+    def byte_width(self) -> int:
+        """The corpus's widest byte lane: the last axis of the encoders'
+        ``attr_bytes`` [B, NB, W]."""
+        return max((int(c.device_width) for c in self.classes),
+                   default=DFA_VALUE_BYTES)
+
+    @property
     def n_leaves(self) -> int:
         return int(self.leaf_op.shape[0])
 
@@ -653,10 +703,14 @@ class SizeClass:
     dfa_tables: np.ndarray        # [T_c, S_c, 256] uint8
     dfa_accept: np.ndarray        # [T_c, S_c] bool
     dfa_table_of_row: np.ndarray  # [R_c] int32 store row -> table of the store
+    # bytes of a value the device scans for a member (the width rule at
+    # DFA_VALUE_BYTES): a longer value overflows to the host scan
+    device_width: int = DFA_VALUE_BYTES
 
     def shape_key(self) -> tuple:
         return (self.own.shape_key(), self.own.evals.shape,
-                self.config_dfa_rows.shape, self.dfa_tables.shape)
+                self.config_dfa_rows.shape, self.dfa_tables.shape,
+                self.device_width)
 
     def widths(self) -> Dict[str, int]:
         """What /debug/vars lists of a class."""
@@ -666,6 +720,7 @@ class SizeClass:
             "leaf_cols_per_row": int(self.own.leaves.shape[1]),
             "dfa_rows_per_row": int(self.config_dfa_rows.shape[1]) if has_dfa else 0,
             "dfa_states": int(self.dfa_tables.shape[1]) if has_dfa else 0,
+            "device_width": int(self.device_width) if has_dfa else 0,
             "cpu_cols": int(self.own.cpu_leaves.shape[1]),
             "evaluators": int(self.own.evals.shape[2]),
         }
@@ -831,7 +886,10 @@ def _class_of(policy: "CompiledPolicy", cfgs: np.ndarray, sizes,
         dfa_rows=dfa_rows, config_dfa_rows=local_rows,
         dfa_tables=np.ascontiguousarray(policy.dfa_tables[tabs][:, :S_c]),
         dfa_accept=np.ascontiguousarray(policy.dfa_accept[tabs][:, :S_c]),
-        dfa_table_of_row=np.asarray(table_of_row, dtype=np.int32).reshape(-1))
+        dfa_table_of_row=np.asarray(table_of_row, dtype=np.int32).reshape(-1),
+        # forced widths (shards stack on one byte tensor): the floor
+        device_width=class_device_width(D_c, S_c)
+        if natural and dfa_rows.size else DFA_VALUE_BYTES)
 
 
 def derive_classes(policy: "CompiledPolicy",

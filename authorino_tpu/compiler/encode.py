@@ -22,7 +22,6 @@ import numpy as np
 from ..authjson import selector as sel
 from ..expressions.ast import parse_int_value
 from .compile import (
-    DFA_VALUE_BYTES,
     OP_CPU,
     OP_ERROR,
     OP_EXCL,
@@ -43,7 +42,7 @@ class EncodedBatch:
     overflow: np.ndarray       # [B, A] bool
     cpu_lane: np.ndarray       # [B, L] bool
     config_id: np.ndarray      # [B] int32
-    attr_bytes: np.ndarray     # [B, NB, DFA_VALUE_BYTES] uint8 (device regex lane)
+    attr_bytes: np.ndarray     # [B, NB, policy.byte_width] uint8 (device regex lane)
     byte_ovf: np.ndarray       # [B, NB] bool — value too long / has NUL → CPU lane
     # numeric comparator lane (ISSUE 14): parsed int32 value + validity per
     # compact numeric slot (None when the corpus has no numeric leaves)
@@ -159,9 +158,11 @@ def encode_batch_py(
     cpu_lane = np.zeros((B, L), dtype=bool)
     config_id = np.zeros((B,), dtype=np.int32)
     NB = max(policy.n_byte_attrs, 1)
-    attr_bytes = np.zeros((B, NB, DFA_VALUE_BYTES), dtype=np.uint8)
+    attr_bytes = np.zeros((B, NB, policy.byte_width), dtype=np.uint8)
     byte_ovf = np.zeros((B, NB), dtype=bool)
     attr_byte_slot = policy.attr_byte_slot
+    # a value overflows past ITS config's size class's width
+    byte_width = policy.config_byte_width
     # numeric + relation lanes (ISSUE 14) — inert (None) when absent
     NN = int(getattr(policy, "n_num_attrs", 0) or 0)
     num_attr_slot = policy.num_attr_slot if NN else None
@@ -212,7 +213,7 @@ def encode_batch_py(
             slot = attr_byte_slot[attr]
             if slot >= 0:
                 raw = rendered.encode("utf-8")
-                if len(raw) > DFA_VALUE_BYTES or 0 in raw:
+                if len(raw) > byte_width[row] or 0 in raw:
                     byte_ovf[r, slot] = True
                     if byte_ovf_attrs is None:
                         byte_ovf_attrs = set()

@@ -155,9 +155,8 @@ class PolicyModel:
             # re-pad to the full byte budget: an empty batch trims to the
             # minimum width, but the compile check must cover the widest
             # DFA-scan variant production values can trigger
-            from ..compiler.compile import DFA_VALUE_BYTES
-
-            full = np.zeros(attr_bytes.shape[:-1] + (DFA_VALUE_BYTES,), dtype=np.uint8)
+            full = np.zeros(attr_bytes.shape[:-1] + (self.policy.byte_width,),
+                            dtype=np.uint8)
             full[..., : attr_bytes.shape[-1]] = attr_bytes
             attr_bytes = full
         from ..ops.pattern_eval import _extra_operands

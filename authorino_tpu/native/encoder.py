@@ -19,7 +19,6 @@ import numpy as np
 
 from ..authjson import selector as sel
 from ..compiler.compile import (
-    DFA_VALUE_BYTES,
     OP_CPU,
     OP_ERROR,
     OP_EXCL,
@@ -144,6 +143,10 @@ class NativeEncoder:
         leaf_attr = np.ascontiguousarray(p.leaf_attr, dtype=np.int32)
         leaf_const = np.ascontiguousarray(p.leaf_const, dtype=np.int32)
         attr_byte_slot = np.ascontiguousarray(p.attr_byte_slot, dtype=np.int32)
+        # copied by policy_new: a value overflows past ITS config's width
+        cfg_byte_width = np.ascontiguousarray(
+            p.config_byte_width if p.n_configs else [0], dtype=np.int32)
+        self._byte_width = int(p.byte_width)
 
         self._handle = mod.policy_new(
             intern_blob, _addr(intern_offs), _addr(intern_ids), len(intern_strings),
@@ -152,7 +155,7 @@ class NativeEncoder:
             p.n_leaves, _addr(leaf_op), _addr(leaf_attr), _addr(leaf_const),
             p.n_configs, _addr(cfg_attr_offs), _addr(cfg_attr_idx_np),
             _addr(cfg_cpu_offs), _addr(cfg_cpu_idx_np),
-            p.members_k, DFA_VALUE_BYTES, max(p.n_byte_attrs, 1),
+            p.members_k, _addr(cfg_byte_width), max(p.n_byte_attrs, 1),
         )
         self.mode = os.environ.get("AUTHORINO_TPU_ENCODE_MODE", "object")
         # a few threads beyond the core count wins even on small hosts: the
@@ -209,14 +212,15 @@ class NativeEncoder:
         overflow = np.zeros((B, A), dtype=bool)
         cpu_lane = np.zeros((B, L), dtype=bool)
         config_id = np.zeros((B,), dtype=np.int32)
-        attr_bytes = np.zeros((B, NB, DFA_VALUE_BYTES), dtype=np.uint8)
+        W = self._byte_width
+        attr_bytes = np.zeros((B, NB, W), dtype=np.uint8)
         byte_ovf = np.zeros((B, NB), dtype=bool)
 
         if n:
             rows = np.asarray(config_rows, dtype=np.int32)
             config_id[:n] = rows
             max_tasks = int(self._cpu_task_bound[rows].sum()) + 1
-            arena_cap = max_tasks * (DFA_VALUE_BYTES + 64) + 4096
+            arena_cap = max_tasks * (W + 64) + 4096
             task_r = np.zeros(max_tasks, dtype=np.int32)
             task_leaf = np.zeros(max_tasks, dtype=np.int32)
             task_off = np.zeros(max_tasks, dtype=np.int64)
@@ -234,13 +238,13 @@ class NativeEncoder:
                 blob = b"".join(parts)
                 rc = self._mod.encode_json(
                     self._handle, blob, _addr(doc_offs), n, _addr(rows),
-                    A, K, L, NB, DFA_VALUE_BYTES, *out_addrs,
+                    A, K, L, NB, W, *out_addrs,
                     max_tasks, _addr(arena), arena_cap, self.n_threads, elem16)
             else:
                 try:
                     rc = self._mod.encode_docs(
                         self._handle, self._seg_objs, docs, _addr(rows), n,
-                        A, K, L, NB, DFA_VALUE_BYTES, *out_addrs,
+                        A, K, L, NB, W, *out_addrs,
                         max_tasks, _addr(arena), arena_cap, elem16)
                 except Exception:
                     return None  # render error (non-serializable nested value)
@@ -315,7 +319,7 @@ class NativeEncoder:
                 slot = int(p.attr_byte_slot[attr])
                 if slot >= 0:
                     raw = rendered.encode("utf-8")
-                    if len(raw) > DFA_VALUE_BYTES or 0 in raw:
+                    if len(raw) > p.config_byte_width[row] or 0 in raw:
                         byte_ovf[r, slot] = True
                     elif raw:
                         attr_bytes[r, slot, : len(raw)] = np.frombuffer(raw, dtype=np.uint8)

@@ -38,15 +38,26 @@ class CutPlan(NamedTuple):
     eligible_misses: int
 
 
-def key_segments(arrays) -> bytes:
+def key_segments(arrays, used=None) -> bytes:
     """The descriptor ``plan_cut`` reads a slot's rows through: (address,
-    bytes a row, rows) of each operand array, in key order.  The arrays must
-    stay alive and C-contiguous for as long as the descriptor is used."""
-    segs = np.empty((len(arrays), 3), dtype=np.uint64)
+    bytes a row, rows, sub-rows, their stride, used) of each operand array,
+    in key order.  ``used`` maps an array's position to a uint16 array, one
+    a row: that array's row is keyed by the first ``used[r]`` bytes of each
+    of its sub-rows (its last axis) and not whole; whoever fills the slot
+    keeps every byte past them zero.  The arrays must stay alive and
+    C-contiguous for as long as the descriptor is used."""
+    segs = np.zeros((len(arrays), 6), dtype=np.uint64)
     for i, a in enumerate(arrays):
         if not a.flags.c_contiguous:
             raise ValueError("key segment is not C-contiguous")
-        segs[i] = (a.ctypes.data, a.nbytes // a.shape[0], a.shape[0])
+        segs[i, :3] = (a.ctypes.data, a.nbytes // a.shape[0], a.shape[0])
+        u = (used or {}).get(i)
+        if u is not None:
+            if u.dtype != np.uint16 or u.shape != (a.shape[0],) \
+                    or not u.flags.c_contiguous:
+                raise ValueError("used bytes must be a contiguous uint16 a row")
+            stride = a.shape[-1] * a.itemsize
+            segs[i, 3:] = (segs[i, 1] // stride, stride, u.ctypes.data)
     return segs.tobytes()
 
 

@@ -646,10 +646,12 @@ class ShardedPolicyModel:
         members_c = np.full((B, S, M, K), PAD, dtype=np.int32)
         cpu_dense = np.zeros((B, S, C), dtype=bool)
         if self.has_dfa:
-            from ..compiler.compile import DFA_VALUE_BYTES
-
             NB = p0.n_byte_attrs
-            attr_bytes = np.zeros((B, S, NB, DFA_VALUE_BYTES), dtype=np.uint8)
+            # shards compile under ShapeTargets: one class each at the floor
+            # width, so this is DFA_VALUE_BYTES on every mesh corpus today
+            attr_bytes = np.zeros(
+                (B, S, NB, max(p.byte_width for p in self.shards)),
+                dtype=np.uint8)
             byte_ovf = np.zeros((B, S, NB), dtype=bool)
         else:
             attr_bytes = byte_ovf = None

@@ -52,6 +52,7 @@ _FIELDS = (
     "h2d_transfers", "h2d_bytes", "d2h_bytes", "pad_rows", "pad_waste_rows",
     "eff_slack_cols", "dedup_avoided_rows", "cache_avoided_rows",
     "dfa_ovf_rows", "own_dfa_slots", "own_dfa_rows", "telemetry_folds",
+    "eff_cols", "dfa_dev_bytes", "dfa_host_bytes",
 )
 
 
@@ -90,19 +91,26 @@ class CostLedger:
                 dedup_avoided_rows: int = 0,
                 cache_avoided_rows: int = 0,
                 dfa_ovf_rows: int = 0, own_dfa_slots: int = 0,
-                own_dfa_rows: int = 0) -> None:
+                own_dfa_rows: int = 0, eff_cols: int = 0,
+                dfa_dev_bytes: int = 0, dfa_host_bytes: int = 0) -> None:
         """Fold one batch: ``rows`` real requests in the cut, of which
         ``device_rows`` actually shipped (``pad_rows`` after padding) in
         ``launches`` device calls, their request operands handed to the
         runtime in ``h2d_transfers`` host-to-device transfers (1 when the
         launch staged one buffer, one an operand otherwise);
-        ``dfa_ovf_rows`` of the cut's rows carried a value past
-        DFA_VALUE_BYTES, whose DFAs the encoder scanned on the host
-        (the native lane counts them); ``own_dfa_slots`` DFA rows were
+        ``dfa_ovf_rows`` of the cut's rows carried a value past their
+        config's size class's byte width, whose DFAs the encoder scanned on
+        the host (the native lane counts them); ``own_dfa_slots`` DFA rows were
         scanned by the cut's launches (pad rows x the launch's size class's
         D), of which ``own_dfa_rows`` are DFA rows the launched rows' own
         configs have: the rest is padding to the class's largest member
-        (the native lane counts both).  Host/degrade
+        (the native lane counts both); ``eff_cols`` is the launches' byte
+        bucket ``eff``, summed (over ``launches``: the scan length a launch
+        ran); ``dfa_dev_bytes`` the value bytes the launched rows' DFAs read
+        on the device (a DFA row a value byte, the row's own config's) and
+        ``dfa_host_bytes`` the value bytes the encoder's overflow scan was
+        handed for the cut's rows (a DFA a value byte, before any early
+        exit).  Host/degrade
         evals and fully cache/dedup-resolved cuts fold with launches=0 and
         zero byte counts.
         The mesh lane folds its batch here with launches=0 and counts the
@@ -131,6 +139,9 @@ class CostLedger:
             lc.dfa_ovf_rows += dfa_ovf_rows
             lc.own_dfa_slots += own_dfa_slots
             lc.own_dfa_rows += own_dfa_rows
+            lc.eff_cols += eff_cols
+            lc.dfa_dev_bytes += dfa_dev_bytes
+            lc.dfa_host_bytes += dfa_host_bytes
         metrics_mod.observe_kernel_cost(
             lane, launches, h2d_bytes, d2h_bytes, pad_waste)
 
@@ -397,10 +408,10 @@ class CostModel:
             return {}
         if policy is None or params is None:
             return {}
-        from ..compiler.compile import DFA_VALUE_BYTES
         from ..ops.pattern_eval import eval_bitpacked_jit, has_dfa
 
-        eff = DFA_VALUE_BYTES if has_dfa(params) else 0
+        # the widest byte bucket a launch of this corpus can take
+        eff = policy.byte_width if has_dfa(params) else 0
         fp = params_fingerprint(params)
         args = _bitpacked_zero_args(policy, params, pad, eff)
         cost = modeled_entry_cost("eval_bitpacked", eval_bitpacked_jit,

@@ -169,12 +169,14 @@ def _classify_selector(selector_str: str):
     return None
 
 
-def _const_plan(policy: CompiledPolicy, attr: int, const_doc: Dict[str, Any]):
+def _const_plan(policy: CompiledPolicy, attr: int, const_doc: Dict[str, Any],
+                row: Optional[int] = None):
     """K_CONST plan tuple for `attr` resolved against a constant auth doc,
     or None when the compact device payload can't hold the value (membership
     overflow, a NUL in a DFA operand) — which disqualifies the config.  A DFA
-    operand past DFA_VALUE_BYTES is flagged (the tuple's last field): the
-    C++ encoder scans it on the host as it does a request's long value."""
+    operand past the byte width of config ``row``'s size class (None: the
+    floor, DFA_VALUE_BYTES) is flagged (the tuple's last field): the C++
+    encoder scans it on the host as it does a request's long value."""
     from ..compiler.encode import _MISSING, _render
 
     res = sel.get(const_doc, policy.attr_selectors[attr])
@@ -194,8 +196,10 @@ def _const_plan(policy: CompiledPolicy, attr: int, const_doc: Dict[str, Any]):
     dfa_operand = int(policy.attr_byte_slot[attr]) >= 0
     if dfa_operand and 0 in raw:
         return None  # byte 0 is the DFA's pad identity: host oracle only
+    width = (DFA_VALUE_BYTES if row is None
+             else int(policy.config_byte_width[row]))
     return (int(attr), K_CONST, "", int(vid), missing, members, raw,
-            dfa_operand and len(raw) > DFA_VALUE_BYTES)
+            dfa_operand and len(raw) > width)
 
 
 def _const_doc(identity_obj) -> Dict[str, Any]:
@@ -515,6 +519,7 @@ def fast_lane_eligible(entry, policy: Optional[CompiledPolicy]) -> Optional[Fast
     plans: List[tuple] = []
     auth_attrs: List[int] = []
     has_batch = False
+    row = None
     if rt.authorization:
         if entry.rules is None or policy is None:
             return None
@@ -568,7 +573,7 @@ def fast_lane_eligible(entry, policy: Optional[CompiledPolicy]) -> Optional[Fast
             return None
         doc = _const_doc(spec.const_identity)
         for attr in auth_attrs:
-            p = _const_plan(policy, attr, doc)
+            p = _const_plan(policy, attr, doc, row)
             if p is None:
                 return None
             spec.plans.append(p)
@@ -590,7 +595,7 @@ def fast_lane_eligible(entry, policy: Optional[CompiledPolicy]) -> Optional[Fast
             if auth_attrs:
                 doc = _const_doc(ident_obj)
                 for attr in auth_attrs:
-                    p = _const_plan(policy, attr, doc)
+                    p = _const_plan(policy, attr, doc, row)
                     if p is None:
                         return None
                     vplans.append(p)
@@ -706,6 +711,9 @@ class _SnapRec:
     views: List[Any] = field(default_factory=list)
     class_of: Optional[np.ndarray] = None
     classes: List[Dict[str, int]] = field(default_factory=list)
+    # the slot arrays' byte-lane width: the widest class's device_width (the
+    # warm grid's byte axis runs to it); 0 = no DFA row in the corpus
+    byte_width: int = 0
     cfg_dfa_n: Optional[np.ndarray] = None
     # first kernel lowering/compile failure of this snapshot's warm grid
     # (swap gate or background rest): surfaced on /debug/vars and /readyz,
@@ -1278,6 +1286,9 @@ class NativeFrontend:
                **kernel_widths(rec.params)}
         for widths, mine in zip(out["classes"], rec.classes):
             widths["cpu_cols"] = mine["cpu_cols"]
+            # bytes of a value the device scans for the class's members (0:
+            # no DFA row); a longer value is the host's overflow scan's
+            widths["device_width"] = mine["device_width"]
         return out
 
     @property
@@ -1417,20 +1428,32 @@ class NativeFrontend:
             p //= 2
         if not pads:  # max_batch < 16: one pad, or refresh would warm nothing
             pads.append(min(bucket_pow2(self.max_batch), self.max_batch))
-        if rec.sharded is not None:
-            has_dfa = rec.sharded.has_dfa
-        else:
-            has_dfa = any(w["dfa_rows_per_row"] for w in rec.classes)
+        # the byte axis (_byte_bucket): powers of two up to the floor width,
+        # as it was, and past it the corpus's widest class's width alone; a
+        # class compiles the buckets up to its own (_warm_one).  No bucket
+        # between: each costs a class 0.4-0.7 s of every boot, pad by pad,
+        # even from the compile cache (PERF.md section 6, PR 38), and a cut
+        # whose longest value is 65-128 bytes runs 256 steps on a chip that
+        # is idle most of the time
         effs: List[int] = [0]
-        if has_dfa:
+        if rec.byte_width:
             effs = []
             e = 16
             while e < DFA_VALUE_BYTES:
                 effs.append(e)
                 e *= 2
             effs.append(DFA_VALUE_BYTES)
+            if rec.byte_width > DFA_VALUE_BYTES:
+                effs.append(rec.byte_width)
             effs.reverse()
         return [(p, e) for p in pads for e in effs]
+
+    @staticmethod
+    def _byte_bucket(used: int, width: int) -> int:
+        """The byte bucket of a launch whose rows' longest value is ``used``
+        bytes, in a class ``width`` wide: the warm grid's byte axis."""
+        bucket = bucket_pow2(max(used, 1))
+        return bucket if bucket <= DFA_VALUE_BYTES else width
 
     def _warm_one(self, rec: _SnapRec, pad: int, eff: int) -> None:
         """Compile (and cache) the jit variant for one bucket shape using
@@ -1466,8 +1489,12 @@ class NativeFrontend:
         first = not rec.warm
         for c, view in enumerate(rec.views):
             # every size class's variant of the bucket: a cut's launch of
-            # any class then finds its shape compiled
-            eff_c = eff if rec.classes[c]["dfa_rows_per_row"] else 0
+            # any class then finds its shape compiled.  A class's rows hold
+            # no value past its own width (0: no DFA row), so its launches
+            # run a bucket past it at that width (_launch_classes): (pad,
+            # eff) warm means every class compiled at (pad, min(eff, its
+            # width)); a shape met again costs one launch of zeros
+            eff_c = min(eff, rec.classes[c]["device_width"])
             layout = self._stage_layout(rec, c, pad, eff_c)
             if layout is not None:
                 size = layout[-1][3] + layout[-1][4]
@@ -1564,7 +1591,7 @@ class NativeFrontend:
         shapes that only the saturated-brownout edge could ever hit."""
         if rec.sharded is not None or rec.policy is None:
             return
-        effs = [DFA_VALUE_BYTES] if rec.policy.n_byte_attrs else [0]
+        effs = [rec.byte_width] if rec.policy.n_byte_attrs else [0]
         for pad in (16, 32):
             if pad > self.max_batch:
                 break
@@ -1804,13 +1831,17 @@ class NativeFrontend:
                 for c, cls in enumerate(classes):
                     rec.class_of[cls.configs] = c
                 rec.classes = [cls.widths() for cls in classes]
+                rec.byte_width = max(w["device_width"] for w in rec.classes)
                 rec.cfg_dfa_n = (policy.config_dfa_rows >= 0).sum(
                     axis=1).astype(np.int64)
                 spec["policy"] = enc._handle
                 dt = wire_dtype(policy)
                 A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
                 C, NB = policy.n_own_cpu, max(policy.n_byte_attrs, 1)
-                spec.update(A=A, M=M, K=K, C=C, NB=NB,
+                # the slots are as wide as the widest class (at least the
+                # floor: a corpus without a DFA row still has the arrays)
+                DVB = max(rec.byte_width, DFA_VALUE_BYTES)
+                spec.update(A=A, M=M, K=K, C=C, NB=NB, DVB=DVB,
                             elem16=1 if dt == np.int16 else 0)
                 ams = np.ascontiguousarray(policy.member_attr_slot, dtype=np.int32)
                 abs_v = np.ascontiguousarray(policy.attr_byte_slot, dtype=np.int32)
@@ -1841,8 +1872,14 @@ class NativeFrontend:
                         "members": np.full((B, M, K), PAD, dtype=dt),
                         "cpu_dense": np.zeros((B, C), dtype=np.uint8),
                         "config_id": np.zeros((B,), dtype=np.int32),
-                        "attr_bytes": np.zeros((B, NB, DFA_VALUE_BYTES), dtype=np.uint8),
+                        "attr_bytes": np.zeros((B, NB, DVB), dtype=np.uint8),
                         "byte_ovf": np.zeros((B, NB), dtype=np.uint8),
+                        # written by the C++ encoder a row (Slot::byte_used,
+                        # Slot::dfa_bytes): the longest value in the row's
+                        # attr_bytes; the value bytes its DFAs read on the
+                        # device [:, 0] and in the host's overflow scan [:, 1]
+                        "byte_used": np.zeros((B,), dtype=np.uint16),
+                        "dfa_bytes": np.zeros((B, 2), dtype=np.uint32),
                     }
                     rec.arrays.append(a)
                     spec["slots"].append({k: v.ctypes.data for k, v in a.items()})
@@ -1870,7 +1907,12 @@ class NativeFrontend:
                 C, NB = p0.n_own_cpu, max(p0.n_byte_attrs, 1)
                 # the sharded step takes int32 operands (parallel/sharded_eval
                 # encode contract), so elem16 stays off
-                spec.update(A=A, M=M, K=K, C=C, NB=NB, S=S_sh, elem16=0)
+                # shards compile under ShapeTargets: one class each, at the
+                # floor width
+                DVB = max(p.byte_width for p in sharded.shards)
+                rec.byte_width = DVB if sharded.has_dfa else 0
+                spec.update(A=A, M=M, K=K, C=C, NB=NB, S=S_sh, DVB=DVB,
+                            elem16=0)
                 ams = np.ascontiguousarray(
                     np.stack([p.member_attr_slot for p in sharded.shards]),
                     dtype=np.int32)
@@ -1915,9 +1957,11 @@ class NativeFrontend:
                         "cpu_dense": np.zeros((B, S_sh, C), dtype=np.uint8),
                         "config_id": np.zeros((B,), dtype=np.int32),
                         "shard_of": np.zeros((B,), dtype=np.int32),
-                        "attr_bytes": np.zeros((B, S_sh, NB, DFA_VALUE_BYTES),
+                        "attr_bytes": np.zeros((B, S_sh, NB, DVB),
                                                dtype=np.uint8),
                         "byte_ovf": np.zeros((B, S_sh, NB), dtype=np.uint8),
+                        "byte_used": np.zeros((B,), dtype=np.uint16),
+                        "dfa_bytes": np.zeros((B, 2), dtype=np.uint32),
                     }
                     rec.arrays.append(a)
                     spec["slots"].append({k: v.ctypes.data for k, v in a.items()})
@@ -2017,10 +2061,13 @@ class NativeFrontend:
                 if sharded is not None:
                     shard, row = sharded.locator[entry.rules.name]
                     fc["row"], fc["shard"] = int(row), int(shard)
+                    fc["dvb"] = int(
+                        sharded.shards[shard].config_byte_width[row])
                     row_key: Any = (int(shard), int(row))
                 else:
                     row = policy.config_ids[entry.rules.name]
                     fc["row"] = int(row)
+                    fc["dvb"] = int(policy.config_byte_width[row])
                     fc_rows.append(int(row))
                     row_key = int(row)
                 rec.row_labels[row_key] = (ns_l, nm_l)
@@ -2180,8 +2227,10 @@ class NativeFrontend:
             if reg_policy is None:
                 return
             doc = _const_doc(obj)
+            reg_row = reg_policy.config_ids.get(entry.rules.name) \
+                if entry.rules is not None else None
             for attr in auth_attrs:
-                p = _const_plan(reg_policy, attr, doc)
+                p = _const_plan(reg_policy, attr, doc, reg_row)
                 if p is None:
                     return  # this token's values don't fit the compact payload
                 vplans.append(p)
@@ -2287,7 +2336,8 @@ class NativeFrontend:
         """Once a snapshot: each kernel row's cache token as a u64 and each
         slot's key descriptor.  The key is the row's encoded operand bytes,
         ``shard_of`` first on a mesh corpus, then ``config_id`` and the
-        operands in this order (compiler/pack.py batch_row_keys's)."""
+        operands in this order (compiler/pack.py batch_row_keys's), the
+        byte lane as far as the row's longest value reaches."""
         if cache_tokens is not None:
             ids = self._cache_token_ids
             rec.tok_ids = np.fromiter(
@@ -2301,7 +2351,11 @@ class NativeFrontend:
                  "attr_bytes", "byte_ovf"]
         if rec.sharded is not None:
             order.insert(0, "shard_of")
-        rec.key_segs = [key_segments([a[k] for k in order])
+        # a row's byte lane is keyed as far as its longest value reaches
+        # (every byte past it is zero), not as wide as the slot
+        at = order.index("attr_bytes")
+        rec.key_segs = [key_segments([a[k] for k in order],
+                                     used={at: a["byte_used"]})
                         for a in rec.arrays]
 
     def _dedup_plan(self, rec: _SnapRec, slot: int, count: int,
@@ -2451,7 +2505,11 @@ class NativeFrontend:
                                 if fan is not None else 0),
             cache_avoided_rows=(len(fan.cached_rows)
                                 if fan is not None else 0),
-            dfa_ovf_rows=ovf_rows)
+            dfa_ovf_rows=ovf_rows,
+            # the value bytes the C++ encoder's overflow scan was handed for
+            # this cut's rows, a DFA a byte (counted once a cut, as the rows)
+            dfa_host_bytes=(int(a["dfa_bytes"][:count, 1].sum())
+                            if ovf_rows else 0))
         if u == 0:
             # every row cache-resolved: complete through the readback queue
             # with no device work at all
@@ -2576,7 +2634,7 @@ class NativeFrontend:
                 present.append((c, n, mine))
         out = _Launched()
         tally = dict(h2d_transfers=0, h2d_bytes=0, d2h_bytes=0, pad_rows=0,
-                     eff_slack_cols=0, own_dfa_slots=0)
+                     eff_slack_cols=0, own_dfa_slots=0, eff_cols=0)
         eff_max = 0
         for c, n, mine in present:
             with bt.stage("encode"):
@@ -2587,16 +2645,22 @@ class NativeFrontend:
                     src = at if unique_rows is None else unique_rows[at]
                 width = rec.classes[c]
                 n_dfa = width["dfa_rows_per_row"]
-                eff_need = (_trim_bytes(a["attr_bytes"][:count] if src is None
-                                        else a["attr_bytes"][src]).shape[-1]
-                            if n_dfa else 0)
+                # the byte bucket of the launch's own rows: the longest
+                # value the encoder wrote into any of them (what trimming
+                # the all-zero columns of their attr_bytes gives), never
+                # past the class's width, past which a value overflowed
+                eff_need = 0
+                if n_dfa:
+                    used = a["byte_used"][slice(count) if src is None else src]
+                    eff_need = self._byte_bucket(int(used.max()),
+                                                 width["device_width"])
                 # round the batch/byte buckets up to an already-compiled
                 # variant so XLA compiles never land on live requests (rows
                 # past the launch's carry stale/repeated operands; results
-                # discarded)
+                # discarded); a bucket past the class's width runs at the
+                # width (0 where the class has no DFA row: _warm_one)
                 pad, eff = self._pick_warm_shape(rec, n, eff_need)
-                if not n_dfa:
-                    eff = 0
+                eff = min(eff, width["device_width"])
                 if src is None:
                     idx = slice(pad)
                 else:
@@ -2635,6 +2699,7 @@ class NativeFrontend:
                 tally["d2h_bytes"] += int(packed.shape[0]) * int(packed.shape[1])
                 tally["pad_rows"] += pad
                 tally["eff_slack_cols"] += eff - eff_need
+                tally["eff_cols"] += eff
                 tally["own_dfa_slots"] += pad * n_dfa
                 eff_max = max(eff_max, eff)
                 if c == present[-1][0]:
@@ -2642,6 +2707,11 @@ class NativeFrontend:
                         "native", rows=count, device_rows=u,
                         launches=len(present),
                         own_dfa_rows=int(rec.cfg_dfa_n[cfg].sum()),
+                        # value bytes the launched rows' DFAs read here, a
+                        # DFA a byte (the encoder counted them a row)
+                        dfa_dev_bytes=int(a["dfa_bytes"][
+                            slice(count) if unique_rows is None
+                            else unique_rows, 0].sum()),
                         **tally, **avoided)
         return out, tally["pad_rows"], eff_max
 

@@ -30,8 +30,10 @@ from __future__ import annotations
 import hashlib
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from ..analysis.translation_validate import _sha, _tree_digest
-from ..compiler.compile import DFA_VALUE_BYTES, CompiledPolicy
+from ..compiler.compile import CompiledPolicy
 
 __all__ = ["rules_fingerprint", "encoding_epoch", "cache_tokens"]
 
@@ -131,8 +133,13 @@ def encoding_epoch(policy: CompiledPolicy) -> str:
         (tuple(policy.attr_selectors[a] for a in policy.member_attrs.tolist()),
          int(policy.n_member_attrs)),
         (tuple(cpu_desc), policy.n_own_cpu),
+        # the byte lane: slot -> selector, the tensor's width (the corpus's
+        # widest class's) and each config's own width, past which its row
+        # encodes a value as overflow + host-scan columns and not as bytes
         (tuple(byte_slots.get(s) for s in range(policy.n_byte_attrs)),
-         DFA_VALUE_BYTES),
+         int(policy.byte_width),
+         hashlib.sha256(np.ascontiguousarray(
+             policy.config_byte_width).tobytes()).hexdigest()[:16]),
         (tuple(num_slots.get(s)
                for s in range(int(getattr(policy, "n_num_attrs", 0) or 0))),
          tuple(rel_desc), bool(getattr(policy, "ovf_assist", False))),

@@ -486,7 +486,10 @@ struct Policy {
   Interner interner;
   std::string strings;                 // owned copy of all table strings
   int32_t n_attrs = 0, n_leaves = 0, n_configs = 0;
-  int32_t members_k = 0, dfa_value_bytes = 0, n_byte_attrs = 0;
+  int32_t members_k = 0, n_byte_attrs = 0;
+  // per config: bytes of a value its size class scans on the device; a
+  // longer value overflows (compiler/compile.py class_device_width)
+  std::vector<int32_t> cfg_byte_width;  // [n_configs]
   std::vector<std::pair<int64_t, int32_t>> seg_views;  // (off,len) into strings
   std::vector<int32_t> attr_seg_offs;   // [n_attrs+1]
   std::vector<uint8_t> attr_complex;    // [n_attrs]
@@ -640,7 +643,7 @@ Policy* atpu_policy_new(
     int32_t n_configs,
     const int32_t* cfg_attr_offs, const int32_t* cfg_attr_idx,
     const int32_t* cfg_cpu_offs, const int32_t* cfg_cpu_idx,
-    int32_t members_k, int32_t dfa_value_bytes, int32_t n_byte_attrs) {
+    int32_t members_k, const int32_t* cfg_byte_width, int32_t n_byte_attrs) {
   Policy* p = new Policy();
   // own copies of the intern blob + segment strings so numpy temporaries can die
   int64_t intern_total = intern_offs[n_intern];
@@ -670,7 +673,7 @@ Policy* atpu_policy_new(
   p->cfg_cpu_offs.assign(cfg_cpu_offs, cfg_cpu_offs + n_configs + 1);
   p->cfg_cpu_idx.assign(cfg_cpu_idx, cfg_cpu_idx + cfg_cpu_offs[n_configs]);
   p->members_k = members_k;
-  p->dfa_value_bytes = dfa_value_bytes;
+  p->cfg_byte_width.assign(cfg_byte_width, cfg_byte_width + n_configs);
   p->n_byte_attrs = n_byte_attrs;
   return p;
 }
@@ -735,7 +738,7 @@ int64_t atpu_encode(
         store_id(attrs_val, (int64_t)r * A + attr, vid, elem16);
         int32_t slot = p->attr_byte_slot[attr];
         if (slot >= 0) {
-          if ((int64_t)rendered.size() > DVB ||
+          if ((int64_t)rendered.size() > p->cfg_byte_width[row] ||
               memchr(rendered.data(), 0, rendered.size()) != nullptr) {
             byte_ovf[(int64_t)r * NB + slot] = 1;
           } else if (!rendered.empty()) {

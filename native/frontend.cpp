@@ -419,6 +419,11 @@ struct CredSource {
 struct FastConfig {
   int32_t row = 0;
   int32_t shard = 0;            // owning mp shard (sharded corpora; else 0)
+  // bytes of a value the device scans for this config: its size class's
+  // width (compiler/compile.py class_device_width), at most the snapshot's
+  // DVB (the slot arrays' stride, the corpus's widest class's); a longer
+  // value overflows to scan_overflow
+  int32_t dvb = 0;
   bool has_batch = true;        // false → identity-only: decide entirely here
   // hybrid lane: the kernel covers only part of the authorization phase
   // (procedural Rego / SAR / SpiceDB evaluators stay in Python).  A kernel
@@ -512,7 +517,7 @@ enum ClockRowId {
   PH_READ,       // recv + nghttp2 framing, HPACK, callbacks' bookkeeping; count: recv calls
   PH_PARSE,      // process_check entry -> the chosen FastConfig; count: Check requests
   PH_ENCODE,     // ensure_fill + zero_row + encode_fast; count: rows encoded
-  PH_OVF_SCAN,   // scan_overflow of a value past DVB; count: such rows
+  PH_OVF_SCAN,   // scan_overflow of a value past its config's width; count: such rows
   PH_CUT,        // flush_batch; count: cuts flushed
   PH_RESPOND,    // drain_done's submit loop; count: answers submitted
   PH_WRITE,      // conn_pump (mem_send + send); count: send calls
@@ -529,12 +534,16 @@ enum ClockRowId {
   // before its verdict was settled: the value's length where none absorbs
   ROW_OVF_DFAS,
   ROW_OVF_LOADS,
+  // counts alone, a Check request: the bytes of its CheckRequest message
+  // and the headers parse_check_request found in it (over `parse`'s count)
+  ROW_REQ_BYTES,
+  ROW_REQ_HEADERS,
   N_CLOCK_ROWS
 };
 static const char* const CLOCK_ROW_NAMES[N_CLOCK_ROWS] = {
     "idle", "read", "parse", "encode", "ovf_scan", "cut", "respond", "write",
     "other", "turn", "req_wait", "req_exec", "req_respond", "ovf_dfas",
-    "ovf_loads"};
+    "ovf_loads", "req_bytes", "req_headers"};
 
 // two branches off the thread's path that say who holds it back, each with
 // its operator's use in docs/observability.md: the peer does not read
@@ -659,6 +668,13 @@ struct Slot {
   int32_t* shard_of = nullptr;   // [Bmax] owning shard (null for S=1)
   uint8_t* attr_bytes = nullptr; // [Bmax, S, NB, DVB]
   uint8_t* byte_ovf = nullptr;   // [Bmax, S, NB] bool
+  // per row: the longest value written into its attr_bytes (every byte of
+  // the row past it, in every slot, is zero: what zero_row clears, what a
+  // launch trims to and what the verdict cache keys on), and the value
+  // bytes its DFAs read, a DFA a byte: [0] on the device (values inside the
+  // config's width), [1] handed to scan_overflow
+  uint16_t* byte_used = nullptr; // [Bmax]
+  uint32_t* dfa_bytes = nullptr; // [Bmax, 2]
 };
 
 struct Snapshot {
@@ -671,6 +687,7 @@ struct Snapshot {
   std::vector<int32_t> attr_byte_slot_v;  // [S*A] → NB row or -1
   int G = 0;  // config rows per shard
   std::vector<std::vector<DfaRef>> cfg_dfas;  // [S*G]; rows globalized
+  std::vector<uint16_t> cfg_slot_dfas;  // [S*G, NB]: DFAs of the config that read the byte slot
   std::vector<uint8_t> dfa_trans;  // [S*R, St, 256]
   std::vector<uint8_t> dfa_flags;  // [S*R, St]: DFA_ACCEPTS | DFA_ABSORBS
   int dfa_S = 0;
@@ -761,8 +778,9 @@ struct SlowPending {
 enum EvKind { EV_TIMEOUT = 0, EV_BATCH = 1, EV_SNAP_RETIRED = 3, EV_STOPPED = 4 };
 // d: for EV_BATCH, the slot's flush time (CLOCK_MONOTONIC ns, the clock of
 // Python's time.monotonic_ns()) — the start of the batch's `pickup` stage;
-// e: for EV_BATCH, the cut's rows with at least one value past DVB, whose
-// DFAs the encoder scanned here (the ledger's `dfa_ovf_rows`);
+// e: for EV_BATCH, the cut's rows with at least one value past their
+// config's width, whose DFAs the encoder scanned here (the ledger's
+// `dfa_ovf_rows`);
 // f: for EV_BATCH, the arrival of the cut's first row, on d's clock — the
 // start of the batch's `fill` stage
 struct Event { int kind; int64_t a, b, c, d, e, f; };
@@ -1134,9 +1152,13 @@ static bool encode_fast(Server* S, Snapshot* snap, Slot& sl, int b,
       if (pl.kind != K_CONST && vn && memchr(vp, 0, vn) != nullptr)
         return false;  // NUL: byte 0 is the DFA pad identity — Python regex
                        // lane is the only exact evaluator (slow lane)
-      bool ovf = pl.kind == K_CONST ? pl.const_byte_ovf : (int)vn > DVB;
+      const size_t ci = (size_t)fc.shard * snap->G + fc.row;
+      if (ci >= snap->cfg_dfas.size()) return false;
+      const uint32_t n_dfas = snap->cfg_slot_dfas[ci * NB + bslot];
+      bool ovf = pl.kind == K_CONST ? pl.const_byte_ovf : (int)vn > fc.dvb;
       if (ovf) {
         sl.byte_ovf[bs * NB + bslot] = 1;
+        sl.dfa_bytes[2 * b + 1] += n_dfas * (uint32_t)(missing ? 0 : vn);
         S->n_dfa_ovf.fetch_add(1, std::memory_order_relaxed);
         S->fill_row_ovf = true;
         // exact host evaluation of every DFA leaf of this config reading
@@ -1144,13 +1166,13 @@ static bool encode_fast(Server* S, Snapshot* snap, Slot& sl, int b,
         // fixed-width)
         const char* sp = missing ? "" : vp;
         size_t sn = missing ? 0 : vn;
-        const size_t ci = (size_t)fc.shard * snap->G + fc.row;
-        if (ci >= snap->cfg_dfas.size()) return false;
         S->clk.stamp(PH_ENCODE);
         scan_overflow(S, snap, ci, attr, sp, sn, sl.cpu_dense + bs * snap->C);
         S->clk.stamp(PH_OVF_SCAN);
       } else if (vn) {
         memcpy(sl.attr_bytes + (bs * NB + bslot) * DVB, vp, vn);
+        if (vn > sl.byte_used[b]) sl.byte_used[b] = (uint16_t)vn;
+        sl.dfa_bytes[2 * b] += n_dfas * (uint32_t)vn;
       }
     }
   }
@@ -1179,7 +1201,14 @@ static void zero_row(Snapshot* snap, Slot& sl, int b) {
     for (int i = 0; i < MK; ++i) m[i] = -3;
   }
   memset(sl.cpu_dense + (int64_t)b * C, 0, (size_t)C);
-  if (sl.attr_bytes) memset(sl.attr_bytes + (int64_t)b * NB * DVB, 0, (size_t)NB * DVB);
+  if (sl.attr_bytes) {
+    // the row's last occupant wrote no byte past byte_used[b] in any slot
+    const size_t used = sl.byte_used[b];
+    uint8_t* row = sl.attr_bytes + (int64_t)b * NB * DVB;
+    for (int i = 0; used && i < NB; ++i) memset(row + (size_t)i * DVB, 0, used);
+    sl.byte_used[b] = 0;
+    sl.dfa_bytes[2 * b] = sl.dfa_bytes[2 * b + 1] = 0;
+  }
   if (sl.byte_ovf) memset(sl.byte_ovf + (int64_t)b * NB, 0, (size_t)NB);
   if (sl.shard_of) sl.shard_of[b] = 0;
 }
@@ -1377,6 +1406,7 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
                   ((uint8_t)st.body[3] << 8) | (uint8_t)st.body[4];
   if (st.body.size() < 5 + (size_t)mlen) { submit_grpc_error(c, stream_id, 13); return; }
   const char* msg = st.body.data() + 5;
+  clk.rows[ROW_REQ_BYTES].bump(mlen);
 
   std::shared_ptr<Snapshot> snap;
   {
@@ -1406,6 +1436,7 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
     submit_grpc_error(c, stream_id, 13);
     return;
   }
+  clk.rows[ROW_REQ_HEADERS].bump(rv.headers.size());
   if (!rv.has_attributes || !rv.has_request || !rv.has_http) {
     S->n_invalid.fetch_add(1, std::memory_order_relaxed);
     direct();

@@ -170,7 +170,7 @@ PyObject* encode_docs(PyObject*, PyObject* args) {
       store_id(attrs_val, (int64_t)r * A + attr, vid, elem16);
       int32_t slot = p->attr_byte_slot[attr];
       if (slot >= 0) {
-        if ((int64_t)rendered.size() > DVB ||
+        if ((int64_t)rendered.size() > p->cfg_byte_width[row] ||
             memchr(rendered.data(), 0, rendered.size()) != nullptr) {
           byte_ovf[(int64_t)r * NB + slot] = 1;
         } else if (!rendered.empty()) {
@@ -210,19 +210,20 @@ PyObject* encode_docs(PyObject*, PyObject* args) {
 //               attr_complex_addr, attr_byte_slot_addr,
 //               n_leaves, leaf_op_addr, leaf_attr_addr, leaf_const_addr,
 //               n_configs, cfg_attr_offs_addr, cfg_attr_idx_addr,
-//               cfg_cpu_offs_addr, cfg_cpu_idx_addr, members_k, dvb, nb)
+//               cfg_cpu_offs_addr, cfg_cpu_idx_addr, members_k,
+//               cfg_byte_width_addr, nb)
 PyObject* policy_new_py(PyObject*, PyObject* args) {
   Py_buffer intern_blob, seg_blob;
   unsigned long long io_a, ii_a, so_a, aso_a, ac_a, abs_a;
-  unsigned long long lo_a, la_a, lc_a, cao_a, cai_a, cco_a, cci_a;
-  int n_intern, n_attrs, n_segs, n_leaves, n_configs, members_k, dvb, nb;
+  unsigned long long lo_a, la_a, lc_a, cao_a, cai_a, cco_a, cci_a, cbw_a;
+  int n_intern, n_attrs, n_segs, n_leaves, n_configs, members_k, nb;
   if (!PyArg_ParseTuple(
-          args, "y*KKiiy*KiKKKiKKKiKKKKiii",
+          args, "y*KKiiy*KiKKKiKKKiKKKKiKi",
           &intern_blob, &io_a, &ii_a, &n_intern,
           &n_attrs, &seg_blob, &so_a, &n_segs, &aso_a, &ac_a, &abs_a,
           &n_leaves, &lo_a, &la_a, &lc_a,
           &n_configs, &cao_a, &cai_a, &cco_a, &cci_a,
-          &members_k, &dvb, &nb))
+          &members_k, &cbw_a, &nb))
     return nullptr;
   Policy* p = atpu_policy_new(
       (const char*)intern_blob.buf, (const int64_t*)io_a, (const int32_t*)ii_a,
@@ -230,7 +231,8 @@ PyObject* policy_new_py(PyObject*, PyObject* args) {
       n_segs, (const int32_t*)aso_a, (const uint8_t*)ac_a, (const int32_t*)abs_a,
       n_leaves, (const int32_t*)lo_a, (const int32_t*)la_a, (const int32_t*)lc_a,
       n_configs, (const int32_t*)cao_a, (const int32_t*)cai_a,
-      (const int32_t*)cco_a, (const int32_t*)cci_a, members_k, dvb, nb);
+      (const int32_t*)cco_a, (const int32_t*)cci_a, members_k,
+      (const int32_t*)cbw_a, nb);
   PyBuffer_Release(&intern_blob);
   PyBuffer_Release(&seg_blob);
   return PyCapsule_New(p, "atpu.Policy", policy_capsule_free);
@@ -440,6 +442,17 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
   size_t most_dfas = 0;
   for (const auto& refs : snap->cfg_dfas) most_dfas = std::max(most_dfas, refs.size());
   snap->scan_lanes.resize(most_dfas);
+  // DFAs a config has on each byte slot: what a value's bytes are counted by
+  const size_t NBs = (size_t)std::max(snap->NB, 1);
+  snap->cfg_slot_dfas.assign(snap->cfg_dfas.size() * NBs, 0);
+  for (size_t ci = 0; ci < snap->cfg_dfas.size(); ++ci) {
+    const size_t meta0 = snap->G > 0 ? (ci / (size_t)snap->G) * (size_t)snap->A : 0;
+    for (const fe::DfaRef& r : snap->cfg_dfas[ci]) {
+      const size_t at = meta0 + (size_t)r.attr;
+      const int32_t bslot = at < snap->attr_byte_slot_v.size() ? snap->attr_byte_slot_v[at] : -1;
+      if (bslot >= 0 && (size_t)bslot < NBs) snap->cfg_slot_dfas[ci * NBs + bslot]++;
+    }
+  }
   if (!dict_bytes(d, "invalid", snap->invalid_msg) ||
       !dict_bytes(d, "notfound", snap->notfound_msg) ||
       !dict_bytes(d, "health", snap->health_msg)) {
@@ -452,6 +465,7 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
     fe::FastConfig fc;
     fc.row = (int32_t)dict_int(f, "row");
     fc.shard = (int32_t)dict_int(f, "shard", 0);
+    fc.dvb = (int32_t)dict_int(f, "dvb", snap->DVB);
     fc.has_batch = dict_int(f, "has_batch", 1) != 0;
     fc.hybrid = dict_int(f, "hybrid", 0) != 0;
     dict_bytes(f, "ok", fc.ok_msg);
@@ -545,6 +559,8 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
     sl.shard_of = (int32_t*)dict_addr(s, "shard_of");
     sl.attr_bytes = (uint8_t*)dict_addr(s, "attr_bytes");
     sl.byte_ovf = (uint8_t*)dict_addr(s, "byte_ovf");
+    sl.byte_used = (uint16_t*)dict_addr(s, "byte_used");
+    sl.dfa_bytes = (uint32_t*)dict_addr(s, "dfa_bytes");
     snap->slots.push_back(sl);
     snap->free_slots.push_back((int)i);
   }
@@ -939,8 +955,9 @@ PyObject* vc_new_py(PyObject*, PyObject* args) {
 
 // vc_plan(cache | None, segments, count, tokens, eligible, dedup)
 //   -> (arrays, n_cached, n_miss, n_unique, eligible_misses, ticket | None)
-// segments: u64 triples (address, bytes a row, rows), one an operand array
-// of the slot, in key order; tokens u64[count]; eligible u8[count].  arrays
+// segments: u64 sextuples (address, bytes a row, rows, sub-rows, their
+// stride, address of the rows' used bytes or 0: vc::Seg), one an operand
+// array of the slot, in key order; tokens u64[count]; eligible u8[count].  arrays
 // is vc::Plan::out as int32 bytes.
 PyObject* vc_plan_py(PyObject*, PyObject* args) {
   PyObject* cache_o;
@@ -959,11 +976,13 @@ PyObject* vc_plan_py(PyObject*, PyObject* args) {
   {
     bool ok = count >= 0 && segs.len % sizeof(vc::Seg) == 0 &&
               tokens.len >= (Py_ssize_t)(count * sizeof(uint64_t)) && eligible.len >= count;
-    for (size_t s = 0; ok && s < segs.len / sizeof(vc::Seg); ++s)
-      ok = (uint64_t)count <= ((const vc::Seg*)segs.buf)[s].rows;
+    for (size_t s = 0; ok && s < segs.len / sizeof(vc::Seg); ++s) {
+      const vc::Seg& g = ((const vc::Seg*)segs.buf)[s];
+      ok = (uint64_t)count <= g.rows && (!g.used || g.sub * g.sub_stride <= g.bytes);
+    }
     if (!ok) {
       PyErr_SetString(PyExc_ValueError, "vc_plan: tokens, eligible or a segment shorter than "
-                                        "count, or segments not (address, bytes, rows) triples");
+                                        "count, or segments not vc::Seg sextuples");
       goto done;
     }
   }

@@ -30,8 +30,14 @@
 namespace vc {
 
 // one operand array of the slot: row r < rows has its bytes at
-// [base + r*bytes, +bytes)
-struct Seg { uint64_t base; uint64_t bytes; uint64_t rows; };
+// [base + r*bytes, +bytes).  `used` 0: the whole row is key.  Otherwise it is
+// the address of a u16 a row, and the row is `sub` sub-rows `sub_stride`
+// bytes apart of which only the first used[r] bytes of each are key: the
+// writer guarantees every byte past them is zero (the byte lane's
+// [NB, W] row, native/frontend.cpp Slot::byte_used), so two rows are equal
+// exactly where their keys are, and a row's key is as long as its longest
+// value and not as wide as the lane.
+struct Seg { uint64_t base, bytes, rows, sub, sub_stride, used; };
 
 inline uint64_t hash_bytes(const uint8_t* p, size_t n) {
   uint64_t h = 0x9E3779B97F4A7C15ull ^ (n * 0xFF51AFD7ED558CCDull);
@@ -165,9 +171,9 @@ struct Cache {
 
 // keys of a cut's eligible unique misses, as they were at plan time
 struct Ticket {
-  size_t width = 0;
   int32_t count = 0;               // rows of the cut: bounds commit's reads
-  std::vector<uint8_t> keys;       // rows.size() x width
+  std::vector<uint8_t> keys;       // key i: [at[i], at[i+1])
+  std::vector<size_t> at;
   std::vector<uint64_t> tokens, hashes;
   std::vector<int32_t> rows;       // the cut's row whose verdict is the value
 };
@@ -182,22 +188,36 @@ struct Plan {
 inline void plan(Cache* cache, const Seg* segs, size_t nseg, int32_t count,
                  const uint64_t* tokens, const uint8_t* eligible, bool dedup,
                  Plan& p, Ticket& t) {
-  size_t width = 0;
-  for (size_t s = 0; s < nseg; ++s) width += segs[s].bytes;
   static thread_local std::vector<uint8_t> rows_buf;
   static thread_local std::vector<uint64_t> hb;
-  rows_buf.resize((size_t)count * width);
+  static thread_local std::vector<size_t> koff;  // row r's key: [koff[r], koff[r+1])
+  koff.resize((size_t)count + 1);
+  koff[0] = 0;
+  for (int32_t r = 0; r < count; ++r) {
+    size_t w = 0;
+    for (size_t s = 0; s < nseg; ++s)
+      w += segs[s].used ? segs[s].sub * ((const uint16_t*)segs[s].used)[r] : segs[s].bytes;
+    koff[r + 1] = koff[r] + w;
+  }
+  rows_buf.resize(koff[count]);
   hb.resize(count);
   uint8_t* rows = rows_buf.data();
-  size_t off = 0;
-  for (size_t s = 0; s < nseg; ++s) {
-    const uint8_t* src = (const uint8_t*)segs[s].base;
-    const size_t nb = segs[s].bytes;
-    for (int32_t r = 0; r < count; ++r)
-      memcpy(rows + (size_t)r * width + off, src + (size_t)r * nb, nb);
-    off += nb;
+  for (int32_t r = 0; r < count; ++r) {
+    uint8_t* dst = rows + koff[r];
+    for (size_t s = 0; s < nseg; ++s) {
+      const uint8_t* src = (const uint8_t*)segs[s].base + (size_t)r * segs[s].bytes;
+      if (!segs[s].used) {
+        memcpy(dst, src, segs[s].bytes);
+        dst += segs[s].bytes;
+        continue;
+      }
+      const size_t u = ((const uint16_t*)segs[s].used)[r];
+      for (uint64_t j = 0; u && j < segs[s].sub; ++j, dst += u)
+        memcpy(dst, src + j * segs[s].sub_stride, u);
+    }
+    hb[r] = hash_bytes(rows + koff[r], koff[r + 1] - koff[r]);
   }
-  for (int32_t r = 0; r < count; ++r) hb[r] = hash_bytes(rows + (size_t)r * width, width);
+  auto width = [&](int32_t r) { return koff[r + 1] - koff[r]; };
 
   std::vector<int32_t> c_rows, c_verdict, c_firing, miss;
   miss.reserve(count);
@@ -207,7 +227,7 @@ inline void plan(Cache* cache, const Seg* segs, size_t nseg, int32_t count,
     for (int32_t r = 0; r < count; ++r) {
       if (eligible[r]) {
         int32_t i = cache->find(tokens[r], mix_token(hb[r], tokens[r]),
-                                rows + (size_t)r * width, width);
+                                rows + koff[r], width(r));
         if (i >= 0) {
           cache->touch(i);
           ++hits;
@@ -246,8 +266,8 @@ inline void plan(Cache* cache, const Seg* segs, size_t nseg, int32_t count,
           break;
         }
         const int32_t ur = unique[u];
-        if (hb[ur] == hb[r] &&
-            memcmp(rows + (size_t)ur * width, rows + (size_t)r * width, width) == 0) {
+        if (hb[ur] == hb[r] && width(ur) == width(r) &&
+            memcmp(rows + koff[ur], rows + koff[r], width(r)) == 0) {
           inverse[j] = u;
           break;
         }
@@ -258,12 +278,13 @@ inline void plan(Cache* cache, const Seg* segs, size_t nseg, int32_t count,
     for (size_t j = 0; j < nm; ++j) inverse[j] = (int32_t)j;
   }
 
-  t.width = width;
   t.count = count;
   if (cache != nullptr) {
+    t.at.push_back(0);
     for (int32_t r : unique) {
       if (!eligible[r]) continue;
-      t.keys.insert(t.keys.end(), rows + (size_t)r * width, rows + (size_t)(r + 1) * width);
+      t.keys.insert(t.keys.end(), rows + koff[r], rows + koff[r + 1]);
+      t.at.push_back(t.keys.size());
       t.tokens.push_back(tokens[r]);
       t.hashes.push_back(mix_token(hb[r], tokens[r]));
       t.rows.push_back(r);
@@ -287,8 +308,9 @@ inline int64_t commit(Cache* cache, Ticket& t, const uint8_t* verdict, const int
     std::lock_guard<std::mutex> lk(cache->mu);
     for (size_t i = 0; i < t.rows.size(); ++i) {
       const int32_t r = t.rows[i];
-      evicted += cache->put(t.tokens[i], t.hashes[i], t.keys.data() + i * t.width, t.width,
-                            verdict[r], firing != nullptr ? firing[r] : -1);
+      evicted += cache->put(t.tokens[i], t.hashes[i], t.keys.data() + t.at[i],
+                            t.at[i + 1] - t.at[i], verdict[r],
+                            firing != nullptr ? firing[r] : -1);
     }
   }
   t.rows.clear();

@@ -101,8 +101,8 @@ def test_routes_1k_cell_is_as_the_issue_states_and_its_files_agree():
 def test_mixed_tenants_1k_cell_is_as_the_issue_states_and_its_files_agree():
     manifest = _manifest()
     cell = harness.load_cell(manifest, ROOT, "mixed-tenants-1k.unique-sat")
-    assert manifest["workloads"][-1]["name"] == cell["name"]    # appended
-    assert manifest["configs"][-1]["name"] == cell["config"] == "mixed-tenants-1k"
+    assert manifest["workloads"][5]["name"] == cell["name"]    # appended by PR 34
+    assert manifest["configs"][4]["name"] == cell["config"] == "mixed-tenants-1k"
     config = cell["config_file"]
     assert (cell["traffic"], cell["chips"]) == ("unique-sat", 1)
     assert config["params"] == {"n_configs": 1000, "n_large": 8, "services": 8}
@@ -119,7 +119,9 @@ def test_mixed_tenants_1k_cell_is_as_the_issue_states_and_its_files_agree():
             "kernel_ms_per_launch", "dfa_rows_per_row", "h2d_bytes_per_row",
             "device_idle_pct", "host_lane_rows_pct"} <= reads
     assert not {"pattern_eval_roofline", "dfa_scan_roofline"} & reads
-    for other in manifest["workloads"][:-1]:
+    for other in manifest["workloads"]:
+        if other["name"] == cell["name"]:
+            continue
         names = {m["name"] for m in
                  harness.load_cell(manifest, ROOT, other["name"])["per_layer"]}
         assert {"launches_per_cut", "dfa_slot_fill_pct"} <= names
@@ -225,3 +227,127 @@ def test_tenants_10k_generator_refuses_a_program_with_dense_operands(
     args = (dict(params, deny_share=0.5), 64)
     assert guarded.requests(*args, random.Random(7)) == \
         plain.requests(*args, random.Random(7))
+
+
+def test_edge_1k_cell_is_as_the_issue_states_and_its_files_agree():
+    """ISSUE 38: the configuration and its one cell are appended, the file
+    states the source, what is known and assumed of it, the other
+    configurations' guarantees word for word, and the generator's own
+    measurements inside the issue's aims."""
+    manifest = _manifest()
+    cell = harness.load_cell(manifest, ROOT, "edge-1k.unique-sat")
+    assert manifest["workloads"][-1]["name"] == cell["name"]    # appended
+    assert manifest["configs"][-1]["name"] == cell["config"] == "edge-1k"
+    checks = [m for m in manifest["end_to_end"] if m["name"] == "checks_per_s"]
+    assert checks[0]["workloads"][-1] == cell["name"]
+    config = cell["config_file"]
+    assert (cell["traffic"], cell["chips"]) == ("unique-sat", 1)
+    assert config["params"] == {"n_configs": 1000}
+    assert config["requests"] == {
+        "browser_share": 0.7, "deny_share": 0.5, "write_share": 0.3,
+        "cookie_pairs": [2, 9], "cookie_tail_share": 0.03}
+    assert config["reduced"] == [] and config["generator"] == "edge_requests"
+    assert len(config["source"]) <= 200 and len(cell["why"]) <= 200
+    assert config["known_of_the_source"] and len(config["assumed"]) >= 10
+    routes = harness.load_cell(manifest, ROOT, "routes-1k.unique-sat")["config_file"]
+    assert config["guarantees"][:3] == routes["guarantees"]
+    assert len(config["guarantees"]) == 4 and "length" in config["guarantees"][3]
+    measured = config["measured_of_the_generator"]
+    assert (measured["rows"], measured["seed"]) == (131072, 0)
+    size = measured["check_request_bytes"]
+    assert 1200 <= size["mean"] <= 2500 and 1200 <= size["p50"] <= size["p99"] <= 2500
+    heads = measured["headers_per_request"]
+    assert 18 <= heads["min"] <= heads["mean"] <= heads["max"] <= 30
+    past = measured["rows_with_a_regex_read_value_past_pct"]
+    assert past["64"] >= 65 and past["64"] > past["128"] > past["256"]
+    assert 8 <= past["256"] <= 15
+    reads = {m["name"] for m in cell["per_layer"]}
+    assert {"fe_bytes_per_check", "fe_headers_per_check", "dfa_eff_bytes",
+            "dfa_dev_bytes_pct", "long_value_roofline", "dfa_ovf_rows_pct",
+            "fe_ovf_scan_pct", "fe_parse_pct", "fe_read_pct", "fe_encode_pct",
+            "fe_us_per_check", "h2d_bytes_per_row", "kernel_ms_per_launch",
+            "device_idle_pct", "host_lane_rows_pct"} <= reads
+    assert not {"pattern_eval_roofline", "dfa_scan_roofline",
+                "own_class_roofline"} & reads
+    for other in manifest["workloads"][:-1]:
+        names = {m["name"] for m in
+                 harness.load_cell(manifest, ROOT, other["name"])["per_layer"]}
+        assert {"fe_bytes_per_check", "fe_headers_per_check", "dfa_eff_bytes",
+                "dfa_dev_bytes_pct"} <= names
+        assert "long_value_roofline" not in names
+    generator = harness.load_module("corpora", config["generator"])
+    manifests = generator.manifests({"n_configs": 3})
+    (evaluator,) = manifests[2]["spec"]["authorization"].values()
+    patterns = evaluator["patternMatching"]["patterns"]
+    assert len(patterns) == 10 and "when" not in manifests[2]["spec"]
+    leaves = [leaf for p in patterns for leaf in p.get("any", [p])]
+    assert sum(leaf["operator"] == "matches" for leaf in leaves) == 5
+    Reference(manifests)  # the plain reference, unedited, takes the corpus
+
+
+def test_edge_requests_measure_is_what_the_file_records():
+    """`measured_of_the_generator` is the generator's own `measure`, at a
+    size a test can afford: the same shares to a few points."""
+    config = harness.load_cell(
+        _manifest(), ROOT, "edge-1k.unique-sat")["config_file"]
+    generator = harness.load_module("corpora", config["generator"])
+    got = generator.measure(dict(config["params"], **config["requests"]), 4096, 0)
+    want = config["measured_of_the_generator"]
+    assert abs(got["check_request_bytes"]["mean"]
+               - want["check_request_bytes"]["mean"]) < 40
+    assert abs(got["headers_per_request"]["mean"]
+               - want["headers_per_request"]["mean"]) < 0.5
+    for width in ("64", "128", "256"):
+        assert abs(got["rows_with_a_regex_read_value_past_pct"][width]
+                   - want["rows_with_a_regex_read_value_past_pct"][width]) < 3
+
+
+@pytest.mark.parametrize("name, reader, counters", [
+    ("fe_bytes_per_check", "front_clock", ("req_bytes", "parse")),
+    ("fe_headers_per_check", "front_clock", ("req_headers", "parse")),
+    ("dfa_eff_bytes", "ledger_ratio", ("eff_cols", "launches")),
+    ("dfa_dev_bytes_pct", "ledger_ratio", ("dfa_dev_bytes", "dfa_host_bytes")),
+    ("long_value_roofline", "trace_kernel", ())])
+def test_edge_metrics_read_counters_the_program_has(name, reader, counters):
+    """ISSUE 38's metrics are data only, over readers that were there; a
+    program without the counters (the parent) gives them nothing to read
+    and the reader does not raise."""
+    from authorino_tpu.runtime import kernel_cost
+
+    spec = harness._load_json(os.path.join(BENCH, "metrics", name + ".json"))
+    assert spec["reader"] == reader
+    assert os.path.isfile(os.path.join(BENCH, "readers", reader + ".py"))
+    entry = [m for m in _manifest()["per_layer"] if m["name"] == name]
+    assert len(entry) == 1 and entry[0] in _manifest()["per_layer"][-5:]
+    assert entry[0].get("workloads") == spec.get("workloads")
+    module = harness.load_module("readers", reader)
+    if reader == "ledger_ratio":
+        assert set(counters) <= set(kernel_cost._FIELDS)
+
+        def at(**fields):
+            return {"native_frontend": {"kernel_cost": {"ledger": {"native": fields}}}}
+
+        a, b = counters
+        ctx = {"vars0": at(**{a: 10, b: 20}), "vars1": at(**{a: 40, b: 60})}
+        want = (100.0 * 30 / 70 if name == "dfa_dev_bytes_pct" else 30 / 40)
+        assert module.read(ctx, **spec["args"]) == pytest.approx(want)
+        old = {"vars0": at(rows=1), "vars1": at(rows=9)}
+        if name == "dfa_dev_bytes_pct":
+            assert module.read(old, **spec["args"]) is None
+    elif reader == "front_clock":
+        cpp = open(os.path.join(ROOT, "native", "frontend.cpp")).read()
+        assert all(f'"{row}"' in cpp for row in counters)
+
+        def at(rows):
+            cell = {"count": 0, "sum_ns": 0, "max_ns": 0}
+            return {"native_frontend": {"front": {
+                "phases": {"parse": dict(cell, count=rows.pop("parse")),
+                           "idle": dict(cell, sum_ns=1)},
+                "rows": {k: dict(cell, count=v) for k, v in rows.items()}}}}
+
+        per, den = counters
+        ctx = {"vars0": at({per: 1000, "parse": 10}),
+               "vars1": at({per: 31000, "parse": 30})}
+        assert module.read(ctx, **spec["args"]) == 1500.0
+        old = {"vars0": at({"parse": 10}), "vars1": at({"parse": 30})}
+        assert module.read(old, **spec["args"]) is None

@@ -210,10 +210,11 @@ def _tenant_doc(i, rng, deny=None):
     elif deny == "org":
         doc["request"]["headers"]["x-org"] = "nobody"
     elif deny == "long":
-        # past the 64-byte tensor: the regex is answered from the CPU lane
-        doc["request"]["url_path"] = f"/api/v1/t{i}/" + "a" * 80
+        # past the widest byte lane a size class takes (256): the regex is
+        # answered from the CPU lane
+        doc["request"]["url_path"] = f"/api/v1/t{i}/" + "a" * 280
     elif deny == "long-bad":
-        doc["request"]["url_path"] = f"/api/v1/t{i}/" + "a" * 80 + "!"
+        doc["request"]["url_path"] = f"/api/v1/t{i}/" + "a" * 280 + "!"
     return doc
 
 

@@ -33,7 +33,8 @@ ROW = {"count", "sum_ns", "max_ns"}
 COUNTERS = {"send_blocked", "cuts_deferred"}
 STAGE_OF_ROW = {"req_wait": "wait", "req_exec": "exec",
                 "req_respond": "respond"}
-SCAN_COUNTS = ("ovf_dfas", "ovf_loads")  # counts alone: no time of their own
+# counts alone: no time of their own
+SCAN_COUNTS = ("ovf_dfas", "ovf_loads", "req_bytes", "req_headers")
 
 
 @pytest.fixture(scope="module")
@@ -176,7 +177,7 @@ def test_ovf_scan_counts_the_rows_the_batch_events_carry(frontend):
     fe, port = frontend
     a, l0 = quiet(fe, 0), native_ledger("dfa_ovf_rows")
     posts = counts(fe)["post"]
-    long_path = "/api/v2/ok/" + "x" * 90  # past DFA_VALUE_BYTES (64)
+    long_path = "/api/v2/ok/" + "x" * 290  # past every class's byte width
     for k in range(3):
         grpc_call(port, make_req("fast-rx.test", path=f"{long_path}{k}"))
     grpc_call(port, make_req("fast-rx.test", path="/api/v2/ok/short"))
@@ -192,6 +193,25 @@ def test_ovf_scan_counts_the_rows_the_batch_events_carry(frontend):
     assert delta(b, a, "ovf_loads") == 3 * len("/api/v2/ok")
     assert all(row_of(b, r)["sum_ns"] == row_of(b, r)["max_ns"] == 0
                for r in SCAN_COUNTS)
+
+
+def test_req_bytes_and_req_headers_count_what_a_request_carried(frontend):
+    """ISSUE 38: two counts a Check request beside `parse`'s own, the bytes
+    of its CheckRequest message and the headers parsed out of it, so that a
+    cell's requests can be set beside another's (231-367 bytes and 3-10
+    headers in the old cells, 1.2-2.5 KB and 18-30 behind an Envoy edge)."""
+    fe, port = frontend
+    a = quiet(fe, 0)
+    reqs = [make_req("fast-eq.test", headers={"x-org": "acme"}),
+            make_req("fast-rx.test", path="/api/v2/ok/" + "y" * 500, headers={
+                f"x-h{j}": "v" * (10 + j) for j in range(20)})]
+    for req in reqs:
+        grpc_call(port, req)
+    b = quiet(fe, 0)
+    assert delta(b, a, "parse") == len(reqs)
+    assert delta(b, a, "req_bytes") == sum(len(r.SerializeToString()) for r in reqs)
+    assert delta(b, a, "req_headers") == sum(
+        len(r.attributes.request.http.headers) for r in reqs)
 
 
 # ---------------------------------------------------------------------------
@@ -578,5 +598,8 @@ def test_every_metric_of_the_issue_has_its_file():
     scan = {"fe_ovf_scan_us", "fe_ovf_loads_per_dfa"}
     assert all(per_layer[n]["workloads"] == [
         "routes-1k.unique-sat", "mixed-tenants-1k.unique-sat"] for n in scan)
-    assert set(_front_metrics()) == scan | {
+    # ISSUE 38's two, counts alone over `parse`'s count, in every cell
+    sizes = {"fe_bytes_per_check", "fe_headers_per_check"}
+    assert all("workloads" not in per_layer[n] for n in sizes)
+    assert set(_front_metrics()) == scan | sizes | {
         n for n in want if n.startswith("fe_") and n != "fe_respond_p50_us"}

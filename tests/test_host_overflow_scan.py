@@ -1,5 +1,6 @@
 """The host's overflow scan (ISSUE 37; native/frontend.cpp `scan_overflow`):
-a value past DFA_VALUE_BYTES on a regex attribute has every DFA of the row's
+a value past its config's size class's byte width (64, 128 or 256: ISSUE 38)
+on a regex attribute has every DFA of the row's
 config that reads the attribute advanced abreast, one byte at a time, and a
 DFA leaves the pass in a state that absorbs.
 
@@ -57,7 +58,8 @@ needs_native = pytest.mark.skipif(
     not _native_available(), reason="native frontend unavailable")
 
 COUNTS = (1, 2, 17, 130)
-LENGTHS = (65, 96, 300, 4096)
+# either side of each width a size class can take (64, 128, 256), and far past
+LENGTHS = (65, 96, 129, 257, 300, 4096)
 FITS = 48  # the same value cut to fit the device's byte tensor
 OK, DENIED = 0, 7
 HEAD = "/api/v1/t0/"
@@ -190,12 +192,20 @@ def _scans(fe):
     return fe._mod.fe_loop_clock()["phases"]["ovf_scan"]["count"]
 
 
+def _width(policy, cfg_id):
+    """The byte width of the config's size class: a value past it is the
+    host's, one inside it the device's (ISSUE 38)."""
+    return int(policy.config_byte_width[policy.config_ids[cfg_id]])
+
+
 @needs_native
 @pytest.mark.parametrize("kind", sorted(KINDS))
 @pytest.mark.parametrize("n", COUNTS)
 def test_every_dfas_verdict_equals_re_and_the_device_lanes(served, n, kind):
-    fe, call, _ = served
+    fe, call, policy = served
     host = f"head-{n}.test"
+    width = _width(policy, f"ns/head-{n}")
+    assert width in (64, 128, 256)
     fits = KINDS[kind](FITS)
     scans = _scans(fe)
     on_device = _verdicts(call, host, n, {"x-path": fits})
@@ -206,7 +216,9 @@ def test_every_dfas_verdict_equals_re_and_the_device_lanes(served, n, kind):
         assert len(value) == length > DFA_VALUE_BYTES
         scans = _scans(fe)
         on_host = _verdicts(call, host, n, {"x-path": value})
-        assert _scans(fe) == scans + n  # the host scanned every row
+        # past the row's own class's width the host scanned every row;
+        # inside it the kernel did, whatever another class's width is
+        assert _scans(fe) == scans + (n if length > width else 0)
         assert on_host == _expected(n, value), (n, kind, length)
         assert all(h == d for j, (h, d) in enumerate(zip(on_host, on_device))
                    if j % 5 != 4), (n, kind, length)
@@ -227,13 +239,14 @@ def test_a_missing_attribute_reads_the_empty_value(served, n):
 @needs_native
 @pytest.mark.parametrize("n", COUNTS)
 def test_a_constant_that_overflows_takes_the_same_pass(served, n):
-    fe, call, _ = served
+    fe, call, policy = served
     host = f"const-{n}.test"
+    width = _width(policy, f"ns/const-{n}")
     got = {}
     for key, (kind, length) in CONST_KEYS.items():
         scans = _scans(fe)
         got[key] = _verdicts(call, host, n, {"x-api-key": f"{key}-{n}"})
-        assert _scans(fe) - scans == (n if length > DFA_VALUE_BYTES else 0)
+        assert _scans(fe) - scans == (n if length > width else 0)
         assert got[key] == _expected(n, KINDS[kind](length)), (n, key)
     assert all(a == b for j, (a, b) in enumerate(zip(
         got["key-96"], got["key-fits"])) if j % 5 != 4)
@@ -300,7 +313,9 @@ def _ovf_counts(fe):
 
 @needs_native
 def test_the_scans_counts_say_where_the_dfas_settled(served):
-    fe, call, _ = served
+    fe, call, policy = served
+    # sixteen routes of up to 72 states: the class keeps the floor width
+    assert _width(policy, "ns/routes") == DFA_VALUE_BYTES
     route = route_rules.ROUTES[route_rules.LONG_ROUTES[0]]
     paths = [HEAD + route[4](random.Random(k), 96 - len(HEAD)) for k in range(8)]
     assert all(len(p) == 96 and re.search(ROUTE_REGEXES[
@@ -317,12 +332,14 @@ def test_the_scans_counts_say_where_the_dfas_settled(served):
     assert 96 / n < per_dfa < 96 / 4, per_dfa
     assert loads1 - loads0 >= 96 * len(paths)
     # a corpus whose DFAs never absorb reads every byte with every DFA
+    past = [n for n in LENGTHS if n > _width(policy, "ns/never")]
+    assert past and len(past) < len(LENGTHS)  # its class is wider than 64
     long_paths = ["/" + "x" * (length - 1) for length in LENGTHS]
     codes = _codes(call, [make_req("never.test", path=p) for p in long_paths])
     dfas2, loads2 = _ovf_counts(fe)
     assert codes == [OK] * len(long_paths)
-    assert dfas2 - dfas1 == len(NEVER_ABSORB) * len(long_paths)
-    assert loads2 - loads1 == len(NEVER_ABSORB) * sum(LENGTHS)
+    assert dfas2 - dfas1 == len(NEVER_ABSORB) * len(past)
+    assert loads2 - loads1 == len(NEVER_ABSORB) * sum(past)
     # a value that fits enters no DFA here
     _codes(call, [make_req("routes.test", path=HEAD + "health")])
     assert _ovf_counts(fe) == (dfas2, loads2)

@@ -211,6 +211,7 @@ REQUESTS = [
     make_req("fast-rx.test", path="/api/v2/ok?x=1"),
     make_req("fast-rx.test", path="/api/nope"),
     make_req("fast-rx.test", path="/api/v9/ok" + "a" * 100),     # > DFA_VALUE_BYTES
+    make_req("fast-rx.test", path="/api/v9/ok" + "a" * 300),     # > every class's width
     make_req("fast-deny.test", headers={"x-pass": "yes"}),
     make_req("fast-deny.test", headers={"x-pass": "no"}),        # custom 302 deny
     make_req("slow-key.test", headers={"authorization": "APIKEY sekret"}),
@@ -1854,7 +1855,7 @@ def test_dfa_overflow_rides_fast_lane(stack):
     C++ host — still the fast lane, still exact."""
     _, fe, native_port, py_port = stack
     before = fe.stats()["dfa_overflow"]
-    req = make_req("fast-rx.test", path="/api/v1/ok" + "b" * 200)
+    req = make_req("fast-rx.test", path="/api/v1/ok" + "b" * 300)
     assert response_key(grpc_call(native_port, req)) == response_key(grpc_call(py_port, req))
     assert fe.stats()["dfa_overflow"] > before
 
@@ -2217,7 +2218,9 @@ def _pinned_lane(path):
          "members": np.zeros((B, 1, 2), np.int16),
          "cpu_dense": np.zeros((B, 2), np.uint8),
          "attr_bytes": np.zeros((B, 1, 64), np.uint8),
-         "byte_ovf": np.zeros((B, 1), np.uint8)}
+         "byte_ovf": np.zeros((B, 1), np.uint8),
+         "byte_used": np.zeros((B,), np.uint16),
+         "dfa_bytes": np.zeros((B, 2), np.uint32)}
     if sharded:
         a["shard_of"] = np.zeros((B,), np.int32)
     rec.arrays.append(a)

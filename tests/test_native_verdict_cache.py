@@ -38,6 +38,10 @@ def slot(sharded):
         "config_id": np.zeros((B,), dtype=np.int32),
         "attr_bytes": np.zeros((B,) + mid + (NB, DVB), dtype=np.uint8),
         "byte_ovf": np.zeros((B,) + mid + (NB,), dtype=np.uint8),
+        # the encoder's: the longest value of the row's byte lane (the key
+        # reads that far), and its DFA byte counts (no part of the key)
+        "byte_used": np.zeros((B,), dtype=np.uint16),
+        "dfa_bytes": np.zeros((B, 2), dtype=np.uint32),
     }
     if sharded:
         a["shard_of"] = np.zeros((B,), dtype=np.int32)
@@ -61,6 +65,7 @@ def fill(a, ids, sharded):
     path = np.zeros((n, DVB), dtype=np.uint8)
     path[:, :8] = ids.astype("<i8").view(np.uint8).reshape(n, 8)
     a["attr_bytes"][at + (0,)] = path
+    a["byte_used"][:n] = 8
     a["byte_ovf"][at + (1,)] = ids % 3 == 0
 
 

@@ -100,8 +100,9 @@ def _corpus(case, seed):
 def _doc(rng):
     path = rng.choice(["/t0/a", "/t1/b/c", "/t3/", "/named/x", "/abc/x", "/T"])
     if rng.random() < 0.25:
-        # past the 64-byte tensor: regexes on it take the CPU-lane answer
-        path += "a" * 80 + rng.choice(["", "!"])
+        # past the widest byte lane a size class takes (256): regexes on
+        # it take the CPU-lane answer
+        path += "a" * 280 + rng.choice(["", "!"])
     return {
         "request": {
             "method": rng.choice(["GET", "DELETE"]),
@@ -154,9 +155,9 @@ def all_operand_docs(rng: random.Random, n=48):
     return [{
         "req": {"n": rng.choice([-10, 0, 3, 29, 30, "x", None]),
                 "m": rng.choice(["GET", "POST", "PUT"]),
-                # the long path exceeds DFA_VALUE_BYTES -> byte overflow
+                # the long path exceeds every class's width -> byte overflow
                 "path": rng.choice(["/svc-0/a", "/svc-1/b", "/zzz",
-                                    "/svc-2/" + "x" * 200]),
+                                    "/svc-2/" + "x" * 300]),
                 "q": rng.choice(["aaaa", "aaa", "ab"])},
         "auth": {"identity": {
             "sub": rng.choice(ents),

@@ -50,12 +50,14 @@ from test_native_frontend import (_native_available, grpc_call,  # noqa: E402
 PARAMS = {"n_configs": 12, "n_large": 2, "services": 3}
 REQUESTS = {"large_share": 0.4, "deny_share": 0.5, "unrouted_share": 0.1,
             "long_path_share": 0.15}
+# device_width (ISSUE 38): 2 x 16 x 256 state-steps a row fit the budget at
+# the widest lane; 50 x 72 pass it at 64 already and keep the floor
 SMALL = {"leaf_cols_per_row": 10, "dfa_rows_per_row": 2, "dfa_states": 16,
-         "cpu_cols": 2, "evaluators": 2}
+         "cpu_cols": 2, "evaluators": 2, "device_width": 256}
 # 3 x 16 route regexes and the catch-all's two; 48 + 2 + 4 methods + 17
 # roles + the organisation + the tier = 73 leaves; 49 evaluators in 64 columns
 LARGE = {"leaf_cols_per_row": 73, "dfa_rows_per_row": 50, "dfa_states": 72,
-         "cpu_cols": 50, "evaluators": 64}
+         "cpu_cols": 50, "evaluators": 64, "device_width": 64}
 
 
 def _entries(manifests, engine=None):
@@ -196,18 +198,18 @@ def test_every_config_is_in_exactly_one_class_and_widths_cover_members(mixed):
 
 
 @pytest.mark.parametrize("generator, params, widths", [
-    (tenant_rules, {"n_configs": 20}, (10, 2, 16, 2, 2)),
-    (tenant_rules, {"n_configs": 1}, (10, 2, 16, 2, 2)),
-    (named_conditions, {"n_configs": 10}, (5, 1, 16, 1, 2)),
-    (route_rules, {"n_configs": 6}, (40, 18, 64, 18, 32)),
-    (route_rules, {"n_configs": 12}, (40, 18, 72, 18, 32))])
+    (tenant_rules, {"n_configs": 20}, (10, 2, 16, 2, 2, 256)),
+    (tenant_rules, {"n_configs": 1}, (10, 2, 16, 2, 2, 256)),
+    (named_conditions, {"n_configs": 10}, (5, 1, 16, 1, 2, 256)),
+    (route_rules, {"n_configs": 6}, (40, 18, 64, 18, 32, 64)),
+    (route_rules, {"n_configs": 12}, (40, 18, 72, 18, 32, 64))])
 def test_a_corpus_of_one_size_is_one_class_with_the_corpus_widths(
         generator, params, widths):
     policy = compile_corpus(
         [e.rules for e in _entries(generator.manifests(params))])
     (only,) = policy.classes
     keys = ("leaf_cols_per_row", "dfa_rows_per_row", "dfa_states", "cpu_cols",
-            "evaluators")
+            "evaluators", "device_width")
     assert only.widths() == dict(zip(keys, widths), configs=params["n_configs"])
     # to the digit: the class's tables ARE the corpus-wide layout's
     own = policy.own
@@ -326,6 +328,52 @@ def test_a_cut_of_both_classes_answers_each_row_as_the_reference(served, mixed):
     a = fe._cur_rec.arrays[0]
     small, large = (fe._row_h2d_bytes(a, 64, n) for n in (w["cpu_cols"] for w in fe._cur_rec.classes))
     assert large - small == LARGE["cpu_cols"] - SMALL["cpu_cols"]
+
+
+@needs_native
+def test_each_class_fills_overflows_and_launches_at_its_own_width(served, mixed):
+    """ISSUE 38: the small class scans up to 256 value bytes on the device,
+    the large one 64; the slot is as wide as the widest, a row overflows
+    past its OWN class's width, a launch's byte bucket never passes its
+    class's, and the warm grid compiled every (class, pad, eff) of that."""
+    fe, port, _ = served
+    rec = fe._cur_rec
+    assert [c["device_width"] for c in rec.classes] == [256, 64]
+    assert rec.byte_width == 256
+    assert all(a["attr_bytes"].shape[-1] == 256 for a in rec.arrays)
+    grid = fe._bucket_grid(rec)
+    assert sorted({e for _, e in grid}) == [16, 32, 64, 256]
+    assert set(rec.layouts) == {(c, p, min(e, w)) for p, e in grid
+                                for c, w in enumerate((256, 64))}
+    rng = random.Random(38)
+    small = mt._small_row(3, rng, dict(REQUESTS, deny_share=0.0))
+    small["path"] = "/api/v1/t3/" + "a" * 190           # past 64, inside 256
+    large = None
+    while large is None or not 64 < len(large["path"]) <= 96 or large["kind"] != "routed":
+        large = mt._large_row(1, rng, dict(PARAMS, **dict(
+            REQUESTS, deny_share=0.0, long_path_share=1.0, unrouted_share=0.0)))
+    fields = ("dfa_ovf_rows", "eff_cols", "launches", "dfa_dev_bytes",
+              "dfa_host_bytes")
+    compiled, miss0 = pe.eval_bitpacked_staged_jit._cache_size(), _misses(fe)
+    got = {}
+    for name, row in (("small", small), ("large", large)):
+        before = {f: native_ledger(f) for f in fields}
+        code = grpc_call(port, _req(row)).status.code
+        assert code == mixed["reference"].decide(row) == OK, name
+        got[name] = {f: native_ledger(f) - before[f] for f in fields}
+    # the small tenant's 201-byte path rode the device at the 256 bucket
+    assert got["small"] == {"dfa_ovf_rows": 0, "eff_cols": 256, "launches": 1,
+                            "dfa_dev_bytes": 201 + len(small["headers"]["x-request-id"]),
+                            "dfa_host_bytes": 0}
+    # the large tenant's 65-96-byte path is the host's: its 129 path DFAs'
+    # bytes are counted there, the request id's one DFA on the device, and
+    # the launch ran the shortest bucket
+    assert got["large"] == {
+        "dfa_ovf_rows": 1, "eff_cols": 16, "launches": 1,
+        "dfa_dev_bytes": len(large["headers"]["x-request-id"]),
+        "dfa_host_bytes": (LARGE["dfa_rows_per_row"] - 1) * len(large["path"])}
+    assert _misses(fe) == miss0
+    assert pe.eval_bitpacked_staged_jit._cache_size() == compiled
 
 
 @needs_native

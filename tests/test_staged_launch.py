@@ -69,7 +69,7 @@ def _slot(case, dtype):
          "cpu_dense": np.asarray(db.cpu_dense).view(np.uint8).copy(),
          "config_id": np.asarray(db.config_id).astype(np.int32),
          "attr_bytes": np.zeros((MAX_BATCH, max(policy.n_byte_attrs, 1),
-                                 DFA_VALUE_BYTES), dtype=np.uint8),
+                                 policy.byte_width), dtype=np.uint8),
          "byte_ovf": np.zeros((MAX_BATCH, max(policy.n_byte_attrs, 1)),
                               dtype=np.uint8)}
     has_dfa = pe.has_dfa(params)
@@ -125,6 +125,31 @@ def test_staged_entry_equals_six_operand_entry_over_the_warm_grid(
         np.testing.assert_array_equal(staged, six)
         answers |= set((staged[:, 0] & 1).tolist())
     assert answers == {0, 1}, "both verdicts were compared"
+
+
+@pytest.mark.parametrize("cut", ["full", "dedup"])
+@pytest.mark.parametrize("eff", [16, 32, 64])
+def test_a_corpus_with_no_value_past_64_stages_what_a_64_wide_slot_stages(eff, cut):
+    """ISSUE 38: the class of `tenant_rules` takes the 256-byte lane, so the
+    slot's `attr_bytes` are [B, NB, 256] where the parent's were [B, NB,
+    64]; no value of its rows passes 64, a launch's bucket is picked from
+    the rows' own values, and the staged buffer is byte for byte what the
+    64-wide slot staged."""
+    from authorino_tpu.runtime.native_frontend import NativeFrontend
+
+    params, a, count, has_dfa, n_cpu = _slot("tenant_rules", np.int16)
+    assert has_dfa and a["attr_bytes"].shape[-1] == 256
+    assert not a["attr_bytes"][..., DFA_VALUE_BYTES:].any()
+    narrow = dict(a, attr_bytes=np.ascontiguousarray(
+        a["attr_bytes"][..., :DFA_VALUE_BYTES]))
+    rows = slice(32) if cut == "full" else np.asarray(
+        list(range(0, 29, 2)) + [0] * 17)
+    wide_buf = pe.fuse_bytes(NativeFrontend._operand_views(a, rows, eff, n_cpu))
+    narrow_buf = pe.fuse_bytes(
+        NativeFrontend._operand_views(narrow, rows, eff, n_cpu))
+    assert wide_buf.tobytes() == narrow_buf.tobytes()
+    assert (NativeFrontend._row_h2d_bytes(a, eff, n_cpu)
+            == NativeFrontend._row_h2d_bytes(narrow, eff, n_cpu))
 
 
 @pytest.mark.parametrize("pad", [16, 32, 64])
@@ -297,11 +322,12 @@ def test_probe_failure_keeps_six_transfers_and_the_same_answers(
 @needs_native
 def test_overflowed_values_count_rows_and_the_kernel_reports_its_widths(served):
     """ISSUE 32: the ledger's `dfa_ovf_rows` counts a row once, however
-    many of its values passed DFA_VALUE_BYTES and whatever a retry does,
+    many of its values passed its class's byte width and whatever a retry does,
     beside the encoder's count of values; /debug/vars names the state axis
     of the served table store and one launch's temporaries."""
     fe, port, engine = served
-    long_path = "/api/v3/ok" + "c" * (DFA_VALUE_BYTES + 9)
+    width = int(engine._snapshot.policy.config_byte_width.max())
+    long_path = "/api/v3/ok" + "c" * (width + 9)
     assert response_key(grpc_call(port, make_req("fast-rx.test", path="/api/v3/ok")))[0] == 0
     rows0, values0 = native_ledger("dfa_ovf_rows"), fe.stats()["dfa_overflow"]
     all_rows0 = native_ledger("rows")
