@@ -12,12 +12,14 @@ run in milliseconds, so a malformed snapshot is caught at reconcile time
 Checks and their finding kinds (catalogue: docs/static_analysis.md):
 
   dfa-table-index    every dfa_table_of_row entry < n_dfa_tables (and >= 0)
-  dfa-next-state     transition tables are [T, S, 256] with next-states < S
+  dfa-next-state     transition tables are [T, S, 256] with next-states < S,
+                     of a dtype that can name S states (u16 past 256)
   circuit-order      And/Or children reference strictly earlier buffer slots
                      (acyclic + topologically ordered by construction)
   operand-range      eval tables / leaf attrs / slot maps inside their grids
   lane-contract      dtype + shape contracts of the gather and matmul lane
-                     operand pytrees (to_device host build)
+                     operand pytrees (to_device host build); the matmul
+                     lane's tables are f32 past 256 states
   scatter-cover      a dedup plan's fan-out reproduces the batch exactly
   pack-grid          packed DeviceBatch axes match the policy's padded grid
   shard-stack        every mesh shard's padded grid matches shard 0's — the
@@ -38,7 +40,8 @@ Checks and their finding kinds (catalogue: docs/static_analysis.md):
                      own position of every class maps back to its corpus
                      slot, a class's DFA rows are its members' and its
                      table store holds their tables, cut to states no
-                     transition leaves
+                     transition leaves, u8 at 256 states or fewer and u16
+                     past (the dtype the served scan's arithmetic follows)
 """
 
 from __future__ import annotations
@@ -59,6 +62,7 @@ from ..compiler.compile import (
     OP_RELATION,
     OP_TREE_CPU,
     CompiledPolicy,
+    dfa_state_dtype,
 )
 from . import Finding
 
@@ -88,6 +92,11 @@ def _check_dfa(policy: CompiledPolicy, out: List[Finding]) -> None:
             "dfa_tables"))
         return
     T, S = int(tables.shape[0]), int(tables.shape[1])
+    if tables.dtype.kind == "u" and S > 1 << (8 * tables.dtype.itemsize):
+        out.append(_err(
+            "dfa-next-state",
+            f"{tables.dtype} tables cannot name {S} states (u16 past 256: "
+            "compiler/compile.py dfa_state_dtype)", "dfa_tables"))
     # uint8 tables can't go negative, but the lint must not trust the
     # dtype it is auditing — a corrupt artifact may arrive signed
     if tables.size and (int(tables.min()) < 0 or int(tables.max()) >= S):
@@ -524,6 +533,12 @@ def _check_classes(policy: CompiledPolicy, out: List[Finding]) -> None:
                 bad("the class's DFA table store does not hold its rows' "
                     "tables, or cuts a state a transition reaches", c)
                 return
+            if cls.dfa_tables.dtype != dfa_state_dtype(S):
+                # the served scan picks its arithmetic by this dtype: u8
+                # (bf16 on the chip) only where every id is under 256
+                bad(f"the class's {S}-state store is {cls.dfa_tables.dtype}, "
+                    f"not {dfa_state_dtype(S)}", c)
+                return
         before = len(out)
         _check_own_layout(policy, out, own=cls.own, cfgs=cfgs,
                           dfa_rows=corpus_rows, where=f"classes[{c}].own")
@@ -621,6 +636,11 @@ def _check_lanes(policy: CompiledPolicy, out: List[Finding]) -> None:
                     "lane-contract",
                     f"dfa_tables_f shape {mm['dfa_tables_f'].shape} != "
                     f"({R}, {S}, 256) (matmul lane expands per-row)", loc))
+            if S > 256 and mm["dfa_tables_f"].dtype != np.float32:
+                out.append(_err(
+                    "lane-contract",
+                    f"dfa_tables_f is {mm['dfa_tables_f'].dtype} at {S} "
+                    "states: ids past 256 round there (f32 keeps them)", loc))
 
 
 def lint_scatter_plan(keys: Sequence[bytes], rows: Sequence[int],

@@ -122,7 +122,10 @@ REASON_CODES = {
     "invalid-regex-fallback": "a whole-tree CPU-fallback leaf (invalid "
                               "regex or unfoldable numeric constant) is "
                               "re-evaluated host-side per request",
-    "cpu-regex": "a regex outside the DFA subset rides the CPU regex lane",
+    "cpu-regex": "a regex outside the DFA subset (lookaround, flags, inner "
+                 "anchors, escapes it lacks; not its size up to "
+                 "redfa.MAX_STATES) rides the CPU regex lane, and the native "
+                 "front end gives its config no fast-lane plan",
     "cpu-grid-overflow": "incl/excl membership leaves can overflow the "
                          "compact K grid, routing those rows to the host "
                          "oracle (reported only while the deciding "
@@ -772,6 +775,9 @@ def _slot_digest(policy: CompiledPolicy, circ: _Circuit, slot: int,
                 t_i = int(policy.dfa_table_of_row[row]) \
                     if 0 <= row < policy.dfa_table_of_row.shape[0] else -1
                 art = hashlib.sha256()
+                # the dtype too: the served scan's arithmetic follows it
+                # (u8: bf16 on the chip; u16: the exact wide scan)
+                art.update(policy.dfa_tables.dtype.str.encode())
                 art.update(policy.dfa_tables[t_i].tobytes()
                            if 0 <= t_i < policy.dfa_tables.shape[0] else b"?")
                 art.update(policy.dfa_accept[t_i].tobytes()

@@ -58,7 +58,8 @@ __all__ = [
     "ConfigRules", "CompiledPolicy", "ShapeTargets", "OwnLayout", "SizeClass",
     "compile_corpus", "derive_layouts", "CLASS_RATIO", "CLASS_FLOOR_BYTES",
     "TRUE_SLOT", "FALSE_SLOT", "DFA_VALUE_BYTES", "DFA_WIDTHS",
-    "DFA_SCAN_BUDGET", "class_device_width",
+    "DFA_SCAN_BUDGET", "class_device_width", "dfa_state_dtype",
+    "cpu_regex_leaves",
 ]
 
 OP_EQ, OP_NEQ, OP_INCL, OP_EXCL, OP_CPU, OP_ERROR, OP_TREE_CPU, OP_REGEX_DFA = (
@@ -103,6 +104,24 @@ _NUM_OP_OF = {
 DFA_VALUE_BYTES = 64
 DFA_WIDTHS = (64, 128, 256)
 DFA_SCAN_BUDGET = 65536
+
+
+def dfa_state_dtype(n_states: int) -> np.dtype:
+    """The dtype of a table store's next states at a state axis of
+    ``n_states``: u8 while every id fits it, u16 past 256 (compiler/redfa.py
+    MAX_STATES).  A store of u8 tables scans as it always has; a u16 store
+    takes the scan that is exact past 256 (ops/pattern_eval.py
+    _own_dfa_row_res), and the host's overflow scan loads two bytes a
+    state."""
+    return np.dtype(np.uint8 if n_states <= 256 else np.uint16)
+
+
+def cpu_regex_leaves(policy: "CompiledPolicy") -> int:
+    """The `matches` leaves of a compiled corpus that compiler/redfa.py could
+    not take (outside its subset): the CPU regex lane decides them, and a
+    config that has one gets no native fast-lane plan."""
+    return sum(1 for op, rx in zip(policy.leaf_op.tolist(), policy.leaf_regex)
+               if op == OP_CPU and rx is not None)
 
 
 def class_device_width(n_dfa_rows: int, n_states: int) -> int:
@@ -268,7 +287,7 @@ class CompiledPolicy:
     # identical patterns across AuthConfigs) share one [S, 256] table and
     # point at it through dfa_table_of_row — rule-tensor compaction that
     # shrinks both the device corpus upload and per-snapshot host memory
-    dfa_tables: np.ndarray     # [T, S, 256] uint8 — UNIQUE transition tables
+    dfa_tables: np.ndarray     # [T, S, 256] dfa_state_dtype(S) — UNIQUE transition tables
     dfa_accept: np.ndarray     # [T, S] bool
     dfa_table_of_row: np.ndarray  # [R] int32 — dfa row → unique table
     dfa_leaf_attr: np.ndarray  # [R] int32 — attr idx of each dfa row
@@ -700,7 +719,7 @@ class SizeClass:
     own: OwnLayout                # [G_c, ...] tables; evals [G_c, 3, E_c]
     dfa_rows: np.ndarray          # [R_c] int32 corpus DFA rows of the store, ascending
     config_dfa_rows: np.ndarray   # [G_c, D_c] int32 positions in dfa_rows (-1 pad)
-    dfa_tables: np.ndarray        # [T_c, S_c, 256] uint8
+    dfa_tables: np.ndarray        # [T_c, S_c, 256] dfa_state_dtype(S_c)
     dfa_accept: np.ndarray        # [T_c, S_c] bool
     dfa_table_of_row: np.ndarray  # [R_c] int32 store row -> table of the store
     # bytes of a value the device scans for a member (the width rule at
@@ -884,7 +903,10 @@ def _class_of(policy: "CompiledPolicy", cfgs: np.ndarray, sizes,
     return SizeClass(
         configs=cfgs.astype(np.int32), cfg_local=cfg_local, own=layout,
         dfa_rows=dfa_rows, config_dfa_rows=local_rows,
-        dfa_tables=np.ascontiguousarray(policy.dfa_tables[tabs][:, :S_c]),
+        # a class of 256 states or fewer keeps u8 tables whatever the
+        # corpus's widest table is
+        dfa_tables=np.ascontiguousarray(policy.dfa_tables[tabs][:, :S_c],
+                                        dtype=dfa_state_dtype(S_c)),
         dfa_accept=np.ascontiguousarray(policy.dfa_accept[tabs][:, :S_c]),
         dfa_table_of_row=np.asarray(table_of_row, dtype=np.int32).reshape(-1),
         # forced widths (shards stack on one byte tensor): the floor
@@ -1307,18 +1329,17 @@ def compile_corpus(
     if targets is not None:
         assert targets.n_dfa_tables >= Tp, "targets.n_dfa_tables too small"
         Tp = targets.n_dfa_tables
-    dfa_tables = np.zeros((Tp, S, 256), dtype=np.uint8)
+    dfa_tables = np.zeros((Tp, S, 256), dtype=dfa_state_dtype(S))
     dfa_accept = np.zeros((Tp, S), dtype=bool)
     for t_i, dfa in enumerate(table_dfas):
         s = dfa.n_states
         dfa_tables[t_i, :s] = dfa.trans
         # padded states self-loop so they can never be reached anyway
-        for extra in range(s, S):
-            dfa_tables[t_i, extra] = extra
+        dfa_tables[t_i, s:] = np.arange(s, S)[:, None]
         dfa_accept[t_i, :s] = dfa.accept
     for t_i in range(T, Tp):
         # padded tables (mesh targets): self-loop everywhere, never referenced
-        dfa_tables[t_i] = np.arange(S, dtype=np.uint8)[:, None]
+        dfa_tables[t_i] = np.arange(S, dtype=dfa_tables.dtype)[:, None]
     if targets is not None:
         assert targets.n_byte_attrs >= n_byte_attrs, "targets.n_byte_attrs too small"
         # force a uniform (possibly dummy) byte-tensor axis so shards whose

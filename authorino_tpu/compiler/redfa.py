@@ -11,7 +11,8 @@ Supported subset (RE2-safe, byte-oriented):
   - literals (UTF-8 bytes), ``.`` (any byte except \\n, like RE2 default)
   - escapes: \\d \\D \\w \\W \\s \\S and escaped metacharacters
   - char classes ``[a-z0-9_]`` with ranges and negation (ASCII only)
-  - ``* + ? {m} {m,} {m,n}`` (bounded counts ≤ 16 to bound state blowup)
+  - ``* + ? {m} {m,} {m,n}`` (counts up to RE2's own 1000; what bounds
+    a counted repeat is the NFA it spells out, NFA_STATES)
   - alternation ``|``, groups ``(...)`` (non-capturing semantics)
   - anchors ``^`` (leading) and ``$`` (trailing) only
 
@@ -19,6 +20,14 @@ Matching is *search* semantics like Go's MatchString: unanchored patterns
 get an implicit leading self-loop and absorbing accept states.  Byte 0 is
 reserved as padding (identity transitions); values containing NUL ride the
 CPU lane.  DFAs are capped at MAX_STATES; larger ones fall back.
+
+Still outside the subset, so the CPU regex lane (and, for a config that has
+such a leaf, the Python path instead of the native fast lane) decides them:
+lookaround and named groups (``(?=`` ``(?!`` ``(?P<``: not RE2 either),
+flags (``(?i)``, ``(?s)``...), anchors anywhere but the pattern's two ends,
+``\\b`` ``\\B`` and other escapes not listed above (Unicode classes
+``\\pL``, hex bytes ``\\x41``), non-ASCII characters inside a class, and a pattern whose
+DFA passes MAX_STATES or whose NFA passes NFA_STATES.
 """
 
 from __future__ import annotations
@@ -28,16 +37,26 @@ from typing import Dict, FrozenSet, List, Optional, Set, Tuple
 
 import numpy as np
 
-__all__ = ["DFA", "compile_regex_dfa", "reserve_memo", "MAX_STATES"]
+__all__ = ["DFA", "compile_regex_dfa", "reserve_memo", "MAX_STATES",
+           "MAX_REPEAT", "NFA_STATES"]
 
-MAX_STATES = 96
-MAX_REPEAT = 16
+# The caps come from what the device's table store holds and what a scan
+# costs, not from a flag.  A table is [S, 256] of next states: u8 while S is
+# at most 256, u16 past it (compiler/compile.py dfa_state_dtype), so a 1,024-
+# state table is 512 KB.  One DFA at the floor width, 1,024 states x 64 bytes,
+# is compiler/compile.py's DFA_SCAN_BUDGET of state-steps a row by itself: a
+# larger one would cost a row more than the width rule lets any class spend.
+# A counted repeat goes to RE2's own limit of 1000; the NFA it spells out
+# (two states a byte-class atom a copy) is what bounds it, at NFA_STATES.
+MAX_STATES = 1024
+MAX_REPEAT = 1000
+NFA_STATES = 4 * MAX_STATES
 ANY_EXCEPT_NL = frozenset(range(1, 256)) - {10}
 
 
 @dataclass
 class DFA:
-    trans: np.ndarray    # [S, 256] uint8 — state transition table
+    trans: np.ndarray    # [S, 256] uint8, uint16 past 256 states: next state
     accept: np.ndarray   # [S] bool
     start: int
 
@@ -63,7 +82,7 @@ class _NFA:
     def new_state(self) -> int:
         self.trans.append({})
         self.eps.append(set())
-        if len(self.trans) > 4 * MAX_STATES:
+        if len(self.trans) > NFA_STATES:
             raise _Unsupported("nfa too large")
         return len(self.trans) - 1
 

@@ -251,10 +251,13 @@ def _matmul_operands(policy: CompiledPolicy, row_slot: np.ndarray, device=None) 
         slot_leaf_oh = np.zeros((NB, L), dtype=np.float32)
         leaf_slot = row_slot[policy.leaf_dfa_row[is_dfa_leaf]]
         slot_leaf_oh[leaf_slot, np.nonzero(is_dfa_leaf)[0]] = 1.0
+        # next-state values <= 255 and state count <= 256: exact in bf16; a
+        # u16 store (ids past 255) travels in f32 and its matmul runs at
+        # HIGHEST precision (_eval_verdicts_matmul), exact to 2**24
+        tdt = cdt if policy.dfa_tables.dtype == np.uint8 else np.float32
         out.update(
             {
-                # next-state values ≤ 255 and state count ≤ 256: exact in bf16
-                "dfa_tables_f": policy.dfa_tables_by_row.astype(cdt),
+                "dfa_tables_f": policy.dfa_tables_by_row.astype(tdt),
                 "dfa_accept_f": policy.dfa_accept_by_row.astype(cdt),
                 "slot_row_oh": slot_row_oh.astype(cdt),
                 "row_leaf_oh": row_leaf_oh.astype(cdt),
@@ -547,11 +550,36 @@ def _pick(x, idx):
     return jnp.sum(jnp.where(hit, x[:, None], 0), axis=2, dtype=x.dtype)
 
 
+def is_wide(tables) -> bool:
+    """Whether a table store holds state ids past 255 (u16 next states,
+    compiler/compile.py dfa_state_dtype): its scan is the wide one."""
+    return tables.dtype != jnp.uint8
+
+
+def _wide_step_maps(own_tables, byte_oh):
+    """[LB, D, S, B] f32 step maps of u16 tables: each next state as two
+    base-256 digits, each a whole number under 256 and so exact in bf16, one
+    bf16 matmul a digit with an f32 result (one non-zero term a sum), then
+    hi * 256 + lo in f32, exact to 2**24.  A bf16 map or carry would round a
+    state id past 256 to its neighbour (bf16 holds 8 bits of mantissa)."""
+    oh = byte_oh.astype(jnp.bfloat16)
+
+    def digit(t):
+        return jnp.einsum("bdsc,bdlc->ldsb", t.astype(jnp.bfloat16), oh,
+                          preferred_element_type=jnp.float32)
+
+    return digit(own_tables >> 8) * 256.0 + digit(own_tables & 0xFF)
+
+
 def _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt):
     """Own-row DFA scan of one size class (``params``: its operands):
     evaluates only the DFA rows ``config_dfa_rows[cfg]`` names and returns
     their accepts [B, D], False on the -1 padding and on rows of no member
     of the class (``cfg`` is the clipped table row).
+
+    A store of u8 tables (S <= 256) scans in ``cdt`` (bf16 on the chip: ids
+    up to 255 are exact there).  A u16 store (S past 256) scans in f32 from
+    ``_wide_step_maps``, whatever ``cdt`` is.
 
     The sequential part keeps the batch on the minor axis: its carry is
     [D, B] and a step reads [D, S, B], so B fills the lanes and S (whole
@@ -560,7 +588,7 @@ def _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt):
     two minor axes (18 x 72) were padded to 24 x 128 and reduced across
     lanes: 2.9 ms a launch of 256 rows at D 18, S 72 against 0.8 ms (chip
     micro-run, PERF.md section 6, PR 32)."""
-    tables = params["dfa_tables"]                            # [T, S, 256] u8
+    tables = params["dfa_tables"]                            # [T, S, 256] u8 / u16
     S = tables.shape[1]
     own = jnp.where(in_range[:, None],
                     jnp.take(params["config_dfa_rows"], cfg, axis=0), -1)  # [B, D]
@@ -570,25 +598,30 @@ def _own_dfa_row_res(params, cfg, in_range, attr_bytes, cdt):
     own_bytes = jnp.take_along_axis(
         attr_bytes, slot[:, :, None], axis=1)                # [B, D, LB] u8
     # whole [S, 256] tables fetched once a launch, from the deduped axis
-    own_tables = jnp.take(tables, tab, axis=0)               # [B, D, S, 256] u8
+    own_tables = jnp.take(tables, tab, axis=0)               # [B, D, S, 256]
     # every byte position's S -> S transition map at once (next-state values
     # <= 255 and 0/1 one-hots: one non-zero term a sum, exact in bf16, maps
     # and carry alike), so the sequential part below carries [D, B] and
     # touches [D, S, B] a step
     byte_oh = own_bytes[..., None] == jnp.arange(256, dtype=own_bytes.dtype)
-    step_maps = jnp.einsum(
-        "bdsc,bdlc->ldsb", own_tables.astype(cdt), byte_oh.astype(cdt),
-        preferred_element_type=cdt)                          # [LB, D, S, B]
-    iota_s = jnp.arange(S, dtype=cdt)
+    if is_wide(tables):
+        sdt = jnp.float32
+        step_maps = _wide_step_maps(own_tables, byte_oh)     # [LB, D, S, B]
+    else:
+        sdt = cdt
+        step_maps = jnp.einsum(
+            "bdsc,bdlc->ldsb", own_tables.astype(cdt), byte_oh.astype(cdt),
+            preferred_element_type=cdt)                      # [LB, D, S, B]
+    iota_s = jnp.arange(S, dtype=sdt)
 
     def dfa_step(state, step_map):  # state [D, B]; step_map [D, S, B]
         nxt = jnp.sum(jnp.where(
             state[:, None, :] == iota_s[:, None], step_map, 0), axis=1)
-        return nxt.astype(cdt), None
+        return nxt.astype(sdt), None
 
     # init carry derived from a varying input (zero-multiplied) so its
     # manual-mesh "varying" type matches inside shard_map
-    init = (own_bytes[:, :, 0].astype(cdt) * 0).T
+    init = (own_bytes[:, :, 0].astype(sdt) * 0).T
     final, _ = jax.lax.scan(dfa_step, init, step_maps)
     accept = jnp.take(params["dfa_accept"], tab, axis=0)     # [B, D, S] bool
     return (own >= 0) & jnp.any(
@@ -669,7 +702,8 @@ def _eval_own_class(params, cp, cdt, attrs_val, members_c, cpu_dense,
         leaf_movf = None
         if member_ovf is not None:
             leaf_movf = _pick(member_ovf, tab[..., OWN_MEMBER])
-    with jax.named_scope("dfa_scan"):
+    wide = cp["dfa_tables"] is not None and is_wide(cp["dfa_tables"])
+    with jax.named_scope("dfa_scan_wide" if wide else "dfa_scan"):
         if cp["dfa_tables"] is not None and attr_bytes is not None:
             own_res = _own_dfa_row_res(cp, cfg, in_range, attr_bytes, cdt)
             # overflowed values: exact answer precomputed into the CPU lane
@@ -738,8 +772,10 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense, config_id,
     # ---- device regex lane: DFA scan, transitions as batched matmuls -----
     with jax.named_scope("dfa_scan"):
         if "dfa_tables_f" in mm and attr_bytes is not None:
-            tables = mm["dfa_tables_f"]                          # [R, S, 256] bf16
+            tables = mm["dfa_tables_f"]           # [R, S, 256] cdt, f32 if wide
             R, S = tables.shape[0], tables.shape[1]
+            tdt = tables.dtype
+            prec = _HIGH if tdt == f32 else None
             # spread each row's attr bytes from its slot: [B, NB, LB] → [B, R, LB]
             row_bytes = jnp.einsum(
                 "bnl,nr->brl", attr_bytes.astype(cdt), mm["slot_row_oh"],
@@ -749,11 +785,11 @@ def _eval_verdicts_matmul(params, attrs_val, members_c, cpu_dense, config_id,
             iota_c = jnp.arange(256, dtype=f32)
 
             def dfa_step(state, byte_col):  # state [B,R] f32; byte_col [B,R] f32
-                byte_oh = (byte_col[..., None] == iota_c).astype(cdt)   # [B,R,256]
+                byte_oh = (byte_col[..., None] == iota_c).astype(tdt)   # [B,R,256]
                 # per-state next-state given this byte: [R,S,256] × [B,R,256]
                 nxt_by_state = jnp.einsum(
-                    "rsc,brc->brs", tables, byte_oh, preferred_element_type=f32
-                )
+                    "rsc,brc->brs", tables, byte_oh, preferred_element_type=f32,
+                    precision=prec)
                 st_oh = (state[..., None] == iota_s).astype(f32)
                 nxt = jnp.sum(st_oh * nxt_by_state, axis=-1)
                 return nxt, None

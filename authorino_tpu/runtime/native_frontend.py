@@ -48,6 +48,7 @@ from ..compiler.compile import (
     OP_REGEX_DFA,
     OP_TREE_CPU,
     CompiledPolicy,
+    cpu_regex_leaves,
 )
 from ..compiler.intern import PAD
 from ..compiler.pack import _trim_bytes, wire_dtype
@@ -539,7 +540,9 @@ def fast_lane_eligible(entry, policy: Optional[CompiledPolicy]) -> Optional[Fast
                 return None
         if not _deny_with_const(rt.deny_with.unauthorized):
             return None
-        # per-request regex/tree oracles cannot run in C++
+        # per-request regex/tree oracles cannot run in C++: a `matches`
+        # outside compiler/redfa.py's subset (lookaround, flags, inner
+        # anchors), never a regex's size, which the device tables hold
         for leaf in policy.config_cpu_leaves[row]:
             if int(policy.leaf_op[leaf]) in (OP_CPU, OP_TREE_CPU):
                 return None
@@ -732,6 +735,9 @@ class _SnapRec:
     # attribution must count only their native denials — kernel-allowed
     # requests continue into the pipeline, which observes them itself
     hybrid_rows: set = field(default_factory=set)
+    # configs of the snapshot that got no native plan (fast_lane_eligible
+    # None): every request to one of their hosts takes the Python pipeline
+    slow_configs: int = 0
     # verdict-cache eligibility per kernel row: [G] bool (single corpus) or
     # [S, G] (mesh) — compiler/compile.py config_cacheable
     cacheable: Optional[np.ndarray] = None
@@ -1248,6 +1254,7 @@ class NativeFrontend:
                 # entry, the operand lane and the widths of one row's work
                 "kernel": self._kernel_of(rec),
                 "fast_configs": len(rec.row_labels),
+                "slow_configs": rec.slow_configs,
                 "hybrid_configs": len(rec.hybrid_rows),
                 "dyn_registrations": len(rec.dyn_regs),
             }
@@ -1263,6 +1270,8 @@ class NativeFrontend:
             view = rec.sharded.host_view
             return {"lane": kernel_lane_of(view), "entry": "sharded_step",
                     "operand_bytes": operand_bytes(view),
+                    "dfa_cpu_leaves": sum(cpu_regex_leaves(p)
+                                          for p in rec.sharded.shards),
                     **kernel_widths(view, own=False)}
         if rec.params is None:
             return None
@@ -1284,11 +1293,20 @@ class NativeFrontend:
                # store: the scalars read the largest class, `classes`
                # lists each
                **kernel_widths(rec.params)}
-        for widths, mine in zip(out["classes"], rec.classes):
+        for widths, mine, cp in zip(out["classes"], rec.classes,
+                                    rec.params["classes"]):
             widths["cpu_cols"] = mine["cpu_cols"]
             # bytes of a value the device scans for the class's members (0:
             # no DFA row); a longer value is the host's overflow scan's
             widths["device_width"] = mine["device_width"]
+            # bytes of a next state in the class's tables: 1 (u8, the bf16
+            # scan), 2 past 256 states (u16, the exact wide scan); 0: no DFA
+            widths["state_bytes"] = (int(cp["dfa_tables"].dtype.itemsize)
+                                     if cp["dfa_tables"] is not None else 0)
+        # `matches` leaves the compiler left to the CPU regex lane (outside
+        # compiler/redfa.py's subset): a config that has one gets no native
+        # plan
+        out["dfa_cpu_leaves"] = cpu_regex_leaves(rec.policy)
         return out
 
     @property
@@ -1852,13 +1870,14 @@ class NativeFrontend:
                 if policy.n_byte_attrs > 0 and policy.dfa_tables.size:
                     # C++ indexes transition tables BY ROW: expand the
                     # compiler's deduped [T, S, 256] store through
-                    # dfa_table_of_row for the native encoder
-                    dt_tr = np.ascontiguousarray(policy.dfa_tables_by_row,
-                                                 dtype=np.uint8)
+                    # dfa_table_of_row for the native encoder, at the
+                    # store's own next-state width (u16 past 256 states)
+                    dt_tr = np.ascontiguousarray(policy.dfa_tables_by_row)
                     dt_fl = np.ascontiguousarray(policy.dfa_flags_by_row,
                                                  dtype=np.uint8)
                     rec.keepalive += [dt_tr, dt_fl]
                     spec.update(dfa_R=int(dt_tr.shape[0]), dfa_S=int(dt_tr.shape[1]),
+                                dfa_state_bytes=int(dt_tr.dtype.itemsize),
                                 dfa_trans_addr=dt_tr.ctypes.data,
                                 dfa_flags_addr=dt_fl.ctypes.data)
                 spec["G"] = policy.n_configs
@@ -1933,8 +1952,7 @@ class NativeFrontend:
                     R = int(p0.dfa_table_of_row.shape[0])
                     dt_tr = np.ascontiguousarray(
                         np.concatenate([p.dfa_tables_by_row
-                                        for p in sharded.shards]),
-                        dtype=np.uint8)
+                                        for p in sharded.shards]))
                     dt_fl = np.ascontiguousarray(
                         np.concatenate([p.dfa_flags_by_row
                                         for p in sharded.shards]),
@@ -1942,6 +1960,7 @@ class NativeFrontend:
                     rec.keepalive += [dt_tr, dt_fl]
                     spec.update(dfa_R=int(dt_tr.shape[0]),
                                 dfa_S=int(dt_tr.shape[1]),
+                                dfa_state_bytes=int(dt_tr.dtype.itemsize),
                                 dfa_trans_addr=dt_tr.ctypes.data,
                                 dfa_flags_addr=dt_fl.ctypes.data)
                     for s, p in enumerate(sharded.shards):
@@ -2077,6 +2096,7 @@ class NativeFrontend:
             for host in entry.hosts:
                 hosts.append((host, fc_idx))
         rec.fc_rows = np.asarray(fc_rows or [0], dtype=np.int64)
+        rec.slow_configs = len(entries) - len(fast_ids)
         if rec.heat is not None:
             rec.heat.bind_authconfigs(rec.row_labels, rec.hybrid_rows)
 

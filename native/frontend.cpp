@@ -635,7 +635,7 @@ static const uint8_t DFA_ACCEPTS = 1, DFA_ABSORBS = 2;
 
 // one DFA of the overflow scan's live set
 struct ScanLane {
-  const uint8_t* trans;  // its [St, 256] table
+  const uint8_t* trans;  // its [St, 256] table, of Snapshot::dfa_state_bytes
   const uint8_t* flags;  // its [St] state flags
   int32_t col;           // its column of the row's cpu_dense
   uint32_t state;
@@ -688,9 +688,11 @@ struct Snapshot {
   int G = 0;  // config rows per shard
   std::vector<std::vector<DfaRef>> cfg_dfas;  // [S*G]; rows globalized
   std::vector<uint16_t> cfg_slot_dfas;  // [S*G, NB]: DFAs of the config that read the byte slot
-  std::vector<uint8_t> dfa_trans;  // [S*R, St, 256]
+  // next states: u8, or u16 where St passes 256 (dfa_state_bytes 2)
+  std::vector<uint8_t> dfa_trans;  // [S*R, St, 256] x dfa_state_bytes
   std::vector<uint8_t> dfa_flags;  // [S*R, St]: DFA_ACCEPTS | DFA_ABSORBS
   int dfa_S = 0;
+  int dfa_state_bytes = 1;
   // the overflow scan's live set (the epoll thread's alone), sized at
   // install to the most DFA leaves any config has: no allocation a request
   std::vector<ScanLane> scan_lanes;
@@ -947,9 +949,11 @@ static inline void put_id(Snapshot* s, char* base, int64_t idx, int32_t v) {
 // St x 256 bytes of a store far larger than the cache) are in flight
 // together and not one after another; a DFA leaves the live set in a state
 // that absorbs, where its verdict is settled.  Each DFA's cpu_dense column
-// is written from its last state's accept bit.
-static void scan_overflow(Server* S, Snapshot* s, size_t ci, int32_t attr,
-                          const char* p, size_t n, uint8_t* cpu_dense) {
+// is written from its last state's accept bit.  `Next` is the tables' next-
+// state type: uint8_t, or uint16_t for a store of more than 256 states.
+template <typename Next>
+static void scan_overflow_as(Server* S, Snapshot* s, size_t ci, int32_t attr,
+                             const char* p, size_t n, uint8_t* cpu_dense) {
   ScanLane* lanes = s->scan_lanes.data();
   const size_t St = (size_t)s->dfa_S;
   const uint8_t first = n ? (uint8_t)p[0] : 0;
@@ -957,11 +961,11 @@ static void scan_overflow(Server* S, Snapshot* s, size_t ci, int32_t attr,
   for (const DfaRef& d : s->cfg_dfas[ci]) {
     if (d.attr != attr) continue;
     ScanLane& l = lanes[live++];
-    l.trans = s->dfa_trans.data() + (size_t)d.row * St * 256;
+    l.trans = s->dfa_trans.data() + (size_t)d.row * St * 256 * sizeof(Next);
     l.flags = s->dfa_flags.data() + (size_t)d.row * St;
     l.col = d.col;
     l.state = 0;
-    __builtin_prefetch(l.trans + first);
+    __builtin_prefetch(l.trans + first * sizeof(Next));
     __builtin_prefetch(l.flags);
   }
   S->clk.rows[ROW_OVF_DFAS].bump((uint64_t)live);
@@ -971,7 +975,8 @@ static void scan_overflow(Server* S, Snapshot* s, size_t ci, int32_t attr,
     loads += (uint64_t)live;
     for (int k = 0; k < live;) {
       ScanLane& l = lanes[k];
-      const uint32_t next = l.trans[(size_t)l.state * 256 + b];
+      const uint32_t next =
+          reinterpret_cast<const Next*>(l.trans)[(size_t)l.state * 256 + b];
       const uint8_t f = l.flags[next];
       if (f & DFA_ABSORBS) {
         cpu_dense[l.col] = f & DFA_ACCEPTS;
@@ -985,6 +990,14 @@ static void scan_overflow(Server* S, Snapshot* s, size_t ci, int32_t attr,
   S->clk.rows[ROW_OVF_LOADS].bump(loads);
   for (int k = 0; k < live; ++k)
     cpu_dense[lanes[k].col] = lanes[k].flags[lanes[k].state] & DFA_ACCEPTS;
+}
+
+static void scan_overflow(Server* S, Snapshot* s, size_t ci, int32_t attr,
+                          const char* p, size_t n, uint8_t* cpu_dense) {
+  if (s->dfa_state_bytes == 2)
+    scan_overflow_as<uint16_t>(S, s, ci, attr, p, n, cpu_dense);
+  else
+    scan_overflow_as<uint8_t>(S, s, ci, attr, p, n, cpu_dense);
 }
 
 static void render_i64(int64_t v, std::string& out) {

@@ -417,10 +417,12 @@ PyObject* fe_swap_py(PyObject*, PyObject* args) {
   // rows arrive globalized by the Python side
   long dfa_R = dict_int(d, "dfa_R");
   snap->dfa_S = (int)dict_int(d, "dfa_S");
+  snap->dfa_state_bytes = dict_int(d, "dfa_state_bytes", 1) == 2 ? 2 : 1;
   if (dfa_R > 0 && snap->dfa_S > 0) {
     const uint8_t* tr = (const uint8_t*)dict_addr(d, "dfa_trans_addr");
     const uint8_t* fl = (const uint8_t*)dict_addr(d, "dfa_flags_addr");
-    snap->dfa_trans.assign(tr, tr + (size_t)dfa_R * snap->dfa_S * 256);
+    snap->dfa_trans.assign(
+        tr, tr + (size_t)dfa_R * snap->dfa_S * 256 * snap->dfa_state_bytes);
     snap->dfa_flags.assign(fl, fl + (size_t)dfa_R * snap->dfa_S);
   }
   snap->G = (int)dict_int(d, "G", 0);

@@ -236,10 +236,11 @@ def test_edge_1k_cell_is_as_the_issue_states_and_its_files_agree():
     measurements inside the issue's aims."""
     manifest = _manifest()
     cell = harness.load_cell(manifest, ROOT, "edge-1k.unique-sat")
-    assert manifest["workloads"][-1]["name"] == cell["name"]    # appended
-    assert manifest["configs"][-1]["name"] == cell["config"] == "edge-1k"
+    # appended after the six cells before it (later configurations follow)
+    assert manifest["workloads"][6]["name"] == cell["name"]
+    assert manifest["configs"][5]["name"] == cell["config"] == "edge-1k"
     checks = [m for m in manifest["end_to_end"] if m["name"] == "checks_per_s"]
-    assert checks[0]["workloads"][-1] == cell["name"]
+    assert checks[0]["workloads"][6] == cell["name"]
     config = cell["config_file"]
     assert (cell["traffic"], cell["chips"]) == ("unique-sat", 1)
     assert config["params"] == {"n_configs": 1000}
@@ -269,7 +270,9 @@ def test_edge_1k_cell_is_as_the_issue_states_and_its_files_agree():
             "device_idle_pct", "host_lane_rows_pct"} <= reads
     assert not {"pattern_eval_roofline", "dfa_scan_roofline",
                 "own_class_roofline"} & reads
-    for other in manifest["workloads"][:-1]:
+    for other in manifest["workloads"]:
+        if other["name"] == cell["name"]:
+            continue
         names = {m["name"] for m in
                  harness.load_cell(manifest, ROOT, other["name"])["per_layer"]}
         assert {"fe_bytes_per_check", "fe_headers_per_check", "dfa_eff_bytes",
@@ -317,8 +320,11 @@ def test_edge_metrics_read_counters_the_program_has(name, reader, counters):
     spec = harness._load_json(os.path.join(BENCH, "metrics", name + ".json"))
     assert spec["reader"] == reader
     assert os.path.isfile(os.path.join(BENCH, "readers", reader + ".py"))
-    entry = [m for m in _manifest()["per_layer"] if m["name"] == name]
-    assert len(entry) == 1 and entry[0] in _manifest()["per_layer"][-5:]
+    per_layer = _manifest()["per_layer"]
+    entry = [m for m in per_layer if m["name"] == name]
+    # the five were appended together (later PRs' metrics follow them)
+    end = [m["name"] for m in per_layer].index("long_value_roofline") + 1
+    assert len(entry) == 1 and entry[0] in per_layer[end - 5:end]
     assert entry[0].get("workloads") == spec.get("workloads")
     module = harness.load_module("readers", reader)
     if reader == "ledger_ratio":
@@ -351,3 +357,55 @@ def test_edge_metrics_read_counters_the_program_has(name, reader, counters):
         assert module.read(ctx, **spec["args"]) == 1500.0
         old = {"vars0": at({"parse": 10}), "vars1": at({"parse": 30})}
         assert module.read(old, **spec["args"]) is None
+
+
+def test_api_allowlist_1k_cell_is_appended_and_its_files_agree():
+    """The allowlist configuration and its one cell come last, its file
+    states what it assumes and guarantees (`edge-1k`'s four and one of its
+    own), and the generator's recorded path lengths are its own."""
+    manifest = _manifest()
+    cell = harness.load_cell(manifest, ROOT, "api-allowlist-1k.unique-sat")
+    assert manifest["workloads"][-1]["name"] == cell["name"]
+    assert manifest["configs"][-1]["name"] == cell["config"] == "api-allowlist-1k"
+    checks = [m for m in manifest["end_to_end"] if m["name"] == "checks_per_s"]
+    assert checks[0]["workloads"][-1] == cell["name"]
+    config = cell["config_file"]
+    assert (cell["traffic"], cell["chips"]) == ("unique-sat", 1)
+    assert config["params"] == {"n_configs": 1000}
+    assert config["requests"] == {"deny_share": 0.5}
+    assert config["reduced"] == [] and config["generator"] == "api_allowlist"
+    edge = harness.load_cell(manifest, ROOT, "edge-1k.unique-sat")["config_file"]
+    assert config["guarantees"][:4] == edge["guarantees"]
+    assert "off the device" in config["guarantees"][4]
+    measured = config["measured_of_the_generator"]
+    states = measured["path_regex_dfa_states"]
+    assert 96 < states["min"] <= states["p50"] <= states["max"] <= 1024
+    assert states["past_96_pct"] == 100.0 and states["past_256_pct"] > 0
+    generator = harness.load_module("corpora", config["generator"])
+    got = generator.measure(dict(config["params"], **config["requests"]), 4096, 0)
+    assert got["rows_with_a_path_past_64_pct"] == 0.0
+    assert got["path_bytes"]["max"] <= measured["path_bytes"]["max"] <= 64
+    reads = {m["name"] for m in cell["per_layer"]}
+    assert {"wide_dfa_roofline", "slow_configs", "dfa_cpu_leaves",
+            "dfa_states", "kernel_ms_per_launch"} <= reads
+    Reference(generator.manifests({"n_configs": 3}))  # the reference takes it
+
+
+@pytest.mark.parametrize("name, path", [
+    ("slow_configs", ["native_frontend", "snapshot", "slow_configs"]),
+    ("dfa_cpu_leaves", ["native_frontend", "snapshot", "kernel", "dfa_cpu_leaves"])])
+def test_lane_metrics_read_the_snapshot_and_are_read_in_every_cell(name, path):
+    """The two lane counters are data over `vars_path`, read in every cell
+    (no `workloads` list); a program without the counter (the parent) gives
+    them nothing to read, and the reader does not raise."""
+    spec = harness._load_json(os.path.join(BENCH, "metrics", name + ".json"))
+    assert (spec["reader"], spec["args"]["path"]) == ("vars_path", path)
+    (entry,) = [m for m in _manifest()["per_layer"] if m["name"] == name]
+    assert "workloads" not in entry and entry["layer"] == "lane selection and brownout"
+    module = harness.load_module("readers", "vars_path")
+    at = {}
+    for key in reversed(path):
+        at = {key: at or 3}
+    assert module.read({"vars1": at}, **spec["args"]) == 3
+    assert module.read({"vars1": {"native_frontend": {"snapshot": {}}}},
+                       **spec["args"]) is None

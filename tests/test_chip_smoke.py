@@ -92,7 +92,7 @@ def test_smoke_passes_end_to_end_on_cpu_when_told_to_expect_one(tmp_path):
     assert kernel == {"lane": "matmul",
                       "entry": "sharded_step", "leaf_cols_per_row": 32,
                       "dfa_rows_per_row": 4, "dfa_rows_total": 4,
-                      "dfa_states": kernel["dfa_states"]}
+                      "dfa_states": kernel["dfa_states"], "dfa_cpu_leaves": 0}
     assert kernel["dfa_states"] % 8 == 0 < kernel["dfa_states"]
     assert summary["wire_device_rows"] >= 0.9 * 384
     assert summary["warm_grid"] and summary["exit_code"] == 0

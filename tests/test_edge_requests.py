@@ -133,16 +133,22 @@ def test_the_width_rule_reads_a_rows_scan_alone(dfa_rows, states, width):
 
 
 def test_no_environment_variable_sets_the_width(monkeypatch):
-    import importlib
+    import importlib.util
 
     monkeypatch.setenv("AUTHORINO_TPU_DFA_VALUE_BYTES", "16")
-    again = importlib.reload(cc)
+    # a fresh copy of the module, executed under a name of its own: a reload
+    # in place would leave every other importer holding classes (the
+    # CompiledPolicy an isinstance() checks) that the module no longer has
+    name = cc.__name__ + "_fresh"
+    spec = importlib.util.spec_from_file_location(name, cc.__file__)
+    again = importlib.util.module_from_spec(spec)
+    sys.modules[name] = again   # dataclasses look their module up by name
     try:
+        spec.loader.exec_module(again)
         assert again.DFA_VALUE_BYTES == 64
         assert again.class_device_width(2, 24) == 256
     finally:
-        monkeypatch.delenv("AUTHORINO_TPU_DFA_VALUE_BYTES")
-        importlib.reload(cc)
+        del sys.modules[name]
 
 
 # --- the served path against the reference -------------------------------------

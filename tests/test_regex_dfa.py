@@ -49,8 +49,9 @@ def test_dfa_compiler_exact_vs_re():
 
 def test_unsupported_patterns_fall_back():
     # backreferences / lookaheads are not RE2 (the reference rejects them
-    # too); unicode classes and huge repeats exceed the device subset
-    assert compile_regex_dfa(r"x{100}") is None
+    # too); unicode classes and repeats past RE2's own 1000 exceed the
+    # device subset
+    assert compile_regex_dfa(r"x{1001}") is None
     assert compile_regex_dfa(r"(?=foo)") is None
 
 
