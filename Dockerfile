@@ -30,7 +30,7 @@ RUN SITE=$(python -c "import sysconfig; print(sysconfig.get_paths()['purelib'])"
     mkdir -p "$SITE/authorino_tpu/native/_build" && \
     g++ -O2 -std=c++17 -shared -fPIC -pthread \
         -I "$(python -c "import sysconfig; print(sysconfig.get_paths()['include'])")" \
-        "$SITE/native/pymod.cpp" -ldl \
+        "$SITE/native/pymod.cpp" \
         -o "$SITE/authorino_tpu/native/_build/_atpuenc.so" && \
     touch "$SITE/authorino_tpu/native/_build/_atpuenc.so" && \
     mkdir -p /staged && cp -a "$SITE" /staged/site-packages && \
@@ -38,14 +38,9 @@ RUN SITE=$(python -c "import sysconfig; print(sysconfig.get_paths()['purelib'])"
     cp /usr/local/bin/authorino-tpu /staged/authorino-tpu
 
 FROM ${BASE_IMAGE}
-# libnghttp2 backs the native gRPC frontend (native/frontend.cpp dlopens
-# it); absent, the server falls back to the Python grpc.aio listener — so
-# the install is best-effort to keep non-apt BASE_IMAGEs buildable
-RUN if command -v apt-get >/dev/null; then \
-        apt-get update && apt-get install -y --no-install-recommends libnghttp2-14 \
-        && rm -rf /var/lib/apt/lists/*; \
-    fi \
-    && groupadd -r authorino && useradd -r -g authorino -u 1001 authorino
+# the native gRPC frontend frames HTTP/2 itself (native/frontend.cpp): the
+# runtime image needs no HTTP/2 library
+RUN groupadd -r authorino && useradd -r -g authorino -u 1001 authorino
 COPY --from=build /staged /staged
 RUN python -c "import shutil, sysconfig; \
 shutil.copytree('/staged/site-packages', sysconfig.get_paths()['purelib'], dirs_exist_ok=True)" && \

@@ -87,7 +87,6 @@ def _build(digest: str) -> bool:
         "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
         "-I", sysconfig.get_paths()["include"],
         os.path.join(_NATIVE_DIR, "pymod.cpp"),
-        "-ldl",  # frontend.cpp dlopens libnghttp2 (absent → slow lanes only)
         "-o", _LIB_PATH + ".tmp",
     ]
     try:

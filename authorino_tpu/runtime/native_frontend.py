@@ -990,8 +990,8 @@ class NativeFrontend:
                           self.slow_cap, self._health_bytes(),
                           1 if self.bind_all else 0)
         if rc != 0:
-            raise RuntimeError(f"native frontend failed to start (rc={rc}; "
-                               "is libnghttp2 present?)")
+            raise RuntimeError(f"native frontend failed to start (rc={rc}: "
+                               "-2 no socket, -3 bind or listen refused)")
         self._running = True
         self.bound_port = mod.fe_port()
         self._threads = [

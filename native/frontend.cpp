@@ -4,13 +4,13 @@
 // the evaluation hot loop (ref: main.go:437-488, pkg/service/auth.go:239-310).
 // The TPU-era equivalent must keep ONE process owning the chip (TPUs are
 // process-exclusive) while the wire path runs at native speed: this file is
-// an epoll HTTP/2 gRPC server (framing/HPACK via the system libnghttp2,
-// loaded with dlopen so the encoder stays usable without it) that parses
-// CheckRequest protobufs, encodes pattern-only ("fast lane") requests
-// straight into the packed kernel operands, micro-batches them, and hands
-// each batch to the Python device-owner thread for ONE JAX dispatch.  The
-// per-request Python cost of the asyncio engine loop (~45µs) drops to zero;
-// Python is touched once per batch.
+// an epoll HTTP/2 gRPC server (its own framer and HPACK decoder, built for
+// unary calls: "HTTP/2" below) that parses CheckRequest protobufs where recv
+// put them, encodes pattern-only ("fast lane") requests straight into the
+// packed kernel operands, micro-batches them, and hands each batch to the
+// Python device-owner thread for ONE JAX dispatch.  The per-request Python
+// cost of the asyncio engine loop (~45µs) drops to zero; Python is touched
+// once per batch.
 //
 // Correctness contract:
 //   - fast lane only for configs whose full pipeline semantics reduce to
@@ -28,7 +28,6 @@
 // Compiled as part of the _atpuenc single translation unit (pymod.cpp).
 
 #include <arpa/inet.h>
-#include <dlfcn.h>
 #include <errno.h>
 #include <fcntl.h>
 #include <netinet/in.h>
@@ -55,131 +54,298 @@
 #include <vector>
 
 // ---------------------------------------------------------------------------
-// nghttp2 ABI subset (dlopen'd from libnghttp2.so.14; prototypes per the
-// public stable C API)
+// HTTP/2 (RFC 9113), the server side of gRPC unary calls, with a complete
+// HPACK decoder (RFC 7541).  Here: the wire's constants, the Huffman code and
+// the decoder; the connection's state machine, which hands each request to
+// the fast lane, is fe::'s ("The framer").  What it implements and what it
+// refuses: docs/architecture.md "The HTTP/2 framer".
 // ---------------------------------------------------------------------------
-namespace ng {
+namespace h2 {
 
-typedef struct nghttp2_session nghttp2_session;
-typedef struct nghttp2_session_callbacks nghttp2_session_callbacks;
-typedef struct nghttp2_option nghttp2_option;
-
-typedef struct {
-  size_t length;
-  int32_t stream_id;
-  uint8_t type;
-  uint8_t flags;
-  uint8_t reserved;
-} nghttp2_frame_hd;
-
-typedef struct {
-  uint8_t* name;
-  uint8_t* value;
-  size_t namelen;
-  size_t valuelen;
-  uint8_t flags;
-} nghttp2_nv;
-
-typedef union {
-  int fd;
-  void* ptr;
-} nghttp2_data_source;
-
-typedef ssize_t (*nghttp2_data_read_callback)(nghttp2_session*, int32_t,
-                                              uint8_t*, size_t, uint32_t*,
-                                              nghttp2_data_source*, void*);
-
-typedef struct {
-  nghttp2_data_source source;
-  nghttp2_data_read_callback read_callback;
-} nghttp2_data_provider;
-
-typedef struct {
-  int32_t settings_id;
-  uint32_t value;
-} nghttp2_settings_entry;
-
-enum {
-  NGHTTP2_FLAG_END_STREAM = 0x01,
-  NGHTTP2_DATA_FLAG_EOF = 0x01,
-  NGHTTP2_DATA_FLAG_NO_END_STREAM = 0x02,
-  NGHTTP2_SETTINGS_MAX_CONCURRENT_STREAMS = 0x03,
-  NGHTTP2_SETTINGS_INITIAL_WINDOW_SIZE = 0x04,
-  NGHTTP2_DATA = 0,
-  NGHTTP2_HEADERS = 1,
-  NGHTTP2_ERR_TEMPORAL_CALLBACK_FAILURE = -521,
+enum FrameType : uint8_t {
+  DATA = 0, HEADERS = 1, PRIORITY = 2, RST_STREAM = 3, SETTINGS = 4,
+  PUSH_PROMISE = 5, PING = 6, GOAWAY = 7, WINDOW_UPDATE = 8, CONTINUATION = 9,
+};
+enum : uint8_t {
+  END_STREAM = 0x1, ACK = 0x1, END_HEADERS = 0x4, PADDED = 0x8, PRIORITY_FLAG = 0x20,
+};
+enum ErrorCode : uint32_t {
+  PROTOCOL_ERROR = 1, FLOW_CONTROL_ERROR = 3, STREAM_CLOSED = 5,
+  FRAME_SIZE_ERROR = 6, REFUSED_STREAM = 7, COMPRESSION_ERROR = 9,
+  ENHANCE_YOUR_CALM = 11,
+};
+enum : uint16_t {
+  S_HEADER_TABLE_SIZE = 1, S_ENABLE_PUSH = 2, S_MAX_CONCURRENT_STREAMS = 3,
+  S_INITIAL_WINDOW_SIZE = 4, S_MAX_FRAME_SIZE = 5,
 };
 
-typedef ssize_t (*send_cb)(nghttp2_session*, const uint8_t*, size_t, int, void*);
-typedef int (*frame_recv_cb)(nghttp2_session*, const void*, void*);
-typedef int (*data_chunk_cb)(nghttp2_session*, uint8_t, int32_t, const uint8_t*, size_t, void*);
-typedef int (*header_cb)(nghttp2_session*, const void*, const uint8_t*, size_t,
-                         const uint8_t*, size_t, uint8_t, void*);
-typedef int (*stream_close_cb)(nghttp2_session*, int32_t, uint32_t, void*);
+static const char PREFACE[] = "PRI * HTTP/2.0\r\n\r\nSM\r\n\r\n";
+static const size_t PREFACE_LEN = sizeof(PREFACE) - 1;
+static const size_t FRAME_HEAD = 9;
+// what this server announces (the stream limit: ref main.go:68-69) ...
+static const uint32_t MAX_STREAMS = 10000;
+static const int64_t STREAM_WINDOW = 1 << 20;
+static const int64_t CONN_WINDOW = 1 << 30;
+// ... and leaves at the protocol's defaults: it takes frames of up to 16 KB
+// and keeps a decoder table of up to 4 KB
+static const uint32_t MAX_FRAME = 16384;
+static const uint32_t HPACK_TABLE = 4096;
+static const int64_t DEFAULT_WINDOW = 65535;
+static const int64_t MAX_WINDOW = 0x7fffffff;
+// a header block continued over CONTINUATION frames past this is refused
+static const size_t MAX_HEADER_BLOCK = 256 << 10;
+// a message past this is not gathered: its stream is answered
+// RESOURCE_EXHAUSTED when it ends
+static const size_t MAX_MESSAGE = 16 << 20;
+// a connection whose unsent answers pass this is not read until its peer
+// takes them; past MAX_ACKS control replies (PING and SETTINGS ACKs,
+// RST_STREAM) queued while the peer takes nothing, it is closed
+static const size_t OUT_CAP = 1 << 20;
+static const uint32_t MAX_ACKS = 1000;
 
-struct Api {
-  int (*callbacks_new)(nghttp2_session_callbacks**);
-  void (*callbacks_del)(nghttp2_session_callbacks*);
-  void (*set_on_frame_recv)(nghttp2_session_callbacks*, frame_recv_cb);
-  void (*set_on_data_chunk)(nghttp2_session_callbacks*, data_chunk_cb);
-  void (*set_on_header)(nghttp2_session_callbacks*, header_cb);
-  void (*set_on_stream_close)(nghttp2_session_callbacks*, stream_close_cb);
-  int (*session_server_new)(nghttp2_session**, const nghttp2_session_callbacks*, void*);
-  void (*session_del)(nghttp2_session*);
-  ssize_t (*mem_recv)(nghttp2_session*, const uint8_t*, size_t);
-  ssize_t (*mem_send)(nghttp2_session*, const uint8_t**);
-  int (*want_read)(nghttp2_session*);
-  int (*want_write)(nghttp2_session*);
-  int (*submit_response)(nghttp2_session*, int32_t, const nghttp2_nv*, size_t,
-                         const nghttp2_data_provider*);
-  int (*submit_trailer)(nghttp2_session*, int32_t, const nghttp2_nv*, size_t);
-  int (*submit_settings)(nghttp2_session*, uint8_t, const nghttp2_settings_entry*, size_t);
-  int (*submit_window_update)(nghttp2_session*, uint8_t, int32_t, int32_t);
-  bool ok = false;
-};
-
-static Api api;
-
-static bool load() {
-  if (api.ok) return true;
-  void* h = dlopen("libnghttp2.so.14", RTLD_NOW | RTLD_GLOBAL);
-  if (!h) h = dlopen("libnghttp2.so", RTLD_NOW | RTLD_GLOBAL);
-  if (!h) return false;
-  auto sym = [&](const char* n) { return dlsym(h, n); };
-  api.callbacks_new = (int (*)(nghttp2_session_callbacks**))sym("nghttp2_session_callbacks_new");
-  api.callbacks_del = (void (*)(nghttp2_session_callbacks*))sym("nghttp2_session_callbacks_del");
-  api.set_on_frame_recv = (void (*)(nghttp2_session_callbacks*, frame_recv_cb))sym(
-      "nghttp2_session_callbacks_set_on_frame_recv_callback");
-  api.set_on_data_chunk = (void (*)(nghttp2_session_callbacks*, data_chunk_cb))sym(
-      "nghttp2_session_callbacks_set_on_data_chunk_recv_callback");
-  api.set_on_header = (void (*)(nghttp2_session_callbacks*, header_cb))sym(
-      "nghttp2_session_callbacks_set_on_header_callback");
-  api.set_on_stream_close = (void (*)(nghttp2_session_callbacks*, stream_close_cb))sym(
-      "nghttp2_session_callbacks_set_on_stream_close_callback");
-  api.session_server_new = (int (*)(nghttp2_session**, const nghttp2_session_callbacks*, void*))sym(
-      "nghttp2_session_server_new");
-  api.session_del = (void (*)(nghttp2_session*))sym("nghttp2_session_del");
-  api.mem_recv = (ssize_t(*)(nghttp2_session*, const uint8_t*, size_t))sym("nghttp2_session_mem_recv");
-  api.mem_send = (ssize_t(*)(nghttp2_session*, const uint8_t**))sym("nghttp2_session_mem_send");
-  api.want_read = (int (*)(nghttp2_session*))sym("nghttp2_session_want_read");
-  api.want_write = (int (*)(nghttp2_session*))sym("nghttp2_session_want_write");
-  api.submit_response = (int (*)(nghttp2_session*, int32_t, const nghttp2_nv*, size_t,
-                                 const nghttp2_data_provider*))sym("nghttp2_submit_response");
-  api.submit_trailer = (int (*)(nghttp2_session*, int32_t, const nghttp2_nv*, size_t))sym(
-      "nghttp2_submit_trailer");
-  api.submit_settings = (int (*)(nghttp2_session*, uint8_t, const nghttp2_settings_entry*,
-                                 size_t))sym("nghttp2_submit_settings");
-  api.submit_window_update = (int (*)(nghttp2_session*, uint8_t, int32_t, int32_t))sym(
-      "nghttp2_submit_window_update");
-  api.ok = api.callbacks_new && api.callbacks_del && api.set_on_frame_recv &&
-           api.set_on_data_chunk && api.set_on_header && api.set_on_stream_close &&
-           api.session_server_new && api.session_del && api.mem_recv && api.mem_send &&
-           api.want_read && api.want_write && api.submit_response && api.submit_trailer &&
-           api.submit_settings && api.submit_window_update;
-  return api.ok;
+static inline uint32_t be24(const uint8_t* p) {
+  return ((uint32_t)p[0] << 16) | ((uint32_t)p[1] << 8) | p[2];
+}
+static inline uint32_t be32(const uint8_t* p) {
+  return ((uint32_t)p[0] << 24) | ((uint32_t)p[1] << 16) | ((uint32_t)p[2] << 8) | p[3];
+}
+static inline void put24(uint8_t* p, uint32_t v) {
+  p[0] = (uint8_t)(v >> 16); p[1] = (uint8_t)(v >> 8); p[2] = (uint8_t)v;
+}
+static inline void put32(uint8_t* p, uint32_t v) {
+  p[0] = (uint8_t)(v >> 24); p[1] = (uint8_t)(v >> 16);
+  p[2] = (uint8_t)(v >> 8); p[3] = (uint8_t)v;
 }
 
-}  // namespace ng
+// ---- HPACK: the static table (Appendix A) ---------------------------------
+struct StaticEnt { const char* name; uint8_t nl; const char* value; uint8_t vl; };
+#define H2_ENT(n, v) {n, sizeof(n) - 1, v, sizeof(v) - 1}
+static const StaticEnt STATIC_TABLE[61] = {
+    H2_ENT(":authority", ""), H2_ENT(":method", "GET"), H2_ENT(":method", "POST"),
+    H2_ENT(":path", "/"), H2_ENT(":path", "/index.html"), H2_ENT(":scheme", "http"),
+    H2_ENT(":scheme", "https"), H2_ENT(":status", "200"), H2_ENT(":status", "204"),
+    H2_ENT(":status", "206"), H2_ENT(":status", "304"), H2_ENT(":status", "400"),
+    H2_ENT(":status", "404"), H2_ENT(":status", "500"), H2_ENT("accept-charset", ""),
+    H2_ENT("accept-encoding", "gzip, deflate"), H2_ENT("accept-language", ""),
+    H2_ENT("accept-ranges", ""), H2_ENT("accept", ""),
+    H2_ENT("access-control-allow-origin", ""), H2_ENT("age", ""), H2_ENT("allow", ""),
+    H2_ENT("authorization", ""), H2_ENT("cache-control", ""),
+    H2_ENT("content-disposition", ""), H2_ENT("content-encoding", ""),
+    H2_ENT("content-language", ""), H2_ENT("content-length", ""),
+    H2_ENT("content-location", ""), H2_ENT("content-range", ""),
+    H2_ENT("content-type", ""), H2_ENT("cookie", ""), H2_ENT("date", ""),
+    H2_ENT("etag", ""), H2_ENT("expect", ""), H2_ENT("expires", ""), H2_ENT("from", ""),
+    H2_ENT("host", ""), H2_ENT("if-match", ""), H2_ENT("if-modified-since", ""),
+    H2_ENT("if-none-match", ""), H2_ENT("if-range", ""),
+    H2_ENT("if-unmodified-since", ""), H2_ENT("last-modified", ""), H2_ENT("link", ""),
+    H2_ENT("location", ""), H2_ENT("max-forwards", ""), H2_ENT("proxy-authenticate", ""),
+    H2_ENT("proxy-authorization", ""), H2_ENT("range", ""), H2_ENT("referer", ""),
+    H2_ENT("refresh", ""), H2_ENT("retry-after", ""), H2_ENT("server", ""),
+    H2_ENT("set-cookie", ""), H2_ENT("strict-transport-security", ""),
+    H2_ENT("transfer-encoding", ""), H2_ENT("user-agent", ""), H2_ENT("vary", ""),
+    H2_ENT("via", ""), H2_ENT("www-authenticate", ""),
+};
+#undef H2_ENT
+
+// ---- HPACK: the Huffman code (Appendix B) ---------------------------------
+// The code is canonical: the bit length of each of the 257 symbols (EOS
+// last) determines every code, so the table is the lengths alone.
+static const uint8_t HUFF_LEN[257] = {
+    13, 23, 28, 28, 28, 28, 28, 28, 28, 24, 30, 28, 28, 30, 28, 28,
+    28, 28, 28, 28, 28, 28, 30, 28, 28, 28, 28, 28, 28, 28, 28, 28,
+    6, 10, 10, 12, 13, 6, 8, 11, 10, 10, 8, 11, 8, 6, 6, 6,
+    5, 5, 5, 6, 6, 6, 6, 6, 6, 6, 7, 8, 15, 6, 12, 10,
+    13, 6, 7, 7, 7, 7, 7, 7, 7, 7, 7, 7, 7, 7, 7, 7,
+    7, 7, 7, 7, 7, 7, 7, 7, 8, 7, 8, 13, 19, 13, 14, 6,
+    15, 5, 6, 5, 6, 5, 6, 6, 6, 5, 7, 7, 6, 6, 6, 5,
+    6, 7, 6, 5, 5, 6, 7, 7, 7, 7, 7, 15, 11, 14, 13, 28,
+    20, 22, 20, 20, 22, 22, 22, 23, 22, 23, 23, 23, 23, 23, 24, 23,
+    24, 24, 22, 23, 24, 23, 23, 23, 23, 21, 22, 23, 22, 23, 23, 24,
+    22, 21, 20, 22, 22, 23, 23, 21, 23, 22, 22, 24, 21, 22, 23, 23,
+    21, 21, 22, 21, 23, 22, 23, 23, 20, 22, 22, 22, 23, 22, 22, 23,
+    26, 26, 20, 19, 22, 23, 22, 25, 26, 26, 26, 27, 27, 26, 24, 25,
+    19, 21, 26, 27, 27, 26, 27, 24, 21, 21, 26, 26, 28, 27, 27, 27,
+    20, 24, 20, 21, 22, 21, 21, 23, 22, 22, 25, 25, 24, 24, 26, 23,
+    26, 27, 26, 26, 27, 27, 27, 27, 27, 28, 27, 27, 27, 27, 27, 26,
+    30,
+};
+static const int HUFF_EOS = 256;
+
+// canonical decoding: the codes of length L are the L-bit values
+// [first[L], limit[L]), and the i-th of them is syms[offset[L] + i]
+struct Huffman {
+  uint32_t first[31] = {}, limit[31] = {};
+  uint16_t offset[31] = {}, syms[257] = {};
+  Huffman() {
+    uint16_t count[31] = {};
+    for (int s = 0; s < 257; ++s) count[HUFF_LEN[s]]++;
+    uint32_t code = 0;
+    uint16_t at = 0;
+    for (int L = 1; L <= 30; ++L) {
+      first[L] = code;
+      limit[L] = code + count[L];
+      offset[L] = at;
+      at = (uint16_t)(at + count[L]);
+      code = (code + count[L]) << 1;
+    }
+    uint16_t next[31];
+    memcpy(next, offset, sizeof next);
+    for (int s = 0; s < 257; ++s) syms[next[HUFF_LEN[s]]++] = (uint16_t)s;
+  }
+  // appends the decoded string to `out`; false on what 5.2 calls a decoding
+  // error: the EOS symbol, or padding longer than 7 bits or not all ones
+  bool decode(const uint8_t* p, size_t n, std::string& out) const {
+    uint64_t acc = 0;
+    int bits = 0;
+    size_t i = 0;
+    for (;;) {
+      while (bits <= 56 && i < n) {
+        acc = (acc << 8) | p[i++];
+        bits += 8;
+      }
+      int L = 5;
+      uint32_t code = 0;
+      for (; L <= 30 && L <= bits; ++L) {
+        code = (uint32_t)(acc >> (bits - L)) & ((1u << L) - 1);
+        if (code < limit[L]) break;
+      }
+      if (L > 30 || L > bits)  // no whole code is left: what is, is padding
+        return bits <= 7 && (acc & ((1ull << bits) - 1)) == (1ull << bits) - 1;
+      const uint16_t sym = syms[offset[L] + (code - first[L])];
+      if (sym == HUFF_EOS) return false;
+      out.push_back((char)sym);
+      bits -= L;
+    }
+  }
+};
+static const Huffman HUFF;
+
+// an integer of an N-bit prefix (5.1); false past the input or 2^31
+static bool hp_int(const uint8_t*& p, const uint8_t* end, int prefix, uint32_t& out) {
+  if (p >= end) return false;
+  const uint32_t mask = (1u << prefix) - 1;
+  uint64_t v = *p++ & mask;
+  if (v < mask) {
+    out = (uint32_t)v;
+    return true;
+  }
+  for (int m = 0; m <= 28; m += 7) {
+    if (p >= end) return false;
+    const uint8_t b = *p++;
+    v += (uint64_t)(b & 0x7f) << m;
+    if (!(b & 0x80)) {
+      if (v > (uint64_t)MAX_WINDOW) return false;
+      out = (uint32_t)v;
+      return true;
+    }
+  }
+  return false;
+}
+
+// a string literal (5.2): raw bytes are handed out where they lie, Huffman-
+// coded ones decoded into `scratch`
+static bool hp_str(const uint8_t*& p, const uint8_t* end, std::string& scratch,
+                   const char*& s, size_t& n) {
+  if (p >= end) return false;
+  const bool huff = (*p & 0x80) != 0;
+  uint32_t len;
+  if (!hp_int(p, end, 7, len) || (size_t)(end - p) < len) return false;
+  if (huff) {
+    scratch.clear();
+    if (!HUFF.decode(p, len, scratch)) return false;
+    s = scratch.data();
+    n = scratch.size();
+  } else {
+    s = (const char*)p;
+    n = len;
+  }
+  p += len;
+  return true;
+}
+
+// one connection's decoder: the dynamic table (2.3.2, newest first) and
+// the scratch the Huffman-coded strings of one field are decoded into
+struct Hpack {
+  std::deque<std::pair<std::string, std::string>> dyn;
+  size_t size = 0;            // 4.1: the entries' bytes, 32 more each
+  size_t max = HPACK_TABLE;   // the table's size as the last update set it
+  std::string sname, svalue;
+
+  void evict_to(size_t cap) {
+    while (size > cap && !dyn.empty()) {
+      size -= dyn.back().first.size() + dyn.back().second.size() + 32;
+      dyn.pop_back();
+    }
+  }
+  // 4.4: the entry is copied first, since its name may be an entry the
+  // insert evicts; one larger than the table empties it
+  void insert(const char* n, size_t nl, const char* v, size_t vl) {
+    const size_t es = nl + vl + 32;
+    if (es > max) {
+      evict_to(0);
+      return;
+    }
+    std::pair<std::string, std::string> e(std::string(n, nl), std::string(v, vl));
+    evict_to(max - es);
+    dyn.push_front(std::move(e));
+    size += es;
+  }
+  bool entry(uint32_t idx, const char*& n, size_t& nl, const char*& v, size_t& vl) const {
+    if (idx == 0) return false;
+    if (idx <= 61) {
+      const StaticEnt& e = STATIC_TABLE[idx - 1];
+      n = e.name; nl = e.nl; v = e.value; vl = e.vl;
+      return true;
+    }
+    if (idx - 62 >= dyn.size()) return false;
+    const auto& e = dyn[idx - 62];
+    n = e.first.data(); nl = e.first.size(); v = e.second.data(); vl = e.second.size();
+    return true;
+  }
+  // decodes one whole header block, calling field(name, nl, value, vl) for
+  // each header field in order; false on a decoding error (a connection
+  // error COMPRESSION_ERROR).  A field is handed on before its insert into
+  // the table, which may evict what the name points at.
+  template <class F>
+  bool decode(const uint8_t* p, size_t n, F&& field) {
+    const uint8_t* end = p + n;
+    bool leading = true;  // 4.2: size updates come first in a block
+    while (p < end) {
+      const uint8_t b = *p;
+      const char *nm, *v;
+      size_t nl, vl;
+      if (b & 0x80) {  // 6.1 indexed
+        uint32_t idx;
+        if (!hp_int(p, end, 7, idx) || !entry(idx, nm, nl, v, vl)) return false;
+        field(nm, nl, v, vl);
+      } else if ((b & 0xe0) == 0x20) {  // 6.3 dynamic table size update
+        uint32_t sz;
+        if (!leading || !hp_int(p, end, 5, sz) || sz > HPACK_TABLE) return false;
+        max = sz;
+        evict_to(max);
+        continue;
+      } else {  // 6.2 literal: with incremental indexing, without, never
+        const bool index = (b & 0xc0) == 0x40;
+        uint32_t ni;
+        if (!hp_int(p, end, index ? 6 : 4, ni)) return false;
+        if (ni) {
+          const char* unused;
+          size_t unused_n;
+          if (!entry(ni, nm, nl, unused, unused_n)) return false;
+        } else if (!hp_str(p, end, sname, nm, nl)) {
+          return false;
+        }
+        if (!hp_str(p, end, svalue, v, vl)) return false;
+        field(nm, nl, v, vl);
+        if (index) insert(nm, nl, v, vl);
+      }
+      leading = false;
+    }
+    return true;
+  }
+};
+
+}  // namespace h2
 
 namespace fe {
 
@@ -507,14 +673,14 @@ static inline int stage_bucket(int64_t ns) {
 // (Python's time.monotonic_ns()), always on.  A stamp charges what lies
 // since the last stamp to the phase it names, so the thread's phases add up
 // to its wall time and a phase holds its self time: `read` is a connection
-// event's recv + mem_recv less the `parse`, `encode`, `ovf_scan` and `cut`
-// its callbacks stamped on the way.  The names are written here and nowhere
+// event's recv + frame walk less the `parse`, `encode`, `ovf_scan` and `cut`
+// its requests stamped on the way.  The names are written here and nowhere
 // else: fe_loop_clock() carries them out, the thread's phases under
 // `phases` and the rows that are not phases of it under `rows`.
 // ---------------------------------------------------------------------------
 enum ClockRowId {
   PH_IDLE = 0,   // inside epoll_wait; count: wakes
-  PH_READ,       // recv + nghttp2 framing, HPACK, callbacks' bookkeeping; count: recv calls
+  PH_READ,       // recv + the framer's walk, HPACK, stream bookkeeping; count: recv calls
   PH_PARSE,      // process_check entry -> the chosen FastConfig; count: Check requests
   PH_ENCODE,     // ensure_fill + zero_row + encode_fast; count: rows encoded
   PH_OVF_SCAN,   // scan_overflow of a value past its config's width; count: such rows
@@ -538,12 +704,24 @@ enum ClockRowId {
   // and the headers parse_check_request found in it (over `parse`'s count)
   ROW_REQ_BYTES,
   ROW_REQ_HEADERS,
+  // the two syscalls, each inside its phase: the time and calls in `recv`
+  // (a part of `read`) and in `send` (a part of `write`); what their phase
+  // spends besides is the framer's
+  ROW_RECV,
+  ROW_SEND,
+  // a count alone: the Check requests parsed where recv put them (the
+  // message whole in one DATA frame, not gathered), over `parse`'s count
+  ROW_MSG_INPLACE,
+  // a count alone: the cuts the batch window's timer made, the slot not
+  // full, over `cut`'s count (every cut)
+  ROW_CUT_TIMER,
   N_CLOCK_ROWS
 };
 static const char* const CLOCK_ROW_NAMES[N_CLOCK_ROWS] = {
     "idle", "read", "parse", "encode", "ovf_scan", "cut", "respond", "write",
     "other", "turn", "req_wait", "req_exec", "req_respond", "ovf_dfas",
-    "ovf_loads", "req_bytes", "req_headers"};
+    "ovf_loads", "req_bytes", "req_headers", "recv", "send", "msg_inplace",
+    "cut_timer"};
 
 // two branches off the thread's path that say who holds it back, each with
 // its operator's use in docs/observability.md: the peer does not read
@@ -734,28 +912,108 @@ struct Snapshot {
 };
 
 // ---------------------------------------------------------------------------
-// Connections / streams
+// The framer: connections and their streams (the read and write sides below)
 // ---------------------------------------------------------------------------
 enum StreamKind { SK_UNSET = 0, SK_CHECK, SK_HEALTH, SK_OTHER };
 
+// one client stream, from its HEADERS until its answer is written
 struct StreamSt {
-  int kind = SK_UNSET;
-  bool compressed = false;
-  std::string body;
-  // response state
-  std::string resp;     // full gRPC message payload (5B prefix + pb)
-  size_t resp_off = 0;
-  bool responded = false;
+  int32_t sid = 0;             // 0: a free slot of the table
+  uint8_t kind = SK_UNSET;
+  bool compressed = false;     // grpc-encoding other than identity
+  bool half_closed = false;    // END_STREAM received: the request is whole
+  bool answered = false;       // an answer is under way, its DATA held
+  bool too_big = false;        // its message passed MAX_MESSAGE: nothing more is kept
+  int64_t recv_used = 0;       // DATA taken since our last WINDOW_UPDATE for it
+  int64_t send_window = 0;     // what the peer lets us send on it
+  std::string body;            // a message gathered over DATA frames
+  std::string held;            // answer DATA the windows would not take yet
 };
+
+// a connection's streams: a flat table, open-addressed by stream id (client
+// ids are odd and rise, so id/2 spreads them), half full at most.  No node
+// and no allocation a stream once it has grown to the connection's
+// concurrency.  A pointer into it lasts until the next insert or erase.
+struct StreamTable {
+  std::vector<StreamSt> slots = std::vector<StreamSt>(64);
+  size_t n = 0;
+
+  size_t home(int32_t sid) const { return ((uint32_t)sid >> 1) & (slots.size() - 1); }
+  StreamSt* find(int32_t sid) {
+    const size_t mask = slots.size() - 1;
+    for (size_t i = home(sid);; i = (i + 1) & mask) {
+      if (slots[i].sid == sid) return &slots[i];
+      if (slots[i].sid == 0) return nullptr;
+    }
+  }
+  StreamSt* insert(int32_t sid) {
+    if (2 * (n + 1) > slots.size()) {
+      std::vector<StreamSt> old(slots.size() * 2);
+      old.swap(slots);
+      for (StreamSt& st : old)
+        if (st.sid) *place(st.sid) = std::move(st);
+    }
+    StreamSt* st = place(sid);
+    st->sid = sid;
+    n++;
+    return st;
+  }
+  // backward-shift deletion: what follows the hole in its probe run moves
+  // up where its home allows, so no tombstone is left
+  void erase(StreamSt* st) {
+    const size_t mask = slots.size() - 1;
+    size_t hole = (size_t)(st - slots.data());
+    slots[hole] = StreamSt();
+    n--;
+    for (size_t j = (hole + 1) & mask; slots[j].sid != 0; j = (j + 1) & mask) {
+      if (((j - home(slots[j].sid)) & mask) >= ((j - hole) & mask)) {
+        slots[hole] = std::move(slots[j]);
+        slots[j] = StreamSt();
+        hole = j;
+      }
+    }
+  }
+
+ private:
+  StreamSt* place(int32_t sid) {
+    const size_t mask = slots.size() - 1;
+    size_t i = home(sid);
+    while (slots[i].sid != 0) i = (i + 1) & mask;
+    return &slots[i];
+  }
+};
+
+// the read buffer: one recv of 64 KB at least lands past the tail of a
+// partial frame (at most a frame head and 16 KB) carried to its front
+static const size_t RBUF_BYTES = 65536 + h2::FRAME_HEAD + h2::MAX_FRAME;
 
 struct Conn {
   int fd = -1;
   uint32_t id = 0;
-  ng::nghttp2_session* sess = nullptr;
-  std::unordered_map<int32_t, StreamSt> streams;
+  // read side: the buffer recv fills and the framer walks in place
+  std::unique_ptr<uint8_t[]> rbuf{new uint8_t[RBUF_BYTES]};
+  size_t rlen = 0;
+  bool preface = false;        // the client's preface seen
+  bool settings = false;       // its first frame, a SETTINGS, seen
+  int32_t last_sid = 0;        // the highest stream the client opened
+  h2::Hpack hpack;
+  StreamTable streams;
+  // a header block continued over CONTINUATION frames
+  int32_t cont_sid = 0;
+  uint8_t cont_flags = 0;
+  std::string cont_block;
+  int64_t recv_window = h2::CONN_WINDOW;  // what the peer may still send
+  // write side: the peer's settings and its window for our DATA
+  int64_t send_window = h2::DEFAULT_WINDOW;
+  int64_t peer_window = h2::DEFAULT_WINDOW;  // its SETTINGS_INITIAL_WINDOW_SIZE
+  uint32_t peer_max_frame = h2::MAX_FRAME;
+  uint32_t enc_table = h2::HPACK_TABLE;  // our encoder's table size
+  bool table_update = false;   // our next header block opens with a size update
+  std::vector<int32_t> held;   // streams whose answer waits on a window
   std::string outbuf;
-  bool want_eout = false;
-  bool dead = false;
+  size_t out_off = 0;          // outbuf's first byte not yet sent
+  uint32_t acks = 0;           // control replies queued since a send last moved bytes
+  uint32_t events = EPOLLIN;   // what epoll watches: EPOLLIN unless past OUT_CAP
 };
 
 struct Done {
@@ -866,73 +1124,165 @@ struct Server {
 
 static Server* g_srv = nullptr;
 
-// ---- response submission (epoll thread only) ------------------------------
+// ---- the framer's write side (epoll thread only) --------------------------
+// Every header block this server sends is constant bytes, built once: the
+// encoder never uses its dynamic table.  An answer is HEADERS (`:status 200`
+// as static index 8, `content-type: application/grpc` as a literal without
+// indexing), DATA (the 5-byte gRPC prefix and the message) and trailers
+// (`grpc-status: 0`, END_STREAM); a trailers-only error is one HEADERS.
 
-static ssize_t resp_read_cb(ng::nghttp2_session*, int32_t stream_id, uint8_t* buf,
-                            size_t length, uint32_t* data_flags,
-                            ng::nghttp2_data_source* source, void*) {
-  Conn* c = (Conn*)source->ptr;
-  auto it = c->streams.find(stream_id);
-  if (it == c->streams.end()) return ng::NGHTTP2_ERR_TEMPORAL_CALLBACK_FAILURE;
-  StreamSt& st = it->second;
-  size_t left = st.resp.size() - st.resp_off;
-  size_t n = left < length ? left : length;
-  memcpy(buf, st.resp.data() + st.resp_off, n);
-  st.resp_off += n;
-  if (st.resp_off == st.resp.size()) {
-    *data_flags = ng::NGHTTP2_DATA_FLAG_EOF | ng::NGHTTP2_DATA_FLAG_NO_END_STREAM;
-    static const char kStatus[] = "grpc-status";
-    static const char kZero[] = "0";
-    ng::nghttp2_nv trailer = {(uint8_t*)kStatus, (uint8_t*)kZero,
-                              sizeof(kStatus) - 1, sizeof(kZero) - 1, 0};
-    ng::api.submit_trailer(c->sess, stream_id, &trailer, 1);
+static const uint8_t RESP_BLOCK[] = {
+    0x88, 0x0f, 0x10, 0x10, 'a', 'p', 'p', 'l', 'i', 'c', 'a', 't',
+    'i', 'o', 'n', '/', 'g', 'r', 'p', 'c'};
+static const uint8_t STATUS_NAME[] = {
+    0x00, 0x0b, 'g', 'r', 'p', 'c', '-', 's', 't', 'a', 't', 'u', 's'};
+
+// an answer's bytes but the message, stream ids and DATA length zero
+struct AnswerBytes {
+  // HEADERS frame, DATA frame head, gRPC prefix
+  uint8_t head[2 * h2::FRAME_HEAD + sizeof RESP_BLOCK + 5] = {};
+  // the trailers' HEADERS frame
+  uint8_t tail[h2::FRAME_HEAD + sizeof STATUS_NAME + 2] = {};
+  static constexpr size_t DATA_AT = h2::FRAME_HEAD + sizeof RESP_BLOCK;
+  AnswerBytes() {
+    h2::put24(head, sizeof RESP_BLOCK);
+    head[3] = h2::HEADERS;
+    head[4] = h2::END_HEADERS;
+    memcpy(head + h2::FRAME_HEAD, RESP_BLOCK, sizeof RESP_BLOCK);
+    head[DATA_AT + 3] = h2::DATA;
+    h2::put24(tail, sizeof STATUS_NAME + 2);
+    tail[3] = h2::HEADERS;
+    tail[4] = h2::END_HEADERS | h2::END_STREAM;
+    memcpy(tail + h2::FRAME_HEAD, STATUS_NAME, sizeof STATUS_NAME);
+    tail[h2::FRAME_HEAD + sizeof STATUS_NAME] = 1;
+    tail[h2::FRAME_HEAD + sizeof STATUS_NAME + 1] = '0';
   }
-  return (ssize_t)n;
+};
+static const AnswerBytes ANSWER;
+
+static inline uint8_t* out_frame(Conn* c, uint32_t len, uint8_t type, uint8_t flags,
+                                 int32_t sid) {
+  const size_t at = c->outbuf.size();
+  c->outbuf.resize(at + h2::FRAME_HEAD + len);
+  uint8_t* f = (uint8_t*)&c->outbuf[at];
+  h2::put24(f, len);
+  f[3] = type;
+  f[4] = flags;
+  h2::put32(f + 5, (uint32_t)sid);
+  return f + h2::FRAME_HEAD;
 }
 
-static void nv_set(ng::nghttp2_nv& nv, const char* n, size_t nl, const char* v, size_t vl) {
-  nv.name = (uint8_t*)n; nv.namelen = nl;
-  nv.value = (uint8_t*)v; nv.valuelen = vl;
-  nv.flags = 0;
+// a header block of ours; the first after the peer shrank its table opens
+// with an update of ours to 0 (RFC 7541 4.2)
+static void out_headers(Conn* c, int32_t sid, uint8_t flags, const uint8_t* block,
+                        size_t n) {
+  const size_t upd = c->table_update ? 1 : 0;
+  uint8_t* p = out_frame(c, (uint32_t)(n + upd), h2::HEADERS, flags, sid);
+  if (upd) *p++ = 0x20;
+  memcpy(p, block, n);
+  c->table_update = false;
 }
 
-// msg: CheckResponse payload; builds 5-byte gRPC prefix + body, then
-// HEADERS(:status 200) + DATA + trailers(grpc-status 0)
-static void submit_grpc_response(Conn* c, int32_t stream_id, const std::string& msg) {
-  auto it = c->streams.find(stream_id);
-  if (it == c->streams.end()) return;
-  StreamSt& st = it->second;
-  if (st.responded) return;
-  st.responded = true;
-  st.resp.clear();
-  st.resp.reserve(5 + msg.size());
-  uint32_t len = (uint32_t)msg.size();
-  char pfx[5] = {0, (char)(len >> 24), (char)(len >> 16), (char)(len >> 8), (char)len};
-  st.resp.append(pfx, 5);
-  st.resp.append(msg);
-  st.resp_off = 0;
-  ng::nghttp2_nv nv[2];
-  nv_set(nv[0], ":status", 7, "200", 3);
-  nv_set(nv[1], "content-type", 12, "application/grpc", 16);
-  ng::nghttp2_data_provider dp;
-  dp.source.ptr = c;
-  dp.read_callback = resp_read_cb;
-  ng::api.submit_response(c->sess, stream_id, nv, 2, &dp);
+static void out_rst(Conn* c, int32_t sid, uint32_t code) {
+  h2::put32(out_frame(c, 4, h2::RST_STREAM, 0, sid), code);
+  c->acks++;
+}
+
+static void out_window_update(Conn* c, int32_t sid, int64_t inc) {
+  h2::put32(out_frame(c, 4, h2::WINDOW_UPDATE, 0, sid), (uint32_t)inc);
+}
+
+// a connection error: GOAWAY is queued, the caller sends it and closes
+static bool out_goaway(Conn* c, uint32_t code) {
+  uint8_t* p = out_frame(c, 8, h2::GOAWAY, 0, 0);
+  h2::put32(p, (uint32_t)c->last_sid);
+  h2::put32(p + 4, code);
+  return false;
+}
+
+// a stream error: RST_STREAM, and the stream is gone
+static void reset_stream(Conn* c, StreamSt* st, uint32_t code) {
+  out_rst(c, st->sid, code);
+  c->streams.erase(st);
+}
+
+// the held DATA of an answer as far as the windows and the peer's frame
+// size let it go, then its trailers; true once the answer is whole (the
+// stream is gone then)
+static bool flush_stream(Conn* c, StreamSt* st) {
+  while (!st->held.empty()) {
+    const int64_t can = std::min({(int64_t)st->held.size(), c->send_window,
+                                  st->send_window, (int64_t)c->peer_max_frame});
+    if (can <= 0) return false;
+    memcpy(out_frame(c, (uint32_t)can, h2::DATA, 0, st->sid), st->held.data(), (size_t)can);
+    st->held.erase(0, (size_t)can);
+    c->send_window -= can;
+    st->send_window -= can;
+  }
+  out_headers(c, st->sid, h2::END_HEADERS | h2::END_STREAM,
+              ANSWER.tail + h2::FRAME_HEAD, sizeof ANSWER.tail - h2::FRAME_HEAD);
+  c->streams.erase(st);
+  return true;
+}
+
+static void flush_held(Conn* c) {
+  size_t kept = 0;
+  for (int32_t sid : c->held) {
+    StreamSt* st = c->streams.find(sid);  // gone: the peer reset it
+    if (st && !flush_stream(c, st)) c->held[kept++] = sid;
+  }
+  c->held.resize(kept);
+}
+
+// msg: CheckResponse payload.  The answer goes out whole when the windows
+// take it and no size update is owed: the constant bytes around the message,
+// the stream id patched into the three frame heads.  Else HEADERS now and
+// the DATA held until a WINDOW_UPDATE lets it go.  An answer for a stream the
+// peer reset (Envoy does at its ext_authz timeout) is dropped here.
+static void submit_grpc_response(Conn* c, int32_t sid, const std::string& msg) {
+  StreamSt* st = c->streams.find(sid);
+  if (st == nullptr || st->answered) return;
+  const size_t n = msg.size();
+  const int64_t dlen = (int64_t)(5 + n);
+  if (!c->table_update && dlen <= c->send_window && dlen <= st->send_window &&
+      dlen <= (int64_t)c->peer_max_frame) {
+    c->send_window -= dlen;
+    const size_t at = c->outbuf.size();
+    c->outbuf.append((const char*)ANSWER.head, sizeof ANSWER.head);
+    c->outbuf.append(msg);
+    c->outbuf.append((const char*)ANSWER.tail, sizeof ANSWER.tail);
+    uint8_t* o = (uint8_t*)&c->outbuf[at];
+    uint8_t* d = o + AnswerBytes::DATA_AT;
+    h2::put32(o + 5, (uint32_t)sid);
+    h2::put24(d, (uint32_t)dlen);
+    h2::put32(d + 5, (uint32_t)sid);
+    h2::put32(d + h2::FRAME_HEAD + 1, (uint32_t)n);
+    h2::put32(d + h2::FRAME_HEAD + 5 + n + 5, (uint32_t)sid);
+    c->streams.erase(st);
+    return;
+  }
+  out_headers(c, sid, h2::END_HEADERS, RESP_BLOCK, sizeof RESP_BLOCK);
+  st->answered = true;
+  uint8_t pfx[5] = {0};
+  h2::put32(pfx + 1, (uint32_t)n);
+  st->held.assign((const char*)pfx, 5);
+  st->held.append(msg);
+  if (!flush_stream(c, st)) c->held.push_back(sid);
 }
 
 // trailers-only gRPC error (no message body)
-static void submit_grpc_error(Conn* c, int32_t stream_id, int code) {
-  auto it = c->streams.find(stream_id);
-  if (it == c->streams.end()) return;
-  if (it->second.responded) return;
-  it->second.responded = true;
-  char buf[8];
-  int n = snprintf(buf, sizeof buf, "%d", code);
-  ng::nghttp2_nv nv[3];
-  nv_set(nv[0], ":status", 7, "200", 3);
-  nv_set(nv[1], "content-type", 12, "application/grpc", 16);
-  nv_set(nv[2], "grpc-status", 11, buf, (size_t)n);
-  ng::api.submit_response(c->sess, stream_id, nv, 3, nullptr);
+static void submit_grpc_error(Conn* c, int32_t sid, int code) {
+  StreamSt* st = c->streams.find(sid);
+  if (st == nullptr || st->answered) return;
+  uint8_t block[sizeof RESP_BLOCK + sizeof STATUS_NAME + 12];
+  memcpy(block, RESP_BLOCK, sizeof RESP_BLOCK);
+  memcpy(block + sizeof RESP_BLOCK, STATUS_NAME, sizeof STATUS_NAME);
+  uint8_t* v = block + sizeof RESP_BLOCK + sizeof STATUS_NAME;
+  const int vn = snprintf((char*)v + 1, 11, "%d", code);
+  v[0] = (uint8_t)vn;
+  out_headers(c, sid, h2::END_HEADERS | h2::END_STREAM, block,
+              sizeof RESP_BLOCK + sizeof STATUS_NAME + 1 + (size_t)vn);
+  c->streams.erase(st);
 }
 
 // ---- fast-lane encode -----------------------------------------------------
@@ -1307,6 +1657,7 @@ static void flush_batch(Server* S, bool from_timer = false) {
     }
     S->batch_cv.notify_all();
     S->clk.rows[PH_CUT].bump();
+    if (from_timer) S->clk.rows[ROW_CUT_TIMER].bump();
   }
 }
 
@@ -1393,7 +1744,10 @@ static inline void record_direct_dur(Snapshot* snap, int32_t fc_idx, int64_t t0)
   d[N_DUR_BUCKETS].fetch_add((uint64_t)dur, std::memory_order_relaxed);
 }
 
-static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
+// body: the gRPC DATA of the request (5-byte prefix and message), where recv
+// put it when `inplace`, else gathered in the stream
+static void process_check(Server* S, Conn* c, int32_t stream_id, const char* body,
+                          size_t body_n, bool inplace) {
   LoopClock& clk = S->clk;
   // the request's arrival, and the loop clock's stamp: what led here was
   // framing, `read`'s.  From here two more stamps a request: where `parse`
@@ -1413,12 +1767,12 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
     clk.rows[PH_OTHER].bump();
     at_exit.ph = PH_OTHER;
   };
-  if (st.body.size() < 5) { submit_grpc_error(c, stream_id, 13); return; }
-  if (st.body[0] != 0) { submit_grpc_error(c, stream_id, 12); return; }  // compressed
-  uint32_t mlen = ((uint8_t)st.body[1] << 24) | ((uint8_t)st.body[2] << 16) |
-                  ((uint8_t)st.body[3] << 8) | (uint8_t)st.body[4];
-  if (st.body.size() < 5 + (size_t)mlen) { submit_grpc_error(c, stream_id, 13); return; }
-  const char* msg = st.body.data() + 5;
+  if (inplace) clk.rows[ROW_MSG_INPLACE].bump();
+  if (body_n < 5) { submit_grpc_error(c, stream_id, 13); return; }
+  if (body[0] != 0) { submit_grpc_error(c, stream_id, 12); return; }  // compressed
+  const uint32_t mlen = h2::be32((const uint8_t*)body + 1);
+  if (body_n < 5 + (size_t)mlen) { submit_grpc_error(c, stream_id, 13); return; }
+  const char* msg = body + 5;
   clk.rows[ROW_REQ_BYTES].bump(mlen);
 
   std::shared_ptr<Snapshot> snap;
@@ -1633,17 +1987,16 @@ static void process_check(Server* S, Conn* c, int32_t stream_id, StreamSt& st) {
   }
 }
 
-static void process_request(Server* S, Conn* c, int32_t stream_id) {
-  auto it = c->streams.find(stream_id);
-  if (it == c->streams.end()) return;
-  StreamSt& st = it->second;
-  if (st.kind == SK_CHECK && !st.compressed) {
-    process_check(S, c, stream_id, st);
+// a whole request: a Check to the fast lane, the rest answered here (the
+// loop clock's `other`).  The stream may be gone on return.
+static void process_request(Server* S, Conn* c, int32_t stream_id, int kind,
+                            bool compressed, const char* body, size_t n, bool inplace) {
+  if (kind == SK_CHECK && !compressed) {
+    process_check(S, c, stream_id, body, n, inplace);
     return;
   }
-  // a direct answer: the loop clock's `other`
   S->clk.stamp(PH_READ);
-  if (st.kind == SK_HEALTH) {
+  if (kind == SK_HEALTH) {
     std::shared_ptr<Snapshot> snap;
     {
       std::lock_guard<std::mutex> lk(S->mu);
@@ -1658,56 +2011,316 @@ static void process_request(Server* S, Conn* c, int32_t stream_id) {
   S->clk.stamp(PH_OTHER);
 }
 
-// ---- nghttp2 callbacks ----------------------------------------------------
+// ---- the framer's read side (epoll thread only) ---------------------------
+// The frames are walked where recv put them.  Of a request's headers only
+// `:path` and `grpc-encoding` are read (the rest are decoded, to keep the
+// HPACK table in step, and dropped); its message, whole in one DATA frame
+// with END_STREAM, is handed on in place, or gathered once in the stream
+// when it spans frames.  A connection error queues GOAWAY and returns false.
 
-static int on_header(ng::nghttp2_session*, const void* frame, const uint8_t* name,
-                     size_t namelen, const uint8_t* value, size_t valuelen, uint8_t,
-                     void* user_data) {
-  Conn* c = (Conn*)user_data;
-  const ng::nghttp2_frame_hd* hd = (const ng::nghttp2_frame_hd*)frame;
-  if (hd->type != ng::NGHTTP2_HEADERS) return 0;
-  StreamSt& st = c->streams[hd->stream_id];
-  if (namelen == 5 && memcmp(name, ":path", 5) == 0) {
-    static const char kCheck[] = "/envoy.service.auth.v3.Authorization/Check";
-    static const char kHealth[] = "/grpc.health.v1.Health/Check";
-    if (valuelen == sizeof(kCheck) - 1 && memcmp(value, kCheck, valuelen) == 0)
-      st.kind = SK_CHECK;
-    else if (valuelen == sizeof(kHealth) - 1 && memcmp(value, kHealth, valuelen) == 0)
-      st.kind = SK_HEALTH;
-    else
-      st.kind = SK_OTHER;
-  } else if (namelen == 13 && memcmp(name, "grpc-encoding", 13) == 0) {
-    if (!(valuelen == 8 && memcmp(value, "identity", 8) == 0)) st.compressed = true;
-  }
-  return 0;
+static int path_kind(const char* v, size_t n) {
+  static const char kCheck[] = "/envoy.service.auth.v3.Authorization/Check";
+  static const char kHealth[] = "/grpc.health.v1.Health/Check";
+  if (n == sizeof(kCheck) - 1 && memcmp(v, kCheck, n) == 0) return SK_CHECK;
+  if (n == sizeof(kHealth) - 1 && memcmp(v, kHealth, n) == 0) return SK_HEALTH;
+  return SK_OTHER;
 }
 
-static int on_data_chunk(ng::nghttp2_session*, uint8_t, int32_t stream_id,
-                         const uint8_t* data, size_t len, void* user_data) {
-  Conn* c = (Conn*)user_data;
-  auto it = c->streams.find(stream_id);
-  if (it != c->streams.end()) {
-    if (it->second.body.size() + len > (size_t)16 << 20) return 0;  // cap 16MB
-    it->second.body.append((const char*)data, len);
+// the gathered message, out of the stream (which its answer may erase)
+static void end_gathered(Server* S, Conn* c, StreamSt* st) {
+  if (st->too_big) {
+    submit_grpc_error(c, st->sid, 8);  // RESOURCE_EXHAUSTED
+    return;
   }
-  return 0;
+  std::string body;
+  body.swap(st->body);
+  process_request(S, c, st->sid, st->kind, st->compressed, body.data(), body.size(), false);
 }
 
-static int on_frame_recv(ng::nghttp2_session*, const void* frame, void* user_data) {
-  Conn* c = (Conn*)user_data;
-  const ng::nghttp2_frame_hd* hd = (const ng::nghttp2_frame_hd*)frame;
-  if ((hd->type == ng::NGHTTP2_DATA || hd->type == ng::NGHTTP2_HEADERS) &&
-      (hd->flags & ng::NGHTTP2_FLAG_END_STREAM)) {
-    process_request(g_srv, c, hd->stream_id);
+static bool on_header_block(Server* S, Conn* c, int32_t sid, uint8_t flags,
+                            const uint8_t* block, size_t n) {
+  int kind = SK_UNSET;
+  bool compressed = false;
+  const bool decoded = c->hpack.decode(
+      block, n, [&](const char* nm, size_t nl, const char* v, size_t vl) {
+        if (nl == 5 && memcmp(nm, ":path", 5) == 0)
+          kind = path_kind(v, vl);
+        else if (nl == 13 && memcmp(nm, "grpc-encoding", 13) == 0)
+          compressed = !(vl == 8 && memcmp(v, "identity", 8) == 0);
+      });
+  if (!decoded) return out_goaway(c, h2::COMPRESSION_ERROR);
+  StreamSt* st = c->streams.find(sid);
+  if (st == nullptr) {
+    if (sid <= c->last_sid) {
+      // trailers of a stream closed here are dropped; a block without
+      // END_STREAM opens a stream below the last (RFC 9113 5.1.1)
+      return (flags & h2::END_STREAM) ? true : out_goaway(c, h2::PROTOCOL_ERROR);
+    }
+    c->last_sid = sid;
+    if (c->streams.n >= h2::MAX_STREAMS) {
+      out_rst(c, sid, h2::REFUSED_STREAM);
+      return true;
+    }
+    st = c->streams.insert(sid);
+    st->kind = (uint8_t)kind;
+    st->compressed = compressed;
+    st->send_window = c->peer_window;
+    if (flags & h2::END_STREAM) {
+      st->half_closed = true;
+      process_request(S, c, sid, kind, compressed, nullptr, 0, false);
+    }
+    return true;
   }
-  return 0;
+  // trailers: they end the request
+  if (st->half_closed) {
+    reset_stream(c, st, h2::STREAM_CLOSED);
+  } else if (!(flags & h2::END_STREAM)) {
+    reset_stream(c, st, h2::PROTOCOL_ERROR);
+  } else {
+    st->half_closed = true;
+    end_gathered(S, c, st);
+  }
+  return true;
 }
 
-static int on_stream_close(ng::nghttp2_session*, int32_t stream_id, uint32_t,
-                           void* user_data) {
-  Conn* c = (Conn*)user_data;
-  c->streams.erase(stream_id);
-  return 0;
+static bool on_data(Server* S, Conn* c, uint8_t flags, int32_t sid, const uint8_t* p,
+                    uint32_t n) {
+  if (sid == 0) return out_goaway(c, h2::PROTOCOL_ERROR);
+  // flow control counts the whole payload, padding too
+  if ((int64_t)n > c->recv_window) return out_goaway(c, h2::FLOW_CONTROL_ERROR);
+  c->recv_window -= n;
+  if (c->recv_window <= h2::CONN_WINDOW / 2) {
+    out_window_update(c, 0, h2::CONN_WINDOW - c->recv_window);
+    c->recv_window = h2::CONN_WINDOW;
+  }
+  size_t m = n;
+  if (flags & h2::PADDED) {
+    if (m < 1 || p[0] >= m) return out_goaway(c, h2::PROTOCOL_ERROR);
+    m -= 1 + p[0];
+    p++;
+  }
+  StreamSt* st = c->streams.find(sid);
+  if (st == nullptr)  // idle: an error; closed here: dropped
+    return sid > c->last_sid ? out_goaway(c, h2::PROTOCOL_ERROR) : true;
+  if (st->half_closed) {
+    reset_stream(c, st, h2::STREAM_CLOSED);
+    return true;
+  }
+  st->recv_used += n;
+  if (st->recv_used > h2::STREAM_WINDOW) {
+    reset_stream(c, st, h2::FLOW_CONTROL_ERROR);
+    return true;
+  }
+  if ((flags & h2::END_STREAM) && st->body.empty() && !st->too_big) {
+    st->half_closed = true;
+    process_request(S, c, sid, st->kind, st->compressed, (const char*)p, m, true);
+    return true;
+  }
+  if (st->too_big || st->body.size() + m > h2::MAX_MESSAGE) {
+    st->too_big = true;
+    std::string().swap(st->body);
+  } else {
+    st->body.append((const char*)p, m);
+  }
+  if (flags & h2::END_STREAM) {
+    st->half_closed = true;
+    end_gathered(S, c, st);
+  } else if (st->recv_used >= h2::STREAM_WINDOW / 2) {
+    out_window_update(c, sid, st->recv_used);
+    st->recv_used = 0;
+  }
+  return true;
+}
+
+static bool on_settings(Conn* c, uint8_t flags, int32_t sid, const uint8_t* p, uint32_t n) {
+  if (sid != 0) return out_goaway(c, h2::PROTOCOL_ERROR);
+  if (flags & h2::ACK) return n == 0 ? true : out_goaway(c, h2::FRAME_SIZE_ERROR);
+  if (n % 6) return out_goaway(c, h2::FRAME_SIZE_ERROR);
+  for (uint32_t i = 0; i < n; i += 6) {
+    const uint16_t id = (uint16_t)((p[i] << 8) | p[i + 1]);
+    const uint32_t v = h2::be32(p + i + 2);
+    switch (id) {
+      case h2::S_HEADER_TABLE_SIZE:
+        // our encoder's table is never used: a shrink is met with a size
+        // update to 0 at the start of our next header block
+        if (v < c->enc_table) {
+          c->enc_table = 0;
+          c->table_update = true;
+        }
+        break;
+      case h2::S_ENABLE_PUSH:
+        if (v > 1) return out_goaway(c, h2::PROTOCOL_ERROR);
+        break;
+      case h2::S_INITIAL_WINDOW_SIZE: {
+        if (v > h2::MAX_WINDOW) return out_goaway(c, h2::FLOW_CONTROL_ERROR);
+        const int64_t delta = (int64_t)v - c->peer_window;
+        c->peer_window = v;
+        for (StreamSt& st : c->streams.slots) {
+          if (st.sid == 0) continue;
+          st.send_window += delta;
+          if (st.send_window > h2::MAX_WINDOW) return out_goaway(c, h2::FLOW_CONTROL_ERROR);
+        }
+        break;
+      }
+      case h2::S_MAX_FRAME_SIZE:
+        if (v < h2::MAX_FRAME || v > 0xffffff) return out_goaway(c, h2::PROTOCOL_ERROR);
+        c->peer_max_frame = v;
+        break;
+      default:
+        break;  // MAX_CONCURRENT_STREAMS (we open none), MAX_HEADER_LIST_SIZE, unknown
+    }
+  }
+  out_frame(c, 0, h2::SETTINGS, h2::ACK, 0);
+  c->acks++;
+  flush_held(c);
+  return true;
+}
+
+static bool on_frame(Server* S, Conn* c, uint8_t type, uint8_t flags, int32_t sid,
+                     const uint8_t* p, uint32_t n) {
+  if (c->cont_sid) {
+    if (type != h2::CONTINUATION || sid != c->cont_sid)
+      return out_goaway(c, h2::PROTOCOL_ERROR);
+    if (c->cont_block.size() + n > h2::MAX_HEADER_BLOCK)
+      return out_goaway(c, h2::ENHANCE_YOUR_CALM);
+    c->cont_block.append((const char*)p, n);
+    if (!(flags & h2::END_HEADERS)) return true;
+    c->cont_sid = 0;
+    std::string block;
+    block.swap(c->cont_block);
+    return on_header_block(S, c, sid, c->cont_flags, (const uint8_t*)block.data(),
+                           block.size());
+  }
+  if (!c->settings) {  // the client's preface ends with a SETTINGS
+    if (type != h2::SETTINGS || (flags & h2::ACK)) return out_goaway(c, h2::PROTOCOL_ERROR);
+    c->settings = true;
+  }
+  switch (type) {
+    case h2::DATA:
+      return on_data(S, c, flags, sid, p, n);
+    case h2::HEADERS: {
+      if (sid == 0 || !(sid & 1)) return out_goaway(c, h2::PROTOCOL_ERROR);
+      size_t m = n, pad = 0;
+      if (flags & h2::PADDED) {
+        if (m < 1) return out_goaway(c, h2::FRAME_SIZE_ERROR);
+        pad = *p++;
+        m--;
+      }
+      if (flags & h2::PRIORITY_FLAG) {  // its dependency and weight are ignored
+        if (m < 5) return out_goaway(c, h2::FRAME_SIZE_ERROR);
+        p += 5;
+        m -= 5;
+      }
+      if (pad > m) return out_goaway(c, h2::PROTOCOL_ERROR);
+      m -= pad;
+      if (!(flags & h2::END_HEADERS)) {
+        c->cont_sid = sid;
+        c->cont_flags = flags;
+        c->cont_block.assign((const char*)p, m);
+        return true;
+      }
+      return on_header_block(S, c, sid, flags, p, m);
+    }
+    case h2::PRIORITY:
+      if (sid == 0) return out_goaway(c, h2::PROTOCOL_ERROR);
+      if (n != 5) {
+        StreamSt* st = c->streams.find(sid);
+        if (st) reset_stream(c, st, h2::FRAME_SIZE_ERROR);
+        else out_rst(c, sid, h2::FRAME_SIZE_ERROR);
+      }
+      return true;
+    case h2::RST_STREAM: {
+      if (sid == 0 || sid > c->last_sid) return out_goaway(c, h2::PROTOCOL_ERROR);
+      if (n != 4) return out_goaway(c, h2::FRAME_SIZE_ERROR);
+      StreamSt* st = c->streams.find(sid);
+      if (st) c->streams.erase(st);  // an answer that comes later is dropped
+      return true;
+    }
+    case h2::SETTINGS:
+      return on_settings(c, flags, sid, p, n);
+    case h2::PING:
+      if (sid != 0) return out_goaway(c, h2::PROTOCOL_ERROR);
+      if (n != 8) return out_goaway(c, h2::FRAME_SIZE_ERROR);
+      if (!(flags & h2::ACK)) {
+        memcpy(out_frame(c, 8, h2::PING, h2::ACK, 0), p, 8);
+        c->acks++;
+      }
+      return true;
+    case h2::GOAWAY:  // the peer opens no more streams: those open are answered
+      if (sid != 0) return out_goaway(c, h2::PROTOCOL_ERROR);
+      if (n < 8) return out_goaway(c, h2::FRAME_SIZE_ERROR);
+      return true;
+    case h2::WINDOW_UPDATE: {
+      if (n != 4) return out_goaway(c, h2::FRAME_SIZE_ERROR);
+      const int64_t inc = h2::be32(p) & 0x7fffffff;
+      if (sid == 0) {
+        if (inc == 0) return out_goaway(c, h2::PROTOCOL_ERROR);
+        c->send_window += inc;
+        if (c->send_window > h2::MAX_WINDOW) return out_goaway(c, h2::FLOW_CONTROL_ERROR);
+      } else {
+        StreamSt* st = c->streams.find(sid);
+        if (st == nullptr)
+          return sid > c->last_sid ? out_goaway(c, h2::PROTOCOL_ERROR) : true;
+        if (inc == 0) {
+          reset_stream(c, st, h2::PROTOCOL_ERROR);
+          return true;
+        }
+        st->send_window += inc;
+        if (st->send_window > h2::MAX_WINDOW) {
+          reset_stream(c, st, h2::FLOW_CONTROL_ERROR);
+          return true;
+        }
+      }
+      flush_held(c);
+      return true;
+    }
+    case h2::PUSH_PROMISE:  // a client sends none
+    case h2::CONTINUATION:  // none is expected
+      return out_goaway(c, h2::PROTOCOL_ERROR);
+    default:
+      return true;  // unknown types are ignored (RFC 9113 4.1, 5.5)
+  }
+}
+
+// walk the frames recv left in the connection's buffer, then carry the tail
+// of a partial frame to its front; false on a connection error
+static bool h2_walk(Server* S, Conn* c) {
+  uint8_t* buf = c->rbuf.get();
+  const size_t len = c->rlen;
+  size_t pos = 0;
+  if (!c->preface) {
+    if (memcmp(buf, h2::PREFACE, std::min(len, h2::PREFACE_LEN)) != 0)
+      return out_goaway(c, h2::PROTOCOL_ERROR);
+    if (len < h2::PREFACE_LEN) return true;
+    c->preface = true;
+    pos = h2::PREFACE_LEN;
+  }
+  while (len - pos >= h2::FRAME_HEAD) {
+    const uint8_t* f = buf + pos;
+    const uint32_t flen = h2::be24(f);
+    if (flen > h2::MAX_FRAME) return out_goaway(c, h2::FRAME_SIZE_ERROR);
+    if (len - pos < h2::FRAME_HEAD + flen) break;
+    const int32_t sid = (int32_t)(h2::be32(f + 5) & 0x7fffffff);
+    if (!on_frame(S, c, f[3], f[4], sid, f + h2::FRAME_HEAD, flen)) return false;
+    if (c->acks > h2::MAX_ACKS) return out_goaway(c, h2::ENHANCE_YOUR_CALM);
+    pos += h2::FRAME_HEAD + flen;
+  }
+  if (pos) memmove(buf, buf + pos, len - pos);
+  c->rlen = len - pos;
+  return true;
+}
+
+// what the server says first: SETTINGS (the stream limit, a 1 MB stream
+// window), then the connection window widened to 1 GB
+static void out_server_preface(Conn* c) {
+  uint8_t* p = out_frame(c, 12, h2::SETTINGS, 0, 0);
+  p[0] = 0;
+  p[1] = h2::S_MAX_CONCURRENT_STREAMS;
+  h2::put32(p + 2, h2::MAX_STREAMS);
+  p[6] = 0;
+  p[7] = h2::S_INITIAL_WINDOW_SIZE;
+  h2::put32(p + 8, (uint32_t)h2::STREAM_WINDOW);
+  out_window_update(c, 0, h2::CONN_WINDOW - h2::DEFAULT_WINDOW);
 }
 
 // ---- epoll loop -----------------------------------------------------------
@@ -1719,49 +2332,46 @@ static void conn_close(Server* S, Conn* c) {
   }
   epoll_ctl(S->epfd, EPOLL_CTL_DEL, c->fd, nullptr);
   close(c->fd);
-  if (c->sess) ng::api.session_del(c->sess);
   delete c;
 }
 
-// drain nghttp2's send queue into conn.outbuf, write once.  The loop
-// clock's `write`: the caller stamps it when the pump returns.
+// one send of what the connection's out buffer holds; what the kernel does
+// not take waits for EPOLLOUT, and past OUT_CAP unsent the connection is not
+// read until it is under again (its open streams then bound what more can be
+// queued).  The loop clock's `write`: the caller stamps it when the pump
+// returns; the syscall's own time is the row `send`.
 static bool conn_pump(Server* S, Conn* c) {
-  for (;;) {
-    if (c->outbuf.size() < (size_t)256 << 10) {
-      const uint8_t* data = nullptr;
-      ssize_t n = ng::api.mem_send(c->sess, &data);
-      if (n < 0) return false;
-      if (n > 0) {
-        c->outbuf.append((const char*)data, (size_t)n);
-        continue;
-      }
-    }
-    if (c->outbuf.empty()) break;
-    ssize_t w = send(c->fd, c->outbuf.data(), c->outbuf.size(), MSG_NOSIGNAL);
+  const size_t left = c->outbuf.size() - c->out_off;
+  if (left) {
+    const int64_t t0 = now_mono_ns();
+    const ssize_t w = send(c->fd, c->outbuf.data() + c->out_off, left, MSG_NOSIGNAL);
+    S->clk.rows[ROW_SEND].add(now_mono_ns() - t0);
+    S->clk.rows[ROW_SEND].bump();
     S->clk.rows[PH_WRITE].bump();
     if (w < 0) {
-      if (errno == EAGAIN || errno == EWOULDBLOCK) {
-        S->clk.count(LC_SEND_BLOCKED);
-        if (!c->want_eout) {
-          struct epoll_event ev;
-          ev.events = EPOLLIN | EPOLLOUT;
-          ev.data.u32 = c->id;
-          epoll_ctl(S->epfd, EPOLL_CTL_MOD, c->fd, &ev);
-          c->want_eout = true;
-        }
-        return true;
+      if (errno != EAGAIN && errno != EWOULDBLOCK) return false;
+      S->clk.count(LC_SEND_BLOCKED);
+    } else if ((size_t)w == left) {
+      c->outbuf.clear();
+      c->out_off = 0;
+      c->acks = 0;
+    } else if (w > 0) {
+      c->out_off += (size_t)w;
+      c->acks = 0;
+      if (c->out_off >= c->outbuf.size() / 2) {
+        c->outbuf.erase(0, c->out_off);
+        c->out_off = 0;
       }
-      return false;
     }
-    c->outbuf.erase(0, (size_t)w);
-    if (c->outbuf.empty() && !ng::api.want_write(c->sess)) break;
   }
-  if (c->want_eout && c->outbuf.empty()) {
+  const size_t unsent = c->outbuf.size() - c->out_off;
+  const uint32_t want = (unsent <= h2::OUT_CAP ? EPOLLIN : 0) | (unsent ? EPOLLOUT : 0);
+  if (want != c->events) {
     struct epoll_event ev;
-    ev.events = EPOLLIN;
+    ev.events = want;
     ev.data.u32 = c->id;
     epoll_ctl(S->epfd, EPOLL_CTL_MOD, c->fd, &ev);
-    c->want_eout = false;
+    c->events = want;
   }
   return true;
 }
@@ -1774,21 +2384,7 @@ static void accept_conns(Server* S) {
     setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof one);
     Conn* c = new Conn();
     c->fd = fd;
-    ng::nghttp2_session_callbacks* cbs = nullptr;
-    ng::api.callbacks_new(&cbs);
-    ng::api.set_on_header(cbs, on_header);
-    ng::api.set_on_data_chunk(cbs, on_data_chunk);
-    ng::api.set_on_frame_recv(cbs, on_frame_recv);
-    ng::api.set_on_stream_close(cbs, on_stream_close);
-    ng::api.session_server_new(&c->sess, cbs, c);
-    ng::api.callbacks_del(cbs);
-    ng::nghttp2_settings_entry iv[2] = {
-        {ng::NGHTTP2_SETTINGS_MAX_CONCURRENT_STREAMS, 10000},  // ref main.go:68-69
-        {ng::NGHTTP2_SETTINGS_INITIAL_WINDOW_SIZE, 1 << 20},
-    };
-    ng::api.submit_settings(c->sess, 0, iv, 2);
-    // widen the connection receive window (auto-replenished by nghttp2)
-    ng::api.submit_window_update(c->sess, 0, 0, (1 << 30) - 65535);
+    out_server_preface(c);
     {
       std::lock_guard<std::mutex> lk(S->mu);
       c->id = S->next_conn_id++;
@@ -1815,12 +2411,16 @@ static void drain_done(Server* S) {
   }
   std::vector<Conn*> touched;
   uint64_t answers = 0, timed = 0, late_sum = 0, late_max = 0;
+  // a cut's answers come in runs of one connection's; connections are
+  // opened and closed on this thread alone, so a lookup holds for the drain
+  uint32_t last_id = 0;
+  Conn* c = nullptr;
   for (Done& d : q) {
-    Conn* c;
-    {
+    if (d.conn_id != last_id) {  // ids start at 1
       std::lock_guard<std::mutex> lk(S->mu);
       auto it = S->conns.find(d.conn_id);
       c = it == S->conns.end() ? nullptr : it->second;
+      last_id = d.conn_id;
     }
     if (!c) continue;
     if (d.grpc_status) submit_grpc_error(c, d.stream_id, d.grpc_status);
@@ -1898,16 +2498,23 @@ static void epoll_loop(Server* S) {
       }
       if (!c) continue;
       bool dead = false;
+      bool closing = false;  // a connection error: its GOAWAY goes, then the socket
       if (evs[i].events & (EPOLLHUP | EPOLLERR)) dead = true;
       if (!dead && (evs[i].events & EPOLLIN)) {
-        char buf[65536];
-        for (;;) {
-          ssize_t r = recv(c->fd, buf, sizeof buf, 0);
+        // not past OUT_CAP unsent: a walk, or a drain since epoll_wait
+        // returned, may pass it
+        while (c->outbuf.size() - c->out_off <= h2::OUT_CAP) {
+          // one recv into the buffer, past the tail the last walk carried
+          const size_t room = RBUF_BYTES - c->rlen;
+          const int64_t t0 = now_mono_ns();
+          const ssize_t r = recv(c->fd, c->rbuf.get() + c->rlen, room, 0);
+          clk.rows[ROW_RECV].add(now_mono_ns() - t0);
+          clk.rows[ROW_RECV].bump();
           clk.rows[PH_READ].bump();
           if (r > 0) {
-            ssize_t rc = ng::api.mem_recv(c->sess, (const uint8_t*)buf, (size_t)r);
-            if (rc < 0) { dead = true; break; }
-            if (r < (ssize_t)sizeof buf) break;
+            c->rlen += (size_t)r;
+            if (!h2_walk(S, c)) { closing = true; break; }
+            if ((size_t)r < room) break;
           } else if (r == 0) { dead = true; break; }
           else {
             if (errno == EAGAIN || errno == EWOULDBLOCK) break;
@@ -1917,7 +2524,7 @@ static void epoll_loop(Server* S) {
       }
       clk.stamp(PH_READ);
       if (!dead) {
-        dead = !conn_pump(S, c);
+        dead = !conn_pump(S, c) || closing;
         clk.stamp(PH_WRITE);
       }
       if (dead) {
@@ -1940,7 +2547,6 @@ static void epoll_loop(Server* S) {
   for (Conn* c : all) {
     epoll_ctl(S->epfd, EPOLL_CTL_DEL, c->fd, nullptr);
     close(c->fd);
-    if (c->sess) ng::api.session_del(c->sess);
     delete c;
   }
   {
@@ -1955,7 +2561,6 @@ static void epoll_loop(Server* S) {
 // the waits which release it in pymod) ---------------------------------------
 
 static int server_start(Server* S) {
-  if (!ng::load()) return -1;
   S->listen_fd = socket(AF_INET, SOCK_STREAM | SOCK_NONBLOCK, 0);
   if (S->listen_fd < 0) return -2;
   int one = 1;

@@ -6,7 +6,7 @@
 // streams each from one thread.  Latency is measured per stream from
 // enqueue to the grpc trailers frame — the number a real client sees.
 //
-// The server side is the full nghttp2 stack; this client stays raw on
+// The server side is a full HTTP/2 framer; this client stays raw on
 // purpose: on the 1-core benchmark host, client cycles eat directly into
 // the measured server throughput, so the client must be as thin as the
 // wire allows (the reference benchmarks pay the same tax in-process via

@@ -34,7 +34,11 @@ COUNTERS = {"send_blocked", "cuts_deferred"}
 STAGE_OF_ROW = {"req_wait": "wait", "req_exec": "exec",
                 "req_respond": "respond"}
 # counts alone: no time of their own
-SCAN_COUNTS = ("ovf_dfas", "ovf_loads", "req_bytes", "req_headers")
+SCAN_COUNTS = ("ovf_dfas", "ovf_loads", "req_bytes", "req_headers", "msg_inplace",
+               "cut_timer")
+# the syscalls' own time, each inside a phase: `recv` in `read`, `send` in
+# `write`
+SYSCALL_OF_PHASE = {"read": "recv", "write": "send"}
 
 
 @pytest.fixture(scope="module")
@@ -106,7 +110,8 @@ def test_table_splits_the_threads_phases_from_the_rows_that_are_not(frontend):
     assert list(table["phases"]) == ["idle", "read", "parse", "encode",
                                      "ovf_scan", "cut", "respond", "write",
                                      "other"]
-    assert set(table["rows"]) == {"turn"} | set(STAGE_OF_ROW) | set(SCAN_COUNTS)
+    assert set(table["rows"]) == ({"turn"} | set(STAGE_OF_ROW) | set(SCAN_COUNTS)
+                                  | set(SYSCALL_OF_PHASE.values()))
     assert 0 < table["mark_mono_ns"] <= time.monotonic_ns()
 
 
@@ -143,6 +148,31 @@ def test_phases_add_up_to_the_threads_wall_time(frontend):
     assert 0 <= b["phases"]["idle"]["count"] - b["rows"]["turn"]["count"] <= 1
 
 
+def test_recv_and_send_lie_inside_read_and_write(frontend):
+    """The two syscalls are rows, not phases: with them in the table the
+    phases still add up to the thread's wall time to the nanosecond, and
+    each syscall's time lies inside its phase's, one call a count of it."""
+    fe, port = frontend
+    a = quiet(fe, 0)
+    for k in range(8):
+        grpc_call(port, fast_req(f"syscalls-{k}"))
+    health_call(port)
+    b = quiet(fe, a["phases"]["respond"]["count"] + 8)
+    assert not set(SYSCALL_OF_PHASE.values()) & set(b["phases"])
+    assert wall(b) - wall(a) == b["mark_mono_ns"] - a["mark_mono_ns"] > 0
+    for table in (b, None):  # over the window, and over the whole run
+        for phase, call in SYSCALL_OF_PHASE.items():
+            if table is None:
+                inside, outside = b["rows"][call], b["phases"][phase]
+                assert inside["sum_ns"] <= outside["sum_ns"]
+                assert inside["count"] == outside["count"]
+                continue
+            assert 0 < delta(b, a, call, "sum_ns") <= delta(b, a, phase, "sum_ns")
+            assert delta(b, a, call) == delta(b, a, phase) > 0
+    # grpcio sends each message whole in one DATA frame: read where it lay
+    assert 0 < delta(b, a, "msg_inplace") <= delta(b, a, "parse") == 8
+
+
 def test_counts_are_the_front_ends_own_counters(frontend):
     fe, port = frontend
     a, s0, fills = quiet(fe, 0), dict(fe._mod.fe_stats()), counts(fe)["fill"]
@@ -167,10 +197,30 @@ def test_counts_are_the_front_ends_own_counters(frontend):
     cuts = delta(b, a, "cut")
     settle(fe, "fill", fills + cuts)
     assert cuts == counts(fe)["fill"] - fills >= 1
+    # one request at a time: every slot was cut part-full, by the window
+    assert delta(b, a, "cut_timer") == cuts
     assert delta(b, a, "read") >= 8 and delta(b, a, "write") >= 8
     for row in b["phases"].values():
         assert row["sum_ns"] >= row["max_ns"] >= 0
     assert b["counters"] == a["counters"]  # nobody was held back
+
+
+def test_cut_timer_counts_the_cuts_the_window_made(frontend):
+    """40 Checks in one write: two slots of 16 are cut full as the rows come,
+    and the window's timer cuts the last 8."""
+    from test_h2_framer import Client, allow, request
+
+    fe, port = frontend
+    a = quiet(fe, 0)
+    c = Client(port)
+    try:
+        c.send(b"".join(request(1 + 2 * k, allow(f"burst-{k}")) for k in range(40)))
+        assert all(c.answer(1 + 2 * k)[1].status.code == 0 for k in range(40))
+    finally:
+        c.close()
+    b = quiet(fe, a["phases"]["respond"]["count"] + 40)
+    assert delta(b, a, "encode") == 40
+    assert (delta(b, a, "cut"), delta(b, a, "cut_timer")) == (3, 1)
 
 
 def test_ovf_scan_counts_the_rows_the_batch_events_carry(frontend):
@@ -515,8 +565,12 @@ def test_a_peer_that_does_not_read_blocks_the_send(frontend):
         s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 2048)
         s.settimeout(10)
         s.connect(("127.0.0.1", port))
+        # its windows opened, as Envoy does: the answers go out, and none
+        # waits in its stream for a WINDOW_UPDATE
         s.sendall(b"PRI * HTTP/2.0\r\n\r\nSM\r\n\r\n"
-                  + b"\x00\x00\x00\x04\x00\x00\x00\x00\x00")
+                  + b"\x00\x00\x06\x04\x00\x00\x00\x00\x00\x00\x04\x7f\xff\xff\xff"
+                  + b"\x00\x00\x04\x08\x00\x00\x00\x00\x00"
+                  + (0x7fffffff - 65535).to_bytes(4, "big"))
         for _ in range(300):
             s.sendall(b"".join(stream(sid + 2 * k) for k in range(2000)))
             sid += 4000
@@ -601,5 +655,12 @@ def test_every_metric_of_the_issue_has_its_file():
     # ISSUE 38's two, counts alone over `parse`'s count, in every cell
     sizes = {"fe_bytes_per_check", "fe_headers_per_check"}
     assert all("workloads" not in per_layer[n] for n in sizes)
-    assert set(_front_metrics()) == scan | sizes | {
+    # the framer's three: the syscalls' shares of the thread, and the Checks
+    # read where recv put them, in every cell
+    framer = {"fe_recv_pct", "fe_send_pct", "fe_inplace_share"}
+    assert all("workloads" not in per_layer[n] and per_layer[n]["moves"] == "checks_per_s"
+               for n in framer)
+    # the cuts the window's timer made, over every cut, in every cell
+    assert "workloads" not in per_layer["cut_timer_share"]
+    assert set(_front_metrics()) == scan | sizes | framer | {"cut_timer_share"} | {
         n for n in want if n.startswith("fe_") and n != "fe_respond_p50_us"}

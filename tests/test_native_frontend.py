@@ -45,7 +45,7 @@ def _native_available() -> bool:
 
 
 pytestmark = pytest.mark.skipif(
-    not _native_available(), reason="native frontend unavailable (no libnghttp2?)")
+    not _native_available(), reason="native frontend unavailable (no g++ to build it?)")
 
 
 # ---------------------------------------------------------------------------

@@ -630,11 +630,13 @@ class TestLoopClockDrain:
     def test_cpp_names_nine_phases_and_four_more_rows(self):
         assert self.PHASES == ["idle", "read", "parse", "encode", "ovf_scan",
                                "cut", "respond", "write", "other"]
-        # ... since ISSUE 37 the overflow scan's two counts, and since
-        # ISSUE 38 a request's bytes and headers
+        # ... then the overflow scan's two counts, a request's bytes and
+        # headers, the two syscalls inside `read` and `write`, the
+        # messages parsed where recv put them, and the cuts the timer made
         assert self.ROWS[9:] == ["turn", "req_wait", "req_exec", "req_respond",
                                  "ovf_dfas", "ovf_loads", "req_bytes",
-                                 "req_headers"]
+                                 "req_headers", "recv", "send", "msg_inplace",
+                                 "cut_timer"]
         # the two counters that have an operator's use (docs/observability.md)
         assert _cpp_list("LOOP_COUNTER_NAMES") == ["send_blocked", "cuts_deferred"]
 
