@@ -52,7 +52,7 @@ _FIELDS = (
     "h2d_transfers", "h2d_bytes", "d2h_bytes", "pad_rows", "pad_waste_rows",
     "eff_slack_cols", "dedup_avoided_rows", "cache_avoided_rows",
     "dfa_ovf_rows", "own_dfa_slots", "own_dfa_rows", "telemetry_folds",
-    "eff_cols", "dfa_dev_bytes", "dfa_host_bytes",
+    "eff_cols", "dfa_dev_bytes", "dfa_host_bytes", "resolved_native",
 )
 
 
@@ -170,6 +170,18 @@ class CostLedger:
             if lc is None:
                 lc = self._lanes[lane] = _LaneCost()
             lc.telemetry_folds += 1
+
+    def observe_resolved(self, lane: str) -> None:
+        """One cut completed by the native lane's one-call resolve
+        (``fe_resolve_cut``: decode, fan-out, completion and the cache's
+        commit outside the interpreter lock): ``resolved_native`` /
+        ``batches`` is the share of cuts that took it; the mesh step,
+        degraded, brownout and host-lane cuts do not."""
+        with self._lock:
+            lc = self._lanes.get(lane)
+            if lc is None:
+                lc = self._lanes[lane] = _LaneCost()
+            lc.resolved_native += 1
 
     def snapshot(self, lane: str) -> Dict[str, Any]:
         """One lane's raw counters (zeros if the lane never folded) —

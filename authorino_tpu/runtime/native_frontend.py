@@ -615,9 +615,10 @@ def fast_lane_eligible(entry, policy: Optional[CompiledPolicy]) -> Optional[Fast
 
 class _Launched:
     """The device launches of one single-corpus cut, one a size class
-    present: ``parts`` holds (result handle [pad, W_c] uint8, positions
-    among the cut's launched rows or None = all of them in order, rows
-    launched, the class's evaluator columns E_c)."""
+    present (none: the cache answered the whole cut): ``parts`` holds
+    (result handle [pad, W_c] uint8, positions among the cut's launched
+    rows or None = all of them in order, rows launched, the class's
+    evaluator columns E_c).  ``fe_resolve_cut`` completes it in one call."""
 
     __slots__ = ("parts",)
 
@@ -631,28 +632,40 @@ class _Launched:
                 return False
         return True
 
-    def unpack(self, u: int, attribute: bool):
-        """(verdict [u] uint8, firing [u] int32 or None) of the ``u``
-        launched rows, each part decoded at its class's width and put back
-        at its rows' positions."""
-        from ..ops.pattern_eval import unpack_attribution
 
-        verdict = firing = None
-        for handle, at, n, E in self.parts:
-            packed = np.asarray(handle)[:n]
-            if attribute:
-                v, f = unpack_attribution(packed, E)
-            else:
-                v, f = (packed[:, 0] & 1).astype(np.uint8), None
-            if at is None:
-                return np.ascontiguousarray(v), f
-            if verdict is None:
-                verdict = np.zeros((u,), dtype=np.uint8)
-                firing = np.full((u,), -1, dtype=np.int32) if attribute else None
-            verdict[at] = v
-            if f is not None:
-                firing[at] = f
-        return verdict, firing
+def resolve_cut(parts, plan, count: int, attribute: bool):
+    """(verdict [count] uint8, firing [count] int32 or None) of a completed
+    cut, in numpy: each of ``parts`` (as ``_Launched.parts``, results read
+    back) decoded at its class's width (``unpack_attribution``) and put back
+    at its positions among the launched rows, then fanned out through
+    ``plan`` (a ``CutPlan``; None: the launched rows are the cut's) with the
+    cached rows' values written last.  A row nothing reaches reads 0 / -1.
+    What ``fe_resolve_cut`` computes outside the interpreter lock; the mesh
+    step completes through this, and the tests hold the native call to it."""
+    from ..ops.pattern_eval import unpack_attribution
+
+    u = count if plan is None else len(plan.unique_rows)
+    uniq_v = np.zeros((u,), dtype=np.uint8)
+    uniq_f = np.full((u,), -1, dtype=np.int32) if attribute else None
+    for packed, at, n, E in parts:
+        packed = np.asarray(packed)[:n]
+        at = slice(n) if at is None else at
+        if attribute:
+            uniq_v[at], uniq_f[at] = unpack_attribution(packed, E)
+        else:
+            uniq_v[at] = packed[:, 0] & 1
+    if plan is None:
+        return uniq_v, uniq_f
+    verdict = np.zeros((count,), dtype=np.uint8)
+    firing = np.full((count,), -1, dtype=np.int32) if attribute else None
+    verdict[plan.miss_rows] = uniq_v[plan.inverse]
+    # cached value = (verdict, firing): a cache hit attributes identically
+    # to the device evaluation it memoized
+    verdict[plan.cached_rows] = plan.cached_verdict
+    if attribute:
+        firing[plan.miss_rows] = uniq_f[plan.inverse]
+        firing[plan.cached_rows] = plan.cached_firing
+    return verdict, firing
 
 
 class _KeptCut(NamedTuple):
@@ -2534,7 +2547,7 @@ class NativeFrontend:
             # every row cache-resolved: complete through the readback queue
             # with no device work at all
             pad = eff = 0
-            packed = np.zeros((0, 1), dtype=np.uint8)
+            packed = _Launched()
             t0 = time.monotonic()
             t0_ns = time.time_ns()
             # structural cost fold (ISSUE 16): ZERO launches, zero bytes —
@@ -2897,6 +2910,9 @@ class NativeFrontend:
             launched = packed if isinstance(packed, _Launched) else None
             if launched is None:
                 packed = np.asarray(packed)
+            else:
+                parts = [(np.asarray(h), at, n, e)
+                         for h, at, n, e in launched.parts]
             if pad:
                 # the device answered (cache-only batches with pad == 0
                 # never touched it): clear the breaker's consecutive-failure
@@ -2908,58 +2924,47 @@ class NativeFrontend:
                 self.breaker.release_probe()
             dispatch_s = time.monotonic() - t0
             # attribution (ISSUE 9): the packed readback already carries the
-            # per-rule result/skip columns — ONE vectorized unpack per launch
-            # recovers the firing column next to the verdict bit (zero
-            # per-request Python, pinned by tests/test_provenance.py)
-            from ..ops.pattern_eval import unpack_attribution
-
+            # per-rule result/skip columns, so the firing column comes back
+            # beside the verdict bit with no per-request Python (pinned by
+            # tests/test_provenance.py)
             heat = rec.heat
             E = heat.E if heat is not None else 0
             u = count if fan is None else len(fan.unique_rows)
-            uniq_v = uniq_f = None
+            cached_n = len(fan.cached_rows) if fan is not None else 0
+            elig_miss_n = fan.eligible_misses if fan is not None else 0
             if launched is not None:
-                # a single corpus's launches, one a size class present
-                uniq_v, uniq_f = launched.unpack(u, bool(E))
-            elif u:
-                # the mesh step's one result: own verdict = bit 0 of byte 0
-                if E:
-                    uniq_v, uniq_f = unpack_attribution(packed[:u], E)
-                    uniq_v = np.ascontiguousarray(uniq_v)
-                else:
-                    uniq_v = np.ascontiguousarray(
-                        packed[:u, 0] & 1).astype(np.uint8)
-            if fan is None:
-                # dedup/cache off: the launched rows are the cut's rows
-                verdict, firing = uniq_v, uniq_f
-                cached_n = elig_miss_n = 0
+                # a single corpus's launches (none: the cache answered the
+                # cut): ONE native call outside the interpreter lock decodes
+                # them, fans them out through the plan, completes the slot
+                # and commits the plan's ticket (native/verdict_cache.cpp
+                # resolve; `resolve_cut` is its reference).  It checks every
+                # input before it touches the slot
+                verdict = np.empty((count,), dtype=np.uint8)
+                firing = np.empty((count,), dtype=np.int32) if E else None
+                evict_d = self._mod.fe_resolve_cut(
+                    parts, fan, count, snap_id, slot, verdict, firing)
             else:
-                elig_miss_n = fan.eligible_misses
-                verdict = np.zeros((count,), dtype=np.uint8)
-                firing = np.full((count,), -1, dtype=np.int32) if E else None
-                if u:
-                    verdict[fan.miss_rows] = uniq_v[fan.inverse]
-                    if firing is not None and uniq_f is not None:
-                        firing[fan.miss_rows] = uniq_f[fan.inverse]
-                # cached value = (verdict, firing): a cache hit attributes
-                # identically to the device evaluation it memoized
-                verdict[fan.cached_rows] = fan.cached_verdict
-                if firing is not None:
-                    firing[fan.cached_rows] = fan.cached_firing
-                cached_n = len(fan.cached_rows)
-            self._mod.fe_complete_batch(snap_id, slot, verdict.ctypes.data)
+                # the mesh step's one result: own verdict = bit 0 of byte 0
+                verdict, firing = resolve_cut([(packed, None, u, E)], fan,
+                                              count, bool(E))
+                self._mod.fe_complete_batch(snap_id, slot, verdict.ctypes.data)
+                evict_d = 0
         # the slot is COMPLETED from here on: an exception below must not
         # propagate to the readback loop's fail-closed deny, which would
         # fe_complete_batch the same slot twice — by then possibly refilled
         # with a fresh live batch
         try:
             with bt.stage("post"):
-                evict_d = 0
                 cache = self._verdict_cache
-                if fan is not None and cache is not None and u:
-                    # unique rows are freshly evaluated: the cacheable ones
-                    # go in at once, under the keys (token and row bytes)
-                    # the ticket copied at plan time: the slot may have
-                    # been refilled since
+                if launched is not None:
+                    LEDGER.observe_resolved(
+                        "native" if rec.sharded is None else "mesh")
+                elif fan is not None and cache is not None and u:
+                    # the mesh step's commit (the call above made the
+                    # others'): unique rows are freshly evaluated, the
+                    # cacheable ones go in at once, under the keys (token
+                    # and row bytes) the ticket copied at plan time: the
+                    # slot may have been refilled since
                     evict_d = cache.commit(fan.ticket, verdict, firing)
                 # the commit has a reader waiting (a later cut's plan); the
                 # rest of the cut's telemetry has none inside the cut

@@ -1046,6 +1046,169 @@ PyObject* vc_commit_py(PyObject*, PyObject* args) {
   return result;
 }
 
+// ---------------------------------------------------------------------------
+// fe_resolve_cut: a completed single-corpus cut in one call
+// ---------------------------------------------------------------------------
+
+// the caller's buffers, held until the call returns (a deque: a Py_buffer
+// may point into itself, so none may move)
+struct HeldBuffers {
+  std::deque<Py_buffer> held;
+  ~HeldBuffers() {
+    for (Py_buffer& b : held) PyBuffer_Release(&b);
+  }
+  // an `ndim`-D array of `what` whose items are `itemsize` bytes of a
+  // format in `kinds` (native byte order), C-contiguous unless `strided`
+  // (then its `strides` say where each item lies); ValueError otherwise
+  const Py_buffer* get(PyObject* o, bool writable, int ndim, const char* kinds,
+                       Py_ssize_t itemsize, Py_ssize_t itemsize_alt, const char* what,
+                       bool strided = false) {
+    Py_buffer b;
+    int flags = (strided ? PyBUF_STRIDES : PyBUF_C_CONTIGUOUS) | PyBUF_FORMAT |
+                (writable ? PyBUF_WRITABLE : 0);
+    if (!PyObject_CheckBuffer(o) || PyObject_GetBuffer(o, &b, flags) < 0) {
+      PyErr_Clear();
+      PyErr_Format(PyExc_ValueError, "fe_resolve_cut: %s is not a%s%s array", what,
+                   strided ? "" : " contiguous", writable ? " writable" : "");
+      return nullptr;
+    }
+    held.push_back(b);
+    const char* f = b.format != nullptr ? b.format : "B";
+    if (*f == '@' || *f == '=' || *f == '<') ++f;
+    if (b.ndim != ndim || f[0] == '\0' || f[1] != '\0' || strchr(kinds, f[0]) == nullptr ||
+        (b.itemsize != itemsize && b.itemsize != itemsize_alt)) {
+      PyErr_Format(PyExc_ValueError, "fe_resolve_cut: %s has the wrong dtype or rank", what);
+      return nullptr;
+    }
+    return &held.back();
+  }
+};
+
+// every one of the first n indices of a 1-D int32 / int64 buffer in [0, bound)
+bool indices_below(const Py_buffer* b, Py_ssize_t n, int64_t bound) {
+  for (Py_ssize_t i = 0; i < n; ++i) {
+    const int64_t v = b->itemsize == 8 ? ((const int64_t*)b->buf)[i] : ((const int32_t*)b->buf)[i];
+    if (v < 0 || v >= bound) return false;
+  }
+  return true;
+}
+
+// fe_resolve_cut(parts, plan | None, count, snap_id, slot, verdict, firing | None)
+//   -> evictions
+// parts: [(packed u8 [>= n, W] (rows may be strided: the runtime's readback
+// can pad them), positions int32/int64 [n] | None, n, E), ...],
+// the cut's launches (runtime/native_frontend.py _Launched), none for a cut
+// the cache answered whole; plan: its CutPlan (authorino_tpu/native/
+// verdict_cache.py), None when dedup and the cache are off; verdict u8
+// [>= count] and firing i32 [>= count] (None: no attribution) are written.
+// Every input is checked first and a ValueError leaves the slot as it was.
+// Then, without the interpreter lock: the decode and fan-out (vc::resolve),
+// the slot's completion (none without a server), and the ticket's commit.
+PyObject* fe_resolve_cut_py(PyObject*, PyObject* args) {
+  PyObject *parts_o, *plan_o, *verdict_o, *firing_o;
+  long long count, snap_id;
+  int slot;
+  if (!PyArg_ParseTuple(args, "OOLLiOO", &parts_o, &plan_o, &count, &snap_id, &slot,
+                        &verdict_o, &firing_o))
+    return nullptr;
+  HeldBuffers bufs;
+  const bool attribute = firing_o != Py_None;
+  const Py_buffer* verdict = bufs.get(verdict_o, true, 1, "B", 1, 1, "verdict");
+  if (verdict == nullptr) return nullptr;
+  const Py_buffer* firing = nullptr;
+  if (attribute && (firing = bufs.get(firing_o, true, 1, "il", 4, 4, "firing")) == nullptr)
+    return nullptr;
+  if (count < 0 || verdict->shape[0] < count || (firing != nullptr && firing->shape[0] < count)) {
+    PyErr_SetString(PyExc_ValueError, "fe_resolve_cut: verdict/firing shorter than the cut");
+    return nullptr;
+  }
+
+  vc::Fan fan{};
+  VcTicket* ticket = nullptr;
+  int64_t launched = count;
+  if (plan_o != Py_None) {
+    PyObject *ticket_o, *unique_o, *elig_o, *arr_o[5];
+    // CutPlan's fields, in order
+    if (!PyArg_ParseTuple(plan_o, "OOOOOOOO", &ticket_o, &arr_o[0], &arr_o[1], &arr_o[2],
+                          &arr_o[3], &unique_o, &arr_o[4], &elig_o))
+      return nullptr;
+    static const char* names[5] = {"cached_rows", "cached_verdict", "cached_firing",
+                                   "miss_rows", "inverse"};
+    const Py_buffer* arr[5];
+    for (int k = 0; k < 5; ++k)
+      if ((arr[k] = bufs.get(arr_o[k], false, 1, "il", 4, 4, names[k])) == nullptr)
+        return nullptr;
+    Py_ssize_t n_unique = PyObject_Length(unique_o);
+    if (n_unique < 0) return nullptr;
+    launched = n_unique;
+    fan = {(const int32_t*)arr[0]->buf, (const int32_t*)arr[1]->buf,
+           (const int32_t*)arr[2]->buf, (const int32_t*)arr[3]->buf,
+           (const int32_t*)arr[4]->buf, arr[0]->shape[0], arr[3]->shape[0]};
+    if (arr[1]->shape[0] != fan.n_cached || arr[2]->shape[0] != fan.n_cached ||
+        arr[4]->shape[0] != fan.n_miss || !indices_below(arr[0], fan.n_cached, count) ||
+        !indices_below(arr[3], fan.n_miss, count) ||
+        !indices_below(arr[4], fan.n_miss, launched)) {
+      PyErr_SetString(PyExc_ValueError, "fe_resolve_cut: the plan's arrays disagree with "
+                                        "each other or with the cut");
+      return nullptr;
+    }
+    if (ticket_o != Py_None) {
+      ticket = (VcTicket*)PyCapsule_GetPointer(ticket_o, VC_TICKET);
+      if (ticket == nullptr ||
+          PyCapsule_GetPointer(ticket->cache_cap, VC_CACHE) == nullptr)
+        return nullptr;
+      if (ticket->t.count > count) {
+        PyErr_SetString(PyExc_ValueError, "fe_resolve_cut: the ticket's cut is longer");
+        return nullptr;
+      }
+    }
+  }
+
+  PyObject* seq = PySequence_Fast(parts_o, "fe_resolve_cut: parts is not a sequence");
+  if (seq == nullptr) return nullptr;
+  std::vector<vc::Part> parts((size_t)PySequence_Fast_GET_SIZE(seq));
+  for (size_t p = 0; p < parts.size(); ++p) {
+    PyObject *packed_o, *at_o;
+    long long n, E;
+    const Py_buffer *packed, *at = nullptr;
+    bool ok = PyArg_ParseTuple(PySequence_Fast_GET_ITEM(seq, (Py_ssize_t)p), "OOLL", &packed_o,
+                               &at_o, &n, &E) &&
+              (packed = bufs.get(packed_o, false, 2, "B", 1, 1, "a part's result", true)) !=
+                  nullptr &&
+              (at_o == Py_None ||
+               (at = bufs.get(at_o, false, 1, "ilq", 4, 8, "a part's positions")) != nullptr);
+    if (ok && (n < 0 || E < 0 || packed->shape[0] < n || packed->shape[1] < 1 ||
+               (attribute && packed->shape[1] * 8 < 1 + 2 * E) ||
+               (at != nullptr ? at->shape[0] < n || !indices_below(at, n, launched)
+                              : n > launched))) {
+      PyErr_SetString(PyExc_ValueError, "fe_resolve_cut: a part is shorter than its rows, "
+                                        "narrower than its columns, or lands past the cut");
+      ok = false;
+    }
+    if (!ok) {
+      Py_DECREF(seq);
+      return nullptr;
+    }
+    parts[p] = {(const uint8_t*)packed->buf, packed->strides[0], packed->strides[1], n, E,
+                at != nullptr ? at->buf : nullptr, at != nullptr && at->itemsize == 8};
+  }
+  Py_DECREF(seq);
+
+  fe::Server* S = fe::g_srv;
+  uint8_t* v = (uint8_t*)verdict->buf;
+  int32_t* f = firing != nullptr ? (int32_t*)firing->buf : nullptr;
+  vc::Cache* cache =
+      ticket != nullptr ? (vc::Cache*)PyCapsule_GetPointer(ticket->cache_cap, VC_CACHE) : nullptr;
+  long long evicted = 0;
+  Py_BEGIN_ALLOW_THREADS
+  vc::resolve(parts.data(), parts.size(), launched, plan_o != Py_None ? &fan : nullptr, count,
+              v, f);
+  if (S != nullptr) fe::complete_batch(S, snap_id, slot, v);
+  if (cache != nullptr) evicted = vc::commit(cache, ticket->t, v, f);
+  Py_END_ALLOW_THREADS
+  return PyLong_FromLongLong(evicted);
+}
+
 // vc_counts(cache) -> {hits, misses, adds, evictions, entries}
 PyObject* vc_counts_py(PyObject*, PyObject* cache_o) {
   vc::Cache* c = (vc::Cache*)PyCapsule_GetPointer(cache_o, VC_CACHE);
@@ -1085,6 +1248,8 @@ PyMethodDef methods[] = {
      "probe the cache for a cut's rows and collapse its misses"},
     {"vc_commit", vc_commit_py, METH_VARARGS, "insert a planned cut's verdicts"},
     {"vc_counts", vc_counts_py, METH_O, "verdict cache counters"},
+    {"fe_resolve_cut", fe_resolve_cut_py, METH_VARARGS,
+     "decode, fan out, complete and commit a finished cut"},
     {nullptr, nullptr, 0, nullptr},
 };
 

@@ -5,9 +5,11 @@
 //           the cache for the eligible ones, collapses the misses to unique
 //           rows, and copies the keys of the eligible unique misses into a
 //           ticket (the slot may be refilled before the verdicts are back);
+//   resolve decodes the cut's launch results and fans them out through the
+//           plan to every row of the cut;
 //   commit  inserts a ticket's keys with the verdicts of their rows.
-// Both run without the interpreter lock and take the cache's one mutex only
-// then (pymod.cpp releases the lock before it calls in here).
+// All run without the interpreter lock; plan and commit take the cache's one
+// mutex only then (pymod.cpp releases the lock before it calls in here).
 //
 // The contract is utils/verdict_cache.py + compiler/pack.py dedup_rows,
 // which stay the engine lane's and the tests' reference: a key is the token
@@ -20,6 +22,7 @@
 //
 // Compiled as part of the _atpuenc single translation unit (pymod.cpp).
 
+#include <algorithm>
 #include <atomic>
 #include <cstdint>
 #include <cstring>
@@ -297,6 +300,78 @@ inline void plan(Cache* cache, const Seg* segs, size_t nseg, int32_t count,
   p.out.reserve(3 * c_rows.size() + 2 * nm + unique.size());
   for (const auto* v : {&c_rows, &c_verdict, &c_firing, &miss, &unique, &inverse})
     p.out.insert(p.out.end(), v->begin(), v->end());
+}
+
+// one launch's readback: rows [0, n) of a [>= n, W] uint8 array whose row
+// r, byte j lies at packed + r * row_stride + j * col_stride (the runtime
+// may hand back a row-major array with padded rows), bit 0 of a row = its
+// verdict, bits 1..E its rule results, E+1..2E their skips
+// (ops/pattern_eval.py unpack_attribution); put back at at[i] among the
+// cut's launched rows (at nullptr: at i; at_wide: int64 positions, else
+// int32)
+struct Part {
+  const uint8_t* packed;
+  int64_t row_stride, col_stride, n, E;
+  const void* at;
+  bool at_wide;
+};
+
+// a CutPlan's arrays (authorino_tpu/native/verdict_cache.py), all int32
+struct Fan {
+  const int32_t *cached_rows, *cached_verdict, *cached_firing, *miss_rows, *inverse;
+  int64_t n_cached, n_miss;
+};
+
+// firing_columns of one packed row, its bytes `stride` apart: the first of
+// its E rule columns that evaluated false and was not skipped, else -1
+inline int32_t first_firing(const uint8_t* row, int64_t stride, int64_t E) {
+  auto bit = [=](int64_t k) { return (row[(k >> 3) * stride] >> (k & 7)) & 1; };
+  for (int64_t j = 0; j < E; ++j)
+    if (!bit(1 + j) && !bit(1 + E + j)) return (int32_t)j;
+  return -1;
+}
+
+// a completed cut's verdicts (and firing columns, firing nullptr: none)
+// into verdict[count] / firing[count]: each part decoded and put back among
+// the `launched` rows, which `fan` (nullptr: the launched rows are the
+// cut's) spreads over its miss rows; the cached rows take the plan's values.
+// A row no part or plan reaches reads 0 / -1.  The caller has checked every
+// position and row against its bound.
+inline void resolve(const Part* parts, size_t nparts, int64_t launched, const Fan* fan,
+                    int64_t count, uint8_t* verdict, int32_t* firing) {
+  static thread_local std::vector<uint8_t> uv;
+  static thread_local std::vector<int32_t> uf;
+  uint8_t* lv = verdict;
+  int32_t* lf = firing;
+  if (fan != nullptr) {
+    uv.assign((size_t)launched, 0);
+    uf.assign(firing != nullptr ? (size_t)launched : 0, -1);
+    lv = uv.data();
+    lf = firing != nullptr ? uf.data() : nullptr;
+  }
+  memset(verdict, 0, (size_t)count);
+  if (firing != nullptr) std::fill(firing, firing + count, -1);
+  for (size_t p = 0; p < nparts; ++p) {
+    const Part& q = parts[p];
+    for (int64_t i = 0; i < q.n; ++i) {
+      const uint8_t* row = q.packed + i * q.row_stride;
+      const int64_t at = q.at == nullptr ? i
+                         : q.at_wide     ? ((const int64_t*)q.at)[i]
+                                         : ((const int32_t*)q.at)[i];
+      lv[at] = row[0] & 1;
+      if (lf != nullptr) lf[at] = first_firing(row, q.col_stride, q.E);
+    }
+  }
+  if (fan == nullptr) return;
+  for (int64_t j = 0; j < fan->n_miss; ++j) {
+    verdict[fan->miss_rows[j]] = lv[fan->inverse[j]];
+    if (firing != nullptr) firing[fan->miss_rows[j]] = lf[fan->inverse[j]];
+  }
+  // a cache hit attributes as the evaluation it memoized
+  for (int64_t k = 0; k < fan->n_cached; ++k) {
+    verdict[fan->cached_rows[k]] = (uint8_t)fan->cached_verdict[k];
+    if (firing != nullptr) firing[fan->cached_rows[k]] = fan->cached_firing[k];
+  }
 }
 
 // inserts the ticket's keys in order, each with the verdict (and firing

@@ -369,6 +369,73 @@ class TestNativeRowBytes:
             == 4 * (base + 2 * 6 + 2 + 4)
 
 
+class TestNativeResolveCount:
+    """``resolved_native``: one a cut completed through the native lane's
+    one-call resolve (``fe_resolve_cut``), counted on the cut's lane beside
+    ``batches``; the mesh step and a degraded slot complete the old way and
+    count none.  No server runs, so the completions complete nothing."""
+
+    B, SNAP = 16, 0x5EED_0016
+
+    @pytest.mark.parametrize("path,counted", [
+        ("served", 1), ("cache-only", 1), ("mesh-step", 0), ("degraded", 0)])
+    def test_a_served_cut_counts_one_and_a_degraded_slot_none(self, path,
+                                                              counted):
+        import time
+
+        nf = pytest.importorskip(
+            "authorino_tpu.runtime.native_frontend",
+            reason="native frontend module import needs cryptography")
+        from authorino_tpu.native import load_library
+        from authorino_tpu.native.verdict_cache import (NativeVerdictCache,
+                                                        key_segments,
+                                                        plan_cut)
+
+        lib = load_library()
+        if lib is None:
+            pytest.skip("native library unavailable")
+        B, SNAP = self.B, self.SNAP
+        fe = nf.NativeFrontend(PolicyEngine(max_batch=B, mesh=None), port=0,
+                               max_batch=B, slo_ms=250.0, lane_select=False)
+        fe._mod = lib
+        rec = nf._SnapRec(snap_id=SNAP, policy=None, params=None,
+                          encoder=None)
+        rows = np.arange(B, dtype=np.int32)
+        packed = np.ones((B, 1), dtype=np.uint8)
+        fan, pad, handle = None, B, packed
+        if path == "served":
+            handle = nf._Launched()
+            handle.parts.append((packed, None, B, 0))
+        elif path == "cache-only":
+            cache = NativeVerdictCache(2 * B)
+            keys = rows.view(np.uint8).reshape(B, 4)
+            segs = key_segments([keys])
+            tokens, eligible = np.zeros(B, np.uint64), np.ones(B, bool)
+            warm = plan_cut(cache, segs, B, tokens, eligible, True)
+            cache.commit(warm.ticket, np.ones(B, np.uint8), None)
+            fan = plan_cut(cache, segs, B, tokens, eligible, True)
+            assert len(fan.unique_rows) == 0
+            fe._verdict_cache, handle, pad = cache, nf._Launched(), 0
+        kept = []
+        post = fe._post_complete_telemetry
+        fe._post_complete_telemetry = lambda *a, **k: (
+            kept.append(a[6].tolist()), post(*a, **k))
+        before = LEDGER.snapshot("native")
+        if path == "degraded":
+            fe._degrade_slot(rec, SNAP, 0, B)
+        else:
+            bt = fe.batch_stages.begin(SNAP, 0, B)
+            bt.ready()
+            fe._complete_device_batch(rec, SNAP, 0, B, pad, 0, rows, None,
+                                      handle, time.monotonic(),
+                                      time.time_ns(), fan, 0, bt)
+        d = LEDGER.snapshot("native")
+        assert d["resolved_native"] - before["resolved_native"] == counted
+        # every row allowed, as the readback (or the cache) said; the
+        # degraded slot has no policy and denies fail-closed, keeping none
+        assert kept == ([] if path == "degraded" else [[1] * B])
+
+
 # ---------------------------------------------------------------------------
 # warm-jit-grid audit: the entry points a snapshot can dispatch through,
 # with the operand lanes each stages (PR 1's grid surface, re-pinned)

@@ -394,3 +394,78 @@ def test_a_tenants_first_decision_is_sampled_once_a_fold_with_its_cuts_latency()
             np.unique(cuts[4]["rows"]))
     finally:
         prov_mod.DECISIONS.configure(sample_n=saved)
+
+
+@pytest.mark.parametrize("firing", [True, False], ids=["firing", "verdict"])
+def test_a_cut_resolved_in_one_native_call_keeps_what_the_python_path_kept(
+        firing):
+    """The keep's inputs of a cut completed through `fe_resolve_cut` (a
+    `_Launched` of two size classes, a plan with repeated and cached rows, a
+    cache small enough to evict) equal those the Python path hands it for the
+    same cut (`resolve_cut` over the one readback, then the cache's commit):
+    rows, verdict, firing, and the dedup tuple with its evictions.  Only the
+    native completion counts `resolved_native`."""
+    from authorino_tpu.native import load_library
+    from authorino_tpu.native.verdict_cache import (NativeVerdictCache,
+                                                    key_segments, plan_cut)
+    from authorino_tpu.ops.pattern_eval import packed_width
+
+    lib = load_library()
+    if lib is None:
+        pytest.skip("native library unavailable")
+    rng = np.random.default_rng(45)
+    lanes = []
+    for native in (True, False):
+        fe = _frontend()
+        if native:
+            fe._mod = lib
+        fe._verdict_cache = NativeVerdictCache(24)
+        kept = []
+        fe._post_complete_telemetry = (
+            lambda *a, kept=kept, **k: kept.append((a, k)))
+        lanes.append((fe, _snapshot(f"resolve-{native}", 0x5EED_0035 + native,
+                                    firing=firing), kept))
+    E_w = E if firing else 0
+    resolved = []
+    for _ in range(6):
+        count = int(rng.integers(2, B + 1))
+        ids = rng.integers(0, 40, count)
+        rows = (ids % G).astype(np.int32)
+        keys = np.ascontiguousarray(ids.astype("<u4").view(np.uint8)
+                                    .reshape(count, 4))
+        segs = key_segments([keys])
+        tokens, eligible = np.zeros(count, np.uint64), np.ones(count, bool)
+        plans = [plan_cut(fe._verdict_cache, segs, count, tokens, eligible,
+                          True) for fe, _, _ in lanes]
+        u = len(plans[0].unique_rows)
+        packed = rng.integers(0, 256, (u, packed_width(1 + 2 * E)),
+                              dtype=np.uint8)
+        launched = nf_mod._Launched()
+        cls = rng.integers(0, 2, u)
+        for c in range(2):
+            at = np.nonzero(cls == c)[0]
+            if len(at):
+                launched.parts.append((packed[at], at, len(at), E_w))
+        before = LEDGER.snapshot("native")["resolved_native"]
+        for (fe, rec, _), plan, handle in zip(lanes, plans,
+                                              (launched, packed)):
+            bt = fe.batch_stages.begin(rec.snap_id, 0, count)
+            bt.ready()
+            fe._complete_device_batch(
+                rec, rec.snap_id, 0, count, B if u else 0, 0, rows, None,
+                handle, time.monotonic(),
+                time.time_ns(), plan, 0, bt)
+        resolved.append(LEDGER.snapshot("native")["resolved_native"] - before)
+    (_, _, mine), (_, _, theirs) = lanes
+    assert resolved == [1] * 6 and len(mine) == len(theirs) == 6
+    assert any(k["dedup"][1] for _, k in mine)       # cached rows
+    assert any(k["dedup"][3] for _, k in mine)       # evictions
+    for (a, k), (b, l) in zip(mine, theirs):
+        assert a[1:4] == b[1:4]                      # count, pad, eff
+        assert a[4].tobytes() == b[4].tobytes()      # rows
+        assert a[6].tobytes() == b[6].tobytes()      # verdict
+        assert (k["firing"] is None) == (l["firing"] is None) == (not firing)
+        if firing:
+            assert k["firing"].tobytes() == l["firing"].tobytes()
+        assert k["dedup"] == l["dedup"]
+        assert k["device_rows"] == l["device_rows"]
