@@ -1007,8 +1007,7 @@ def main(argv=None) -> int:
                          "rule).  Exit 1 when any request flips")
     ap.add_argument("--log", metavar="SRC",
                     help="capture log for --replay: a *.atpucap segment "
-                         "file or a capture directory (--capture-log-dir / "
-                         "bench --capture-log)")
+                         "file or a capture directory (--capture-log-dir)")
     ap.add_argument("--replay-budget-s", type=float, default=None,
                     help="optional wall-clock bound for --replay (records "
                          "past it are reported as truncated)")

@@ -237,8 +237,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="Persist captured records as rotated checksummed "
                         "segments (*.atpucap) in this directory, pruned to "
                         "--capture-log-size-mb, readable offline by "
-                        "'analysis --replay OLD NEW --log DIR' and "
-                        "'bench.py --replay-log DIR'.  Implies --capture")
+                        "'analysis --replay OLD NEW --log DIR'.  Implies "
+                        "--capture")
     s.add_argument("--capture-log-size-mb", type=float,
                    default=env_var("CAPTURE_LOG_SIZE_MB", 64.0),
                    help="Capture budget in MB of ENCODED record bytes — "

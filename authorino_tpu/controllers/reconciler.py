@@ -93,8 +93,7 @@ class StatusReportMap:
 
 class AuthConfigReconciler:
     """Translates a full set of AuthConfig resources and swaps the engine
-    snapshot.  Whole-set reconciliation keeps the corpus compile atomic; at
-    1k configs a recompile is tens of milliseconds (bench.py)."""
+    snapshot.  Whole-set reconciliation keeps the corpus compile atomic."""
 
     def __init__(
         self,

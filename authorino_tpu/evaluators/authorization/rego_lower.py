@@ -6,7 +6,7 @@ the TPU kernel: when a policy's ``allow`` reduces to conjunctions /
 disjunctions of string comparisons over the request, the whole verdict
 compiles into the SAME ``ConfigRules`` slots the pattern-matching
 evaluators ride — one kernel matmul decides Rego and patterns together and
-the config keeps the native fast lane (VERDICT r4 item 1).
+the config keeps the native fast lane.
 
 Soundness is the whole game: the lowered expression must agree with the
 interpreter (`rego.RegoModule.evaluate`) on EVERY input, not just typical

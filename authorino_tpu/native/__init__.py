@@ -138,29 +138,4 @@ def load_library():
         return _mod
 
 
-_LOADGEN_PATH = os.path.join(_BUILD_DIR, "loadgen")
-
-
-def build_loadgen():
-    """Build (if stale) the standalone HTTP/2 load generator
-    (native/loadgen.cpp); returns its path or None."""
-    src = os.path.join(_NATIVE_DIR, "loadgen.cpp")
-    try:
-        stale = (not os.path.exists(_LOADGEN_PATH)
-                 or os.path.getmtime(_LOADGEN_PATH) < os.path.getmtime(src))
-    except OSError:
-        stale = True
-    if not stale:
-        return _LOADGEN_PATH
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = ["g++", "-O2", "-std=c++17", src, "-o", _LOADGEN_PATH + ".tmp"]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_LOADGEN_PATH + ".tmp", _LOADGEN_PATH)
-        return _LOADGEN_PATH
-    except (subprocess.SubprocessError, OSError) as e:
-        log.warning("loadgen build failed: %s", e)
-        return None
-
-
 from .encoder import NativeEncoder, get_native_encoder  # noqa: E402,F401

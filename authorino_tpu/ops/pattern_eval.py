@@ -20,8 +20,9 @@ Three bodies, one selection:
 
   - ``eval_own`` is what serves.  Every entry that reads only each row's
     own config (``eval_full_jit`` and, through it, ``eval_packed_jit``,
-    ``eval_bitpacked_jit`` — the served entry — and ``eval_fused_jit``, the
-    engine lane's single-staging-buffer entry) runs it: ONE body that
+    ``eval_bitpacked_jit`` — the host twin's — and the two staged entries,
+    ``eval_bitpacked_staged_jit``, what the native lane serves, and
+    ``eval_fused_jit``, the engine lane's) runs it: ONE body that
     gathers the request's config's row of its SIZE CLASS's per-config
     tables (compiler/compile.py ``SizeClass``: the configs cut into classes
     of like size, each with tables of its own widths, so that a row pays
@@ -130,8 +131,8 @@ __all__ = ["DevicePolicy", "to_device", "eval_verdicts", "eval_own",
            "eval_batch_jit", "kernel_widths", "class_widths", "class_view",
            "has_dfa", "operand_bytes",
            "fuse_layout", "fuse_bytes", "fuse_batch", "eval_fused_jit",
-           "dispatch_fused",
-           "fused_h2d_supported", "eval_bitpacked_jit",
+           "dispatch_fused", "fused_h2d_supported", "require_fused_h2d",
+           "eval_bitpacked_jit",
            "eval_bitpacked_staged_jit", "unpack_verdicts",
            "packed_width", "firing_columns", "unpack_attribution",
            "kernel_lane_of"]
@@ -1155,26 +1156,6 @@ def _extra_operands(db) -> tuple:
         for a in (db.attrs_num, db.num_valid, db.rel_rows, db.member_ovf))
 
 
-def dispatch_packed(params, db, bitpack: bool = False) -> "jax.Array":
-    """Enqueue one compact batch (compiler/pack.py DeviceBatch) without
-    blocking; returns the on-device packed [B, 1+2E] result — or the
-    [B, W] uint8 bitmask with ``bitpack=True`` — for a deferred readback
-    (jax async dispatch = transfer/compute of batch N+1 overlaps the
-    readback of batch N)."""
-    dfa = has_dfa(params)
-    fn = eval_bitpacked_jit if bitpack else eval_packed_jit
-    return fn(
-        params,
-        jnp.asarray(db.attrs_val),
-        jnp.asarray(db.members_c),
-        jnp.asarray(db.cpu_dense),
-        jnp.asarray(db.config_id),
-        jnp.asarray(db.attr_bytes) if dfa else None,
-        jnp.asarray(db.byte_ovf) if dfa else None,
-        *_extra_operands(db),
-    )
-
-
 # ---------------------------------------------------------------------------
 # fused H2D staging: ONE host→device transfer per micro-batch
 # ---------------------------------------------------------------------------
@@ -1187,9 +1168,9 @@ def dispatch_packed(params, db, bitpack: bool = False) -> "jax.Array":
 # operands back out INSIDE the jitted kernel (static layout → static slices;
 # the decode is free relative to the transfer it replaces).
 #
-# Bitcast byte order must match numpy's little-endian view; _fused_probe
-# verifies the round trip once per process and the engine falls back to
-# per-operand transfers if the backend disagrees (big-endian hosts).
+# Bitcast byte order must match numpy's little-endian view;
+# fused_h2d_supported verifies the round trip once per process, and where
+# the backend disagrees (a big-endian host) neither lane launches at all.
 
 _FUSED_FIELDS = ("attrs_val", "members_c", "cpu_dense", "config_id",
                  "attr_bytes", "byte_ovf", "attrs_num", "num_valid",
@@ -1229,8 +1210,8 @@ def fuse_batch(db) -> Tuple[np.ndarray, tuple]:
 def staged_h2d_bytes(db) -> int:
     """Exact request-operand bytes one launch of ``db`` stages host-to-
     device — the fused staging buffer size (sum of nbytes over the present
-    _FUSED_FIELDS), identical for the per-operand fallback path.  Pure
-    shape arithmetic for the kernel-cost ledger: no copy, no fuse."""
+    _FUSED_FIELDS).  Pure shape arithmetic for the kernel-cost ledger: no
+    copy, no fuse."""
     total = 0
     for name in _FUSED_FIELDS:
         arr = getattr(db, name)
@@ -1299,8 +1280,7 @@ def _defuse_probe(buf, layout):
 
 def fused_h2d_supported() -> bool:
     """One-time probe that the backend's bitcast byte order matches numpy's
-    view (little-endian); the engine degrades to per-operand transfers —
-    never to wrong answers — when it does not."""
+    view (little-endian), which ``_defuse`` decodes a staged buffer by."""
     global _FUSED_OK
     if _FUSED_OK is None:
         try:
@@ -1318,18 +1298,24 @@ def fused_h2d_supported() -> bool:
     return _FUSED_OK
 
 
+def require_fused_h2d() -> None:
+    """Refuse to launch where the probe failed: a staged buffer would decode
+    to wrong operands, and no other launch format serves."""
+    if not fused_h2d_supported():
+        raise RuntimeError(
+            "the backend's bitcast byte order is not numpy's little-endian "
+            "view, which a staged launch buffer is decoded by")
+
+
 def dispatch_fused(params, db) -> "jax.Array":
     """Non-blocking launch of one compact batch with a single fused H2D
-    transfer (falling back to per-operand transfers when the backend's
-    bitcast disagrees with numpy byte order).  The result is the BIT-PACKED
-    [B, W] uint8 readback (decode with ``unpack_verdicts``); the device→
-    host copy starts eagerly so a later np.asarray only waits, never
-    initiates."""
-    if fused_h2d_supported():
-        buf, layout = fuse_batch(db)
-        out = eval_fused_jit(params, jnp.asarray(buf), layout)
-    else:
-        out = dispatch_packed(params, db, bitpack=True)
+    transfer; raises where the backend's bitcast byte order failed the
+    probe.  The result is the BIT-PACKED [B, W] uint8 readback (decode with
+    ``unpack_verdicts``); the device→host copy starts eagerly so a later
+    np.asarray only waits, never initiates."""
+    require_fused_h2d()
+    buf, layout = fuse_batch(db)
+    out = eval_fused_jit(params, jnp.asarray(buf), layout)
     try:
         out.copy_to_host_async()
     except Exception:

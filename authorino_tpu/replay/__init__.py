@@ -1,6 +1,6 @@
 """Traffic replay & what-if preflight (ISSUE 13, docs/replay.md).
 
-Four layers composing PR 8 (loadable snapshots), PR 9 (decision/attribution
+Three layers composing PR 8 (loadable snapshots), PR 9 (decision/attribution
 provenance) and PR 10 (canary guards) into "test a policy change against
 yesterday's traffic with zero live exposure":
 
@@ -10,9 +10,7 @@ yesterday's traffic with zero live exposure":
   against two snapshots through the exact host oracle, flips grouped by
   (authconfig, rule) via provenance attribution;
 - :mod:`.pregate`   — the reconcile preflight gate: a diff breaching the
-  canary guard thresholds rejects the swap BEFORE the canary window;
-- :mod:`.bench_load` — captured arrivals as bench.py's open-loop
-  timetable (``--replay-log``).
+  canary guard thresholds rejects the swap BEFORE the canary window.
 
 Only the import-light capture surface is re-exported here; the replay /
 pregate layers import the host oracle (and with it jax) — pull them in

@@ -52,8 +52,8 @@ __all__ = ["CAPTURE", "CaptureLog", "CaptureFormatError", "CAPTURE_SCHEMA",
 log = logging.getLogger("authorino_tpu.replay.capture")
 
 # capture record schema: bumped whenever the per-record field set changes,
-# so offline readers (analysis --replay, bench --replay-log) can refuse
-# version-skewed logs with a typed error instead of misparsing.
+# so offline readers (analysis --replay) can refuse version-skewed logs
+# with a typed error instead of misparsing.
 # v2 (ISSUE 14): + metadata_doc_digest — the combined digest of the
 # prefetch cache's pinned metadata documents the decision evaluated under
 # (None for configs with no pinned metadata), making metadata-dependent

@@ -1,13 +1,13 @@
 """Reconcile preflight gate (ISSUE 13 layer 3): decide a snapshot swap's
 fate on REPLAYED traffic before any live request sees the candidate.
 
-PR 10's canary detects a poison config after ~0.7–1.8 s of live exposure
-(BENCH_r08's measured detection latency): real requests are served wrong
-answers until the guard accumulates evidence.  The pregate moves that
-evidence window to zero live exposure — the candidate snapshot is replayed
-against the in-process capture ring (replay/capture.py) and the verdict
-diff is judged against the SAME :class:`GuardThresholds` the canary would
-apply, mapped onto replay semantics:
+PR 10's canary detects a poison config only after live exposure: real
+requests are served wrong answers until the guard accumulates evidence.
+The pregate moves that evidence window to zero live exposure — the
+candidate snapshot is replayed against the in-process capture ring
+(replay/capture.py) and the verdict diff is judged against the SAME
+:class:`GuardThresholds` the canary would apply, mapped onto replay
+semantics:
 
 - ``deny_delta``     → net replayed deny-rate delta ((newly-denied −
   newly-allowed) / replayed) AND the total flip rate (a change that flips

@@ -3,16 +3,15 @@
 Both serving lanes (the asyncio engine in runtime/engine.py and the C++
 device-owner frontend in runtime/native_frontend.py) call into this module
 at three points of every micro-batch — encode, kernel launch (covers the
-H2D enqueue), and readback — so tests, ``bench.py --chaos`` and a
-``--fault-profile`` server run can make any stage raise, hang, or slow
-down per batch, deterministically, without touching the device code.
+H2D enqueue), and readback — so tests and a ``--fault-profile`` server
+run can make any stage raise, hang, or slow down per batch,
+deterministically, without touching the device code.
 
 Zero-cost when off: hot paths gate every hook on the module-level
 ``ACTIVE`` flag (one attribute read per batch); nothing else of this
 module runs until ``FAULTS.arm()`` flips it.
 
-Spec grammar (also accepted by AUTHORINO_TPU_FAULTS / --fault-profile /
-bench --chaos)::
+Spec grammar (also accepted by AUTHORINO_TPU_FAULTS / --fault-profile)::
 
     spec  := profile | rule (";" rule)*
     rule  := stage ":" mode [":" key=value]*

@@ -8,7 +8,7 @@ Two planes:
    sharded, native ``_dispatch``, mesh shard-steps, and the host/brownout/
    degrade CPU evals).  Counts the things wall clock cannot swing:
    device-computation launches (the number item 2 must drive to 1 per
-   batch), H2D bytes (fused staging buffer / per-operand upload sizes —
+   batch), H2D bytes (the fused staging buffer's size —
    snapshot upload traffic stays on the PR 8 ``delta/full_upload_bytes``
    counters so the two planes compose instead of double-counting), D2H
    bytes (the PR 3 bitpacked ``[pad, W]`` readback), pad waste (padded −
@@ -277,13 +277,11 @@ def modeled_entry_cost(entry: str, fn, args: tuple, pad: int,
     }
 
 
-def _bitpacked_zero_args(policy, params, pad: int, eff: int,
-                         n_cpu: Optional[int] = None) -> tuple:
+def _bitpacked_zero_args(policy, params, pad: int, eff: int) -> tuple:
     """Throwaway zero operands for eval_bitpacked_jit at one (pad, eff)
     bucket, shapes only (PR 14 operand tail rides on the params'
-    structural Nones): what the cost model lowers it with, and the native
-    lane's warm grid where it compiles the six-operand entry.  ``n_cpu``:
-    the CPU columns staged (a size class's c_own; None: the corpus's)."""
+    structural Nones): what the cost model lowers it with, and what the
+    native lane's host twin warms its CPU variants with."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -292,7 +290,7 @@ def _bitpacked_zero_args(policy, params, pad: int, eff: int,
 
     dt = wire_dtype(policy)
     A, M, K = policy.n_attrs, policy.n_member_attrs, policy.members_k
-    C = policy.n_own_cpu if n_cpu is None else n_cpu
+    C = policy.n_own_cpu
     NB = max(policy.n_byte_attrs, 1)
     return (
         params,
@@ -344,16 +342,15 @@ def entry_points(policy=None, sharded=None) -> List[Dict[str, Any]]:
         })
         out.append({
             "entry": "eval_fused",
-            "kind": "single fused H2D staging buffer (same compute as "
-                    "eval_bitpacked; per-operand fallback when the "
-                    "backend bitcast probe fails)",
+            "kind": "the engine lane's entry: one fused H2D staging "
+                    "buffer (same compute as eval_bitpacked)",
             "operands": ops,
         })
         out.append({
             "entry": "eval_bitpacked_staged",
             "kind": "the native lane's served entry: one staged uint8 "
                     "buffer decoded on the device (same compute as "
-                    "eval_bitpacked, which serves when the probe fails)",
+                    "eval_bitpacked, the host twin's entry)",
             "operands": ops,
         })
     return out

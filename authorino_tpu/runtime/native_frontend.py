@@ -1290,10 +1290,8 @@ class NativeFrontend:
             return None
         out = {"lane": kernel_lane_of(rec.params),
                # the jitted function a launch runs, which a device trace
-               # finds the served XLA module by: the staged entry, or the
-               # six-operand one where the staging probe failed
-               "entry": ("eval_bitpacked_staged" if rec.layouts
-                         else "eval_bitpacked"),
+               # finds the served XLA module by
+               "entry": "eval_bitpacked_staged",
                # bytes of the serving snapshot's device operands, summed
                # over the uploaded pytree
                "operand_bytes": operand_bytes(rec.params),
@@ -1494,8 +1492,7 @@ class NativeFrontend:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pattern_eval import (eval_bitpacked_jit,
-                                        eval_bitpacked_staged_jit)
+        from ..ops.pattern_eval import eval_bitpacked_staged_jit
 
         if rec.sharded is not None:
             from ..parallel.sharded_eval import _ShardedEncoded
@@ -1527,19 +1524,13 @@ class NativeFrontend:
             # width)); a shape met again costs one launch of zeros
             eff_c = min(eff, rec.classes[c]["device_width"])
             layout = self._stage_layout(rec, c, pad, eff_c)
-            if layout is not None:
-                size = layout[-1][3] + layout[-1][4]
-                fn = eval_bitpacked_staged_jit
-                args = (view, jnp.asarray(np.zeros(size, dtype=np.uint8)),
-                        layout)
-            else:
-                fn = eval_bitpacked_jit
-                args = _bitpacked_zero_args(rec.policy, view, pad, eff_c,
-                                            n_cpu=rec.classes[c]["cpu_cols"])
+            size = layout[-1][3] + layout[-1][4]
+            args = (view, jnp.asarray(np.zeros(size, dtype=np.uint8)), layout)
             if first:
-                rec.launch_temp_bytes = max(rec.launch_temp_bytes,
-                                            launch_temp_bytes(fn, *args))
-            out = fn(*args)
+                rec.launch_temp_bytes = max(
+                    rec.launch_temp_bytes,
+                    launch_temp_bytes(eval_bitpacked_staged_jit, *args))
+            out = eval_bitpacked_staged_jit(*args)
             jax.block_until_ready(out)
         rec.warm.add((pad, eff))
 
@@ -1563,21 +1554,22 @@ class NativeFrontend:
         return views
 
     @staticmethod
-    def _stage_layout(rec: _SnapRec, c: int, pad: int,
-                      eff: int) -> Optional[tuple]:
+    def _stage_layout(rec: _SnapRec, c: int, pad: int, eff: int) -> tuple:
         """The static layout of the staging buffer of one single-corpus
         launch of size class ``c`` at bucket (pad, eff): the slot arrays'
         operands, [:pad] rows each, ``cpu_dense`` cut to the class's columns
         and ``attr_bytes`` to ``eff``, end to end in the order the served
         entry decodes them (``eff`` 0 = no DFA operands).  A function of the
         snapshot's operand shapes and wire dtype alone, built once per
-        snapshot, class and bucket.  None where the backend's byte order
-        failed the one-time probe: the six transfers remain."""
+        snapshot, class and bucket.  Raises where the backend's byte order
+        failed the one-time probe: the swap gate's warm then fails, and so
+        does any launch."""
         from ..ops.pattern_eval import (_FUSED_FIELDS, fuse_layout,
-                                        fused_h2d_supported)
+                                        require_fused_h2d)
 
         layout = rec.layouts.get((c, pad, eff))
-        if layout is None and fused_h2d_supported():
+        if layout is None:
+            require_fused_h2d()
             # the operands' own dtypes and row shapes, under the names the
             # entry decodes them by (its first four, or six, in order)
             views = NativeFrontend._operand_views(
@@ -1923,8 +1915,8 @@ class NativeFrontend:
             # ShapeTargets-unified operand shapes, so the C++ encoder writes
             # each request into its owning shard's [B, S, ...] slice and the
             # dispatcher feeds the shard_map step directly — multi-device
-            # scaling and the native frontend compose (VERDICT r3 missing #2;
-            # the reference's sharding composes with its full server,
+            # scaling and the native frontend compose (the reference's
+            # sharding composes with its full server,
             # ref controllers/label_selector.go:14-45)
             from ..native.encoder import get_native_encoder
 
@@ -2653,8 +2645,7 @@ class NativeFrontend:
         pad rows launched in all, the widest byte bucket)."""
         import jax.numpy as jnp
 
-        from ..ops.pattern_eval import (eval_bitpacked_jit,
-                                        eval_bitpacked_staged_jit, fuse_bytes)
+        from ..ops.pattern_eval import eval_bitpacked_staged_jit, fuse_bytes
 
         cfg = rows if unique_rows is None else rows[unique_rows]
         u = cfg.shape[0]
@@ -2703,17 +2694,11 @@ class NativeFrontend:
                 # decodes them on the device), handed to the runtime here,
                 # in one transfer, so that `launch` times the jitted call
                 # alone
-                operands = self._operand_views(a, idx, eff, width["cpu_cols"])
                 layout = self._stage_layout(rec, c, pad, eff)
-                if layout is not None:
-                    operands = [fuse_bytes(operands)]
-                operands = [jnp.asarray(o) for o in operands]
+                staged = jnp.asarray(fuse_bytes(self._operand_views(
+                    a, idx, eff, width["cpu_cols"])))
             with bt.stage("launch"):
-                if layout is not None:
-                    packed = eval_bitpacked_staged_jit(
-                        rec.views[c], *operands, layout)
-                else:
-                    packed = eval_bitpacked_jit(rec.views[c], *operands)
+                packed = eval_bitpacked_staged_jit(rec.views[c], staged, layout)
                 if faults.ACTIVE:
                     packed = faults.FAULTS.wrap_handle(packed, "native")
                 try:
@@ -2726,7 +2711,7 @@ class NativeFrontend:
                 # [pad, W] readback; eff-column slack is the warm-shape
                 # round-up (eff - eff_need); what the launch scanned: pad
                 # rows x the class's D
-                tally["h2d_transfers"] += len(operands)
+                tally["h2d_transfers"] += 1
                 tally["h2d_bytes"] += pad * self._row_h2d_bytes(
                     a, eff, width["cpu_cols"])
                 tally["d2h_bytes"] += int(packed.shape[0]) * int(packed.shape[1])

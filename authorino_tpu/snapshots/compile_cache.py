@@ -52,7 +52,7 @@ class ConfigArtifact:
 @dataclass
 class CompileReport:
     """What one incremental compile actually did (the churn evidence that
-    lands on /debug/vars, the reconcile metrics, and bench --churn)."""
+    lands on /debug/vars and the reconcile metrics)."""
 
     total: int = 0            # rules-bearing configs in the corpus
     compiled: int = 0         # artifacts built this reconcile (cache misses)

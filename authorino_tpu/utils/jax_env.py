@@ -1,6 +1,5 @@
 """Process-level JAX set-up, shared by every entry point that initialises
-JAX (cli.run_server, bench.py / bench_micro.py main, runtime/
-restart_harness.py): the persistent compilation cache, the CPU backend the
+JAX (cli.run_server, runtime/restart_harness.py): the persistent compilation cache, the CPU backend the
 native lane's host twin needs next to the accelerator, and the facts about
 the backend that /debug/vars reports: what it is, how long the process took
 to bring it up and to be ready behind it, and what each device holds.

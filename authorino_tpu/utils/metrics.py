@@ -772,7 +772,7 @@ watchdog_timeouts = _counter(
 injected_faults = _counter(
     "auth_server_injected_faults_total",
     "Faults fired by the injection plane (runtime/faults.py) — non-zero "
-    "only under --fault-profile / bench --chaos / tests.",
+    "only under --fault-profile / tests.",
     ("stage", "mode", "lane"),
 )
 
@@ -1262,8 +1262,8 @@ kernel_launches = _counter(
 )
 kernel_h2d_bytes = _counter(
     "auth_server_kernel_h2d_bytes_total",
-    "Request-operand bytes staged host-to-device per lane (the fused "
-    "staging buffer / per-operand upload sizes of each launch).  Snapshot "
+    "Request-operand bytes staged host-to-device per lane (the size of "
+    "each launch's fused staging buffer).  Snapshot "
     "upload traffic is accounted separately by "
     "auth_server_{delta,full}_upload_bytes_total — together the two give "
     "total H2D.",

@@ -1,11 +1,13 @@
 """BENCHMARK.json and the files it names, held in tier-1 (benchmark/tests is
-outside it): the manifest's own checker finds no problem on the tree, and the
-newest configuration's generator, reference and the program's host expression
-oracle agree on a seeded sample of its rows."""
+outside it): the manifest's own checker finds no problem on the tree, the
+generators, the reference and the program's host expression oracle agree on
+seeded samples of their rows, and one cell, cut to a tiny size, serves end
+to end on the CPU through the harness the benchmark runs."""
 
 import os
 import random
 import sys
+import time
 
 import pytest
 
@@ -19,11 +21,40 @@ import harness  # noqa: E402
 import manifest_check  # noqa: E402
 from reference import OK, PERMISSION_DENIED, Reference  # noqa: E402
 
-import chip_smoke  # noqa: E402
-
 
 def _manifest():
     return harness._load_json(os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def _oracle_verdicts(corpus, table):
+    """Every request decided by the host expression oracle, from the
+    manifests as written (not from the generator's intent): the rules of
+    the request's AuthConfig AND-ed over the authorization JSON the server
+    would build for it."""
+    from authorino_tpu.authjson import (
+        CheckRequestModel,
+        HttpRequestAttributes,
+        build_authorization_json,
+    )
+    from authorino_tpu.expressions import All, Operator, Pattern
+
+    rules = []
+    for manifest in corpus:
+        pats = manifest["spec"]["authorization"]["rules"][
+            "patternMatching"]["patterns"]
+        rules.append(All(*[
+            Pattern(p["selector"], Operator.from_string(p["operator"]),
+                    p["value"]) for p in pats]))
+    out = []
+    for req in table:
+        headers = dict(req["headers"], host=req["host"])
+        doc = build_authorization_json(
+            CheckRequestModel(http=HttpRequestAttributes(
+                method=req["method"], path=req["path"], host=req["host"],
+                headers=headers)),
+            {"identity": {"anonymous": True}})
+        out.append(bool(rules[req["config"]].matches(doc)))
+    return out
 
 
 def test_manifest_check_finds_no_problem_on_the_tree():
@@ -61,12 +92,101 @@ def test_tenants_10k_rows_agree_with_reference_and_host_oracle():
     by_host = {m["spec"]["hosts"][0]: i for i, m in enumerate(manifests)}
     reference = Reference(manifests)
     answers = [reference.decide(r) for r in rows]
-    oracle = chip_smoke.oracle_verdicts(
+    oracle = _oracle_verdicts(
         manifests, [dict(r, config=by_host[r["host"]]) for r in rows])
     assert answers == [OK if allow else PERMISSION_DENIED for allow in oracle]
     # half of the rows break one rule; hosts spread over the whole corpus
     assert 0.4 < answers.count(PERMISSION_DENIED) / len(rows) < 0.6
     assert len({r["host"] for r in rows}) > 450
+
+
+def _tenant_rules():
+    return harness.load_module("corpora", "tenant_rules")
+
+
+def _with_config(rows):
+    """The generator's rows with the index of the config their host names."""
+    return [dict(r, config=int(r["host"].split(".")[0][4:])) for r in rows]
+
+
+def test_tenant_rules_rows_are_deterministic_under_seed():
+    gen = _tenant_rules()
+    manifests = gen.manifests({"n_configs": 8})
+    assert manifests == gen.manifests({"n_configs": 8})
+    params = {"n_configs": 8, "deny_share": 0.5}
+    rows = gen.requests(params, 64, random.Random(7))
+    assert rows == gen.requests(params, 64, random.Random(7))
+    assert rows != gen.requests(params, 64, random.Random(8))
+    want = _oracle_verdicts(manifests, _with_config(rows))
+    # the table holds both verdicts, and every config per-config regexes
+    # and a membership leaf
+    assert 0 < sum(want) < len(want)
+    for manifest in manifests:
+        ops = [p["operator"] for p in manifest["spec"]["authorization"][
+            "rules"]["patternMatching"]["patterns"]]
+        assert len(ops) == 10
+        assert ops.count("matches") == 2 and "incl" in ops
+    regexes = {p["value"] for i in range(8) for p in gen._patterns(i)
+               if p["operator"] == "matches"}
+    assert len(regexes) == 16  # two per config, all distinct
+
+
+@pytest.mark.parametrize("key", [k for k, _ in _tenant_rules()._VIOLATIONS])
+def test_each_violation_breaks_exactly_its_rule(key):
+    """The oracle, not the generator's intent, decides: the way to break a
+    request named ``key`` flips the verdict to deny, and of the config's ten
+    rules it breaks the one in its own place and no other."""
+    gen = _tenant_rules()
+    index = [k for k, _ in gen._VIOLATIONS].index(key)
+    breaker = gen._VIOLATIONS[index][1]
+    corpus = gen.manifests({"n_configs": 3})
+    rng = random.Random(1)
+    for i in range(3):
+        vals = gen._allowed(i, rng)
+        ok = dict(vals)
+        vals[key] = breaker(i, vals)
+
+        def row(v):
+            v = dict(v)
+            return {"config": 0, "host": gen._host(i), "method": v.pop("method"),
+                    "path": v.pop("path"), "headers": v}
+
+        assert _oracle_verdicts(corpus[i:i + 1], [row(ok), row(vals)]) == \
+            [True, False], (i, key)
+        # one rule a corpus: the broken row fails the key's own rule alone
+        patterns = corpus[i]["spec"]["authorization"]["rules"][
+            "patternMatching"]["patterns"]
+        alone = [{"spec": {"authorization": {"rules": {"patternMatching": {
+            "patterns": [p]}}}}} for p in patterns]
+        failed = [j for j in range(len(alone)) if _oracle_verdicts(
+            alone, [dict(row(vals), config=j)]) == [False]]
+        assert failed == [index], (i, key)
+
+
+def test_tiny_cell_serves_end_to_end_on_cpu(tmp_path, monkeypatch):
+    """The harness the benchmark runs, on a tenth of a second of its
+    yardstick: `tenants-1k.unique-sat` cut to 20 configs, served for two
+    seconds by the CLI's child over loopback HTTP/2 on the CPU, every
+    answer compared with the reference.  One device for the child, as on
+    the chip (this directory's conftest forces eight, which would shard the
+    corpus), so the staged entry serves and a launch is one transfer."""
+    flags = os.environ.get("XLA_FLAGS", "").replace(
+        "--xla_force_host_platform_device_count=8", "").strip()
+    monkeypatch.setenv("XLA_FLAGS", flags)
+    cell = harness.load_cell(_manifest(), ROOT, "tenants-1k.unique-sat")
+    cell["config_file"]["params"]["n_configs"] = 20
+    cell["mix"].update(distinct_rows=4096, warm_s=1.0)
+    result = harness.run(cell, ROOT, 2**31 + 5, 2.0, False, "cpu",
+                         time.monotonic(), out_root=str(tmp_path))
+    assert result["correct"] and result["failed"] == 0 < result["attempted"]
+    assert result["compared"]["wrong"]["value"] == 0
+    assert result["compared"]["unanswered"]["value"] == 0
+    assert result["device"]["platform"] == "cpu"
+    assert set(result["metrics"]) == {"checks_per_s", "setup_s"}
+    fe = result["evidence"]["vars"]["native_frontend"]
+    assert fe["snapshot"]["kernel"]["entry"] == "eval_bitpacked_staged"
+    native = fe["kernel_cost"]["ledger"]["native"]
+    assert native["h2d_transfers"] == native["launches"] > 0
 
 
 def test_routes_1k_cell_is_as_the_issue_states_and_its_files_agree():

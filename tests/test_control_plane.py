@@ -535,7 +535,7 @@ class TestWatchBookmarksAndStorms:
         """A storm of watch drops / 410s / stale re-lists while requests
         are being served: the index must end EXACTLY at the final apiserver
         state (no missed deletes, no zombies), readiness must hold, and
-        every concurrent Check() must answer (VERDICT r3 next #9)."""
+        every concurrent Check() must answer."""
         from authorino_tpu.controllers.sources import K8sWatchSource
 
         async def body():

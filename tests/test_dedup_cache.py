@@ -42,6 +42,18 @@ def run(coro):
         loop.close()
 
 
+def _packed(params, db, bitpack=False):
+    """One DeviceBatch through the host twin's entries: eval_packed_jit's
+    [B, 1+2E] bool, or eval_bitpacked_jit's [B, W] uint8 bitmask."""
+    dfa = pe.has_dfa(params)
+    fn = pe.eval_bitpacked_jit if bitpack else pe.eval_packed_jit
+    return fn(params, jnp.asarray(db.attrs_val), jnp.asarray(db.members_c),
+              jnp.asarray(db.cpu_dense), jnp.asarray(db.config_id),
+              jnp.asarray(db.attr_bytes) if dfa else None,
+              jnp.asarray(db.byte_ovf) if dfa else None,
+              *pe._extra_operands(db))
+
+
 def _corpus(n_configs=9):
     rng = random.Random(3)
     configs = []
@@ -108,7 +120,7 @@ def test_dedup_evaluate_scatter_bit_identical(dup_fraction, seed):
             else [rng.randrange(policy.n_configs) for _ in range(n)])
     db = pack_batch(policy, encode_batch_py(policy, docs, rows, batch_pad=pad))
 
-    reference = np.asarray(pe.dispatch_packed(params, db))[:n]  # [n, 1+2E]
+    reference = np.asarray(_packed(params, db))[:n]  # [n, 1+2E]
 
     keys = batch_row_keys(db, n)
     unique_rows, inverse = dedup_rows(keys, list(range(n)))
@@ -118,7 +130,7 @@ def test_dedup_evaluate_scatter_bit_identical(dup_fraction, seed):
     if dup_fraction == 0.0:
         assert u == n
     db_u = select_rows(db, unique_rows, batch_pad=u + (-u % 16))
-    packed_u = np.asarray(pe.dispatch_packed(params, db_u))
+    packed_u = np.asarray(_packed(params, db_u))
     scattered = packed_u[inverse]  # fan unique verdicts back out
     np.testing.assert_array_equal(scattered, reference)
 
@@ -422,8 +434,8 @@ def test_dedup_beats_full_evaluation_on_90pct_duplicates():
     db_u = select_rows(db, unique_rows, batch_pad=bucket_pow2(u))
 
     # warm both jit variants off the clock
-    np.asarray(pe.dispatch_packed(params, db, bitpack=True))
-    np.asarray(pe.dispatch_packed(params, db_u, bitpack=True))
+    np.asarray(_packed(params, db, bitpack=True))
+    np.asarray(_packed(params, db_u, bitpack=True))
 
     def best_of(fn, runs=5):
         best = float("inf")
@@ -434,9 +446,9 @@ def test_dedup_beats_full_evaluation_on_90pct_duplicates():
         return best
 
     t_full = best_of(lambda: np.asarray(
-        pe.dispatch_packed(params, db, bitpack=True)))
+        _packed(params, db, bitpack=True)))
     t_dedup = best_of(lambda: (
-        np.asarray(pe.dispatch_packed(params, db_u, bitpack=True))[inverse]))
+        np.asarray(_packed(params, db_u, bitpack=True))[inverse]))
     assert t_dedup < t_full, (
         f"dedup path ({t_dedup * 1e3:.2f}ms, {u} rows) not faster than "
         f"full evaluation ({t_full * 1e3:.2f}ms, {n} rows)")

@@ -880,8 +880,8 @@ class TestRegoBuiltinsRound3:
 
 class TestRegoRound4:
     """walk(), `with` function/builtin mocking, multi-module composition
-    (the round-3 fail-closed rejections, now implemented — VERDICT r3
-    missing #3; the reference evaluates these via embedded OPA,
+    (the round-3 fail-closed rejections, now implemented; the reference
+    evaluates these via embedded OPA,
     ref pkg/evaluators/authorization/opa.go:86-141)."""
 
     def test_walk_relation(self):

@@ -162,8 +162,8 @@ def build_engine() -> PolicyEngine:
         Pattern("request.method", Operator.NEQ, "DELETE")))
     # fast (round 5): patternMatching + decidable inline Rego in ONE config —
     # the Rego verdict lowers into a kernel slot (rego_lower) so the mixed
-    # config keeps the fast lane (VERDICT r4 item 1; the reference runs OPA
-    # inline at full server speed, ref pkg/evaluators/authorization/opa.go:86-117)
+    # config keeps the fast lane (the reference runs OPA inline at full
+    # server speed, ref pkg/evaluators/authorization/opa.go:86-117)
     opa = OPA("ns/fast-rego/rego", inline_rego=(
         'allow { input.request.method == "GET" }\n'
         'allow { input.request.headers["x-root"] == "true" }'))
@@ -1521,7 +1521,7 @@ def test_mtls_fast_lane_cert_cache():
 def test_slow_lane_no_head_of_line_blocking():
     """A straggling slow-lane request (slow metadata backend) must not
     delay unrelated slow-lane requests queued behind it: admission is
-    continuous, not batch-gather convoys (VERDICT r3 weak #7)."""
+    continuous, not batch-gather convoys."""
     import concurrent.futures
 
     from authorino_tpu.evaluators import MetadataConfig
@@ -1669,7 +1669,7 @@ def test_fast_lane_classification(stack):
 
 def test_lowered_rego_rides_fast_lane(stack):
     """Mixed pattern+Rego traffic must be served natively — zero slow-lane
-    handoffs for the lowered config (BASELINE class 5, VERDICT r4 item 1)."""
+    handoffs for the lowered config (BASELINE class 5)."""
     _, fe, native_port, _ = stack
     before = fe.stats()
     for hdrs, method in [({"x-tier": "gold"}, "GET"),
@@ -1685,7 +1685,7 @@ def test_lowered_rego_rides_fast_lane(stack):
 
 def test_prewarm_covers_bucket_grid(stack):
     """Every (batch_pad, byte_eff) jit variant compiles off the serving
-    path at swap time (VERDICT r3 weak #1)."""
+    path at swap time."""
     _, fe, _, _ = stack
     assert fe.wait_warm(180)
     with fe._lock:
@@ -1766,7 +1766,7 @@ def test_swap_under_load_never_compiles_on_live_requests(stack):
     warmed jit variants only: the previous snapshot serves until the new
     one's largest bucket is compiled, then dispatch rounds up to warmed
     shapes.  A pick outside rec.warm would be an inline XLA compile on a
-    live request — the exact source of BENCH_r03 trial 1's 3.3s p99."""
+    live request, a latency spike on whatever it serves."""
     engine, fe, native_port, _ = stack
     assert fe.wait_warm(180)
     base_entries = list(engine._snapshot.by_id.values())
@@ -2065,9 +2065,8 @@ def test_hostile_wire_input(stack):
 
 def test_duration_and_stage_histograms(stack):
     """The fast lane must feed the SAME duration series the pipeline
-    observes (auth_server_authconfig_duration_seconds; VERDICT r3 weak #4)
-    plus the on-box stage histograms (enqueue→flush→complete→respond;
-    VERDICT r3 missing #4: a latency artifact, not an argument)."""
+    observes (auth_server_authconfig_duration_seconds) plus the on-box
+    stage histograms (enqueue→flush→complete→respond)."""
     _, fe, native_port, _ = stack
     for _ in range(40):
         grpc_call(native_port, make_req("fast-eq.test", headers={"x-org": "acme"}))
@@ -2095,7 +2094,7 @@ def test_observe_bucketed_fallback_preserves_shape():
     """If prometheus_client internals (`_buckets`/`_sum`) ever vanish, the
     fallback must keep per-bucket counts (incl. +Inf overflow binned ABOVE
     the last finite bound) and land the exact drained sum — not collapse to
-    one mean observation (ADVICE r4)."""
+    one mean observation."""
     from authorino_tpu.utils import metrics as metrics_mod
 
     class FakeChild:

@@ -427,13 +427,14 @@ def test_fused_h2d_staging_matches_per_operand_path(corpus):
     from authorino_tpu.expressions import All, Any_
     from authorino_tpu.ops.pattern_eval import (
         _FUSED_FIELDS,
-        dispatch_packed,
         eval_fused_jit,
+        eval_packed_jit,
         fuse_batch,
         fused_h2d_supported,
         to_device,
         unpack_verdicts,
     )
+    from test_own_config_eval import _operands
 
     assert fused_h2d_supported()  # little-endian bitcast probe
     if corpus == "one-rule":
@@ -462,7 +463,7 @@ def test_fused_h2d_staging_matches_per_operand_path(corpus):
     params = to_device(policy)
     enc = encode_batch(policy, docs, rows, batch_pad=16)
     db = pack_batch(policy, enc)
-    reference = np.asarray(dispatch_packed(params, db))
+    reference = np.asarray(eval_packed_jit(params, *_operands(db)))
     assert reference[:len(docs), 0].any() and not reference[:len(docs), 0].all()
     buf, layout = fuse_batch(db)
     assert buf.dtype == np.uint8 and buf.ndim == 1  # ONE staging buffer
@@ -476,3 +477,35 @@ def test_fused_h2d_staging_matches_per_operand_path(corpus):
     E = int(policy.eval_rule.shape[1])
     assert fused.shape[1] == (1 + 2 * E + 7) // 8
     assert np.array_equal(reference, unpack_verdicts(fused, 1 + 2 * E))
+
+
+def test_engine_dispatch_refuses_a_failed_probe(monkeypatch):
+    """Where the backend's byte order fails the one-time probe the engine
+    lane's launch raises, naming it, and no entry runs: a staged buffer
+    would decode to wrong operands, and no other launch format serves."""
+    from authorino_tpu.compiler.compile import compile_corpus
+    from authorino_tpu.compiler.encode import encode_batch
+    from authorino_tpu.compiler.pack import pack_batch
+    from authorino_tpu.ops import pattern_eval as pe
+
+    policy = compile_corpus([ConfigRules(name="c", evaluators=[(None, Pattern(
+        "request.method", Operator.EQ, "GET"))])], members_k=4)
+    params = pe.to_device(policy)
+    db = pack_batch(policy, encode_batch(
+        policy, [{"request": {"method": m}} for m in ("GET", "POST")], [0, 0],
+        batch_pad=16))
+    ran = []
+    for name in ("eval_fused_jit", "eval_packed_jit", "eval_bitpacked_jit"):
+        real = getattr(pe, name)
+        monkeypatch.setattr(pe, name, lambda *a, _f=real, _n=name, **k: (
+            ran.append(_n), _f(*a, **k))[1])
+    with monkeypatch.context() as m:
+        m.setattr(pe, "_FUSED_OK", False)
+        with pytest.raises(RuntimeError, match="byte order"):
+            pe.dispatch_fused(params, db)
+    assert ran == []
+    got = pe.unpack_verdicts(np.asarray(pe.dispatch_fused(params, db)),
+                             1 + 2 * int(policy.eval_rule.shape[1]))
+    # the staged entry, whose body (traced once) runs eval_packed_jit
+    assert ran[0] == "eval_fused_jit" and "eval_bitpacked_jit" not in ran
+    assert got[:2, 0].tolist() == [True, False]

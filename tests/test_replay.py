@@ -514,27 +514,8 @@ def test_analysis_decisions_reader_rejects_skew(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# bench load model + offline CLI
+# offline CLI
 # ---------------------------------------------------------------------------
-
-
-def test_bench_load_timetable(tmp_path):
-    from authorino_tpu.replay.bench_load import load_timetable
-
-    d = str(tmp_path / "cap")
-    os.makedirs(d)
-    records = [make_record(i) for i in range(20)]
-    records.reverse()  # out of order on disk: the loader must sort
-    write_segment(os.path.join(d, f"s1{cap_mod.SEGMENT_SUFFIX}"), records)
-    offsets, names, docs, meta = load_timetable(d, speed=2.0)
-    assert offsets[0] == 0.0
-    assert offsets == sorted(offsets)
-    assert meta["records"] == 20 and meta["speed"] == 2.0
-    # 19 gaps of 10 ms at 2x speed → ~95 ms span
-    assert abs(offsets[-1] - 0.095) < 1e-6
-    assert names[0] == "cfg-a" and docs[0]["request"]["host"] == "h0"
-    offs2, *_ = load_timetable(d, limit=5)
-    assert len(offs2) == 5
 
 
 def test_analysis_replay_cli_offline(tmp_path, capsys):

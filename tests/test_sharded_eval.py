@@ -203,7 +203,7 @@ def test_engine_serves_from_sharded_snapshot():
 
 
 class TestServingPathBitParity:
-    """VERDICT sweep: the mesh serving path and the single-corpus serving
+    """The mesh serving path and the single-corpus serving
     path must produce IDENTICAL per-evaluator (rule, skipped) bits on a
     corpus that exercises all three lanes — device-DFA regex rows (incl.
     byte-tensor overflow), membership overflow (host-fallback lane), and

@@ -5,9 +5,9 @@ runtime in one transfer, and the served entry decodes it on the device.
 Held here, on the CPU: the staged entry equals the six-operand entry bit for
 bit over the warm grid, for both wire dtypes, with and without DFA operands,
 for a full cut and a deduplicated one; a served launch counts one transfer
-and the bytes it counted before; the probe that guards byte order falls back
-to six transfers and the same answers; the warm grid compiles every variant
-that serves; /debug/vars names the function that serves."""
+and the bytes it counted before; where the probe that guards byte order
+fails, the warm fails and nothing launches; the warm grid compiles every
+variant that serves; /debug/vars names the function that serves."""
 
 import random
 import re
@@ -287,32 +287,29 @@ def test_served_launch_is_one_transfer_of_the_same_bytes(served):
 
 
 @needs_native
-def test_probe_failure_keeps_six_transfers_and_the_same_answers(
-        served, monkeypatch):
-    """A backend whose byte order fails the one-time probe serves through
-    the six-operand entry: six transfers a launch, the same bytes counted,
-    the same answers; /debug/vars names that entry."""
+def test_probe_failure_fails_the_warm_loudly(served, monkeypatch):
+    """A backend whose byte order fails the one-time probe has no launch
+    format: the swap gate's warm fails with an error that names the byte
+    order, `wait_warm` answers False (the CLI stops there), and a cut
+    served meanwhile launches nothing on the device; the dispatch-failure
+    path answers it on the CPU twin."""
     fe, port, _ = served
     want = _answers(port)
-    assert fe.debug_vars()["snapshot"]["kernel"]["entry"] == (
-        "eval_bitpacked_staged")
     try:
         with monkeypatch.context() as m:
             m.setattr(pe, "_FUSED_OK", False)
+            before = _ledger()
             fe.refresh()  # a new snapshot record: its layouts are built anew
-            assert fe.wait_warm(300.0)
-            before, since = _ledger(), fe.batch_stages.to_json()["committed"]
-            assert _answers(port) == want
-            d = _delta(before)
-            assert d["launches"] >= 1
-            assert d["h2d_transfers"] == 6 * d["launches"]
+            assert not fe.wait_warm(300.0)
+            assert "byte order" in fe.warm_error
             assert _current(fe).layouts == {}
-            _same_bytes(fe, since, d)
-            assert fe.debug_vars()["snapshot"]["kernel"]["entry"] == (
-                "eval_bitpacked")
+            assert response_key(grpc_call(port, KERNEL_REQS[0])) == want[0]
+            d = _delta(before)
+            assert d["launches"] == d["h2d_transfers"] == d["h2d_bytes"] == 0
     finally:
         fe.refresh()
         assert fe.wait_warm(300.0)
+    assert fe.warm_error is None
     before = _ledger()
     assert _answers(port) == want
     d = _delta(before)

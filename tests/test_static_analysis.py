@@ -51,7 +51,7 @@ from authorino_tpu.runtime.engine import SnapshotRejected
 
 
 def _random_corpus(seed: int, n_configs: int = 7):
-    """bench.py-shaped generated corpus: every operator, ~regex mix,
+    """A generated corpus: every operator, ~regex mix,
     nested And/Or, shared + unique constants."""
     rng = random.Random(seed)
     configs = []

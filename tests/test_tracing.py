@@ -149,8 +149,7 @@ class TestNativeFrontendTracing:
         """With span export active, the native frontend head-samples:
         1-in-N requests take the Python pipeline and produce exported spans
         with the propagated trace id, the rest keep serving natively —
-        observability must not cost the fast lane wholesale (VERDICT r3
-        weak #2)."""
+        observability must not cost the fast lane wholesale."""
         import grpc
 
         from aiohttp import web
